@@ -4,7 +4,7 @@ PY ?= python
 DOCKER ?= docker
 TAG ?= latest
 
-.PHONY: test test-fast test-unit test-k8s bench bench-tiny bench-trend chaos chaos-soak cold-start dryrun loadgen loadgen-demo native clean charts images images-check fleet-snapshot perf-gate disagg-bench incident-drill incident-report qos-drill gray-drill kv-bench forecast-drill spike-drill
+.PHONY: test test-fast test-unit test-k8s chip-smoke bench bench-tiny bench-trend chaos chaos-soak cold-start dryrun loadgen loadgen-demo native clean charts images images-check fleet-snapshot perf-gate disagg-bench incident-drill incident-report qos-drill gray-drill kv-bench forecast-drill spike-drill
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -33,18 +33,26 @@ chaos-soak: ## seeded randomized multi-fault soak: 200 episodes vs a live stack,
 	JAX_PLATFORMS=cpu $(PY) benchmarks/chaos_soak.py \
 	    --episodes $(or $(EPISODES),200) --seed $(or $(SEED),1)
 
-bench:
+chip-smoke: ## serve a 7B model at published widths through the operator on ONE TPU chip
+	@# Needs a chip (exits non-zero without one); `CHIPS=4` runs the
+	@# tensor-parallel comparison on a four-chip host instead. The last
+	@# stdout line is the result. See README "Running it".
+	$(PY) chip_smoke.py $(if $(CHIPS),--chips $(CHIPS))
+
+bench: ## one chip preset (default 8b-int8); needs a TPU, exits non-zero without one
 	$(PY) bench.py
 
-bench-tiny:
+bench-tiny: ## the CPU smoke of bench.py (its result names the cpu device)
 	$(PY) bench.py --tiny
 
 BENCH ?=
-perf-gate: ## schema-validate a bench JSON + compare vs best prior BENCH_r*.json
-	@# Usage: make perf-gate [BENCH=path.json] — default gates the newest
-	@# BENCH_r*.json against the rest. Exits 1 on tok/s / MFU / TTFT
+BASELINES ?=
+perf-gate: ## schema-validate a bench JSON (+ compare vs prior ones, if any are given)
+	@# Usage: make perf-gate BENCH=new.json [BASELINES='old-*.json'].
+	@# No chip record is committed (PERF_LEDGER.jsonl is the driver's),
+	@# so there is no default baseline. Exits 1 on tok/s / MFU / TTFT
 	@# regression, 2 on schema violation (see benchmarks/BENCH_SCHEMA.md).
-	$(PY) benchmarks/perf_gate.py $(BENCH)
+	$(PY) benchmarks/perf_gate.py $(BENCH) $(if $(BASELINES),--baseline-glob '$(BASELINES)')
 
 cold-start: ## scale-from-zero SLO: serial vs streamed+warmed vs parked attach
 	JAX_PLATFORMS=cpu $(PY) benchmarks/cold_start.py --json BENCH_cold_start.json
@@ -140,10 +148,11 @@ fleet-snapshot: ## dump EVERY surface the operator's GET /debug index lists (run
 	@# endpoints ride along without Makefile edits.
 	$(PY) benchmarks/fleet_snapshot.py --url $(OPERATOR_URL)
 
-bench-trend: ## render the committed BENCH_r*.json perf trajectory as a table
-	@# tok/s, MFU, rate-controlled TTFT per round; CPU-fallback and
-	@# failed rounds are flagged, not plotted as real numbers.
-	$(PY) benchmarks/bench_trend.py
+bench-trend: ## render bench.py result files as one table: make bench-trend GLOB='results/*.json'
+	@# tok/s, MFU, rate-controlled TTFT per file; CPU and failed runs
+	@# are flagged, not plotted as real numbers. No result files are
+	@# committed, so name them.
+	$(PY) benchmarks/bench_trend.py --glob '$(GLOB)'
 
 dryrun:  ## multi-chip sharding dryrun on 8 virtual CPU devices
 	$(PY) __graft_entry__.py 8

@@ -11,7 +11,7 @@ waiting completion's first token streams back — under FOUR regimes:
   fast_cold     streamed weight load + background AOT compile overlap,
                 still an empty cache (isolates the overlap win)
   fast_warm     the full fast path: the loader Job pre-warmed the
-                shared KUBEAI_COMPILE_CACHE (--warm-compile-cache),
+                shared JAX_COMPILATION_CACHE_DIR (--warm-compile-cache),
                 weights stream, compiles are disk reads
   parked_attach scale-from-zero lands on a pre-warmed PARKED pod
                 (process + jax + cache already up; /v1/attach streams
@@ -157,9 +157,10 @@ def run_manager(ckpt: str, xla_cache: str, fast: bool, parked: int = 0):
     system.autoscaling.interval_seconds = 0.5
     system.parked_replicas = parked
     mgr = Manager(system, local_runtime=True, host="127.0.0.1", port=0)
-    if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-        mgr.local_runtime.extra_env["JAX_PLATFORMS"] = "cpu"
-    mgr.local_runtime.extra_env["KUBEAI_COMPILE_CACHE"] = xla_cache
+    # A CPU measurement of the start path's structure (the parked regime
+    # runs two engine processes at once; a chip takes one).
+    mgr.local_runtime.extra_env["JAX_PLATFORMS"] = "cpu"
+    mgr.local_runtime.extra_env["JAX_COMPILATION_CACHE_DIR"] = xla_cache
     # EVERY regime warms up fully before ready, so "first token" always
     # prices the same compiled coverage — without this the serial run
     # hides most of its compile debt behind later requests and the
@@ -214,6 +215,8 @@ def main():
 
     ckpt = tempfile.mkdtemp(prefix="cold-start-ckpt-")
     nbytes = save_bench_checkpoint(ckpt)
+    # Fresh cache directories on purpose: this benchmark compares a cold
+    # cache with a warm one, so it cannot use the checkout's shared one.
     cache_cold1 = tempfile.mkdtemp(prefix="cold-start-xla-serial-")
     cache_cold2 = tempfile.mkdtemp(prefix="cold-start-xla-fastcold-")
     cache_warm = tempfile.mkdtemp(prefix="cold-start-xla-warm-")
@@ -246,8 +249,9 @@ def main():
 
         # 3. loader Job warms the shared cache (the satellite CLI),
         #    then the fast path runs against it.
-        env = dict(os.environ, KUBEAI_COMPILE_CACHE=cache_warm)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = dict(
+            os.environ, JAX_COMPILATION_CACHE_DIR=cache_warm, JAX_PLATFORMS="cpu"
+        )
         t0 = time.monotonic()
         staged = os.path.join(tempfile.mkdtemp(prefix="cold-start-staged-"), "model")
         r = subprocess.run(

@@ -40,7 +40,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # Device constant tables live in the shared perf accounting now
 # (kubeai_tpu/obs/perf.py) — one source for bench.py, the engine's live
 # MFU/roofline gauges, and this harness.
-from kubeai_tpu.obs.perf import HBM_GBPS, device_constants  # noqa: E402
+from kubeai_tpu.obs.perf import HBM_GBPS, PEAK_FLOPS, device_constants  # noqa: E402
 
 
 def log(msg):
@@ -129,9 +129,10 @@ def run_sweep(
     # 8b-int8 flagship (the config the 96-slot cliff was measured on) —
     # step = weight-read floor + measured attention x num_layers — so
     # the cliff analysis reads directly off the sweep output as mfu /
-    # roofline_fraction columns. Unknown devices (CPU smoke) assume v5e
+    # roofline_fraction columns. The CPU smoke projects onto v5e
     # constants, labeled `assumed_device` — trend-only, like the rest
-    # of a degraded run.
+    # of a degraded run; an accelerator missing from the peak table is
+    # an error, never a default.
     from kubeai_tpu.models.base import ModelConfig
     from kubeai_tpu.obs.perf import PerfModel, device_constants
 
@@ -146,8 +147,13 @@ def run_sweep(
     )
     env = device_constants(str(kind))
     assumed_device = env.hbm_gbps is None or env.peak_flops is None
+    if assumed_device and not degraded:
+        raise SystemExit(
+            f"no peak FLOP/s or HBM bandwidth on record for device kind "
+            f"{kind!r} (obs/perf.py PEAK_FLOPS / HBM_GBPS)"
+        )
     hbm_gbps = env.hbm_gbps or HBM_GBPS["v5e"]
-    peak_flops = env.peak_flops or 197e12
+    peak_flops = env.peak_flops or PEAK_FLOPS["v5e"]
     floor_ms = pm.step_floor_seconds(hbm_gbps) * 1e3
 
     def make_doc(rows):
@@ -397,14 +403,7 @@ def main():
 
     from kubeai_tpu.engine.coldstart import setup_compile_cache
 
-    # Shared helper: KUBEAI_COMPILE_CACHE wins, else the repo-local dir.
-    setup_compile_cache(
-        os.environ.get("KUBEAI_COMPILE_CACHE")
-        or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_compile_cache",
-        )
-    )
+    setup_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np

@@ -201,14 +201,14 @@ def main():
     import shutil
 
     ckpt = make_tiny_checkpoint()
-    xla_cache = tempfile.mkdtemp(prefix="routing-compare-xla-")
     system = System().default_and_validate()
     mgr = Manager(system, local_runtime=True, host="127.0.0.1", port=0)
-    if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-        mgr.local_runtime.extra_env["JAX_PLATFORMS"] = "cpu"
-    # Shared persistent compile cache: later strategies' replicas reuse
-    # the first's compiled kernels (identical shapes).
-    mgr.local_runtime.extra_env["KUBEAI_COMPILE_CACHE"] = xla_cache
+    # A CPU comparison: every replica is its own process, a chip belongs
+    # to one process at a time, and LocalRuntime cannot yet give each
+    # pod its own chip (ROADMAP B6). The replicas share the engine
+    # CLI's compile cache (engine/coldstart.py), so later strategies'
+    # replicas reuse the first's compiled programs.
+    mgr.local_runtime.extra_env["JAX_PLATFORMS"] = "cpu"
     mgr.start()
     rows = []
     try:
@@ -218,7 +218,6 @@ def main():
     finally:
         mgr.stop()
         shutil.rmtree(ckpt, ignore_errors=True)
-        shutil.rmtree(xla_cache, ignore_errors=True)
 
     print(render_table(rows))
     if args.json:

@@ -170,7 +170,7 @@ class CacheReconciler:
         except NotFound:
             # Opt-in loader warm: the staging Job also AOT-compiles the
             # engine step functions for this checkpoint into the shared
-            # KUBEAI_COMPILE_CACHE, keyed to the Model's own engine args
+            # JAX_COMPILATION_CACHE_DIR, keyed to the Model's own engine args
             # — hot before the first replica starts. One decision for
             # both the flag and the trailing args (they are useless
             # apart).

@@ -6,10 +6,12 @@ read the whole checkpoint, convert on host, device_put, jit-compile,
 warm up — while the proxy held requests. This module provides the
 machinery that collapses it:
 
-- ``setup_compile_cache()`` — the ONE place ``KUBEAI_COMPILE_CACHE``
-  is honored. Every engine entry point (CLI server, gang follower,
-  bench harnesses, in-process engines) calls it, so a shared cache
-  mount turns first-compiles into disk reads everywhere.
+- ``setup_compile_cache()`` — the ONE place the persistent compilation
+  cache is placed: ``JAX_COMPILATION_CACHE_DIR`` where it is set, the
+  checkout's ``.jax_compile_cache`` otherwise. Every process entry
+  point (CLI server incl. gang followers, loader warm, bench harnesses)
+  calls it, so a shared cache mount turns first-compiles into disk
+  reads everywhere.
 - ``ColdStartTimeline`` — per-phase stamps (stage/load/compile/warmup
   → ready) surfaced in ``/debug/engine`` and the
   ``kubeai_engine_cold_start_seconds{phase}`` histogram. Sum-of-phases
@@ -53,37 +55,41 @@ M_COLD_START = default_registry.histogram(
 )
 
 
-def setup_compile_cache(cache_dir: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at *cache_dir* (default:
-    the ``KUBEAI_COMPILE_CACHE`` env var; no-op when neither is set).
+def default_compile_cache_dir() -> str:
+    """``<checkout>/.jax_compile_cache`` (git-ignored): a fixed path,
+    because the path is part of the cache's key and a directory that
+    moves never hits."""
+    root = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    return os.path.join(root, ".jax_compile_cache")
 
-    The single shared helper every engine entry point calls — the CLI
-    server, the gang follower path, bench.py, profile_engine.py, and
-    in-process engine construction — so a shared cache mount benefits
-    all of them, not just the CLI server. Safe to call repeatedly."""
-    cache_dir = cache_dir or os.environ.get("KUBEAI_COMPILE_CACHE")
-    if not cache_dir:
-        return None
+
+def setup_compile_cache() -> str:
+    """Place jax's persistent compilation cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set (a shared mount, the engine
+    image's /cache/jax) the cache lives there, otherwise in
+    default_compile_cache_dir(). Returns the directory.
+
+    The ONE writer of ``jax_compilation_cache_dir``, called by the
+    process entry points (engine server CLI, loader warm, bench.py,
+    profile_engine.py) — library code never places a cache, it only
+    reads ``jax.config.jax_compilation_cache_dir`` to see whether one is
+    on. Safe to call repeatedly."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or default_compile_cache_dir()
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Cache EVERY compilation by default: the loader-warmed / parked
-    # fast path depends on sub-second compiles (small models, per-bucket
-    # prefill shapes) being hits too — the old 1s floor silently skipped
-    # exactly the entries that make warmup cheap.
-    min_secs = float(os.environ.get("KUBEAI_COMPILE_CACHE_MIN_SECS", "0"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
-    try:
-        # The cache module latches its initialized state at first use:
-        # a process that already compiled anything (in-process engines,
-        # tests) would silently ignore the new dir without this reset.
-        from jax._src import compilation_cache as _cc
-
-        if getattr(_cc, "is_initialized", lambda: False)():
-            _cc.reset_cache()
-    except Exception:  # pragma: no cover - private-API drift guard
-        pass
+    # Cache EVERY compilation: the loader-warmed / parked fast path
+    # depends on sub-second compiles (small models, per-bucket prefill
+    # shapes) being hits too — jax's default 1s floor skips exactly the
+    # entries that make warmup cheap.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # The cache latches its directory at first use: a process that
+    # already compiled anything would ignore the new one without this.
+    compilation_cache.reset_cache()
     return cache_dir
 
 

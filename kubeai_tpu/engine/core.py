@@ -102,9 +102,9 @@ class EngineConfig:
     # the slot: prompt_len + max_tokens <= max_seq_len).
     default_max_tokens: int = 256
     # Decode steps fused into one jitted lax.scan call: host<->device
-    # round-trips (expensive over remote-attached TPU) are amortized K x at
-    # the cost of up to K-1 wasted steps per finished sequence and
-    # admission latency quantized to one chunk.
+    # round-trips are amortized K x at the cost of up to K-1 wasted
+    # steps per finished sequence and admission latency quantized to
+    # one chunk.
     decode_chunk: int = 8
     # Batched multi-LoRA capacity (bank allocated on first adapter load;
     # the first load triggers one recompile of the step functions).
@@ -171,8 +171,7 @@ class EngineConfig:
     # requests exceeding this cap (it does NOT silently truncate —
     # _bias_rows' first-N drop is only a backstop for direct submit()
     # callers). Static shape — the [B, K] bias arrays ride every decode
-    # dispatch regardless of use (~2.4 KB/slot at 300; operators can
-    # shrink it for dispatch-bandwidth-sensitive remote-TPU setups).
+    # dispatch regardless of use (~2.4 KB/slot at 300).
     max_logit_bias: int = 300
     # Top-N alternative logprobs computed per choice point (one extra
     # lax.top_k over the vocab per step — same order of work as the
@@ -648,10 +647,25 @@ class Engine:
             "roofline_toks_per_sec": round(roof, 1) if roof else None,
             "flops_per_token": self.perf.flops_per_token,
             "weight_bytes": self.perf.weight_bytes,
+            "platform": env.platform,
             "device": env.kind,
             "devices": self._perf_devices,
+            "visible_devices": env.visible_devices,
             "peak_flops": peak,
             "hbm_gbps": hbm,
+            # Per local device, as memory_stats() reports it (nothing on
+            # the CPU backend): under tp every chip should hold its
+            # share of the weights and of the pool.
+            "memory": [
+                {
+                    "id": dev.id,
+                    **{
+                        k: v for k, v in (dev.memory_stats() or {}).items()
+                        if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                    },
+                }
+                for dev in jax.local_devices()
+            ],
             "stall": self._stall.report(),
         }
 
@@ -711,12 +725,12 @@ class Engine:
             keys = jax.random.key_data(jax.random.split(jax.random.key(0), B))
             return cache, tok_hist, adm_toks, lengths, last_tokens, keys
 
-        if self._multiproc:
-            # Every rank runs the SAME jitted init with explicit global
-            # out_shardings: the KV pool is tp-sharded over heads, the
-            # small per-slot state fully replicated. Eager jnp.zeros
-            # would pin process-local arrays that a global-mesh jit
-            # rejects.
+        if self._mesh is not None:
+            # One jitted init with explicit out_shardings: the KV pool is
+            # tp-sharded over heads, the small per-slot state fully
+            # replicated. Eager jnp.zeros would put the whole pool on the
+            # first device (and, on a gang, pin process-local arrays that
+            # a global-mesh jit rejects).
             from jax.sharding import NamedSharding, PartitionSpec
 
             from kubeai_tpu.parallel.sharding import paged_cache_specs
@@ -741,9 +755,8 @@ class Engine:
         # Per-slot request state is HOST-authoritative numpy, uploaded
         # with every decode dispatch (the arrays ride the execute RPC —
         # free). Round 2 kept these as device arrays mutated by eager
-        # .at[].set per admission: ~9 eager dispatches x ~10ms host time
-        # per admitted request on a remote-attached TPU, all spent while
-        # the device sat idle. Only state that EVOLVES device-side
+        # .at[].set per admission: ~9 eager dispatches of host time per
+        # admitted request, all spent while the device sat idle. Only state that EVOLVES device-side
         # between host syncs (pool, lengths, last token, PRNG keys,
         # speculation history) stays as donated device carries.
         self._h_active = np.zeros((B,), bool)
@@ -806,9 +819,7 @@ class Engine:
         # divisibility, MXU tiling); padded columns carry zero weights and
         # logit 0.0, which is very much sampleable — mask them out.
         n_valid = min(getattr(self.tokenizer, "vocab_size", mc.vocab_size), mc.vocab_size)
-        sf = build_step_functions(
-            mc, self.cfg, n_valid, mesh=self._mesh, multiproc=self._multiproc
-        )
+        sf = build_step_functions(mc, self.cfg, n_valid, mesh=self._mesh)
         self._step_fns = sf
         self._prefill_chunk_jit = sf.prefill_chunk_jit
         self._prefill_batch_jit = sf.prefill_batch_jit
@@ -818,6 +829,21 @@ class Engine:
         self._decode_jit = self._decode_jit_for(self._decode_kernel)
 
 
+
+    def _attn_kernel(self, kind: str, queries: int) -> str:
+        """The attention implementation the step *kind* compiles to at
+        *queries* tokens per row, for the step records: "flash", the
+        paged kernel flavor ("ragged" | "dedicated"), or "xla" (the
+        portable gather route: every CPU run, sliding-window models)."""
+        route = llama.cached_attention_route(
+            self.model_config, queries,
+            left_aligned=kind == "prefill_group", paged=True,
+        )
+        if route != "paged_kernel":
+            return route
+        # Only the decode step takes the configured flavor; prefill
+        # always rides the ragged kernel.
+        return self._decode_kernel if kind == "decode_chunk" else "ragged"
 
     def _decode_jit_for(self, kernel: str):
         """The jitted decode step for a concrete kernel flavor, built on
@@ -1427,29 +1453,22 @@ class Engine:
         the HBM callback gauges at /metrics collect time."""
         used = limit = 0
         for dev in jax.local_devices():
-            stats = getattr(dev, "memory_stats", lambda: None)()
-            if stats:
-                used += stats.get("bytes_in_use", 0)
-                limit += stats.get("bytes_limit", 0)
+            stats = dev.memory_stats() or {}  # None on the CPU backend
+            used += stats.get("bytes_in_use", 0)
+            limit += stats.get("bytes_limit", 0)
         return used, limit
 
     def _jit_cache_entries(self) -> int:
         """Total compiled executables across the step functions (jax's
         per-function lowering cache). Growth = a compilation happened."""
-        fns = list(self._decode_jits.values()) + [
+        fns = [
+            *self._decode_jits.values(),
             self._prefill_batch_jit,
             self._prefill_chunk_jit,
-            getattr(self, "_embed_jit", None),
         ]
-        total = 0
-        for fn in fns:
-            size = getattr(fn, "_cache_size", None)
-            if callable(size):
-                try:
-                    total += size()
-                except Exception:  # pragma: no cover - jax API drift guard
-                    pass
-        return total
+        if hasattr(self, "_embed_jit"):  # built on first embeddings call
+            fns.append(self._embed_jit)
+        return sum(fn._cache_size() for fn in fns)
 
     def _update_recompile_counter(self) -> None:
         """Scheduler-loop poll: surface compilations (warmup AND shape-
@@ -2280,6 +2299,7 @@ class Engine:
             self.m_pad_prefill.inc(pad_tokens)
         default_recorder.record_step(
             kind="prefill_chunked", slot=slot_idx,
+            kernel=self._attn_kernel("prefill_chunked", max_bucket),
             prompt_tokens=len(ids), reuse_tokens=reuse,
             pad_tokens=pad_tokens,
             dur_ms=round(dur * 1000, 3),
@@ -2455,6 +2475,7 @@ class Engine:
             self.m_pad_prefill.inc(pad_tokens)
         default_recorder.record_step(
             kind="prefill_group", bucket=bucket, batch=n,
+            kernel=self._attn_kernel("prefill_group", bucket),
             slots=[s for s, _ in items],
             prompt_tokens=real_tokens,
             pad_tokens=pad_tokens,
@@ -2655,7 +2676,9 @@ class Engine:
             "steps": K_steps,
             "slots": [i for i, _, _ in snapshot],
             "tokens": n_emitted,
-            "kernel": self._decode_kernel,
+            "kernel": self._attn_kernel(
+                "decode_chunk", 1 + self.cfg.speculate_tokens
+            ),
             "pages_used": self._pool.used(),
             "pages_total": self._pool.num_pages - 1,
             "queue_depth": self.queue_depth(),
@@ -2831,9 +2854,22 @@ class Engine:
                 keys.at[slot].set(key_row),
             )
 
+        imp_kw = set_kw = {}
+        if self._mesh is not None:
+            # Single-process tp: the donated pool and carries come back
+            # laid out as they went in (see build_step_functions).
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            from kubeai_tpu.parallel.sharding import named, paged_cache_specs
+
+            repl = NamedSharding(self._mesh, PartitionSpec())
+            imp_kw = {"out_shardings": named(paged_cache_specs(), self._mesh)["kv"]}
+            set_kw = {"out_shardings": (repl,) * 4}
         self._kv_evolve_jit = jax.jit(evolve)
-        self._kv_import_jit = jax.jit(imp, donate_argnums=(0,))
-        self._kv_slotset_jit = jax.jit(slotset, donate_argnums=(0, 1, 2, 3))
+        self._kv_import_jit = jax.jit(imp, donate_argnums=(0,), **imp_kw)
+        self._kv_slotset_jit = jax.jit(
+            slotset, donate_argnums=(0, 1, 2, 3), **set_kw
+        )
 
     def _park_slot(self, slot_idx: int, slot: "_Slot") -> dict | None:
         """Serialize a finishing slot's KV state into the host park store
@@ -3178,7 +3214,6 @@ def build_step_functions(
     engine_config: EngineConfig,
     n_valid_vocab: int | None = None,
     mesh=None,
-    multiproc: bool = False,
 ) -> StepFunctions:
     """Build the jitted prefill/decode step functions for a config pair.
 
@@ -3218,7 +3253,7 @@ def build_step_functions(
         keys = jax.vmap(jax.random.key)(seeds)
         logits, cache = llama.prefill_paged_cold(
             params, mc, tokens, cache, tables, lengths,
-            lora=lora, lora_rows=lora_rows,
+            lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
         )
         masked = mask_pad(logits[:, -1])
         # Bias steers choice; the reported logprob stays the model's
@@ -3240,6 +3275,7 @@ def build_step_functions(
             params, mc, tokens, cache, table, start[None], last_idx[None],
             lora=lora,
             lora_rows=None if lora_row is None else lora_row[None],
+            tp_mesh=mesh,
         )
         masked = mask_pad(logits[:, -1])
         tok = sample(
@@ -3342,7 +3378,7 @@ def build_step_functions(
             logits, cache = llama.decode_speculative_paged(
                 params, mc, inputs, cache, tables, lengths,
                 lora=lora, lora_rows=lora_rows,
-                decode_kernel=_decode_kernel,
+                decode_kernel=_decode_kernel, tp_mesh=mesh,
             )
             logits = mask_pad(logits)  # [B, G+1, V]
             if penalties_on:
@@ -3448,13 +3484,14 @@ def build_step_functions(
     # adm_toks (prefill arg 11 / chunk arg 12) and the cache are
     # donated through prefill calls; decode reads adm_toks without
     # donating it (it survives until the next prefill overwrites it).
-    # Multi-process gangs pin out_shardings explicitly: the KV pool
-    # keeps its tp sharding, everything the host reads back must be
-    # fully replicated (device_get on a cross-process-sharded array
-    # has no local copy to fetch) — single-host leaves GSPMD free.
+    # A mesh pins out_shardings explicitly: the KV pool keeps its tp
+    # sharding (left to the compiler, the donated pool could come back
+    # laid out differently from how it went in), and everything the
+    # host reads back is fully replicated (device_get on a
+    # cross-process-sharded array has no local copy to fetch).
     shard_kw = {}
     chunk_kw = {}
-    if multiproc:
+    if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from kubeai_tpu.parallel.sharding import paged_cache_specs
@@ -3499,12 +3536,7 @@ def build_test_engine(
 ) -> Engine:
     """A tiny randomly-initialized byte-vocab engine for tests/dev — the
     in-process analogue of the reference's mock engine seam."""
-    from kubeai_tpu.engine.coldstart import setup_compile_cache
     from kubeai_tpu.engine.tokenizer import ByteTokenizer
-
-    # In-process engines honor the shared compile cache too (no-op
-    # unless KUBEAI_COMPILE_CACHE is set).
-    setup_compile_cache()
 
     tok = ByteTokenizer()
     mc = model_config or ModelConfig(

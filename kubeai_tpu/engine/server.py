@@ -1438,17 +1438,22 @@ def build_engine_from_args(args, publisher=None, warmup: bool | None = None) -> 
 
 def maybe_init_distributed() -> list[str] | None:
     """Multi-host slice bootstrap: the controller stamps gang pods with
-    TPU_WORKER_ID + TPU_WORKER_HOSTNAMES (controller/engines/tpu.py);
-    rank 0's host serves as the jax.distributed coordinator so the gang
-    forms one device mesh across hosts. Returns the gang host list (or
-    None for single-host pods)."""
-    import os
-
+    TPU_WORKER_ID + TPU_WORKER_HOSTNAMES + KUBEAI_GANG_SECRET
+    (controller.reconcile_pods); rank 0's host serves as the
+    jax.distributed coordinator so the gang forms one device mesh across
+    hosts. Returns the gang host list (or None for single-host pods)."""
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
-    if not hostnames:
-        return None
     hosts = [h.strip() for h in hostnames.split(",") if h.strip()]
     if len(hosts) < 2:
+        return None
+    if not os.environ.get("KUBEAI_GANG_SECRET"):
+        # A TPU VM's own environment lists the slice's workers under the
+        # same variable; only the controller's stamp (which always comes
+        # with the gang secret) makes this pod a gang rank.
+        log.info(
+            "TPU_WORKER_HOSTNAMES lists %d hosts but no gang secret is "
+            "stamped: serving single-host", len(hosts),
+        )
         return None
     import jax
 
@@ -1614,7 +1619,7 @@ def make_engine_arg_parser(require_model: bool = True) -> argparse.ArgumentParse
         "--warmup", action="store_true",
         default=os.environ.get("KUBEAI_ENGINE_WARMUP", "0") == "1",
         help="pre-dispatch every step-function shape before serving "
-             "(cheap with a warm KUBEAI_COMPILE_CACHE; the first real "
+             "(cheap with a warm compile cache; the first real "
              "request then never pays a compile)",
     )
     parser.add_argument(
@@ -1633,22 +1638,12 @@ def make_engine_arg_parser(require_model: bool = True) -> argparse.ArgumentParse
 
 
 def main(argv=None):
-    # Honor JAX_PLATFORMS explicitly: plugin registration can override the
-    # env var, and config only works before the first backend query.
-    import os
-
-    want = os.environ.get("JAX_PLATFORMS")
-    if want:
-        import jax
-
-        jax.config.update("jax_platforms", want)
     # Persistent XLA compilation cache: replicas of the same model shape
     # skip recompilation (big cold-start cut when the cache dir is a
-    # shared mount; harmless otherwise). Shared helper — the follower
-    # path, bench harnesses, and in-process engines use the same one.
+    # shared mount). Gang followers come through here too.
     from kubeai_tpu.engine.coldstart import setup_compile_cache
 
-    setup_compile_cache()
+    cache_dir = setup_compile_cache()
     gang_hosts = maybe_init_distributed()
 
     parser = make_engine_arg_parser(require_model=False)
@@ -1656,6 +1651,15 @@ def main(argv=None):
     if not args.parked and not args.model:
         parser.error("--model is required (unless --parked)")
     setup_logging("engine")
+    import jax
+
+    # Initializes the backend: with JAX_PLATFORMS=tpu and no chip this
+    # raises here, before any model work, instead of serving from CPU.
+    dev = jax.devices()[0]
+    log.info(
+        "engine process on platform=%s device_kind=%s devices=%d compile_cache=%s",
+        dev.platform, dev.device_kind, jax.device_count(), cache_dir,
+    )
 
     if args.parked:
         if gang_hosts:

@@ -105,10 +105,14 @@ def apply_backend_flags(config: ModelConfig) -> ModelConfig:
 
 class SafetensorsSource:
     """Random-access view over a checkpoint's *.safetensors shards:
-    opens every shard (header reads only — tensor data stays on disk
-    until asked for) and serves tensors by name. The streaming loader's
-    read side: one parameter group's tensors are materialized at a
-    time, so peak host memory is one stacked group, not the model."""
+    indexes every shard's tensor names (header reads only — tensor data
+    stays on disk until asked for) and serves tensors by name. The
+    streaming loader's read side: one parameter group's tensors are
+    materialized at a time, so peak host memory is one stacked group,
+    not the model. A shard is mapped only for the length of one get():
+    a mapping held open keeps every page ever read resident, which by
+    the end of a load is the whole checkpoint (15 GB for a 7B model,
+    charged to the process on a sandboxed host)."""
 
     def __init__(self, path: str):
         from safetensors import safe_open
@@ -116,16 +120,19 @@ class SafetensorsSource:
         self.files = sorted(glob.glob(os.path.join(path, "*.safetensors")))
         if not self.files:
             raise FileNotFoundError(f"no *.safetensors under {path}")
-        self._readers = [safe_open(f, framework="np") for f in self.files]
-        self._index = {
-            name: r for r in self._readers for name in r.keys()
-        }
+        self._index: dict[str, str] = {}
+        for f in self.files:
+            with safe_open(f, framework="np") as reader:
+                self._index.update(dict.fromkeys(reader.keys(), f))
 
     def __contains__(self, name: str) -> bool:
         return name in self._index
 
     def get(self, name: str) -> np.ndarray:
-        return self._index[name].get_tensor(name)
+        from safetensors import safe_open
+
+        with safe_open(self._index[name], framework="np") as reader:
+            return reader.get_tensor(name)  # a copy: outlives the mapping
 
     def names(self):
         return self._index.keys()
@@ -142,13 +149,21 @@ def stream_params_from_hf(
     quantize_model_params: each parameter group (one stacked-layer
     weight, the embedding, the head) is read, converted, vocab-padded,
     quantized, and device_put with its target sharding BEFORE the next
-    group is touched — host memory peaks at one group instead of the
-    whole state dict + the converted tree + the pad copy coexisting,
-    and HBM starts filling while the tail of the checkpoint is still
-    being read. Returns (device params, config with the padded vocab).
+    group is touched, and within a stacked group each layer is
+    converted and quantized on its own (a few layers at a time, on
+    threads: numpy works outside the interpreter lock) and written
+    straight into its row of the stacked array — host memory peaks at a
+    few layers' float32 copies plus one group in its final dtype, not at
+    the whole group in float32 several times over (a 7B int8 load
+    peaked at 45 GB of host memory that way, on a 40 GiB host), and HBM
+    starts filling while the tail of the checkpoint is still being read.
+    Returns (device params, config with the padded vocab).
 
     Single-process only (a gang rank must assemble global arrays from
     the full host tree — load_engine_from_path falls back there)."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
     from jax.sharding import NamedSharding
 
     from kubeai_tpu.ops.quant import quantize, quantize_rows
@@ -164,17 +179,11 @@ def stream_params_from_hf(
     quant_dense = ("wq", "wk", "wv", "wo") + (
         () if config.num_experts > 0 else ("wg", "wu", "wd")
     )
+    int8 = quantization == "int8"
 
     def put(host, *key_path):
-        """Convert + (maybe) quantize + device_put ONE group, with its
-        target sharding when a tp mesh is given."""
-        if quantization == "int8":
-            if key_path == ("embed",):
-                host = quantize_rows(host)
-            elif key_path == ("lm_head",) or (
-                len(key_path) == 2 and key_path[1] in quant_dense
-            ):
-                host = quantize(host, contract_axis=-2)
+        """device_put ONE converted group, with its target sharding when
+        a tp mesh is given."""
         if mesh is not None:
             spec = specs
             for k in key_path:
@@ -185,22 +194,45 @@ def stream_params_from_hf(
     def conv(a):
         return np.asarray(a, dtype)
 
-    def stack(fmt, transpose=True):
-        ws = [np.asarray(source.get(fmt.format(i))) for i in range(L)]
-        return conv(np.stack([w.T if transpose else w for w in ws]))
+    def stack(fmt, transpose=True, quant=False):
+        """One stacked group [L, ...], each layer converted (and
+        quantized: per-layer, per-output-channel scales, exactly what
+        quantizing the stacked array gives) on its own."""
+
+        out: dict = {}  # allocated by whichever layer finishes first
+
+        def one(i):
+            w = np.asarray(source.get(fmt.format(i)))
+            w = conv(w.T if transpose else w)
+            parts = quantize(w, contract_axis=-2) if quant else {"w": w}
+            for k, a in parts.items():
+                # Straight into its row of the stacked array: a list of
+                # layers stacked afterwards would hold the group twice.
+                with alloc:
+                    if k not in out:
+                        out[k] = np.empty((L, *a.shape), a.dtype)
+                out[k][i] = a
+
+        alloc = threading.Lock()
+        with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as workers:
+            list(workers.map(one, range(L)))  # list(): re-raise a layer's error
+        return out if quant else out["w"]
 
     embed = conv(np.asarray(source.get("model.embed_tokens.weight")))
     if pad:
         embed = np.pad(embed, ((0, pad), (0, 0)))
     params: dict = {
-        "embed": put(embed, "embed"),
+        "embed": put(quantize_rows(embed) if int8 else embed, "embed"),
         "final_norm": put(conv(np.asarray(source.get("model.norm.weight"))), "final_norm"),
     }
     del embed
     layers: dict = {}
 
     def put_layer(key, fmt, transpose=True):
-        layers[key] = put(stack(fmt, transpose=transpose), "layers", key)
+        layers[key] = put(
+            stack(fmt, transpose=transpose, quant=int8 and key in quant_dense),
+            "layers", key,
+        )
 
     put_layer("ln1", "model.layers.{}.input_layernorm.weight", transpose=False)
     put_layer("wq", "model.layers.{}.self_attn.q_proj.weight")
@@ -247,7 +279,9 @@ def stream_params_from_hf(
         head = conv(np.asarray(source.get("lm_head.weight")).T)
         if pad:
             head = np.pad(head, ((0, 0), (0, pad)))
-        params["lm_head"] = put(head, "lm_head")
+        params["lm_head"] = put(
+            quantize(head, contract_axis=-2) if int8 else head, "lm_head"
+        )
         del head
     return params, out_config
 
@@ -290,13 +324,14 @@ def load_engine_from_path(
     # crashloop-at-weight-load scenario the controller must absorb).
     from kubeai_tpu.engine.coldstart import (
         ColdStartTimeline,
-        setup_compile_cache,
         start_background_warm,
     )
     from kubeai_tpu.faults import fault
 
     fault("weights.load")
-    cache_dir = setup_compile_cache() or jax.config.jax_compilation_cache_dir
+    # Placed by the process entry point (coldstart.setup_compile_cache);
+    # None for in-process engines that never asked for one.
+    cache_dir = jax.config.jax_compilation_cache_dir
     if quantization:
         if quantization != "int8":
             raise ValueError(f"unsupported quantization {quantization!r} (supported: int8)")
@@ -433,8 +468,8 @@ def load_engine_from_path(
         return eng
 
     if mesh is not None:
-        # Cache + step functions inherit shardings via XLA propagation from
-        # the params; the engine jits inside this mesh context.
+        # The engine allocates its device state with shardings on this
+        # mesh and pins them on its step functions' outputs.
         with mesh:
             return build(mesh)
     return build()
@@ -500,13 +535,15 @@ def write_peft_checkpoint(path, config: "ModelConfig", rank=4, alpha=8, seed=0, 
     return tensors
 
 
-def save_hf_checkpoint(path: str, config: ModelConfig, state_dict: dict[str, np.ndarray], tokenizer_src: str | None = None):
-    """Write a minimal HF-format checkpoint dir (config.json + one
-    safetensors file). Used by tests and the model-loader."""
+def write_hf_config(path: str, config: ModelConfig) -> None:
+    """Write the HF-format config.json for *config*: Llama-shaped, or
+    Qwen2-shaped (the loader then expects q/k/v bias tensors) when the
+    config has qkv_bias."""
     os.makedirs(path, exist_ok=True)
+    qwen2 = config.qkv_bias
     cfg = {
-        "architectures": ["LlamaForCausalLM"],
-        "model_type": "llama",
+        "architectures": ["Qwen2ForCausalLM" if qwen2 else "LlamaForCausalLM"],
+        "model_type": "qwen2" if qwen2 else "llama",
         "vocab_size": config.vocab_size,
         "hidden_size": config.hidden_size,
         "intermediate_size": config.intermediate_size,
@@ -520,6 +557,12 @@ def save_hf_checkpoint(path: str, config: ModelConfig, state_dict: dict[str, np.
     }
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(cfg, f, indent=1)
+
+
+def save_hf_checkpoint(path: str, config: ModelConfig, state_dict: dict[str, np.ndarray]):
+    """Write a minimal HF-format checkpoint dir (config.json + one
+    safetensors file). Used by tests and the model-loader."""
     from safetensors.numpy import save_file
 
+    write_hf_config(path, config)
     save_file(state_dict, os.path.join(path, "model.safetensors"))

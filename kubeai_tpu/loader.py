@@ -17,8 +17,8 @@ complete.
 --warm-compile-cache additionally AOT-compiles the engine's step
 functions against the staged checkpoint's shapes (config.json +
 tokenizer only — no weights are loaded) into the shared
-KUBEAI_COMPILE_CACHE, so the cache is hot BEFORE the first replica ever
-starts. Trailing engine-server args (e.g. the Model's spec.args:
+JAX_COMPILATION_CACHE_DIR, so the cache is hot BEFORE the first replica
+ever starts. Trailing engine-server args (e.g. the Model's spec.args:
 ``--max-seq-len 512 --max-slots 4``) pin the warmed shapes to what the
 serving pods will actually run.
 """
@@ -102,15 +102,16 @@ def stage_remote(url: str, base_dir: str, prefix: str = "") -> str:
 
 
 def warm_compile_cache(dest: str, engine_args: list[str] | None = None) -> dict | None:
-    """Loader-side compile-cache warm: requires KUBEAI_COMPILE_CACHE
-    (warming a process-local cache would benefit nobody). Never raises —
-    a warm failure must not fail the staging Job that gates pod
-    creation."""
+    """Loader-side compile-cache warm: requires JAX_COMPILATION_CACHE_DIR
+    (a cache placed from outside is one the serving pods share; warming
+    this Job's own default would benefit nobody). Never raises — a warm
+    failure must not fail the staging Job that gates pod creation."""
     from kubeai_tpu.engine.coldstart import setup_compile_cache, warm_from_checkpoint
 
-    if setup_compile_cache() is None:
-        log.info("KUBEAI_COMPILE_CACHE is not set; skipping compile warm")
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        log.info("JAX_COMPILATION_CACHE_DIR is not set; skipping compile warm")
         return None
+    setup_compile_cache()
     try:
         stats = warm_from_checkpoint(dest, engine_args)
     except Exception as e:
@@ -126,7 +127,7 @@ def main(argv=None):
     parser.add_argument(
         "--warm-compile-cache", action="store_true",
         help="after staging, AOT-compile the engine step functions for "
-             "the checkpoint's shapes into KUBEAI_COMPILE_CACHE; "
+             "the checkpoint's shapes into JAX_COMPILATION_CACHE_DIR; "
              "trailing engine-server args pin the warmed shapes",
     )
     parser.add_argument("src_or_dir")

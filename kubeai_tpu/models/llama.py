@@ -225,6 +225,34 @@ def kv_pool_dtype(config: ModelConfig):
 LORA_TARGETS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
 
 
+def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, paged: bool) -> str:
+    """The attention implementation apply() takes for a cached call of
+    *S* queries per row: "flash" (Pallas flash prefill), "paged_kernel"
+    (a Pallas paged kernel reading pages in place) or "xla" (the
+    portable gather + masked attention). One decision, shared with the
+    engine's step records so they name the route that actually ran."""
+    # Flash prefill: only when the caller vouches the positions are
+    # arange(S) (left_aligned — inferring it from shapes would silently
+    # mis-mask offset-position calls), on plain causal models with
+    # kernel-friendly shapes.
+    if (
+        config.use_flash_prefill
+        and left_aligned
+        and S >= 256
+        and S % 256 == 0
+        and config.attn_softcap == 0.0
+        and config.sliding_window == 0
+    ):
+        return "flash"
+    # Paged kernels handle 1..S queries per slot, so plain decode AND
+    # speculative verification read pages in place; per-layer sliding-
+    # window interleaves can't use one static kernel window, so
+    # Gemma2-style configs take the gather path.
+    if config.use_paged_kernel and paged and config.sliding_window == 0:
+        return "paged_kernel"
+    return "xla"
+
+
 def init_lora_bank(config: ModelConfig, n_adapters: int, rank: int, dtype=None) -> Params:
     """Zeroed stacked adapter bank for batched multi-LoRA (punica-style):
     per target, A [L, N, in, r] and B [L, N, r, out]. *n_adapters* is the
@@ -324,6 +352,10 @@ def apply(
     # O((S/sp)^2) scores per device — parallel/ring_attention.py). The
     # trainer's long-context path; requires positions == arange(S),
     # no sliding window, no softcap.
+    tp_mesh=None,  # Mesh whose `tp` axis shards heads: the flash and
+    # paged kernels run per shard under jax.shard_map (a Mosaic kernel
+    # cannot be partitioned by GSPMD), q and the pool split on their
+    # head axes as parallel/sharding.py's specs say.
 ):
     """Run the decoder. Returns (logits, new_cache).
 
@@ -367,34 +399,43 @@ def apply(
         lambda v: jax.nn.gelu(v, approximate=True)
     )
     norm_offset = 1.0 if config.rms_one_offset else 0.0
-    # Flash prefill: only when the caller vouches the positions are
-    # arange(S) (left_aligned — prefill/prefill_into set it; inferring it
-    # from shapes would silently mis-mask offset-position calls), on plain
-    # causal models with kernel-friendly shapes.
-    use_flash = (
-        config.use_flash_prefill
-        and left_aligned
-        and cache is not None
-        and S >= 256
-        and S % 256 == 0
-        and config.attn_softcap == 0.0
-        and config.sliding_window == 0
+    route = (
+        cached_attention_route(config, S, left_aligned, page_table is not None)
+        if cache is not None
+        else "xla"
     )
-    # Paged attention kernel (ragged: handles 1..S queries per slot, so
-    # plain decode AND speculative verification read pages in place);
-    # per-layer sliding-window interleaves can't use one static kernel
-    # window, so Gemma2-style configs fall back to the gather path.
-    use_paged_kernel = (
-        config.use_paged_kernel
-        and page_table is not None
-        and config.sliding_window == 0
-        and not use_flash
-    )
+    use_flash = route == "flash"
+    use_paged_kernel = route == "paged_kernel"
     use_dedicated_decode = False
     if use_paged_kernel:
         from kubeai_tpu.ops.paged_decode_attention import resolve_decode_kernel
 
         use_dedicated_decode = resolve_decode_kernel(decode_kernel, S) == "dedicated"
+
+    def per_tp_shard(fn, n_head_split, n_replicated=0):
+        """Run an attention kernel on each tp shard's heads: the first
+        *n_head_split* arguments ([.., .., heads, h]) and the output are
+        split on axis 2, the *n_replicated* after them (tables, lengths)
+        are whole on every shard."""
+        tp = tp_mesh.shape["tp"] if tp_mesh is not None else 1
+        if tp == 1:
+            return fn
+        if H % tp or Kv % tp:
+            raise ValueError(
+                f"the attention kernels shard whole KV heads over tp: "
+                f"num_heads={H} and num_kv_heads={Kv} must be multiples "
+                f"of tp={tp}"
+            )
+        from jax.sharding import PartitionSpec as P
+
+        heads = P(None, None, "tp", None)
+        return jax.shard_map(
+            fn, mesh=tp_mesh,
+            in_specs=(heads,) * n_head_split + (P(),) * n_replicated,
+            out_specs=heads,
+            # pallas_call outputs carry no varying-axes type.
+            check_vma=False,
+        )
 
     paged = page_table is not None
     kv_quant = False
@@ -529,14 +570,16 @@ def apply(
                     paged_attention_ragged as paged_attn_fn,
                 )
 
-            attn_out = paged_attn_fn(
-                q, kv_full, table_l,
-                kv_lengths=positions[:, -1] + 1,  # keys 0..last pos inclusive
-                scale=config.query_scale,
-                softcap=config.attn_softcap,
-                k_scale=kq_scale if kv_quant else None,
-                v_scale=vq_scale if kv_quant else None,
-            )
+            attn_out = per_tp_shard(
+                lambda q_, kv_, table_, lens_: paged_attn_fn(
+                    q_, kv_, table_, lens_,
+                    scale=config.query_scale,
+                    softcap=config.attn_softcap,
+                    k_scale=kq_scale if kv_quant else None,
+                    v_scale=vq_scale if kv_quant else None,
+                ),
+                n_head_split=2, n_replicated=2,
+            )(q, kv_full, table_l, positions[:, -1] + 1)  # keys 0..last pos inclusive
         elif use_flash:
             # Prefill positions are arange(S): the cache columns 0..S-1
             # were just written with exactly k/v, so plain causal over
@@ -544,10 +587,12 @@ def apply(
             # cache — no cache read needed.
             from kubeai_tpu.ops.flash_attention import flash_attention_tpu
 
-            attn_out = flash_attention_tpu(
-                q, k, v, causal=True, sm_scale=config.query_scale,
-                interpret=jax.default_backend() != "tpu",
-            )
+            attn_out = per_tp_shard(
+                lambda q_, k_, v_: flash_attention_tpu(
+                    q_, k_, v_, causal=True, sm_scale=config.query_scale,
+                ),
+                n_head_split=3,
+            )(q, k, v)
         elif ring_mesh is not None and cache is None:
             from kubeai_tpu.parallel.ring_attention import ring_attention
 
@@ -704,7 +749,7 @@ def decode_step(params, config, tokens, cache, lengths, lora=None, lora_rows=Non
 # -- paged-cache variants (engine serving path; see init_paged_cache) -------
 
 
-def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None):
+def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None, tp_mesh=None):
     """Prefill [B, S] left-aligned token chunks at absolute offset
     *start* [B] into the paged *pool* through *page_table* [B, max_pages].
     Handles both whole-prompt prefill (start=0) and chunked continuation
@@ -721,11 +766,11 @@ def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lor
         # Flash prefill's plain-causal fast path needs positions ==
         # arange(S), i.e. a cold start-0 prefill; chunked continuations
         # carry real offsets. Callers split on that statically.
-        left_aligned=False,
+        left_aligned=False, tp_mesh=tp_mesh,
     )
 
 
-def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None):
+def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None):
     """Whole-prompt paged prefill (positions arange(S)); eligible for the
     flash-attention fast path. Returns (logits [B, 1, V] at lengths-1,
     pool)."""
@@ -735,21 +780,21 @@ def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=N
         params, config, tokens, pos, pool,
         logits_idx=jnp.reshape(lengths, (-1,)).astype(jnp.int32) - 1,
         lora=lora, lora_rows=lora_rows,
-        page_table=page_table, left_aligned=True,
+        page_table=page_table, left_aligned=True, tp_mesh=tp_mesh,
     )
 
 
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, decode_kernel="ragged"):
+def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, decode_kernel="ragged", tp_mesh=None):
     """One paged decode step for [B, 1] tokens at positions *lengths* [B].
     Returns (logits [B, 1, V], pool)."""
     return apply(
         params, config, tokens, lengths[:, None].astype(jnp.int32), pool,
         lora=lora, lora_rows=lora_rows, page_table=page_table,
-        decode_kernel=decode_kernel,
+        decode_kernel=decode_kernel, tp_mesh=tp_mesh,
     )
 
 
-def decode_speculative_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, decode_kernel="ragged"):
+def decode_speculative_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, decode_kernel="ragged", tp_mesh=None):
     """Speculative paged decode: [B, S] candidate tokens (real next token
     + S-1 drafts) at positions lengths..lengths+S-1. Returns logits for
     ALL S positions ([B, S, V], for draft verification) and the pool.
@@ -762,5 +807,5 @@ def decode_speculative_paged(params, config, tokens, pool, page_table, lengths, 
     return apply(
         params, config, tokens, pos, pool,
         lora=lora, lora_rows=lora_rows, page_table=page_table,
-        decode_kernel=decode_kernel,
+        decode_kernel=decode_kernel, tp_mesh=tp_mesh,
     )

@@ -75,6 +75,10 @@ class DeviceEnv:
     kind: str = ""
     peak_flops: float | None = None
     hbm_gbps: float | None = None
+    # As jax reports them for this process (detect_device); empty/0 when
+    # the constants were looked up by kind alone.
+    platform: str = ""
+    visible_devices: int = 0
 
 
 def device_constants(device_kind: str) -> DeviceEnv:
@@ -94,15 +98,18 @@ def device_constants(device_kind: str) -> DeviceEnv:
 
 
 def detect_device() -> DeviceEnv:
-    """DeviceEnv for the current process's first local device (lazy jax
-    import; never raises — an unprobeable backend is just 'unknown')."""
-    try:
-        import jax
+    """DeviceEnv for the current process's first local device, with the
+    platform and device count jax reports (lazy jax import)."""
+    import dataclasses
 
-        kind = getattr(jax.local_devices()[0], "device_kind", "")
-    except Exception:  # pragma: no cover - backend init failure
-        kind = ""
-    return device_constants(kind)
+    import jax
+
+    dev = jax.local_devices()[0]
+    return dataclasses.replace(
+        device_constants(dev.device_kind),
+        platform=dev.platform,
+        visible_devices=len(jax.devices()),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -278,9 +285,7 @@ STALL_CAUSES = ("dispatch", "host_overlap", "fetch_wait", "emit", "prefill", "kv
 _INTERPRET = {
     "fetch_wait": (
         "host blocked in device_get — host-bound on the device round-trip: "
-        "device compute + result transfer outlast the overlapped host work "
-        "(on a remote-attached TPU this is usually the transfer/dispatch "
-        "round-trip, not kernel time)"
+        "device compute + result transfer outlast the overlapped host work"
     ),
     "host_overlap": (
         "host-bound between dispatch and fetch: admissions/aux/emission "

@@ -59,10 +59,13 @@ def paged_attention_ragged(
         fn = ragged_paged_attention
         # The kernel's default scoped-VMEM budget (16MB) under-provisions
         # large-head configs: an 8B-class (H=32, Kv=8, h=128) prefill
-        # needs ~16.4MB of kernel stack and dies in compile ("Ran out of
-        # memory in memory space vmem"). v5e/v5p have 128MB VMEM; 64MB
-        # leaves XLA plenty for the surrounding fusion.
-        tuning["vmem_limit_bytes"] = 64 * 1024 * 1024
+        # needs ~16.4MB of kernel stack, and at --max-seq-len 8192 (128
+        # pages a sequence, the library's untuned 128-page KV block) its
+        # double buffer alone takes 65.5MB: both die in compile ("Ran
+        # out of memory in memory space vmem") under a smaller limit.
+        # v5e/v5p have 128MB VMEM; 96MB leaves XLA its own 16MB scope
+        # for the surrounding fusion (tests/test_tpu_compile.py).
+        tuning["vmem_limit_bytes"] = 96 * 1024 * 1024
         # Optional grid-tuning override ("kv_pages,queries" per block):
         # the library's tuned table targets vLLM-style shapes; decode at
         # S=1 per slot is grid-underutilized, and this knob lets bench

@@ -30,12 +30,25 @@ def quantize(w, contract_axis: int = -2) -> dict[str, Any]:
     numpy inputs are quantized ON HOST with numpy outputs: the checkpoint
     loader quantizes before any device transfer, so an 8B model never
     materializes at full precision in HBM."""
-    xp = np if isinstance(w, np.ndarray) else jnp
-    w32 = xp.asarray(w).astype(xp.float32)
-    amax = xp.max(xp.abs(w32), axis=contract_axis, keepdims=True)
-    scale = xp.maximum(amax / 127.0, 1e-12)
-    q = xp.clip(xp.round(w32 / scale), -127, 127).astype(xp.int8)
-    return {QKEY: q, SKEY: scale.astype(xp.float32)}
+    if isinstance(w, np.ndarray):
+        # The same arithmetic as below, in place on ONE float32 copy: the
+        # loader runs this on several 68M-element layers at once, and
+        # five full-size temporaries each is what filled a 40 GiB host.
+        w32 = w.astype(np.float32)
+        amax = np.maximum(
+            w32.max(axis=contract_axis, keepdims=True),
+            -w32.min(axis=contract_axis, keepdims=True),
+        )
+        scale = np.maximum(amax / np.float32(127.0), np.float32(1e-12))
+        np.divide(w32, scale, out=w32)
+        np.round(w32, out=w32)
+        np.clip(w32, -127, 127, out=w32)
+        return {QKEY: w32.astype(np.int8), SKEY: scale}
+    w32 = jnp.asarray(w).astype(jnp.float32)
+    amax = jnp.max(jnp.abs(w32), axis=contract_axis, keepdims=True)
+    scale = jnp.maximum(amax / 127.0, 1e-12)
+    q = jnp.clip(jnp.round(w32 / scale), -127, 127).astype(jnp.int8)
+    return {QKEY: q, SKEY: scale.astype(jnp.float32)}
 
 
 def quantize_rows(w) -> dict[str, Any]:

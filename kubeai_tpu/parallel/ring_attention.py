@@ -19,25 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# API-drift compat: jax >= 0.5 exposes shard_map at the top level and
-# requires jax.lax.pvary to align scan carry types under the varying-
-# axes type system; 0.4.x ships shard_map under jax.experimental and
-# has no pvary (carries need no axis annotation there — identity).
-_shard_map = getattr(jax, "shard_map", None)
-_shard_map_kw: dict = {}
-if _shard_map is None:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # 0.4.x's replication checker mis-types the scan carry (the carry
-    # becomes axis-varying via the my_idx-dependent mask); jax's own
-    # error message prescribes check_rep=False. Numerics are pinned by
-    # the equality tests against dense attention, not by the checker.
-    _shard_map_kw = {"check_rep": False}
-_pvary = getattr(jax.lax, "pvary", None)
-if _pvary is None:  # jax 0.4.x: no varying-axes types to align
-    def _pvary(x, axes):
-        return x
-
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -82,7 +63,7 @@ def _ring_body(my_idx, n, block_len, q, k0, v0, scale, vary_axes=("sp",)):
     # The carry becomes device-varying inside the loop (my_idx-dependent
     # masks, and q/k vary over every sharded mesh axis); mark the initial
     # values over the same axes so scan's carry types line up.
-    o, l, m = (_pvary(t, vary_axes) for t in (o, l, m))
+    o, l, m = (jax.lax.pvary(t, vary_axes) for t in (o, l, m))
 
     def step(carry, i):
         o, l, m, k_cur, v_cur = carry
@@ -127,11 +108,10 @@ def ring_attention(q, k, v, mesh: Mesh, scale: float | None = None):
     spec = P(dp_ax, "sp", tp_ax, None)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_shard_map_kw,
     )
     def sharded(q_blk, k_blk, v_blk):
         my_idx = jax.lax.axis_index("sp")

@@ -1,11 +1,14 @@
 """Native extension loader: builds native/fasthash.cc with g++ on first
-use (cached under build/) and binds it via ctypes. Every native entry
-point has a pure-Python fallback, so absence of a toolchain degrades
-performance, never correctness."""
+use and binds it via ctypes. The library under build/ is named by the
+source's content hash, so what is loaded was built from the source that
+is there (file times mean nothing in a copied tree, and build/ is not
+in git). Every native entry point has a pure-Python fallback, so absence
+of a toolchain degrades performance, never correctness."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -47,15 +50,21 @@ def load() -> ctypes.CDLL | None:
         if src is None:
             return None
         build_dir = os.environ.get("KUBEAI_BUILD_DIR") or os.path.join(root, "build")
-        so_path = os.path.join(build_dir, "libfasthash.so")
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        so_path = os.path.join(build_dir, f"libfasthash-{digest}.so")
         try:
-            if not os.path.exists(so_path) or os.path.getmtime(so_path) < os.path.getmtime(src):
+            if not os.path.exists(so_path):
                 os.makedirs(build_dir, exist_ok=True)
+                # Build beside the target and rename: a concurrent
+                # process never loads a half-written library.
+                tmp_path = f"{so_path}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-o", so_path, src],
+                    ["g++", "-O3", "-shared", "-fPIC", "-o", tmp_path, src],
                     check=True,
                     capture_output=True,
                 )
+                os.replace(tmp_path, so_path)
             lib = ctypes.CDLL(so_path)
             lib.xxh64.restype = ctypes.c_uint64
             lib.xxh64.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64]

@@ -18,43 +18,6 @@ from kubeai_tpu.models import llama
 from kubeai_tpu.models.base import ModelConfig
 
 
-def test_probe_retry_backs_off_before_cpu_fallback(monkeypatch):
-    """VERDICT r5 weak #1: one wedged accelerator init must not send the
-    whole bench to the CPU-fallback headline — the probe retries with
-    growing backoff while the deadline allows."""
-    import time as _time
-    import types
-
-    import bench
-
-    attempts = []
-    sleeps = []
-    monkeypatch.setattr(
-        bench, "probe_device",
-        lambda timeout, platform=None: (
-            attempts.append(timeout), [None, None, "tpu"][len(attempts) - 1]
-        )[1],
-    )
-    monkeypatch.setattr(bench.time, "sleep", lambda s: sleeps.append(s))
-    args = types.SimpleNamespace(probe_timeout=10, probe_retries=3, probe_backoff=5.0)
-    got = bench.probe_device_with_retry(args, deadline=_time.monotonic() + 3600)
-    assert got == "tpu"
-    assert len(attempts) == 3
-    assert sleeps == [5.0, 10.0]  # backoff doubles between attempts
-
-    # Exhausted retries -> None (the orchestrator then takes the clearly
-    # labeled CPU fallback, unchanged).
-    attempts.clear()
-    sleeps.clear()
-    monkeypatch.setattr(bench, "probe_device", lambda timeout, platform=None: None)
-    assert bench.probe_device_with_retry(args, deadline=_time.monotonic() + 3600) is None
-
-    # A nearly-spent deadline stops retrying instead of sleeping past it.
-    sleeps.clear()
-    assert bench.probe_device_with_retry(args, deadline=_time.monotonic() + 60) is None
-    assert sleeps == []
-
-
 def test_worker_emits_headline_before_teardown_failure(monkeypatch, capsys):
     """ADVICE r5 regression: the measured headline must be emitted
     BEFORE engine teardown, so a hung/raising stop() can't forfeit an
@@ -93,6 +56,10 @@ def test_worker_emits_headline_before_teardown_failure(monkeypatch, capsys):
     ][-1]
     assert line["metric"] == "engine_output_tokens_per_sec_per_chip"
     assert line["value"] > 0  # the measurement survived the teardown failure
+    # Every result names the device it ran on: the tiny preset is the
+    # labelled CPU smoke, never a chip figure.
+    assert line["preset"] == "tiny"
+    assert line["device"]["platform"] == "cpu"
 
 
 def test_synth_tree_matches_quantized_loader():

@@ -102,23 +102,42 @@ def test_timeline_installs_into_debug_engine():
 # Compile-cache helper
 
 
-def test_setup_compile_cache_env_and_explicit(tmp_path, monkeypatch):
-    prior = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.delenv("KUBEAI_COMPILE_CACHE", raising=False)
-        assert setup_compile_cache() is None  # no env, no arg: no-op
+@pytest.fixture
+def compile_cache_at(monkeypatch):
+    """Place the persistent compile cache where the test says, through
+    the one writer, and switch it off again afterwards (in-process
+    engines of later tests never asked for one)."""
+    from jax.experimental.compilation_cache import compilation_cache
 
-        d1 = str(tmp_path / "cache1")
-        assert setup_compile_cache(d1) == d1
-        assert os.path.isdir(d1)
-        assert jax.config.jax_compilation_cache_dir == d1
+    def place(path: str) -> str:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+        return setup_compile_cache()
 
-        d2 = str(tmp_path / "cache2")
-        monkeypatch.setenv("KUBEAI_COMPILE_CACHE", d2)
-        assert setup_compile_cache() == d2
-        assert jax.config.jax_compilation_cache_dir == d2
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior)
+    yield place
+    compilation_cache.set_cache_dir(None)
+    compilation_cache.reset_cache()
+
+
+def test_setup_compile_cache_env_wins_over_checkout_default(tmp_path, monkeypatch, compile_cache_at):
+    from kubeai_tpu.engine import coldstart
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert coldstart.default_compile_cache_dir() == os.path.join(
+        repo, ".jax_compile_cache"
+    )
+    # No env: the checkout's fixed, git-ignored directory (steered to a
+    # tmp dir here so the test leaves the real one alone).
+    default = str(tmp_path / "checkout" / ".jax_compile_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(coldstart, "default_compile_cache_dir", lambda: default)
+    assert setup_compile_cache() == default
+    assert os.path.isdir(default)
+    assert jax.config.jax_compilation_cache_dir == default
+
+    # Placed from outside: the jax variable wins.
+    d2 = str(tmp_path / "cache2")
+    assert compile_cache_at(d2) == d2
+    assert jax.config.jax_compilation_cache_dir == d2
 
 
 # ---------------------------------------------------------------------------
@@ -190,20 +209,15 @@ def _load_tp2(ckpt_dir, stream):
 # AOT warm compile + the overlap smoke.
 
 
-def test_warm_compile_populates_persistent_cache(ckpt_dir, tmp_path):
+def test_warm_compile_populates_persistent_cache(ckpt_dir, tmp_path, compile_cache_at):
     from kubeai_tpu.engine.coldstart import warm_from_checkpoint
 
-    prior = jax.config.jax_compilation_cache_dir
-    cache = str(tmp_path / "xla-cache")
-    try:
-        setup_compile_cache(cache)
-        stats = warm_from_checkpoint(
-            ckpt_dir,
-            ["--max-slots", "2", "--max-seq-len", "64"],
-            include_group=False,
-        )
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior)
+    cache = compile_cache_at(str(tmp_path / "xla-cache"))
+    stats = warm_from_checkpoint(
+        ckpt_dir,
+        ["--max-slots", "2", "--max-seq-len", "64"],
+        include_group=False,
+    )
     assert stats["shapes"] > 0
     assert not stats.get("errors")
     entries = [f for f in os.listdir(cache) if f.endswith("-cache")]

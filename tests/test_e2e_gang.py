@@ -27,18 +27,6 @@ from tests.test_e2e_local import ckpt_dir  # noqa: F401 (fixture reuse)
 pytestmark = pytest.mark.e2e
 
 
-def _cpu_backend_supports_multiprocess() -> bool:
-    """jax 0.4.x's CPU backend cannot execute multiprocess (global-mesh)
-    computations at all — every gang pod dies at engine build with
-    'Multiprocess computations aren't implemented on the CPU backend'.
-    Gate the 2-process slice e2e on that capability instead of burning
-    minutes of crash-loop to a guaranteed failure."""
-    import jax
-
-    major, minor, *_ = (int(x) for x in jax.__version__.split(".")[:2])
-    return (major, minor) >= (0, 5)
-
-
 @pytest.fixture(scope="module")
 def manager():
     system = System().default_and_validate()
@@ -120,11 +108,6 @@ def test_gang_round_trips_completion_in_process():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not _cpu_backend_supports_multiprocess(),
-    reason="jax 0.4 CPU backend cannot execute multiprocess computations "
-           "(the 2-process slice gang crash-loops at engine build)",
-)
 def test_gang_round_trips_completion(manager, ckpt_dir):  # noqa: F811
     mgr = manager
     mgr.store.create(

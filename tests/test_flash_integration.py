@@ -1,18 +1,32 @@
 """Flash-prefill glue in llama.apply exercised on CPU (interpret mode):
 the full model with use_flash_prefill must match the masked XLA path."""
 
+import functools
+
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
 
 from kubeai_tpu.models import llama
 from kubeai_tpu.models.base import ModelConfig
+from kubeai_tpu.ops import flash_attention as flash_ops
 
 CFG = ModelConfig(
     vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
     num_heads=4, num_kv_heads=2, dtype="float32", max_position=1024,
 )
+
+
+@pytest.fixture(autouse=True)
+def interpret_flash_kernel(monkeypatch):
+    """The serving path always compiles the kernel for the chip; on the
+    CPU the test steers it into Pallas interpret mode."""
+    monkeypatch.setattr(
+        flash_ops, "flash_attention_tpu",
+        functools.partial(flash_ops.flash_attention_tpu, interpret=True),
+    )
 
 
 def test_flash_prefill_matches_masked_path():

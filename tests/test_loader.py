@@ -120,7 +120,7 @@ def test_cli_warm_passes_engine_args_through(tmp_path, monkeypatch):
 
 
 def test_warm_compile_cache_requires_cache_env(tmp_path, monkeypatch, caplog):
-    monkeypatch.delenv("KUBEAI_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     with caplog.at_level("INFO", logger="kubeai_tpu.loader"):
         assert loader.warm_compile_cache(str(tmp_path)) is None
     assert any("skipping compile warm" in m for m in caplog.messages)

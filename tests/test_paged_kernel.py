@@ -106,7 +106,7 @@ def test_tpu_dispatch_arm_builds_identical_call(monkeypatch):
     np.testing.assert_array_equal(np.asarray(recorded["n"]), [B])
     # The raised scoped-VMEM budget must reach the kernel (8B-class heads
     # exceed the 16MB default during prefill).
-    assert recorded["vmem"] == 64 * 1024 * 1024
+    assert recorded["vmem"] == 96 * 1024 * 1024
     assert recorded["scale"] == pytest.approx(h**-0.5)
     assert recorded["cap"] == 25.0
 

@@ -287,7 +287,7 @@ def test_parked_attach_serves_completion(ckpt_dir, tmp_path_factory):
     system.autoscaling.interval_seconds = 0.5
     mgr = Manager(system, local_runtime=True, host="127.0.0.1", port=0)
     mgr.local_runtime.extra_env["JAX_PLATFORMS"] = "cpu"
-    mgr.local_runtime.extra_env["KUBEAI_COMPILE_CACHE"] = str(
+    mgr.local_runtime.extra_env["JAX_COMPILATION_CACHE_DIR"] = str(
         tmp_path_factory.mktemp("xla-cache")
     )
     mgr.start()

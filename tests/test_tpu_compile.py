@@ -1,0 +1,240 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e at
+real widths: nothing runs and no chip is needed, but what the chip's
+compiler would refuse (a block shape off the tiling, too much scoped
+VMEM, a kernel GSPMD cannot partition) is refused here, at no chip time
+(on-chip-measurement guide, section 2). Interpret-mode tests cannot see
+any of these. A compile that passes is not a chip run.
+
+The program asks jax for its backend and, inside the library kernel,
+for the device kind; both still see the CPU here, so the test steers
+them (never an option of the program).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from kubeai_tpu.models import llama
+from kubeai_tpu.models.base import ModelConfig
+from kubeai_tpu.ops.flash_attention import flash_attention_tpu
+from kubeai_tpu.ops.paged_attention import paged_attention_ragged
+from kubeai_tpu.ops.paged_decode_attention import paged_decode_attention
+
+# (num_heads, num_kv_heads) at head_dim 128.
+QWEN25_7B = (28, 4)
+LLAMA3_8B = (32, 8)  # Mistral-7B has the same grouping
+GEMMA_2B = (8, 1)
+# What one of four tp shards sees of the two 7B/8B models.
+QWEN25_7B_TP4 = (7, 1)
+LLAMA3_8B_TP4 = (8, 2)
+H_DIM, PAGE, SLOTS = 128, 64, 32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e 2x2."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def for_the_chip(monkeypatch):
+    """Take the TPU branch of the kernel dispatchers, resolve the library
+    kernel's tuned block sizes as on a v5e, trace at the serving
+    processes' matmul precision (conftest's "highest" is for CPU
+    numerics; Mosaic refuses an f32 x bf16 matmul at fp32 precision),
+    and keep these compiles out of the persistent cache (a compile for a
+    described device is written there but cannot be read back without a
+    chip)."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
+        tuned_block_sizes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(tuned_block_sizes, "get_tpu_version", lambda: 5)
+    monkeypatch.setattr(
+        tuned_block_sizes, "get_device_name", lambda num_devices=None: "TPU v5"
+    )
+    precision = jax.config.jax_default_matmul_precision
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_default_matmul_precision", None)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_default_matmul_precision", precision)
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described device; the text of the
+    compiled program."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _paged_args(device, B, S, heads, max_len=2048, pool_dtype=jnp.bfloat16):
+    H, Kv = heads
+    max_pages = max_len // PAGE
+    one = SingleDeviceSharding(device)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return (
+        sds((B, S, H, H_DIM), jnp.bfloat16),
+        sds((SLOTS * max_pages + 1, PAGE, 2 * Kv, H_DIM), pool_dtype),
+        sds((B, max_pages), jnp.int32),
+        sds((B,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "heads",
+    [QWEN25_7B, LLAMA3_8B, QWEN25_7B_TP4, LLAMA3_8B_TP4],
+    ids=["qwen2.5-7b", "llama3-8b", "qwen2.5-7b/tp4", "llama3-8b/tp4"],
+)
+def test_flash_prefill_lowers(v5e, heads):
+    H, Kv = heads
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 1024, H, H_DIM), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 1024, Kv, H_DIM), jnp.bfloat16, sharding=one)
+    text = _compile(lambda q, k, v: flash_attention_tpu(q, k, v, causal=True), q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "heads,B,S,pool_dtype",
+    [
+        (QWEN25_7B, SLOTS, 1, jnp.bfloat16),
+        (QWEN25_7B, 8, 512, jnp.bfloat16),
+        (QWEN25_7B, SLOTS, 1, jnp.float8_e4m3fn),
+        (LLAMA3_8B, SLOTS, 1, jnp.bfloat16),
+        (LLAMA3_8B, 8, 512, jnp.bfloat16),
+        (QWEN25_7B_TP4, SLOTS, 1, jnp.bfloat16),
+        (QWEN25_7B_TP4, 8, 512, jnp.bfloat16),
+        (LLAMA3_8B_TP4, SLOTS, 1, jnp.bfloat16),
+        (GEMMA_2B, SLOTS, 1, jnp.bfloat16),
+    ],
+    ids=[
+        "qwen2.5-7b/decode", "qwen2.5-7b/prefill-8x512", "qwen2.5-7b/decode-fp8-pool",
+        "llama3-8b/decode", "llama3-8b/prefill-8x512",
+        "qwen2.5-7b/tp4/decode", "qwen2.5-7b/tp4/prefill-8x512",
+        "llama3-8b/tp4/decode", "gemma-2b/decode",
+    ],
+)
+def test_ragged_paged_kernel_lowers(v5e, heads, B, S, pool_dtype):
+    quant = {} if pool_dtype == jnp.bfloat16 else {"k_scale": 1.0, "v_scale": 1.0}
+    text = _compile(
+        lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, **quant),
+        *_paged_args(v5e[0], B, S, heads, pool_dtype=pool_dtype),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.slow  # 12-15 s: the library's untuned 128-page KV block unrolls
+def test_ragged_paged_kernel_fits_vmem_at_8192(v5e):
+    """deploy/models/llama-3.1-8b-instruct-tpu.yaml serves at
+    --max-seq-len 8192: the kernel's double buffer takes 65.5 MB of
+    scoped VMEM there, over the 64 MB this wrapper used to allow."""
+    text = _compile(
+        lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens),
+        *_paged_args(v5e[0], SLOTS, 1, LLAMA3_8B, max_len=8192),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="library kernel (jax 0.9.0): 'Not implemented: "
+           "num_combined_kv_heads=2 can not be XLA fully tiled.' — an 8-bit "
+           "pool packs 4 heads a sublane, so one KV head (gemma-2b, or a "
+           "Kv=4 model at tp=4) cannot take --kv-cache-dtype fp8|int8 on the "
+           "kernel route (ROADMAP A5)",
+)
+def test_ragged_paged_kernel_quantized_pool_single_kv_head(v5e):
+    _compile(
+        lambda q, kv, tbl, lens: paged_attention_ragged(
+            q, kv, tbl, lens, k_scale=1.0, v_scale=1.0
+        ),
+        *_paged_args(v5e[0], SLOTS, 1, GEMMA_2B, pool_dtype=jnp.float8_e4m3fn),
+    )
+
+
+def _dedicated(v5e, heads):
+    return _compile(
+        lambda q, kv, tbl, lens: paged_decode_attention(
+            q, kv, tbl, lens, interpret=False
+        ),
+        *_paged_args(v5e[0], SLOTS, 1, heads),
+    )
+
+
+def test_dedicated_decode_kernel_lowers_single_kv_head(v5e):
+    assert "tpu_custom_call" in _dedicated(v5e, GEMMA_2B)
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="'The Pallas TPU lowering currently requires that the last two "
+           "dimensions of your block shape are divisible by 8 and 128 "
+           "respectively, or be equal to the respective dimensions of the "
+           "overall array': q is blocked (1,S,G,h) on the H axis and pages "
+           "(1,page,2,h) on the 2*Kv axis, so --decode-kernel dedicated|auto "
+           "lowers only at Kv=1 (ROADMAP A5/C5)",
+)
+@pytest.mark.parametrize("heads", [QWEN25_7B, LLAMA3_8B], ids=["G=7", "G=4"])
+def test_dedicated_decode_kernel_lowers_grouped_heads(v5e, heads):
+    _dedicated(v5e, heads)
+
+
+def test_tp4_decode_step_keeps_the_kernel(v5e):
+    """Tensor parallelism: GSPMD cannot partition a Mosaic kernel, so
+    llama.apply runs it per tp shard under shard_map. One decode layer
+    of Qwen2.5-7B on the 2x2 mesh must compile, with the kernel in it
+    and the pool still split four ways on its head axis."""
+    mesh = Mesh(np.array(v5e).reshape(1, 1, 1, 4), ("dp", "sp", "ep", "tp"))
+    from kubeai_tpu.engine.coldstart import param_shapes
+    from kubeai_tpu.parallel.sharding import llama_param_specs, paged_cache_specs
+
+    H, Kv = QWEN25_7B
+    mc = ModelConfig(
+        vocab_size=1024, hidden_size=H * H_DIM, intermediate_size=2048,
+        num_layers=1, num_heads=H, num_kv_heads=Kv, qkv_bias=True,
+        dtype="bfloat16", use_flash_prefill=True, use_paged_kernel=True,
+    )
+
+    def on_mesh(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    params = jax.tree_util.tree_map(
+        lambda x, s: on_mesh(x.shape, x.dtype, s),
+        param_shapes(mc), llama_param_specs(mc),
+    )
+    B, max_pages = 8, 2048 // PAGE
+    pool_spec = paged_cache_specs()["kv"]
+    pool = {"kv": on_mesh((B * max_pages + 1, PAGE, 2 * Kv, H_DIM), jnp.bfloat16, pool_spec)}
+    compiled = jax.jit(
+        lambda p, t, c, tbl, lens: llama.decode_step_paged(
+            p, mc, t, c, tbl, lens, tp_mesh=mesh
+        ),
+        out_shardings=(NamedSharding(mesh, P()), {"kv": NamedSharding(mesh, pool_spec)}),
+    ).lower(
+        params, on_mesh((B, 1), jnp.int32), pool,
+        on_mesh((B, max_pages), jnp.int32), on_mesh((B,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # A quarter of the pool on each chip, not the whole of it.
+    pool_bytes = np.prod(pool["kv"].shape) * 2
+    assert compiled.memory_analysis().output_size_in_bytes < pool_bytes / 2
