@@ -1,0 +1,181 @@
+#!/usr/bin/env python3
+"""Checks of the yardstick itself, on the CPU, in seconds:
+
+    python3 perfbench/selftest.py
+
+ * the trace reduction on a hand-made event list (overlapping lines,
+   nested and overlapping events, events crossing the window's edge, an
+   empty device plane is an error and not 0);
+ * the same on one small recorded trace (testdata/cpu-small.xplane.pb, a
+   CPU engine's own /debug/profile: the rehearsal's platform);
+ * the traffic generator: every seed gives the same multiset of sizes and
+   the same arrival span, in another order, and takes seeds past 2**31;
+ * resultline.py refuses each way a last line went wrong before;
+ * BENCHMARK.json: every name resolves to a file, every metric's `moves`
+   is reported where the metric is.
+
+Not part of tests/ (this PR may add files only under perfbench/).
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import resultline  # noqa: E402
+import trace_reduce as tr  # noqa: E402
+import traffic  # noqa: E402
+
+FAILED: list[str] = []
+
+
+def check(name: str, ok: bool, detail="") -> None:
+    print(("ok   " if ok else "FAIL ") + name + (f": {detail}" if not ok else ""))
+    if not ok:
+        FAILED.append(name)
+
+
+def raises(fn, exc) -> bool:
+    try:
+        fn()
+    except exc:
+        return True
+    return False
+
+
+def hand_made() -> None:
+    # A window of [100, 1100). One device, its ops line:
+    ops = [
+        ("a", 0, 150),       # crosses the left edge: 50 inside
+        ("b", 200, 100),     # [200, 300)
+        ("b", 250, 100),     # overlaps the one before: union [200, 350)
+        ("k", 260, 20),      # nested
+        ("c", 1000, 500),    # crosses the right edge: 100 inside
+        ("d", 5000, 10),     # outside
+    ]
+    modules = [("m1", 190, 170), ("m2", 990, 600)]  # m1 wholly inside, m2 cut
+    busy, merged = tr.union_ns(((s, s + d) for _, s, d in ops), 100, 1100)
+    check("union clips and merges", busy == 50 + 150 + 100, busy)
+    check("merged pieces", merged == [(100, 150), (200, 350), (1000, 1100)], merged)
+    gaps = tr.gaps_ns(merged, 100, 1100)
+    check("gaps", gaps == [(150, 200), (350, 1000)], gaps)
+    out = tr.reduce_events([{"name": "/device:TPU:0", "ops": ops, "modules": modules}], (100, 1100))
+    check("busy_s <= window_s", 0 < out["busy_s"] <= out["window_s"], out)
+    check("busy is a union, not a sum", abs(out["busy_s"] - 300e-9) < 1e-15, out["busy_s"])
+    check("sum of durations would exceed", sum(d for _, _, d in ops) > 300)
+    check("whole runs only", out["modules_s"]["m1"] == (170e-9, 1, 170e-9, 1) and out["modules_s"]["m2"][3] == 0, out["modules_s"])
+    check("ops inside whole runs", out["ops_in_modules_s"] == {"m1": {"b": (200e-9, 2), "k": (20e-9, 1)}}, out["ops_in_modules_s"])
+    check("longest gap first", out["gaps_s"][0] == (250e-9, 650e-9), out["gaps_s"])
+    # Two lines that cover the same time (ops and modules): feeding both as
+    # "ops" is what gave busy > window once; the reader takes ONE line.
+    two = tr.reduce_events(
+        [{"name": "d0", "ops": ops, "modules": []}, {"name": "d1", "ops": [("x", 100, 1000)], "modules": []}],
+        (100, 1100),
+    )
+    check("mean over devices", abs(two["busy_s"] - (300e-9 + 1000e-9) / 2) < 1e-15, two["busy_s"])
+    check("empty device plane is an error", raises(
+        lambda: tr.reduce_events([{"name": "d0", "ops": [], "modules": []}], (100, 1100)), tr.TraceError))
+    check("ops outside the window is an error", raises(
+        lambda: tr.reduce_events([{"name": "d0", "ops": [("d", 5000, 10)], "modules": []}], (100, 1100)), tr.TraceError))
+    check("no plane is an error", raises(lambda: tr.reduce_events([], (0, 1)), tr.TraceError))
+
+
+def recorded() -> None:
+    path = os.path.join(HERE, "testdata", "cpu-small.xplane.pb")
+    if not os.path.exists(path):
+        check("recorded trace present", False, path)
+        return
+    with open(os.path.join(HERE, "trace.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(HERE, "testdata", "cpu-small.expected.json")) as f:
+        want = json.load(f)
+    planes, window, notes = tr.read_xplane(path, {**spec["cpu"], "profile_seconds": want["profile_seconds"]})
+    out = tr.reduce_events(planes, window)
+    check("recorded: window from the profiler's sleep", notes["window_from"].startswith("host event"), notes["window_from"])
+    check("recorded: window_s", abs(out["window_s"] - want["window_s"]) < 1e-6, out["window_s"])
+    check("recorded: busy_s", abs(out["busy_s"] - want["busy_s"]) < 1e-6, out["busy_s"])
+    check("recorded: 0 < busy <= window", 0 < out["busy_s"] <= out["window_s"])
+    check("recorded: programs found", set(want["modules"]) <= set(out["modules_s"]), sorted(out["modules_s"]))
+    check("recorded: wrong plane is an error", raises(
+        lambda: tr.read_xplane(path, {**spec["tpu"], "profile_seconds": None}), tr.TraceError))
+
+
+def generator() -> None:
+    for name in ("chat-sat", "chat-rate", "docqa"):
+        spec = traffic.load(name)
+        plans = [traffic.build(spec, seed, 40) for seed in (1, 2**31 + 11)]
+
+        def sizes(p):
+            reqs = p.shared or [r for c in p.per_client for r in c]
+            return sorted((r.prompt_tokens, r.max_tokens) for r in reqs)
+
+        a, b = (sizes(p) for p in plans)
+        check(f"{name}: same sizes for every seed", a == b)
+        first = lambda p: [r.prompt for r in (p.shared or p.per_client[0])[:3]]  # noqa: E731
+        check(f"{name}: other bytes for another seed", first(plans[0]) != first(plans[1]))
+        check(f"{name}: same seed, same plan", first(plans[0]) == first(traffic.build(spec, 1, 40)))
+        cap = spec["max_total_tokens"]
+        check(f"{name}: fits the engine", all(p + o <= cap for p, o in a))
+        if plans[0].loop == "open":
+            ends = [p.shared[-1].due_s for p in plans]
+            check(f"{name}: same arrival span", abs(ends[0] - ends[1]) < 1e-6, ends)
+
+
+def last_line() -> None:
+    bench = resultline.load_benchmark()
+    cell = bench["workloads"][0]["name"]
+    e2e = resultline.declared(bench, cell, False)
+    layer = resultline.declared(bench, cell, True)
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1.2e10}
+    good0 = {"correct": True, "attempted": 10, "failed": 0, "device": dev,
+             "metrics": {n: {"value": 1.5, "unit": u} for n, u in e2e.items()}}
+    good1 = {"correct": True, "attempted": 10, "failed": 0, "device": {**dev, "window_s": 4.0, "busy_s": 3.0},
+             "metrics": {n: {"value": 1.5, "unit": u} for n, u in layer.items()}}
+    bad = lambda obj, trace: bool(resultline.problems(obj, bench, cell, trace, 1))  # noqa: E731
+    check("good untraced line", not bad(good0, False), resultline.problems(good0, bench, cell, False, 1))
+    check("good traced line", not bad(good1, True), resultline.problems(good1, bench, cell, True, 1))
+    name = next(iter(layer))
+    check("busy over window refused", bad({**good1, "device": {**good1["device"], "busy_s": 4.5}}, True))
+    check("busy 0 refused", bad({**good1, "device": {**good1["device"], "busy_s": 0}}, True))
+    check("traced line without window_s refused", bad({**good1, "device": dev}, True))
+    check("NaN refused", bad({**good1, "metrics": {**good1["metrics"], name: {"value": float("nan"), "unit": layer[name]}}}, True))
+    check("null refused", bad({**good1, "metrics": {**good1["metrics"], name: {"value": None, "unit": layer[name]}}}, True))
+    check("wrong unit refused", bad({**good1, "metrics": {**good1["metrics"], name: {"value": 1, "unit": "parsecs"}}}, True))
+    check("end-to-end metric in a traced line refused", bad({**good1, "metrics": {**good1["metrics"], **good0["metrics"]}}, True))
+    check("missing metric refused", bad({**good0, "metrics": {}}, False))
+    check("cpu refused", bad({**good0, "device": {**dev, "platform": "cpu"}}, False))
+    check("wrong count refused", bad({**good0, "device": {**dev, "count": 4}}, False))
+    check("render refuses NaN", raises(lambda: resultline.render({"x": float("nan")}), ValueError))
+
+
+def benchmark_file() -> None:
+    bench = resultline.load_benchmark()
+    root = resultline.ROOT
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    cells = [w["name"] for w in bench["workloads"]]
+    for c in bench["configs"]:
+        check(f"config file {c['name']}", os.path.exists(os.path.join(root, c["file"])))
+    for w in bench["workloads"]:
+        check(f"traffic file {w['traffic']}", os.path.exists(os.path.join(HERE, "traffic", w["traffic"] + ".json")))
+        check(f"{w['name']}: an end-to-end metric besides setup_s", len(resultline.declared(bench, w["name"], False)) >= 2)
+        check(f"{w['name']}: a per-layer metric", len(resultline.declared(bench, w["name"], True)) >= 1)
+    for m in bench["per_layer"]:
+        check(f"metric file {m['name']}", os.path.exists(os.path.join(HERE, "layer_metrics", m["name"] + ".json")))
+        moved = e2e[m["moves"]]
+        where = m.get("workloads", cells)
+        check(f"{m['name']} moves {m['moves']} where it is reported",
+              all(c in moved.get("workloads", cells) for c in where))
+
+
+if __name__ == "__main__":
+    hand_made()
+    recorded()
+    generator()
+    last_line()
+    benchmark_file()
+    print(f"{len(FAILED)} failed" if FAILED else "all passed")
+    sys.exit(1 if FAILED else 0)
