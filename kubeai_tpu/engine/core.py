@@ -74,6 +74,22 @@ from kubeai_tpu.qos import uninstall_queue as qos_uninstall_queue
 # sites stamp explicitly with ``extra=trace_extra(req.trace)``.
 log = get_logger("kubeai_tpu.engine")
 
+# The scheduler segments a decode chunk's step record carries, as
+# dispatch_ms / host_overlap_ms / fetch_wait_ms / emit_ms.
+_CHUNK_SEGMENTS = ("dispatch", "host_overlap", "fetch_wait", "emit")
+
+
+def _name_os_thread(name: str) -> None:
+    """Name the calling thread for the kernel (PR_SET_NAME, 15 bytes): a
+    profiler trace labels a thread's line with that name, and Python 3.12
+    leaves it the process's."""
+    try:
+        import ctypes
+
+        ctypes.CDLL(None, use_errno=True).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):  # not Linux: the line keeps the process name
+        pass
+
 
 class GangLost(ConnectionError):
     """A gang follower's dispatch connection failed — the gang's
@@ -411,7 +427,9 @@ class Engine:
         )
         self.m_prefill_s = default_registry.histogram(
             "kubeai_engine_prefill_seconds",
-            "prefill dispatch to first emitted token",
+            "per request: its prefill dispatch to its first emitted token (a "
+            "latency as the request sees it, not device time: a group of 8 "
+            "counts its seconds 8 times)",
         )
         self.m_tpot = default_registry.histogram(
             "kubeai_engine_tpot_seconds",
@@ -508,9 +526,11 @@ class Engine:
         self.m_step = default_registry.histogram(
             "kubeai_engine_step_seconds",
             "scheduler step wall time by phase. decode_chunk = chunk TURNAROUND "
-            "(dispatch to results consumed — the pipelined loop overlaps host "
-            "work, admissions, and the next dispatch inside it; the pure host "
-            "wait is the step record's fetch_wait_ms); prefill_* = dispatch call",
+            "of a pipelined loop (its dispatch call returned to its results on "
+            "the host: the next chunk's dispatch and the round's prefills run "
+            "inside it, so about two chunks of device time, never a device "
+            "time per step; the pure host wait is the step record's "
+            "fetch_wait_ms); prefill_* = the host's dispatch call",
         )
         self.m_slot_steps = default_registry.counter(
             "kubeai_engine_slot_steps_total",
@@ -1671,47 +1691,49 @@ class Engine:
         # on this thread then fire @<port> twins so chaos schedules can
         # fault ONE replica of a multi-replica in-process fleet.
         faults.set_thread_scope(getattr(self, "fault_scope", None))
-        pending = None  # (payload_device_refs, [(slot_idx, _Slot, epoch), ...])
+        _name_os_thread("engine-loop")  # the line a profiler trace gives this thread
+        # Every statement of an iteration runs under exactly one segment
+        # (obs/perf.py STALL_CAUSES): stamped once, the stamps feed the
+        # stall counter, /debug/pipeline, the step records and, while a
+        # profiler runs, the sched.* events of its trace.
+        segment = self._stall.segment
+        pending = None  # (payload_device_refs, [(slot_idx, _Slot, epoch), ...], dispatch Segment)
         while self._running:
             try:
-                # Failpoint: chaos tests hang/fail the scheduler here —
-                # an injected error exercises the device-state recovery
-                # path below exactly like a real dispatch failure.
-                fault("engine.step")
-                self._sweep_deadlines()
-                self._sweep_qos_budgets()
-                self._sweep_kv_park()
+                with segment("sweep"):
+                    # Failpoint: chaos tests hang/fail the scheduler here —
+                    # an injected error exercises the device-state recovery
+                    # path below exactly like a real dispatch failure.
+                    fault("engine.step")
+                    self._sweep_deadlines()
+                    self._sweep_qos_budgets()
+                    self._sweep_kv_park()
                 admitted = self._admit_waiting()
                 dispatched = self._dispatch_chunk() if self._n_active > 0 else None
                 # First-token sync AFTER the dispatch: the chunk reads
                 # its first tokens from the device staging vector, so
                 # this host round-trip overlaps device compute.
-                t_host = time.monotonic()
-                self._emit_admitted(admitted)
-                self._run_aux()
-                # The host_overlap stall segment is measured HERE, as
-                # exactly the work between this iteration's dispatch and
-                # its fetch — deriving it as t_fetch - t_disp would span
-                # the previous chunk's whole _process_chunk (its fetch
-                # wait + emit) plus the next dispatch, double-counting
-                # segments other causes already record.
-                host_ms = (time.monotonic() - t_host) * 1000
+                with segment("host_overlap", admitted=len(admitted)):
+                    self._emit_admitted(admitted)
+                    self._run_aux()
                 if pending is not None:
-                    self._process_chunk(*pending, host_overlap_ms=host_ms)
+                    self._process_chunk(*pending)
                 pending = dispatched
-                self._update_recompile_counter()
+                with segment("sweep"):
+                    self._update_recompile_counter()
                 if (
                     pending is None and not admitted and self._n_active == 0
                     and self._aux.empty()
                 ):
-                    # Idle: the goodput gauge must read 0, not the last
-                    # busy chunk's rate — and the window re-anchors so
-                    # the next busy chunk doesn't span the idle gap.
-                    if len(self._rate_window):
-                        self._rate_window.reset()
-                        self.m_tok_rate.set(0.0)
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with segment("idle"):
+                        # Idle: the goodput gauge must read 0, not the last
+                        # busy chunk's rate — and the window re-anchors so
+                        # the next busy chunk doesn't span the idle gap.
+                        if len(self._rate_window):
+                            self._rate_window.reset()
+                            self.m_tok_rate.set(0.0)
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
             except GangDesync as e:
                 # The followers executed an op this rank didn't: no reset
                 # can realign the gang (they're blocked in its collective).
@@ -1951,10 +1973,18 @@ class Engine:
         # tok_ref/lp_ref are device arrays ([N] for group members, scalar
         # for chunked singles) and j indexes group outputs (None=scalar).
         admitted: list[tuple] = []
-        singles: list[tuple[int, int, "Request", int]] = []  # (seq, slot, req, reuse)
-        groups: dict[int, list[tuple[int, "Request"]]] = {}  # bucket -> items
         taken: set[int] = set()
         max_bucket = max(self.cfg.prefill_buckets)
+        with self._stall.segment("admit"):
+            work = self._plan_admissions(admitted, taken, max_bucket)
+            self._run_prefills(work)
+        return admitted
+
+    def _plan_admissions(self, admitted: list, taken: set[int], max_bucket: int) -> list:
+        """Drain the queue into free slots and reserved pages; returns the
+        prefill calls to make, in dispatch order, as (items, thunk)."""
+        singles: list[tuple[int, int, "Request", int]] = []  # (seq, slot, req, reuse)
+        groups: dict[int, list[tuple[int, "Request"]]] = {}  # bucket -> items
         seq = 0
         while True:
             if not (self._n_active + len(taken) < self.cfg.max_slots):
@@ -2050,7 +2080,9 @@ class Engine:
                 admitted.append(self._prefill_chunked(slot_idx, req, reuse))
 
             work.append(([(slot_idx, req)], one))
+        return work
 
+    def _run_prefills(self, work: list) -> None:
         for w, (items, thunk) in enumerate(work):
             try:
                 thunk()
@@ -2096,7 +2128,6 @@ class Engine:
                                     req, "error", error=f"prefill failed: {e}"
                                 )
                     raise
-        return admitted
 
     def _emit_admitted(self, admitted: list) -> None:
         """One host sync for all first tokens of an admission round —
@@ -2105,10 +2136,13 @@ class Engine:
         for client streaming only and overlaps device compute)."""
         if not admitted:
             return
-        toks, lps, tids, tlps = jax.device_get((
-            [a[2] for a in admitted], [a[4] for a in admitted],
-            [a[5] for a in admitted], [a[6] for a in admitted],
-        ))
+        # Blocks until the round's prefills have run (behind whatever
+        # chunk the device is still on): a wait, not host work.
+        with self._stall.segment("fetch_wait", of="first_tokens"):
+            toks, lps, tids, tlps = jax.device_get((
+                [a[2] for a in admitted], [a[4] for a in admitted],
+                [a[5] for a in admitted], [a[6] for a in admitted],
+            ))
         for (slot_idx, epoch, _, j, *_), tarr, larr, tid, tlp in zip(
             admitted, toks, lps, tids, tlps
         ):
@@ -2239,8 +2273,31 @@ class Engine:
         if req.trace is not None:
             req.trace.mark("prefill")
             req.trace.attrs["reuse_tokens"] = reuse
-        t_disp = time.monotonic()
+        max_bucket = max(self.cfg.prefill_buckets)
+        # Only the last chunk is shorter than the largest bucket.
+        tail = (len(ids) - reuse) % max_bucket
+        pad_tokens = self._bucket(tail) - tail if tail else 0
+        with self._stall.segment(
+            "prefill", kind="chunk", bucket=max_bucket, batch=1,
+            tokens=len(ids) - reuse, cached=reuse, pad=pad_tokens,
+        ) as seg:
+            out = self._prefill_chunks(slot_idx, req, reuse, seed, max_bucket)
+        self.m_step.observe(seg.seconds, labels={"phase": "prefill_chunked"})
+        self._stall.end_step("prefill_chunked")
+        if pad_tokens:
+            self.m_pad_prefill.inc(pad_tokens)
+        default_recorder.record_step(
+            kind="prefill_chunked", slot=slot_idx,
+            kernel=self._attn_kernel("prefill_chunked", max_bucket),
+            prompt_tokens=len(ids), reuse_tokens=reuse,
+            pad_tokens=pad_tokens,
+            dur_ms=round(seg.seconds * 1000, 3),
+        )
+        return out
 
+    def _prefill_chunks(self, slot_idx: int, req: Request, reuse: int, seed, max_bucket: int):
+        ids = req.prompt_ids
+        sp = req.params
         lora_args = {}
         lora_row = 0
         if self._adapters is not None:
@@ -2248,15 +2305,12 @@ class Engine:
             lora_args = {"lora": self._adapters.bank, "lora_row": np.int32(lora_row)}
 
         table = self._page_table[slot_idx : slot_idx + 1].copy()
-        max_bucket = max(self.cfg.prefill_buckets)
         bias_ids, bias_vals = self._bias_rows(sp)
         tok = lp = None
-        pad_tokens = 0
         for start in range(reuse, len(ids), max_bucket):
             chunk = ids[start : start + max_bucket]
             is_last = start + max_bucket >= len(ids)
             bucket = max_bucket if not is_last else self._bucket(len(chunk))
-            pad_tokens += bucket - len(chunk)
             chunk_padded = np.zeros((1, bucket), np.int32)
             chunk_padded[0, : len(chunk)] = chunk
             with self._lockstep(
@@ -2292,18 +2346,6 @@ class Engine:
                 )
 
         self._register(slot_idx, req, seed, lora_row, reuse)
-        dur = time.monotonic() - t_disp
-        self.m_step.observe(dur, labels={"phase": "prefill_chunked"})
-        self._stall.record_prefill("prefill_chunked", dur * 1000)
-        if pad_tokens:
-            self.m_pad_prefill.inc(pad_tokens)
-        default_recorder.record_step(
-            kind="prefill_chunked", slot=slot_idx,
-            kernel=self._attn_kernel("prefill_chunked", max_bucket),
-            prompt_tokens=len(ids), reuse_tokens=reuse,
-            pad_tokens=pad_tokens,
-            dur_ms=round(dur * 1000, 3),
-        )
         return (slot_idx, self._slot_epoch[slot_idx], tok, None, lp, t_ids, t_lp)
 
     def _bias_rows(self, sp: SamplingParams) -> tuple[np.ndarray, np.ndarray]:
@@ -2391,12 +2433,35 @@ class Engine:
         lets warmup cover every shape the measure phase hits (round 2's
         pow2 padding compiled new shapes mid-measurement)."""
         n = len(items)
-        t_disp = time.monotonic()
         for _, req in items:
             if req.trace is not None:
                 req.trace.mark("prefill")
         n_pad = 1 if n == 1 else max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
+        real_tokens = int(sum(len(r.prompt_ids) for _, r in items))
+        # Padding waste: the compiled [n_pad, bucket] shape vs the real
+        # prompt tokens (bucket tail pad + duplicated batch-pad rows).
+        pad_tokens = n_pad * bucket - real_tokens
+        with self._stall.segment(
+            "prefill", kind="group", bucket=bucket, batch=n,
+            tokens=real_tokens, cached=0, pad=pad_tokens,
+        ) as seg:
+            out = self._prefill_group_call(items, bucket, n_pad)
+        self.m_step.observe(seg.seconds, labels={"phase": "prefill_group"})
+        self._stall.end_step("prefill_group")
+        if pad_tokens > 0:
+            self.m_pad_prefill.inc(pad_tokens)
+        default_recorder.record_step(
+            kind="prefill_group", bucket=bucket, batch=n,
+            kernel=self._attn_kernel("prefill_group", bucket),
+            slots=[s for s, _ in items],
+            prompt_tokens=real_tokens,
+            pad_tokens=pad_tokens,
+            dur_ms=round(seg.seconds * 1000, 3),
+        )
+        return out
 
+    def _prefill_group_call(self, items: list, bucket: int, n_pad: int) -> list:
+        n = len(items)
         tokens = np.zeros((n_pad, bucket), np.int32)
         lengths = np.zeros((n_pad,), np.int32)
         tables = np.zeros((n_pad, self._max_pages), np.int32)
@@ -2464,23 +2529,6 @@ class Engine:
         for j, (slot_idx, req) in enumerate(items):
             self._register(slot_idx, req, seeds[j], int(lora_rows_arr[j]), reuse=0)
             out.append((slot_idx, self._slot_epoch[slot_idx], toks, j, lps, t_ids, t_lp))
-        dur = time.monotonic() - t_disp
-        real_tokens = int(sum(len(r.prompt_ids) for _, r in items))
-        # Padding waste: the compiled [n_pad, bucket] shape vs the real
-        # prompt tokens (bucket tail pad + duplicated batch-pad rows).
-        pad_tokens = n_pad * bucket - real_tokens
-        self.m_step.observe(dur, labels={"phase": "prefill_group"})
-        self._stall.record_prefill("prefill_group", dur * 1000)
-        if pad_tokens > 0:
-            self.m_pad_prefill.inc(pad_tokens)
-        default_recorder.record_step(
-            kind="prefill_group", bucket=bucket, batch=n,
-            kernel=self._attn_kernel("prefill_group", bucket),
-            slots=[s for s, _ in items],
-            prompt_tokens=real_tokens,
-            pad_tokens=pad_tokens,
-            dur_ms=round(dur * 1000, 3),
-        )
         return out
 
     def _dispatch_chunk(self):
@@ -2489,8 +2537,16 @@ class Engine:
         are passed as numpy COPIES (they ride the execute RPC; copies
         because the host mutates the originals while the transfer may
         still alias them). The admission merge arrays are consumed by
-        exactly this dispatch and cleared."""
-        t_start = time.monotonic()
+        exactly this dispatch and cleared. Returns (payload, snapshot,
+        the dispatch's Segment): a chunk's turnaround counts from the
+        segment's end stamp."""
+        with self._stall.segment(
+            "dispatch", active=self._n_active, steps=self.cfg.decode_chunk
+        ) as seg:
+            payload, snapshot = self._dispatch_chunk_call()
+        return payload, snapshot, seg
+
+    def _dispatch_chunk_call(self):
         lora_args = {}
         if self._adapters is not None:
             lora_args = {"lora": self._adapters.bank, "lora_rows": self._h_lora_rows.copy()}
@@ -2550,15 +2606,9 @@ class Engine:
         snapshot = [
             (i, s, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
-        # (t_start, t_dispatched) bound the dispatch segment of the
-        # chunk's stall breakdown; the loop measures the overlapped
-        # host segment itself (see _loop's host_ms).
-        return (
-            (d_seq, c_seq, a_seq, lpd_seq, lpc_seq, tid_seq, tlp_seq),
-            snapshot, t_start, time.monotonic(),
-        )
+        return (d_seq, c_seq, a_seq, lpd_seq, lpc_seq, tid_seq, tlp_seq), snapshot
 
-    def _process_chunk(self, payload, snapshot, t_start=None, t_disp=None, host_overlap_ms=0.0):
+    def _process_chunk(self, payload, snapshot, dispatched):
         # The top-N alternative arrays are fetched only when some slot in
         # this chunk's snapshot asked for logprobs: the device compute is
         # part of the static graph either way, but the host transfer
@@ -2566,20 +2616,35 @@ class Engine:
         any_top = any(
             s_obj.req.params.logprobs for _, s_obj, _ in snapshot
         )
-        t_fetch = time.monotonic()  # host wait starts here (device_get blocks)
-        if any_top:
-            drafts, corr, acc, lp_d, lp_c, t_ids, t_lp = jax.device_get(payload)
-            t_ids = np.asarray(t_ids)  # [K, B, G+1, N] top-N alternative ids
-            t_lp = np.asarray(t_lp)  # [K, B, G+1, N]
-        else:
-            drafts, corr, acc, lp_d, lp_c = jax.device_get(payload[:5])
-            t_ids = t_lp = None
-        fetch_wait = time.monotonic() - t_fetch
-        drafts = np.asarray(drafts)  # [K, B, G]
-        corr = np.asarray(corr)  # [K, B]
+        with self._stall.segment("fetch_wait", of="chunk") as fetched:  # device_get blocks
+            if any_top:
+                drafts, corr, acc, lp_d, lp_c, t_ids, t_lp = jax.device_get(payload)
+            else:
+                drafts, corr, acc, lp_d, lp_c = jax.device_get(payload[:5])
+                t_ids = t_lp = None
+        # The chunk's turnaround: dispatch call returned -> results on the host.
+        dur = fetched.t1 - dispatched.t1
         acc = np.asarray(acc)  # [K, B]
-        lp_d = np.asarray(lp_d)  # [K, B, G]
-        lp_c = np.asarray(lp_c)  # [K, B]
+        with self._stall.segment("emit", tokens=int(acc.sum()) + acc.shape[0] * len(snapshot)):
+            step = self._emit_chunk(
+                snapshot, dur, np.asarray(drafts), np.asarray(corr), acc,
+                np.asarray(lp_d), np.asarray(lp_c),
+                None if t_ids is None else np.asarray(t_ids),
+                None if t_lp is None else np.asarray(t_lp),
+            )
+        # The one set of stamps, once more: ms by cause of this loop
+        # iteration's segments (its dispatch and host overlap, and the
+        # fetch and emission of the chunk dispatched one iteration ago).
+        ms = self._stall.end_step("decode_chunk")
+        default_recorder.record_step(
+            **step, **{f"{c}_ms": round(ms.get(c, 0.0), 3) for c in _CHUNK_SEGMENTS}
+        )
+
+    def _emit_chunk(self, snapshot, dur, drafts, corr, acc, lp_d, lp_c, t_ids, t_lp) -> dict:
+        """Deliver a fetched chunk's tokens ([K, B, G] drafts, [K, B]
+        corrections and acceptance counts, their log-probs, [K, B, G+1, N]
+        top-N alternatives or None); returns its step record, less the
+        segment times."""
         G = drafts.shape[2]
         # Saturation accounting BEFORE emission: this chunk ran K fused
         # steps over the full [B] batch with only the snapshot's slots
@@ -2588,8 +2653,6 @@ class Engine:
         # Emission below delivers terminal events — a client unblocked
         # by one must already see these observations.
         K_steps = int(acc.shape[0])
-        t_fetched = time.monotonic()
-        dur = (t_fetched - t_disp) if t_disp is not None else 0.0
         self.m_step.observe(dur, labels={"phase": "decode_chunk"})
         self.m_slot_steps.inc(K_steps * len(snapshot), labels={"state": "active"})
         idle = K_steps * (self.cfg.max_slots - len(snapshot))
@@ -2653,21 +2716,6 @@ class Engine:
         now = time.monotonic()
         self._rate_window.add(n_emitted, now)
         self.m_tok_rate.set(round(self._rate_window.rate(now), 3))
-        # Uniform stall breakdown for this chunk (obs/perf.py causes):
-        # dispatch (argument upload + broadcast + async jit call), host
-        # overlap (emit_admitted + aux work the loop measured between
-        # its dispatch and this fetch — successfully pipelined; passed
-        # in so segments stay disjoint), fetch wait (pure host block in
-        # device_get), emit (detokenize/stop-check/delivery above).
-        emit_ms = (now - fetch_wait - t_fetch) * 1000
-        dispatch_ms = ((t_disp - t_start) if t_start is not None else 0.0) * 1000
-        self._stall.record_decode(
-            dispatch_ms=dispatch_ms,
-            host_overlap_ms=host_overlap_ms,
-            fetch_wait_ms=fetch_wait * 1000,
-            emit_ms=emit_ms,
-            now=now,
-        )
         # Flight-recorder step record: what the scheduler dispatched and
         # what came back (the /debug/engine view — batch composition,
         # token counts, kernel flavor, pages in use).
@@ -2682,20 +2730,17 @@ class Engine:
             "pages_used": self._pool.used(),
             "pages_total": self._pool.num_pages - 1,
             "queue_depth": self.queue_depth(),
+            # A chunk TURNAROUND (dispatch returned -> results fetched):
+            # the segment times the caller adds (dispatch_ms,
+            # host_overlap_ms, fetch_wait_ms, emit_ms) say where in it
+            # the host was; dur_ms minus fetch_wait_ms is the loop work
+            # the pipelining overlapped.
             "dur_ms": round(dur * 1000, 3),
-            # Pure host block inside device_get — dur_ms minus this is
-            # the loop work the pipelining successfully overlapped.
-            "fetch_wait_ms": round(fetch_wait * 1000, 3),
-            # The rest of the uniform stall breakdown (/debug/pipeline
-            # aggregates these over a sliding window).
-            "dispatch_ms": round(dispatch_ms, 3),
-            "host_overlap_ms": round(max(host_overlap_ms, 0.0), 3),
-            "emit_ms": round(max(emit_ms, 0.0), 3),
         }
         if G:
             step["spec_drafted"] = spec_drafted
             step["spec_accepted"] = spec_accepted
-        default_recorder.record_step(**step)
+        return step
 
     def _emit_token(self, slot_idx: int, token_id: int, logprob: float | None = None, top=None):
         """Deliver one generated token to the request; apply stop logic.
@@ -2990,10 +3035,16 @@ class Engine:
         failure — the caller then falls through to replay admission
         (clearing req.restore), which keeps state-transfer failures
         invisible to the client and the proxy's breaker."""
+        with self._stall.segment("kv_transfer", tokens=len(req.restore.history)) as seg:
+            res = self._import_restored(req, taken, seg)
+        if isinstance(res, int):
+            self._stall.end_step("kv_restore")
+        return res
+
+    def _import_restored(self, req: "Request", taken: set[int], seg) -> int | str | None:
         from kubeai_tpu.engine.paging import pages_for
 
         state = req.restore
-        t0 = time.monotonic()
         ps = self.cfg.page_size
         ids = req.prompt_ids
         row: list[int] | None = None
@@ -3142,10 +3193,10 @@ class Engine:
             for ev in state.events:
                 req.out.put(ev)
             self.kv_park.drop(req.restore_key)
-            dur = time.monotonic() - t0
             kvstate.M_KV_IMPORT.inc(labels={"outcome": "ok"})
-            kvstate.M_KV_RESTORE_SECONDS.observe(dur, labels={"phase": "import"})
-            self._stall.record_kv_transfer(dur * 1000)
+            kvstate.M_KV_RESTORE_SECONDS.observe(
+                time.monotonic() - seg.t0, labels={"phase": "import"}
+            )
             record_admitted(
                 req.priority, max(time.monotonic() - req.arrival, 0.0)
             )
@@ -3255,16 +3306,18 @@ def build_step_functions(
             params, mc, tokens, cache, tables, lengths,
             lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
         )
-        masked = mask_pad(logits[:, -1])
-        # Bias steers choice; the reported logprob stays the model's
-        # raw log p (same contract as decode).
-        toks = sample(
-            apply_logit_bias(masked, bias_ids, bias_vals),
-            keys, temp, top_p, top_k, max_top_k=mtk,
-        )
-        logp = jax.nn.log_softmax(masked, axis=-1)
-        lps = jnp.take_along_axis(logp, toks[:, None], axis=1)[:, 0]
-        t_lp, t_ids = jax.lax.top_k(logp, topn)
+        with jax.named_scope("sampling"):
+            masked = mask_pad(logits[:, -1])
+            # Bias steers choice; the reported logprob stays the model's
+            # raw log p (same contract as decode).
+            toks = sample(
+                apply_logit_bias(masked, bias_ids, bias_vals),
+                keys, temp, top_p, top_k, max_top_k=mtk,
+            )
+        with jax.named_scope("logprobs"):
+            logp = jax.nn.log_softmax(masked, axis=-1)
+            lps = jnp.take_along_axis(logp, toks[:, None], axis=1)[:, 0]
+            t_lp, t_ids = jax.lax.top_k(logp, topn)
         adm_toks = adm_toks.at[slots].set(toks)
         return toks, lps, t_ids.astype(jnp.int32), t_lp, cache, adm_toks
 
@@ -3277,14 +3330,16 @@ def build_step_functions(
             lora_rows=None if lora_row is None else lora_row[None],
             tp_mesh=mesh,
         )
-        masked = mask_pad(logits[:, -1])
-        tok = sample(
-            apply_logit_bias(masked, bias_ids[None], bias_vals[None]),
-            key[None], temp[None], top_p[None], top_k[None], max_top_k=mtk,
-        )[0]
-        logp = jax.nn.log_softmax(masked, axis=-1)
-        lp = logp[0, tok]
-        t_lp, t_ids = jax.lax.top_k(logp[0], topn)
+        with jax.named_scope("sampling"):
+            masked = mask_pad(logits[:, -1])
+            tok = sample(
+                apply_logit_bias(masked, bias_ids[None], bias_vals[None]),
+                key[None], temp[None], top_p[None], top_k[None], max_top_k=mtk,
+            )[0]
+        with jax.named_scope("logprobs"):
+            logp = jax.nn.log_softmax(masked, axis=-1)
+            lp = logp[0, tok]
+            t_lp, t_ids = jax.lax.top_k(logp[0], topn)
         adm_toks = adm_toks.at[slot].set(tok)
         return tok, lp, t_ids.astype(jnp.int32), t_lp, cache, adm_toks
 
@@ -3380,91 +3435,95 @@ def build_step_functions(
                 lora=lora, lora_rows=lora_rows,
                 decode_kernel=_decode_kernel, tp_mesh=mesh,
             )
-            logits = mask_pad(logits)  # [B, G+1, V]
-            if penalties_on:
-                # OpenAI presence/frequency penalties over the
-                # GENERATED window of the device token history —
-                # [gen_start, lengths] INCLUSIVE: position `lengths`
-                # holds this step's input (the token emitted last
-                # step, just scattered above), so the full output so
-                # far counts. Unaccepted-draft overshoot sits at
-                # positions > lengths, outside the window. Applied
-                # to position 0 (the token being chosen this step);
-                # penalty slots never accept drafts (below), so
-                # positions 1..G stay penalty-free verify lanes.
-                # The penalized view steers CHOICE only (argmax /
-                # sampling); reported logprobs stay the model's raw
-                # log p(token | prefix), matching how temperature /
-                # top_p shape choice without reshaping logprobs.
-                w_idx = jnp.arange(hist.shape[1], dtype=jnp.int32)[None, :]
-                pen_valid = (w_idx >= gen_start[:, None]) & (
-                    w_idx <= lengths[:, None]
+            with jax.named_scope("sampling"):
+                logits = mask_pad(logits)  # [B, G+1, V]
+                if penalties_on:
+                    # OpenAI presence/frequency penalties over the
+                    # GENERATED window of the device token history —
+                    # [gen_start, lengths] INCLUSIVE: position `lengths`
+                    # holds this step's input (the token emitted last
+                    # step, just scattered above), so the full output so
+                    # far counts. Unaccepted-draft overshoot sits at
+                    # positions > lengths, outside the window. Applied
+                    # to position 0 (the token being chosen this step);
+                    # penalty slots never accept drafts (below), so
+                    # positions 1..G stay penalty-free verify lanes.
+                    # The penalized view steers CHOICE only (argmax /
+                    # sampling); reported logprobs stay the model's raw
+                    # log p(token | prefix), matching how temperature /
+                    # top_p shape choice without reshaping logprobs.
+                    w_idx = jnp.arange(hist.shape[1], dtype=jnp.int32)[None, :]
+                    pen_valid = (w_idx >= gen_start[:, None]) & (
+                        w_idx <= lengths[:, None]
+                    )
+                    pen0 = apply_penalties(
+                        logits[:, 0], hist, pen_valid, presence, frequency
+                    )
+                else:
+                    pen0 = logits[:, 0]
+                pen0 = apply_logit_bias(pen0, bias_ids, bias_vals)
+            with jax.named_scope("logprobs"):
+                # Chosen-token logprob = raw logit - logsumexp: avoids
+                # materializing a normalized [B, G+1, V] tensor in the
+                # hottest loop just to gather G+1 entries.
+                lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B, G+1]
+            with jax.named_scope("sampling"):
+                yhat = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                yhat0_pen = jnp.argmax(pen0, axis=-1).astype(jnp.int32)
+                # Greedy slots accept the longest draft prefix the model
+                # agrees with (exactness by causality); sampled slots
+                # accept nothing and sample position 0 as before. Slots
+                # with any penalty also accept nothing: draft exactness
+                # is argmax-equivalence against the UNpenalized verify
+                # lanes, which a penalized distribution breaks.
+                greedy = temp <= 0.0
+                if G > 0:
+                    matches = (yhat[:, :G] == drafts).astype(jnp.int32)
+                    acc = jnp.cumprod(matches, axis=1).sum(axis=1)
+                    # Penalty/bias slots accept nothing: the verify
+                    # lanes (positions 1..G) are raw-argmax.
+                    no_pen = (
+                        (presence == 0.0)
+                        & (frequency == 0.0)
+                        & (bias_vals == 0.0).all(axis=1)
+                    )
+                    acc = jnp.where(greedy & active & no_pen, acc, 0)
+                else:
+                    acc = jnp.zeros((B,), jnp.int32)
+                step_keys = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+                sampled0 = sample(
+                    pen0, step_keys[:, 0], temp, top_p, top_k, max_top_k=mtk
                 )
-                pen0 = apply_penalties(
-                    logits[:, 0], hist, pen_valid, presence, frequency
+                # Greedy: position 0 picks from the penalized view
+                # (identical to raw when penalties are zero); accepted-
+                # draft positions (acc>0, only reachable penalty-free)
+                # pick from the raw verify lanes.
+                greedy_pick = jnp.where(
+                    acc > 0,
+                    jnp.take_along_axis(yhat, acc[:, None], axis=1)[:, 0],
+                    yhat0_pen,
                 )
-            else:
-                pen0 = logits[:, 0]
-            pen0 = apply_logit_bias(pen0, bias_ids, bias_vals)
-            # Chosen-token logprob = raw logit - logsumexp: avoids
-            # materializing a normalized [B, G+1, V] tensor in the
-            # hottest loop just to gather G+1 entries.
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B, G+1]
-            yhat = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            yhat0_pen = jnp.argmax(pen0, axis=-1).astype(jnp.int32)
-            # Greedy slots accept the longest draft prefix the model
-            # agrees with (exactness by causality); sampled slots
-            # accept nothing and sample position 0 as before. Slots
-            # with any penalty also accept nothing: draft exactness
-            # is argmax-equivalence against the UNpenalized verify
-            # lanes, which a penalized distribution breaks.
-            greedy = temp <= 0.0
-            if G > 0:
-                matches = (yhat[:, :G] == drafts).astype(jnp.int32)
-                acc = jnp.cumprod(matches, axis=1).sum(axis=1)
-                # Penalty/bias slots accept nothing: the verify
-                # lanes (positions 1..G) are raw-argmax.
-                no_pen = (
-                    (presence == 0.0)
-                    & (frequency == 0.0)
-                    & (bias_vals == 0.0).all(axis=1)
+                corr = jnp.where(greedy, greedy_pick, sampled0)
+                corr = jnp.where(active, corr, last)
+            with jax.named_scope("logprobs"):
+                if G > 0:
+                    lp_d = (
+                        jnp.take_along_axis(
+                            logits[:, :G], drafts[:, :, None], axis=2
+                        )[:, :, 0]
+                        - lse[:, :G]
+                    )
+                else:
+                    lp_d = jnp.zeros((B, 0), jnp.float32)
+                logits_at_a = jnp.take_along_axis(logits, acc[:, None, None], axis=1)[:, 0]
+                lp_corr = (
+                    jnp.take_along_axis(logits_at_a, corr[:, None], axis=1)[:, 0]
+                    - jnp.take_along_axis(lse, acc[:, None], axis=1)[:, 0]
                 )
-                acc = jnp.where(greedy & active & no_pen, acc, 0)
-            else:
-                acc = jnp.zeros((B,), jnp.int32)
-            step_keys = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-            sampled0 = sample(
-                pen0, step_keys[:, 0], temp, top_p, top_k, max_top_k=mtk
-            )
-            # Greedy: position 0 picks from the penalized view
-            # (identical to raw when penalties are zero); accepted-
-            # draft positions (acc>0, only reachable penalty-free)
-            # pick from the raw verify lanes.
-            greedy_pick = jnp.where(
-                acc > 0,
-                jnp.take_along_axis(yhat, acc[:, None], axis=1)[:, 0],
-                yhat0_pen,
-            )
-            corr = jnp.where(greedy, greedy_pick, sampled0)
-            corr = jnp.where(active, corr, last)
-            if G > 0:
-                lp_d = (
-                    jnp.take_along_axis(
-                        logits[:, :G], drafts[:, :, None], axis=2
-                    )[:, :, 0]
-                    - lse[:, :G]
-                )
-            else:
-                lp_d = jnp.zeros((B, 0), jnp.float32)
-            logits_at_a = jnp.take_along_axis(logits, acc[:, None, None], axis=1)[:, 0]
-            lp_corr = (
-                jnp.take_along_axis(logits_at_a, corr[:, None], axis=1)[:, 0]
-                - jnp.take_along_axis(lse, acc[:, None], axis=1)[:, 0]
-            )
-            # Top-N alternatives per position (raw model dist, pre-
-            # penalty/bias — same contract as the chosen logprob).
-            t_raw, t_ids = jax.lax.top_k(logits, topn)  # [B, G+1, N]
-            t_lp = t_raw - lse[..., None]
+                # Top-N alternatives per position (raw model dist, pre-
+                # penalty/bias — same contract as the chosen logprob).
+                t_raw, t_ids = jax.lax.top_k(logits, topn)  # [B, G+1, N]
+                t_lp = t_raw - lse[..., None]
             lengths = jnp.where(active, lengths + acc + 1, lengths)
             return (cache, hist, lengths, corr, step_keys[:, 1]), (
                 drafts, corr, acc, lp_d, lp_corr,

@@ -3,6 +3,8 @@
 All slots in the continuous-batching decode step sample in one fused call:
 per-slot temperature / top-k / top-p live in device arrays so the sampler
 is a single jitted kernel with no host branching. Greedy is temperature=0.
+Everything here traces under ``jax.named_scope("sampling")``: the name a
+device trace files these operations' time under.
 
 top-k uses `lax.top_k` with a static MAX_TOP_K (full-vocab sort would
 serialize the TPU); requests asking for larger k are clamped.
@@ -56,11 +58,12 @@ def apply_penalties(
     zeros (the compiled graph is shared; the two [B, V] temporaries are
     ~50 MB of fused traffic per call, noise next to the weight reads)."""
     B, V = logits.shape
-    b_idx = jnp.arange(B)[:, None]
-    v = hist_valid.astype(jnp.float32)
-    occurred = jnp.zeros((B, V), jnp.float32).at[b_idx, hist].max(v)
-    counts = jnp.zeros((B, V), jnp.float32).at[b_idx, hist].add(v)
-    return logits - presence[:, None] * occurred - frequency[:, None] * counts
+    with jax.named_scope("sampling"):
+        b_idx = jnp.arange(B)[:, None]
+        v = hist_valid.astype(jnp.float32)
+        occurred = jnp.zeros((B, V), jnp.float32).at[b_idx, hist].max(v)
+        counts = jnp.zeros((B, V), jnp.float32).at[b_idx, hist].add(v)
+        return logits - presence[:, None] * occurred - frequency[:, None] * counts
 
 
 def apply_logit_bias(
@@ -72,7 +75,8 @@ def apply_logit_bias(
     token 0 — a no-op). Like penalties, bias steers CHOICE only; callers
     keep reported logprobs on the raw logits."""
     B = logits.shape[0]
-    return logits.at[jnp.arange(B)[:, None], bias_ids].add(bias_vals)
+    with jax.named_scope("sampling"):
+        return logits.at[jnp.arange(B)[:, None], bias_ids].add(bias_vals)
 
 
 def sample(
@@ -84,6 +88,11 @@ def sample(
     max_top_k: int = MAX_TOP_K,  # static candidate-space cap; <=0 = full vocab
 ) -> jnp.ndarray:
     """Sample one token per slot. Returns [B] int32."""
+    with jax.named_scope("sampling"):
+        return _sample(logits, keys, temperature, top_p, top_k, max_top_k)
+
+
+def _sample(logits, keys, temperature, top_p, top_k, max_top_k):
     B, V = logits.shape
 
     # Work in the top-max_top_k candidate space; for top_k==0/top_p==1 the

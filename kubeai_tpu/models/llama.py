@@ -389,11 +389,16 @@ def apply(
             "ring attention does not support sliding windows or softcap"
         )
 
-    x = qgather(params["embed"], tokens, jnp.dtype(config.dtype))
-    if config.embed_scale:
-        # Gemma multiplies embeddings by sqrt(hidden), rounded through the
-        # compute dtype (HF casts the normalizer).
-        x = x * jnp.asarray(config.hidden_size**0.5, x.dtype)
+    # jax.named_scope below (embed; per layer attn, attn.kernel around the
+    # Pallas call, and ffn; lm_head) changes operation metadata only: the
+    # names a device trace files each operation's time under (PERF.md
+    # section 3; perfbench/readers/scope_share.py).
+    with jax.named_scope("embed"):
+        x = qgather(params["embed"], tokens, jnp.dtype(config.dtype))
+        if config.embed_scale:
+            # Gemma multiplies embeddings by sqrt(hidden), rounded through the
+            # compute dtype (HF casts the normalizer).
+            x = x * jnp.asarray(config.hidden_size**0.5, x.dtype)
 
     act = jax.nn.silu if config.hidden_act == "silu" else (
         lambda v: jax.nn.gelu(v, approximate=True)
@@ -505,124 +510,128 @@ def apply(
         def norm(inp, name):
             return rms_norm(inp, w[name] + norm_offset, config.rms_norm_eps)
 
-        attn_in = norm(x, "ln1")
-        q = proj(attn_in, "wq").reshape(B, S, H, h)
-        k = proj(attn_in, "wk").reshape(B, S, Kv, h)
-        v = proj(attn_in, "wv").reshape(B, S, Kv, h)
-        q, k = apply_rope(q, k, positions, inv_freq)
+        with jax.named_scope("attn"):
+            attn_in = norm(x, "ln1")
+            q = proj(attn_in, "wq").reshape(B, S, H, h)
+            k = proj(attn_in, "wk").reshape(B, S, Kv, h)
+            v = proj(attn_in, "wv").reshape(B, S, Kv, h)
+            q, k = apply_rope(q, k, positions, inv_freq)
 
-        if kv_pool is not None:
-            # kv_pool: the FULL flat [L*P, page, 2Kv, h] pool, K/V
-            # interleaved on the head axis (kernel-native); this layer
-            # owns rows layer_idx*P..(layer_idx+1)*P. One scatter writes
-            # both through the offset block table; the kernel (or CPU
-            # reference) reads pages in place, and the portable fallback
-            # gathers a contiguous view. The pool rides the scan CARRY
-            # un-sliced — slicing a per-layer plane out of a stacked
-            # array cost ~10ms/step in copies (see init_paged_cache).
-            interleaved = jnp.stack([k, v], axis=3).reshape(B, S, 2 * Kv, h)
-            if kv_quant:
-                y = interleaved.astype(jnp.float32) / kv_scale_vec
-                if kv_dt == jnp.dtype(jnp.int8):
-                    y = jnp.clip(jnp.round(y), -127.0, 127.0)
-                else:
-                    # e4m3fn overflow converts to NaN, not max — clip to
-                    # the format's finite range first.
-                    y = jnp.clip(y, -448.0, 448.0)
-                interleaved = y.astype(kv_dt)
-            table_l = page_table + layer_idx * pool_P
-            kv_full = kv_pool.at[w_pages + layer_idx * pool_P, w_offs].set(interleaved)
-            k_full = v_full = None
-            if use_paged_kernel or use_flash:
-                # Neither path reads the gathered view: the ragged kernel
-                # walks pages in place, and flash prefill (left-aligned,
-                # positions arange(S)) attends exactly the just-computed
-                # k/v — gathering the full table width only to slice S
-                # columns would move max_pages*page/S times the needed
-                # KV bytes per layer.
-                k_att = v_att = None
-            else:
-                gathered = kv_full[table_l]  # [B, mp, page, 2Kv, h]
+            if kv_pool is not None:
+                # kv_pool: the FULL flat [L*P, page, 2Kv, h] pool, K/V
+                # interleaved on the head axis (kernel-native); this layer
+                # owns rows layer_idx*P..(layer_idx+1)*P. One scatter writes
+                # both through the offset block table; the kernel (or CPU
+                # reference) reads pages in place, and the portable fallback
+                # gathers a contiguous view. The pool rides the scan CARRY
+                # un-sliced — slicing a per-layer plane out of a stacked
+                # array cost ~10ms/step in copies (see init_paged_cache).
+                interleaved = jnp.stack([k, v], axis=3).reshape(B, S, 2 * Kv, h)
                 if kv_quant:
-                    gathered = (
-                        gathered.astype(jnp.float32) * kv_scale_vec
-                    ).astype(jnp.dtype(config.dtype))
-                k_att = gathered[..., 0::2, :].reshape(B, skv, Kv, h)
-                v_att = gathered[..., 1::2, :].reshape(B, skv, Kv, h)
-        elif k_cache_l is not None:
-            k_full = k_cache_l.at[rows, positions].set(k)
-            v_full = v_cache_l.at[rows, positions].set(v)
-            if cache_rows is None:
-                k_att, v_att = k_full, v_full
+                    y = interleaved.astype(jnp.float32) / kv_scale_vec
+                    if kv_dt == jnp.dtype(jnp.int8):
+                        y = jnp.clip(jnp.round(y), -127.0, 127.0)
+                    else:
+                        # e4m3fn overflow converts to NaN, not max — clip to
+                        # the format's finite range first.
+                        y = jnp.clip(y, -448.0, 448.0)
+                    interleaved = y.astype(kv_dt)
+                table_l = page_table + layer_idx * pool_P
+                kv_full = kv_pool.at[w_pages + layer_idx * pool_P, w_offs].set(interleaved)
+                k_full = v_full = None
+                if use_paged_kernel or use_flash:
+                    # Neither path reads the gathered view: the ragged kernel
+                    # walks pages in place, and flash prefill (left-aligned,
+                    # positions arange(S)) attends exactly the just-computed
+                    # k/v — gathering the full table width only to slice S
+                    # columns would move max_pages*page/S times the needed
+                    # KV bytes per layer.
+                    k_att = v_att = None
+                else:
+                    gathered = kv_full[table_l]  # [B, mp, page, 2Kv, h]
+                    if kv_quant:
+                        gathered = (
+                            gathered.astype(jnp.float32) * kv_scale_vec
+                        ).astype(jnp.dtype(config.dtype))
+                    k_att = gathered[..., 0::2, :].reshape(B, skv, Kv, h)
+                    v_att = gathered[..., 1::2, :].reshape(B, skv, Kv, h)
+            elif k_cache_l is not None:
+                k_full = k_cache_l.at[rows, positions].set(k)
+                v_full = v_cache_l.at[rows, positions].set(v)
+                if cache_rows is None:
+                    k_att, v_att = k_full, v_full
+                else:
+                    k_att, v_att = k_full[cache_rows], v_full[cache_rows]
             else:
-                k_att, v_att = k_full[cache_rows], v_full[cache_rows]
-        else:
-            k_full, v_full = k, v
-            k_att, v_att = k, v
+                k_full, v_full = k, v
+                k_att, v_att = k, v
 
-        if use_paged_kernel:
-            if use_dedicated_decode:
-                from kubeai_tpu.ops.paged_decode_attention import (
-                    paged_decode_attention as paged_attn_fn,
+            if use_paged_kernel:
+                if use_dedicated_decode:
+                    from kubeai_tpu.ops.paged_decode_attention import (
+                        paged_decode_attention as paged_attn_fn,
+                    )
+                else:
+                    from kubeai_tpu.ops.paged_attention import (
+                        paged_attention_ragged as paged_attn_fn,
+                    )
+
+                with jax.named_scope("attn.kernel"):
+                    attn_out = per_tp_shard(
+                        lambda q_, kv_, table_, lens_: paged_attn_fn(
+                            q_, kv_, table_, lens_,
+                            scale=config.query_scale,
+                            softcap=config.attn_softcap,
+                            k_scale=kq_scale if kv_quant else None,
+                            v_scale=vq_scale if kv_quant else None,
+                        ),
+                        n_head_split=2, n_replicated=2,
+                    )(q, kv_full, table_l, positions[:, -1] + 1)  # keys 0..last pos inclusive
+            elif use_flash:
+                # Prefill positions are arange(S): the cache columns 0..S-1
+                # were just written with exactly k/v, so plain causal over
+                # the fresh tensors == the position-derived mask over the
+                # cache — no cache read needed.
+                from kubeai_tpu.ops.flash_attention import flash_attention_tpu
+
+                with jax.named_scope("attn.kernel"):
+                    attn_out = per_tp_shard(
+                        lambda q_, k_, v_: flash_attention_tpu(
+                            q_, k_, v_, causal=True, sm_scale=config.query_scale,
+                        ),
+                        n_head_split=3,
+                    )(q, k, v)
+            elif ring_mesh is not None and cache is None:
+                from kubeai_tpu.parallel.ring_attention import ring_attention
+
+                attn_out = ring_attention(
+                    q, k, v, ring_mesh, scale=config.query_scale
                 )
             else:
-                from kubeai_tpu.ops.paged_attention import (
-                    paged_attention_ragged as paged_attn_fn,
+                layer_mask = mask
+                if window_ok is not None and sliding is not None:
+                    layer_mask = jnp.logical_and(mask, jnp.logical_or(~sliding, window_ok))
+                attn_out = attention(
+                    q, k_att, v_att, layer_mask,
+                    scale=config.query_scale, softcap=config.attn_softcap,
                 )
+            o = proj(attn_out.reshape(B, S, H * h), "wo")
+            if config.post_norms:
+                o = norm(o, "ln1b")
+            x = x + o
 
-            attn_out = per_tp_shard(
-                lambda q_, kv_, table_, lens_: paged_attn_fn(
-                    q_, kv_, table_, lens_,
-                    scale=config.query_scale,
-                    softcap=config.attn_softcap,
-                    k_scale=kq_scale if kv_quant else None,
-                    v_scale=vq_scale if kv_quant else None,
-                ),
-                n_head_split=2, n_replicated=2,
-            )(q, kv_full, table_l, positions[:, -1] + 1)  # keys 0..last pos inclusive
-        elif use_flash:
-            # Prefill positions are arange(S): the cache columns 0..S-1
-            # were just written with exactly k/v, so plain causal over
-            # the fresh tensors == the position-derived mask over the
-            # cache — no cache read needed.
-            from kubeai_tpu.ops.flash_attention import flash_attention_tpu
-
-            attn_out = per_tp_shard(
-                lambda q_, k_, v_: flash_attention_tpu(
-                    q_, k_, v_, causal=True, sm_scale=config.query_scale,
-                ),
-                n_head_split=3,
-            )(q, k, v)
-        elif ring_mesh is not None and cache is None:
-            from kubeai_tpu.parallel.ring_attention import ring_attention
-
-            attn_out = ring_attention(
-                q, k, v, ring_mesh, scale=config.query_scale
-            )
-        else:
-            layer_mask = mask
-            if window_ok is not None and sliding is not None:
-                layer_mask = jnp.logical_and(mask, jnp.logical_or(~sliding, window_ok))
-            attn_out = attention(
-                q, k_att, v_att, layer_mask,
-                scale=config.query_scale, softcap=config.attn_softcap,
-            )
-        o = proj(attn_out.reshape(B, S, H * h), "wo")
-        if config.post_norms:
-            o = norm(o, "ln1b")
-        x = x + o
-
-        mlp_in = norm(x, "ln2")
-        if config.num_experts > 0:
-            m = moe_mlp(
-                mlp_in, w["wr"], w["wg"], w["wu"], w["wd"],
-                config.num_experts_per_tok, config.moe_capacity_factor,
-            )
-        else:
-            m = proj(act(proj(mlp_in, "wg")) * proj(mlp_in, "wu"), "wd")
-        if config.post_norms:
-            m = norm(m, "ln2b")
-        x = x + m
+        with jax.named_scope("ffn"):
+            mlp_in = norm(x, "ln2")
+            if config.num_experts > 0:
+                m = moe_mlp(
+                    mlp_in, w["wr"], w["wg"], w["wu"], w["wd"],
+                    config.num_experts_per_tok, config.moe_capacity_factor,
+                )
+            else:
+                m = proj(act(proj(mlp_in, "wg")) * proj(mlp_in, "wu"), "wd")
+            if config.post_norms:
+                m = norm(m, "ln2b")
+            x = x + m
         cache_out = kv_full if kv_pool is not None else (k_full, v_full)
         return x, cache_out
 
@@ -670,15 +679,16 @@ def apply(
     x = rms_norm(x, params["final_norm"] + norm_offset, config.rms_norm_eps)
     if return_hidden:
         return x.astype(jnp.float32), new_cache
-    if logits_idx is not None:
-        x = x[batch_idx, logits_idx[:, None]]  # [B, 1, D]
-    if config.tie_word_embeddings:
-        logits = qmatT(x, params["embed"])
-    else:
-        logits = qdot(x, params["lm_head"])
-    logits = logits.astype(jnp.float32)
-    if config.logit_softcap > 0.0:
-        logits = config.logit_softcap * jnp.tanh(logits / config.logit_softcap)
+    with jax.named_scope("lm_head"):
+        if logits_idx is not None:
+            x = x[batch_idx, logits_idx[:, None]]  # [B, 1, D]
+        if config.tie_word_embeddings:
+            logits = qmatT(x, params["embed"])
+        else:
+            logits = qdot(x, params["lm_head"])
+        logits = logits.astype(jnp.float32)
+        if config.logit_softcap > 0.0:
+            logits = config.logit_softcap * jnp.tanh(logits / config.logit_softcap)
     return logits, new_cache
 
 

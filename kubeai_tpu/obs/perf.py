@@ -1,8 +1,8 @@
 """Perf X-ray: roofline/MFU accounting, step-pipeline stall attribution,
 and on-demand device profiler capture.
 
-Four pieces, all dependency-free (jax is imported lazily and only by the
-profiler capture):
+Four pieces, all dependency-free (jax is imported lazily: by the profiler
+capture, and for the TraceAnnotation of a scheduler segment):
 
 - **PerfModel** — model FLOPs/token and weight-bytes/token computed ONCE
   from ModelConfig. This is the single source of truth for the roofline
@@ -16,10 +16,10 @@ profiler capture):
   totals and report (last-first)/(span); the first sample only ANCHORS
   the window, so an idle→busy transition cannot report a spike the
   fleet's counter-delta view would never show.
-- **PipelineStallTracker** — aggregates the engine's enriched step
-  records (dispatch / host-overlap / fetch-wait / emit / prefill) over a
-  sliding window into the ``GET /debug/pipeline`` stall report and the
-  ``kubeai_engine_stall_seconds_total{cause}`` counter.
+- **PipelineStallTracker** — ``segment(cause)`` stamps each segment of
+  the scheduler loop once and feeds the ``GET /debug/pipeline`` report,
+  the ``kubeai_engine_stall_seconds_total{cause}`` counter, the engine's
+  step records and the ``sched.<cause>`` events of a profiler trace.
 - **ProfilerCapture** + ``handle_perf_request`` — ``GET
   /debug/profile?seconds=N`` starts a ``jax.profiler`` trace (single-
   flight; opt-in via ``KUBEAI_DEBUG_PROFILE=1``, mirroring the
@@ -264,23 +264,41 @@ class TokenRateWindow:
 # ---------------------------------------------------------------------------
 # Stall attribution.
 
-# The uniform timing breakdown every scheduler step record maps onto
-# (segments are DISJOINT wall-time slices — the engine measures each
-# directly rather than deriving any as an interval difference, so the
-# per-cause seconds can be summed without double-counting):
-#   dispatch      argument upload + broadcast + async jit call
+# The scheduler loop's segments: one vocabulary for the counter
+# ``kubeai_engine_stall_seconds_total{cause}``, GET /debug/pipeline, the
+# step records of /debug/engine and the ``sched.<cause>`` events the loop
+# writes into a profiler trace (host spans on the device trace's clock).
+# Segments are DISJOINT wall-time slices of the scheduler thread — each is
+# stamped once, at its two ends, by ``PipelineStallTracker.segment`` — and
+# with ``other``, the time between one segment's end stamp and the next
+# one's start stamp, the per-cause seconds sum to the loop's wall time:
+#   sweep         deadline / QoS-budget / KV-park sweeps at the top of an
+#                 iteration, the recompile counter at its end
+#   admit         queue drain and slot + KV page planning (the prefill and
+#                 kv_transfer segments nest inside and are not counted twice)
+#   prefill       prefill dispatch calls (group and chunked)
+#   kv_transfer   KV restore admissions (engine/kvstate.py): blob
+#                 validation + page upload + slot rebuild — the import cost
+#                 restore pays instead of the prefill cost replay would
+#   dispatch      argument upload + broadcast + async jit call of a chunk
 #   host_overlap  first-token emission for admitted requests + aux work
 #                 between a dispatch and its fetch — time the pipelining
 #                 successfully hid behind device compute
-#   fetch_wait    pure host block inside device_get (device compute +
-#                 result transfer outlasting the overlapped host work)
+#   fetch_wait    pure host block inside device_get: a chunk's results in
+#                 _process_chunk, an admission round's first tokens in
+#                 _emit_admitted (device compute + result transfer
+#                 outlasting the overlapped host work)
 #   emit          detokenize / stop-check / client delivery
-#   prefill       prefill dispatch calls (group and chunked)
-#   kv_transfer   KV restore admissions (engine/kvstate.py): blob
-#                 validation + page upload + slot rebuild on the
-#                 scheduler thread — the import cost restore pays
-#                 instead of the prefill cost replay would
-STALL_CAUSES = ("dispatch", "host_overlap", "fetch_wait", "emit", "prefill", "kv_transfer")
+#   idle          nothing to do: the wait for the next request
+#   other         under no segment (a trace shows nothing there): the
+#                 loop's statements between segments, and what falls on
+#                 them — the wait to get the interpreter lock back from
+#                 the serving threads after an emission woke them up,
+#                 recovery after a failed step
+STALL_CAUSES = (
+    "sweep", "admit", "prefill", "kv_transfer", "dispatch", "host_overlap",
+    "fetch_wait", "emit", "idle", "other",
+)
 
 _INTERPRET = {
     "fetch_wait": (
@@ -301,63 +319,124 @@ _INTERPRET = {
         "imported; check kubeai_kv_restore_seconds and the break-even "
         "floor (KUBEAI_KV_BREAKEVEN_TOKENS)"
     ),
+    "sweep": "host-bound on the per-iteration sweeps (deadlines, QoS budgets, KV park)",
+    "admit": "host-bound on admission: queue drain and KV page planning dominate",
+    "idle": "idle: the scheduler mostly waits for requests",
+    "other": (
+        "under no segment: the scheduler thread mostly waits for the "
+        "interpreter lock (serving threads delivering tokens) or recovers"
+    ),
 }
+
+_annotation = None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation (a TraceMe: a flag test while no
+    profiler runs), resolved once; a null context where jax is absent."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation
+        except Exception:  # pragma: no cover - jax is a hard dependency of the engine
+            import contextlib
+
+            _annotation = lambda name, **attrs: contextlib.nullcontext()  # noqa: E731
+    return _annotation
+
+
+class Segment:
+    """One stamped slice of the scheduler thread's time (see
+    ``PipelineStallTracker.segment``). After exit: ``t0``/``t1`` are the
+    stamps and ``seconds`` the time between them."""
+
+    __slots__ = ("_tracker", "cause", "_ann", "t0", "t1", "seconds", "_own", "_resumed")
+
+    def __init__(self, tracker: "PipelineStallTracker", cause: str, attrs: dict):
+        self._tracker = tracker
+        self.cause = cause
+        self._ann = _trace_annotation()(f"sched.{cause}", **attrs)
+        self.t0 = self.t1 = self.seconds = self._own = self._resumed = 0.0
+
+    def __enter__(self) -> "Segment":
+        self._ann.__enter__()
+        tracker = self._tracker
+        stack = tracker._stack
+        self.t0 = self._resumed = now = tracker._clock()
+        if stack:  # the enclosing segment stops counting while this one runs
+            stack[-1]._own += now - stack[-1]._resumed
+        elif tracker._last_end is not None:
+            # Since the last segment ended: the same two stamps, no third.
+            tracker._add("other", now - tracker._last_end, now)
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        stack = self._tracker._stack
+        self.t1 = now = self._tracker._clock()
+        self.seconds = now - self.t0
+        stack.pop()
+        if stack:
+            stack[-1]._resumed = now
+        else:
+            self._tracker._last_end = now
+        self._tracker._add(self.cause, self._own + now - self._resumed, now)
+        self._ann.__exit__(*exc)
 
 
 class PipelineStallTracker:
-    """Sliding-window aggregation of enriched scheduler step records into
-    a stall-attribution report ('where does decode wall-time go'). The
-    engine records one entry per decode chunk / prefill call; ``report``
-    answers ``GET /debug/pipeline``. Per-cause totals also feed the
-    ``kubeai_engine_stall_seconds_total{cause}`` counter so the fleet
-    collector and SLO layers see the same attribution fleet-wide."""
+    """Where the scheduler thread's wall time goes. The loop wraps each of
+    its segments in ``segment(cause)``; the one pair of stamps taken there
+    feeds the ``kubeai_engine_stall_seconds_total{cause}`` counter, the
+    sliding window behind ``GET /debug/pipeline`` (``report``), the step
+    record the engine hands to /debug/engine (``end_step``) and, while a
+    profiler runs, a ``sched.<cause>`` event on the scheduler thread's
+    line of the trace."""
 
     def __init__(self, window: float = 60.0, clock=time.monotonic, registry=None):
         self.window = window
         self._clock = clock
         self._lock = threading.Lock()
-        # (t, kind, {cause: ms})
-        self._records: deque[tuple[float, str, dict]] = deque()
+        # (t_end, cause, ms) per segment; (t, kind, None) per finished step
+        self._records: deque[tuple[float, str, float | None]] = deque()
+        self._stack: list[Segment] = []  # open segments (scheduler thread only)
+        self._last_end: float | None = None  # end stamp of the last outermost segment
+        self._step: dict[str, float] = {}  # ms by cause since the last end_step
         reg = registry or default_registry
         self._counter = reg.counter(
             "kubeai_engine_stall_seconds_total",
-            "scheduler step wall time by stall cause (dispatch | "
-            "host_overlap | fetch_wait | emit | prefill | kv_transfer) — "
-            "the aggregate behind GET /debug/pipeline",
+            "scheduler thread wall time by segment (sweep | admit | prefill | "
+            "kv_transfer | dispatch | host_overlap | fetch_wait | emit | idle | "
+            "other): disjoint, together the loop's whole wall time — the aggregate "
+            "behind GET /debug/pipeline and the sched.<cause> events of a profiler trace",
         )
 
-    def record_decode(
-        self,
-        dispatch_ms: float,
-        host_overlap_ms: float,
-        fetch_wait_ms: float,
-        emit_ms: float,
-        now: float | None = None,
-    ) -> None:
-        self._record(
-            "decode_chunk",
-            {
-                "dispatch": max(dispatch_ms, 0.0),
-                "host_overlap": max(host_overlap_ms, 0.0),
-                "fetch_wait": max(fetch_wait_ms, 0.0),
-                "emit": max(emit_ms, 0.0),
-            },
-            now,
-        )
+    def segment(self, cause: str, **attrs) -> Segment:
+        """Context manager around one segment of the scheduler loop.
+        *attrs* go onto the trace event only. A segment opened inside
+        another suspends the outer one: seconds are never counted twice."""
+        return Segment(self, cause, attrs)
 
-    def record_prefill(self, kind: str, dur_ms: float, now: float | None = None) -> None:
-        self._record(kind, {"prefill": max(dur_ms, 0.0)}, now)
-
-    def record_kv_transfer(self, dur_ms: float, now: float | None = None) -> None:
-        self._record("kv_restore", {"kv_transfer": max(dur_ms, 0.0)}, now)
-
-    def _record(self, kind: str, causes: dict, now: float | None) -> None:
-        now = self._clock() if now is None else now
-        for cause, ms in causes.items():
-            if ms:
-                self._counter.inc(ms / 1000.0, labels={"cause": cause})
+    def end_step(self, kind: str) -> dict[str, float]:
+        """Close the current step's record: ms by cause of the segments
+        that ended since the last call, counted in the window as one step
+        of *kind* (decode_chunk | prefill_group | prefill_chunked |
+        kv_restore)."""
+        step, self._step = self._step, {}
+        now = self._clock()
         with self._lock:
-            self._records.append((now, kind, causes))
+            self._records.append((now, kind, None))
+            self._prune_locked(now)
+        return step
+
+    def _add(self, cause: str, seconds: float, now: float) -> None:
+        seconds = max(seconds, 0.0)
+        if seconds:
+            self._counter.inc(seconds, labels={"cause": cause})
+        ms = seconds * 1000.0
+        self._step[cause] = self._step.get(cause, 0.0) + ms
+        with self._lock:
+            self._records.append((now, cause, ms))
             self._prune_locked(now)
 
     def _prune_locked(self, now: float) -> None:
@@ -367,20 +446,24 @@ class PipelineStallTracker:
 
     def report(self, now: float | None = None) -> dict:
         """The /debug/pipeline payload: per-cause ms + fraction of
-        accounted step time (fractions sum to 1.0 by construction),
-        step counts by kind, and a human interpretation of the dominant
-        cause. ``coverage`` is accounted time / observed wall span — the
-        remainder is scheduler idle (or work between records)."""
+        accounted time (fractions sum to 1.0 by construction), step
+        counts by kind, and a human interpretation of the dominant
+        cause. ``coverage`` is the share of the observed wall span that
+        lay under a named segment (everything but ``other``)."""
         now = self._clock() if now is None else now
         with self._lock:
             self._prune_locked(now)
             records = list(self._records)
         cause_ms = {c: 0.0 for c in STALL_CAUSES}
         steps: dict[str, int] = {}
-        for _, kind, causes in records:
-            steps[kind] = steps.get(kind, 0) + 1
-            for cause, ms in causes.items():
-                cause_ms[cause] = cause_ms.get(cause, 0.0) + ms
+        first = None  # when the oldest segment of the window began
+        for t, name, ms in records:
+            if ms is None:
+                steps[name] = steps.get(name, 0) + 1
+                continue
+            cause_ms[name] = cause_ms.get(name, 0.0) + ms
+            if first is None:
+                first = t - ms / 1000.0
         accounted = sum(cause_ms.values())
         out: dict = {
             "window_seconds": self.window,
@@ -394,10 +477,9 @@ class PipelineStallTracker:
                 for c, ms in cause_ms.items()
             },
         }
-        if records:
-            span = now - records[0][0]
-            if span > 0:
-                out["coverage"] = round(min(accounted / (span * 1000.0), 1.0), 4)
+        if first is not None and now > first:
+            named = accounted - cause_ms["other"]
+            out["coverage"] = round(min(named / ((now - first) * 1000.0), 1.0), 4)
         if accounted:
             dominant = max(cause_ms, key=lambda c: cause_ms[c])
             out["dominant_cause"] = dominant
@@ -416,6 +498,54 @@ def profiling_enabled() -> bool:
     ``KUBEAI_DEBUG_PROFILE=1`` opt-in (mirroring the /debug/faults
     arming gate). Re-read per request so tests can toggle it."""
     return os.environ.get("KUBEAI_DEBUG_PROFILE", "") in ("1", "true", "yes")
+
+
+class _TraceSession:
+    """One profiler trace, started on construction; ``stop(out_dir)`` writes
+    it as ``<out_dir>/plugins/profile/<time>/<host>.xplane.pb``, where
+    TensorBoard's profile plugin and ``jax.profiler.ProfileData`` look.
+
+    Only the .xplane.pb is written: ``jax.profiler.stop_trace`` also
+    converts the whole trace to a trace-viewer JSON and gzips it, which on a
+    v5e took most of the 23 s a 4 s capture of a 7B engine needed after its
+    traced seconds (PERF.md, PR 24). ``KUBEAI_PROFILE_PYTHON_TRACER=0``
+    turns the Python tracer off: it hooks every call of every Python thread
+    of the process, and the device planes and the host's TraceMe events
+    (the scheduler's ``sched.*``, ``profile.window``) survive without it;
+    it stays on by default because the benchmark's accepted harness takes
+    the traced interval from that tracer's record of the capture's sleep.
+    Where this jax has no ``ProfilerSession`` to stop by hand, it is
+    ``start_trace`` / ``stop_trace`` after all."""
+
+    def __init__(self, jax, out_dir: str):
+        self._jax, self._out_dir, self._session = jax, out_dir, None
+        options = None
+        if os.environ.get("KUBEAI_PROFILE_PYTHON_TRACER", "") in ("0", "false", "no"):
+            try:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+            except AttributeError:  # a jax without ProfileOptions
+                options = None
+        try:
+            from jax._src.lib import _profiler
+
+            self._session = _profiler.ProfilerSession(options) if options else _profiler.ProfilerSession()
+        except Exception:  # no such class here, or it refused: the public pair
+            jax.profiler.start_trace(out_dir, **({"profiler_options": options} if options else {}))
+
+    def stop(self) -> None:
+        if self._session is None:
+            self._jax.profiler.stop_trace()
+            return
+        xspace = self._session.stop()  # the trace, as a serialized XSpace
+        import socket
+
+        run_dir = os.path.join(
+            self._out_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S")
+        )
+        os.makedirs(run_dir, exist_ok=True)
+        with open(os.path.join(run_dir, socket.gethostname() + ".xplane.pb"), "wb") as f:
+            f.write(xspace)
 
 
 class ProfilerBusy(RuntimeError):
@@ -454,11 +584,19 @@ class ProfilerCapture:
                     log.warning("profile gang fan-out failed: %s", e)
             import jax
 
-            jax.profiler.start_trace(out_dir)
+            t_start = time.monotonic()
+            session = _TraceSession(jax, out_dir)
+            t_traced = time.monotonic()
             try:
-                time.sleep(seconds)
+                # The traced interval, as an event of the trace itself: a
+                # reader takes its window from here, not from the first
+                # and last device operation.
+                with _trace_annotation()("profile.window", seconds=seconds):
+                    time.sleep(seconds)
             finally:
-                jax.profiler.stop_trace()
+                t_stop = time.monotonic()
+                session.stop()  # collects and writes: the long part
+                t_written = time.monotonic()
             files = 0
             total = 0
             for r, _, fs in os.walk(out_dir):
@@ -474,6 +612,9 @@ class ProfilerCapture:
                 "files": files,
                 "bytes": total,
                 "gang_fanout": fanout,
+                # What the capture cost beyond the traced seconds.
+                "start_seconds": round(t_traced - t_start, 3),
+                "stop_seconds": round(t_written - t_stop, 3),
             }
         finally:
             self._lock.release()

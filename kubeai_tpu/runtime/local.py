@@ -346,12 +346,37 @@ class LocalRuntime:
             out += ["--port", str(port)]
         return out
 
+    # How long a killed pod gets to be gone, twice: a process that holds an
+    # accelerator takes the driver's teardown with it, and after a profiler
+    # capture that has outlasted 5 s on the chip.
+    KILL_WAIT_S = 5.0
+    KILL_WAIT_AGAIN_S = 30.0
+
     def _kill(self, lp: LocalProcess):
+        """SIGKILL the pod's process group and reap it. A pod that is not
+        gone in KILL_WAIT_S is killed once more, by pid, and given
+        KILL_WAIT_AGAIN_S; one that outlasts that too is logged and left
+        (the caller's thread must live: it is the manager's drain)."""
         try:
             os.killpg(os.getpgid(lp.proc.pid), signal.SIGKILL)
         except (ProcessLookupError, PermissionError):
             pass
-        lp.proc.wait(timeout=5)
+        try:
+            lp.proc.wait(timeout=self.KILL_WAIT_S)
+            return
+        except subprocess.TimeoutExpired:
+            log.warning(
+                "pod process %s (pid %d) still there %.0fs after SIGKILL; killing again",
+                lp.pod_name, lp.proc.pid, self.KILL_WAIT_S,
+            )
+        lp.proc.kill()
+        try:
+            lp.proc.wait(timeout=self.KILL_WAIT_AGAIN_S)
+        except subprocess.TimeoutExpired:
+            log.error(
+                "pod process %s (pid %d) survived SIGKILL for %.0fs; leaving it",
+                lp.pod_name, lp.proc.pid, self.KILL_WAIT_S + self.KILL_WAIT_AGAIN_S,
+            )
 
     # -- readiness ---------------------------------------------------------
 

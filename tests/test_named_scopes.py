@@ -1,0 +1,67 @@
+"""jax.named_scope in the model step (embed, attn, attn.kernel, ffn,
+lm_head, sampling, logprobs) names operations for a device trace and must
+change nothing else: the step programs lower to the same text, metadata
+aside, with the scopes and without them."""
+
+import contextlib
+import re
+
+import jax
+import pytest
+
+from kubeai_tpu.engine.coldstart import warm_compile
+from kubeai_tpu.engine.core import EngineConfig
+from kubeai_tpu.models.base import ModelConfig
+
+SCOPES = ("embed", "attn", "attn.kernel", "ffn", "lm_head", "sampling", "logprobs")
+
+
+def _lowered_programs(scoped: bool) -> list[tuple[str, str]]:
+    """(text without debug info, text with it) of every program the warm
+    compile lowers: the decode chunk, the prefill buckets, the chunked
+    prefill. Nothing is compiled."""
+    texts: list[tuple[str, str]] = []
+
+    def record(lowered, *a, **k):
+        texts.append((lowered.as_text(), lowered.as_text(debug_info=True)))
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(jax.stages.Lowered, "compile", record)
+        if not scoped:
+            m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        mc = ModelConfig(
+            vocab_size=272, hidden_size=64, intermediate_size=128, num_layers=2,
+            num_heads=4, num_kv_heads=2, dtype="float32", max_position=256,
+            # The kernel routes (XLA twins on the CPU): attn.kernel is
+            # around the call either way.
+            use_paged_kernel=True, use_flash_prefill=True,
+        )
+        cfg = EngineConfig(
+            max_slots=2, max_seq_len=64, page_size=16, prefill_buckets=(16, 32),
+            decode_chunk=2, enable_penalties=True,
+        )
+        out = warm_compile(mc, cfg, n_valid_vocab=259)
+    assert "errors" not in out, out
+    return texts
+
+
+@pytest.fixture(scope="module")
+def scoped_programs():
+    return _lowered_programs(True)
+
+
+def test_scopes_change_metadata_only(scoped_programs):
+    plain = _lowered_programs(False)
+    assert len(scoped_programs) == len(plain) >= 4  # decode, 2 buckets x 2 sizes, chunk
+    for i, ((s_text, s_debug), (p_text, p_debug)) in enumerate(zip(scoped_programs, plain)):
+        assert s_text == p_text, f"program {i}: the computation changed with the scopes"
+        assert s_debug != p_debug, f"program {i}: the scopes left no trace in the metadata"
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_every_scope_names_operations(scoped_programs, scope):
+    # A location reads loc("attn/attn.kernel/dot_general"(...)): the scope
+    # is one component of the operation's name.
+    rx = re.compile(r'["/]' + re.escape(scope) + "/")
+    for _, debug_text in scoped_programs[:2]:  # the decode chunk, a prefill
+        assert rx.search(debug_text), scope

@@ -82,13 +82,11 @@ def test_debug_requests_timeline_covers_e2e_latency(served):
     # phase/e2e comparison is about steady-state attribution.
     _post_completion(api, {"model": "m1", "prompt": "warm", "max_tokens": 4,
                            "temperature": 0}, headers={"X-Request-ID": "obs-warm"})
-    t0 = time.monotonic()
     status, body, resp_headers = _post_completion(
         api,
         {"model": "m1", "prompt": "hello trace", "max_tokens": 8, "temperature": 0},
         headers={"X-Request-ID": rid},
     )
-    e2e_ms = (time.monotonic() - t0) * 1000
     assert status == 200
     assert resp_headers.get("X-Request-ID") == rid
 
@@ -100,10 +98,6 @@ def test_debug_requests_timeline_covers_e2e_latency(served):
     # The phases partition the engine timeline...
     phase_sum = sum(p["duration_ms"] for p in tl["phases"])
     assert abs(phase_sum - tl["duration_ms"]) < 2.0
-    # ...and the engine timeline accounts for ~all of the client-visible
-    # e2e latency (the proxy adds parse/routing overhead, bounded here).
-    assert phase_sum <= e2e_ms + 2.0
-    assert phase_sum > 0.5 * e2e_ms, (phase_sum, e2e_ms)
     decode = tl["phases"][2]
     assert decode["attrs"]["tokens"] == body["usage"]["completion_tokens"]
 
@@ -113,6 +107,15 @@ def test_debug_requests_timeline_covers_e2e_latency(served):
     pnames = [p["name"] for p in ptl["phases"]]
     assert "parse" in pnames and "endpoint_pick" in pnames and "upstream" in pnames
     assert ptl["outcome"] == "ok" and ptl["attrs"]["status"] == 200
+    # ...and the engine timeline accounts for ~all of what the proxy waited
+    # for upstream (its `upstream` span of the same trace: connect, the
+    # engine's queue + prefill + decode, the body). Both spans are taken
+    # inside the program: a client's clock around the HTTP call also counts
+    # this test process's own scheduling under parallel test workers, which
+    # says nothing about the attribution.
+    upstream_ms = sum(p["duration_ms"] for p in ptl["phases"] if p["name"] == "upstream")
+    assert phase_sum <= upstream_ms + 2.0, (phase_sum, upstream_ms)
+    assert phase_sum > 0.5 * upstream_ms, (phase_sum, upstream_ms)
 
     # /debug/requests on BOTH servers serves the timeline by id.
     for port in (api.port, eng_srv.port):

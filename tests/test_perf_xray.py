@@ -120,21 +120,38 @@ class TestPerfModel:
 
 
 class TestStallTracker:
+    @staticmethod
+    def _run(tr, clock, cause, ms, **attrs):
+        with tr.segment(cause, **attrs) as seg:
+            clock.advance(ms / 1000.0)
+        return seg
+
     def test_scripted_fractions_exact(self):
         clock = FakeClock()
         tr = PipelineStallTracker(window=60.0, clock=clock)
         counter = tr._counter
         base = counter.value(labels={"cause": "fetch_wait"})
         for _ in range(10):
-            tr.record_decode(
-                dispatch_ms=1.0, host_overlap_ms=2.0,
-                fetch_wait_ms=6.0, emit_ms=1.0,
+            for cause, ms in (
+                ("dispatch", 1.0), ("host_overlap", 2.0), ("fetch_wait", 6.0), ("emit", 1.0),
+            ):
+                self._run(tr, clock, cause, ms)
+            step = tr.end_step("decode_chunk")
+            # The step record holds the same stamps the counter saw.
+            step.pop("other", None)  # the scripted second since the last chunk
+            assert step == pytest.approx(
+                {"dispatch": 1.0, "host_overlap": 2.0, "fetch_wait": 6.0, "emit": 1.0}
             )
             clock.advance(1.0)
-        tr.record_prefill("prefill_group", 10.0)
+        seg = self._run(tr, clock, "prefill", 10.0, kind="group", bucket=64, batch=2)
+        assert seg.seconds == pytest.approx(0.010) and seg.t1 - seg.t0 == seg.seconds
+        assert tr.end_step("prefill_group") == pytest.approx({"prefill": 10.0, "other": 1000.0})
         rep = tr.report()
-        assert rep["accounted_ms"] == pytest.approx(110.0)
+        # 110 ms under segments; the scripted second between chunks lies
+        # under none and is `other`.
+        assert rep["accounted_ms"] == pytest.approx(10110.0)
         causes = rep["causes"]
+        assert causes["other"]["ms"] == pytest.approx(10000.0)
         assert causes["dispatch"]["ms"] == pytest.approx(10.0)
         assert causes["host_overlap"]["ms"] == pytest.approx(20.0)
         assert causes["fetch_wait"]["ms"] == pytest.approx(60.0)
@@ -142,19 +159,60 @@ class TestStallTracker:
         assert causes["prefill"]["ms"] == pytest.approx(10.0)
         # The acceptance shape: per-cause fractions sum to ~1.0 and
         # match the scripted scenario exactly.
-        assert causes["fetch_wait"]["fraction"] == pytest.approx(60 / 110, abs=1e-3)
-        assert causes["host_overlap"]["fraction"] == pytest.approx(20 / 110, abs=1e-3)
+        assert causes["fetch_wait"]["fraction"] == pytest.approx(60 / 10110, abs=1e-4)
+        assert causes["host_overlap"]["fraction"] == pytest.approx(20 / 10110, abs=1e-4)
         assert sum(c["fraction"] for c in causes.values()) == pytest.approx(1.0, abs=1e-3)
-        assert rep["dominant_cause"] == "fetch_wait"
-        assert rep["interpretation"].startswith("55% fetch_wait")
+        assert rep["dominant_cause"] == "other"
+        assert rep["interpretation"].startswith("99% other")
         assert rep["steps"] == {"decode_chunk": 10, "prefill_group": 1}
         # The fleet-visible counter saw the same seconds.
         assert counter.value(labels={"cause": "fetch_wait"}) - base == pytest.approx(0.060)
+        # 110 ms under a named segment in a span of 10.11 s.
+        assert rep["coverage"] == pytest.approx(0.110 / 10.110, abs=1e-3)
+
+    def test_nested_segments_are_disjoint_and_cover(self):
+        """A segment opened inside another suspends the outer one: the
+        causes of one iteration are disjoint and sum to its wall time."""
+        clock = FakeClock()
+        tr = PipelineStallTracker(window=60.0, clock=clock)
+        t_begin = clock()
+        self._run(tr, clock, "sweep", 0.5)
+        with tr.segment("admit") as admit:
+            clock.advance(0.002)
+            self._run(tr, clock, "kv_transfer", 3.0, tokens=40)
+            clock.advance(0.001)
+            inner = self._run(tr, clock, "prefill", 20.0, kind="chunk", cached=128)
+            clock.advance(0.001)
+        self._run(tr, clock, "dispatch", 1.5, active=2, steps=8)
+        self._run(tr, clock, "idle", 50.0)
+        wall_ms = (clock() - t_begin) * 1000
+        step = tr.end_step("decode_chunk")
+        assert step == pytest.approx(
+            {"sweep": 0.5, "admit": 4.0, "kv_transfer": 3.0, "prefill": 20.0,
+             "dispatch": 1.5, "idle": 50.0, "other": 0.0}
+        )
+        assert sum(step.values()) == pytest.approx(wall_ms)
+        # The outer segment's own stamps still span the inner ones.
+        assert admit.seconds == pytest.approx(0.027) and inner.seconds == pytest.approx(0.020)
+        rep = tr.report()
+        assert rep["accounted_ms"] == pytest.approx(wall_ms)
+        assert rep["coverage"] == pytest.approx(1.0)
+        assert tr._stack == []
+
+    def test_segment_closes_on_exception(self):
+        clock = FakeClock()
+        tr = PipelineStallTracker(window=60.0, clock=clock)
+        with pytest.raises(RuntimeError):
+            with tr.segment("sweep"):
+                clock.advance(0.004)
+                raise RuntimeError("injected")
+        assert tr._stack == []
+        assert tr.end_step("decode_chunk") == pytest.approx({"sweep": 4.0})
 
     def test_window_prunes(self):
         clock = FakeClock()
         tr = PipelineStallTracker(window=30.0, clock=clock)
-        tr.record_decode(1.0, 1.0, 1.0, 1.0)
+        self._run(tr, clock, "emit", 1.0)
         clock.advance(31.0)
         assert tr.report()["accounted_ms"] == 0.0
         assert "dominant_cause" not in tr.report()

@@ -790,11 +790,17 @@ class TestProxyE2E:
             except Exception as e:
                 errs.append(e)
 
+        gen_before = counter("kubeai_engine_generated_tokens_total")
         t = threading.Thread(target=run_batch, daemon=True)
         t.start()
+        # MID-decode: the slot is taken and tokens are flowing, so the
+        # proxy has delivered events before the seizure (an arrival in the
+        # instant after admission preempts a stream that has delivered
+        # none, and the span below would carry a cursor of 0).
         await_cond(
-            lambda: counter("kubeai_engine_active_slots") >= 1,
-            msg="batch stream occupying the slot",
+            lambda: counter("kubeai_engine_active_slots") >= 1
+            and counter("kubeai_engine_generated_tokens_total") >= gen_before + 8,
+            msg="batch stream occupying the slot and decoding",
         )
         shape = sse_post(
             api.port, dict(body, prompt="quick question", max_tokens=4),
