@@ -293,7 +293,7 @@ def warm_compile(
         params, cache, sds((B, max_pages), i32), sds((B, hist_width), i32),
         sds((B,), i32), sds((B,), i32), keys,
         sds((B,), jnp.bool_), sds((B,), f32), sds((B,), f32), sds((B,), i32),
-        sds((B,), f32), sds((B,), f32), sds((B,), i32),
+        sds((B,), f32), sds((B,), f32), sds((B,), jnp.bool_), sds((B,), i32),
         sds((B, Kb), i32), sds((B, Kb), f32),
         sds((B,), jnp.bool_), sds((B,), i32), sds((B,), u32), sds((B,), i32),
         **adm_hist_kw,

@@ -44,9 +44,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubeai_tpu.engine.sampling import (
+    EPILOGUE_PARTS,
     SamplingParams,
     apply_logit_bias,
     apply_penalties,
+    epilogue_parts,
     sample,
 )
 from kubeai_tpu import faults
@@ -166,11 +168,6 @@ class EngineConfig:
     # "fp8"/"int8" quantize the paged pool (see ModelConfig.kv_cache_dtype
     # — halves KV HBM, doubling the slot ceiling on a 16GB chip).
     kv_cache_dtype: str = ""
-    # OpenAI presence/frequency penalties, computed in-graph from the
-    # token history. On by default (the API accepts the params, so
-    # silently ignoring them would be worse than the ~two fused [B, V]
-    # temporaries per decode step the shared graph costs).
-    enable_penalties: bool = True
     # Decode-path paged-attention kernel: "ragged" keeps the shared
     # ragged kernel (prefill-tuned grid) on the decode step, "dedicated"
     # uses ops/paged_decode_attention (S=1/G+1-specialized blocking:
@@ -189,10 +186,11 @@ class EngineConfig:
     # callers). Static shape — the [B, K] bias arrays ride every decode
     # dispatch regardless of use (~2.4 KB/slot at 300).
     max_logit_bias: int = 300
-    # Top-N alternative logprobs computed per choice point (one extra
-    # lax.top_k over the vocab per step — same order of work as the
-    # sampler's candidate top_k). Requests can ask for at most this many
-    # (OpenAI caps completions logprobs at 5, chat top_logprobs at 20).
+    # Top-N alternative logprobs per choice point: one lax.top_k over
+    # the vocab, in decode only in the chunks where a slot asked for
+    # logprobs (a fifth of a step at a 152k vocabulary otherwise).
+    # Requests can ask for at most this many (OpenAI caps completions
+    # logprobs at 5, chat top_logprobs at 20).
     top_logprobs_k: int = 5
 
 
@@ -537,6 +535,12 @@ class Engine:
             "fused decode slot-steps by state; batch utilization = "
             "active / (active + idle)",
         )
+        self.m_epilogue = default_registry.counter(
+            "kubeai_engine_decode_epilogue_chunks_total",
+            "dispatched decode chunks by optional part of the step's epilogue "
+            "(top_logprobs | candidates | penalties) and whether an active "
+            "slot asked for it (ran=1: the chunk computed it)",
+        )
         self.m_pad_prefill = default_registry.counter(
             "kubeai_engine_prefill_padded_tokens_total",
             "prompt positions computed as bucket/batch padding (prefill waste; "
@@ -785,6 +789,10 @@ class Engine:
         self._h_top_k = np.zeros((B,), np.int32)
         self._h_presence = np.zeros((B,), np.float32)
         self._h_freq = np.zeros((B,), np.float32)
+        # SamplingParams.logprobs per slot: with active/temp/presence/
+        # freq it decides which optional parts of the decode epilogue a
+        # chunk runs (sampling.epilogue_parts, on both sides).
+        self._h_want_top = np.zeros((B,), bool)
         # First generated position per slot (= prompt length): the
         # penalty window over the device token history is
         # [gen_start, lengths) — generated tokens only.
@@ -930,6 +938,7 @@ class Engine:
             self._lengths, self._last_tokens, self._keys,
             self._h_active.copy(), self._h_temp.copy(), self._h_top_p.copy(),
             self._h_top_k.copy(), self._h_presence.copy(), self._h_freq.copy(),
+            self._h_want_top.copy(),
             self._h_gen_start.copy(), self._h_bias_ids.copy(),
             self._h_bias_vals.copy(), self._adm_mask.copy(),
             self._adm_len.copy(), self._adm_seed.copy(), self._adm_toks,
@@ -1639,7 +1648,7 @@ class Engine:
                     self.params, self._cache, ar["tables"], self._tok_hist,
                     self._lengths, self._last_tokens, self._keys,
                     ar["active"], ar["temp"], ar["top_p"], ar["top_k"],
-                    ar["presence"], ar["freq"], ar["gen_start"],
+                    ar["presence"], ar["freq"], ar["want_top"], ar["gen_start"],
                     ar["bias_ids"], ar["bias_vals"],
                     ar["adm_mask"], ar["adm_len"], ar["adm_seed"],
                     self._adm_toks, **adm_hist, **lora_args,
@@ -2411,6 +2420,7 @@ class Engine:
         self._h_top_k[slot_idx] = sp.top_k
         self._h_presence[slot_idx] = sp.presence_penalty
         self._h_freq[slot_idx] = sp.frequency_penalty
+        self._h_want_top[slot_idx] = bool(sp.logprobs)
         self._h_gen_start[slot_idx] = len(ids)
         self._h_bias_ids[slot_idx], self._h_bias_vals[slot_idx] = self._bias_rows(sp)
         self._h_lora_rows[slot_idx] = lora_row
@@ -2567,7 +2577,8 @@ class Engine:
                 "tables": self._page_table, "active": self._h_active,
                 "temp": self._h_temp, "top_p": self._h_top_p,
                 "top_k": self._h_top_k, "presence": self._h_presence,
-                "freq": self._h_freq, "gen_start": self._h_gen_start,
+                "freq": self._h_freq, "want_top": self._h_want_top,
+                "gen_start": self._h_gen_start,
                 "bias_ids": self._h_bias_ids, "bias_vals": self._h_bias_vals,
                 "adm_mask": self._adm_mask,
                 "adm_len": self._adm_len, "adm_seed": self._adm_seed,
@@ -2592,6 +2603,7 @@ class Engine:
                 self._h_top_k.copy(),
                 self._h_presence.copy(),
                 self._h_freq.copy(),
+                self._h_want_top.copy(),
                 self._h_gen_start.copy(),
                 self._h_bias_ids.copy(),
                 self._h_bias_vals.copy(),
@@ -2606,22 +2618,29 @@ class Engine:
         snapshot = [
             (i, s, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
-        return (d_seq, c_seq, a_seq, lpd_seq, lpc_seq, tid_seq, tlp_seq), snapshot
+        # The program's own predicates, on the arrays it was just given:
+        # which optional parts of the epilogue this chunk ran.
+        ran = epilogue_parts(
+            self._h_active, self._h_temp, self._h_presence, self._h_freq,
+            self._h_want_top,
+        )
+        for part, r in zip(EPILOGUE_PARTS, ran):
+            self.m_epilogue.inc(labels={"part": part, "ran": "1" if r else "0"})
+        payload = (d_seq, c_seq, a_seq, lpd_seq, lpc_seq)
+        if ran[0]:
+            # Only then do the top-N arrays hold anything (zeros
+            # otherwise): a chunk that ran without them never hands
+            # them to the host.
+            payload += (tid_seq, tlp_seq)
+        return payload, snapshot
 
     def _process_chunk(self, payload, snapshot, dispatched):
-        # The top-N alternative arrays are fetched only when some slot in
-        # this chunk's snapshot asked for logprobs: the device compute is
-        # part of the static graph either way, but the host transfer
-        # (~hundreds of KB per chunk at high slots) is gateable.
-        any_top = any(
-            s_obj.req.params.logprobs for _, s_obj, _ in snapshot
-        )
+        # The top-N alternative arrays are in the payload only when some
+        # slot active at the dispatch asked for logprobs: only then did
+        # the device compute them (_dispatch_chunk_call).
         with self._stall.segment("fetch_wait", of="chunk") as fetched:  # device_get blocks
-            if any_top:
-                drafts, corr, acc, lp_d, lp_c, t_ids, t_lp = jax.device_get(payload)
-            else:
-                drafts, corr, acc, lp_d, lp_c = jax.device_get(payload[:5])
-                t_ids = t_lp = None
+            drafts, corr, acc, lp_d, lp_c, *top = jax.device_get(payload)
+            t_ids, t_lp = top or (None, None)
         # The chunk's turnaround: dispatch call returned -> results on the host.
         dur = fetched.t1 - dispatched.t1
         acc = np.asarray(acc)  # [K, B]
@@ -3181,6 +3200,7 @@ class Engine:
             self._h_top_k[slot_idx] = sp.top_k
             self._h_presence[slot_idx] = sp.presence_penalty
             self._h_freq[slot_idx] = sp.frequency_penalty
+            self._h_want_top[slot_idx] = bool(sp.logprobs)
             self._h_gen_start[slot_idx] = len(ids)
             self._h_bias_ids[slot_idx], self._h_bias_vals[slot_idx] = self._bias_rows(sp)
             self._h_lora_rows[slot_idx] = lora_row
@@ -3367,8 +3387,6 @@ def build_step_functions(
 
         return jax.vmap(one)(hist, lengths, last)
 
-    penalties_on = cfg.enable_penalties
-
     def make_decode_fn(decode_kernel: str):
         """Decode step builder, parameterized by the CONCRETE paged-
         attention kernel ("ragged" | "dedicated") baked into the
@@ -3378,7 +3396,7 @@ def build_step_functions(
         (gang lockstep: all ranks must run the same program)."""
         return partial(decode_fn, _decode_kernel=decode_kernel)
 
-    def decode_fn(params, cache, tables, hist, lengths, last_tokens, keys, active, temp, top_p, top_k, presence, frequency, gen_start, bias_ids, bias_vals, adm_mask, adm_len, adm_seed, adm_toks, adm_hist=None, lora=None, lora_rows=None, _decode_kernel="ragged"):
+    def decode_fn(params, cache, tables, hist, lengths, last_tokens, keys, active, temp, top_p, top_k, presence, frequency, want_top, gen_start, bias_ids, bias_vals, adm_mask, adm_len, adm_seed, adm_toks, adm_hist=None, lora=None, lora_rows=None, _decode_kernel="ragged"):
         """K fused decode steps, each verifying up to G drafts.
         Returns (drafts [K, B, G], corr [K, B], accepted [K, B]) —
         the host emits drafts[:a] + [corr] per slot per step, where
@@ -3392,8 +3410,22 @@ def build_step_functions(
         (adm_mask/adm_len/adm_seed numpy from the host; adm_toks the
         device staging vector the prefill scattered its sample into)
         — admission therefore requires zero eager device mutation
-        and the dispatch never waits on a first-token host sync."""
+        and the dispatch never waits on a first-token host sync.
+
+        The optional parts of the epilogue (top-N alternatives, sampling
+        candidates, penalties) each run under a `lax.cond` on whether an
+        active slot of THIS batch asked for them (epilogue_parts): one
+        program, both branches, so what nobody reads is not computed
+        and what somebody reads is bit for bit what it always was. A
+        part that did not run returns what it gives when nobody asks:
+        the greedy pick, the unpenalized logits; the top-N arrays are
+        zeros then, which the host never fetches."""
         B = lengths.shape[0]
+        # Scalars of the batch's request parameters, none of them
+        # carried: the same for all K steps, computed once out here.
+        run_top, run_cand, run_pen = epilogue_parts(
+            active, temp, presence, frequency, want_top
+        )
         adm_keys = jax.vmap(
             lambda s: jax.random.fold_in(jax.random.key(s), 1)
         )(adm_seed)
@@ -3437,7 +3469,8 @@ def build_step_functions(
             )
             with jax.named_scope("sampling"):
                 logits = mask_pad(logits)  # [B, G+1, V]
-                if penalties_on:
+
+                def penalized():
                     # OpenAI presence/frequency penalties over the
                     # GENERATED window of the device token history —
                     # [gen_start, lengths] INCLUSIVE: position `lengths`
@@ -3456,11 +3489,13 @@ def build_step_functions(
                     pen_valid = (w_idx >= gen_start[:, None]) & (
                         w_idx <= lengths[:, None]
                     )
-                    pen0 = apply_penalties(
+                    return apply_penalties(
                         logits[:, 0], hist, pen_valid, presence, frequency
                     )
-                else:
-                    pen0 = logits[:, 0]
+
+                # No active slot set a penalty: subtracting zeros gives
+                # the logits back, so the scatters are left out.
+                pen0 = jax.lax.cond(run_pen, penalized, lambda: logits[:, 0])
                 pen0 = apply_logit_bias(pen0, bias_ids, bias_vals)
             with jax.named_scope("logprobs"):
                 # Chosen-token logprob = raw logit - logsumexp: avoids
@@ -3490,9 +3525,18 @@ def build_step_functions(
                     acc = jnp.where(greedy & active & no_pen, acc, 0)
                 else:
                     acc = jnp.zeros((B,), jnp.int32)
+                # The keys split every step, whatever the batch holds:
+                # a seeded sampled request draws the same stream whether
+                # or not its neighbours open the gate around it.
                 step_keys = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-                sampled0 = sample(
-                    pen0, step_keys[:, 0], temp, top_p, top_k, max_top_k=mtk
+                # Every active slot greedy: `sample` would return the
+                # argmax of pen0 for each, which is yhat0_pen.
+                sampled0 = jax.lax.cond(
+                    run_cand,
+                    lambda: sample(
+                        pen0, step_keys[:, 0], temp, top_p, top_k, max_top_k=mtk
+                    ),
+                    lambda: yhat0_pen,
                 )
                 # Greedy: position 0 picks from the penalized view
                 # (identical to raw when penalties are zero); accepted-
@@ -3520,14 +3564,31 @@ def build_step_functions(
                     jnp.take_along_axis(logits_at_a, corr[:, None], axis=1)[:, 0]
                     - jnp.take_along_axis(lse, acc[:, None], axis=1)[:, 0]
                 )
-                # Top-N alternatives per position (raw model dist, pre-
-                # penalty/bias — same contract as the chosen logprob).
-                t_raw, t_ids = jax.lax.top_k(logits, topn)  # [B, G+1, N]
-                t_lp = t_raw - lse[..., None]
+
+                def top_alternatives():
+                    # Top-N alternatives per position (raw model dist,
+                    # pre-penalty/bias — same contract as the chosen
+                    # logprob), as top_k of the 2-D view: on the chip a
+                    # [B, G+1, V] operand lowers to a sort of the whole
+                    # vocabulary, a [B*(G+1), V] one to the TopK custom
+                    # call (same values and ids, ties to the lower id).
+                    t_raw, t_ids = jax.lax.top_k(
+                        logits.reshape(B * (G + 1), -1), topn
+                    )
+                    t_lp = t_raw.reshape(B, G + 1, topn) - lse[..., None]
+                    return t_ids.reshape(B, G + 1, topn).astype(jnp.int32), t_lp
+
+                t_ids, t_lp = jax.lax.cond(
+                    run_top,
+                    top_alternatives,
+                    lambda: (
+                        jnp.zeros((B, G + 1, topn), jnp.int32),
+                        jnp.zeros((B, G + 1, topn), jnp.float32),
+                    ),
+                )
             lengths = jnp.where(active, lengths + acc + 1, lengths)
             return (cache, hist, lengths, corr, step_keys[:, 1]), (
-                drafts, corr, acc, lp_d, lp_corr,
-                t_ids.astype(jnp.int32), t_lp,
+                drafts, corr, acc, lp_d, lp_corr, t_ids, t_lp,
             )
 
         (cache, hist, lengths, last, keys), (

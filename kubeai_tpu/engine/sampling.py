@@ -6,8 +6,12 @@ is a single jitted kernel with no host branching. Greedy is temperature=0.
 Everything here traces under ``jax.named_scope("sampling")``: the name a
 device trace files these operations' time under.
 
-top-k uses `lax.top_k` with a static MAX_TOP_K (full-vocab sort would
-serialize the TPU); requests asking for larger k are clamped.
+top-k uses `lax.top_k` with a static MAX_TOP_K; requests asking for
+larger k are clamped. On a v5e the 2-D top-128 of a [32, 152064] batch is
+a TopK custom call of 1.8 ms, and a `top_k` the compiler lowers to a
+full-vocabulary sort costs 6.2 ms (PERF.md section 5): neither is free,
+so the decode step runs each optional part of its epilogue only when a
+slot of the batch asked for it (`epilogue_parts`).
 """
 
 from __future__ import annotations
@@ -18,6 +22,11 @@ import jax
 import jax.numpy as jnp
 
 MAX_TOP_K = 128
+
+# The optional parts of the decode step's epilogue, in the order of
+# `epilogue_parts`' result; the `part` label of
+# kubeai_engine_decode_epilogue_chunks_total.
+EPILOGUE_PARTS = ("top_logprobs", "candidates", "penalties")
 
 
 @dataclass
@@ -43,6 +52,22 @@ class SamplingParams:
     logit_bias: tuple = ()
 
 
+def epilogue_parts(active, temperature, presence, frequency, want_top):
+    """Which optional parts of the decode epilogue a batch needs, as
+    three scalar bools in EPILOGUE_PARTS' order: the top-N log-prob
+    alternatives, the sampling candidates, the penalties. Each holds if
+    some ACTIVE slot asked for it; what an idle slot's stale parameters
+    say decides nothing. One statement for both sides: the decode
+    program calls it on its traced [B] inputs (the predicates of its
+    three `lax.cond`s), the host on the numpy arrays it uploads with the
+    same dispatch (what it fetches, and the counter)."""
+    return (
+        (active & want_top).any(),
+        (active & ~(temperature <= 0.0)).any(),  # not greedy, as the step reads it
+        (active & ((presence != 0.0) | (frequency != 0.0))).any(),
+    )
+
+
 def apply_penalties(
     logits: jnp.ndarray,  # [B, V] float32
     hist: jnp.ndarray,  # [B, W] int32 token ids (engine token history)
@@ -55,8 +80,10 @@ def apply_penalties(
     carry/donate): scatter-max builds the appeared-at-all flag, scatter-
     add the occurrence counts — duplicate history entries accumulate
     exactly count * frequency. Rows with both penalties zero subtract
-    zeros (the compiled graph is shared; the two [B, V] temporaries are
-    ~50 MB of fused traffic per call, noise next to the weight reads)."""
+    zeros. The two [B, V] scatters are not noise next to the weight
+    reads: 3.9% of a decode step at a 152k vocabulary and 32 slots, 4.9%
+    at 32k and 8 (PERF.md section 5), so the decode step calls this only
+    when a slot of the batch set a penalty (`epilogue_parts`)."""
     B, V = logits.shape
     with jax.named_scope("sampling"):
         b_idx = jnp.arange(B)[:, None]

@@ -1,0 +1,121 @@
+"""The decode program's structure: every `top_k` / `sort` and both penalty
+scatters sit inside a `lax.cond` of the scan's body, three in all, each on
+a scalar predicate (a `vmap` over a batched one would have left a select
+and both branches); and the host's predicates, which feed the counter and
+decide what is fetched, are the device's."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kubeai_tpu.engine.coldstart import warm_compile
+from kubeai_tpu.engine.core import EngineConfig
+from kubeai_tpu.engine.sampling import EPILOGUE_PARTS, epilogue_parts
+from kubeai_tpu.models.base import ModelConfig
+
+B, V = 2, 272
+
+
+def _decode_jaxpr(speculate: int):
+    """The jaxpr of the decode chunk as the warm compile traces it (the
+    first program it lowers); nothing is compiled."""
+    traced = []
+    lower = jax.stages.Traced.lower
+
+    def record(self, *a, **k):
+        traced.append(self.jaxpr)
+        return lower(self, *a, **k)
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(jax.stages.Traced, "lower", record)
+        m.setattr(jax.stages.Lowered, "compile", lambda self, *a, **k: None)
+        mc = ModelConfig(
+            vocab_size=V, hidden_size=64, intermediate_size=128, num_layers=2,
+            num_heads=4, num_kv_heads=2, dtype="float32", max_position=256,
+            use_paged_kernel=True, use_flash_prefill=True,
+        )
+        cfg = EngineConfig(
+            max_slots=B, max_seq_len=64, page_size=16, prefill_buckets=(16, 32),
+            decode_chunk=2, speculate_tokens=speculate,
+        )
+        out = warm_compile(mc, cfg, n_valid_vocab=259)
+    assert "errors" not in out, out
+    return traced[0]
+
+
+def _walk(jaxpr, path=()):
+    """(names of the enclosing primitives, equation) of every equation,
+    nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield path, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub, path + (eqn.primitive.name,))
+
+
+@pytest.mark.parametrize("speculate", [0, 2], ids=["plain", "speculate2"])
+def test_the_optional_epilogue_sits_under_three_conds_in_the_scan_body(speculate):
+    eqns = list(_walk(_decode_jaxpr(speculate).jaxpr))
+    shape = lambda eqn: tuple(eqn.outvars[0].aval.shape)  # noqa: E731
+
+    conds = [(path, e) for path, e in eqns if e.primitive.name == "cond"]
+    assert [path for path, _ in conds] == [("scan",)] * 3
+    for _, e in conds:
+        assert e.invars[0].aval.shape == () and len(e.params["branches"]) == 2
+    # In the order the body runs them: penalties -> the [B, V] logits the
+    # choice is made from; candidates -> [B] tokens; alternatives -> ids
+    # and log-probs [B, G+1, 5].
+    assert [shape(e) for _, e in conds] == [(B, V), (B,), (B, speculate + 1, 5)]
+
+    sorts = [(path, e) for path, e in eqns if e.primitive.name in ("top_k", "sort")]
+    assert [path for path, _ in sorts] == [("scan", "cond")] * 2
+    # top-128 candidates; the top-5 over the 2-D view of the positions.
+    assert [shape(e) for _, e in sorts] == [(B, 128), (B * (speculate + 1), 5)]
+
+    # Scatters that build a [B, V] array: the penalties' two, inside their
+    # cond; the logit bias, outside (it stays: it is cheap and always read).
+    vocab_scatters = [
+        (path, e.primitive.name) for path, e in eqns
+        if e.primitive.name.startswith("scatter") and shape(e) == (B, V)
+    ]
+    assert sorted(vocab_scatters) == [
+        (("scan",), "scatter-add"),
+        (("scan", "cond"), "scatter-add"),
+        (("scan", "cond"), "scatter-max"),
+    ]
+
+
+def _random_batch(seed: int, n: int = 16):
+    """active, temperature, presence, frequency, want_top of *n* slots:
+    sparse enough that every part comes out both ways over a few seeds."""
+    rng = np.random.default_rng(seed)
+    sparse = lambda p, values: np.where(rng.random(n) < p, values, 0.0).astype(np.float32)  # noqa: E731
+    return (
+        rng.random(n) < 0.3, sparse(0.15, rng.random(n)), sparse(0.1, rng.normal(size=n)),
+        sparse(0.1, rng.normal(size=n)), rng.random(n) < 0.15,
+    )
+
+
+SEEDS = range(8)
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_host_and_device_state_the_same_predicates(seed):
+    batch = _random_batch(seed)
+    host = [bool(r) for r in epilogue_parts(*batch)]
+    device = [bool(r) for r in jax.jit(epilogue_parts)(*(jnp.asarray(a) for a in batch))]
+    assert host == device and len(host) == len(EPILOGUE_PARTS)
+    # An idle slot's stale parameters decide nothing.
+    active, temp, presence, frequency, want_top = batch
+    assert host == [
+        bool(want_top[active].any()), bool((temp[active] > 0).any()),
+        bool(((presence[active] != 0) | (frequency[active] != 0)).any()),
+    ]
+
+
+def test_the_seeds_open_and_shut_every_gate():
+    seen = {(i, bool(r)) for seed in SEEDS for i, r in enumerate(epilogue_parts(*_random_batch(seed)))}
+    assert len(seen) == 2 * len(EPILOGUE_PARTS)

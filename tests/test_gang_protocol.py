@@ -457,11 +457,13 @@ def test_decode_kernel_flag_rides_broadcast():
         publisher=pub,
     )
     seen: list[dict] = []
+    seen_arrays: list[dict] = []
     real_publish = pub.publish
 
     def spying_publish(op, scalars=None, arrays=None):
         if op == "decode":
             seen.append(dict(scalars or {}))
+            seen_arrays.append({k: (v.dtype, v.shape) for k, v in arrays.items()})
         real_publish(op, scalars, arrays)
 
     pub.publish = spying_publish
@@ -477,6 +479,12 @@ def test_decode_kernel_flag_rides_broadcast():
         # Every decode broadcast carried the resolved flavor.
         assert seen, "no decode op was broadcast"
         assert all(sc.get("decode_kernel") == "dedicated" for sc in seen), seen
+        # And the per-slot request parameters the program's epilogue
+        # gates branch on, want_top among them: a follower that lacked
+        # one would run another branch than rank 0.
+        for arrays in seen_arrays:
+            assert {"active", "temp", "presence", "freq", "want_top"} <= set(arrays)
+            assert arrays["want_top"] == (np.dtype(bool), (4,))
         # The follower honored the payload over its own config: it
         # compiled the dedicated flavor while its local resolution (and
         # local jit) remain ragged.

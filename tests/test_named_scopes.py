@@ -38,7 +38,7 @@ def _lowered_programs(scoped: bool) -> list[tuple[str, str]]:
         )
         cfg = EngineConfig(
             max_slots=2, max_seq_len=64, page_size=16, prefill_buckets=(16, 32),
-            decode_chunk=2, enable_penalties=True,
+            decode_chunk=2,
         )
         out = warm_compile(mc, cfg, n_valid_vocab=259)
     assert "errors" not in out, out
