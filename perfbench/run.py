@@ -18,7 +18,9 @@ that needs it is a child that has ended before the next starts):
     edges and once a second; a compile inside it fails the run;
  5. the probes again, the engine's own account of its device and memory;
  6. stop the operator, see every pod gone;
- 7. kernel-route logits against the float32 portable route, in a child;
+ 7. the logits check of the configuration's family (perfbench/families/:
+    for the dense decoder, kernel-route logits against the float32
+    portable route), in a child;
  8. with --trace 1: reduce the trace the ENGINE took of itself
     (/debug/profile) in a child on the CPU backend;
  9. check the result against BENCHMARK.json (resultline.py) and print it,
@@ -189,11 +191,15 @@ class Run:
 
     # -- phases ------------------------------------------------------------
 
+    def work_file(self, name: str, obj) -> str:
+        path = os.path.join(self.workdir, name)
+        with open(path, "w") as f:
+            json.dump(obj, f)
+        return path
+
     def phase_checkpoint(self) -> str:
         path = os.path.join(self.workdir, "ckpt")
-        hf_path = os.path.join(self.workdir, "hf_config.json")
-        with open(hf_path, "w") as f:
-            json.dump(self.hf, f)
+        hf_path = self.work_file("hf_config.json", self.hf)
         t = time.monotonic()
         out = self.run_child("checkpoint", path, hf_path, str(self.args.seed), platform="cpu", timeout=600)
         emit("checkpoint", seconds=time.monotonic() - t, **out)
@@ -493,17 +499,17 @@ class Run:
         return out
 
     def phase_logits(self, ckpt: str) -> dict:
-        """The first layers of the same checkpoint, through the same loader."""
+        """The family's logits check (perfbench/families/) on the same seed's
+        checkpoint at `logits_check_layers` layers, through the same loader:
+        the full checkpoint's shards where the family's plan for the shallower
+        model is the same, written anew where a layer depends on the depth."""
         depth = min(self.serving["logits_check_layers"], self.hf["num_hidden_layers"])
         shallow = os.path.join(self.workdir, f"ckpt-{depth}-layers")
-        os.makedirs(shallow, exist_ok=True)
-        for name in os.listdir(ckpt):
-            m = re.match(r"model-layer-(\d+)\.safetensors$", name)
-            if name.endswith(".safetensors") and (m is None or int(m.group(1)) < depth):
-                os.symlink(os.path.join(ckpt, name), os.path.join(shallow, name))
-        with open(os.path.join(shallow, "config.json"), "w") as f:
-            json.dump({**self.hf, "num_hidden_layers": depth}, f)
-        return self.run_child("logits", shallow, str(self.args.seed), platform=self.platform, timeout=900)
+        hf_path = self.work_file("hf_config_cut.json", {**self.hf, "num_hidden_layers": depth})
+        serving_path = self.work_file("serving.json", self.serving)
+        cut = self.run_child("checkpoint", shallow, hf_path, str(self.args.seed), ckpt, platform="cpu", timeout=600)
+        out = self.run_child("logits", shallow, str(self.args.seed), serving_path, platform=self.platform, timeout=900)
+        return {"cut": cut, **out}
 
     def phase_trace(self, ctx: Context, box: dict, device: dict, obj: dict, pipeline: dict) -> None:
         if "result" not in box:

@@ -12,21 +12,35 @@
    the same arrival span, in another order, and takes seeds past 2**31;
  * resultline.py refuses each way a last line went wrong before;
  * BENCHMARK.json: every name resolves to a file, every metric's `moves`
-   is reported where the metric is.
+   is reported where the metric is;
+ * families/: same seed, same bytes (the shards of both dense
+   configurations against the digests the harness wrote before the plan
+   moved into families/, testdata/checkpoint-digests.json); every
+   configuration has a family file with the three names; the dense
+   decoder's shallow cut is the first shards of its full checkpoint; and a
+   family is added by files alone (a copy of the benchmark, a toy family
+   whose layers depend on the depth, run.py's own checkpoint and logits
+   phases, no file of the copy changed).
 
 Not part of tests/ (this PR may add files only under perfbench/).
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
+import shutil
+import subprocess
 import sys
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
+import children  # noqa: E402
 import resultline  # noqa: E402
+import run  # noqa: E402
 import trace_reduce as tr  # noqa: E402
 import traffic  # noqa: E402
 
@@ -171,11 +185,181 @@ def benchmark_file() -> None:
               all(c in moved.get("workloads", cells) for c in where))
 
 
+def sha256(path: str) -> str:
+    with open(path, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def shards(path: str) -> dict[str, str]:
+    return {n: sha256(os.path.join(path, n)) for n in sorted(os.listdir(path)) if n.endswith(".safetensors")}
+
+
+def hf_keys(config: dict, rehearsal: bool) -> dict:
+    """The published keys of a configuration file, as run.py reads them."""
+    hf = {k: v for k, v in config.items() if k not in run.NOT_HF_KEYS}
+    if rehearsal:
+        hf.update(config["rehearsal"]["hf_overrides"])
+    return hf
+
+
+def families(tmp: str) -> None:
+    bench = resultline.load_benchmark()
+    configs = {}
+    for c in bench["configs"]:
+        with open(os.path.join(resultline.ROOT, c["file"])) as f:
+            configs[c["name"]] = json.load(f)
+        family = children.family_of(hf_keys(configs[c["name"]], False))
+        check(f"{c['name']}: a family file with the three names",
+              all(callable(getattr(family, n, None)) for n in ("layer_plan", "outside_plan", "logits")), family)
+    with open(os.path.join(HERE, "testdata", "checkpoint-digests.json")) as f:
+        digests = json.load(f)
+    for key, want in digests.items():
+        name, seed = key.split("/")
+        hf = hf_keys(configs[name], True)
+        hf_path = os.path.join(tmp, "hf.json")
+        with open(hf_path, "w") as f:
+            json.dump(hf, f)
+        full = os.path.join(tmp, f"{name}-{seed}")
+        report = children.child_checkpoint(full, hf_path, seed)
+        check(f"{key}: same seed, same bytes", shards(full) == want["sha256"] and report == want["report"], report)
+        # The shallow cut: what the plan gives at that depth from the same
+        # seed, which for the dense decoder is the full checkpoint's first shards.
+        depth = configs[name]["rehearsal"]["logits_check_layers"]
+        with open(hf_path, "w") as f:
+            json.dump({**hf, "num_hidden_layers": depth}, f)
+        cut, anew = full + "-cut", full + "-anew"
+        report = children.child_checkpoint(cut, hf_path, seed, full)
+        names = ["model-outside-layers.safetensors"] + [f"model-layer-{i:03d}.safetensors" for i in range(depth)]
+        first = {n: want["sha256"][n] for n in sorted(names)}
+        check(f"{key}: the cut links the first shards", report == {"bytes": 0, "shards": depth + 1, "linked": depth + 1}
+              and all(os.path.islink(os.path.join(cut, n)) for n in first) and shards(cut) == first, report)
+        children.child_checkpoint(anew, hf_path, seed)
+        check(f"{key}: and they are what the plan writes at that depth", shards(anew) == first)
+
+
+TOY_FAMILY = '''"""A family for the selftest: two tensors a layer, and what layer i is
+depends on the depth; a logits check that reports what it was given."""
+import json, os
+from safetensors import safe_open
+
+
+def layer_plan(hf, i):
+    kind = "lower" if i < hf["num_hidden_layers"] // 2 else "upper"
+    p = f"model.layers.{i}."
+    return [(p + "norm.weight", (hf["hidden_size"],), None), (p + kind + ".weight", (hf["hidden_size"], 3), 0.5)]
+
+
+def outside_plan(hf):
+    return [("model.embed_tokens.weight", (hf["vocab_size"], hf["hidden_size"]), 0.02)]
+
+
+def logits(path, seed, serving):
+    names = {}
+    for n in sorted(os.listdir(path)):
+        if n.endswith(".safetensors"):
+            with safe_open(os.path.join(path, n), "np") as f:
+                names[n] = sorted(f.keys())
+    with open(os.path.join(path, "config.json")) as f:
+        depth = json.load(f)["num_hidden_layers"]
+    return {"ok": True, "platform": "cpu", "compared": {"toy": {"max_abs": 0.0}}, "tensors": names,
+            "depth": depth, "seed": seed, "model_name": serving["model_name"]}
+'''
+
+DRIVE = """
+import argparse, json, os, sys
+sys.path.insert(0, "perfbench")
+import run
+r = run.Run(argparse.Namespace(workload=sys.argv[1], seed=2**31 + 9, rehearse=True, trace=0, seconds=1, keep=True))
+os.makedirs(r.workdir)
+try:
+    ckpt = r.phase_checkpoint()
+    print(json.dumps({"ckpt": ckpt, "logits": r.phase_logits(ckpt)}))
+except run.RunFailure as e:
+    print(json.dumps({"failed": str(e)}))
+"""
+
+
+def tree(root: str) -> dict[str, str]:
+    return {
+        os.path.relpath(os.path.join(d, n), root): sha256(os.path.join(d, n))
+        for d, _, names in os.walk(root) if "__pycache__" not in d and ".perfbench_work" not in d for n in names
+    }
+
+
+def by_files_alone(tmp: str) -> None:
+    """A configuration of a new family, brought as a later PR may bring it:
+    new files under perfbench/ and entries appended to BENCHMARK.json."""
+    from safetensors import safe_open
+
+    def tensors(*path: str) -> list[str]:
+        with safe_open(os.path.join(*path), "np") as f:
+            return sorted(f.keys())
+
+    copy = os.path.join(tmp, "copy")
+    shutil.copytree(HERE, os.path.join(copy, "perfbench"), ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(resultline.ROOT, "BENCHMARK.json"), copy)
+    before = tree(copy)
+    bench = resultline.load_benchmark(copy)
+    serving = {"model_name": "toy", "logits_check_layers": 2}
+    added = {"perfbench/families/toy.py": TOY_FAMILY}
+    for name in ("toy", "orphan"):  # `orphan`: a model_type nobody wrote a family file for
+        added[f"perfbench/configs/{name}.json"] = json.dumps({
+            "model_type": name, "num_hidden_layers": 6, "hidden_size": 8, "vocab_size": 16, "serving": serving,
+            "rehearsal": {"hf_overrides": {}, "logits_check_layers": 2},
+        })
+        bench["configs"].append({"name": name, "source": "selftest", "file": f"perfbench/configs/{name}.json", "reduced": [], "why": "selftest"})
+        bench["workloads"].append({"name": f"{name}-cell", "config": name, "traffic": "chat-sat", "chips": 1, "why": "selftest"})
+    added["BENCHMARK.json"] = json.dumps(bench, indent=1)
+    for rel, text in added.items():
+        with open(os.path.join(copy, rel), "w") as f:
+            f.write(text)
+
+    def drive(cell: str) -> dict:
+        proc = subprocess.run([sys.executable, "-c", DRIVE, cell], cwd=copy, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=120)
+        if proc.returncode != 0:
+            return {"failed": proc.stderr.decode()[-1500:]}
+        return json.loads(proc.stdout.decode().strip().splitlines()[-1])
+
+    out = drive("toy-cell")
+    ok = "logits" in out
+    check("toy family: checkpoint, cut and logits through run.py's own phases", ok, out)
+    if ok:
+        got, ckpt = out["logits"], out["ckpt"]
+        shallow = os.path.join(os.path.dirname(ckpt), "ckpt-2-layers")
+        check("toy family: its logits report comes back", got["ok"] and got["model_name"] == "toy" and got["depth"] == 2
+              and got["seed"] == str(2**31 + 9) and got["compared"] == {"toy": {"max_abs": 0.0}}, got)
+        check("toy family: the full checkpoint holds its plan", len(shards(ckpt)) == 7
+              and tensors(ckpt, "model-layer-002.safetensors") == ["model.layers.2.lower.weight", "model.layers.2.norm.weight"]
+              and tensors(ckpt, "model-layer-003.safetensors") == ["model.layers.3.norm.weight", "model.layers.3.upper.weight"])
+        # At depth 2 layer 1 is an upper layer (a lower one at depth 6): written
+        # by the plan. Layer 0 and the outside shard are the full checkpoint's.
+        check("toy family: a layer that depends on the depth is written, the others are linked",
+              got["cut"] == {"bytes": 2 * (8 + 8 * 3), "shards": 3, "linked": 2}
+              and got["tensors"]["model-layer-001.safetensors"] == ["model.layers.1.norm.weight", "model.layers.1.upper.weight"]
+              and not os.path.islink(os.path.join(shallow, "model-layer-001.safetensors"))
+              and os.path.realpath(os.path.join(shallow, "model-layer-000.safetensors")) == os.path.realpath(os.path.join(ckpt, "model-layer-000.safetensors"))
+              and os.path.islink(os.path.join(shallow, "model-outside-layers.safetensors")), got)
+    out = drive("orphan-cell")
+    check("no family file: the checkpoint phase names the file to add",
+          "perfbench/families/orphan.py" in out.get("failed", "") and "child checkpoint" in out.get("failed", ""), out)
+    after = tree(copy)
+    changed = sorted(n for n in before if after.get(n) != before[n])
+    check("by files alone: nothing the copy had has changed but BENCHMARK.json", changed == ["BENCHMARK.json"], changed)
+    check("by files alone: the new files are the ones added", sorted(set(after) - set(before)) == sorted(set(added) - {"BENCHMARK.json"}),
+          sorted(set(after) - set(before)))
+    old = resultline.load_benchmark()
+    check("by files alone: BENCHMARK.json only grew", set(bench) == set(old) and all(
+        bench[k][: len(v)] == v if isinstance(v, list) else bench[k] == v for k, v in old.items()))
+
+
 if __name__ == "__main__":
     hand_made()
     recorded()
     generator()
     last_line()
     benchmark_file()
+    with tempfile.TemporaryDirectory() as tmp:
+        families(tmp)
+        by_files_alone(tmp)
     print(f"{len(FAILED)} failed" if FAILED else "all passed")
     sys.exit(1 if FAILED else 0)
