@@ -18,8 +18,9 @@ Usage: python benchmarks/profile_engine.py [--preset 1.3b|8b-int8] [--paths gath
 --sweep: kernel-level decode-attention microbench — per-step latency of
 the paged attention call ALONE (weights out of the picture, so the
 attention term of the 96-slot cliff is measured in isolation), swept
-over slot counts x ragged-kernel block shapes (KUBEAI_PAGED_KERNEL_BLOCK
-grid) x the dedicated decode kernel, emitted as one JSON document with
+over slot counts x ragged-kernel block shapes (pages:queries, passed to
+the wrapper as `blocks=`; "default" is what the wrapper chooses from the
+call's shapes) x the dedicated decode kernel, emitted as one JSON document with
 grid-utilization diagnosis fields per config. `--smoke` shrinks shapes
 so the identical harness runs on CPU in CI (timings are then reference-
 implementation numbers — structure and relative trends only, labeled as
@@ -111,7 +112,7 @@ def run_sweep(
     import jax.numpy as jnp
     import numpy as np
 
-    from kubeai_tpu.ops.paged_attention import paged_attention_ragged
+    from kubeai_tpu.ops.paged_attention import kernel_blocks, paged_attention_ragged
     from kubeai_tpu.ops.paged_decode_attention import paged_decode_attention
 
     sh = _sweep_shapes(smoke)
@@ -218,10 +219,6 @@ def run_sweep(
             f.write("\n")
         os.replace(tmp, out_path)
 
-    # The block knob is trace-time global state: remember the caller's
-    # value (tuned deployments export it) and restore it afterwards —
-    # the sweep must not silently erase a live process's tuning.
-    prior_blk = os.environ.get("KUBEAI_PAGED_KERNEL_BLOCK")
     for B in slots_list:
         pending = [
             (kernel, blk)
@@ -256,22 +253,17 @@ def run_sweep(
                 log(f"sweep kernel={kernel} block={blk} slots={B}: resumed")
                 continue
             if kernel == "ragged":
-                if blk == "default":
-                    os.environ.pop("KUBEAI_PAGED_KERNEL_BLOCK", None)
-                    blk_pages = blk_queries = None
-                else:
-                    blk_pages, blk_queries = (int(x) for x in blk.split(":"))
-                    os.environ["KUBEAI_PAGED_KERNEL_BLOCK"] = f"{blk_pages},{blk_queries}"
-                # Fresh lambda per config: the env knob is read at trace
-                # time, so a shared jitted callable would silently reuse
-                # the first config's grid for every row of the table.
-                fn = jax.jit(
-                    lambda q, kv, t, l: paged_attention_ragged(q, kv, t, l)
+                # "default": the pair the wrapper chooses from the
+                # call's own shapes (ops/paged_attention.py).
+                pair = (
+                    kernel_blocks(qlen, H // Kv, max_pages, page)
+                    if blk == "default"
+                    else tuple(int(x) for x in blk.split(":"))
                 )
-                # Grid math for the diagnosis columns (library default
-                # query block is prefill-tuned; at S=1 the whole batch
-                # is B*qlen rows).
-                qb = blk_queries or 32
+                fn = jax.jit(partial(paged_attention_ragged, blocks=pair))
+                # Grid math for the diagnosis columns: one program a
+                # query block.
+                qb = pair[1]
                 programs = -(-B * qlen // qb)
                 q_rows = min(B * qlen, qb)
             else:
@@ -327,10 +319,6 @@ def run_sweep(
                 f"sweep kernel={kernel} block={blk} slots={B}: "
                 f"{'%.3f ms' % ms if ms else 'FAILED'}"
             )
-    if prior_blk is None:
-        os.environ.pop("KUBEAI_PAGED_KERNEL_BLOCK", None)
-    else:
-        os.environ["KUBEAI_PAGED_KERNEL_BLOCK"] = prior_blk
     persist()
     return make_doc(results)
 

@@ -67,6 +67,7 @@ from kubeai_tpu.obs.recorder import (
 )
 from kubeai_tpu.obs.logs import get_logger, trace_extra
 from kubeai_tpu.obs.trace import RequestTrace, TraceContext
+from kubeai_tpu.ops import paged_attention
 from kubeai_tpu.qos import QoSQueue, record_admitted, record_preemption
 from kubeai_tpu.qos import install_queue as qos_install_queue
 from kubeai_tpu.qos import uninstall_queue as qos_uninstall_queue
@@ -690,6 +691,9 @@ class Engine:
                 }
                 for dev in jax.local_devices()
             ],
+            # (kv pages, queries) a block the ragged paged kernel was
+            # given, per call shape this process has traced.
+            "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
             "stall": self._stall.report(),
         }
 

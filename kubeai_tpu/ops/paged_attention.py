@@ -21,6 +21,38 @@ import jax
 import jax.numpy as jnp
 
 
+# What kernel_blocks chose, per compiled call shape: filled at trace
+# time, served in the perf section of /debug/engine.
+chosen_blocks: dict[str, tuple[int, int]] = {}
+
+
+def kernel_blocks(S: int, G: int, pages_per_seq: int, page: int) -> tuple[int, int]:
+    """(num_kv_pages_per_block, num_queries_per_block) for the library
+    kernel, from the call's own shapes: S query rows a slot, G query
+    heads a KV head, the table's width and the page size. The library's
+    tuned table starts at 512 batched tokens, so a serving call would
+    take its default (128 pages x 32 queries, clipped), and the kernel
+    (a) copies every page of a KV block whatever the sequence's length
+    and (b) scores the whole query block against every sequence that
+    touches it.
+
+    Queries: one slot's rows a block, so at decode no slot's row meets
+    another slot's keys; a long chunk is cut where the score tile
+    (queries x G rows) reaches 256 rows: more rows gained under 3% and
+    the compiler's time grows faster than the tile (27 s at 896 rows).
+    KV: 512 tokens a block, which a short context does not copy far
+    past its end, and at least twice the query rows a slot, since a
+    chunk sits behind that many keys or more and every block turn costs
+    what scoring 300 (G=4) to 1300 (G=7) keys does. Swept on a v5e at
+    page 64 (PERF.md section 6, PR 30): at decode 8 pages x 1 query is
+    the fastest or within 4% of it from 32 slots x 350 tokens (59 us a
+    call against 377) to 8 slots x 8000; an fp8 pool liked 4 pages 7%
+    better."""
+    queries = min(S, 1 << (max(1, 256 // G).bit_length() - 1))
+    kv_pages = max(512, 2 * S) // page
+    return max(1, min(kv_pages, pages_per_seq)), queries
+
+
 def paged_attention_ragged(
     q: jnp.ndarray,  # [B, S, H, h] queries (the slots' newest S tokens)
     kv_pages: jnp.ndarray,  # [P, page, 2*Kv, h] (K even, V odd)
@@ -30,13 +62,14 @@ def paged_attention_ragged(
     softcap: float = 0.0,
     k_scale: float | None = None,  # static dequant scales for quantized
     v_scale: float | None = None,  # (int8/fp8) pools; None = pool is bf16
+    blocks: tuple[int, int] | None = None,  # a sweep's (kv pages, queries); serving leaves it None
 ) -> jnp.ndarray:
     """Returns [B, S, H, h] attention output. With a quantized pool the
     kernel dequantizes pages in-VMEM (x.astype(f32) * scale -> q.dtype),
     so HBM page traffic stays 8-bit."""
     B, S, H, h = q.shape
     max_pages = page_table.shape[1]
-    page = kv_pages.shape[1]
+    page, Kv = kv_pages.shape[1], kv_pages.shape[2] // 2
     if scale is None:
         scale = h**-0.5
 
@@ -59,24 +92,18 @@ def paged_attention_ragged(
         fn = ragged_paged_attention
         # The kernel's default scoped-VMEM budget (16MB) under-provisions
         # large-head configs: an 8B-class (H=32, Kv=8, h=128) prefill
-        # needs ~16.4MB of kernel stack, and at --max-seq-len 8192 (128
-        # pages a sequence, the library's untuned 128-page KV block) its
-        # double buffer alone takes 65.5MB: both die in compile ("Ran
-        # out of memory in memory space vmem") under a smaller limit.
-        # v5e/v5p have 128MB VMEM; 96MB leaves XLA its own 16MB scope
-        # for the surrounding fusion (tests/test_tpu_compile.py).
+        # needs ~16.4MB of kernel stack and dies in compile ("Ran out of
+        # memory in memory space vmem") under a smaller limit. v5e/v5p
+        # have 128MB VMEM; 96MB leaves XLA its own 16MB scope for the
+        # surrounding fusion (tests/test_tpu_compile.py).
         tuning["vmem_limit_bytes"] = 96 * 1024 * 1024
-        # Optional grid-tuning override ("kv_pages,queries" per block):
-        # the library's tuned table targets vLLM-style shapes; decode at
-        # S=1 per slot is grid-underutilized, and this knob lets bench
-        # sweeps probe better blockings without code edits.
-        import os
-
-        blk = os.environ.get("KUBEAI_PAGED_KERNEL_BLOCK")
-        if blk:
-            blk_pages, blk_queries = (int(x) for x in blk.split(","))
-            tuning["num_kv_pages_per_block"] = blk_pages
-            tuning["num_queries_per_block"] = blk_queries
+        if blocks is None:
+            blocks = kernel_blocks(S, H // Kv, max_pages, page)
+            chosen_blocks[
+                f"B={B} S={S} H={H} Kv={Kv} "
+                f"pages={max_pages}x{page} {kv_pages.dtype.name}"
+            ] = blocks
+        tuning["num_kv_pages_per_block"], tuning["num_queries_per_block"] = blocks
     # One argument construction for BOTH arms (the twin is signature-
     # identical to the kernel), so CPU tests exercise the exact call the
     # TPU makes; TPU-only tuning kwargs ride separately.

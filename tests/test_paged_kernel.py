@@ -94,11 +94,10 @@ def test_tpu_dispatch_arm_builds_identical_call(monkeypatch):
     table = jnp.asarray(np.arange(1, 1 + B * mp, dtype=np.int32).reshape(B, mp))
     kv_lens = jnp.asarray([10, 30], jnp.int32)
 
-    # Grid-tuning env knob must flow through (and not shadow the query
-    # tensor — a r5 review catch).
-    monkeypatch.setenv("KUBEAI_PAGED_KERNEL_BLOCK", "8,4")
+    # The pair the wrapper chose from the call's shapes must flow through
+    # (and not shadow the query tensor — a r5 review catch).
     got = pa.paged_attention_ragged(q, kv_pages, table, kv_lens, softcap=25.0)
-    assert recorded["blk"] == (8, 4)
+    assert recorded["blk"] == pa.kernel_blocks(S, H // Kv, mp, ps)
 
     assert recorded["q"].shape == (B * S, H, h)
     np.testing.assert_array_equal(np.asarray(recorded["cu"]), np.arange(B + 1) * S)
@@ -114,6 +113,74 @@ def test_tpu_dispatch_arm_builds_identical_call(monkeypatch):
     monkeypatch.setattr(pa.jax, "default_backend", lambda: "cpu")
     want = pa.paged_attention_ragged(q, kv_pages, table, kv_lens, softcap=25.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+
+
+# (heads, kv heads, slots, query rows a slot, max_len, pool dtype) at the
+# benchmark's page of 64 and head_dim 128.
+_BLOCK_CASES = {
+    "qwen2.5-7b/decode": (28, 4, 32, 1, 2048, jnp.bfloat16),
+    "mistral-7b/decode": (32, 8, 8, 1, 8192, jnp.bfloat16),
+    "qwen2.5-7b/verify-S3": (28, 4, 32, 3, 2048, jnp.bfloat16),
+    "qwen2.5-7b/prefill-512": (28, 4, 1, 512, 2048, jnp.bfloat16),
+    "mistral-7b/chunk-1024": (32, 8, 1, 1024, 8192, jnp.bfloat16),
+    "qwen2.5-7b/decode-fp8-pool": (28, 4, 32, 1, 2048, jnp.float8_e4m3fn),
+    "qwen2.5-7b/tp4/decode": (7, 1, 32, 1, 2048, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_BLOCK_CASES), ids=list(_BLOCK_CASES))
+def test_kernel_blocks_are_chosen_from_the_calls_shapes(monkeypatch, case):
+    """The (kv pages, queries) pair the library kernel is given is
+    kernel_blocks' choice from the call's own shapes and dtypes: inside
+    what the kernel accepts, recorded for /debug/engine, and at decode
+    smaller than the library default it replaces (128 pages x 32
+    queries, clipped to the table's width and the call's rows: a block
+    of 32 slots scored against every slot's whole table)."""
+    import kubeai_tpu.ops.paged_attention as pa
+
+    lib = pytest.importorskip("jax.experimental.pallas.ops.tpu.ragged_paged_attention")
+    H, Kv, B, S, max_len, pool_dtype = _BLOCK_CASES[case]
+    h, ps = 128, 64
+    mp = max_len // ps
+    seen = {}
+
+    def fake_kernel(q_flat, *args, num_kv_pages_per_block=None, num_queries_per_block=None, **kw):
+        seen["blk"] = (num_kv_pages_per_block, num_queries_per_block)
+        return q_flat
+
+    monkeypatch.setattr(lib, "ragged_paged_attention", fake_kernel)
+    monkeypatch.setattr(pa.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pa, "chosen_blocks", {})
+    quant = {} if pool_dtype == jnp.bfloat16 else {"k_scale": 1.0, "v_scale": 1.0}
+    args = (
+        jax.ShapeDtypeStruct((B, S, H, h), jnp.bfloat16),
+        jax.ShapeDtypeStruct((B * mp + 1, ps, 2 * Kv, h), pool_dtype),
+        jax.ShapeDtypeStruct((B, mp), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+    )
+    out = jax.eval_shape(
+        lambda q, kv, tbl, lens: pa.paged_attention_ragged(q, kv, tbl, lens, **quant), *args
+    )
+    assert out.shape == (B, S, H, h)
+    kv_pages, queries = seen["blk"]
+    assert (kv_pages, queries) == pa.kernel_blocks(S, H // Kv, mp, ps)
+    assert 0 < kv_pages <= mp
+    assert 0 < queries <= B * S
+    # One entry a compiled call shape, the pair as the kernel got it.
+    assert list(pa.chosen_blocks.values()) == [(kv_pages, queries)]
+    default = (min(mp, 128), min(B * S, 32))
+    if S <= 4:
+        # One slot a query block: no slot's rows are scored against
+        # another slot's keys.
+        assert queries == S
+        assert kv_pages < default[0] and queries < default[1]
+    # A sweep's pair goes through as given and leaves no record.
+    jax.eval_shape(
+        lambda q, kv, tbl, lens: pa.paged_attention_ragged(q, kv, tbl, lens, blocks=(2, 8), **quant),
+        *args,
+    )
+    assert seen["blk"] == (2, 8)
+    assert len(pa.chosen_blocks) == 1
 
 
 def test_wrapper_clamps_overrun_lengths():

@@ -382,6 +382,24 @@ class TestEngineWiring:
         assert section["weight_bytes"] > 0
         assert "stall" in section
 
+    def test_debug_engine_serves_the_paged_kernel_blocks(self, monkeypatch):
+        """The (kv pages, queries) pair ops/paged_attention.py chose for
+        each call shape it traced reaches a reader through the perf
+        section of GET /debug/engine."""
+        from kubeai_tpu.engine.core import build_test_engine
+        from kubeai_tpu.obs.recorder import handle_debug_request
+        from kubeai_tpu.ops import paged_attention
+
+        shape = "B=32 S=1 H=28 Kv=4 pages=32x64 bfloat16"
+        monkeypatch.setattr(paged_attention, "chosen_blocks", {shape: (8, 1)})
+        eng = build_test_engine()
+        try:
+            code, _, body = handle_debug_request("/debug/engine", "limit=1")
+            assert code == 200
+            assert json.loads(body)["perf"]["paged_kernel_blocks"] == {shape: [8, 1]}
+        finally:
+            eng.stop()
+
     def test_stop_unregisters_perf_section(self):
         """stop() must unpin the engine from the process-global debug
         registry (it holds the KV pool + jit caches via the bound

@@ -115,39 +115,45 @@ def test_flash_prefill_lowers(v5e, heads):
 
 
 @pytest.mark.parametrize(
-    "heads,B,S,pool_dtype",
+    "heads,B,S,pool_dtype,max_len",
     [
-        (QWEN25_7B, SLOTS, 1, jnp.bfloat16),
-        (QWEN25_7B, 8, 512, jnp.bfloat16),
-        (QWEN25_7B, SLOTS, 1, jnp.float8_e4m3fn),
-        (LLAMA3_8B, SLOTS, 1, jnp.bfloat16),
-        (LLAMA3_8B, 8, 512, jnp.bfloat16),
-        (QWEN25_7B_TP4, SLOTS, 1, jnp.bfloat16),
-        (QWEN25_7B_TP4, 8, 512, jnp.bfloat16),
-        (LLAMA3_8B_TP4, SLOTS, 1, jnp.bfloat16),
-        (GEMMA_2B, SLOTS, 1, jnp.bfloat16),
+        (QWEN25_7B, SLOTS, 1, jnp.bfloat16, 2048),
+        (QWEN25_7B, 8, 512, jnp.bfloat16, 2048),
+        (QWEN25_7B, SLOTS, 1, jnp.float8_e4m3fn, 2048),
+        (LLAMA3_8B, SLOTS, 1, jnp.bfloat16, 2048),
+        (LLAMA3_8B, 8, 512, jnp.bfloat16, 2048),
+        (QWEN25_7B_TP4, SLOTS, 1, jnp.bfloat16, 2048),
+        (QWEN25_7B_TP4, 8, 512, jnp.bfloat16, 2048),
+        (LLAMA3_8B_TP4, SLOTS, 1, jnp.bfloat16, 2048),
+        (GEMMA_2B, SLOTS, 1, jnp.bfloat16, 2048),
+        # The benchmark's Mistral cell (mistral7b-int8-docqa): 8 slots of
+        # 128 pages; a 1024-row chunk of a document behind its prefix.
+        (LLAMA3_8B, 8, 1, jnp.bfloat16, 8192),
+        (LLAMA3_8B, 1, 1024, jnp.bfloat16, 8192),
     ],
     ids=[
         "qwen2.5-7b/decode", "qwen2.5-7b/prefill-8x512", "qwen2.5-7b/decode-fp8-pool",
         "llama3-8b/decode", "llama3-8b/prefill-8x512",
         "qwen2.5-7b/tp4/decode", "qwen2.5-7b/tp4/prefill-8x512",
         "llama3-8b/tp4/decode", "gemma-2b/decode",
+        "mistral-7b/decode-8x8192", "mistral-7b/chunk-1024-of-8192",
     ],
 )
-def test_ragged_paged_kernel_lowers(v5e, heads, B, S, pool_dtype):
+def test_ragged_paged_kernel_lowers(v5e, heads, B, S, pool_dtype, max_len):
     quant = {} if pool_dtype == jnp.bfloat16 else {"k_scale": 1.0, "v_scale": 1.0}
     text = _compile(
         lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, **quant),
-        *_paged_args(v5e[0], B, S, heads, pool_dtype=pool_dtype),
+        *_paged_args(v5e[0], B, S, heads, max_len=max_len, pool_dtype=pool_dtype),
     )
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.slow  # 12-15 s: the library's untuned 128-page KV block unrolls
 def test_ragged_paged_kernel_fits_vmem_at_8192(v5e):
     """deploy/models/llama-3.1-8b-instruct-tpu.yaml serves at
-    --max-seq-len 8192: the kernel's double buffer takes 65.5 MB of
-    scoped VMEM there, over the 64 MB this wrapper used to allow."""
+    --max-seq-len 8192 (128 pages a sequence). At the library's untuned
+    128-page KV block the double buffer alone took 65.5 MB of scoped
+    VMEM and the compile 12-15 s (one DMA unrolled per page); at the
+    8 pages the wrapper passes it takes 4 MB and under a second."""
     text = _compile(
         lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens),
         *_paged_args(v5e[0], SLOTS, 1, LLAMA3_8B, max_len=8192),
