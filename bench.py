@@ -156,7 +156,7 @@ def synth_int8_params(mc):
     }
 
 
-def build_engine(preset: str, speculate: int = 0, slots: int = 0, chunk: int = 0, kv_dtype: str = "", decode_kernel: str = ""):
+def build_engine(preset: str, slots: int = 0, chunk: int = 0, kv_dtype: str = ""):
     import jax
 
     from kubeai_tpu.engine.core import Engine, EngineConfig
@@ -228,16 +228,12 @@ def build_engine(preset: str, speculate: int = 0, slots: int = 0, chunk: int = 0
             decode_chunk=16,
         )
         params = llama.init_params(mc, jax.random.key(0))
-    if speculate:
-        ec.speculate_tokens = speculate
     if kv_dtype:
         ec.kv_cache_dtype = "" if kv_dtype == "bf16" else kv_dtype
     if slots:
         ec.max_slots = slots
     if chunk:
         ec.decode_chunk = chunk
-    if decode_kernel:
-        ec.decode_kernel = decode_kernel
     return Engine(mc, params, ByteTokenizer(), ec)
 
 
@@ -291,19 +287,15 @@ def run_worker(args) -> None:
     t0 = time.monotonic()
     log(f"phase=build constructing engine (weights on device)")
     eng = build_engine(
-        preset, speculate=args.speculate, slots=args.slots, chunk=args.chunk,
-        kv_dtype=args.kv_dtype, decode_kernel=args.decode_kernel,
+        preset, slots=args.slots, chunk=args.chunk, kv_dtype=args.kv_dtype,
     )
     eng.start()
     log(f"phase=build done ({time.monotonic()-t0:.1f}s)")
 
     rng = np.random.default_rng(0)
-    if args.speculate or args.greedy:
-        # Speculation comparison runs greedy (drafts are only accepted on
-        # greedy slots — exactness by argmax match) with REPETITIVE
-        # prompts: a repeated phrase pattern gives the device-side 2-gram
-        # lookup real continuations to draft, standing in for the
-        # chat-echoes-its-context workloads speculation targets.
+    if args.greedy:
+        # Greedy runs use REPETITIVE prompts: a repeated phrase pattern,
+        # standing in for chat workloads that echo their context.
         phrase = rng.integers(1, 200, 16)
         prompts = [
             np.concatenate(
@@ -359,10 +351,6 @@ def run_worker(args) -> None:
             if ev[0] == "error":
                 raise RuntimeError(ev[1])
     log(f"phase=warmup done ({time.monotonic()-t0:.1f}s)")
-    # Snapshot lifetime speculation counters so the reported acceptance
-    # covers ONLY the measured phase (warmup's random disjoint prompts
-    # draft at near-zero acceptance and would bias it down).
-    spec_base = (eng.m_spec_drafted.value(), eng.m_spec_accepted.value())
 
     results = [None] * n_requests
     ttfts = [None] * n_requests
@@ -441,13 +429,8 @@ def run_worker(args) -> None:
         }
     except Exception as e:  # pragma: no cover - block is best-effort
         log(f"slo block unavailable: {e}")
-    if args.speculate or args.greedy:
-        drafted = eng.m_spec_drafted.value() - spec_base[0]
-        accepted = eng.m_spec_accepted.value() - spec_base[1]
-        extras["speculate_tokens"] = args.speculate
+    if args.greedy:
         extras["sampling"] = "greedy"
-        if drafted:
-            extras["spec_acceptance_pct"] = round(100 * accepted / drafted, 1)
     # Roofline/MFU from the SHARED accounting (kubeai_tpu/obs/perf.py —
     # the same math the engine's kubeai_engine_mfu gauge and the sweep
     # JSON use; previously hand-maintained constants here): FLOPs/token
@@ -487,7 +470,7 @@ def run_worker(args) -> None:
     # rate-controlled TTFT) is BASELINE.json's "req/s/chip + p50 TTFT"
     # north star.
     rate = args.request_rate
-    if rate is None and preset != "tiny" and not args.speculate:
+    if rate is None and preset != "tiny":
         # Default: ~70% of the just-measured saturated request rate —
         # comfortably inside capacity so TTFT measures the system, not
         # the queue.
@@ -515,8 +498,6 @@ def run_worker(args) -> None:
         except Exception as e:  # pragma: no cover - defensive
             extras["rate_error"] = str(e)[:200]
             log(f"phase=rate FAILED: {e}")
-    if args.decode_kernel:
-        extras["decode_kernel"] = args.decode_kernel
     # Emit the measured headline BEFORE teardown: an exception or hang
     # in eng.stop() must not be able to forfeit an already-measured
     # result (ADVICE r5 — emit() had drifted to after stop()).
@@ -618,14 +599,8 @@ def main():
     parser.add_argument("--requests", type=int, default=None)
     parser.add_argument("--max-tokens", type=int, default=None)
     parser.add_argument(
-        "--speculate", type=int, default=0,
-        help="n-gram speculative decoding: drafts verified per step "
-             "(runs greedy with repetitive prompts; 0 = off)",
-    )
-    parser.add_argument(
         "--greedy", action="store_true",
-        help="greedy sampling + repetitive prompts WITHOUT speculation "
-             "(the control for --speculate comparisons)",
+        help="greedy sampling + repetitive prompts",
     )
     parser.add_argument(
         "--slots", type=int, default=0,
@@ -638,12 +613,6 @@ def main():
     parser.add_argument(
         "--kv-dtype", default="", choices=["", "bf16", "fp8", "int8"],
         help="override the preset's KV pool dtype (bf16 = unquantized)",
-    )
-    parser.add_argument(
-        "--decode-kernel", default="",
-        choices=["", "ragged", "dedicated", "auto"],
-        help="decode-path paged-attention kernel (empty = preset default "
-             "'ragged'; see EngineConfig.decode_kernel)",
     )
     parser.add_argument(
         "--request-rate", type=float, default=None,

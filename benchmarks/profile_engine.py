@@ -20,7 +20,7 @@ the paged attention call ALONE (weights out of the picture, so the
 attention term of the 96-slot cliff is measured in isolation), swept
 over slot counts x ragged-kernel block shapes (pages:queries, passed to
 the wrapper as `blocks=`; "default" is what the wrapper chooses from the
-call's shapes) x the dedicated decode kernel, emitted as one JSON document with
+call's shapes), emitted as one JSON document with
 grid-utilization diagnosis fields per config. `--smoke` shrinks shapes
 so the identical harness runs on CPU in CI (timings are then reference-
 implementation numbers — structure and relative trends only, labeled as
@@ -89,12 +89,9 @@ def run_sweep(
         underutilization): more slots add work per program, not more
         programs, and past the VMEM-resident span the serial page walk
         dominates — latency then jumps superlinearly (the cliff shape).
-      - The DEDICATED kernel's grid is Kv x slots x pages: programs
-        scale with slots by construction, so its latency-vs-slots curve
-        separates grid effects from raw page-walk bandwidth.
       - `grid_programs` / `q_rows_per_program` per row are the derived
         utilization facts; `kv_mb_walked` is the per-call page traffic
-        (identical across kernels at equal slots — any latency delta at
+        (identical across blocks at equal slots — any latency delta at
         equal traffic is scheduling, not bandwidth).
 
     CPU runs (smoke or no accelerator) time the REFERENCE
@@ -113,7 +110,6 @@ def run_sweep(
     import numpy as np
 
     from kubeai_tpu.ops.paged_attention import kernel_blocks, paged_attention_ragged
-    from kubeai_tpu.ops.paged_decode_attention import paged_decode_attention
 
     sh = _sweep_shapes(smoke)
     H, Kv, h, page, seq = sh["H"], sh["Kv"], sh["h"], sh["page"], sh["seq"]
@@ -159,7 +155,7 @@ def run_sweep(
 
     def make_doc(rows):
         return {
-            "metric": "paged_decode_attention_sweep",
+            "metric": "paged_attention_sweep",
             "backend": backend,
             "device": str(kind),
             "degraded": degraded,
@@ -200,7 +196,7 @@ def run_sweep(
         except (OSError, ValueError) as e:
             log(f"resume: cannot read {out_path} ({e}); starting fresh")
             prior = None
-        if prior and prior.get("metric") == "paged_decode_attention_sweep":
+        if prior and prior.get("metric") == "paged_attention_sweep":
             for row in prior.get("results", []):
                 # A measured latency OR a recorded failure both count as
                 # done; a row with neither was interrupted mid-cell.
@@ -220,15 +216,11 @@ def run_sweep(
         os.replace(tmp, out_path)
 
     for B in slots_list:
-        pending = [
-            (kernel, blk)
-            for kernel, blk in [("dedicated", "slotwise")] + [("ragged", b) for b in blocks]
-            if (kernel, blk, B, qlen) not in completed
-        ]
-        if not pending:
+        configs = [("ragged", blk) for blk in blocks]
+        if all((kernel, blk, B, qlen) in completed for kernel, blk in configs):
             # Every cell at this slot count is already measured: reuse
             # the rows without allocating the (large) test arrays.
-            for kernel, blk in [("dedicated", "slotwise")] + [("ragged", b) for b in blocks]:
+            for kernel, blk in configs:
                 results.append(completed[(kernel, blk, B, qlen)])
                 log(f"sweep kernel={kernel} block={blk} slots={B}: resumed")
             continue
@@ -246,32 +238,24 @@ def run_sweep(
         kv_mb = float(B * (seq // 2) * 2 * Kv * h * np.dtype(
             "float32" if degraded else "bfloat16").itemsize) / 1e6
 
-        configs = [("dedicated", "slotwise")] + [("ragged", blk) for blk in blocks]
         for kernel, blk in configs:
             if (kernel, blk, B, qlen) in completed:
                 results.append(completed[(kernel, blk, B, qlen)])
                 log(f"sweep kernel={kernel} block={blk} slots={B}: resumed")
                 continue
-            if kernel == "ragged":
-                # "default": the pair the wrapper chooses from the
-                # call's own shapes (ops/paged_attention.py).
-                pair = (
-                    kernel_blocks(qlen, H // Kv, max_pages, page)
-                    if blk == "default"
-                    else tuple(int(x) for x in blk.split(":"))
-                )
-                fn = jax.jit(partial(paged_attention_ragged, blocks=pair))
-                # Grid math for the diagnosis columns: one program a
-                # query block.
-                qb = pair[1]
-                programs = -(-B * qlen // qb)
-                q_rows = min(B * qlen, qb)
-            else:
-                fn = jax.jit(
-                    lambda q, kv, t, l: paged_decode_attention(q, kv, t, l)
-                )
-                programs = Kv * B  # x pages innermost
-                q_rows = qlen * (H // Kv)
+            # "default": the pair the wrapper chooses from the call's
+            # own shapes (ops/paged_attention.py).
+            pair = (
+                kernel_blocks(qlen, H // Kv, max_pages, page)
+                if blk == "default"
+                else tuple(int(x) for x in blk.split(":"))
+            )
+            fn = jax.jit(partial(paged_attention_ragged, blocks=pair))
+            # Grid math for the diagnosis columns: one program a query
+            # block.
+            qb = pair[1]
+            programs = -(-B * qlen // qb)
+            q_rows = min(B * qlen, qb)
             try:
                 for _ in range(warmup):
                     jax.block_until_ready(fn(q, kv_pages, table, kv_lens))
@@ -356,7 +340,7 @@ def main():
     )
     p.add_argument(
         "--sweep-qlen", type=int, default=1,
-        help="queries per slot (1 = plain decode; G+1 probes speculative)",
+        help="queries per slot (1 = decode)",
     )
     args = p.parse_args()
 

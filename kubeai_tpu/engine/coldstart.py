@@ -261,7 +261,6 @@ def warm_compile(
     max_pages, P, hist_width = core.engine_dims(cfg)
     B = cfg.max_slots
     Kb = cfg.max_logit_bias
-    G = cfg.speculate_tokens
     params = param_shapes(model_config, quantization)
     cache = jax.eval_shape(
         lambda: llama.init_paged_cache(model_config, P, cfg.page_size)
@@ -286,17 +285,15 @@ def warm_compile(
             log.warning("warm compile of %s failed: %s", label, e)
             errors.append(f"{label}: {e}")
 
-    adm_hist_kw = {"adm_hist": sds((B, hist_width), i32)} if G > 0 else {}
     compile_one(
         "decode",
-        sf.decode_jit_for(sf.decode_kernel),
+        sf.decode_jit,
         params, cache, sds((B, max_pages), i32), sds((B, hist_width), i32),
         sds((B,), i32), sds((B,), i32), keys,
         sds((B,), jnp.bool_), sds((B,), f32), sds((B,), f32), sds((B,), i32),
         sds((B,), f32), sds((B,), f32), sds((B,), jnp.bool_), sds((B,), i32),
         sds((B, Kb), i32), sds((B, Kb), f32),
         sds((B,), jnp.bool_), sds((B,), i32), sds((B,), u32), sds((B,), i32),
-        **adm_hist_kw,
     )
     cap = max(1, min(cfg.prefill_group_cap, cfg.max_slots))
     sizes = (1, cap) if include_group and cap > 1 else (1,)
@@ -320,11 +317,7 @@ def warm_compile(
         sds((), f32), sds((), i32), sds((Kb,), i32), sds((Kb,), f32),
         sds((B,), i32), cache,
     )
-    out = {
-        "shapes": shapes,
-        "seconds": round(time.monotonic() - t0, 3),
-        "decode_kernel": sf.decode_kernel,
-    }
+    out = {"shapes": shapes, "seconds": round(time.monotonic() - t0, 3)}
     if errors:
         out["errors"] = errors
     log.info(

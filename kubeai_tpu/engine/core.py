@@ -156,29 +156,10 @@ class EngineConfig:
     # the engine — the reference relies on vLLM's prefix cache for the
     # same effect. 0 disables.
     prefix_cache_min: int = 16
-    # Speculative decoding via device-side n-gram prompt lookup: each
-    # decode step verifies up to this many draft tokens (drafted from a
-    # device-resident token history — chat replies echo their context, so
-    # 2-gram continuation lookup hits often) in ONE forward, amortizing
-    # the weight reads that bound TPU decode. Drafts are accepted only
-    # where the model's greedy choice matches, so greedy output is
-    # byte-identical to non-speculative; sampled (temperature>0) slots
-    # never accept drafts and behave exactly as before. 0 disables.
-    speculate_tokens: int = 0
     # KV pool storage dtype override: "" keeps ModelConfig's choice,
     # "fp8"/"int8" quantize the paged pool (see ModelConfig.kv_cache_dtype
     # — halves KV HBM, doubling the slot ceiling on a 16GB chip).
     kv_cache_dtype: str = ""
-    # Decode-path paged-attention kernel: "ragged" keeps the shared
-    # ragged kernel (prefill-tuned grid) on the decode step, "dedicated"
-    # uses ops/paged_decode_attention (S=1/G+1-specialized blocking:
-    # grid scales with kv_heads x slots instead of collapsing at one
-    # query per slot), "auto" keys on the decode query length at trace
-    # time. The RESOLVED choice rides every decode broadcast so gang
-    # followers compile the same program (lockstep contract). Default
-    # "ragged" keeps existing behavior/replay suites unchanged; only
-    # affects TPU runs (CPU dispatches to semantics twins either way).
-    decode_kernel: str = "ragged"
     # logit_bias entries honored per request. Default equals the
     # OpenAI/proxy cap (openai_types.LOGIT_BIAS_CAP) so proxy-valid
     # requests can't be rejected downstream; the engine server 400s
@@ -203,9 +184,7 @@ def engine_dims(cfg: EngineConfig) -> tuple[int, int, int]:
     ps = cfg.page_size
     max_pages = -(-cfg.max_seq_len // ps)
     P = cfg.num_pages or (cfg.max_slots * max_pages + 1)
-    hist_width = cfg.max_seq_len + (cfg.decode_chunk + 1) * (
-        cfg.speculate_tokens + 1
-    )
+    hist_width = cfg.max_seq_len + cfg.decode_chunk + 1
     return max_pages, P, hist_width
 
 
@@ -566,12 +545,6 @@ class Engine:
             "gang re-formations: a lost follower reconnected and rank 0 "
             "reset + resumed serving (vs the old fatal rank exit)",
         )
-        self.m_spec_drafted = default_registry.counter(
-            "kubeai_engine_speculative_drafted_total", "draft tokens proposed"
-        )
-        self.m_spec_accepted = default_registry.counter(
-            "kubeai_engine_speculative_accepted_total", "draft tokens accepted"
-        )
         # Weight residency evidence: on a tp gang each rank's local bytes
         # are ~global/ranks (the multi-host e2e asserts this — the model
         # provably spans the gang rather than being replicated).
@@ -735,10 +708,9 @@ class Engine:
         ps = self.cfg.page_size
         self._max_pages, P, hist_width = engine_dims(self.cfg)
         self._pool = PagePool(P, ps)
-        # Device-resident token history for speculative n-gram drafting
-        # (written positions only; padded past max_seq_len so in-chunk
-        # speculation overshoot after a finish never scatter-collides).
-        G = self.cfg.speculate_tokens
+        # Device-resident token history, read by the penalties (written
+        # positions only; padded past max_seq_len so a slot that finishes
+        # mid-chunk and keeps stepping never scatter-collides).
 
         def mk_device_arrays():
             cache = llama.init_paged_cache(self.model_config, P, ps)
@@ -786,7 +758,7 @@ class Engine:
         # .at[].set per admission: ~9 eager dispatches of host time per
         # admitted request, all spent while the device sat idle. Only state that EVOLVES device-side
         # between host syncs (pool, lengths, last token, PRNG keys,
-        # speculation history) stays as donated device carries.
+        # token history) stays as donated device carries.
         self._h_active = np.zeros((B,), bool)
         self._h_temp = np.ones((B,), np.float32)
         self._h_top_p = np.ones((B,), np.float32)
@@ -817,7 +789,6 @@ class Engine:
         self._adm_mask = np.zeros((B,), bool)
         self._adm_len = np.zeros((B,), np.int32)
         self._adm_seed = np.zeros((B,), np.uint32)
-        self._adm_hist = np.zeros((B, hist_width), np.int32) if G > 0 else None
         self._slot_pages: list[list[int]] = [[] for _ in range(B)]
         # Pages content-registered at plan time whose prefill has NOT yet
         # succeeded (cleared by _register): a failed prefill must
@@ -855,37 +826,18 @@ class Engine:
         self._step_fns = sf
         self._prefill_chunk_jit = sf.prefill_chunk_jit
         self._prefill_batch_jit = sf.prefill_batch_jit
-        self._decode_jits = sf.decode_jits
-        self._make_decode_jit = sf.make_decode_jit
-        self._decode_kernel = sf.decode_kernel
-        self._decode_jit = self._decode_jit_for(self._decode_kernel)
-
-
+        self._decode_jit = sf.decode_jit
 
     def _attn_kernel(self, kind: str, queries: int) -> str:
         """The attention implementation the step *kind* compiles to at
-        *queries* tokens per row, for the step records: "flash", the
-        paged kernel flavor ("ragged" | "dedicated"), or "xla" (the
-        portable gather route: every CPU run, sliding-window models)."""
+        *queries* tokens per row, for the step records: "flash",
+        "ragged" (the paged kernel), or "xla" (the portable gather
+        route: every CPU run, sliding-window models)."""
         route = llama.cached_attention_route(
             self.model_config, queries,
             left_aligned=kind == "prefill_group", paged=True,
         )
-        if route != "paged_kernel":
-            return route
-        # Only the decode step takes the configured flavor; prefill
-        # always rides the ragged kernel.
-        return self._decode_kernel if kind == "decode_chunk" else "ragged"
-
-    def _decode_jit_for(self, kernel: str):
-        """The jitted decode step for a concrete kernel flavor, built on
-        first use. Keyed storage (not attributes) so a gang follower can
-        honor a broadcast flavor that differs from its local config
-        without clobbering its own."""
-        fn = self._decode_jits.get(kernel)
-        if fn is None:
-            fn = self._decode_jits[kernel] = self._make_decode_jit(kernel)
-        return fn
+        return "ragged" if route == "paged_kernel" else route
 
     # -- public API --------------------------------------------------------
 
@@ -928,11 +880,6 @@ class Engine:
         t0 = time.monotonic()
         shapes = 0
         # Decode chunk (the hot loop).
-        adm_hist = (
-            {"adm_hist": self._adm_hist.copy()}
-            if self.cfg.speculate_tokens > 0
-            else {}
-        )
         (
             *_,
             self._cache, self._tok_hist, self._lengths,
@@ -946,7 +893,6 @@ class Engine:
             self._h_gen_start.copy(), self._h_bias_ids.copy(),
             self._h_bias_vals.copy(), self._adm_mask.copy(),
             self._adm_len.copy(), self._adm_seed.copy(), self._adm_toks,
-            **adm_hist,
         )
         shapes += 1
         cap = max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
@@ -1494,11 +1440,8 @@ class Engine:
     def _jit_cache_entries(self) -> int:
         """Total compiled executables across the step functions (jax's
         per-function lowering cache). Growth = a compilation happened."""
-        fns = [
-            *self._decode_jits.values(),
-            self._prefill_batch_jit,
-            self._prefill_chunk_jit,
-        ]
+        sf = self._step_fns
+        fns = [sf.decode_jit, sf.prefill_batch_jit, sf.prefill_chunk_jit]
         if hasattr(self, "_embed_jit"):  # built on first embeddings call
             fns.append(self._embed_jit)
         return sum(fn._cache_size() for fn in fns)
@@ -1633,29 +1576,18 @@ class Engine:
                 continue
             if op == "decode":
                 lora_args = self._follower_lora(ar)
-                adm_hist = (
-                    {"adm_hist": ar["adm_hist"]} if self.cfg.speculate_tokens > 0 else {}
-                )
-                # The kernel flavor is keyed off the PAYLOAD, not this
-                # rank's config: rank 0's resolution is authoritative
-                # (all ranks must execute the same compiled program).
-                # Absent key = a pre-flag publisher; fall back to the
-                # local resolution.
-                decode_fn = self._decode_jit_for(
-                    (sc or {}).get("decode_kernel", self._decode_kernel)
-                )
                 (
-                    _, _, _, _, _, _, _,
+                    _, _, _, _,
                     self._cache, self._tok_hist, self._lengths,
                     self._last_tokens, self._keys,
-                ) = decode_fn(
+                ) = self._decode_jit(
                     self.params, self._cache, ar["tables"], self._tok_hist,
                     self._lengths, self._last_tokens, self._keys,
                     ar["active"], ar["temp"], ar["top_p"], ar["top_k"],
                     ar["presence"], ar["freq"], ar["want_top"], ar["gen_start"],
                     ar["bias_ids"], ar["bias_vals"],
                     ar["adm_mask"], ar["adm_len"], ar["adm_seed"],
-                    self._adm_toks, **adm_hist, **lora_args,
+                    self._adm_toks, **lora_args,
                 )
             elif op == "prefill_batch":
                 lora_args = self._follower_lora(ar)
@@ -2431,10 +2363,6 @@ class Engine:
         self._adm_mask[slot_idx] = True
         self._adm_len[slot_idx] = len(ids)
         self._adm_seed[slot_idx] = seed
-        if self.cfg.speculate_tokens > 0:
-            row = np.zeros((self._tok_hist.shape[1],), np.int32)
-            row[: len(ids)] = ids
-            self._adm_hist[slot_idx] = row
 
     def _prefill_group(self, items: list, bucket: int):
         """One prefill call for N same-bucket cold requests. The batch
@@ -2564,19 +2492,8 @@ class Engine:
         lora_args = {}
         if self._adapters is not None:
             lora_args = {"lora": self._adapters.bank, "lora_rows": self._h_lora_rows.copy()}
-        adm_hist = (
-            {"adm_hist": self._adm_hist.copy()}
-            if self.cfg.speculate_tokens > 0
-            else {}
-        )
         with self._lockstep(
             "decode",
-            # The resolved kernel flavor rides every decode broadcast:
-            # followers must compile the SAME program (a rank pairing a
-            # different attention kernel would still agree numerically
-            # but break the "identical jitted computation" lockstep
-            # contract the gang's collectives rely on).
-            scalars={"decode_kernel": self._decode_kernel},
             arrays={
                 "tables": self._page_table, "active": self._h_active,
                 "temp": self._h_temp, "top_p": self._h_top_p,
@@ -2586,12 +2503,11 @@ class Engine:
                 "bias_ids": self._h_bias_ids, "bias_vals": self._h_bias_vals,
                 "adm_mask": self._adm_mask,
                 "adm_len": self._adm_len, "adm_seed": self._adm_seed,
-                **({"adm_hist": self._adm_hist} if self.cfg.speculate_tokens > 0 else {}),
                 **({"lora_rows": self._h_lora_rows} if self._adapters is not None else {}),
             },
         ):
             (
-                d_seq, c_seq, a_seq, lpd_seq, lpc_seq, tid_seq, tlp_seq,
+                c_seq, lpc_seq, tid_seq, tlp_seq,
                 self._cache, self._tok_hist, self._lengths, self._last_tokens, self._keys,
             ) = self._decode_jit(
                 self.params,
@@ -2615,7 +2531,6 @@ class Engine:
                 self._adm_len.copy(),
                 self._adm_seed.copy(),
                 self._adm_toks,
-                **adm_hist,
                 **lora_args,
             )
         self._adm_mask[:] = False
@@ -2630,7 +2545,7 @@ class Engine:
         )
         for part, r in zip(EPILOGUE_PARTS, ran):
             self.m_epilogue.inc(labels={"part": part, "ran": "1" if r else "0"})
-        payload = (d_seq, c_seq, a_seq, lpd_seq, lpc_seq)
+        payload = (c_seq, lpc_seq)
         if ran[0]:
             # Only then do the top-N arrays hold anything (zeros
             # otherwise): a chunk that ran without them never hands
@@ -2643,15 +2558,14 @@ class Engine:
         # slot active at the dispatch asked for logprobs: only then did
         # the device compute them (_dispatch_chunk_call).
         with self._stall.segment("fetch_wait", of="chunk") as fetched:  # device_get blocks
-            drafts, corr, acc, lp_d, lp_c, *top = jax.device_get(payload)
+            corr, lp_c, *top = jax.device_get(payload)
             t_ids, t_lp = top or (None, None)
         # The chunk's turnaround: dispatch call returned -> results on the host.
         dur = fetched.t1 - dispatched.t1
-        acc = np.asarray(acc)  # [K, B]
-        with self._stall.segment("emit", tokens=int(acc.sum()) + acc.shape[0] * len(snapshot)):
+        corr = np.asarray(corr)  # [K, B]
+        with self._stall.segment("emit", tokens=corr.shape[0] * len(snapshot)):
             step = self._emit_chunk(
-                snapshot, dur, np.asarray(drafts), np.asarray(corr), acc,
-                np.asarray(lp_d), np.asarray(lp_c),
+                snapshot, dur, corr, np.asarray(lp_c),
                 None if t_ids is None else np.asarray(t_ids),
                 None if t_lp is None else np.asarray(t_lp),
             )
@@ -2663,76 +2577,52 @@ class Engine:
             **step, **{f"{c}_ms": round(ms.get(c, 0.0), 3) for c in _CHUNK_SEGMENTS}
         )
 
-    def _emit_chunk(self, snapshot, dur, drafts, corr, acc, lp_d, lp_c, t_ids, t_lp) -> dict:
-        """Deliver a fetched chunk's tokens ([K, B, G] drafts, [K, B]
-        corrections and acceptance counts, their log-probs, [K, B, G+1, N]
-        top-N alternatives or None); returns its step record, less the
+    def _emit_chunk(self, snapshot, dur, corr, lp_c, t_ids, t_lp) -> dict:
+        """Deliver a fetched chunk's tokens ([K, B] device-chosen tokens
+        and their log-probs, [K, B, N] top-N alternatives or None): one
+        token a live slot a step; returns its step record, less the
         segment times."""
-        G = drafts.shape[2]
         # Saturation accounting BEFORE emission: this chunk ran K fused
         # steps over the full [B] batch with only the snapshot's slots
         # doing useful work, and the step's wall time (dispatch ->
         # results fetched) is known the moment the device_get returns.
         # Emission below delivers terminal events — a client unblocked
         # by one must already see these observations.
-        K_steps = int(acc.shape[0])
+        K_steps = int(corr.shape[0])
         self.m_step.observe(dur, labels={"phase": "decode_chunk"})
         self.m_slot_steps.inc(K_steps * len(snapshot), labels={"state": "active"})
         idle = K_steps * (self.cfg.max_slots - len(snapshot))
         if idle:
             self.m_slot_steps.inc(idle, labels={"state": "idle"})
         n_emitted = 0
-        spec_drafted = spec_accepted = 0
-        for k in range(acc.shape[0]):
+        for k in range(K_steps):
             for i, slot_obj, epoch in snapshot:
-                a = int(acc[k, i])
-                if self._slots[i] is slot_obj:
-                    # One PRNG key evolution per fused step whose tokens
-                    # reach emission — a park snapshot reconstructs the
-                    # slot key from this count (see _Slot.kv_steps).
-                    slot_obj.kv_steps += 1
-                want_top = (
-                    t_ids is not None
-                    and self._slots[i] is slot_obj
-                    and slot_obj.req.params.logprobs
-                )
-
-                def top_at(pos):
-                    if not want_top:
-                        return None
-                    return list(zip(t_ids[k, i, pos].tolist(), t_lp[k, i, pos].tolist()))
-
-                # Accepted drafts then the device-chosen next token (the
-                # model's continuation input — greedy argmax OR sampled),
-                # each with its logprob under the model. Position j's
-                # top-N is the model's distribution at that choice point.
-                emitted = [
-                    (int(drafts[k, i, j]), float(lp_d[k, i, j]), top_at(j))
-                    for j in range(a)
-                ]
-                emitted.append((int(corr[k, i]), float(lp_c[k, i]), top_at(a)))
-                if G and self._slots[i] is slot_obj \
-                        and slot_obj.req.params.temperature <= 0.0:
-                    self.m_spec_drafted.inc(G)
-                    self.m_spec_accepted.inc(a)
-                    spec_drafted += G
-                    spec_accepted += a
-                for tok, lp, top in emitted:
-                    # Record KV residency for prefix reuse: each step
-                    # WROTE its pending (input) token; each emitted token
-                    # becomes the next write. Skip if a new occupant
-                    # reset the slot.
-                    if self._slot_epoch[i] == epoch:
-                        if self._kv_pending[i] is not None:
-                            self._kv_history[i].append(self._kv_pending[i])
-                        self._kv_pending[i] = tok
-                    # Emit only while the slot still belongs to the
-                    # request it held at dispatch time (it may finish
-                    # mid-chunk, or have been freed and re-admitted
-                    # since dispatch).
-                    if self._slots[i] is slot_obj:
-                        self._emit_token(i, tok, lp, top)
-                        n_emitted += 1
+                # The device-chosen next token (the model's continuation
+                # input — greedy argmax OR sampled) with its logprob
+                # under the model.
+                tok = int(corr[k, i])
+                # Record KV residency for prefix reuse: each step WROTE
+                # its pending (input) token; the emitted token becomes
+                # the next write. Skip if a new occupant reset the slot.
+                if self._slot_epoch[i] == epoch:
+                    if self._kv_pending[i] is not None:
+                        self._kv_history[i].append(self._kv_pending[i])
+                    self._kv_pending[i] = tok
+                # Emit only while the slot still belongs to the request
+                # it held at dispatch time (it may finish mid-chunk, or
+                # have been freed and re-admitted since dispatch).
+                if self._slots[i] is not slot_obj:
+                    continue
+                # One PRNG key evolution per fused step whose token
+                # reaches emission — a park snapshot reconstructs the
+                # slot key from this count (see _Slot.kv_steps).
+                slot_obj.kv_steps += 1
+                top = None
+                if t_ids is not None and slot_obj.req.params.logprobs:
+                    # The model's distribution at this choice point.
+                    top = list(zip(t_ids[k, i].tolist(), t_lp[k, i].tolist()))
+                self._emit_token(i, tok, float(lp_c[k, i]), top)
+                n_emitted += 1
         # Goodput gauge: emitted tokens over a sliding ~10s window
         # (shared TokenRateWindow — counter-delta semantics, so it
         # agrees with the fleet collector's derivation by construction).
@@ -2747,9 +2637,7 @@ class Engine:
             "steps": K_steps,
             "slots": [i for i, _, _ in snapshot],
             "tokens": n_emitted,
-            "kernel": self._attn_kernel(
-                "decode_chunk", 1 + self.cfg.speculate_tokens
-            ),
+            "kernel": self._attn_kernel("decode_chunk", 1),
             "pages_used": self._pool.used(),
             "pages_total": self._pool.num_pages - 1,
             "queue_depth": self.queue_depth(),
@@ -2760,9 +2648,6 @@ class Engine:
             # the pipelining overlapped.
             "dur_ms": round(dur * 1000, 3),
         }
-        if G:
-            step["spec_drafted"] = spec_drafted
-            step["spec_accepted"] = spec_accepted
         return step
 
     def _emit_token(self, slot_idx: int, token_id: int, logprob: float | None = None, top=None):
@@ -3273,15 +3158,7 @@ class StepFunctions:
 
     prefill_batch_jit: Any
     prefill_chunk_jit: Any
-    decode_jits: dict
-    make_decode_jit: Any
-    decode_kernel: str
-
-    def decode_jit_for(self, kernel: str):
-        fn = self.decode_jits.get(kernel)
-        if fn is None:
-            fn = self.decode_jits[kernel] = self.make_decode_jit(kernel)
-        return fn
+    decode_jit: Any
 
 
 def build_step_functions(
@@ -3300,11 +3177,6 @@ def build_step_functions(
     masked); defaults to the model vocab (no padding mask)."""
     mc = model_config
     cfg = engine_config
-    if cfg.decode_kernel not in ("ragged", "dedicated", "auto"):
-        raise ValueError(
-            f"decode_kernel must be 'ragged', 'dedicated' or 'auto', "
-            f"got {cfg.decode_kernel!r}"
-        )
     n_valid = mc.vocab_size if n_valid_vocab is None else min(n_valid_vocab, mc.vocab_size)
 
     def mask_pad(logits):
@@ -3368,47 +3240,14 @@ def build_step_functions(
         return tok, lp, t_ids.astype(jnp.int32), t_lp, cache, adm_toks
 
     K = cfg.decode_chunk
-    G = cfg.speculate_tokens
 
-    def ngram_drafts(hist, lengths, last):
-        """Per-slot 2-gram continuation lookup over the device token
-        history: find the latest previous occurrence of the bigram
-        (hist[L-1], last) and propose the G tokens that followed it.
-        No match (or tail too short) proposes zeros, which simply
-        fail verification. All shapes static; runs inside the scan."""
-        Sh = hist.shape[1]
-        idx = jnp.arange(Sh)
-
-        def one(h, L, a):
-            prev = h[jnp.maximum(L - 1, 0)]
-            nxt = jnp.roll(h, -1)  # nxt[j] = h[j+1]
-            ok = (h == prev) & (nxt == a) & (idx < L - 1) & (L > 0)
-            found = ok.any()
-            j = jnp.argmax(jnp.where(ok, idx, -1))
-            didx = j + 2 + jnp.arange(G)
-            valid = found & (didx < L)
-            return jnp.where(valid, h[jnp.clip(didx, 0, Sh - 1)], 0)
-
-        return jax.vmap(one)(hist, lengths, last)
-
-    def make_decode_fn(decode_kernel: str):
-        """Decode step builder, parameterized by the CONCRETE paged-
-        attention kernel ("ragged" | "dedicated") baked into the
-        trace. Rank 0 resolves EngineConfig.decode_kernel once and
-        broadcasts the resolution with every decode op; a follower
-        whose own config disagrees compiles the broadcast flavor
-        (gang lockstep: all ranks must run the same program)."""
-        return partial(decode_fn, _decode_kernel=decode_kernel)
-
-    def decode_fn(params, cache, tables, hist, lengths, last_tokens, keys, active, temp, top_p, top_k, presence, frequency, want_top, gen_start, bias_ids, bias_vals, adm_mask, adm_len, adm_seed, adm_toks, adm_hist=None, lora=None, lora_rows=None, _decode_kernel="ragged"):
-        """K fused decode steps, each verifying up to G drafts.
-        Returns (drafts [K, B, G], corr [K, B], accepted [K, B]) —
-        the host emits drafts[:a] + [corr] per slot per step, where
-        corr is THE device-chosen next token (greedy: the model's
-        argmax after the accepted drafts; sampled: the sampled
-        token — never substitute argmax, the device decodes from
-        corr so emission must match it). G=0 reduces exactly to
-        one-token-per-step decoding.
+    def decode_fn(params, cache, tables, hist, lengths, last_tokens, keys, active, temp, top_p, top_k, presence, frequency, want_top, gen_start, bias_ids, bias_vals, adm_mask, adm_len, adm_seed, adm_toks, lora=None, lora_rows=None):
+        """K fused decode steps, one token a slot a step. Returns
+        (corr [K, B], lp_corr [K, B], t_ids [K, B, N], t_lp [K, B, N])
+        then the five carries. corr is THE device-chosen next token
+        (greedy: the model's argmax; sampled: the sampled token — never
+        substitute argmax, the device decodes from corr so emission
+        must match it).
 
         Slots admitted since the last dispatch are REBASED in-graph
         (adm_mask/adm_len/adm_seed numpy from the host; adm_toks the
@@ -3444,35 +3283,26 @@ def build_step_functions(
         )
         lengths = jnp.where(adm_mask, adm_len, lengths)
         last_tokens = jnp.where(adm_mask, adm_toks, last_tokens)
-        if G > 0:
-            hist = jnp.where(adm_mask[:, None], adm_hist, hist)
 
         def body(carry, _):
             cache, hist, lengths, last, keys = carry
-            if G > 0:
-                drafts = ngram_drafts(hist, lengths, last)
-            else:
-                drafts = jnp.zeros((B, 0), jnp.int32)
-            inputs = jnp.concatenate([last[:, None], drafts], axis=1)
-            # Record the inputs this step WRITES into KV at positions
-            # lengths..lengths+G (history width covers overshoot) —
-            # BEFORE the penalty window is read, so position
-            # `lengths` (= the previously emitted token, this step's
-            # input) is already in the history when penalties count
-            # it (ADVICE r5: computing penalties first lagged them
-            # one token — the most recent token's first immediate
-            # repeat went unpenalized, off OpenAI/vLLM semantics).
-            pos = lengths[:, None] + jnp.arange(G + 1, dtype=jnp.int32)
-            hist = hist.at[jnp.arange(B)[:, None], pos].set(
-                jnp.where(active[:, None], inputs, jnp.take_along_axis(hist, pos, axis=1))
+            # Record the input this step WRITES into KV at position
+            # `lengths` BEFORE the penalty window is read, so the
+            # previously emitted token (this step's input) is already
+            # in the history when penalties count it (ADVICE r5:
+            # computing penalties first lagged them one token — the
+            # most recent token's first immediate repeat went
+            # unpenalized, off OpenAI/vLLM semantics).
+            rows = jnp.arange(B)
+            hist = hist.at[rows, lengths].set(
+                jnp.where(active, last, hist[rows, lengths])
             )
-            logits, cache = llama.decode_speculative_paged(
-                params, mc, inputs, cache, tables, lengths,
-                lora=lora, lora_rows=lora_rows,
-                decode_kernel=_decode_kernel, tp_mesh=mesh,
+            logits, cache = llama.decode_step_paged(
+                params, mc, last[:, None], cache, tables, lengths,
+                lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
             )
             with jax.named_scope("sampling"):
-                logits = mask_pad(logits)  # [B, G+1, V]
+                logits = mask_pad(logits[:, 0])  # [B, V]
 
                 def penalized():
                     # OpenAI presence/frequency penalties over the
@@ -3480,128 +3310,81 @@ def build_step_functions(
                     # [gen_start, lengths] INCLUSIVE: position `lengths`
                     # holds this step's input (the token emitted last
                     # step, just scattered above), so the full output so
-                    # far counts. Unaccepted-draft overshoot sits at
-                    # positions > lengths, outside the window. Applied
-                    # to position 0 (the token being chosen this step);
-                    # penalty slots never accept drafts (below), so
-                    # positions 1..G stay penalty-free verify lanes.
-                    # The penalized view steers CHOICE only (argmax /
-                    # sampling); reported logprobs stay the model's raw
-                    # log p(token | prefix), matching how temperature /
-                    # top_p shape choice without reshaping logprobs.
+                    # far counts. The penalized view steers CHOICE only
+                    # (argmax / sampling); reported logprobs stay the
+                    # model's raw log p(token | prefix), matching how
+                    # temperature / top_p shape choice without reshaping
+                    # logprobs.
                     w_idx = jnp.arange(hist.shape[1], dtype=jnp.int32)[None, :]
                     pen_valid = (w_idx >= gen_start[:, None]) & (
                         w_idx <= lengths[:, None]
                     )
                     return apply_penalties(
-                        logits[:, 0], hist, pen_valid, presence, frequency
+                        logits, hist, pen_valid, presence, frequency
                     )
 
                 # No active slot set a penalty: subtracting zeros gives
                 # the logits back, so the scatters are left out.
-                pen0 = jax.lax.cond(run_pen, penalized, lambda: logits[:, 0])
-                pen0 = apply_logit_bias(pen0, bias_ids, bias_vals)
+                pen = jax.lax.cond(run_pen, penalized, lambda: logits)
+                pen = apply_logit_bias(pen, bias_ids, bias_vals)
             with jax.named_scope("logprobs"):
                 # Chosen-token logprob = raw logit - logsumexp: avoids
-                # materializing a normalized [B, G+1, V] tensor in the
-                # hottest loop just to gather G+1 entries.
-                lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B, G+1]
+                # materializing a normalized [B, V] tensor in the
+                # hottest loop just to gather one entry a slot.
+                lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B]
             with jax.named_scope("sampling"):
-                yhat = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                yhat0_pen = jnp.argmax(pen0, axis=-1).astype(jnp.int32)
-                # Greedy slots accept the longest draft prefix the model
-                # agrees with (exactness by causality); sampled slots
-                # accept nothing and sample position 0 as before. Slots
-                # with any penalty also accept nothing: draft exactness
-                # is argmax-equivalence against the UNpenalized verify
-                # lanes, which a penalized distribution breaks.
-                greedy = temp <= 0.0
-                if G > 0:
-                    matches = (yhat[:, :G] == drafts).astype(jnp.int32)
-                    acc = jnp.cumprod(matches, axis=1).sum(axis=1)
-                    # Penalty/bias slots accept nothing: the verify
-                    # lanes (positions 1..G) are raw-argmax.
-                    no_pen = (
-                        (presence == 0.0)
-                        & (frequency == 0.0)
-                        & (bias_vals == 0.0).all(axis=1)
-                    )
-                    acc = jnp.where(greedy & active & no_pen, acc, 0)
-                else:
-                    acc = jnp.zeros((B,), jnp.int32)
+                yhat_pen = jnp.argmax(pen, axis=-1).astype(jnp.int32)
                 # The keys split every step, whatever the batch holds:
                 # a seeded sampled request draws the same stream whether
                 # or not its neighbours open the gate around it.
                 step_keys = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
                 # Every active slot greedy: `sample` would return the
-                # argmax of pen0 for each, which is yhat0_pen.
-                sampled0 = jax.lax.cond(
+                # argmax of pen for each, which is yhat_pen.
+                sampled = jax.lax.cond(
                     run_cand,
                     lambda: sample(
-                        pen0, step_keys[:, 0], temp, top_p, top_k, max_top_k=mtk
+                        pen, step_keys[:, 0], temp, top_p, top_k, max_top_k=mtk
                     ),
-                    lambda: yhat0_pen,
+                    lambda: yhat_pen,
                 )
-                # Greedy: position 0 picks from the penalized view
-                # (identical to raw when penalties are zero); accepted-
-                # draft positions (acc>0, only reachable penalty-free)
-                # pick from the raw verify lanes.
-                greedy_pick = jnp.where(
-                    acc > 0,
-                    jnp.take_along_axis(yhat, acc[:, None], axis=1)[:, 0],
-                    yhat0_pen,
-                )
-                corr = jnp.where(greedy, greedy_pick, sampled0)
+                # Greedy picks from the penalized view (identical to raw
+                # when penalties are zero).
+                corr = jnp.where(temp <= 0.0, yhat_pen, sampled)
                 corr = jnp.where(active, corr, last)
             with jax.named_scope("logprobs"):
-                if G > 0:
-                    lp_d = (
-                        jnp.take_along_axis(
-                            logits[:, :G], drafts[:, :, None], axis=2
-                        )[:, :, 0]
-                        - lse[:, :G]
-                    )
-                else:
-                    lp_d = jnp.zeros((B, 0), jnp.float32)
-                logits_at_a = jnp.take_along_axis(logits, acc[:, None, None], axis=1)[:, 0]
                 lp_corr = (
-                    jnp.take_along_axis(logits_at_a, corr[:, None], axis=1)[:, 0]
-                    - jnp.take_along_axis(lse, acc[:, None], axis=1)[:, 0]
+                    jnp.take_along_axis(logits, corr[:, None], axis=1)[:, 0] - lse
                 )
 
                 def top_alternatives():
-                    # Top-N alternatives per position (raw model dist,
-                    # pre-penalty/bias — same contract as the chosen
-                    # logprob), as top_k of the 2-D view: on the chip a
-                    # [B, G+1, V] operand lowers to a sort of the whole
-                    # vocabulary, a [B*(G+1), V] one to the TopK custom
-                    # call (same values and ids, ties to the lower id).
-                    t_raw, t_ids = jax.lax.top_k(
-                        logits.reshape(B * (G + 1), -1), topn
-                    )
-                    t_lp = t_raw.reshape(B, G + 1, topn) - lse[..., None]
-                    return t_ids.reshape(B, G + 1, topn).astype(jnp.int32), t_lp
+                    # Top-N alternatives (raw model dist, pre-penalty/
+                    # bias — same contract as the chosen logprob). The
+                    # operand stays 2-D: on the chip a [B, V] top_k
+                    # lowers to the TopK custom call, one with a third
+                    # axis to a sort of the whole vocabulary.
+                    t_raw, t_ids = jax.lax.top_k(logits, topn)
+                    return t_ids.astype(jnp.int32), t_raw - lse[:, None]
 
                 t_ids, t_lp = jax.lax.cond(
                     run_top,
                     top_alternatives,
                     lambda: (
-                        jnp.zeros((B, G + 1, topn), jnp.int32),
-                        jnp.zeros((B, G + 1, topn), jnp.float32),
+                        jnp.zeros((B, topn), jnp.int32),
+                        jnp.zeros((B, topn), jnp.float32),
                     ),
                 )
-            lengths = jnp.where(active, lengths + acc + 1, lengths)
+            lengths = jnp.where(active, lengths + 1, lengths)
             return (cache, hist, lengths, corr, step_keys[:, 1]), (
-                drafts, corr, acc, lp_d, lp_corr, t_ids, t_lp,
+                corr, lp_corr, t_ids, t_lp,
             )
 
         (cache, hist, lengths, last, keys), (
-            d_seq, c_seq, a_seq, lpd_seq, lpc_seq, tid_seq, tlp_seq,
+            c_seq, lpc_seq, tid_seq, tlp_seq,
         ) = jax.lax.scan(
             body, (cache, hist, lengths, last_tokens, keys), None, length=K
         )
         return (
-            d_seq, c_seq, a_seq, lpd_seq, lpc_seq, tid_seq, tlp_seq,
+            c_seq, lpc_seq, tid_seq, tlp_seq,
             cache, hist, lengths, last, jax.random.key_data(keys),
         )
 
@@ -3626,18 +3409,13 @@ def build_step_functions(
             for k, s in paged_cache_specs().items()
         }
         shard_kw = {
-            "out_shardings": (repl, repl, repl, repl, repl, repl, repl, cache_sh, repl, repl, repl, repl)
+            "out_shardings": (repl, repl, repl, repl, cache_sh, repl, repl, repl, repl)
         }
         chunk_kw = {"out_shardings": (repl, repl, repl, repl, cache_sh, repl)}
     # tables + per-slot request state (active/temp/top_p/top_k and
     # the adm_* merge arrays) are host-authoritative numpy uploaded
     # per dispatch — not donated. cache/hist/lengths/last/keys are
-    # the device carries. One jit per kernel flavor, built lazily
-    # (decode_jit_for): the configured flavor compiles at warmup as
-    # before; a follower only pays for a second flavor if rank 0's
-    # broadcast actually asks for it.
-    from kubeai_tpu.ops.paged_decode_attention import resolve_decode_kernel
-
+    # the device carries.
     return StepFunctions(
         prefill_batch_jit=jax.jit(
             prefill_batch_fn, donate_argnums=(11, 12), **chunk_kw
@@ -3645,12 +3423,14 @@ def build_step_functions(
         prefill_chunk_jit=jax.jit(
             prefill_chunk_fn, donate_argnums=(12, 13), **chunk_kw
         ),
-        decode_jits={},
-        make_decode_jit=lambda kernel: jax.jit(
-            make_decode_fn(kernel), donate_argnums=(1, 3, 4, 5, 6), **shard_kw
-        ),
-        decode_kernel=resolve_decode_kernel(
-            cfg.decode_kernel, 1 + cfg.speculate_tokens
+        # Jitted through a partial ON PURPOSE: a partial has no
+        # __name__, so the program is `jit__unknown` on the device trace,
+        # the name perfbench/layer_metrics/ selects the decode program
+        # by. jax.jit(decode_fn) would rename it and null every decode
+        # per-layer metric; the rename moves with those files (ROADMAP
+        # B-III).
+        decode_jit=jax.jit(
+            partial(decode_fn), donate_argnums=(1, 3, 4, 5, 6), **shard_kw
         ),
     )
 

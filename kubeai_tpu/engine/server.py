@@ -1403,9 +1403,7 @@ def engine_config_from_args(args):
         page_size=getattr(args, "page_size", 64),
         num_pages=getattr(args, "kv_pages", 0),
         prefix_cache_min=getattr(args, "prefix_cache_min", 16),
-        speculate_tokens=getattr(args, "speculate_tokens", 0),
         kv_cache_dtype=getattr(args, "kv_cache_dtype", ""),
-        decode_kernel=getattr(args, "decode_kernel", "ragged"),
     )
 
 
@@ -1582,18 +1580,6 @@ def make_engine_arg_parser(require_model: bool = True) -> argparse.ArgumentParse
     parser.add_argument(
         "--prefix-cache-min", type=int, default=16,
         help="min shared-prefix tokens to reuse across slots (0 disables)",
-    )
-    parser.add_argument(
-        "--speculate-tokens", type=int, default=0,
-        help="draft tokens verified per decode step via n-gram prompt "
-             "lookup (greedy-exact; 0 disables)",
-    )
-    parser.add_argument(
-        "--decode-kernel", default="ragged",
-        choices=["ragged", "dedicated", "auto"],
-        help="decode-path paged-attention kernel: the shared ragged "
-             "kernel, the dedicated S=1 decode-blocked kernel, or "
-             "auto (picked by decode query length)",
     )
     parser.add_argument(
         "--role", default="", choices=["", "prefill", "decode"],

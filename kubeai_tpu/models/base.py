@@ -48,10 +48,10 @@ class ModelConfig:
     # on TPU; only valid without softcap/sliding-window).
     use_flash_prefill: bool = False
     # Use the ragged paged-attention kernel over the paged KV pool for
-    # decode AND speculative verification (set by the engine on TPU;
-    # only valid without sliding-window — softcap is supported). The
-    # portable path gathers pages via XLA; on CPU the kernel path runs
-    # a jit-safe semantics twin.
+    # decode and chunked prefill (set by the engine on TPU; only valid
+    # without sliding-window — softcap is supported). The portable path
+    # gathers pages via XLA; on CPU the kernel path runs a jit-safe
+    # semantics twin.
     use_paged_kernel: bool = False
     dtype: str = "bfloat16"
     # Paged KV pool storage dtype: "" keeps the compute dtype; "fp8"

@@ -180,11 +180,10 @@ def init_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> Par
 def init_paged_cache(config: ModelConfig, num_pages: int, page_size: int, dtype=None) -> Params:
     """Paged KV pool: one FLAT array [L*P, page, 2*Kv, head_dim] with K/V
     interleaved on the head axis (K at even indices, V at odd — the TPU
-    ragged-paged-attention kernel's native layout, so prefill, decode,
-    and speculative verification all read pages in place with zero
-    re-layout). Layer l owns pool rows [l*P, (l+1)*P); the engine's
-    block tables stay layer-agnostic (logical pages 0..P-1) and the
-    forward adds the l*P offset in-graph.
+    ragged-paged-attention kernel's native layout, so prefill and decode
+    both read pages in place with zero re-layout). Layer l owns pool
+    rows [l*P, (l+1)*P); the engine's block tables stay layer-agnostic
+    (logical pages 0..P-1) and the forward adds the l*P offset in-graph.
 
     Why flat instead of a stacked [L, P, ...] leading layer axis: the
     layer scan would then have to slice layer l's 100MB+ pool plane out
@@ -244,8 +243,8 @@ def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, page
         and config.sliding_window == 0
     ):
         return "flash"
-    # Paged kernels handle 1..S queries per slot, so plain decode AND
-    # speculative verification read pages in place; per-layer sliding-
+    # The paged kernel handles 1..S queries per slot, so decode and
+    # chunked prefill read pages in place; per-layer sliding-
     # window interleaves can't use one static kernel window, so
     # Gemma2-style configs take the gather path.
     if config.use_paged_kernel and paged and config.sliding_window == 0:
@@ -342,11 +341,6 @@ def apply(
     left_aligned: bool = False,  # caller guarantees positions == arange(S)
     return_hidden: bool = False,  # final-norm hidden states instead of logits
     page_table: jnp.ndarray | None = None,  # [B, max_pages] pool page per seq page
-    decode_kernel: str = "ragged",  # paged-kernel flavor for this call:
-    # "ragged" (the shared prefill-tuned kernel), "dedicated" (the
-    # S=1/G+1 decode-blocked kernel, ops/paged_decode_attention), or
-    # "auto" (keyed on S at trace time). Only decode-path callers pass
-    # non-default; prefill always rides the ragged/flash paths.
     ring_mesh=None,  # Mesh with an `sp` axis: cache-less attention runs
     # as ring attention over sequence-sharded blocks (ppermute ring,
     # O((S/sp)^2) scores per device — parallel/ring_attention.py). The
@@ -411,11 +405,6 @@ def apply(
     )
     use_flash = route == "flash"
     use_paged_kernel = route == "paged_kernel"
-    use_dedicated_decode = False
-    if use_paged_kernel:
-        from kubeai_tpu.ops.paged_decode_attention import resolve_decode_kernel
-
-        use_dedicated_decode = resolve_decode_kernel(decode_kernel, S) == "dedicated"
 
     def per_tp_shard(fn, n_head_split, n_replicated=0):
         """Run an attention kernel on each tp shard's heads: the first
@@ -567,18 +556,11 @@ def apply(
                 k_att, v_att = k, v
 
             if use_paged_kernel:
-                if use_dedicated_decode:
-                    from kubeai_tpu.ops.paged_decode_attention import (
-                        paged_decode_attention as paged_attn_fn,
-                    )
-                else:
-                    from kubeai_tpu.ops.paged_attention import (
-                        paged_attention_ragged as paged_attn_fn,
-                    )
+                from kubeai_tpu.ops.paged_attention import paged_attention_ragged
 
                 with jax.named_scope("attn.kernel"):
                     attn_out = per_tp_shard(
-                        lambda q_, kv_, table_, lens_: paged_attn_fn(
+                        lambda q_, kv_, table_, lens_: paged_attention_ragged(
                             q_, kv_, table_, lens_,
                             scale=config.query_scale,
                             softcap=config.attn_softcap,
@@ -794,28 +776,10 @@ def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=N
     )
 
 
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, decode_kernel="ragged", tp_mesh=None):
+def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None):
     """One paged decode step for [B, 1] tokens at positions *lengths* [B].
     Returns (logits [B, 1, V], pool)."""
     return apply(
         params, config, tokens, lengths[:, None].astype(jnp.int32), pool,
-        lora=lora, lora_rows=lora_rows, page_table=page_table,
-        decode_kernel=decode_kernel, tp_mesh=tp_mesh,
-    )
-
-
-def decode_speculative_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, decode_kernel="ragged", tp_mesh=None):
-    """Speculative paged decode: [B, S] candidate tokens (real next token
-    + S-1 drafts) at positions lengths..lengths+S-1. Returns logits for
-    ALL S positions ([B, S, V], for draft verification) and the pool.
-    Causality makes verification exact: logits at position j depend only
-    on inputs 0..j, so a draft mismatch at j invalidates positions > j
-    without contaminating <= j. *decode_kernel* selects the paged
-    attention flavor (EngineConfig.decode_kernel; see apply())."""
-    S = tokens.shape[1]
-    pos = lengths[:, None].astype(jnp.int32) + jnp.arange(S, dtype=jnp.int32)[None, :]
-    return apply(
-        params, config, tokens, pos, pool,
-        lora=lora, lora_rows=lora_rows, page_table=page_table,
-        decode_kernel=decode_kernel, tp_mesh=tp_mesh,
+        lora=lora, lora_rows=lora_rows, page_table=page_table, tp_mesh=tp_mesh,
     )

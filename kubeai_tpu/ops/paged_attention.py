@@ -1,14 +1,13 @@
-"""Paged attention for TPU: in-place page reads for prefill, decode,
-and speculative verification.
+"""Paged attention for TPU: in-place page reads for prefill and decode.
 
 Wraps JAX's ragged-paged-attention Pallas kernel (the vLLM-TPU
 workhorse): KV lives as [P, page, 2*Kv, h] pages with K/V interleaved
 on the head axis, a block table maps each slot's positions onto pages,
-and queries of ANY length per slot (1 for plain decode, G+1 for
-speculative verification, a whole bucket for prefill) attend causally
-with pages streamed HBM->VMEM — no gathered contiguous copy of the KV
-span (the portable XLA path in models/llama.py gathers; acceptable on
-CPU tests, wasteful on a bandwidth-bound TPU).
+and queries of ANY length per slot (1 for decode, a few for a caller
+that scores several tokens a step, a whole bucket for prefill) attend
+causally with pages streamed HBM->VMEM — no gathered contiguous copy of
+the KV span (the portable XLA path in models/llama.py gathers;
+acceptable on CPU tests, wasteful on a bandwidth-bound TPU).
 
 On non-TPU backends this dispatches to the library's pure-JAX reference
 implementation (identical semantics), so the engine's kernel path is

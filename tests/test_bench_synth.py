@@ -44,8 +44,8 @@ def test_worker_emits_headline_before_teardown_failure(monkeypatch, capsys):
 
     monkeypatch.setattr(Engine, "stop", exploding_stop)
     args = types.SimpleNamespace(
-        preset="tiny", watchdog=0, requests=2, max_tokens=2, speculate=0,
-        greedy=False, slots=0, chunk=0, kv_dtype="", decode_kernel="",
+        preset="tiny", watchdog=0, requests=2, max_tokens=2,
+        greedy=False, slots=0, chunk=0, kv_dtype="",
         request_rate=0, rate_duration=45.0,
     )
     bench.run_worker(args)  # must not raise despite the exploding stop

@@ -3,7 +3,8 @@ candidates, penalties) runs only in the chunks where an active slot asked
 for it. Mixed batches: a request that needs one part joins others that are
 decoding and leaves before they end, so the gate opens and shuts around
 them; every stream is the one its request gets without the company, and
-the counter says which chunks ran what."""
+the counter says which chunks ran what. The gates are evaluated once a
+dispatch, so every case runs at chunks of 1, 3 and 4 steps."""
 
 import re
 
@@ -63,30 +64,30 @@ CASES = {
 
 
 class _Engines:
-    """One engine a speculation depth, built on first use; the streams of a
+    """One engine a chunk length, built on first use; the streams of a
     set of requests decoding together with no guest, computed once."""
 
     def __init__(self):
         self._engines: dict[int, Engine] = {}
         self._baselines: dict = {}
 
-    def get(self, spec: int) -> Engine:
-        if spec not in self._engines:
+    def get(self, chunk: int) -> Engine:
+        if chunk not in self._engines:
             eng = Engine(
                 CFG, llama.init_params(CFG, jax.random.key(31)), ByteTokenizer(),
                 EngineConfig(
                     max_slots=4, max_seq_len=512, prefill_buckets=(32, 64),
-                    page_size=16, decode_chunk=3, speculate_tokens=spec,
+                    page_size=16, decode_chunk=chunk,
                 ),
             )
             eng.start()
-            self._engines[spec] = eng
-        return self._engines[spec]
+            self._engines[chunk] = eng
+        return self._engines[chunk]
 
-    def baseline(self, spec: int, requests: list) -> list:
-        key = (spec, tuple(id(r) for r in requests))
+    def baseline(self, chunk: int, requests: list) -> list:
+        key = (chunk, tuple(id(r) for r in requests))
         if key not in self._baselines:
-            eng = self.get(spec)
+            eng = self.get(chunk)
             reqs = [eng.submit(list(p), sp) for p, sp in requests]
             self._baselines[key] = [_drain(r) for r in reqs]
         return self._baselines[key]
@@ -152,13 +153,13 @@ class _GateLog:
         return "".join(c[part] for c in self.chunks)
 
 
-@pytest.mark.parametrize("spec", [0, 2], ids=["plain", "speculate2"])
+@pytest.mark.parametrize("chunk", [3, 1, 4], ids=["plain", "chunk1", "chunk4"])
 @pytest.mark.parametrize("case", list(CASES))
-def test_a_guest_joins_and_leaves_and_every_stream_is_its_own(engines, monkeypatch, case, spec):
+def test_a_guest_joins_and_leaves_and_every_stream_is_its_own(engines, monkeypatch, case, chunk):
     hosts, guests, flipping, steady = CASES[case]
-    eng = engines.get(spec)
-    alone = [engines.baseline(spec, [g])[0] for g in guests]
-    hosts_alone = engines.baseline(spec, hosts)
+    eng = engines.get(chunk)
+    alone = [engines.baseline(chunk, [g])[0] for g in guests]
+    hosts_alone = engines.baseline(chunk, hosts)
     log = _GateLog(eng.m_epilogue)
     monkeypatch.setattr(eng, "m_epilogue", log)
 
@@ -194,7 +195,7 @@ def test_a_guest_joins_and_leaves_and_every_stream_is_its_own(engines, monkeypat
 
 
 def test_the_counter_moves_by_one_a_part_and_chunk(engines):
-    eng = engines.get(0)
+    eng = engines.get(3)
     value = lambda part, ran: eng.m_epilogue.value({"part": part, "ran": ran})  # noqa: E731
     before = {(p, r): value(p, r) for p in EPILOGUE_PARTS for r in "01"}
     toks = _drain(eng.submit(_prompt(5), SamplingParams(temperature=0.0, max_tokens=12, logprobs=True)))

@@ -55,6 +55,62 @@ class TestEngineCore:
         ids2, _, _ = eng.generate(eng.tokenizer.encode("xyz"), p)
         assert ids1 == ids2
 
+    def test_sampled_stream_matches_independent_reference(self, server):
+        """Golden check AGAINST THE MODEL, not a sibling engine: replay the
+        engine's documented key discipline (prefill samples with key(seed);
+        decode carries fold_in(key,1) and splits per step) with raw
+        llama.* calls and the sampler, and require the engine to emit
+        exactly that stream for a seeded temperature>0 request. A
+        decode-path bug (e.g. emitting argmax instead of the sampled
+        token) cannot hide from this."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from kubeai_tpu.engine.sampling import sample
+        from kubeai_tpu.models import llama
+
+        eng = server.engine
+        mc, ps = eng.model_config, eng.cfg.page_size
+        prompt = np.random.default_rng(9).integers(1, 200, 20).tolist()
+        n_new = 8
+        p = SamplingParams(temperature=0.8, top_p=0.9, max_tokens=n_new, seed=123)
+
+        mp = eng.cfg.max_seq_len // ps
+        pool = llama.init_paged_cache(mc, num_pages=1 + mp, page_size=ps)
+        table = jnp.asarray(np.arange(1, 1 + mp, dtype=np.int32)[None, :])
+        n_valid = eng.tokenizer.vocab_size  # the engine masks padded logits
+
+        def mask_pad(logits):
+            return logits.at[..., n_valid:].set(-jnp.inf)
+
+        padded = np.zeros((1, 32), np.int32)
+        padded[0, : len(prompt)] = prompt
+        logits, pool = llama.prefill_paged_cold(
+            eng.params, mc, jnp.asarray(padded), pool, table,
+            jnp.asarray([len(prompt)], jnp.int32),
+        )
+        key = jax.random.key(123)
+        temp = jnp.asarray([0.8], jnp.float32)
+        top_p = jnp.asarray([0.9], jnp.float32)
+        top_k = jnp.asarray([0], jnp.int32)
+        tok = sample(mask_pad(logits[:, -1]), key[None], temp, top_p, top_k)[0]
+        expected = [int(tok)]
+        k = jax.random.fold_in(key, 1)
+        length = len(prompt)
+        for _ in range(n_new - 1):
+            logits, pool = llama.decode_step_paged(
+                eng.params, mc, jnp.asarray([[expected[-1]]], jnp.int32), pool,
+                table, jnp.asarray([length], jnp.int32),
+            )
+            step = jax.random.split(k, 2)
+            tok = sample(mask_pad(logits[:, 0]), step[0][None], temp, top_p, top_k)[0]
+            expected.append(int(tok))
+            k = step[1]
+            length += 1
+
+        assert eng.generate(prompt, p)[0] == expected
+
     def test_concurrent_requests_exceed_slots(self, server):
         eng = server.engine
         results = {}

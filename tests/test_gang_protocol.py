@@ -437,69 +437,40 @@ class TestAssemblyCountsProvenRanksOnly:
         pub.close()
 
 
-def test_decode_kernel_flag_rides_broadcast():
-    """The decode-kernel flavor is part of the lockstep contract: rank
-    0's RESOLVED choice must ride every decode broadcast, and a follower
-    whose own config disagrees must compile/execute the broadcast
-    flavor (all ranks must run the same program — a follower silently
-    using its local default would diverge the compiled computations)."""
-    follower_eng = build_test_engine()  # local default: "ragged"
-    pub = GangPublisher(1, port=0, host="127.0.0.1", secret=SECRET)
-    fol = connect_pair(pub)
-    leader = Engine(
-        follower_eng.model_config,
-        follower_eng.params,
-        follower_eng.tokenizer,
-        EngineConfig(
-            max_slots=4, max_seq_len=256, prefill_buckets=(16, 32, 64, 128),
-            decode_kernel="dedicated",
-        ),
-        publisher=pub,
-    )
-    seen: list[dict] = []
-    seen_arrays: list[dict] = []
+def test_decode_broadcast_carries_arrays_only_and_one_program_replays_it(pair):
+    """There is one decode program: the decode broadcast names no flavor
+    of it (no scalars at all), carries every array the program's epilogue
+    gates branch on, and the follower replays it on the one jitted step
+    its own step functions built, compiled once."""
+    leader, follower_eng, _ = pair
+    pub = leader._publisher
+    seen: list[tuple] = []
     real_publish = pub.publish
 
     def spying_publish(op, scalars=None, arrays=None):
         if op == "decode":
-            seen.append(dict(scalars or {}))
-            seen_arrays.append({k: (v.dtype, v.shape) for k, v in arrays.items()})
+            seen.append((scalars, {k: (v.dtype, v.shape) for k, v in arrays.items()}))
         real_publish(op, scalars, arrays)
 
     pub.publish = spying_publish
-    t = threading.Thread(target=follower_eng.run_follower, args=(fol,), daemon=True)
-    t.start()
-    leader.start()
-    try:
-        ids, _, fin = leader.generate(
-            list(range(1, 20)), SamplingParams(temperature=0.0, max_tokens=6),
-            timeout=120,
-        )
-        assert fin.completion_tokens >= 1
-        # Every decode broadcast carried the resolved flavor.
-        assert seen, "no decode op was broadcast"
-        assert all(sc.get("decode_kernel") == "dedicated" for sc in seen), seen
-        # And the per-slot request parameters the program's epilogue
-        # gates branch on, want_top among them: a follower that lacked
-        # one would run another branch than rank 0.
-        for arrays in seen_arrays:
-            assert {"active", "temp", "presence", "freq", "want_top"} <= set(arrays)
-            assert arrays["want_top"] == (np.dtype(bool), (4,))
-        # The follower honored the payload over its own config: it
-        # compiled the dedicated flavor while its local resolution (and
-        # local jit) remain ragged.
-        deadline = time.monotonic() + 30
-        while time.monotonic() < deadline and "dedicated" not in follower_eng._decode_jits:
-            time.sleep(0.05)
-        assert "dedicated" in follower_eng._decode_jits
-        assert follower_eng._decode_kernel == "ragged"
-        # And the replayed device carries converge to the leader's.
-        want = np.asarray(jax.device_get(leader._lengths))
-        np.testing.assert_array_equal(_sync(lambda: follower_eng._lengths, want), want)
-    finally:
-        leader.stop()
-        t.join(timeout=20)
-    assert not t.is_alive(), "follower loop did not exit on stop"
+    ids, _, fin = leader.generate(
+        list(range(1, 20)), SamplingParams(temperature=0.0, max_tokens=6),
+        timeout=120,
+    )
+    assert fin.completion_tokens >= 1
+    assert seen, "no decode op was broadcast"
+    for scalars, arrays in seen:
+        assert not scalars, scalars
+        # The per-slot request parameters the epilogue's gates branch on,
+        # want_top among them: a follower that lacked one would run
+        # another branch than rank 0.
+        assert {"active", "temp", "presence", "freq", "want_top"} <= set(arrays)
+        assert arrays["want_top"] == (np.dtype(bool), (4,))
+    # The replayed device carries converge to the leader's.
+    want = np.asarray(jax.device_get(leader._lengths))
+    np.testing.assert_array_equal(_sync(lambda: follower_eng._lengths, want), want)
+    assert follower_eng._decode_jit is follower_eng._step_fns.decode_jit
+    assert follower_eng._decode_jit._cache_size() == 1
 
 
 def test_penalized_and_biased_generation_replays(pair):

@@ -80,29 +80,6 @@ def test_logprobs_present_for_sampled(engine):
     assert all(lp is not None and lp <= 0.0 for lp in lps)
 
 
-def test_logprobs_identical_under_speculation():
-    """Accepted-draft logprobs (the lp_d path) must equal the
-    non-speculative engine's logprobs for the same greedy run."""
-    params = llama.init_params(CFG, jax.random.key(31))
-    ec = dict(max_slots=2, max_seq_len=256, prefill_buckets=(32, 64, 128),
-              page_size=16, decode_chunk=4)
-    spec = Engine(CFG, params, ByteTokenizer(), EngineConfig(speculate_tokens=3, **ec))
-    base = Engine(CFG, params, ByteTokenizer(), EngineConfig(**ec))
-    spec.start()
-    base.start()
-    try:
-        prompt = np.random.default_rng(4).integers(1, 200, 24).tolist()
-        p = SamplingParams(temperature=0.0, max_tokens=40)
-        ts, ls = drain_with_logprobs(spec.submit(list(prompt), p))
-        tb, lb = drain_with_logprobs(base.submit(list(prompt), p))
-        assert ts == tb
-        np.testing.assert_allclose(ls, lb, atol=2e-3)
-        assert spec.m_spec_drafted.value() > 0
-    finally:
-        spec.stop()
-        base.stop()
-
-
 @pytest.fixture(scope="module")
 def server(engine):
     from kubeai_tpu.engine.server import EngineServer

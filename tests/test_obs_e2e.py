@@ -170,6 +170,22 @@ def test_perfetto_export_and_engine_steps(served):
     assert {"slot occupancy", "free KV pages", "fetch_wait_ms"} <= counters
 
 
+def test_a_decode_chunks_step_record_names_the_route_that_ran(served):
+    """`kernel` is the attention route of the one decode program; one
+    token a live slot a step, so the record counts nothing drafted or
+    accepted."""
+    api, eng_srv = served
+    _post_completion(api, {"model": "m1", "prompt": "route", "max_tokens": 3,
+                           "temperature": 0})
+    _, doc = _get(eng_srv.port, "/debug/engine?limit=50")
+    chunks = [s for s in doc["steps"] if s["kind"] == "decode_chunk"]
+    assert chunks
+    for chunk in chunks:
+        assert chunk["kernel"] in ("flash", "ragged", "xla")
+        assert not [k for k in chunk if k.startswith("spec_")]
+        assert chunk["tokens"] <= chunk["steps"] * len(chunk["slots"])
+
+
 def test_phase_histograms_and_outcome_labels(served):
     api, eng_srv = served
     base = default_registry.counter("kubeai_engine_requests_total").value(

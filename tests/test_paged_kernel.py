@@ -11,12 +11,10 @@ import jax.numpy as jnp
 from kubeai_tpu.ops.paged_attention import paged_attention_ragged
 
 
-def _ref(q_flat, kv_pages, kv_lens, table, cu, n, scale, softcap):
+def _ref(q_flat, kv_pages, kv_lens, table, cu, n, scale, softcap, k_scale=None, v_scale=None):
     # The library kernel ships with TPU-enabled jax builds only; a
-    # CPU-only jax (this CI) has no oracle to compare against — skip
-    # rather than fail (the CPU twin is still pinned against the
-    # dedicated decode kernel's interpret-mode run in
-    # test_decode_kernel.py).
+    # CPU-only jax has no oracle to compare against — skip rather than
+    # fail.
     pytest.importorskip("jax.experimental.pallas.ops.tpu.ragged_paged_attention")
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention.kernel import (
         ref_ragged_paged_attention,
@@ -24,25 +22,34 @@ def _ref(q_flat, kv_pages, kv_lens, table, cu, n, scale, softcap):
 
     return ref_ragged_paged_attention(
         q_flat, kv_pages, kv_lens, table, cu, n,
-        sm_scale=scale, soft_cap=softcap,
+        sm_scale=scale, soft_cap=softcap, k_scale=k_scale, v_scale=v_scale,
     )
 
 
 @pytest.mark.parametrize(
-    "B,S,H,Kv,lens,softcap",
+    "B,S,H,Kv,lens,softcap,k_scale,v_scale,pool_dtype",
     [
-        (2, 1, 8, 2, [17, 42], None),      # plain decode
-        (2, 4, 8, 2, [19, 45], None),      # speculative (G=3)
-        (1, 16, 4, 4, [16], None),         # prefill-sized query block
-        (2, 2, 4, 2, [30, 61], 30.0),      # softcap
-        (3, 1, 16, 2, [1, 33, 64], None),  # extreme lengths
+        (2, 1, 8, 2, [17, 42], None, None, None, jnp.float32),      # plain decode
+        (2, 4, 8, 2, [19, 45], None, None, None, jnp.float32),      # a few rows a slot
+        (1, 16, 4, 4, [16], None, None, None, jnp.float32),         # prefill-sized query block
+        (2, 2, 4, 2, [30, 61], 30.0, None, None, jnp.float32),      # softcap
+        (3, 1, 16, 2, [1, 33, 64], None, None, None, jnp.float32),  # extreme lengths
+        # The dequantising arm (x.astype(f32) * scale -> q.dtype).
+        (2, 1, 4, 2, [17, 42], None, 0.03, 0.05, jnp.float32),
+        (2, 4, 8, 2, [19, 45], None, 0.03, 0.05, jnp.float32),
+        (2, 1, 4, 2, [17, 42], 25.0, 0.03, 0.05, jnp.float32),
+        (3, 1, 16, 2, [1, 33, 64], 30.0, None, None, jnp.float32),
+        (2, 1, 8, 2, [17, 42], None, 0.03, 0.05, jnp.int8),         # an 8-bit pool
     ],
 )
-def test_wrapper_matches_library_reference(B, S, H, Kv, lens, softcap):
+def test_wrapper_matches_library_reference(B, S, H, Kv, lens, softcap, k_scale, v_scale, pool_dtype):
     h, P, ps, mp = 128, 1 + 3 * 4, 16, 4
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, S, H, h)), jnp.float32)
-    kv_pages = jnp.asarray(rng.standard_normal((P, ps, 2 * Kv, h)), jnp.float32)
+    if pool_dtype == jnp.int8:
+        kv_pages = jnp.asarray(rng.integers(-127, 128, (P, ps, 2 * Kv, h)), jnp.int8)
+    else:
+        kv_pages = jnp.asarray(rng.standard_normal((P, ps, 2 * Kv, h)), pool_dtype)
     table = jnp.asarray(
         rng.choice(np.arange(1, P), size=(B, mp), replace=False).astype(np.int32)
     )
@@ -50,12 +57,13 @@ def test_wrapper_matches_library_reference(B, S, H, Kv, lens, softcap):
     scale = h**-0.5
 
     got = paged_attention_ragged(
-        q, kv_pages, table, kv_lens, softcap=softcap or 0.0
+        q, kv_pages, table, kv_lens, softcap=softcap or 0.0,
+        k_scale=k_scale, v_scale=v_scale,
     )
     want = _ref(
         q.reshape(B * S, H, h), kv_pages, kv_lens, table,
         jnp.arange(B + 1, dtype=jnp.int32) * S, jnp.asarray([B], jnp.int32),
-        scale, softcap,
+        scale, softcap, k_scale, v_scale,
     ).reshape(B, S, H, h)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
@@ -202,7 +210,7 @@ def test_wrapper_clamps_overrun_lengths():
 
 def test_decode_step_paged_kernel_wiring():
     """llama decode with use_paged_kernel=True must match the gather path
-    for single AND multi-token (speculative) queries — validates the
+    at one query row a slot and at several — validates the
     kv_lengths=last_pos+1 and scale plumbing in apply()."""
     from kubeai_tpu.models import llama
     from kubeai_tpu.models.base import ModelConfig
@@ -224,11 +232,14 @@ def test_decode_step_paged_kernel_wiring():
     cfg_k = cfg.replace(use_paged_kernel=True)
     for S in (1, 3):
         step_tok = jnp.asarray(rng.integers(1, 200, (B, S)), jnp.int32)
-        ref_logits, _ = llama.decode_speculative_paged(
-            params, cfg, step_tok, {k: v.copy() for k, v in pool.items()}, table, lengths
+        pos = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+        ref_logits, _ = llama.apply(
+            params, cfg, step_tok, pos, {k: v.copy() for k, v in pool.items()},
+            page_table=table,
         )
-        kern_logits, _ = llama.decode_speculative_paged(
-            params, cfg_k, step_tok, {k: v.copy() for k, v in pool.items()}, table, lengths
+        kern_logits, _ = llama.apply(
+            params, cfg_k, step_tok, pos, {k: v.copy() for k, v in pool.items()},
+            page_table=table,
         )
         np.testing.assert_allclose(
             np.asarray(kern_logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4

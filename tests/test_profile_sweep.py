@@ -1,4 +1,4 @@
-"""CI smoke for the decode-kernel autotune sweep
+"""CI smoke for the paged-attention block sweep
 (benchmarks/profile_engine.py --sweep): tiny shapes on CPU must produce
 the full JSON document — every (kernel, block, slots) row present with
 latency + diagnosis fields — so a TPU run of the identical harness is
@@ -18,18 +18,17 @@ def test_sweep_smoke_emits_full_table():
     doc = run_sweep(slots_list=(2, 4), blocks=("default", "2:8"), smoke=True)
     # JSON-serializable end-to-end (the harness writes this to disk).
     doc = json.loads(json.dumps(doc))
-    assert doc["metric"] == "paged_decode_attention_sweep"
+    assert doc["metric"] == "paged_attention_sweep"
     assert doc["degraded"] is True  # CPU run must label itself honestly
     assert "not TPU numbers" in doc["note"]
     for key in ("H", "Kv", "head_dim", "page", "seq"):
         assert key in doc["shapes"]
 
     rows = doc["results"]
-    # 1 dedicated + 2 ragged blocks, per slot count.
-    assert len(rows) == 2 * (1 + 2)
+    # 2 blocks, per slot count.
+    assert len(rows) == 2 * 2
     combos = {(r["kernel"], r["block"], r["slots"]) for r in rows}
     for slots in (2, 4):
-        assert ("dedicated", "slotwise", slots) in combos
         assert ("ragged", "default", slots) in combos
         assert ("ragged", "2:8", slots) in combos
     # The sweep JSON carries the roofline constants its columns used
@@ -56,11 +55,6 @@ def test_sweep_smoke_emits_full_table():
         assert 0 < r["roofline_fraction"] <= 1
         assert 0 < r["mfu"] <= 1
 
-    # The dedicated kernel's grid must scale with slots (the design
-    # property that distinguishes it from the collapsed ragged grid).
-    ded = {r["slots"]: r["grid_programs"] for r in rows if r["kernel"] == "dedicated"}
-    assert ded[4] == 2 * ded[2]
-
     # The pair travels as an argument: the environment name the sweep
     # used to set is read nowhere in the package.
     pkg = pathlib.Path(__file__).resolve().parent.parent / "kubeai_tpu"
@@ -76,34 +70,28 @@ def test_sweep_resume_skips_completed_cells(tmp_path):
     from benchmarks.profile_engine import run_sweep
 
     out = str(tmp_path / "sweep.json")
-    doc1 = run_sweep(
-        slots_list=(2,), blocks=("default",), smoke=True, out_path=out
-    )
+    blocks = ("default", "2:8")
+    doc1 = run_sweep(slots_list=(2,), blocks=blocks, smoke=True, out_path=out)
     with open(out) as f:
         on_disk = json.load(f)
     assert on_disk["results"] == json.loads(json.dumps(doc1["results"]))
-    assert len(doc1["results"]) == 2  # dedicated + ragged default
+    assert len(doc1["results"]) == 2
 
-    # Simulate a crash mid-grid: drop the ragged cell from the file.
-    on_disk["results"] = [
-        r for r in on_disk["results"] if r["kernel"] == "dedicated"
-    ]
+    # Simulate a crash mid-grid: drop the second cell from the file.
+    on_disk["results"] = [r for r in on_disk["results"] if r["block"] == "default"]
     with open(out, "w") as f:
         json.dump(on_disk, f)
 
     doc2 = run_sweep(
-        slots_list=(2, 4), blocks=("default",), smoke=True,
-        out_path=out, resume=True,
+        slots_list=(2, 4), blocks=blocks, smoke=True, out_path=out, resume=True,
     )
-    rows = {(r["kernel"], r["slots"]): r for r in doc2["results"]}
-    assert set(rows) == {
-        ("dedicated", 2), ("ragged", 2), ("dedicated", 4), ("ragged", 4)
-    }
+    rows = {(r["block"], r["slots"]): r for r in doc2["results"]}
+    assert set(rows) == {("default", 2), ("2:8", 2), ("default", 4), ("2:8", 4)}
     # The completed cell was reused VERBATIM (identical measurement),
     # the dropped + new cells were measured fresh.
-    kept = next(r for r in on_disk["results"] if r["kernel"] == "dedicated")
-    assert rows[("dedicated", 2)]["latency_ms"] == kept["latency_ms"]
-    for key in (("ragged", 2), ("dedicated", 4), ("ragged", 4)):
+    (kept,) = on_disk["results"]
+    assert rows[("default", 2)]["latency_ms"] == kept["latency_ms"]
+    for key in (("2:8", 2), ("default", 4), ("2:8", 4)):
         assert rows[key]["latency_ms"] is not None and rows[key]["latency_ms"] > 0
     # And the file on disk holds the final full document.
     with open(out) as f:
@@ -121,6 +109,6 @@ def test_sweep_resume_ignores_corrupt_file(tmp_path):
         slots_list=(2,), blocks=("default",), smoke=True,
         out_path=out, resume=True,
     )
-    assert len(doc["results"]) == 2
+    assert len(doc["results"]) == 1
     with open(out) as f:
-        assert len(json.load(f)["results"]) == 2
+        assert len(json.load(f)["results"]) == 1

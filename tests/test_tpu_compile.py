@@ -25,7 +25,6 @@ from kubeai_tpu.models import llama
 from kubeai_tpu.models.base import ModelConfig
 from kubeai_tpu.ops.flash_attention import flash_attention_tpu
 from kubeai_tpu.ops.paged_attention import paged_attention_ragged
-from kubeai_tpu.ops.paged_decode_attention import paged_decode_attention
 
 # (num_heads, num_kv_heads) at head_dim 128.
 QWEN25_7B = (28, 4)
@@ -176,33 +175,6 @@ def test_ragged_paged_kernel_quantized_pool_single_kv_head(v5e):
         ),
         *_paged_args(v5e[0], SLOTS, 1, GEMMA_2B, pool_dtype=jnp.float8_e4m3fn),
     )
-
-
-def _dedicated(v5e, heads):
-    return _compile(
-        lambda q, kv, tbl, lens: paged_decode_attention(
-            q, kv, tbl, lens, interpret=False
-        ),
-        *_paged_args(v5e[0], SLOTS, 1, heads),
-    )
-
-
-def test_dedicated_decode_kernel_lowers_single_kv_head(v5e):
-    assert "tpu_custom_call" in _dedicated(v5e, GEMMA_2B)
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="'The Pallas TPU lowering currently requires that the last two "
-           "dimensions of your block shape are divisible by 8 and 128 "
-           "respectively, or be equal to the respective dimensions of the "
-           "overall array': q is blocked (1,S,G,h) on the H axis and pages "
-           "(1,page,2,h) on the 2*Kv axis, so --decode-kernel dedicated|auto "
-           "lowers only at Kv=1 (ROADMAP A5/C5)",
-)
-@pytest.mark.parametrize("heads", [QWEN25_7B, LLAMA3_8B], ids=["G=7", "G=4"])
-def test_dedicated_decode_kernel_lowers_grouped_heads(v5e, heads):
-    _dedicated(v5e, heads)
 
 
 def test_tp4_decode_step_keeps_the_kernel(v5e):
