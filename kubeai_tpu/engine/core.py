@@ -132,13 +132,12 @@ class EngineConfig:
     # Non-greedy sampling candidate space (see engine/sampling.py);
     # <= 0 samples the exact full distribution (full-vocab sort).
     max_top_k: int = 128
-    # Batched cold prefill runs at exactly TWO compiled batch sizes per
-    # bucket: 1 (steady-state singles) and min(this, max_slots) (groups;
-    # larger admission rounds split into group-cap chunks). Two sizes
-    # bound the compile count, keep warmup able to cover every shape,
-    # and cap the padding waste when 2-4 requests arrive together (a
-    # max_slots pad would pay up to max_slots/n the needed prefill
-    # FLOPs on the TTFT-critical path).
+    # Cold prefill runs at exactly TWO compiled batch sizes per bucket:
+    # 1 row and min(this, max_slots) rows. Two sizes bound the compile
+    # count and let warmup cover every shape. This names the full-group
+    # shape only: a round's same-bucket cold prompts run as calls of
+    # this many rows while that many are left, and the rest as one-row
+    # calls, so no call computes a row nobody sent (_plan_admissions).
     prefill_group_cap: int = 8
     # Paged KV: tokens per page. 64 keeps TPU tiling happy (page x head
     # dims land on (16,128)+ bf16 tiles) while giving fine-grained HBM
@@ -526,6 +525,13 @@ class Engine:
             "prompt positions computed as bucket/batch padding (prefill waste; "
             "compare against kubeai_engine_prefill_tokens_total)",
         )
+        self.m_prefill_rows = default_registry.counter(
+            "kubeai_engine_prefill_rows_total",
+            "rows the cold group prefill calls computed, by kind: real (a "
+            "prompt that was sent) | duplicate (a copy of one, filling the "
+            "compiled row count: 0 while a short group runs as one-row calls)",
+        )
+        self.m_prefill_rows.inc(0, labels={"kind": "duplicate"})  # scraped as 0, not absent
         self.m_tok_rate = default_registry.gauge(
             "kubeai_engine_tokens_per_second",
             "decode goodput over the most recent chunks (0 when idle)",
@@ -667,6 +673,12 @@ class Engine:
             # (kv pages, queries) a block the ragged paged kernel was
             # given, per call shape this process has traced.
             "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
+            # Rows the cold group prefill calls computed (the counter
+            # kubeai_engine_prefill_rows_total, since the process began).
+            "prefill_rows": {
+                kind: int(self.m_prefill_rows.value(labels={"kind": kind}))
+                for kind in ("real", "duplicate")
+            },
             "stall": self._stall.report(),
         }
 
@@ -2009,12 +2021,21 @@ class Engine:
         work: list[tuple[list, Any]] = []  # (items, thunk)
         # Groups first: shared pages registered by a cold group member
         # must be written before a reuse single reads them (device-stream
-        # order follows dispatch order). Oversized groups split into
-        # group-cap chunks (see EngineConfig.prefill_group_cap).
+        # order follows dispatch order). A bucket's prompts run, in the
+        # order they were planned, as calls of the two compiled row
+        # counts (EngineConfig.prefill_group_cap): `cap` rows while `cap`
+        # prompts are left, then one row each. A call of `cap` rows for
+        # fewer prompts computes copies of its last row: on a 7B that
+        # costs more device time than the one-row calls at every bucket
+        # of 128 or more, and below that wins only for three or more
+        # prompts of at most 32 tokens or five of at most 64 in ONE round
+        # (the table is in PERF.md section 6, PR 32).
         cap = max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
         for bucket, items in groups.items():
-            for off in range(0, len(items), cap):
-                part = items[off : off + cap]
+            full = len(items) // cap * cap
+            parts = [items[off : off + cap] for off in range(0, full, cap)]
+            parts += [[item] for item in items[full:]]
+            for part in parts:
 
                 def batch(items=part, bucket=bucket):
                     admitted.extend(self._prefill_group(items, bucket))
@@ -2365,33 +2386,28 @@ class Engine:
         self._adm_seed[slot_idx] = seed
 
     def _prefill_group(self, items: list, bucket: int):
-        """One prefill call for N same-bucket cold requests. The batch
-        dim is padded to exactly TWO compiled sizes — 1 (the steady-state
-        single admission, where batch padding would waste a whole batch
-        of prefill compute per admission) and the group cap (cold-burst
-        groups; _admit_waiting pre-splits larger rounds) — by duplicating
-        the last row; duplicate scatters of identical values are benign.
-        Two sizes x len(prefill_buckets) bounds the compile count AND
-        lets warmup cover every shape the measure phase hits (round 2's
-        pow2 padding compiled new shapes mid-measurement)."""
+        """One prefill call for N same-bucket cold requests, one row a
+        request. N is one of the TWO compiled row counts, 1 and the
+        group cap (_plan_admissions cuts a round's prompts so): two
+        sizes x len(prefill_buckets) bounds the compile count AND lets
+        warmup cover every shape serving hits. Every row is a prompt
+        that was sent, so the call's padding is its rows' bucket tails."""
         n = len(items)
         for _, req in items:
             if req.trace is not None:
                 req.trace.mark("prefill")
-        n_pad = 1 if n == 1 else max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
         real_tokens = int(sum(len(r.prompt_ids) for _, r in items))
-        # Padding waste: the compiled [n_pad, bucket] shape vs the real
-        # prompt tokens (bucket tail pad + duplicated batch-pad rows).
-        pad_tokens = n_pad * bucket - real_tokens
+        pad_tokens = n * bucket - real_tokens
         with self._stall.segment(
             "prefill", kind="group", bucket=bucket, batch=n,
             tokens=real_tokens, cached=0, pad=pad_tokens,
         ) as seg:
-            out = self._prefill_group_call(items, bucket, n_pad)
+            out = self._prefill_group_call(items, bucket)
         self.m_step.observe(seg.seconds, labels={"phase": "prefill_group"})
         self._stall.end_step("prefill_group")
         if pad_tokens > 0:
             self.m_pad_prefill.inc(pad_tokens)
+        self.m_prefill_rows.inc(n, labels={"kind": "real"})
         default_recorder.record_step(
             kind="prefill_group", bucket=bucket, batch=n,
             kernel=self._attn_kernel("prefill_group", bucket),
@@ -2402,32 +2418,27 @@ class Engine:
         )
         return out
 
-    def _prefill_group_call(self, items: list, bucket: int, n_pad: int) -> list:
+    def _prefill_group_call(self, items: list, bucket: int) -> list:
         n = len(items)
-        tokens = np.zeros((n_pad, bucket), np.int32)
-        lengths = np.zeros((n_pad,), np.int32)
-        tables = np.zeros((n_pad, self._max_pages), np.int32)
-        slots_arr = np.zeros((n_pad,), np.int32)
-        seeds = np.zeros((n_pad,), np.uint32)
-        temps = np.ones((n_pad,), np.float32)
-        top_ps = np.ones((n_pad,), np.float32)
-        top_ks = np.zeros((n_pad,), np.int32)
-        bias_ids = np.zeros((n_pad, self.cfg.max_logit_bias), np.int32)
-        bias_vals = np.zeros((n_pad, self.cfg.max_logit_bias), np.float32)
-        lora_rows_arr = np.zeros((n_pad,), np.int32)
-        # Seeds computed once per ITEM (time-based when unset): padding
-        # rows must replicate the last row exactly so their duplicate
-        # adm_toks scatters write the same value.
-        item_seeds = [self._seed32(req.params, j) for j, (_, req) in enumerate(items)]
-        for j in range(n_pad):
-            slot_idx, req = items[min(j, n - 1)]
+        tokens = np.zeros((n, bucket), np.int32)
+        lengths = np.zeros((n,), np.int32)
+        tables = np.zeros((n, self._max_pages), np.int32)
+        slots_arr = np.zeros((n,), np.int32)
+        seeds = np.zeros((n,), np.uint32)
+        temps = np.ones((n,), np.float32)
+        top_ps = np.ones((n,), np.float32)
+        top_ks = np.zeros((n,), np.int32)
+        bias_ids = np.zeros((n, self.cfg.max_logit_bias), np.int32)
+        bias_vals = np.zeros((n, self.cfg.max_logit_bias), np.float32)
+        lora_rows_arr = np.zeros((n,), np.int32)
+        for j, (slot_idx, req) in enumerate(items):
             ids = req.prompt_ids
             sp = req.params
             tokens[j, : len(ids)] = ids
             lengths[j] = len(ids)
             tables[j] = self._page_table[slot_idx]
             slots_arr[j] = slot_idx
-            seeds[j] = item_seeds[min(j, n - 1)]
+            seeds[j] = self._seed32(sp, j)
             temps[j] = sp.temperature
             top_ps[j] = sp.top_p
             top_ks[j] = sp.top_k
@@ -3188,15 +3199,15 @@ def build_step_functions(
     topn = max(1, cfg.top_logprobs_k)
 
     def prefill_batch_fn(params, tokens, lengths, tables, slots, seeds, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_rows=None):
-        """Cold prefill for N requests in ONE call (N is a static pad
-        size — 1 for steady-state singles, max_slots for cold
-        bursts): tokens [N, S] land in the pages of *tables*
-        [N, max_pages]. Sampled first tokens are scattered into the
-        device staging vector adm_toks[slots] so the NEXT decode
-        dispatch can merge them in-graph without a host round-trip
-        (padding duplicates the last row: same slot, same value —
-        benign). PRNG keys derive from uint32 *seeds* in-graph, so
-        every argument arrives as plain numpy riding the dispatch."""
+        """Cold prefill for N requests in ONE call (N is one of two
+        compiled row counts — 1, and the group cap for a full group):
+        tokens [N, S] land in the pages of *tables* [N, max_pages].
+        Sampled first tokens are scattered into the device staging
+        vector adm_toks[slots] so the NEXT decode dispatch can merge
+        them in-graph without a host round-trip (every row is its own
+        request's: the slots are distinct). PRNG keys derive from
+        uint32 *seeds* in-graph, so every argument arrives as plain
+        numpy riding the dispatch."""
         keys = jax.vmap(jax.random.key)(seeds)
         logits, cache = llama.prefill_paged_cold(
             params, mc, tokens, cache, tables, lengths,
