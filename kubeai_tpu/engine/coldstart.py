@@ -207,17 +207,17 @@ def padded_vocab_size(vocab_size: int, tp: int = 1) -> int:
 def param_shapes(model_config, quantization: str = ""):
     """ShapeDtypeStruct tree of the engine's parameters, derived from
     the model config alone (no weights touched). Mirrors what the
-    checkpoint loader produces — llama.init_params builds the identical
+    checkpoint loader produces — the family's init_params builds the identical
     tree structure to params_from_hf, and quantize_model_params is
     traceable — via jax.eval_shape, so nothing is allocated and drift
     with the real loaders is impossible by construction. The config
     must already carry the PADDED vocab (padded_vocab_size)."""
     import jax
 
-    from kubeai_tpu.models import llama
+    from kubeai_tpu.models import family
 
     def build():
-        params = llama.init_params(model_config, jax.random.key(0))
+        params = family(model_config).init_params(model_config, jax.random.key(0))
         if quantization == "int8":
             from kubeai_tpu.engine.weights import quantize_model_params
 
@@ -253,7 +253,7 @@ def warm_compile(
     import jax.numpy as jnp
 
     from kubeai_tpu.engine import core
-    from kubeai_tpu.models import llama
+    from kubeai_tpu.models import family
 
     cfg = engine_config or core.EngineConfig()
     t0 = time.monotonic()
@@ -263,7 +263,7 @@ def warm_compile(
     Kb = cfg.max_logit_bias
     params = param_shapes(model_config, quantization)
     cache = jax.eval_shape(
-        lambda: llama.init_paged_cache(model_config, P, cfg.page_size)
+        lambda: family(model_config).init_paged_cache(model_config, P, cfg.page_size)
     )
     keys = jax.eval_shape(
         lambda: jax.random.key_data(jax.random.split(jax.random.key(0), B))

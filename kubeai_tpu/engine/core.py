@@ -56,7 +56,7 @@ from kubeai_tpu.faults import FaultError, fault
 from kubeai_tpu.engine import kvstate
 from kubeai_tpu.engine.tokenizer import IncrementalDetokenizer
 from kubeai_tpu.metrics import default_registry
-from kubeai_tpu.models import llama
+from kubeai_tpu.models import family
 from kubeai_tpu.models.base import ModelConfig
 from kubeai_tpu.obs import default_recorder
 from kubeai_tpu.obs import perf as perf_obs
@@ -67,7 +67,7 @@ from kubeai_tpu.obs.recorder import (
 )
 from kubeai_tpu.obs.logs import get_logger, trace_extra
 from kubeai_tpu.obs.trace import RequestTrace, TraceContext
-from kubeai_tpu.ops import paged_attention
+from kubeai_tpu.ops import mla_attention, moe, paged_attention
 from kubeai_tpu.qos import QoSQueue, record_admitted, record_preemption
 from kubeai_tpu.qos import install_queue as qos_install_queue
 from kubeai_tpu.qos import uninstall_queue as qos_uninstall_queue
@@ -309,6 +309,7 @@ class Engine:
             model_config = _dc.replace(
                 model_config, kv_cache_dtype=self.cfg.kv_cache_dtype
             )
+        family(model_config).refuse_unsupported(model_config)
         self.model_config = model_config
         self.params = params
         self.tokenizer = tokenizer
@@ -532,6 +533,23 @@ class Engine:
             "compiled row count: 0 while a short group runs as one-row calls)",
         )
         self.m_prefill_rows.inc(0, labels={"kind": "duplicate"})  # scraped as 0, not absent
+        self.m_moe_hit = default_registry.counter(
+            "kubeai_engine_moe_experts_hit_total",
+            "(expert layer, expert) pairs that got at least one row, summed "
+            "over the step programs' calls, by phase (decode | prefill): the "
+            "expert matrices a call had to read",
+        )
+        self.m_moe_possible = default_registry.counter(
+            "kubeai_engine_moe_expert_reads_possible_total",
+            "experts x expert layers x model steps, by phase: what "
+            "kubeai_engine_moe_experts_hit_total reads if every call hits "
+            "every expert",
+        )
+        self.m_moe_assign = default_registry.counter(
+            "kubeai_engine_moe_assignments_total",
+            "(token row, chosen expert) pairs the step programs computed, "
+            "padding rows and idle slots included, by phase",
+        )
         self.m_tok_rate = default_registry.gauge(
             "kubeai_engine_tokens_per_second",
             "decode goodput over the most recent chunks (0 when idle)",
@@ -673,6 +691,16 @@ class Engine:
             # (kv pages, queries) a block the ragged paged kernel was
             # given, per call shape this process has traced.
             "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
+            # The expert family's kernels, likewise: latent pages a block
+            # of the MLA decode kernel, (tm, tk, tn) of the grouped matmul.
+            "mla_kernel_blocks": dict(mla_attention.chosen_blocks),
+            "grouped_matmul_tiles": dict(moe.chosen_tiles),
+            # Bytes of the pool a token occupies, all layers, as stored:
+            # the pool's own size over its tokens (a latent page is a
+            # page of another width).
+            "kv_bytes_per_token": int(
+                self._cache["kv"].nbytes // (self._pool.num_pages * self.cfg.page_size)
+            ),
             # Rows the cold group prefill calls computed (the counter
             # kubeai_engine_prefill_rows_total, since the process began).
             "prefill_rows": {
@@ -725,7 +753,7 @@ class Engine:
         # mid-chunk and keeps stepping never scatter-collides).
 
         def mk_device_arrays():
-            cache = llama.init_paged_cache(self.model_config, P, ps)
+            cache = family(self.model_config).init_paged_cache(self.model_config, P, ps)
             tok_hist = jnp.zeros((B, hist_width), jnp.int32)
             adm_toks = jnp.zeros((B,), jnp.int32)
             lengths = jnp.zeros((B,), jnp.int32)
@@ -801,6 +829,9 @@ class Engine:
         self._adm_mask = np.zeros((B,), bool)
         self._adm_len = np.zeros((B,), np.int32)
         self._adm_seed = np.zeros((B,), np.uint32)
+        # (phase, token rows computed, the program's counters) of prefill
+        # calls whose first tokens the host has not fetched yet.
+        self._program_counters: list = []
         self._slot_pages: list[list[int]] = [[] for _ in range(B)]
         # Pages content-registered at plan time whose prefill has NOT yet
         # succeeded (cleared by _register): a failed prefill must
@@ -845,11 +876,12 @@ class Engine:
         *queries* tokens per row, for the step records: "flash",
         "ragged" (the paged kernel), or "xla" (the portable gather
         route: every CPU run, sliding-window models)."""
-        route = llama.cached_attention_route(
+        model = family(self.model_config)
+        route = model.cached_attention_route(
             self.model_config, queries,
             left_aligned=kind == "prefill_group", paged=True,
         )
-        return "ragged" if route == "paged_kernel" else route
+        return model.PAGED_KERNEL_LABEL if route == "paged_kernel" else route
 
     # -- public API --------------------------------------------------------
 
@@ -895,7 +927,7 @@ class Engine:
         (
             *_,
             self._cache, self._tok_hist, self._lengths,
-            self._last_tokens, self._keys,
+            self._last_tokens, self._keys, _counters,
         ) = self._decode_jit(
             self.params, self._cache, self._page_table.copy(), self._tok_hist,
             self._lengths, self._last_tokens, self._keys,
@@ -911,7 +943,7 @@ class Engine:
         sizes = (1, cap) if include_group and cap > 1 else (1,)
         for bucket in self.cfg.prefill_buckets:
             for n_pad in sizes:
-                *_, self._cache, self._adm_toks = self._prefill_batch_jit(
+                *_, self._cache, self._adm_toks, _counters = self._prefill_batch_jit(
                     self.params,
                     np.zeros((n_pad, bucket), np.int32),
                     np.full((n_pad,), bucket, np.int32),
@@ -933,7 +965,7 @@ class Engine:
         # max_bucket leaves a mid-serving compile on the first
         # prefix-reuse prompt whose tail lands in a smaller bucket.
         for bucket in self.cfg.prefill_buckets:
-            *_, self._cache, self._adm_toks = self._prefill_chunk_jit(
+            *_, self._cache, self._adm_toks, _counters = self._prefill_chunk_jit(
                 self.params,
                 np.zeros((1, bucket), np.int32),
                 np.int32(0),
@@ -1282,7 +1314,7 @@ class Engine:
         def embed_fn(params, tokens, lengths):
             B, S = tokens.shape
             pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-            hidden, _ = llama.apply(params, mc, tokens, pos, return_hidden=True)
+            hidden, _ = family(mc).apply(params, mc, tokens, pos, return_hidden=True)
             valid = (pos < lengths[:, None]).astype(jnp.float32)[..., None]
             pooled = (hidden * valid).sum(1) / jnp.maximum(valid.sum(1), 1.0)
             return pooled / jnp.maximum(
@@ -1368,6 +1400,8 @@ class Engine:
         # the scheduler thunk would freeze every client's token stream
         # for its duration. Only the bank install/broadcast needs
         # dispatch-stream ordering.
+        if not hasattr(family(self.model_config), "init_lora_bank"):
+            raise ValueError(f"{self.model_config.model_type}: LoRA adapters are not supported")
         staged = self._stage_adapter(name, path)
 
         def do():
@@ -1591,7 +1625,7 @@ class Engine:
                 (
                     _, _, _, _,
                     self._cache, self._tok_hist, self._lengths,
-                    self._last_tokens, self._keys,
+                    self._last_tokens, self._keys, _,
                 ) = self._decode_jit(
                     self.params, self._cache, ar["tables"], self._tok_hist,
                     self._lengths, self._last_tokens, self._keys,
@@ -1603,7 +1637,7 @@ class Engine:
                 )
             elif op == "prefill_batch":
                 lora_args = self._follower_lora(ar)
-                _, _, _, _, self._cache, self._adm_toks = self._prefill_batch_jit(
+                _, _, _, _, self._cache, self._adm_toks, _ = self._prefill_batch_jit(
                     self.params, ar["tokens"], ar["lengths"], ar["tables"],
                     ar["slots"], ar["seeds"], ar["temps"], ar["top_ps"],
                     ar["top_ks"], ar["bias_ids"], ar["bias_vals"],
@@ -1620,7 +1654,7 @@ class Engine:
                         "lora": self._adapters.bank,
                         "lora_row": np.int32(sc["lora_row"]),
                     }
-                _, _, _, _, self._cache, self._adm_toks = self._prefill_chunk_jit(
+                _, _, _, _, self._cache, self._adm_toks, _ = self._prefill_chunk_jit(
                     self.params, ar["tokens"], np.int32(sc["start"]),
                     np.int32(sc["last_idx"]), ar["table"], np.int32(sc["slot"]),
                     np.uint32(sc["seed"]), np.float32(sc["temperature"]),
@@ -2095,6 +2129,20 @@ class Engine:
                                 )
                     raise
 
+    def _count_program(self, phase: str, rows: int, counters: dict) -> None:
+        """A fetched step program's counters (build_step_functions:
+        split_counters) into the engine's series. *rows*: the token rows
+        the call computed (decode: steps x slots)."""
+        if "moe_hits" not in counters:
+            return
+        mc = self.model_config
+        layers = mc.num_layers - min(mc.first_k_dense_replace, mc.num_layers)
+        steps = self.cfg.decode_chunk if phase == "decode" else 1
+        labels = {"phase": phase}
+        self.m_moe_hit.inc(int(counters["moe_hits"]), labels=labels)
+        self.m_moe_possible.inc(mc.n_routed_experts * layers * steps, labels=labels)
+        self.m_moe_assign.inc(rows * mc.num_experts_per_tok * layers, labels=labels)
+
     def _emit_admitted(self, admitted: list) -> None:
         """One host sync for all first tokens of an admission round —
         called AFTER the next decode chunk is dispatched (the chunk takes
@@ -2102,13 +2150,17 @@ class Engine:
         for client streaming only and overlaps device compute)."""
         if not admitted:
             return
+        calls, self._program_counters = self._program_counters, []
         # Blocks until the round's prefills have run (behind whatever
         # chunk the device is still on): a wait, not host work.
         with self._stall.segment("fetch_wait", of="first_tokens"):
-            toks, lps, tids, tlps = jax.device_get((
+            toks, lps, tids, tlps, counted = jax.device_get((
                 [a[2] for a in admitted], [a[4] for a in admitted],
                 [a[5] for a in admitted], [a[6] for a in admitted],
+                [c[2] for c in calls],
             ))
+        for (phase, rows, _), counters in zip(calls, counted):
+            self._count_program(phase, rows, counters)
         for (slot_idx, epoch, _, j, *_), tarr, larr, tid, tlp in zip(
             admitted, toks, lps, tids, tlps
         ):
@@ -2162,6 +2214,13 @@ class Engine:
         claimed: list[int] = []
         if self.cfg.prefix_cache_min:
             claimed = self._pool.match_prefix(ids, sig)
+            if claimed and family(self.model_config).REUSE_WHOLE_PREFILL_CALLS:
+                # A hit takes away whole leading calls of the prompt's cold
+                # prefill and nothing else (models/deepseek.py).
+                call = max(self.cfg.prefill_buckets)
+                keep = len(claimed) * ps // call * call // ps if call % ps == 0 else 0
+                self._pool.release(claimed[keep:])
+                claimed = claimed[:keep]
             if claimed and len(claimed) * ps < self.cfg.prefix_cache_min:
                 self._pool.release(claimed)
                 claimed = []
@@ -2293,7 +2352,7 @@ class Engine:
                     "bias_ids": bias_ids, "bias_vals": bias_vals,
                 },
             ):
-                tok, lp, t_ids, t_lp, self._cache, self._adm_toks = self._prefill_chunk_jit(
+                tok, lp, t_ids, t_lp, self._cache, self._adm_toks, counters = self._prefill_chunk_jit(
                     self.params,
                     chunk_padded,
                     np.int32(start),
@@ -2310,6 +2369,7 @@ class Engine:
                     self._cache,
                     **lora_args,
                 )
+                self._program_counters.append(("prefill", bucket, counters))
 
         self._register(slot_idx, req, seed, lora_row, reuse)
         return (slot_idx, self._slot_epoch[slot_idx], tok, None, lp, t_ids, t_lp)
@@ -2462,7 +2522,7 @@ class Engine:
                 **({"lora_rows": lora_rows_arr} if self._adapters is not None else {}),
             },
         ):
-            toks, lps, t_ids, t_lp, self._cache, self._adm_toks = self._prefill_batch_jit(
+            toks, lps, t_ids, t_lp, self._cache, self._adm_toks, counters = self._prefill_batch_jit(
                 self.params,
                 tokens,
                 lengths,
@@ -2478,6 +2538,7 @@ class Engine:
                 self._cache,
                 **lora_args,
             )
+        self._program_counters.append(("prefill", tokens.size, counters))
         out = []
         for j, (slot_idx, req) in enumerate(items):
             self._register(slot_idx, req, seeds[j], int(lora_rows_arr[j]), reuse=0)
@@ -2520,6 +2581,7 @@ class Engine:
             (
                 c_seq, lpc_seq, tid_seq, tlp_seq,
                 self._cache, self._tok_hist, self._lengths, self._last_tokens, self._keys,
+                counters,
             ) = self._decode_jit(
                 self.params,
                 self._cache,
@@ -2556,7 +2618,7 @@ class Engine:
         )
         for part, r in zip(EPILOGUE_PARTS, ran):
             self.m_epilogue.inc(labels={"part": part, "ran": "1" if r else "0"})
-        payload = (c_seq, lpc_seq)
+        payload = (c_seq, lpc_seq, counters)
         if ran[0]:
             # Only then do the top-N arrays hold anything (zeros
             # otherwise): a chunk that ran without them never hands
@@ -2569,11 +2631,12 @@ class Engine:
         # slot active at the dispatch asked for logprobs: only then did
         # the device compute them (_dispatch_chunk_call).
         with self._stall.segment("fetch_wait", of="chunk") as fetched:  # device_get blocks
-            corr, lp_c, *top = jax.device_get(payload)
+            corr, lp_c, counters, *top = jax.device_get(payload)
             t_ids, t_lp = top or (None, None)
+        corr = np.asarray(corr)  # [K, B]
+        self._count_program("decode", corr.size, counters)
         # The chunk's turnaround: dispatch call returned -> results on the host.
         dur = fetched.t1 - dispatched.t1
-        corr = np.asarray(corr)  # [K, B]
         with self._stall.segment("emit", tokens=corr.shape[0] * len(snapshot)):
             step = self._emit_chunk(
                 snapshot, dur, corr, np.asarray(lp_c),
@@ -3188,7 +3251,16 @@ def build_step_functions(
     masked); defaults to the model vocab (no padding mask)."""
     mc = model_config
     cfg = engine_config
+    model = family(mc)  # the one place the step programs name a model module
     n_valid = mc.vocab_size if n_valid_vocab is None else min(n_valid_vocab, mc.vocab_size)
+
+    def split_counters(cache):
+        """A family's model call may return, beside the pool (`kv`),
+        scalar program counters in the cache dict (models/deepseek.py:
+        `moe_hits`). They leave the program as its LAST output and never
+        enter one: ({"kv": pool}, {name: scalar}). A family without any
+        gives {}: no output at all."""
+        return {"kv": cache["kv"]}, {k: v for k, v in cache.items() if k != "kv"}
 
     def mask_pad(logits):
         if n_valid < mc.vocab_size:
@@ -3209,10 +3281,11 @@ def build_step_functions(
         uint32 *seeds* in-graph, so every argument arrives as plain
         numpy riding the dispatch."""
         keys = jax.vmap(jax.random.key)(seeds)
-        logits, cache = llama.prefill_paged_cold(
+        logits, cache = model.prefill_paged_cold(
             params, mc, tokens, cache, tables, lengths,
             lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
         )
+        cache, counters = split_counters(cache)
         with jax.named_scope("sampling"):
             masked = mask_pad(logits[:, -1])
             # Bias steers choice; the reported logprob stays the model's
@@ -3226,17 +3299,18 @@ def build_step_functions(
             lps = jnp.take_along_axis(logp, toks[:, None], axis=1)[:, 0]
             t_lp, t_ids = jax.lax.top_k(logp, topn)
         adm_toks = adm_toks.at[slots].set(toks)
-        return toks, lps, t_ids.astype(jnp.int32), t_lp, cache, adm_toks
+        return toks, lps, t_ids.astype(jnp.int32), t_lp, cache, adm_toks, counters
 
     def prefill_chunk_fn(params, tokens, start, last_idx, table, slot, seed, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_row=None):
         """One chunk of a long or prefix-resuming prompt."""
         key = jax.random.key(seed)
-        logits, cache = llama.prefill_paged(
+        logits, cache = model.prefill_paged(
             params, mc, tokens, cache, table, start[None], last_idx[None],
             lora=lora,
             lora_rows=None if lora_row is None else lora_row[None],
             tp_mesh=mesh,
         )
+        cache, counters = split_counters(cache)
         with jax.named_scope("sampling"):
             masked = mask_pad(logits[:, -1])
             tok = sample(
@@ -3248,7 +3322,7 @@ def build_step_functions(
             lp = logp[0, tok]
             t_lp, t_ids = jax.lax.top_k(logp[0], topn)
         adm_toks = adm_toks.at[slot].set(tok)
-        return tok, lp, t_ids.astype(jnp.int32), t_lp, cache, adm_toks
+        return tok, lp, t_ids.astype(jnp.int32), t_lp, cache, adm_toks, counters
 
     K = cfg.decode_chunk
 
@@ -3308,10 +3382,11 @@ def build_step_functions(
             hist = hist.at[rows, lengths].set(
                 jnp.where(active, last, hist[rows, lengths])
             )
-            logits, cache = llama.decode_step_paged(
+            logits, cache = model.decode_step_paged(
                 params, mc, last[:, None], cache, tables, lengths,
                 lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
             )
+            cache, counters = split_counters(cache)
             with jax.named_scope("sampling"):
                 logits = mask_pad(logits[:, 0])  # [B, V]
 
@@ -3386,17 +3461,18 @@ def build_step_functions(
                 )
             lengths = jnp.where(active, lengths + 1, lengths)
             return (cache, hist, lengths, corr, step_keys[:, 1]), (
-                corr, lp_corr, t_ids, t_lp,
+                corr, lp_corr, t_ids, t_lp, counters,
             )
 
         (cache, hist, lengths, last, keys), (
-            c_seq, lpc_seq, tid_seq, tlp_seq,
+            c_seq, lpc_seq, tid_seq, tlp_seq, counters_seq,
         ) = jax.lax.scan(
             body, (cache, hist, lengths, last_tokens, keys), None, length=K
         )
         return (
             c_seq, lpc_seq, tid_seq, tlp_seq,
             cache, hist, lengths, last, jax.random.key_data(keys),
+            {k: v.sum(0) for k, v in counters_seq.items()},  # the chunk's K steps
         )
 
     # adm_toks (prefill arg 11 / chunk arg 12) and the cache are
@@ -3420,9 +3496,9 @@ def build_step_functions(
             for k, s in paged_cache_specs().items()
         }
         shard_kw = {
-            "out_shardings": (repl, repl, repl, repl, cache_sh, repl, repl, repl, repl)
+            "out_shardings": (repl, repl, repl, repl, cache_sh, repl, repl, repl, repl, repl)
         }
-        chunk_kw = {"out_shardings": (repl, repl, repl, repl, cache_sh, repl)}
+        chunk_kw = {"out_shardings": (repl, repl, repl, repl, cache_sh, repl, repl)}
     # tables + per-slot request state (active/temp/top_p/top_k and
     # the adm_* merge arrays) are host-authoritative numpy uploaded
     # per dispatch — not donated. cache/hist/lengths/last/keys are
@@ -3464,6 +3540,6 @@ def build_test_engine(
         dtype="float32",
         max_position=2048,
     )
-    params = llama.init_params(mc, jax.random.key(seed))
+    params = family(mc).init_params(mc, jax.random.key(seed))
     ec = engine_config or EngineConfig(max_slots=4, max_seq_len=256, prefill_buckets=(16, 32, 64, 128))
     return Engine(mc, params, tok, ec)

@@ -143,6 +143,12 @@ def model_fingerprint(model_config, page_size: int) -> str:
         int(page_size),
         VERSION,
     )
+    if getattr(mc, "kv_lora_rank", 0):
+        # A latent page ([page, 1, W]): what its bytes mean is decided by
+        # the latent's split, not by a head layout. (The blob's header
+        # carries the payload's own shape, trailing page shape included,
+        # and an import whose layout differs is refused by name.)
+        fields += (str(mc.model_type), int(mc.kv_lora_rank), int(mc.qk_rope_head_dim))
     return hashlib.sha256(repr(fields).encode()).hexdigest()[:32]
 
 
@@ -284,8 +290,11 @@ def decode_state(
         events_raw = header["events"]
     except (KeyError, TypeError, ValueError) as e:
         raise KVFormatError(f"malformed header field: {e}") from None
-    if len(shape) != 5 or any(d < 0 for d in shape):
-        raise KVFormatError(f"payload shape must be 5-D, got {shape}")
+    # [n_pages, L, page, 2*Kv, h], or [n_pages, L, page, W] for latent
+    # pages: the header carries the page's own trailing shape, and the
+    # importer refuses a layout that is not its pool's, by name.
+    if len(shape) not in (4, 5) or any(d < 0 for d in shape):
+        raise KVFormatError(f"payload shape must be 4-D or 5-D, got {shape}")
     if len(crcs) != shape[0]:
         raise KVFormatError("page checksum count does not match page count")
     hlen = struct.unpack(">I", blob[5:9])[0]

@@ -20,7 +20,7 @@ import numpy as np
 
 from kubeai_tpu.engine.core import Engine, EngineConfig
 from kubeai_tpu.engine.tokenizer import load_tokenizer
-from kubeai_tpu.models import llama
+from kubeai_tpu.models import family
 from kubeai_tpu.models.base import ModelConfig
 from kubeai_tpu.parallel import llama_param_specs, make_mesh, shard_tree
 
@@ -341,6 +341,8 @@ def load_engine_from_path(
     config = apply_backend_flags(
         ModelConfig.from_json_file(path).replace(dtype=dtype)
     )
+    model = family(config)  # by the checkpoint's model_type, nothing else
+    model.refuse_unsupported(config, quantization=quantization, tp=tp)
     multiproc = jax.process_count() > 1
     if stream is None:
         stream = os.environ.get("KUBEAI_STREAM_WEIGHTS", "1") != "0"
@@ -422,7 +424,14 @@ def load_engine_from_path(
         )
 
     with timeline.phase("load"):
-        if use_stream:
+        if use_stream and hasattr(model, "stream_params_from_hf"):
+            # A family that streams its own tree (models/deepseek.py).
+            from kubeai_tpu.engine.coldstart import padded_vocab_size
+
+            padded = padded_vocab_size(config.vocab_size, tp)
+            params = model.stream_params_from_hf(source, config, pad=padded - config.vocab_size)
+            config = config.replace(vocab_size=padded)
+        elif use_stream:
             params, config = stream_params_from_hf(
                 source, config, tp=tp, quantization=quantization, mesh=mesh
             )
@@ -435,7 +444,7 @@ def load_engine_from_path(
             # (leaving it numpy would re-upload the model on every
             # jitted step). Multi-process: stay on host until
             # shard_tree assembles the global arrays.
-            params = llama.params_from_hf(
+            params = model.params_from_hf(
                 sd, config, to_device=quantization != "int8" and not multiproc
             )
             params, config = pad_vocab(params, config, multiple=max(tp * 128, 128))

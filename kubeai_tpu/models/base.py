@@ -12,6 +12,10 @@ from kubeai_tpu.ops.rope import RopeScaling
 
 @dataclass(frozen=True)
 class ModelConfig:
+    # The family: which model module runs the configuration
+    # (kubeai_tpu/models/__init__.py::family). From the published
+    # config.json's `model_type`; nothing else selects a module.
+    model_type: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -67,6 +71,27 @@ class ModelConfig:
     kv_cache_dtype: str = ""
     kv_scale_k: float = 1.0
     kv_scale_v: float = 1.0
+    # DeepSeek-V3 family (model_type "deepseek_v3", models/deepseek.py;
+    # read for that family only, so the same keys on another family's
+    # config.json stay ignored). Experts: the first
+    # `first_k_dense_replace` layers are dense (intermediate_size), the
+    # rest hold `n_routed_experts` of width `moe_intermediate_size`,
+    # `num_experts_per_tok` a token by sigmoid scores, beside
+    # `n_shared_experts` that every token takes.
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    first_k_dense_replace: int = 0
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # Latent attention (MLA): what a token caches is one vector of
+    # kv_lora_rank + qk_rope_head_dim values a layer, shared by all heads.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
 
     @property
     def head_dim_(self) -> int:
@@ -130,8 +155,11 @@ class ModelConfig:
                     sliding_window=get("sliding_window") or 0,
                     sliding_layers="even",
                 )
+        if model_type == "deepseek_v3":
+            gemma_kw = _deepseek_v3_keys(get)
         return cls(
             **gemma_kw,
+            model_type=model_type,
             vocab_size=config.vocab_size,
             hidden_size=config.hidden_size,
             intermediate_size=get("intermediate_size") or get("ffn_dim"),
@@ -162,3 +190,32 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+
+def _deepseek_v3_keys(get) -> dict:
+    """The DeepSeek-V3 keys of a published config.json as ModelConfig
+    fields. What models/deepseek.py does not compute is refused here,
+    by name, and not served as something else."""
+    if get("q_lora_rank"):
+        raise ValueError("deepseek_v3: a query low-rank (q_lora_rank) is not supported")
+    if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
+        raise ValueError("deepseek_v3: group-limited routing (n_group/topk_group > 1) is not supported")
+    if get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"deepseek_v3: scoring_func {get('scoring_func')!r} is not supported (sigmoid)")
+    if (get("moe_layer_freq") or 1) != 1:
+        raise ValueError("deepseek_v3: moe_layer_freq other than 1 is not supported")
+    if get("attention_bias"):
+        raise ValueError("deepseek_v3: attention_bias is not supported")
+    return dict(
+        n_routed_experts=get("n_routed_experts") or 0,
+        n_shared_experts=get("n_shared_experts") or 0,
+        moe_intermediate_size=get("moe_intermediate_size") or 0,
+        first_k_dense_replace=get("first_k_dense_replace") or 0,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
+        kv_lora_rank=get("kv_lora_rank"),
+        qk_nope_head_dim=get("qk_nope_head_dim"),
+        qk_rope_head_dim=get("qk_rope_head_dim"),
+        v_head_dim=get("v_head_dim"),
+        rope_interleave=bool(get("rope_interleave", False)),
+    )

@@ -222,6 +222,11 @@ def kv_pool_dtype(config: ModelConfig):
 
 
 LORA_TARGETS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
+# What the engine's step records call this family's "paged_kernel" route.
+PAGED_KERNEL_LABEL = "ragged"
+# A prefix found in the cache is used to the page (models/deepseek.py has
+# the family that uses it in whole prefill calls, and why).
+REUSE_WHOLE_PREFILL_CALLS = False
 
 
 def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, paged: bool) -> str:
@@ -250,6 +255,11 @@ def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, page
     if config.use_paged_kernel and paged and config.sliding_window == 0:
         return "paged_kernel"
     return "xla"
+
+
+def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1) -> None:
+    """What this family does not run, refused at load: nothing beyond
+    what `weights.load_engine_from_path` checks for every family."""
 
 
 def init_lora_bank(config: ModelConfig, n_adapters: int, rank: int, dtype=None) -> Params:

@@ -124,6 +124,8 @@ def param_counts(mc) -> tuple[float, float]:
     resident (weight-read roofline: a batched decode step touches all
     experts) but only the routed top-k as active (FLOPs/token)."""
     D, F, L, V = mc.hidden_size, mc.intermediate_size, mc.num_layers, mc.vocab_size
+    if getattr(mc, "model_type", "") == "deepseek_v3":
+        return _deepseek_v3_param_counts(mc)
     H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
     attn = D * H * h + 2 * D * Kv * h + H * h * D
     if getattr(mc, "qkv_bias", False):
@@ -142,6 +144,28 @@ def param_counts(mc) -> tuple[float, float]:
     head = 0 if getattr(mc, "tie_word_embeddings", False) else V * D
     fixed = embed + head + D
     return float(fixed + L * layer_total), float(fixed + L * layer_active)
+
+
+def _deepseek_v3_param_counts(mc) -> tuple[float, float]:
+    """DeepSeek-V3 family (models/deepseek.py): latent attention without
+    a query low-rank; `first_k_dense_replace` dense layers, then layers
+    whose every routed expert is resident and of which a token passes
+    through `num_experts_per_tok` and the shared ones. kanana-2 at 8
+    layers: 5.07G held, 0.78G a token (64.1M + 7 x 64.4M + the head)."""
+    D, L, V, H = mc.hidden_size, mc.num_layers, mc.vocab_size, mc.num_heads
+    dn, dr, dv, r = mc.qk_nope_head_dim, mc.qk_rope_head_dim, mc.v_head_dim, mc.kv_lora_rank
+    attn = D * H * (dn + dr) + D * (r + dr) + r + r * H * (dn + dv) + H * dv * D + 2 * D
+    n_dense = min(mc.first_k_dense_replace, L)
+    expert = 3 * D * mc.moe_intermediate_size
+    router = D * mc.n_routed_experts + mc.n_routed_experts
+    shared = mc.n_shared_experts * expert
+    dense = attn + 3 * D * mc.intermediate_size
+    fixed = 2 * V * D + D
+    total = fixed + n_dense * dense + (L - n_dense) * (attn + router + shared + mc.n_routed_experts * expert)
+    # Active leaves the embedding table out (a row is looked up, not
+    # multiplied; at 128k rows it would be a third of the count).
+    active = V * D + D + n_dense * dense + (L - n_dense) * (attn + router + shared + mc.num_experts_per_tok * expert)
+    return float(total), float(active)
 
 
 @dataclass(frozen=True)

@@ -1,0 +1,125 @@
+"""Exact routed experts: every (token, choice) assignment is computed,
+none is dropped and there is no capacity.
+
+The `T*k` assignments are sorted by expert, the rows gathered in that
+order, and each of the three expert projections is ONE grouped matmul
+over the groups that have rows (rows of expert e times expert e's
+matrix); the weighted rows are scatter-added back to their tokens.
+Shapes are static (`T*k` rows whatever the loads are), so a batch whose
+routing changes never recompiles. `models/llama.py::moe_mlp` (Mixtral:
+softmax over the top-k, a static capacity, tokens past it dropped) is a
+different layer and stays where it is.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+# (tm, tk, tn) the grouped matmul was given, per call shape this process
+# has traced (diagnosis: /debug/engine -> perf.grouped_matmul_tiles).
+chosen_tiles: dict[str, tuple[int, int, int]] = {}
+
+
+def gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """Tiles of the megablox kernel from the call's own shapes. Rows: a
+    tile that spans several experts is visited once for each, masked, so
+    where there are few rows an expert (decode: 384 rows over 128
+    experts) the tile is the smallest bf16 holds (16), and where there
+    are many (prefill) it is 512 cut to the rows there are. The
+    contraction and the output keep the expert's whole matrix in one
+    tile where it is at most 4 MiB in bf16 (2048 x 768: 3 MiB, two
+    buffers), so an expert's weights are one DMA and a grid step is not
+    shorter than its fixed cost."""
+    tm = 16 if m <= 2048 else 512
+    while m % tm:
+        tm //= 2
+    tk, tn = k, n
+    while tk * tn > (2 << 20) and tn % 256 == 0 and tn > 512:
+        tn //= 2
+    while tk * tn > (2 << 20) and tk % 256 == 0 and tk > 512:
+        tk //= 2
+    return tm, tk, tn
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """`lhs[rows of group g] @ rhs[g]` for every group: lhs [m, k] with
+    its rows sorted by group, rhs [G, k, n], group_sizes [G] int32 summing
+    to m. On the chip the megablox Pallas kernel (it visits only groups
+    that have rows, so an expert nobody chose is not read); elsewhere
+    `jax.lax.ragged_dot`, the same mathematics in XLA."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tiles = gmm_tiles(m, k, n)
+    chosen_tiles[f"m={m} k={k} n={n} G={rhs.shape[0]}"] = tiles
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=tiles)
+
+
+def route_sigmoid(x, wr, bias, k: int, norm_topk: bool, scale: float, forced=None):
+    """DeepSeek-V3's `noaux_tc` router with one group: scores are
+    sigmoid(float32(x) W_g^T); the top k of score + bias are chosen; the
+    weights are the SCORES at the chosen (without the bias), divided by
+    their sum + 1e-20 where `norm_topk`, times `scale`. x [T, D], wr
+    [D, E], bias [E]. Returns (idx [T, k] int32, weights [T, k] float32).
+    *forced* [T, k] takes the place of the choice (a debug call: a
+    comparison that must not hang on which side of a near-tie each
+    side's rounding fell)."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x.astype(jnp.float32), wr.astype(jnp.float32), preferred_element_type=jnp.float32)
+    )
+    if forced is None:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32)[None, :], k)
+    else:
+        idx = forced
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if norm_topk:
+        w = w / (w.sum(axis=1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def routed_experts(x, idx, weights, wg, wu, wd, layer=None):
+    """sum_i weights[t, i] * SwiGLU_{idx[t, i]}(x[t]) for every token:
+    x [T, D]; idx, weights [T, k]; wg, wu [E, D, F]; wd [E, F, D].
+    Returns (y [T, D] in x's dtype, hit: how many experts got a row).
+
+    With *layer* (a traced int32) the weights are the WHOLE stack, wg, wu
+    [L, E, D, F] and wd [L, E, F, D], and the layer's experts are groups
+    layer*E .. layer*E+E-1 of L*E, every other group empty: the grouped
+    matmul reads only groups that have rows, so a scan over layers hands
+    it the stack as it lies in memory. (Slicing a layer's experts out
+    of the stack first is a copy of all of them, 1.2 GB a layer for
+    kanana-2: 20 ms of a 49 ms decode step, PERF.md section 6, PR 33.)"""
+    T, D = x.shape
+    k = idx.shape[1]
+    E = wg.shape[-3]
+    with jax.named_scope("moe.dispatch"):
+        flat = idx.reshape(T * k)
+        order = jnp.argsort(flat, stable=True)
+        token = order // k
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        hit = (group_sizes > 0).sum().astype(jnp.int32)
+        if layer is not None:
+            L = wg.shape[0]
+            group_sizes = jax.lax.dynamic_update_slice(jnp.zeros((L * E,), jnp.int32), group_sizes, (layer * E,))
+            wg, wu, wd = (w.reshape(L * E, *w.shape[2:]) for w in (wg, wu, wd))
+        rows = x[token]  # [T*k, D], sorted by expert
+    # `moe.experts` holds the grouped matmuls and nothing else: a device
+    # trace files their time under it whatever implements them.
+    with jax.named_scope("moe.experts"):
+        gate = grouped_matmul(rows, wg, group_sizes)
+        up = grouped_matmul(rows, wu, group_sizes)
+    act = jax.nn.silu(gate) * up
+    with jax.named_scope("moe.experts"):
+        out = grouped_matmul(act, wd, group_sizes)
+    with jax.named_scope("moe.combine"):
+        # Back to (token, choice) order by a gather (row j of the sorted
+        # rows is assignment order[j]), then the weighted sum over a
+        # token's k rows in float32.
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(jnp.arange(T * k, dtype=jnp.int32))
+        rows_back = out[back].reshape(T, k, D).astype(jnp.float32)
+        y = (rows_back * weights[:, :, None]).sum(axis=1)
+    return y.astype(x.dtype), hit

@@ -16,7 +16,7 @@ from kubeai_tpu.models.base import ModelConfig
 SCOPES = ("embed", "attn", "attn.kernel", "ffn", "lm_head", "sampling", "logprobs")
 
 
-def _lowered_programs(scoped: bool) -> list[tuple[str, str]]:
+def _lowered_programs(scoped: bool, mc: ModelConfig | None = None) -> list[tuple[str, str]]:
     """(text without debug info, text with it) of every program the warm
     compile lowers: the decode chunk, the prefill buckets, the chunked
     prefill. Nothing is compiled."""
@@ -29,7 +29,7 @@ def _lowered_programs(scoped: bool) -> list[tuple[str, str]]:
         m.setattr(jax.stages.Lowered, "compile", record)
         if not scoped:
             m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-        mc = ModelConfig(
+        mc = mc or ModelConfig(
             vocab_size=272, hidden_size=64, intermediate_size=128, num_layers=2,
             num_heads=4, num_kv_heads=2, dtype="float32", max_position=256,
             # The kernel routes (XLA twins on the CPU): attn.kernel is
@@ -64,4 +64,38 @@ def test_every_scope_names_operations(scoped_programs, scope):
     # is one component of the operation's name.
     rx = re.compile(r'["/]' + re.escape(scope) + "/")
     for _, debug_text in scoped_programs[:2]:  # the decode chunk, a prefill
+        assert rx.search(debug_text), scope
+
+
+# -- the expert family's module (models/deepseek.py, ops/moe.py) ---------------
+
+MOE_SCOPES = (
+    "embed", "attn", "attn.kernel", "ffn", "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+    "moe.shared", "lm_head", "sampling", "logprobs",
+)
+DEEPSEEK = ModelConfig(
+    model_type="deepseek_v3", vocab_size=272, hidden_size=64, intermediate_size=128, num_layers=3, num_heads=4,
+    num_kv_heads=4, dtype="float32", max_position=256, num_experts_per_tok=2, n_routed_experts=8,
+    n_shared_experts=1, moe_intermediate_size=32, first_k_dense_replace=1, routed_scaling_factor=2.448,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+)
+
+
+@pytest.fixture(scope="module")
+def scoped_moe_programs():
+    return _lowered_programs(True, DEEPSEEK)
+
+
+def test_scopes_change_metadata_only_in_the_expert_family(scoped_moe_programs):
+    plain = _lowered_programs(False, DEEPSEEK)
+    assert len(scoped_moe_programs) == len(plain) >= 4
+    for i, ((s_text, s_debug), (p_text, p_debug)) in enumerate(zip(scoped_moe_programs, plain)):
+        assert s_text == p_text, f"program {i}: the computation changed with the scopes"
+        assert s_debug != p_debug, f"program {i}: the scopes left no trace in the metadata"
+
+
+@pytest.mark.parametrize("scope", MOE_SCOPES)
+def test_every_scope_names_operations_in_the_expert_family(scoped_moe_programs, scope):
+    rx = re.compile(r'["/]' + re.escape(scope) + "/")
+    for _, debug_text in scoped_moe_programs[:2]:  # the decode chunk, a prefill
         assert rx.search(debug_text), scope

@@ -41,28 +41,52 @@ def test_selftest_passes():
     assert proc.returncode == 0 and out.strip().endswith("all passed"), out[-3000:]
 
 
+# The cells PR 24/25's eight metrics name; a cell of a later PR (another
+# family's) carries none of them and has its own case below.
+NEW_CELLS = sorted(set().union(*NEW.values()))
+# PR 33's cell and what it declares: every per-layer metric that lists it.
+MOE_CELL = "kanana2-bf16-reason-sat"
+MOE_METRICS = {
+    "decode_step_ms.moe", "device_idle_pct.moe", "prefill_share_pct.moe", "batch_occupancy_pct.moe",
+    "kv_pages_peak_pct.moe", "host_work_per_chunk_ms.moe", "prefill_padding_pct.moe", "decode_mla_share_pct",
+    "decode_moe_share_pct", "decode_sampling_share_pct.moe", "moe_experts_hit_pct", "scale_from_zero_s.moe",
+    "engine_load_s.moe", "engine_warmup_s.moe", "moe_experts_roofline", "mla_decode_roofline",
+    "decode_step_roofline.moe", "window_mfu.moe",
+}
+
+
 def test_benchmark_declares_the_new_metrics():
     bench = resultline.load_benchmark()
     assert "trace_in_run" not in bench  # the switch was left out (PERF.md section 7)
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == list(NEW)  # appended, in the issue's order
+    names = [m["name"] for m in bench["per_layer"]]
+    at = [names.index(n) for n in NEW]  # each declared, wherever later PRs' entries stand
+    assert at == sorted(at) and at == list(range(at[0], at[0] + len(NEW)))  # together, in the issue's order
+    for m in bench["per_layer"]:
+        if m["name"] in NEW:
+            assert set(m["workloads"]) == NEW[m["name"]]
     for name in NEW:
         with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
             spec = json.load(f)
         assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py"))
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in resultline.load_benchmark()["workloads"]])
-def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
-    bench = resultline.load_benchmark()
-    traced, untraced = resultline.declared(bench, cell, True), resultline.declared(bench, cell, False)
-    mine = {n for n, cells in NEW.items() if cell in cells}
-    assert mine and mine <= set(traced) and not set(NEW) & set(untraced)
-    assert not (set(NEW) - mine) & set(traced)
-    line = {
+def _traced_line(traced):
+    return {
         "correct": True, "attempted": 10, "failed": 0,
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1.2e10, "window_s": 4.0, "busy_s": 3.9},
         "metrics": {n: {"value": 1.5, "unit": u} for n, u in traced.items()},
     }
+
+
+@pytest.mark.parametrize("cell", NEW_CELLS)
+def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
+    bench = resultline.load_benchmark()
+    assert cell in [w["name"] for w in bench["workloads"]]
+    traced, untraced = resultline.declared(bench, cell, True), resultline.declared(bench, cell, False)
+    mine = {n for n, cells in NEW.items() if cell in cells}
+    assert mine and mine <= set(traced) and not set(NEW) & set(untraced)
+    assert not (set(NEW) - mine) & set(traced)
+    line = _traced_line(traced)
     assert resultline.problems(line, bench, cell, True, 1) == []
     # A reader that finds nothing (the parent's program) leaves its metric out.
     cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k not in mine}}
@@ -70,7 +94,40 @@ def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
     assert resultline.problems(cut, bench, cell, True, 1)
 
 
-@pytest.mark.parametrize("name", ["chat-sat", "chat-rate", "docqa"])
+def test_every_cell_is_one_of_those_with_a_case_here():
+    cells = [w["name"] for w in resultline.load_benchmark()["workloads"]]
+    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL])
+
+
+def test_the_expert_models_cell_declares_its_own_metrics_and_none_of_the_dense_cells():
+    bench = resultline.load_benchmark()
+    traced, untraced = resultline.declared(bench, MOE_CELL, True), resultline.declared(bench, MOE_CELL, False)
+    assert set(traced) == MOE_METRICS and not set(NEW) & set(traced)
+    assert set(untraced) == {"output_tok_s", "setup_s"}
+    for m in bench["per_layer"]:  # no metric without a list: a later cell takes none by default
+        assert "workloads" in m, m["name"]
+        if m["name"] in MOE_METRICS:
+            assert m["workloads"] == [MOE_CELL]
+            assert m["moves"] == ("setup_s" if m["name"].split(".")[0] in ("scale_from_zero_s", "engine_load_s", "engine_warmup_s") else "output_tok_s")
+    for name in MOE_METRICS:
+        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py"))
+
+
+@pytest.mark.parametrize("missing", sorted(n for n in MOE_METRICS if n.endswith(".moe")))
+def test_a_traced_line_of_the_expert_models_cell(missing):
+    bench = resultline.load_benchmark()
+    line = _traced_line(resultline.declared(bench, MOE_CELL, True))
+    assert resultline.problems(line, bench, MOE_CELL, True, 1) == []
+    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
+    assert resultline.problems(cut, bench, MOE_CELL, True, 1) == [f"metric {missing} of this workload and mode is missing"]
+    assert resultline.problems(cut, bench, MOE_CELL, True, 1, may_miss={missing}) == []
+    over = {**line, "metrics": {**line["metrics"], "window_mfu.moe": {"value": 106.0, "unit": "%"}}}
+    assert any("over 105%" in p for p in resultline.problems(over, bench, MOE_CELL, True, 1))
+
+
+@pytest.mark.parametrize("name", ["chat-sat", "chat-rate", "docqa", "reason-sat"])
 @pytest.mark.parametrize("seed", [3, 2**31 + 17])
 def test_a_longer_plan_has_the_shorter_one_as_its_prefix(name, seed):
     spec = traffic.load(name)
@@ -215,3 +272,28 @@ def test_rehearsal_of_a_traced_run(tmp_path):
     assert 0 <= last["metrics"]["prefill_padding_pct"]["value"] < 100
     shares = [last["metrics"][n]["value"] for n in mine if n.endswith("_share_pct")]
     assert all(0 <= v <= 100 for v in shares) and sum(shares) <= 100.0
+
+
+@pytest.mark.slow  # about a minute alone, more beside five other workers: not tier-1 (CHANGES.md, PR 33)
+def test_rehearsal_of_the_expert_models_cell(tmp_path):
+    """--rehearse --trace 1 of kanana2-bf16-reason-sat at the tests' small
+    size: every phase, the family's two-part logits check, and every
+    per-layer metric the CPU can read."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", MOE_CELL, "--rehearse",
+         "--trace", "1", "--seed", str(2**31 + 7)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600,
+    )
+    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
+    last = lines[-1]
+    bench = resultline.load_benchmark()
+    # Time a step of one scope means nothing in a CPU trace (readers/moe_rooflines.py).
+    may_miss = {"moe_experts_roofline", "mla_decode_roofline"}
+    assert resultline.problems(last, bench, MOE_CELL, True, 1, rehearsal=True, may_miss=may_miss) == []
+    assert last["correct"] is True and MOE_METRICS - may_miss <= set(last["metrics"])
+    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
+    assert 0 < last["metrics"]["moe_experts_hit_pct"]["value"] <= 100

@@ -216,3 +216,58 @@ def test_tp4_decode_step_keeps_the_kernel(v5e):
     # A quarter of the pool on each chip, not the whole of it.
     pool_bytes = np.prod(pool["kv"].shape) * 2
     assert compiled.memory_analysis().output_size_in_bytes < pool_bytes / 2
+
+
+# -- kanana-2 (deepseek_v3): the kernels of its step at the published widths --
+
+KANANA_H, KANANA_W, KANANA_RANK = 32, 640, 512  # 32 heads; latent 512 + rope 64, stored at 640
+
+
+def _sds(v5e, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(v5e[0]))
+
+
+def test_mla_paged_decode_kernel_lowers(v5e):
+    """One query row a slot, 64 slots x 4096 tokens of latent pages."""
+    from kubeai_tpu.ops.mla_attention import mla_paged_decode
+
+    B, max_pages = 64, 4096 // PAGE
+    text = _compile(
+        lambda q, pool, table, lens: mla_paged_decode(q, pool, table, lens, scale=192**-0.5, rank=KANANA_RANK),
+        _sds(v5e, (B, KANANA_H, KANANA_W), jnp.bfloat16),
+        _sds(v5e, (8 * (B * max_pages + 1), PAGE, KANANA_W), jnp.bfloat16),
+        _sds(v5e, (B, max_pages), jnp.int32),
+        _sds(v5e, (B,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("B,S", [(1, 256), (8, 1024)])
+def test_latent_prefill_attention_lowers(v5e, B, S):
+    """Every prefill's attention: 32 query heads of 640 over latent pages,
+    in blocks of keys (XLA; no kernel)."""
+    from kubeai_tpu.ops.mla_attention import latent_attention_paged
+
+    max_pages = 4096 // PAGE
+    text = _compile(
+        lambda q, pool, table, pos: latent_attention_paged(q, pool, table, pos, scale=192**-0.5, rank=KANANA_RANK),
+        _sds(v5e, (B, S, KANANA_H, KANANA_W), jnp.bfloat16),
+        _sds(v5e, (8 * (64 * max_pages + 1), PAGE, KANANA_W), jnp.bfloat16),
+        _sds(v5e, (B, max_pages), jnp.int32),
+        _sds(v5e, (B, S), jnp.int32),
+    )
+    assert "while" in text and "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("rows,k,n", [(384, 2048, 768), (384, 768, 2048), (8 * 1024 * 6, 2048, 768), (8 * 1024 * 6, 768, 2048)])
+def test_grouped_expert_matmul_lowers(v5e, rows, k, n):
+    """The decode step's 64 x 6 assignments and a full prefill group's,
+    over the 7 x 128 experts of the whole stack (one layer's groups hold
+    the rows), with the tiles `gmm_tiles` chooses."""
+    from kubeai_tpu.ops.moe import grouped_matmul
+
+    text = _compile(
+        grouped_matmul,
+        _sds(v5e, (rows, k), jnp.bfloat16), _sds(v5e, (7 * 128, k, n), jnp.bfloat16), _sds(v5e, (7 * 128,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
