@@ -22,18 +22,24 @@ chosen_tiles: dict[str, tuple[int, int, int]] = {}
 
 
 def gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
-    """Tiles of the megablox kernel from the call's own shapes. Rows: a
-    tile that spans several experts is visited once for each, masked, so
-    where there are few rows an expert (decode: 384 rows over 128
-    experts) the tile is the smallest bf16 holds (16), and where there
-    are many (prefill) it is 512 cut to the rows there are. The
-    contraction and the output keep the expert's whole matrix in one
-    tile where it is at most 4 MiB in bf16 (2048 x 768: 3 MiB, two
-    buffers), so an expert's weights are one DMA and a grid step is not
-    shorter than its fixed cost."""
-    tm = 16 if m <= 2048 else 512
-    while m % tm:
-        tm //= 2
+    """Tiles of the megablox kernel from the call's own shapes. The
+    contraction and the output keep the expert's whole matrix in one tile
+    where it is at most 4 MiB in bf16 (2048 x 768: 3 MiB, two buffers), so
+    an expert's weights are one DMA and a grid step is not shorter than
+    its fixed cost.
+
+    Rows: the largest tile of at most 192 that divides them (Mosaic takes
+    a block of whole 8-row sublanes, or one as tall as the array: rows no
+    such tile divides are one tile). A grid step is one (row tile, expert)
+    pair, masked to the expert's rows. A smaller tile has more edges for
+    an expert's rows to straddle, each a second pass of its matrix through
+    the MXU, and, costing more than those, more tiles for the kernel's
+    group metadata to lay out before a layer's three calls; from 256 rows
+    up a step is compute-bound on masked rows. Read on the chip in the
+    step's own form at every shape the engine compiles, under real and
+    uniform loads: 192 is the fastest or within 1.2% of it at each
+    (PERF.md section 6, PR 35)."""
+    tm = max((t for t in range(8, min(m, 192) + 1, 8) if m % t == 0), default=m)
     tk, tn = k, n
     while tk * tn > (2 << 20) and tn % 256 == 0 and tn > 512:
         tn //= 2

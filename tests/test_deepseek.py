@@ -269,6 +269,62 @@ def test_a_layer_of_the_experts_stack_is_read_in_place(layer):
     assert int(hit) == len(set(idx.reshape(-1).tolist()))
 
 
+# The grouped matmul's call shapes at kanana-2's widths (hidden 2048, expert
+# width 768, top-6) as the engine compiles them for the benchmark's cell
+# (64 slots; `prefill_buckets` x (1, `prefill_group_cap`) rows) and for the
+# 128-slot run of PERF.md: rows = 6 x tokens in the call.
+GMM_CALLS = [("decode_64_slots", 6 * 64), ("decode_128_slots", 6 * 128)] + [
+    (f"prefill_{rows}x{bucket}", 6 * rows * bucket) for rows in (1, 8) for bucket in (32, 64, 128, 256, 512, 1024)
+]
+SCOPED_VMEM = 16 << 20  # what Mosaic gives a kernel on a v5e unless it is asked for more
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)], ids=["gate_up", "down"])
+@pytest.mark.parametrize("call,m", GMM_CALLS, ids=[c for c, _ in GMM_CALLS])
+def test_the_grouped_matmuls_row_tile_comes_from_the_calls_rows(call, m, k, n):
+    """The tile divides the rows (the kernel refuses another), is whole
+    bf16 sublane pairs, keeps the expert's matrix in one tile (one DMA an
+    expert) and fits the scoped VMEM: two buffers each of the lhs, rhs and
+    out blocks in bf16 and the float32 accumulator. The decode shapes get
+    what the chip read fastest in the step's own form (PERF.md section 6,
+    PR 35)."""
+    tm, tk, tn = moe.gmm_tiles(m, k, n)
+    assert (tk, tn) == (k, n)
+    assert m % tm == 0 and tm % 16 == 0
+    assert 2 * 2 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn <= SCOPED_VMEM
+    if call.startswith("decode"):
+        assert tm == 192
+
+
+@pytest.mark.parametrize("m,tm", [(6 * 10, 60), (6 * 3, 18), (6 * 100, 120), (6 * 48, 144)])
+def test_any_number_of_slots_gets_a_row_tile_mosaic_takes(m, tm):
+    """A block of whole 8-row sublanes that divides the rows (100 slots:
+    120, not a multiple of 16; 48 slots: 144), and where no such block
+    divides them (10 slots, 3 slots) the whole array, always a legal one."""
+    assert moe.gmm_tiles(m, 2048, 768)[0] == tm
+
+
+def test_the_kernel_at_the_chosen_tile_is_ragged_dot():
+    """megablox in interpret mode at the tile `gmm_tiles` gives 576 rows
+    (three tiles of 192), against `jax.lax.ragged_dot`: the stack of three
+    layers with only the middle one's groups filled (empty groups on both
+    sides), a group that ends inside a tile, one that straddles a tile's
+    edge, an empty one, one that ends on an edge and one that starts there."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    rng = np.random.default_rng(11)
+    m, k, n, E, L = 576, 128, 128, 5, 3
+    tiles = moe.gmm_tiles(m, k, n)
+    assert tiles == (192, k, n)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(L * E, k, n)) * k**-0.5, jnp.float32)
+    sizes = np.zeros((L * E,), np.int32)
+    sizes[E : 2 * E] = [100, 156, 0, 128, 192]
+    got = gmm(lhs, rhs, jnp.asarray(sizes), preferred_element_type=jnp.float32, tiling=tiles, interpret=True)
+    want = jax.lax.ragged_dot(lhs, rhs, jnp.asarray(sizes))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-5)
+
+
 def test_the_router_is_sigmoid_with_the_bias_in_the_choice_only():
     x = jnp.asarray(np.random.default_rng(1).normal(size=(5, 8)), jnp.float32)
     wr = jnp.asarray(np.random.default_rng(2).normal(size=(8, 6)), jnp.float32)

@@ -259,11 +259,15 @@ def test_latent_prefill_attention_lowers(v5e, B, S):
     assert "while" in text and "tpu_custom_call" not in text
 
 
-@pytest.mark.parametrize("rows,k,n", [(384, 2048, 768), (384, 768, 2048), (8 * 1024 * 6, 2048, 768), (8 * 1024 * 6, 768, 2048)])
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)], ids=["gate_up", "down"])
+@pytest.mark.parametrize("rows", [384, 768, 192, 6 * 1024, 8 * 1024 * 6, 60, 600])
 def test_grouped_expert_matmul_lowers(v5e, rows, k, n):
-    """The decode step's 64 x 6 assignments and a full prefill group's,
-    over the 7 x 128 experts of the whole stack (one layer's groups hold
-    the rows), with the tiles `gmm_tiles` chooses."""
+    """The decode step's 64 x 6 and 128 x 6 assignments, a one-row prefill
+    call's at the smallest and the largest bucket and a full prefill
+    group's, over the 7 x 128 experts of the whole stack (one layer's
+    groups hold the rows), with the tiles `gmm_tiles` chooses: 192 rows,
+    and for 10 and 100 slots the whole 60 rows and 120 (every kind of row
+    tile the rule can return)."""
     from kubeai_tpu.ops.moe import grouped_matmul
 
     text = _compile(
