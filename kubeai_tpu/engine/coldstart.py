@@ -253,18 +253,18 @@ def warm_compile(
     import jax.numpy as jnp
 
     from kubeai_tpu.engine import core
-    from kubeai_tpu.models import family
 
     cfg = engine_config or core.EngineConfig()
     t0 = time.monotonic()
     sf = core.build_step_functions(model_config, cfg, n_valid_vocab)
-    max_pages, P, hist_width = core.engine_dims(cfg)
+    hist_width = core.engine_dims(cfg)[2]
+    # Columns of a block-table row: two tables side by side for a family
+    # with two page budgets a slot.
+    table_cols = core.table_width(model_config, cfg)
     B = cfg.max_slots
     Kb = cfg.max_logit_bias
     params = param_shapes(model_config, quantization)
-    cache = jax.eval_shape(
-        lambda: family(model_config).init_paged_cache(model_config, P, cfg.page_size)
-    )
+    cache = jax.eval_shape(lambda: core.init_pools(model_config, cfg))
     keys = jax.eval_shape(
         lambda: jax.random.key_data(jax.random.split(jax.random.key(0), B))
     )
@@ -288,7 +288,7 @@ def warm_compile(
     compile_one(
         "decode",
         sf.decode_jit,
-        params, cache, sds((B, max_pages), i32), sds((B, hist_width), i32),
+        params, cache, sds((B, table_cols), i32), sds((B, hist_width), i32),
         sds((B,), i32), sds((B,), i32), keys,
         sds((B,), jnp.bool_), sds((B,), f32), sds((B,), f32), sds((B,), i32),
         sds((B,), f32), sds((B,), f32), sds((B,), jnp.bool_), sds((B,), i32),
@@ -303,7 +303,7 @@ def warm_compile(
                 f"prefill_batch[{n_pad}x{bucket}]",
                 sf.prefill_batch_jit,
                 params, sds((n_pad, bucket), i32), sds((n_pad,), i32),
-                sds((n_pad, max_pages), i32), sds((n_pad,), i32),
+                sds((n_pad, table_cols), i32), sds((n_pad,), i32),
                 sds((n_pad,), u32), sds((n_pad,), f32), sds((n_pad,), f32),
                 sds((n_pad,), i32), sds((n_pad, Kb), i32),
                 sds((n_pad, Kb), f32), sds((B,), i32), cache,
@@ -313,7 +313,7 @@ def warm_compile(
         f"prefill_chunk[{max_bucket}]",
         sf.prefill_chunk_jit,
         params, sds((1, max_bucket), i32), sds((), i32), sds((), i32),
-        sds((1, max_pages), i32), sds((), i32), sds((), u32), sds((), f32),
+        sds((1, table_cols), i32), sds((), i32), sds((), u32), sds((), f32),
         sds((), f32), sds((), i32), sds((Kb,), i32), sds((Kb,), f32),
         sds((B,), i32), cache,
     )

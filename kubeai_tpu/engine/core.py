@@ -187,6 +187,38 @@ def engine_dims(cfg: EngineConfig) -> tuple[int, int, int]:
     return max_pages, P, hist_width
 
 
+def window_pool_dims(model_config: ModelConfig, cfg: EngineConfig) -> tuple[int, int]:
+    """(window in tokens, pages of the window layers' pool) for a family
+    whose window layers keep a page pool of their own beside the full
+    layers' (models/smallthinker.py); (0, 0) for every other family: one
+    page budget a slot. Nothing is configured: the window is the model's,
+    the pool holds every slot's cap (engine/paging.py::WindowPages), and
+    `num_pages` is then the FULL layers' pool."""
+    from kubeai_tpu.engine.paging import WindowPages
+
+    window = family(model_config).window_pool_tokens(model_config)
+    if not window:
+        return 0, 0
+    cap = WindowPages.slot_cap(engine_dims(cfg)[0], window, max(cfg.prefill_buckets), cfg.page_size)
+    return window, cfg.max_slots * cap + 1
+
+
+def table_width(model_config: ModelConfig, cfg: EngineConfig) -> int:
+    """Columns of a slot's block-table row: `max_pages`, and as many
+    again behind them for the window layers' table."""
+    return engine_dims(cfg)[0] * (2 if window_pool_dims(model_config, cfg)[0] else 1)
+
+
+def init_pools(model_config: ModelConfig, cfg: EngineConfig):
+    """The family's page pool(s) at the engine's dimensions (shared with
+    the AOT warm compiler, as engine_dims is)."""
+    window_pages = window_pool_dims(model_config, cfg)[1]
+    return family(model_config).init_paged_cache(
+        model_config, engine_dims(cfg)[1], cfg.page_size,
+        **({"window_pages": window_pages} if window_pages else {}),
+    )
+
+
 @dataclass
 class FinishInfo:
     reason: str  # "stop" | "length"
@@ -486,7 +518,39 @@ class Engine:
             "live KV pressure",
             pages_parked_fn,
         )
+        # The window layers' pool beside the full layers' (a family with
+        # two page budgets a slot, models/smallthinker.py; 0 / 0 for every
+        # other, whose one pool the series above describe).
+        wpages_used_fn = lambda: float(self._wpages.pool.used()) if self._wpages else 0.0  # noqa: E731
+        wpages_total_fn = lambda: float(self._wpages.pool.num_pages - 1) if self._wpages else 0.0  # noqa: E731
+        self.m_wpages_used = default_registry.callback_gauge(
+            "kubeai_engine_kv_window_pages_used",
+            "pages of the window layers' pool referenced by live slots "
+            "(kubeai_engine_kv_pages_used is then the full layers' pool)",
+            wpages_used_fn,
+        )
+        self.m_wpages_total = default_registry.callback_gauge(
+            "kubeai_engine_kv_window_pages_total",
+            "allocatable pages of the window layers' pool (0: the model "
+            "has one page budget a slot)",
+            wpages_total_fn,
+        )
+        self.m_window_released = default_registry.counter(
+            "kubeai_engine_kv_window_pages_released_total",
+            "window-pool pages slots handed back because no query of "
+            "theirs can see them any more (behind position - window)",
+        )
+        self.m_attn_pairs = default_registry.counter(
+            "kubeai_engine_attn_pairs_total",
+            "(query, key) pairs inside the attention mask that the "
+            "dispatched calls compute, summed over the layers of a kind "
+            "(full | window), by phase (prefill | decode): real tokens "
+            "only, from each call's start, tokens and the window; counted "
+            "for a family with window layers",
+        )
         self._gauge_callbacks = [
+            (self.m_wpages_used, wpages_used_fn),
+            (self.m_wpages_total, wpages_total_fn),
             (self.m_hbm_used, hbm_used_fn),
             (self.m_hbm_limit, hbm_limit_fn),
             (self.m_pages_used, pages_used_fn),
@@ -564,6 +628,9 @@ class Engine:
         # implementation the fleet collector's counter-delta tok/s uses,
         # so the two can no longer disagree during idle→busy transitions.
         self._rate_window = perf_obs.TokenRateWindow(span=10.0)
+        # Decode's masked (query, key) pairs over the same span, for a
+        # family that counts them (_count_attn_pairs): MFU's attention.
+        self._pairs_window = perf_obs.TokenRateWindow(span=10.0)
         self.m_gang_reforms = default_registry.counter(
             "kubeai_gang_reforms_total",
             "gang re-formations: a lost follower reconnected and rank 0 "
@@ -648,7 +715,7 @@ class Engine:
 
     def _mfu(self) -> float:
         peak, _ = self._perf_constants()
-        return self.perf.mfu(self.m_tok_rate.value(), peak)
+        return self.perf.mfu(self.m_tok_rate.value(), peak, self._pairs_window.rate())
 
     def _roofline_fraction(self) -> float:
         _, hbm = self._perf_constants()
@@ -662,6 +729,7 @@ class Engine:
         env = self.perf_env
         peak, hbm = self._perf_constants()
         roof = self.perf.roofline_tokens_per_sec(self.cfg.max_slots, hbm)
+        kv_bytes_per_token = int(self._cache["kv"].nbytes // (self._pool.num_pages * self.cfg.page_size))
         return {
             "tokens_per_second": self.m_tok_rate.value(),
             "mfu": round(self._mfu(), 5),
@@ -698,8 +766,26 @@ class Engine:
             # Bytes of the pool a token occupies, all layers, as stored:
             # the pool's own size over its tokens (a latent page is a
             # page of another width).
-            "kv_bytes_per_token": int(
-                self._cache["kv"].nbytes // (self._pool.num_pages * self.cfg.page_size)
+            "kv_bytes_per_token": kv_bytes_per_token,
+            # ... and by kind of layer where the window layers keep a
+            # pool of their own (the line above is then the full layers').
+            **(
+                {
+                    "kv_bytes_per_token_by_kind": {
+                        "full": kv_bytes_per_token,
+                        "window": int(
+                            self._cache["kv_window"].nbytes
+                            // (self._wpages.pool.num_pages * self.cfg.page_size)
+                        ),
+                    },
+                    "window_pool": {
+                        "window": self._wpages.window, "slot_cap_pages": self._wpages.cap,
+                        "pages_total": self._wpages.pool.num_pages - 1,
+                        "pages_used": self._wpages.pool.used(),
+                        "released_total": self._wpages.released,
+                    },
+                }
+                if self._wpages is not None else {}
             ),
             # Rows the cold group prefill calls computed (the counter
             # kubeai_engine_prefill_rows_total, since the process began).
@@ -742,7 +828,7 @@ class Engine:
     # -- device state ------------------------------------------------------
 
     def _init_device_state(self):
-        from kubeai_tpu.engine.paging import PagePool
+        from kubeai_tpu.engine.paging import PagePool, WindowPages
 
         B = self.cfg.max_slots
         ps = self.cfg.page_size
@@ -753,7 +839,7 @@ class Engine:
         # mid-chunk and keeps stepping never scatter-collides).
 
         def mk_device_arrays():
-            cache = family(self.model_config).init_paged_cache(self.model_config, P, ps)
+            cache = init_pools(self.model_config, self.cfg)
             tok_hist = jnp.zeros((B, hist_width), jnp.int32)
             adm_toks = jnp.zeros((B,), jnp.int32)
             lengths = jnp.zeros((B,), jnp.int32)
@@ -791,7 +877,22 @@ class Engine:
             self._lengths, self._last_tokens, self._keys,
         ) = out
         # Host-authoritative block tables, uploaded per dispatch (tiny).
-        self._page_table = np.zeros((B, self._max_pages), np.int32)
+        # A family whose window layers keep a pool of their own has a
+        # second table behind the first (table_width); WindowPages is
+        # that half's only writer.
+        self._page_table = np.zeros((B, table_width(self.model_config, self.cfg)), np.int32)
+        window = window_pool_dims(self.model_config, self.cfg)[0]
+        self._wpages = (
+            WindowPages(self._page_table[:, self._max_pages :], window, max(self.cfg.prefill_buckets), ps)
+            if window else None
+        )
+        # Where each slot's next decode chunk starts (the device's own
+        # `lengths`, kept in step on the host: admission sets it, every
+        # dispatch adds its steps to the active slots).
+        self._w_pos = np.zeros((B,), np.int64)
+        # (full layers, window layers): what _count_attn_pairs multiplies by.
+        self._kind_layers = family(self.model_config).layer_kinds(self.model_config) if window else (0, 0)
+        self._window_released_seen = 0  # of WindowPages.released, already in the counter
         # Per-slot request state is HOST-authoritative numpy, uploaded
         # with every decode dispatch (the arrays ride the execute RPC —
         # free). Round 2 kept these as device arrays mutated by eager
@@ -947,7 +1048,7 @@ class Engine:
                     self.params,
                     np.zeros((n_pad, bucket), np.int32),
                     np.full((n_pad,), bucket, np.int32),
-                    np.zeros((n_pad, self._max_pages), np.int32),
+                    np.zeros((n_pad, self._page_table.shape[1]), np.int32),
                     np.zeros((n_pad,), np.int32),
                     np.zeros((n_pad,), np.uint32),
                     np.ones((n_pad,), np.float32),
@@ -970,7 +1071,7 @@ class Engine:
                 np.zeros((1, bucket), np.int32),
                 np.int32(0),
                 np.int32(bucket - 1),
-                np.zeros((1, self._max_pages), np.int32),
+                np.zeros((1, self._page_table.shape[1]), np.int32),
                 np.int32(0),
                 np.uint32(0),
                 np.float32(1.0),
@@ -1722,6 +1823,7 @@ class Engine:
                         # the next busy chunk doesn't span the idle gap.
                         if len(self._rate_window):
                             self._rate_window.reset()
+                            self._pairs_window.reset()
                             self.m_tok_rate.set(0.0)
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
@@ -2143,6 +2245,22 @@ class Engine:
         self.m_moe_possible.inc(mc.n_routed_experts * layers * steps, labels=labels)
         self.m_moe_assign.inc(rows * mc.num_experts_per_tok * layers, labels=labels)
 
+    def _count_attn_pairs(self, phase: str, starts: np.ndarray, n: int) -> None:
+        """kubeai_engine_attn_pairs_total for calls of *n* real queries
+        a row behind *starts* [rows] cached tokens: a query at position
+        p sees p + 1 keys in a full layer and min(p + 1, window) in a
+        window layer. Exact, on the host, no sync."""
+        W = self._wpages.window
+        n_full, n_window = self._kind_layers
+        a, b = starts.astype(np.int64) + 1, starts.astype(np.int64) + n  # p + 1 runs a..b
+        seen = np.clip(np.minimum(b, W) - a + 1, 0, None)  # queries with at most W keys before and at them
+        full = ((a + b) * n // 2).sum()
+        window = ((a + np.minimum(b, W)) * seen // 2 + (n - seen) * W).sum()
+        self.m_attn_pairs.inc(int(n_full * full), labels={"kind": "full", "phase": phase})
+        self.m_attn_pairs.inc(int(n_window * window), labels={"kind": "window", "phase": phase})
+        if phase == "decode":
+            self._pairs_window.add(float(n_full * full + n_window * window))
+
     def _emit_admitted(self, admitted: list) -> None:
         """One host sync for all first tokens of an admission round —
         called AFTER the next decode chunk is dispatched (the chunk takes
@@ -2212,22 +2330,40 @@ class Engine:
         n_total = pages_for(len(ids) + budget, ps)
         sig = self._lora_sig(req.adapter)
         claimed: list[int] = []
+        wp = self._wpages
+        w_digests: list[bytes] = []
+        w_claimed: list[int] = []
+
+        def give_back() -> None:
+            self._pool.release(claimed)
+            if wp is not None:
+                wp.pool.release(w_claimed)
+
         if self.cfg.prefix_cache_min:
             claimed = self._pool.match_prefix(ids, sig)
+            step = 1  # pages a hit is cut down to a multiple of
             if claimed and family(self.model_config).REUSE_WHOLE_PREFILL_CALLS:
                 # A hit takes away whole leading calls of the prompt's cold
                 # prefill and nothing else (models/deepseek.py).
                 call = max(self.cfg.prefill_buckets)
-                keep = len(claimed) * ps // call * call // ps if call % ps == 0 else 0
-                self._pool.release(claimed[keep:])
-                claimed = claimed[:keep]
+                step = call // ps if call % ps == 0 else len(claimed) + 1
+            keep = len(claimed) // step * step
+            if wp is not None:
+                # ... and only where the window pool still holds the pages
+                # the first new query can see (models/smallthinker.py).
+                w_digests = wp.pool.chain_digests(ids, sig)
+                keep, w_claimed = wp.match(w_digests, keep, step)
+            self._pool.release(claimed[keep:])
+            claimed = claimed[:keep]
             if claimed and len(claimed) * ps < self.cfg.prefix_cache_min:
-                self._pool.release(claimed)
-                claimed = []
+                give_back()
+                claimed, w_claimed = [], []
         if n_total - len(claimed) > self._pool.available():
-            self._pool.release(claimed)
+            give_back()
             return None
         row = claimed + self._pool.allocate(n_total - len(claimed))
+        if wp is not None:
+            wp.admit(slot_idx, w_digests, len(claimed), w_claimed, n_total)
         if self.cfg.prefix_cache_min:
             # Register the cold prompt pages NOW so a same-round request
             # with the same prefix shares them (its prefill dispatches
@@ -2235,7 +2371,7 @@ class Engine:
             self._slot_fresh[slot_idx] = self._pool.register_chain(ids, sig, row)
         self._slot_budget[slot_idx] = budget
         self._slot_pages[slot_idx] = row
-        self._page_table[slot_idx, :] = 0
+        self._page_table[slot_idx, : self._max_pages] = 0
         self._page_table[slot_idx, : len(row)] = row
         reuse = len(claimed) * ps
         if self.cfg.prefix_cache_min:
@@ -2252,7 +2388,8 @@ class Engine:
         row = self._slot_pages[slot_idx]
         if not row:
             return
-        if register and self.cfg.prefix_cache_min:
+        register = register and bool(self.cfg.prefix_cache_min)
+        if register:
             # Content-register every full page this slot wrote (prompt
             # AND generated tokens): a follow-up turn extending this
             # conversation hits them from any slot.
@@ -2261,6 +2398,12 @@ class Engine:
             )
         self._pool.release(row)
         self._slot_pages[slot_idx] = []
+        if self._wpages is not None:
+            self._wpages.free(
+                slot_idx,
+                self._pool.chain_digests(self._kv_history[slot_idx], self._kv_lora_sig[slot_idx])
+                if register else None,
+            )
         self._page_table[slot_idx, :] = 0
 
     def _record_slot_cost(self, slot: "_Slot", slot_idx: int) -> None:
@@ -2336,6 +2479,12 @@ class Engine:
             chunk = ids[start : start + max_bucket]
             is_last = start + max_bucket >= len(ids)
             bucket = max_bucket if not is_last else self._bucket(len(chunk))
+            if self._wpages is not None:
+                # The window table moves with the chunk: pages behind its
+                # first query's window go back, the chunk's own come.
+                self._wpages.advance(slot_idx, start, start + bucket)
+                table = self._page_table[slot_idx : slot_idx + 1].copy()
+                self._count_attn_pairs("prefill", np.asarray([start]), len(chunk))
             chunk_padded = np.zeros((1, bucket), np.int32)
             chunk_padded[0, : len(chunk)] = chunk
             with self._lockstep(
@@ -2405,6 +2554,9 @@ class Engine:
         # exactly prompt+budget, so it must not be recomputed here.
         budget = self._slot_budget[slot_idx]
         self._slot_fresh[slot_idx] = []  # prefill succeeded; content valid
+        if self._wpages is not None:
+            self._wpages.settle(slot_idx)
+        self._w_pos[slot_idx] = len(ids)
         slot = _Slot(
             req=req,
             detok=IncrementalDetokenizer(self.tokenizer),
@@ -2482,7 +2634,7 @@ class Engine:
         n = len(items)
         tokens = np.zeros((n, bucket), np.int32)
         lengths = np.zeros((n,), np.int32)
-        tables = np.zeros((n, self._max_pages), np.int32)
+        tables = np.zeros((n, self._page_table.shape[1]), np.int32)
         slots_arr = np.zeros((n,), np.int32)
         seeds = np.zeros((n,), np.uint32)
         temps = np.ones((n,), np.float32)
@@ -2496,6 +2648,9 @@ class Engine:
             sp = req.params
             tokens[j, : len(ids)] = ids
             lengths[j] = len(ids)
+            if self._wpages is not None:
+                self._wpages.advance(slot_idx, 0, bucket)
+                self._count_attn_pairs("prefill", np.zeros((1,), np.int64), len(ids))
             tables[j] = self._page_table[slot_idx]
             slots_arr[j] = slot_idx
             seeds[j] = self._seed32(sp, j)
@@ -2561,6 +2716,16 @@ class Engine:
         return payload, snapshot, seg
 
     def _dispatch_chunk_call(self):
+        if self._wpages is not None:
+            K = self.cfg.decode_chunk
+            live = np.flatnonzero(self._h_active)
+            for i in live:
+                self._wpages.advance(int(i), int(self._w_pos[i]), int(self._w_pos[i]) + K)
+            self._count_attn_pairs("decode", self._w_pos[live], K)
+            self._w_pos[live] += K
+            released = self._wpages.released
+            self.m_window_released.inc(released - self._window_released_seen)
+            self._window_released_seen = released
         lora_args = {}
         if self._adapters is not None:
             lora_args = {"lora": self._adapters.bank, "lora_rows": self._h_lora_rows.copy()}
@@ -2845,6 +3010,7 @@ class Engine:
             kvstate.restore_enabled()
             and self._publisher is None
             and not self._multiproc
+            and family(self.model_config).KV_PARK
         )
 
     def _ensure_kv_jits(self) -> None:
@@ -3258,9 +3424,11 @@ def build_step_functions(
         """A family's model call may return, beside the pool (`kv`),
         scalar program counters in the cache dict (models/deepseek.py:
         `moe_hits`). They leave the program as its LAST output and never
-        enter one: ({"kv": pool}, {name: scalar}). A family without any
-        gives {}: no output at all."""
-        return {"kv": cache["kv"]}, {k: v for k, v in cache.items() if k != "kv"}
+        enter one: ({"kv": pool}, {name: scalar}); a second pool
+        (models/smallthinker.py: `kv_window`) stays with the first. A
+        family without counters gives {}: no output at all."""
+        pools = {k: v for k, v in cache.items() if k.startswith("kv")}
+        return pools, {k: v for k, v in cache.items() if k not in pools}
 
     def mask_pad(logits):
         if n_valid < mc.vocab_size:

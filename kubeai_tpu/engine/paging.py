@@ -201,8 +201,14 @@ class PagePool:
         DIFFERENT page keeps the first (the duplicate page stays
         private). Returns the NEWLY registered pages, so a caller whose
         content-write subsequently fails can unregister exactly those."""
+        return self.register_pages(self.chain_digests(token_ids, sig), pages)
+
+    def register_pages(self, digests: list[bytes], pages: list[int]) -> list[int]:
+        """register_chain's rule for pages whose digests the caller
+        holds (a window table holds only part of a chain). Returns the
+        newly registered pages."""
         fresh: list[int] = []
-        for digest, page in zip(self.chain_digests(token_ids, sig), pages):
+        for digest, page in zip(digests, pages):
             if page in self._digest_of:
                 continue
             if digest in self._by_digest:
@@ -211,6 +217,16 @@ class PagePool:
             self._digest_of[page] = digest
             fresh.append(page)
         return fresh
+
+    def claim_pages(self, digests: list[bytes]) -> list[int] | None:
+        """Claim (ref++) the resident pages of ALL of *digests*, in
+        order; None, and nothing claimed, where one is not resident."""
+        pages = [self._by_digest.get(d) for d in digests]
+        if any(p is None for p in pages):
+            return None
+        for page in pages:
+            self._claim(page)
+        return pages
 
     def unregister_pages(self, pages: list[int]) -> None:
         """Drop content registration (the pages keep their refcounts)."""
@@ -274,3 +290,114 @@ class PagePool:
                     self._cached.move_to_end(page)
                 else:
                     self._free.append(page)
+
+
+class WindowPages:
+    """The page budget of a family's WINDOW layers (models/smallthinker.py):
+    a pool of its own, and for every slot the one contiguous run of
+    logical pages that some query of the slot's next call can still see.
+    *table* is the engine's view of the slots' window tables ([slots,
+    max_pages], one entry a logical page, 0 = no page: a write there goes
+    to the trash page); this class is the only writer of it.
+
+    Before every call the engine says where the slot's queries will sit
+    (`advance`): pages wholly behind `start - window` are handed back
+    (content-registered ones stay findable until the pool needs them),
+    pages up to the call's last position are allocated. A slot therefore
+    holds at most `cap = (window + chunk) / page + 1` pages whatever its
+    length, and the pool is sized so that every slot can hold its cap at
+    once: a window page is always there when a slot needs it, so
+    admission never waits on this pool and nothing is reserved ahead."""
+
+    @staticmethod
+    def slot_cap(max_pages: int, window: int, chunk: int, page_size: int) -> int:
+        """The most window pages a slot holds: a chunk's queries and the
+        window behind the first of them, at the worst alignment."""
+        return min(max_pages, (window + chunk) // page_size + 1)
+
+    def __init__(self, table, window: int, chunk: int, page_size: int):
+        slots, max_pages = table.shape
+        self.table = table
+        self.window = window
+        self.page_size = page_size
+        self.cap = self.slot_cap(max_pages, window, chunk, page_size)
+        self.pool = PagePool(slots * self.cap + 1, page_size)
+        self.released = 0  # pages handed back behind a window, cumulative
+        self._lo = [0] * slots  # the slot holds logical pages [lo, hi)
+        self._hi = [0] * slots
+        self._limit = [0] * slots  # pages of prompt + budget: nothing is allocated past them
+        self._digests: list[list[bytes]] = [[] for _ in range(slots)]
+        # Pages `advance` registered for a prompt whose prefill has not
+        # succeeded yet (`settle`): a failed one takes exactly these back.
+        self._fresh: list[list[int]] = [[] for _ in range(slots)]
+
+    def first_page(self, start: int) -> int:
+        """The first logical page a query at position *start* can see."""
+        return max(start - self.window + 1, 0) // self.page_size
+
+    def held(self, slot: int) -> int:
+        return self._hi[slot] - self._lo[slot]
+
+    def match(self, digests: list[bytes], n: int, step: int) -> tuple[int, list[int]]:
+        """The longest prefix of at most *n* pages, a multiple of *step*,
+        whose visible window pages are all resident: (pages of prefix,
+        the claimed window pages), (0, []) where there is none."""
+        n = n // step * step
+        while n > 0:
+            pages = self.pool.claim_pages(digests[self.first_page(n * self.page_size) : n])
+            if pages is not None:
+                return n, pages
+            n -= step
+        return 0, []
+
+    def admit(self, slot: int, digests: list[bytes], reuse_pages: int, claimed: list[int], limit: int) -> None:
+        """A slot starts behind *reuse_pages* cached pages of which it
+        holds the *claimed* last ones; *digests* are its prompt's."""
+        self._hi[slot] = reuse_pages
+        self._lo[slot] = reuse_pages - len(claimed)
+        self._limit[slot] = min(limit, self.table.shape[1])
+        self._digests[slot] = digests
+        self.table[slot, :] = 0
+        self.table[slot, self._lo[slot] : reuse_pages] = claimed
+
+    def advance(self, slot: int, start: int, end: int) -> None:
+        """Ready the slot's table for a call whose queries sit at
+        positions [start, end)."""
+        lo, hi = self._lo[slot], self._hi[slot]
+        first = min(self.first_page(start), hi)
+        last = min(-(-end // self.page_size), self._limit[slot])
+        if first <= lo and last <= hi:
+            return  # most decode chunks: no page boundary crossed
+        row = self.table[slot]
+        if first > lo:
+            self.pool.release(row[lo:first].tolist())
+            row[lo:first] = 0
+            self.released += first - lo
+            self._lo[slot] = first
+        if last > hi:
+            pages = self.pool.allocate(last - hi)
+            row[hi:last] = pages
+            # A prompt's pages are findable from now on, as the full
+            # pool's are from admission: whoever claims one is dispatched
+            # behind the call that writes it.
+            self._fresh[slot] += self.pool.register_pages(self._digests[slot][hi:last], pages)
+            self._hi[slot] = last
+
+    def settle(self, slot: int) -> None:
+        """The slot's prefill succeeded: what it registered is content."""
+        self._fresh[slot] = []
+
+    def free(self, slot: int, digests: list[bytes] | None = None) -> None:
+        """The slot ends: every page goes back. *digests* (the chain of
+        everything the slot wrote) registers the whole pages it still
+        holds; pages of a prefill that never succeeded are unregistered."""
+        lo, hi = self._lo[slot], self._hi[slot]
+        pages = self.table[slot, lo:hi].tolist()
+        self.pool.unregister_pages(self._fresh[slot])
+        self._fresh[slot] = []
+        if digests is not None:
+            self.pool.register_pages(digests[lo:hi], pages)
+        self.pool.release(pages)
+        self.table[slot, :] = 0
+        self._lo[slot] = self._hi[slot] = self._limit[slot] = 0
+        self._digests[slot] = []

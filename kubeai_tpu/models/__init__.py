@@ -1,8 +1,10 @@
 """Model families. The engine reaches a model through ONE lookup,
 `family(config)`: the module that runs the configuration's `model_type`.
 A family module gives `init_params`, `params_from_hf`, `init_paged_cache`,
-`cached_attention_route`, `prefill_paged_cold`, `prefill_paged` and
-`decode_step_paged`; nothing but the published `model_type` chooses it."""
+`cached_attention_route`, `prefill_paged_cold`, `prefill_paged`,
+`decode_step_paged`, `refuse_unsupported`, `window_pool_tokens` and the
+rules `REUSE_WHOLE_PREFILL_CALLS` and `KV_PARK`; nothing but the
+published `model_type` chooses it."""
 
 from kubeai_tpu.models.base import ModelConfig
 
@@ -11,12 +13,17 @@ __all__ = ["ModelConfig", "family"]
 
 def family(config: ModelConfig):
     """The model module of *config*'s family: `models/deepseek.py` for
-    `deepseek_v3`, `models/llama.py` for every dense or Mixtral-style
-    decoder it has always run (Llama, Mistral, Qwen2, Gemma, Mixtral)."""
+    `deepseek_v3`, `models/smallthinker.py` for `smallthinker`,
+    `models/llama.py` for every dense or Mixtral-style decoder it has
+    always run (Llama, Mistral, Qwen2, Gemma, Mixtral)."""
     if config.model_type == "deepseek_v3":
         from kubeai_tpu.models import deepseek
 
         return deepseek
+    if config.model_type == "smallthinker":
+        from kubeai_tpu.models import smallthinker
+
+        return smallthinker
     from kubeai_tpu.models import llama
 
     return llama

@@ -92,6 +92,16 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False
+    # SmallThinker family (model_type "smallthinker", models/smallthinker.py;
+    # read for that family only). One entry a layer: 1 where the layer
+    # attends to the last `sliding_window_size` keys / rotates q and k,
+    # 0 where it attends to the whole context / applies no rope. Experts
+    # reuse n_routed_experts, moe_intermediate_size, num_experts_per_tok
+    # and norm_topk_prob above. (`sliding_window` stays 0: that key is
+    # Gemma2's, one window for a whole llama.py stack.)
+    sliding_window_size: int = 0
+    sliding_window_layout: tuple[int, ...] = ()
+    rope_layout: tuple[int, ...] = ()
 
     @property
     def head_dim_(self) -> int:
@@ -157,8 +167,9 @@ class ModelConfig:
                 )
         if model_type == "deepseek_v3":
             gemma_kw = _deepseek_v3_keys(get)
-        return cls(
-            **gemma_kw,
+        if model_type == "smallthinker":
+            gemma_kw = _smallthinker_keys(get)
+        kw = dict(
             model_type=model_type,
             vocab_size=config.vocab_size,
             hidden_size=config.hidden_size,
@@ -175,6 +186,8 @@ class ModelConfig:
             num_experts=get("num_local_experts", 0) or 0,
             num_experts_per_tok=get("num_experts_per_tok", 2) or 2,
         )
+        kw.update(gemma_kw)  # a family's own keys win (smallthinker names its experts per token otherwise)
+        return cls(**kw)
 
     @classmethod
     def from_json_file(cls, path: str) -> "ModelConfig":
@@ -218,4 +231,55 @@ def _deepseek_v3_keys(get) -> dict:
         qk_rope_head_dim=get("qk_rope_head_dim"),
         v_head_dim=get("v_head_dim"),
         rope_interleave=bool(get("rope_interleave", False)),
+    )
+
+
+def _smallthinker_keys(get) -> dict:
+    """The SmallThinker keys of a published config.json as ModelConfig
+    fields. What models/smallthinker.py does not compute is refused
+    here, by name. The layouts may be longer than the depth (a
+    checkpoint cut in depth keeps the published lists): the first
+    `num_hidden_layers` entries are the model's."""
+    L = get("num_hidden_layers")
+    if not get("moe_primary_router_apply_softmax", False):
+        raise ValueError("smallthinker: moe_primary_router_apply_softmax false (a sigmoid router) is not supported")
+    if get("moe_enable_secondary_experts") or get("moe_num_secondary_experts"):
+        raise ValueError("smallthinker: secondary experts are not supported")
+    if get("rope_scaling"):
+        raise ValueError("smallthinker: rope_scaling is not supported")
+    if not get("norm_topk_prob", True):
+        raise ValueError("smallthinker: norm_topk_prob false is not supported")
+    layouts = {}
+    for key in ("sliding_window_layout", "rope_layout"):
+        layout = get(key)
+        if not isinstance(layout, (list, tuple)) or len(layout) < L or any(v not in (0, 1) for v in layout):
+            raise ValueError(f"smallthinker: {key} must give 0 or 1 for each of the {L} layers")
+        layouts[key] = tuple(int(v) for v in layout)
+    period = layout_period(*layouts.values())
+    if L % period:
+        raise ValueError(
+            f"smallthinker: {L} layers are not whole periods of the layouts' pattern of {period} layers"
+        )
+    layouts = {key: layout[:L] for key, layout in layouts.items()}
+    window = get("sliding_window_size") or 0
+    if any(layouts["sliding_window_layout"]) and window <= 0:
+        raise ValueError("smallthinker: sliding_window_layout names window layers and sliding_window_size gives no window")
+    return dict(
+        intermediate_size=0,  # no dense feed-forward anywhere in the stack
+        n_routed_experts=get("moe_num_primary_experts") or 0,
+        num_experts_per_tok=get("moe_num_active_primary_experts") or 0,
+        moe_intermediate_size=get("moe_ffn_hidden_size") or 0,
+        norm_topk_prob=True,
+        sliding_window_size=int(window),
+        **layouts,
+    )
+
+
+def layout_period(*layouts: tuple[int, ...]) -> int:
+    """The smallest p with layout[i] == layout[i % p] for every layout,
+    over the entries all of them have (that length where nothing shorter
+    repeats)."""
+    n = min(len(layout) for layout in layouts)
+    return next(
+        (p for p in range(1, n) if all(layout[i] == layout[i % p] for layout in layouts for i in range(n))), max(n, 1)
     )

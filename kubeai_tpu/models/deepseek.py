@@ -453,3 +453,14 @@ def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=No
     Returns (logits [B, 1, V], pool)."""
     _refuse_lora(lora)
     return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, **debug)
+
+
+# Appended, so that no line above moves (a Pallas program's cache key holds
+# its call site's line): the seam's two newest names.
+KV_PARK = True  # a slot's pages can be parked, restored and handed off (engine/kvstate.py)
+
+
+def window_pool_tokens(config: ModelConfig) -> int:
+    """No layer of this family keeps a page pool of its own
+    (models/smallthinker.py has the family whose window layers do)."""
+    return 0

@@ -793,3 +793,14 @@ def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=No
         params, config, tokens, lengths[:, None].astype(jnp.int32), pool,
         lora=lora, lora_rows=lora_rows, page_table=page_table, tp_mesh=tp_mesh,
     )
+
+
+# Appended, so that no line above moves (a Pallas program's cache key holds
+# its call site's line): the seam's two newest names.
+KV_PARK = True  # a slot's pages can be parked, restored and handed off (engine/kvstate.py)
+
+
+def window_pool_tokens(config: ModelConfig) -> int:
+    """No layer of this family keeps a page pool of its own
+    (models/smallthinker.py has the family whose window layers do)."""
+    return 0

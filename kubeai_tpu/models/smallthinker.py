@@ -1,0 +1,460 @@
+"""SmallThinker decoder (`model_type: smallthinker`) in functional JAX: a
+stack whose layers come in PERIODS (the published 21B-A3B: one layer that
+attends to the whole context without rope, then three that attend to the
+last `sliding_window_size` keys with rope), every layer with routed
+experts chosen from the layer's INPUT. With `x` a layer's input:
+
+    r = x W_r                                  # router logits, before any norm
+    a = rmsnorm(x; g1);  q, k, v = a Wq, a Wk, a Wv        # no biases
+    q, k = rope(q), rope(k)      where rope_layout[l] == 1 # half-split pairs
+    o = softmax(q k^T / sqrt(h) + causal [+ key j > i - window
+        where sliding_window_layout[l] == 1]) v;  h = x + o Wo
+    m = rmsnorm(h; g2);  S = top-k of r;  w = softmax(r[S])
+    y = sum_{e in S} w_e (relu(m Wg_e) * (m Wu_e)) Wd_e;   x' = h + y
+
+The engine reaches a model through `kubeai_tpu.models.family(config)`;
+this module gives it the entry points it uses of `models/llama.py`. What
+the family does not run is refused at load (`refuse_unsupported`, and
+`models/base.py::_smallthinker_keys` for what the config itself asks).
+
+**The scan runs over periods.** Inside its body the period's layers are
+unrolled, so each kind of layer is a call of its own: the window is a
+static argument where a kernel wants it static, and a layer without rope
+traces no rotation. Experts are read from the whole stack in place, as
+`models/deepseek.py` reads them (`ops/moe.py::routed_experts`, `layer=`).
+
+**Two pools, two tables.** Full layers and window layers keep their keys
+and values in pools of their own: `cache["kv"]` is `[Lf*Pf, page, 2*Kv,
+h]` and `cache["kv_window"]` `[Lw*Pw, page, 2*Kv, h]`, each laid out as
+`llama.init_paged_cache` lays its one (K even, V odd; a layer owns `P`
+rows; logical page 0 of every layer is its trash page). A slot's block-
+table row is two tables side by side, `[full | window]`, each
+`max_pages` wide and each indexed by the position's page: the host
+(`engine/paging.py::WindowPages`) keeps in the window table only the
+pages some query of the next call can still see and hands the others
+back, so a slot holds at most `(window + chunk) / page + 1` window pages
+whatever its length, while its full pages grow with it.
+
+**A window layer reads its window.** The kernel's `sliding_window` only
+masks: it would still copy every page up to the sequence's end. So the
+window layers hand it the table FROM the first page a query of this call
+can see (`first = max(0, pos0 - window + 1) // page`, a gather of
+`(S + window - 2) // page + 2` columns) with the lengths shifted by
+`first * page`: every mask of the kernel is relative (a query sits at
+`kv_len - S + i`), keys carry their rope from when they were written, so
+nothing else moves. The portable route gathers the same columns.
+
+**Routes** (`cached_attention_route`): cold prefill of 256 rows or more
+takes the flash kernel for both kinds where the call is no longer than
+the window (the window mask is then all true; the engine's largest
+bucket, 1024, is a quarter of the published window); decode, chunks
+behind cached tokens and every other cold call take the ragged paged
+kernel on the chip ("paged_kernel"); everything on the CPU the portable
+gather ("xla") unless a test asks for the kernel's twin.
+
+**What is limited for this family, stated here once.**
+`REUSE_WHOLE_PREFILL_CALLS`: a prefix found in the cache is used in
+whole prefill calls, as `models/deepseek.py` says and for its reason (a
+router turns one rounding between two call shapes into other experts).
+A hit also needs the window pool to hold the pages the first new query
+can see (`WindowPages.match`): window pages a slot has handed back stay
+content-registered until the pool needs them, so a recent prompt's
+prefix is found and an old one's is recomputed. `KV_PARK = False`: a
+slot's state is not parked, restored or handed off (the wire format
+carries one pool's pages; a window slot has two).
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from kubeai_tpu.models.base import ModelConfig, layout_period
+from kubeai_tpu.ops import moe
+from kubeai_tpu.ops.attention import attention
+from kubeai_tpu.ops.norms import rms_norm
+from kubeai_tpu.ops.paged_attention import paged_attention_ragged
+from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
+
+Params = dict[str, Any]
+
+PAGED_KERNEL_LABEL = "ragged"
+REUSE_WHOLE_PREFILL_CALLS = True  # the module docstring says why
+KV_PARK = False  # likewise
+
+
+def period(config: ModelConfig) -> int:
+    """Layers in one period of the layouts' pattern (4 as published)."""
+    return layout_period(config.sliding_window_layout, config.rope_layout)
+
+
+def layer_kinds(config: ModelConfig) -> tuple[int, int]:
+    """(full layers, window layers) of the whole stack."""
+    n_window = sum(config.sliding_window_layout)
+    return config.num_layers - n_window, n_window
+
+
+def window_pool_tokens(config: ModelConfig) -> int:
+    """The window whose layers keep a page pool of their own (0: none)."""
+    return config.sliding_window_size
+
+
+def window_pages(config: ModelConfig, S: int, page: int, max_pages: int) -> int:
+    """Table columns a window layer reads for a call of *S* contiguous
+    queries a row: from the first page the first query can see to the
+    page of the last, at the worst alignment."""
+    return min(max_pages, (S + config.sliding_window_size - 2) // page + 2)
+
+
+def kv_pool_dtype(config: ModelConfig):
+    return jnp.dtype(config.dtype)
+
+
+def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1) -> None:
+    """What this family does not run, refused at load by name."""
+    if quantization:
+        raise ValueError("smallthinker: --quantization is not supported (no int8 for stacked expert weights)")
+    if tp > 1:
+        raise ValueError("smallthinker: --tensor-parallel-size > 1 is not supported (experts and both pools are unsharded)")
+    if config.kv_cache_dtype not in ("", "auto", config.dtype):
+        raise ValueError("smallthinker: a kv_cache_dtype other than the compute dtype is not supported")
+    if config.tie_word_embeddings:
+        raise ValueError("smallthinker: tied embeddings are not supported (the checkpoint must hold lm_head.weight)")
+    full, window = layer_kinds(config)
+    if not full or not window:
+        raise ValueError("smallthinker: a stack without both full and window layers is not supported")
+
+
+def _refuse_lora(lora) -> None:
+    if lora is not None:
+        raise ValueError("smallthinker: LoRA adapters are not supported")
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+
+
+def _shapes(config: ModelConfig) -> tuple[dict, dict]:
+    """(what a layer holds outside its experts, its experts), ONE layer."""
+    D, H, Kv, h = config.hidden_size, config.num_heads, config.num_kv_heads, config.head_dim_
+    F, E = config.moe_intermediate_size, config.n_routed_experts
+    layer = {
+        "ln1": (D,), "ln2": (D,), "wr": (D, E),
+        "wq": (D, H * h), "wk": (D, Kv * h), "wv": (D, Kv * h), "wo": (H * h, D),
+    }
+    experts = {"we_g": (E, D, F), "we_u": (E, D, F), "we_d": (E, F, D)}
+    return layer, experts
+
+
+def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
+    """Random parameters in the tree the loader builds: `layers` and
+    `experts` each stack every layer on a leading axis."""
+    dtype = dtype or jnp.dtype(config.dtype)
+    layer, experts = _shapes(config)
+    L, D, V = config.num_layers, config.hidden_size, config.vocab_size
+    keys = iter(jax.random.split(key, 16))
+
+    def draw(name, shape):
+        if name in ("ln1", "ln2"):
+            return jnp.ones((L, *shape), dtype)
+        return (jax.random.normal(next(keys), (L, *shape), jnp.float32) * shape[-2] ** -0.5).astype(dtype)
+
+    return {
+        "embed": (jax.random.normal(next(keys), (V, D), jnp.float32) * 0.02).astype(dtype),
+        "final_norm": jnp.ones((D,), dtype),
+        "lm_head": (jax.random.normal(next(keys), (D, V), jnp.float32) * 0.02).astype(dtype),
+        "layers": {k: draw(k, s) for k, s in layer.items()},
+        "experts": {k: draw(k, s) for k, s in experts.items()},
+    }
+
+
+def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> tuple[dict, dict]:
+    """Layer *i* of an HF checkpoint (get(name) -> array): linears
+    transposed to [in, out]; the experts stacked [E, out, in] on the host
+    (one contiguous copy; the device transposes them, `_put_row`)."""
+    p = f"model.layers.{i}."
+    conv = lambda a: np.asarray(a, dtype)  # noqa: E731
+    lin = lambda name: conv(np.asarray(get(p + name + ".weight")).T)  # noqa: E731
+    stack = lambda which: conv(  # noqa: E731
+        np.stack([np.asarray(get(f"{p}block_sparse_moe.experts.{j}.{which}.weight")) for j in range(config.n_routed_experts)])
+    )
+    layer = {
+        "ln1": conv(get(p + "input_layernorm.weight")),
+        "ln2": conv(get(p + "post_attention_layernorm.weight")),
+        "wr": lin("block_sparse_moe.primary_router"),
+        "wq": lin("self_attn.q_proj"), "wk": lin("self_attn.k_proj"),
+        "wv": lin("self_attn.v_proj"), "wo": lin("self_attn.o_proj"),
+    }
+    return layer, {"we_g": stack("gate"), "we_u": stack("up"), "we_d": stack("down")}
+
+
+def _put_row(buf, a, i, transpose: bool):
+    return buf.at[i].set(jnp.swapaxes(a, -1, -2) if transpose else a)
+
+
+def stream_params_from_hf(source, config: ModelConfig, pad: int = 0) -> Params:
+    """The streamed load: while a layer is put on the device and written
+    into its row of the stacked arrays ON the device (the buffer donated),
+    a reader thread takes the next from the checkpoint and converts it (a
+    layer's experts are 0.75 GB in bf16: the host holds two layers, the
+    device never a stack twice). *source* serves tensors by HF name; *pad*
+    columns of zeros are added to the vocabulary."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    dtype = jnp.dtype(config.dtype)
+    L = config.num_layers
+    donate = (0,) if jax.default_backend() != "cpu" else ()  # the CPU backend cannot reuse a donated buffer
+    put_row = jax.jit(_put_row, static_argnums=(3,), donate_argnums=donate)
+    params: Params = {"layers": {}, "experts": {}}
+    with ThreadPoolExecutor(max_workers=1) as reader:
+        ahead = reader.submit(_layer_tensors, source.get, config, 0, dtype)
+        for i in range(L):
+            groups = ahead.result()
+            if i + 1 < L:
+                ahead = reader.submit(_layer_tensors, source.get, config, i + 1, dtype)
+            for group, tensors in zip(("layers", "experts"), groups):
+                experts = group == "experts"
+                for k, a in tensors.items():
+                    shape = (a.shape[0], a.shape[2], a.shape[1]) if experts else a.shape
+                    if k not in params[group]:
+                        params[group][k] = jnp.zeros((L, *shape), a.dtype)
+                    params[group][k] = put_row(params[group][k], a, i, experts)
+    embed = np.asarray(source.get("model.embed_tokens.weight"), dtype)
+    head = np.asarray(source.get("lm_head.weight"), dtype).T
+    if pad:
+        embed, head = np.pad(embed, ((0, pad), (0, 0))), np.pad(head, ((0, 0), (0, pad)))
+    params["embed"] = jax.device_put(embed)
+    params["final_norm"] = jax.device_put(np.asarray(source.get("model.norm.weight"), dtype))
+    params["lm_head"] = jax.device_put(head)
+    return params
+
+
+class _DictSource:
+    def __init__(self, state_dict):
+        self.get = state_dict.__getitem__
+
+
+def params_from_hf(state_dict: dict[str, np.ndarray], config: ModelConfig, dtype=None, to_device: bool = True) -> Params:
+    """An HF state dict (name -> array) as this module's tree."""
+    del to_device  # one path: the tree is assembled on the device
+    cfg = config if dtype is None else config.replace(dtype=str(jnp.dtype(dtype)))
+    return stream_params_from_hf(_DictSource(state_dict), cfg)
+
+
+# ---------------------------------------------------------------------------
+# Cache and routes
+
+
+def init_paged_cache(config: ModelConfig, num_pages: int, page_size: int, dtype=None, window_pages: int = 0) -> Params:
+    """The two pools (module docstring): *num_pages* logical pages a full
+    layer, *window_pages* a window layer."""
+    dtype = dtype or kv_pool_dtype(config)
+    full, window = layer_kinds(config)
+    page = (page_size, 2 * config.num_kv_heads, config.head_dim_)
+    return {
+        "kv": jnp.zeros((full * num_pages, *page), dtype),
+        "kv_window": jnp.zeros((window * max(window_pages, 1), *page), dtype),
+    }
+
+
+def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, paged: bool) -> str:
+    """The attention implementation a cached call of *S* queries a row
+    takes, for both kinds of layer: "flash" (cold prefill of whole
+    256-row tiles, no longer than the window: the window mask is all
+    true), "paged_kernel" (the ragged kernel over pages in place) or
+    "xla" (the portable gather of the same pages)."""
+    if config.use_flash_prefill and left_aligned and S >= 256 and S % 256 == 0 and S <= config.sliding_window_size:
+        return "flash"
+    if config.use_paged_kernel and paged:
+        return "paged_kernel"
+    return "xla"
+
+
+# ---------------------------------------------------------------------------
+# Forward
+
+
+def apply(
+    params: Params,
+    config: ModelConfig,
+    tokens: jnp.ndarray,  # [B, S] int32
+    positions: jnp.ndarray,  # [B, S] int32 absolute positions, contiguous along S
+    cache: Params | None = None,  # init_paged_cache
+    page_table: jnp.ndarray | None = None,  # [B, 2 * max_pages]: [full | window]
+    logits_idx: jnp.ndarray | None = None,
+    left_aligned: bool = False,  # caller guarantees positions == arange(S)
+    forced_choices: jnp.ndarray | None = None,  # [L, B*S, k]: route by these (debug)
+    return_choices: bool = False,  # also return the routers' choices (debug; no timed program asks)
+    **unsupported,  # what llama.apply takes and this family does not run (return_hidden, lora, ...)
+):
+    """Run the decoder over the two paged pools. Returns (logits, cache)
+    with `cache["moe_hits"]` the (layer, expert) pairs that got a row;
+    with *return_choices* also the choices [L, B*S, k]. A position whose
+    table entry is 0 (padding past a prompt, a page handed back, a
+    finished slot's overrun) writes to the layer's trash page."""
+    if cache is None or page_table is None or unsupported:
+        raise ValueError("smallthinker: a call without the paged pool (embeddings, scoring) is not supported")
+    B, S = tokens.shape
+    H, Kv, h, L = config.num_heads, config.num_kv_heads, config.head_dim_, config.num_layers
+    window, eps, top_k = config.sliding_window_size, config.rms_norm_eps, config.num_experts_per_tok
+    per = period(config)
+    kinds = config.sliding_window_layout[:per]
+    ropes = config.rope_layout[:per]
+    n_full, n_window = layer_kinds(config)
+    inv_freq = jnp.asarray(rope_frequencies(h, config.rope_theta, None))
+    route = cached_attention_route(config, S, left_aligned, True)
+
+    pools = {0: cache["kv"], 1: cache["kv_window"]}
+    page = pools[0].shape[1]
+    rows = {0: pools[0].shape[0] // n_full, 1: pools[1].shape[0] // n_window}  # logical pages a layer
+    max_pages = page_table.shape[1] // 2
+    tables = {0: page_table[:, :max_pages], 1: page_table[:, max_pages:]}
+    skv = max_pages * page
+    w_idx = jnp.clip(positions // page, 0, max_pages - 1)
+    w_offs = positions % page
+    w_pages = {
+        kind: jnp.where(positions < skv, jnp.take_along_axis(tables[kind], w_idx, axis=1), 0) for kind in (0, 1)
+    }
+    # What each kind of layer reads: a table, the keys' count and the
+    # position of the table's first key. Full: the slot's whole table.
+    # Window: the columns from the first page this call's first query
+    # can see (module docstring).
+    last = positions[:, -1]
+    first = jnp.maximum(positions[:, 0] - window + 1, 0) // page
+    Wp = window_pages(config, S, page, max_pages)
+    cols = jnp.clip(first[:, None] + jnp.arange(Wp, dtype=jnp.int32)[None, :], 0, max_pages - 1)
+    read = {
+        0: (tables[0], last + 1, jnp.zeros_like(first)),
+        1: (jnp.take_along_axis(tables[1], cols, axis=1), last + 1 - first * page, first * page),
+    }
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.dtype(config.dtype))
+
+    def attend(q, k, v, pool, row0, kind):
+        """The attention of one layer of *kind* over its pool (already
+        holding this call's keys and values)."""
+        if route == "flash":
+            from kubeai_tpu.ops.flash_attention import flash_attention_tpu
+
+            return flash_attention_tpu(q, k, v, causal=True)
+        table, kv_len, key0 = read[kind]
+        if route == "paged_kernel":
+            return paged_attention_ragged(
+                q, pool, table + row0, kv_len, sliding_window=window if kind else None,
+            )
+        gathered = pool[table + row0]  # [B, columns, page, 2Kv, h]
+        n_keys = table.shape[1] * page
+        k_att = gathered[..., 0::2, :].reshape(B, n_keys, Kv, h)
+        v_att = gathered[..., 1::2, :].reshape(B, n_keys, Kv, h)
+        key_pos = key0[:, None, None] + jnp.arange(n_keys, dtype=jnp.int32)[None, None, :]
+        mask = key_pos <= positions[:, :, None]
+        if kind:
+            mask = jnp.logical_and(mask, key_pos > positions[:, :, None] - window)
+        return attention(q, k_att, v_att, mask)
+
+    def layer(x, w, pool, row0, kind, rope, forced, l):
+        scope = "attn.window" if kind else "attn.full"
+        with jax.named_scope("moe"), jax.named_scope("moe.router"):
+            # The router reads the layer's INPUT, before any norm.
+            xt = x.reshape(B * S, -1)
+            logits_r = jnp.dot(xt.astype(jnp.float32), w["wr"].astype(jnp.float32), preferred_element_type=jnp.float32)
+            idx, weights = moe.route_softmax_topk(logits_r, top_k, forced=forced)
+        with jax.named_scope(scope):
+            a = rms_norm(x, w["ln1"], eps)
+            q = jnp.dot(a, w["wq"]).reshape(B, S, H, h)
+            k = jnp.dot(a, w["wk"]).reshape(B, S, Kv, h)
+            v = jnp.dot(a, w["wv"]).reshape(B, S, Kv, h)
+            if rope:
+                q, k = apply_rope(q, k, positions, inv_freq)
+            interleaved = jnp.stack([k, v], axis=3).reshape(B, S, 2 * Kv, h)
+            pool = pool.at[w_pages[kind] + row0, w_offs].set(interleaved.astype(pool.dtype))
+            with jax.named_scope("attn.kernel"):
+                o = attend(q, k, v, pool, row0, kind)
+            x = x + jnp.dot(o.reshape(B, S, H * h), w["wo"])
+        with jax.named_scope("moe"):
+            m = rms_norm(x, w["ln2"], eps).reshape(B * S, -1)
+            # The experts' stacks are not sliced: layer l's are groups of the whole (ops/moe.py).
+            y, hit = moe.routed_experts(
+                m, idx, weights, params["experts"]["we_g"], params["experts"]["we_u"], params["experts"]["we_d"],
+                layer=l, act=jax.nn.relu,
+            )
+            x = x + y.reshape(B, S, -1)
+        return x, pool, hit, idx
+
+    # Which of its kind each layer of a period is: its pool rows follow.
+    nth = [sum(1 for j2 in range(j) if kinds[j2] == kinds[j]) for j in range(per)]
+    per_kind = {kind: sum(1 for v_ in kinds if v_ == kind) for kind in (0, 1)}
+
+    def step(carry, xs):
+        x, pool_f, pool_w, hits = carry
+        n, forced = xs
+        held = {0: pool_f, 1: pool_w}
+        chosen = []
+        for j in range(per):  # unrolled: a period's layers differ in kind
+            kind, l = kinds[j], n * per + j
+            row0 = (n * per_kind[kind] + nth[j]) * rows[kind]
+            # Each layer's weights are read from the whole stack at its own
+            # index: a period's block sliced out first and then indexed is
+            # a copy of the block (73 MB of `wo` a period a step on the chip).
+            w = jax.tree.map(lambda a_: jax.lax.dynamic_index_in_dim(a_, l, keepdims=False), params["layers"])
+            x, held[kind], hit, idx = layer(
+                x, w, held[kind], row0, kind, ropes[j], None if forced is None else forced[j], l,
+            )
+            hits = hits + hit
+            chosen.append(idx)
+        return (x, held[0], held[1], hits), (jnp.stack(chosen) if return_choices else None)
+
+    n_periods = L // per
+    (x, pool_f, pool_w, hits), choices = jax.lax.scan(
+        step, (x, pools[0], pools[1], jnp.zeros((), jnp.int32)),
+        (
+            jnp.arange(n_periods, dtype=jnp.int32),
+            None if forced_choices is None else forced_choices.reshape(n_periods, per, *forced_choices.shape[1:]),
+        ),
+    )
+
+    x = rms_norm(x, params["final_norm"], eps)
+    with jax.named_scope("lm_head"):
+        if logits_idx is not None:
+            x = x[jnp.arange(B)[:, None], logits_idx[:, None]]
+        logits = jnp.dot(x, params["lm_head"]).astype(jnp.float32)
+    new_cache = {"kv": pool_f, "kv_window": pool_w, "moe_hits": hits}
+    if return_choices:
+        return logits, new_cache, choices.reshape(L, B * S, top_k)
+    return logits, new_cache
+
+
+def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None, tp_mesh=None, **debug):
+    """A chunk [B, S] at absolute offset *start* [B] behind whatever the
+    tables' pages already hold. Returns (logits [B, 1, V] at *last_idx*
+    within the chunk, pools)."""
+    _refuse_lora(lora)
+    S = tokens.shape[1]
+    start = jnp.reshape(start, (-1,)).astype(jnp.int32)
+    pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    return apply(
+        params, config, tokens, pos, pool, page_table,
+        logits_idx=jnp.reshape(last_idx, (-1,)).astype(jnp.int32), **debug,
+    )
+
+
+def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
+    """Whole-prompt prefill (positions arange(S)). Returns (logits
+    [B, 1, V] at lengths-1, pools)."""
+    _refuse_lora(lora)
+    B, S = tokens.shape
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
+    return apply(
+        params, config, tokens, pos, pool, page_table,
+        logits_idx=jnp.reshape(lengths, (-1,)).astype(jnp.int32) - 1, left_aligned=True, **debug,
+    )
+
+
+def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
+    """One decode step for [B, 1] tokens at positions *lengths* [B].
+    Returns (logits [B, 1, V], pools)."""
+    _refuse_lora(lora)
+    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, **debug)

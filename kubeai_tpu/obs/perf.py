@@ -126,6 +126,8 @@ def param_counts(mc) -> tuple[float, float]:
     D, F, L, V = mc.hidden_size, mc.intermediate_size, mc.num_layers, mc.vocab_size
     if getattr(mc, "model_type", "") == "deepseek_v3":
         return _deepseek_v3_param_counts(mc)
+    if getattr(mc, "model_type", "") == "smallthinker":
+        return _smallthinker_param_counts(mc)
     H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
     attn = D * H * h + 2 * D * Kv * h + H * h * D
     if getattr(mc, "qkv_bias", False):
@@ -168,6 +170,25 @@ def _deepseek_v3_param_counts(mc) -> tuple[float, float]:
     return float(total), float(active)
 
 
+def _smallthinker_param_counts(mc) -> tuple[float, float]:
+    """SmallThinker family (models/smallthinker.py): every layer holds
+    grouped-query attention, a router and `n_routed_experts` experts, of
+    which a token passes through `num_experts_per_tok`; no dense layer,
+    no shared expert. The published 21B-A3B at 12 layers: 4.78G in
+    layers + 0.78G outside held; 0.68G + 0.39G a token (56.5M a layer
+    with 6 experts, and the head). Held to perfbench/families/
+    smallthinker_counts.py by tests/test_smallthinker.py."""
+    D, L, V = mc.hidden_size, mc.num_layers, mc.vocab_size
+    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
+    attn = D * (H + 2 * Kv) * h + H * h * D + 2 * D
+    expert = 3 * D * mc.moe_intermediate_size
+    router = D * mc.n_routed_experts
+    total = 2 * V * D + D + L * (attn + router + mc.n_routed_experts * expert)
+    # Active leaves the embedding table out (a row is looked up).
+    active = V * D + D + L * (attn + router + mc.num_experts_per_tok * expert)
+    return float(total), float(active)
+
+
 @dataclass(frozen=True)
 class PerfModel:
     """Per-model roofline constants, computed once. ``flops_per_token``
@@ -179,6 +200,12 @@ class PerfModel:
     active_params: float  # params touched per token (FLOPs)
     flops_per_token: float
     weight_bytes: float
+    # FLOPs of one (query, key) pair inside the mask in one layer (score
+    # and weighted value over every query head): set for a family whose
+    # engine counts its masked pairs (kubeai_engine_attn_pairs_total), so
+    # that MFU includes the attention its masks leave; 0: the few % the
+    # docstring above speaks of are left out.
+    attn_flops_per_pair: float = 0.0
 
     @classmethod
     def from_model_config(cls, mc, quantization: str = "", weight_bytes: float | None = None) -> "PerfModel":
@@ -195,6 +222,9 @@ class PerfModel:
             active_params=active,
             flops_per_token=2.0 * active,
             weight_bytes=float(weight_bytes),
+            attn_flops_per_pair=(
+                4.0 * mc.num_heads * mc.head_dim_ if getattr(mc, "model_type", "") == "smallthinker" else 0.0
+            ),
         )
 
     def step_floor_seconds(self, hbm_gbps: float) -> float:
@@ -209,11 +239,13 @@ class PerfModel:
             return None
         return batch / self.step_floor_seconds(hbm_gbps)
 
-    def mfu(self, tokens_per_sec: float, peak_flops: float | None) -> float:
-        """Model FLOPs utilization (fraction of peak) at a decode rate."""
+    def mfu(self, tokens_per_sec: float, peak_flops: float | None, pairs_per_sec: float = 0.0) -> float:
+        """Model FLOPs utilization (fraction of peak) at a decode rate,
+        with the masked attention of *pairs_per_sec* (query, key) pairs
+        summed over layers where the engine counts them."""
         if not peak_flops:
             return 0.0
-        return tokens_per_sec * self.flops_per_token / peak_flops
+        return (tokens_per_sec * self.flops_per_token + pairs_per_sec * self.attn_flops_per_pair) / peak_flops
 
 
 # ---------------------------------------------------------------------------

@@ -87,8 +87,24 @@ def route_sigmoid(x, wr, bias, k: int, norm_topk: bool, scale: float, forced=Non
     return idx.astype(jnp.int32), w * scale
 
 
-def routed_experts(x, idx, weights, wg, wu, wd, layer=None):
-    """sum_i weights[t, i] * SwiGLU_{idx[t, i]}(x[t]) for every token:
+def route_softmax_topk(r, k: int, forced=None):
+    """A router whose logits *r* [T, E] are given (SmallThinker computes
+    them from the layer's input, before attention): the top k logits are
+    chosen and the weights are the softmax over the CHOSEN logits, in
+    float32, which is the softmax over all E, taken at the chosen and
+    renormalised (`norm_topk_prob`). Returns (idx [T, k] int32, weights
+    [T, k] float32). *forced* as in `route_sigmoid`."""
+    r = r.astype(jnp.float32)
+    if forced is None:
+        _, idx = jax.lax.top_k(r, k)
+    else:
+        idx = forced
+    return idx.astype(jnp.int32), jax.nn.softmax(jnp.take_along_axis(r, idx, axis=1), axis=1)
+
+
+def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu):
+    """sum_i weights[t, i] * GLU_{idx[t, i]}(x[t]) for every token, the
+    gate's activation *act* (SiLU: SwiGLU; ReLU: SmallThinker's ReGLU):
     x [T, D]; idx, weights [T, k]; wg, wu [E, D, F]; wd [E, F, D].
     Returns (y [T, D] in x's dtype, hit: how many experts got a row).
 
@@ -118,9 +134,9 @@ def routed_experts(x, idx, weights, wg, wu, wd, layer=None):
     with jax.named_scope("moe.experts"):
         gate = grouped_matmul(rows, wg, group_sizes)
         up = grouped_matmul(rows, wu, group_sizes)
-    act = jax.nn.silu(gate) * up
+    hidden = act(gate) * up
     with jax.named_scope("moe.experts"):
-        out = grouped_matmul(act, wd, group_sizes)
+        out = grouped_matmul(hidden, wd, group_sizes)
     with jax.named_scope("moe.combine"):
         # Back to (token, choice) order by a gather (row j of the sorted
         # rows is assignment order[j]), then the weighted sum over a

@@ -62,6 +62,7 @@ def paged_attention_ragged(
     k_scale: float | None = None,  # static dequant scales for quantized
     v_scale: float | None = None,  # (int8/fp8) pools; None = pool is bf16
     blocks: tuple[int, int] | None = None,  # a sweep's (kv pages, queries); serving leaves it None
+    sliding_window: int | None = None,  # static: a query sees keys j > i - sliding_window only
 ) -> jnp.ndarray:
     """Returns [B, S, H, h] attention output. With a quantized pool the
     kernel dequantizes pages in-VMEM (x.astype(f32) * scale -> q.dtype),
@@ -80,7 +81,11 @@ def paged_attention_ragged(
     kv_lens = jnp.minimum(kv_lengths, max_pages * page).astype(jnp.int32)
     num_seqs = jnp.asarray([B], jnp.int32)
 
-    tuning = {}
+    # The kernel's window only masks: it copies every page from the
+    # table's first to the sequence's end, so a caller with a window
+    # hands in the table from the first page inside it, lengths shifted
+    # (models/smallthinker.py).
+    tuning = {"sliding_window": sliding_window} if sliding_window else {}
     if jax.default_backend() == "cpu":
         fn = _cpu_twin
     else:
@@ -101,6 +106,7 @@ def paged_attention_ragged(
             chosen_blocks[
                 f"B={B} S={S} H={H} Kv={Kv} "
                 f"pages={max_pages}x{page} {kv_pages.dtype.name}"
+                + (f" window={sliding_window}" if sliding_window else "")
             ] = blocks
         tuning["num_kv_pages_per_block"], tuning["num_queries_per_block"] = blocks
     # One argument construction for BOTH arms (the twin is signature-
@@ -118,7 +124,7 @@ def paged_attention_ragged(
     return out.reshape(B, S, H, h).astype(q.dtype)
 
 
-def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, sm_scale, soft_cap=None, k_scale=None, v_scale=None):
+def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, sm_scale, soft_cap=None, k_scale=None, v_scale=None, sliding_window=None):
     """Jit-safe semantics twin of ragged_paged_attention, with the SAME
     signature (the library's pure-JAX reference uses Python loops over
     traced bounds, so it only runs eagerly; tests compare this twin
@@ -146,6 +152,8 @@ def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, s
         v_att = (v_att.astype(jnp.float32) * v_scale).astype(q.dtype)
     pos_q = kv_lens[:, None] - S + jnp.arange(S, dtype=jnp.int32)[None, :]
     mask = jnp.arange(skv)[None, None, :] <= pos_q[:, :, None]
+    if sliding_window is not None:
+        mask = jnp.logical_and(mask, jnp.arange(skv)[None, None, :] > pos_q[:, :, None] - sliding_window)
     return attention(
         q, k_att, v_att, mask, scale=sm_scale, softcap=soft_cap or 0.0
     ).reshape(B * S, H, h)

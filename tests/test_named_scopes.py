@@ -99,3 +99,40 @@ def test_every_scope_names_operations_in_the_expert_family(scoped_moe_programs, 
     rx = re.compile(r'["/]' + re.escape(scope) + "/")
     for _, debug_text in scoped_moe_programs[:2]:  # the decode chunk, a prefill
         assert rx.search(debug_text), scope
+
+
+# -- the window family's module (models/smallthinker.py) -----------------------
+
+SWA_SCOPES = (
+    "embed", "attn.full", "attn.window", "attn.kernel", "moe", "moe.router", "moe.dispatch", "moe.experts",
+    "moe.combine", "lm_head", "sampling", "logprobs",
+)
+SMALLTHINKER = ModelConfig(
+    model_type="smallthinker", vocab_size=272, hidden_size=64, intermediate_size=0, num_layers=4, num_heads=4,
+    num_kv_heads=2, head_dim=16, dtype="float32", max_position=256, num_experts_per_tok=2, n_routed_experts=8,
+    moe_intermediate_size=32, sliding_window_size=32, sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+    use_paged_kernel=True, use_flash_prefill=True,
+)
+
+
+@pytest.fixture(scope="module")
+def scoped_swa_programs():
+    return _lowered_programs(True, SMALLTHINKER)
+
+
+def test_scopes_change_metadata_only_in_the_window_family(scoped_swa_programs):
+    plain = _lowered_programs(False, SMALLTHINKER)
+    assert len(scoped_swa_programs) == len(plain) >= 4
+    for i, ((s_text, s_debug), (p_text, p_debug)) in enumerate(zip(scoped_swa_programs, plain)):
+        assert s_text == p_text, f"program {i}: the computation changed with the scopes"
+        assert s_debug != p_debug, f"program {i}: the scopes left no trace in the metadata"
+
+
+@pytest.mark.parametrize("scope", SWA_SCOPES)
+def test_every_scope_names_operations_in_the_window_family(scoped_swa_programs, scope):
+    rx = re.compile(r'["/]' + re.escape(scope) + "/")
+    for _, debug_text in scoped_swa_programs[:2]:  # the decode chunk, a prefill
+        assert rx.search(debug_text), scope
+    # Each kind's kernel scope sits inside its layer's scope: a reader tells them apart by the path.
+    for kind in ("attn.full", "attn.window"):
+        assert re.search(r'["/]' + re.escape(kind) + "/attn.kernel/", scoped_swa_programs[0][1]), kind

@@ -96,7 +96,7 @@ def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
 
 def test_every_cell_is_one_of_those_with_a_case_here():
     cells = [w["name"] for w in resultline.load_benchmark()["workloads"]]
-    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL])
+    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL, SWA_CELL])
 
 
 def test_the_expert_models_cell_declares_its_own_metrics_and_none_of_the_dense_cells():
@@ -297,3 +297,139 @@ def test_rehearsal_of_the_expert_models_cell(tmp_path):
     logits = next(ln for ln in lines if ln.get("phase") == "logits")
     assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
     assert 0 < last["metrics"]["moe_experts_hit_pct"]["value"] <= 100
+
+
+# -- PR 36: the window family's cell and its readers ---------------------------
+
+SWA_CELL = "smallthinker-bf16-longdoc-sat"
+SWA_ROOFLINES = {
+    "moe_experts_roofline.swa", "full_attn_decode_roofline", "window_attn_decode_roofline", "prefill_attn_roofline.swa",
+}
+
+
+def test_the_window_cells_metrics_are_its_own():
+    bench = resultline.load_benchmark()
+    mine = resultline.declared(bench, SWA_CELL, True)
+    assert len(mine) == 23 and SWA_ROOFLINES <= set(mine)
+    assert {"kv_pages_peak_pct.full", "kv_pages_peak_pct.window", "window_mfu.swa", "decode_step_roofline.swa"} <= set(mine)
+    # No other cell carries them, and this cell none of theirs.
+    for m in bench["per_layer"]:
+        assert (SWA_CELL in m["workloads"]) == (m["workloads"] == [SWA_CELL]), m["name"]
+    assert set(resultline.declared(bench, SWA_CELL, False)) == {"output_tok_s", "setup_s"}
+    for name in mine:
+        assert os.path.exists(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")), name
+
+
+@pytest.mark.parametrize("missing", ["decode_step_ms.swa", "kv_pages_peak_pct.window", "window_attn_decode_roofline", "window_mfu.swa"])
+def test_a_traced_line_of_the_window_models_cell(missing):
+    bench = resultline.load_benchmark()
+    line = _traced_line(resultline.declared(bench, SWA_CELL, True))
+    assert resultline.problems(line, bench, SWA_CELL, True, 1) == []
+    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
+    assert resultline.problems(cut, bench, SWA_CELL, True, 1) == [f"metric {missing} of this workload and mode is missing"]
+    assert resultline.problems(cut, bench, SWA_CELL, True, 1, may_miss={missing}) == []
+    over = {**line, "metrics": {**line["metrics"], "window_attn_decode_roofline": {"value": 106.0, "unit": "%"}}}
+    assert any("over 105%" in p for p in resultline.problems(over, bench, SWA_CELL, True, 1))
+
+
+def _scrape(at, **series):
+    return types.SimpleNamespace(
+        at=at, has=lambda name: name in series,
+        value=lambda name, **labels: sum(
+            v for have, v in series.get(name, ()) if all(have.get(k) == w for k, w in labels.items())
+        ),
+    )
+
+
+def test_the_window_rooflines_from_counters_and_scopes():
+    """readers/swa_rooflines.py on a made-up window: 100 decode chunks of 8
+    steps, pairs that are 24 slots x (3 full layers x 8000 + 9 window
+    layers x 4096) keys a step, half the experts hit; scope seconds by the
+    difference of the two reductions (readers/swa_scopes.py)."""
+    from readers import swa_rooflines, swa_scopes
+
+    with open(os.path.join(ROOT, "perfbench", "configs", "smallthinker-21b-a3b-bf16.json")) as f:
+        cfg = json.load(f)
+    steps = 800
+    pairs = lambda kind, phase, n: ({"kind": kind, "phase": phase}, float(n))  # noqa: E731
+    after = {
+        "kubeai_engine_attn_pairs_total": [
+            pairs("full", "decode", steps * 24 * 3 * 8000), pairs("window", "decode", steps * 24 * 9 * 4096),
+            pairs("full", "prefill", 3 * 4e9), pairs("window", "prefill", 9 * 2e9),
+        ],
+        "kubeai_engine_moe_experts_hit_total": [({"phase": "decode"}, steps * 12 * 32.0)],
+        "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 12 * 64.0)],
+        "kubeai_engine_step_seconds_count": [({"phase": "decode_chunk"}, 100.0)],
+        "kubeai_engine_prefill_tokens_total": [({}, 1.0e6)], "kubeai_engine_generated_tokens_total": [({}, 19200.0)],
+    }
+    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
+    ctx = types.SimpleNamespace(
+        hf={k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")},
+        serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
+        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[],
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
+    )
+    by = lambda **s: {"total_s": 1.6, "by_scope_s": {k.replace("_", "."): v for k, v in s.items()}}  # noqa: E731
+    ctx.swa_scope_shares = {
+        "layers": {
+            "jit__unknown(7)": by(attn_full=0.20, attn_window=0.40, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.2, "attn.window": 0.4}},
+        },
+        "kernels": {
+            "jit__unknown(7)": by(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.05, "attn.window": 0.1, "attn.kernel": 0.45}},
+        },
+    }
+    assert swa_scopes.read(ctx, "^jit__unknown", "full") == pytest.approx(12.5)
+    assert swa_scopes.seconds(ctx, "^jit__unknown", "window", kernel=True)[0] == pytest.approx(0.24)
+    n_steps = 10 * 8
+    full_bytes, window_bytes = 24 * 3 * 8000 * 2048, 24 * 9 * 4096 * 2048
+    read = lambda what, **kw: swa_rooflines.read(ctx, what, **kw)  # noqa: E731
+    assert read("full_attn") == pytest.approx(100 * (full_bytes / 819e9) / (0.12 / n_steps))
+    assert read("window_attn") == pytest.approx(100 * (window_bytes / 819e9) / (0.24 / n_steps))
+    expert_bytes = 12 * 32 * 3 * 2560 * 768 * 2
+    assert read("experts") == pytest.approx(100 * (expert_bytes / 819e9) / (0.64 / n_steps))
+    outside = (12 * (20_976_640 + 2560 * 64) + 151936 * 2560 + 2560) * 2
+    assert read("decode_step") == pytest.approx(
+        100 * ((outside + expert_bytes + full_bytes + window_bytes) / 819e9) / (1.6 / n_steps)
+    )
+    # Prefill: the pairs between the polls around the traced seconds (here the window's edges).
+    flops = 4 * 3584 * (3 * 4e9 + 9 * 2e9)
+    assert read("prefill_attn", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.6 * 50.0 / 4.0))
+    active = 12 * (20_976_640 + 2560 * 64 + 6 * 3 * 2560 * 768) + 151936 * 2560 + 2560
+    all_pairs = steps * 24 * (3 * 8000 + 9 * 4096) + 3 * 4e9 + 9 * 2e9
+    assert read("window_mfu") == pytest.approx(100 * (2 * active * 1019200 + 4 * 3584 * all_pairs) / (197e12 * 50))
+    assert 0 < read("window_mfu") < 100
+    # A program of another family, or the parent's: no such counter, nothing read, nothing raised.
+    ctx.after = _scrape(50.0)
+    assert all(read(w) is None for w in ("window_mfu", "experts", "full_attn", "window_attn", "decode_step", "prefill_attn"))
+    ctx.trace, ctx.swa_scope_shares = None, None
+    assert swa_scopes.read(ctx, "^jit__unknown", "full") is None
+
+
+@pytest.mark.slow  # a minute and a half alone: not tier-1, as the expert model's rehearsal is not
+def test_rehearsal_of_the_window_models_cell(tmp_path):
+    """--rehearse --trace 1 of smallthinker-bf16-longdoc-sat at the
+    configuration's `rehearsal` keys (window 256, 8 layers): every phase,
+    the family's logits check through both pools, and every per-layer
+    metric the CPU can read."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", SWA_CELL, "--rehearse",
+         "--trace", "1", "--seed", str(2**31 + 11)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900,
+    )
+    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
+    last = lines[-1]
+    bench = resultline.load_benchmark()
+    # Time a step of one scope means nothing in a CPU trace (readers/swa_rooflines.py).
+    assert resultline.problems(last, bench, SWA_CELL, True, 1, rehearsal=True, may_miss=SWA_ROOFLINES) == []
+    assert last["correct"] is True
+    assert set(resultline.declared(bench, SWA_CELL, True)) - SWA_ROOFLINES <= set(last["metrics"])
+    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
+    assert logits["sample"]["window_pages_released"] > 0
+    assert 0 < last["metrics"]["kv_pages_peak_pct.window"]["value"] <= 100

@@ -275,3 +275,80 @@ def test_grouped_expert_matmul_lowers(v5e, rows, k, n):
         _sds(v5e, (rows, k), jnp.bfloat16), _sds(v5e, (7 * 128, k, n), jnp.bfloat16), _sds(v5e, (7 * 128,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+# -- SmallThinker-21BA3B: the kernels of its step at the published widths ------
+
+SMALLTHINKER = (28, 4)  # 28 query / 4 KV heads of 128 (G = 7, as Qwen2.5-7B)
+SWA_WINDOW, SWA_SLOTS, SWA_MAX_LEN = 4096, 24, 16384
+
+
+@pytest.mark.parametrize(
+    "B,S,columns,window",
+    [
+        (SWA_SLOTS, 1, SWA_MAX_LEN // PAGE, None),
+        (SWA_SLOTS, 1, (SWA_WINDOW - 1) // PAGE + 2, SWA_WINDOW),
+        (1, 1024, SWA_MAX_LEN // PAGE, None),
+        (1, 1024, (1024 + SWA_WINDOW - 2) // PAGE + 2, SWA_WINDOW),
+        (8, 32, (32 + SWA_WINDOW - 2) // PAGE + 2, SWA_WINDOW),
+    ],
+    ids=["decode/full-256-pages", "decode/window-65-pages", "chunk-1024/full", "chunk-1024/window-81-pages", "cold-8x32/window"],
+)
+def test_ragged_paged_kernel_lowers_with_a_window_over_a_shifted_table(v5e, B, S, columns, window):
+    """The cell smallthinker-bf16-longdoc-sat: 24 slots of 256 pages; a
+    full layer walks the slot's whole table, a window layer the columns
+    from its first visible page (models/smallthinker.py) with the
+    kernel's static `sliding_window`."""
+    q, pool, _, lens = _paged_args(v5e[0], B, S, SMALLTHINKER, max_len=SWA_MAX_LEN)
+    table = _sds(v5e, (B, columns), jnp.int32)
+    text = _compile(
+        lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, sliding_window=window), q, pool, table, lens,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("k,n", [(2560, 768), (768, 2560)], ids=["gate_up", "down"])
+@pytest.mark.parametrize("rows", [24 * 6, 6 * 1024, 8 * 1024 * 6, 6 * 32])
+def test_grouped_expert_matmul_lowers_at_smallthinkers_widths(v5e, rows, k, n):
+    """24 slots x 6 choices at decode (144 rows: one tile), a chunk's 6144,
+    a full group's 49152 and the smallest bucket's 192, over the 12 x 64
+    experts of the whole stack; an expert's 2560 x 768 matrix is one tile
+    (3.75 MiB in bf16)."""
+    from kubeai_tpu.ops.moe import gmm_tiles, grouped_matmul
+
+    assert gmm_tiles(rows, k, n)[1:] == (k, n)
+    text = _compile(
+        grouped_matmul,
+        _sds(v5e, (rows, k), jnp.bfloat16), _sds(v5e, (12 * 64, k, n), jnp.bfloat16), _sds(v5e, (12 * 64,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_one_period_of_smallthinker_decodes_through_both_pools(v5e):
+    """A decode step of one period (a full layer and three window layers)
+    at the published widths: the step holds both kinds' paged kernels
+    and the grouped matmuls, and both pools come back in place."""
+    from kubeai_tpu.engine.coldstart import param_shapes
+    from kubeai_tpu.models import smallthinker
+
+    mc = ModelConfig(
+        model_type="smallthinker", vocab_size=1024, hidden_size=2560, intermediate_size=0, num_layers=4,
+        num_heads=28, num_kv_heads=4, head_dim=128, dtype="bfloat16", num_experts_per_tok=6, n_routed_experts=64,
+        moe_intermediate_size=768, sliding_window_size=SWA_WINDOW, sliding_window_layout=(0, 1, 1, 1),
+        rope_layout=(0, 1, 1, 1), rope_theta=1.5e6, use_flash_prefill=True, use_paged_kernel=True,
+    )
+    B, max_pages = SWA_SLOTS, SWA_MAX_LEN // PAGE
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), param_shapes(mc))
+    pools = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(lambda: smallthinker.init_paged_cache(mc, 3841, PAGE, window_pages=B * 81 + 1)),
+    )
+    compiled = jax.jit(
+        lambda p, t, c, tbl, lens: smallthinker.decode_step_paged(p, mc, t, c, tbl, lens), donate_argnums=(2,),
+    ).lower(
+        params, _sds(v5e, (B, 1), jnp.int32), pools, _sds(v5e, (B, 2 * max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+    ).compile()
+    # One period in the scan's body: 4 paged kernels and 4 x 3 grouped matmuls.
+    assert compiled.as_text().count("tpu_custom_call") >= 16
+    pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
