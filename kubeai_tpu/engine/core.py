@@ -759,8 +759,8 @@ class Engine:
             # (kv pages, queries) a block the ragged paged kernel was
             # given, per call shape this process has traced.
             "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
-            # The expert family's kernels, likewise: latent pages a block
-            # of the MLA decode kernel, (tm, tk, tn) of the grouped matmul.
+            # The expert family's kernels, likewise: the MLA decode kernel's
+            # pages a block, ring and form; (tm, tk, tn) of the grouped matmul.
             "mla_kernel_blocks": dict(mla_attention.chosen_blocks),
             "grouped_matmul_tiles": dict(moe.chosen_tiles),
             # Bytes of the pool a token occupies, all layers, as stored:

@@ -22,9 +22,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# (pages a block) the decode kernel was given, per call shape this process
-# has traced (diagnosis: /debug/engine -> perf.mla_kernel_blocks).
-chosen_blocks: dict[str, int] = {}
+# What the decode kernel was given, per call shape this process has
+# traced (diagnosis: /debug/engine -> perf.mla_kernel_blocks): the pages
+# a block, the buffers of its ring, and that the copies run from slot to
+# slot through the whole call.
+chosen_blocks: dict[str, dict] = {}
 
 
 # Keys a turn of the portable form's loop (whole pages are taken).
@@ -82,82 +84,161 @@ def latent_attention_paged(q_lat, pool, page_table, positions, *, scale: float, 
 
 def kernel_pages_per_block(max_pages: int, page: int) -> int:
     """Latent pages a block of the decode kernel, from the call's own
-    shapes: 512 tokens (PR 30's sweep of the ragged kernel at one query
-    row a slot: a block of 512 is not copied far past a short sequence's
-    end and a turn's fixed cost is spread over enough keys), cut to the
-    table's width. A block is two buffers of pages x page x W in VMEM
-    (8 x 64 x 640 bf16: 0.66 MB each)."""
-    return max(1, min(512 // page, max_pages))
+    shapes: 1024 tokens, cut to the table's width. Read in this kernel's
+    own sweep (PR 37, PERF.md section 6: the kernel alone at 64 and 128
+    slots of 300 to 2,500 live tokens, 2 to 32 pages a block): a turn
+    has a fixed cost, so 2 and 4 pages run 1.3-2 times longer than 8;
+    16 pages are 7-8% under 8 from 1,230 tokens a slot up and 15% over
+    at 300; 32 gain nothing. A block is `KERNEL_BUFFERS` buffers of
+    pages x page x W in VMEM (16 x 64 x 640 bf16: 1.3 MB each)."""
+    return max(1, min(1024 // page, max_pages))
 
 
-def _decode_kernel(lens_ref, table_ref, q_ref, pool_ref, o_ref, buf, sem, *, page, ppb, max_pages, scale, rank):
-    """One slot a program: its query rows [H, W] against its latent pages,
-    copied HBM -> VMEM a block of `ppb` pages at a time into two buffers
-    (the next block's copy runs under this block's scores), only pages
-    that hold a live token; online softmax in float32."""
+# Buffers in the decode kernel's ring: each turn starts the copy two
+# blocks ahead of the one it scores. With two, the copy of the next
+# slot's first block starts only when this slot's last (short) block is
+# reached, and the copy engine idles while a whole block is scored
+# (PR 37's sweep: 156 -> 136 us a call at 64 slots x 1,230 tokens; a
+# fourth buffer adds nothing).
+KERNEL_BUFFERS = 3
+
+
+def _quarter(ppb: int) -> int:
+    """Pages in a quarter of a block: what its copies are issued by and
+    its last block is sized by."""
+    return -(-ppb // 4)
+
+
+def _last_block_sizes(ppb: int) -> list[int]:
+    """The page counts a slot's last block can be scored at: quarters of
+    a block, so that the keys scored past a slot's end are under a
+    quarter of a block and not under a whole one (a slot of 300 tokens
+    in a block of 1024)."""
+    quarter = _quarter(ppb)
+    return [min(n, ppb) for n in range(quarter, ppb + quarter, quarter)]
+
+
+def _decode_kernel(lens_ref, table_ref, q_ref, pool_ref, o_ref, buf, sem, ring_ref, *, page, ppb, max_pages, scale, rank):
+    """One slot a program, in the slots' order: its query rows [H, W]
+    against its latent pages, copied HBM -> VMEM a block of `ppb` pages
+    at a time, only pages that hold a live token; online softmax in
+    float32.
+
+    The copies are ONE pipeline over the whole call: its (slot, block)
+    pairs in order go round a ring of buffers, and every turn, before it
+    waits for its own block, starts the copy of the block a ring less
+    one ahead of it, which after a slot's last blocks is a block of the
+    NEXT slots (the buffers, the semaphores and `ring_ref`, where the
+    ring stands, outlive a program). Only the call's very first block is
+    copied with nothing to compute meanwhile.
+
+    Only a slot's last block can hold a dead key (a row past the length,
+    a page not copied): the blocks before it are scored whole and
+    unmasked, and the last one over its live pages rounded up to a
+    quarter of a block (`_last_block_sizes`), masked."""
     b = pl.program_id(0)
-    length = lens_ref[b]
-    n_pages = (length + page - 1) // page
-    n_blocks = (n_pages + ppb - 1) // ppb
+    B = pl.num_programs(0)
+    ring = buf.shape[0]
     q = q_ref[0]  # [H, W]
     H = q.shape[0]
+    length = lens_ref[b]
+    # ring_ref: the buffer of this program's block 0; the next block to
+    # be copied (slot, block) and its buffer.
+    MINE, NEXT_SLOT, NEXT_BLOCK, NEXT_BUFFER = range(4)
 
-    def copies(slot, blk):
-        return [
-            (
-                blk * ppb + i,
-                pltpu.make_async_copy(
-                    pool_ref.at[table_ref[b * max_pages + jnp.minimum(blk * ppb + i, max_pages - 1)]],
-                    buf.at[slot, i], sem.at[slot],
-                ),
-            )
-            for i in range(ppb)
-        ]
+    def blocks(b):
+        n_pages = (lens_ref[b] + page - 1) // page
+        return n_pages, jnp.maximum((n_pages + ppb - 1) // ppb, 1)
 
-    def start(slot, blk):
-        for p, c in copies(slot, blk):
-            @pl.when(p < n_pages)
-            def _():
-                c.start()
+    def each_live_page(b, blk, side, do):
+        """`do` on the copy of every live page of a block, a quarter of
+        the block a turn: as few turns as the live pages need, and a
+        quarter's copies in line (PR 37's sweep: a predicate for each of
+        a block's 16 pages costs 10% at 300 tokens a slot and a turn a
+        page 2% at 1,230; all 16 in line, at six sites, add 1.6 s to
+        lowering the decode step, which every start pays)."""
+        first = blk * ppb
+        live = jnp.clip(blocks(b)[0] - first, 0, ppb)
+        quarter = _quarter(ppb)
 
-    def wait(slot, blk):
-        for p, c in copies(slot, blk):
-            @pl.when(p < n_pages)
-            def _():
-                c.wait()
+        def turn(g, _):
+            for i in range(quarter):
+                @pl.when(g * quarter + i < live)
+                def _():
+                    n = g * quarter + i
+                    do(pltpu.make_async_copy(pool_ref.at[table_ref[b * max_pages + first + n]], buf.at[side, n], sem.at[side]))
 
-    start(0, 0)
+        jax.lax.fori_loop(0, (live + quarter - 1) // quarter, turn, None)
 
-    def body(blk, carry):
-        m, l, acc = carry
-        slot = blk % 2
+    def start_next():
+        nb, nblk, side = ring_ref[NEXT_SLOT], ring_ref[NEXT_BLOCK], ring_ref[NEXT_BUFFER]
 
-        @pl.when(blk + 1 < n_blocks)
+        @pl.when(nb < B)
         def _():
-            start(1 - slot, blk + 1)
+            each_live_page(nb, nblk, side, lambda copy: copy.start())
+            last = nblk + 1 == blocks(nb)[1]
+            ring_ref[NEXT_SLOT] = jnp.where(last, nb + 1, nb)
+            ring_ref[NEXT_BLOCK] = jnp.where(last, 0, nblk + 1)
+            ring_ref[NEXT_BUFFER] = (side + 1) % ring
 
-        wait(slot, blk)
-        k = buf[slot].reshape(ppb * page, -1)  # [T, W]
+    def wait(blk, side):
+        each_live_page(b, blk, side, lambda copy: copy.wait())
+
+    def score(carry, blk, side, *, pages, masked):
+        """The online softmax over the first `pages` pages of a buffer."""
+        m, l, acc = carry
+        k = buf[side, :pages].reshape(pages * page, -1)  # [T, W]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        pos = blk * ppb * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        live = pos < length
-        s = jnp.where(live, s, _NEG_INF)
+        v = k[:, :rank]
+        if masked:
+            first = blk * ppb * page
+            live = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
+            s = jnp.where(live, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        # A page not copied holds whatever the buffer held: its
-        # probabilities are exact zeros, and its rows are zeroed too, so
-        # that a stale inf or nan cannot reach the sum.
-        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if masked:
+            # A page not copied holds whatever the buffer held: its
+            # probabilities are exact zeros, and its rows are zeroed
+            # too, so that a stale inf or nan cannot reach the sum.
+            p = jnp.where(live, p, 0.0)
+            live_rows = first + jax.lax.broadcasted_iota(jnp.int32, (pages * page, 1), 0) < length
+            v = jnp.where(live_rows, v, jnp.zeros((), k.dtype))
         alpha = jnp.exp(m - m_new)
-        live_rows = (blk * ppb * page + jax.lax.broadcasted_iota(jnp.int32, (ppb * page, 1), 0)) < length
-        v = jnp.where(live_rows, k[:, :rank], jnp.zeros((), k.dtype))
         acc = acc * alpha + jnp.dot(p.astype(k.dtype), v, preferred_element_type=jnp.float32)
         return m_new, l * alpha + p.sum(axis=-1, keepdims=True), acc
 
-    m, l, acc = jax.lax.fori_loop(
-        0, n_blocks, body,
+    @pl.when(b == 0)
+    def _():
+        for i in range(ring_ref.shape[0]):
+            ring_ref[i] = 0
+        jax.lax.fori_loop(0, ring - 1, lambda i, _: start_next(), None)
+
+    mine = ring_ref[MINE]
+    n_pages, n_blocks = blocks(b)
+
+    def whole_block(blk, carry):
+        side = (mine + blk) % ring
+        start_next()
+        wait(blk, side)
+        return score(carry, blk, side, pages=ppb, masked=False)
+
+    carry = jax.lax.fori_loop(
+        0, n_blocks - 1, whole_block,
         (jnp.full((H, 1), _NEG_INF, jnp.float32), jnp.zeros((H, 1), jnp.float32), jnp.zeros((H, rank), jnp.float32)),
     )
+    blk = n_blocks - 1
+    side = (mine + blk) % ring
+    start_next()
+    wait(blk, side)
+    sizes = _last_block_sizes(ppb)  # steps of sizes[0] pages
+    live_pages = n_pages - blk * ppb
+    _, l, acc = jax.lax.switch(
+        jnp.clip((live_pages + sizes[0] - 1) // sizes[0] - 1, 0, len(sizes) - 1),
+        [functools.partial(score, blk=blk, side=side, pages=n, masked=True) for n in sizes],
+        carry,
+    )
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    ring_ref[MINE] = (mine + n_blocks) % ring
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "rank", "pages_per_block", "interpret"))
@@ -180,8 +261,9 @@ def mla_paged_decode_kernel(q_lat, pool, page_table, kv_lengths, *, scale, rank,
             ],
             out_specs=pl.BlockSpec((1, H, rank), lambda b, lens, table: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, ppb, page, W), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((KERNEL_BUFFERS, ppb, page, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((KERNEL_BUFFERS,)),
+                pltpu.SMEM((4,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
@@ -202,6 +284,9 @@ def mla_paged_decode(q_lat, pool, page_table, kv_lengths, *, scale: float, rank:
     # writes went to the trash page): never walk past the table.
     lens = jnp.clip(kv_lengths, 1, max_pages * page).astype(jnp.int32)
     if jax.default_backend() == "tpu":
-        chosen_blocks[f"B={B} H={H} W={W} pages={max_pages}x{page} {pool.dtype.name}"] = kernel_pages_per_block(max_pages, page)
+        chosen_blocks[f"B={B} H={H} W={W} pages={max_pages}x{page} {pool.dtype.name}"] = {
+            "pages_per_block": kernel_pages_per_block(max_pages, page), "buffers": KERNEL_BUFFERS,
+            "copies": "one pipeline over the call's slots, one slot a program",
+        }
         return mla_paged_decode_kernel(q_lat, pool, page_table, lens, scale=scale, rank=rank)
     return latent_attention_paged(q_lat[:, None], pool, page_table, lens[:, None] - 1, scale=scale, rank=rank)[:, 0]

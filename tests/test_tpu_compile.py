@@ -227,15 +227,20 @@ def _sds(v5e, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(v5e[0]))
 
 
-def test_mla_paged_decode_kernel_lowers(v5e):
-    """One query row a slot, 64 slots x 4096 tokens of latent pages."""
+@pytest.mark.parametrize("max_len", [4096, 8192])
+@pytest.mark.parametrize("B", [64, 128])
+def test_mla_paged_decode_kernel_lowers(v5e, B, max_len):
+    """One query row a slot over latent pages, at the cell's 64 slots x
+    4096 tokens and at a deployment's 128 slots and 8192: the ring of
+    page buffers (three blocks of 16 pages) has to fit scoped VMEM and
+    the copies carried from one program to the next have to lower."""
     from kubeai_tpu.ops.mla_attention import mla_paged_decode
 
-    B, max_pages = 64, 4096 // PAGE
+    max_pages = max_len // PAGE  # the pool's rows are no part of the kernel's shapes: the cell's pool for all four
     text = _compile(
         lambda q, pool, table, lens: mla_paged_decode(q, pool, table, lens, scale=192**-0.5, rank=KANANA_RANK),
         _sds(v5e, (B, KANANA_H, KANANA_W), jnp.bfloat16),
-        _sds(v5e, (8 * (B * max_pages + 1), PAGE, KANANA_W), jnp.bfloat16),
+        _sds(v5e, (8 * (64 * 64 + 1), PAGE, KANANA_W), jnp.bfloat16),
         _sds(v5e, (B, max_pages), jnp.int32),
         _sds(v5e, (B,), jnp.int32),
     )
