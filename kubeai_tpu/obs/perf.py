@@ -449,6 +449,9 @@ class PipelineStallTracker:
     profiler runs, a ``sched.<cause>`` event on the scheduler thread's
     line of the trace."""
 
+    SLOWEST_KEPT = 8  # steps shown under ``slowest_steps``
+    SLOWEST_HORIZON = 600.0  # seconds a slow step is remembered
+
     def __init__(self, window: float = 60.0, clock=time.monotonic, registry=None):
         self.window = window
         self._clock = clock
@@ -458,6 +461,12 @@ class PipelineStallTracker:
         self._stack: list[Segment] = []  # open segments (scheduler thread only)
         self._last_end: float | None = None  # end stamp of the last outermost segment
         self._step: dict[str, float] = {}  # ms by cause since the last end_step
+        # The few slowest steps of the last ten minutes, (total ms, end
+        # stamp, kind, ms by cause): what an operator asks after a stall.
+        # ``_slow_floor`` is what a step must outlast to get in, until the
+        # oldest kept one passes the horizon (``_slow_expires``).
+        self._slowest: list[tuple[float, float, str, dict[str, float]]] = []
+        self._slow_floor, self._slow_expires = 0.0, float("inf")
         reg = registry or default_registry
         self._counter = reg.counter(
             "kubeai_engine_stall_seconds_total",
@@ -480,10 +489,45 @@ class PipelineStallTracker:
         kv_restore)."""
         step, self._step = self._step, {}
         now = self._clock()
+        total = sum(step.values()) - step.get("idle", 0.0)  # a wait for requests is no slow step
         with self._lock:
             self._records.append((now, kind, None))
             self._prune_locked(now)
+            if total > self._slow_floor or now > self._slow_expires:
+                self._keep_slow_locked(total, now, kind, step)
         return step
+
+    def _keep_slow_locked(self, total: float, now: float, kind: str, step: dict[str, float]) -> None:
+        """Off the hot path: only a step slower than the least kept one, or
+        the first after a kept one passed the horizon, gets here."""
+        kept = [e for e in self._slowest if e[1] >= now - self.SLOWEST_HORIZON]
+        kept.append((total, now, kind, dict(step)))
+        kept.sort(key=lambda e: -e[0])
+        self._set_slowest_locked(kept[: self.SLOWEST_KEPT])
+
+    def _set_slowest_locked(self, kept: list) -> None:
+        self._slowest = kept
+        self._slow_floor = kept[-1][0] if len(kept) == self.SLOWEST_KEPT else 0.0
+        self._slow_expires = min((e[1] for e in kept), default=float("inf")) + self.SLOWEST_HORIZON
+
+    def slowest_steps(self, now: float | None = None) -> list[dict]:
+        """The slowest steps of the last ten minutes, slowest first:
+        kind, seconds since the step ended (``age_s``; ``end_monotonic`` is
+        the stamp itself, this host's CLOCK_MONOTONIC), total ms (``idle``
+        left out) and ms by cause of the segments that ended inside the
+        step."""
+        now = self._clock() if now is None else now
+        with self._lock:
+            kept = [e for e in self._slowest if e[1] >= now - self.SLOWEST_HORIZON]
+            if len(kept) != len(self._slowest):  # forgotten: the floor falls with them
+                self._set_slowest_locked(kept)
+        return [
+            {
+                "kind": kind, "age_s": round(now - end, 3), "end_monotonic": round(end, 6),
+                "total_ms": round(total, 3), "ms": {c: round(ms, 3) for c, ms in step.items()},
+            }
+            for total, end, kind, step in kept
+        ]
 
     def _add(self, cause: str, seconds: float, now: float) -> None:
         seconds = max(seconds, 0.0)
@@ -541,11 +585,15 @@ class PipelineStallTracker:
             out["dominant_cause"] = dominant
             pct = round(100.0 * cause_ms[dominant] / accounted)
             out["interpretation"] = f"{pct}% {dominant} → {_INTERPRET[dominant]}"
+        out["slowest_steps"] = self.slowest_steps(now)
         return out
 
 
 # ---------------------------------------------------------------------------
 # On-demand device profiler capture.
+
+
+PROFILE_WINDOW_EVENT = "profile.window"  # the capture's own span, on the capturing thread's line
 
 
 def profiling_enabled() -> bool:
@@ -564,19 +612,21 @@ class _TraceSession:
     Only the .xplane.pb is written: ``jax.profiler.stop_trace`` also
     converts the whole trace to a trace-viewer JSON and gzips it, which on a
     v5e took most of the 23 s a 4 s capture of a 7B engine needed after its
-    traced seconds (PERF.md, PR 24). ``KUBEAI_PROFILE_PYTHON_TRACER=0``
-    turns the Python tracer off: it hooks every call of every Python thread
-    of the process, and the device planes and the host's TraceMe events
-    (the scheduler's ``sched.*``, ``profile.window``) survive without it;
-    it stays on by default because the benchmark's accepted harness takes
-    the traced interval from that tracer's record of the capture's sleep.
-    Where this jax has no ``ProfilerSession`` to stop by hand, it is
-    ``start_trace`` / ``stop_trace`` after all."""
+    traced seconds (PERF.md, PR 24). *python_tracer* is the call's choice
+    (``/debug/profile?python_tracer=0|1``): the Python tracer hooks every
+    call of every Python thread of the process, and the device planes and
+    the host's TraceMe events (the scheduler's ``sched.*``,
+    ``profile.window``) are there without it; it is on by default because
+    the benchmark's ``--trace 1`` takes the traced interval from that
+    tracer's record of the capture's sleep. Where this jax has no
+    ``ProfilerSession`` to stop by hand, it is ``start_trace`` /
+    ``stop_trace`` after all. ``stop()`` returns the .xplane.pb's path
+    (None where it cannot tell)."""
 
-    def __init__(self, jax, out_dir: str):
+    def __init__(self, jax, out_dir: str, python_tracer: bool = True):
         self._jax, self._out_dir, self._session = jax, out_dir, None
         options = None
-        if os.environ.get("KUBEAI_PROFILE_PYTHON_TRACER", "") in ("0", "false", "no"):
+        if not python_tracer:
             try:
                 options = jax.profiler.ProfileOptions()
                 options.python_tracer_level = 0
@@ -589,10 +639,13 @@ class _TraceSession:
         except Exception:  # no such class here, or it refused: the public pair
             jax.profiler.start_trace(out_dir, **({"profiler_options": options} if options else {}))
 
-    def stop(self) -> None:
+    def stop(self) -> str | None:
         if self._session is None:
             self._jax.profiler.stop_trace()
-            return
+            import glob
+
+            found = glob.glob(os.path.join(self._out_dir, "plugins", "profile", "*", "*.xplane.pb"))
+            return max(found, key=os.path.getmtime, default=None)
         xspace = self._session.stop()  # the trace, as a serialized XSpace
         import socket
 
@@ -600,8 +653,10 @@ class _TraceSession:
             self._out_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S")
         )
         os.makedirs(run_dir, exist_ok=True)
-        with open(os.path.join(run_dir, socket.gethostname() + ".xplane.pb"), "wb") as f:
+        path = os.path.join(run_dir, socket.gethostname() + ".xplane.pb")
+        with open(path, "wb") as f:
             f.write(xspace)
+        return path
 
 
 class ProfilerBusy(RuntimeError):
@@ -621,7 +676,8 @@ class ProfilerCapture:
             "KUBEAI_PROFILE_DIR", "/tmp/kubeai-profiles"
         )
 
-    def capture(self, seconds: float, engine=None, out_dir: str | None = None) -> dict:
+    def capture(self, seconds: float, engine=None, out_dir: str | None = None,
+                python_tracer: bool = True) -> dict:
         if not self._lock.acquire(blocking=False):
             raise ProfilerBusy("a profile capture is already in flight")
         try:
@@ -641,17 +697,17 @@ class ProfilerCapture:
             import jax
 
             t_start = time.monotonic()
-            session = _TraceSession(jax, out_dir)
+            session = _TraceSession(jax, out_dir, python_tracer)
             t_traced = time.monotonic()
             try:
                 # The traced interval, as an event of the trace itself: a
                 # reader takes its window from here, not from the first
                 # and last device operation.
-                with _trace_annotation()("profile.window", seconds=seconds):
+                with _trace_annotation()(PROFILE_WINDOW_EVENT, seconds=seconds):
                     time.sleep(seconds)
             finally:
                 t_stop = time.monotonic()
-                session.stop()  # collects and writes: the long part
+                xplane = session.stop()  # collects and writes: the long part
                 t_written = time.monotonic()
             files = 0
             total = 0
@@ -668,6 +724,12 @@ class ProfilerCapture:
                 "files": files,
                 "bytes": total,
                 "gang_fanout": fanout,
+                # What a reader of the trace needs and cannot guess: the
+                # file, the host event that spans the traced interval, and
+                # whether the Python tracer ran beside it.
+                "xplane": xplane,
+                "window_event": PROFILE_WINDOW_EVENT,
+                "python_tracer": bool(python_tracer),
                 # What the capture cost beyond the traced seconds.
                 "start_seconds": round(t_traced - t_start, 3),
                 "stop_seconds": round(t_written - t_stop, 3),
@@ -720,8 +782,9 @@ def handle_perf_request(path: str, query: str = "", engine=None) -> tuple[int, s
 
     - ``/debug/pipeline`` — the windowed stall-attribution report (plus
       live MFU/roofline context when an engine is attached).
-    - ``/debug/profile?seconds=N`` — start a jax.profiler trace for N
-      seconds (default 2, clamped to [0.05, 120]); 403 unless
+    - ``/debug/profile?seconds=N&python_tracer=0|1`` — start a
+      jax.profiler trace for N seconds (default 2, clamped to [0.05,
+      120]), with the Python tracer (default) or without; 403 unless
       ``KUBEAI_DEBUG_PROFILE=1``, 409 while a capture is in flight.
     """
     import json
@@ -750,8 +813,13 @@ def handle_perf_request(path: str, query: str = "", engine=None) -> tuple[int, s
                 {"error": {"message": "seconds must be a number"}}
             ).encode()
         seconds = min(max(seconds, 0.05), 120.0)
+        tracer = (q.get("python_tracer") or ["1"])[0]
+        if tracer not in ("0", "1"):
+            return 400, "application/json", json.dumps(
+                {"error": {"message": "python_tracer must be 0 or 1"}}
+            ).encode()
         try:
-            result = default_profiler.capture(seconds, engine=engine)
+            result = default_profiler.capture(seconds, engine=engine, python_tracer=tracer == "1")
         except ProfilerBusy as e:
             return 409, "application/json", json.dumps(
                 {"error": {"message": str(e), "type": "conflict"}}

@@ -6,7 +6,7 @@ checkpoint holds, what decides its logits right) is in families/.
 
     python3 perfbench/children.py checkpoint <dir> <hf_config.json> <seed> [<full checkpoint>]
     python3 perfbench/children.py logits <dir> <seed> <serving.json>
-    python3 perfbench/children.py trace <trace.xplane.pb> <platform> <seconds>
+    python3 perfbench/children.py trace <trace.xplane.pb> <platform> <seconds> [<window event regex>]
 
 The last stdout line of each is its JSON result.
 """
@@ -114,13 +114,17 @@ def child_logits(path: str, seed: str, serving_path: str) -> dict:
     return family_of(hf).logits(path, seed, serving)
 
 
-def child_trace(path: str, platform: str, seconds: str) -> dict:
+def child_trace(path: str, platform: str, seconds: str, window_event: str = "") -> dict:
+    """*window_event* (`--trace 2`): the host event that spans the traced
+    interval, as the engine's reply named it, in place of trace.json's."""
     sys.path.insert(0, HERE)
     import trace_reduce
 
     with open(os.path.join(HERE, "trace.json")) as f:
         spec = json.load(f)
     part = {**spec[platform], "profile_seconds": float(seconds)}
+    if window_event:
+        part["window_event"] = window_event
     planes, window, notes = trace_reduce.read_xplane(path, part)
     out = trace_reduce.reduce_events(planes, window)
     out["window_from"] = notes["window_from"]

@@ -131,22 +131,27 @@ def _first_top(pieces) -> dict:
 class Load:
     """Runs a plan. `start()` begins the load (the ramp), the window is
     [t_open, t_close) on time.monotonic(), `finish()` stops new sends,
-    lets in-flight requests drain and returns every record."""
+    lets in-flight requests drain and returns every record.
 
-    def __init__(self, base: str, model: str, plan, seconds: float, timeout: float = 300.0):
+    New requests are sent until `t_end`: the window's close, or with
+    *hold* (`--trace 2`: the same traffic goes on past the window, under
+    the tail's trace) whenever `end_sending()` is called."""
+
+    def __init__(self, base: str, model: str, plan, seconds: float, timeout: float = 300.0, hold: bool = False):
         self.base, self.model, self.plan, self.seconds = base, model, plan, seconds
-        self.timeout = timeout
+        self.timeout, self.hold = timeout, hold
         self.records: list[Record] = []
         self._lock = threading.Lock()
         self._threads: list[threading.Thread] = []
         self._stop = threading.Event()
         self.exhausted = False  # a closed-loop client ran out of requests
-        self.t_start = self.t_open = self.t_close = 0.0
+        self.t_start = self.t_open = self.t_close = self.t_end = 0.0
 
     def start(self) -> None:
         self.t_start = time.monotonic()
         self.t_open = self.t_start + self.plan.ramp_s
         self.t_close = self.t_open + self.seconds
+        self.t_end = float("inf") if self.hold else self.t_close
         if self.plan.loop == "open":
             th = threading.Thread(target=self._pace, daemon=True)
             th.start()
@@ -179,7 +184,7 @@ class Load:
     def _client(self, nxt, delay: float = 0.0) -> None:
         if delay > 0 and self._stop.wait(delay):
             return
-        while time.monotonic() < self.t_close and not self._stop.is_set():
+        while time.monotonic() < self.t_end and not self._stop.is_set():
             req = nxt()
             if req is None:
                 with self._lock:
@@ -191,21 +196,36 @@ class Load:
         workers = []
         for req in self.plan.shared:
             due = self.t_start + req.due_s
-            if due >= self.t_close:
+            if due >= self.t_end:
                 break
             delay = due - time.monotonic()
             if delay > 0 and self._stop.wait(delay):
                 break
-            if self._stop.is_set():
+            if self._stop.is_set() or due >= self.t_end:
                 break
             th = threading.Thread(target=self._one, args=(req, due), daemon=True)
             th.start()
             workers.append(th)
+        else:
+            if self.hold and self.t_end == float("inf"):
+                with self._lock:
+                    self.exhausted = True  # the plan ended before the tail's trace did
+                self.end_sending()
         for th in workers:
-            th.join(timeout=max(self.t_close + self.plan.drain_s - time.monotonic(), 0.1))
+            th.join(timeout=max(self.t_end + self.plan.drain_s - time.monotonic(), 0.1))
+
+    def snapshot(self) -> list[Record]:
+        """The records so far, those still in flight among them."""
+        with self._lock:
+            return list(self.records)
+
+    def end_sending(self) -> None:
+        """With *hold*: no new request from now on (in-flight ones go on)."""
+        self.t_end = min(self.t_end, time.monotonic())
 
     def finish(self) -> list[Record]:
-        deadline = self.t_close + self.plan.drain_s
+        self.end_sending()  # without *hold* t_end is t_close, which is past
+        deadline = self.t_end + self.plan.drain_s
         for th in self._threads:
             th.join(timeout=max(deadline - time.monotonic(), 0.1))
         self._stop.set()

@@ -10,4 +10,11 @@ second), `records` (the window's client records), `all_records`,
 (the request sent to zero replicas), `trace` (trace_reduce's output) with
 `trace_t0`/`trace_t1` (host clock around the profile call), `hf` (the
 published config), `serving`, `peaks`, `rehearsal`.
+
+In a run that traced itself (`--trace 2`) `before` / `after` / `polls` /
+`records` / `window_s` are the MEASURED window's (untraced), `trace` and
+`trace_t0` / `trace_t1` the tail's, whose requests are in `all_records`
+only; `trace_path` and `window_event_rx` are what the engine's reply named;
+a reader listed under `tail_view` in perfbench/trace_in_run.json gets the
+tail's polls in `before` / `polls` / `after` (run.py's TailView).
 """

@@ -1,8 +1,11 @@
 """The check every result goes through before it is printed: the object
 against BENCHMARK.json and the contract of the last line. `run.py` calls
-`check()` in both modes and prints nothing it rejects.
+`check()` in every mode and prints nothing it rejects. A mode is `--trace`'s
+value: 0 (the end-to-end metrics), 1 (the per-layer ones, of a traced run
+of its own) or 2 (both side by side: the run measured its window and then
+traced itself); `False` / `True` still read as 0 / 1.
 
-    python3 perfbench/resultline.py <workload> <0|1> < line   # by hand
+    python3 perfbench/resultline.py <workload> <0|1|2> < line   # by hand
 """
 
 from __future__ import annotations
@@ -20,15 +23,16 @@ def load_benchmark(root: str = ROOT) -> dict:
         return json.load(f)
 
 
-def declared(bench: dict, workload: str, trace: bool) -> dict[str, str]:
+def declared(bench: dict, workload: str, trace: int) -> dict[str, str]:
     """name -> unit of the metrics this workload carries in this mode: the
-    end-to-end ones untraced, the per-layer ones traced. A metric with a
-    `workloads` key belongs to the cells it lists, one without to all."""
+    end-to-end ones untraced, the per-layer ones traced, both in mode 2. A
+    metric with a `workloads` key belongs to the cells it lists, one
+    without to all."""
     if workload not in [w["name"] for w in bench["workloads"]]:
         raise ValueError(f"workload {workload!r} is not in BENCHMARK.json")
-    group = bench["per_layer"] if trace else bench["end_to_end"]
+    groups = {0: ("end_to_end",), 1: ("per_layer",), 2: ("end_to_end", "per_layer")}[int(trace)]
     return {
-        m["name"]: m["unit"] for m in group
+        m["name"]: m["unit"] for group in groups for m in bench[group]
         if "workloads" not in m or workload in m["workloads"]
     }
 
@@ -37,11 +41,12 @@ def _finite(x) -> bool:
     return isinstance(x, (int, float)) and not isinstance(x, bool) and math.isfinite(x)
 
 
-def problems(obj, bench: dict, workload: str, trace: bool, chips: int,
+def problems(obj, bench: dict, workload: str, trace: int, chips: int,
              rehearsal: bool = False, may_miss: set[str] | None = None) -> list[str]:
     """Everything wrong with *obj* as the last line of a run; [] if nothing.
     *may_miss*: per-layer metrics whose reader found nothing to read (the
-    contract lets the harness leave those out of the line)."""
+    contract lets the harness leave those out of the line; an end-to-end
+    metric may never be missed)."""
     out: list[str] = []
     if not isinstance(obj, dict):
         return ["not a JSON object"]
@@ -63,7 +68,8 @@ def problems(obj, bench: dict, workload: str, trace: bool, chips: int,
     got = obj["metrics"]
     if not isinstance(got, dict):
         return out + ["metrics is not an object"]
-    for name in sorted(set(want) - set(got) - (may_miss or set())):
+    may_miss = set(may_miss or ()) - set(declared(bench, workload, 0))
+    for name in sorted(set(want) - set(got) - may_miss):
         out.append(f"metric {name} of this workload and mode is missing")
     for name in sorted(set(got) - set(want)):
         out.append(f"metric {name} is not declared for this workload in this mode")
@@ -121,7 +127,7 @@ def render(obj) -> str:
     return json.dumps(obj, allow_nan=False, separators=(", ", ": "))
 
 
-def check(obj, workload: str, trace: bool, chips: int, rehearsal: bool = False,
+def check(obj, workload: str, trace: int, chips: int, rehearsal: bool = False,
           may_miss: set[str] | None = None, bench: dict | None = None) -> str:
     """The printable line, or ValueError listing what is wrong with it."""
     bad = problems(obj, bench or load_benchmark(), workload, trace, chips, rehearsal, may_miss)
@@ -137,7 +143,7 @@ if __name__ == "__main__":
     bench = load_benchmark()
     cell = next(w for w in bench["workloads"] if w["name"] == sys.argv[1])
     obj = json.loads(sys.stdin.read().strip().splitlines()[-1])
-    bad = problems(obj, bench, sys.argv[1], sys.argv[2] == "1", cell["chips"],
+    bad = problems(obj, bench, sys.argv[1], int(sys.argv[2]), cell["chips"],
                    rehearsal=obj.get("device", {}).get("platform") == "cpu")
     print("\n".join(bad) or "ok")
     sys.exit(1 if bad else 0)

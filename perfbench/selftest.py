@@ -10,7 +10,15 @@
    CPU engine's own /debug/profile: the rehearsal's platform);
  * the traffic generator: every seed gives the same multiset of sizes and
    the same arrival span, in another order, and takes seeds past 2**31;
- * resultline.py refuses each way a last line went wrong before;
+ * resultline.py refuses each way a last line went wrong before, and a
+   `--trace 2` line that lacks either kind of metric;
+ * every idle piece of the device put down to the scheduler segment beside
+   it (idle_attribution.py), on hand-made events: a gap under two
+   segments, under none, across the window's edge, nested segments, shares
+   that add up to the idle share;
+ * `--trace 2`: when the tail's trace may begin, on records of each cell's
+   kind; the operator's command and environment are those of `--trace 0`;
+   a tail's plan has the window's plan as its prefix;
  * BENCHMARK.json: every name resolves to a file, every metric's `moves`
    is reported where the metric is;
  * families/: same seed, same bytes (the shards of both dense
@@ -39,6 +47,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import children  # noqa: E402
+import idle_attribution as ia  # noqa: E402
+import loadgen  # noqa: E402
 import resultline  # noqa: E402
 import run  # noqa: E402
 import trace_reduce as tr  # noqa: E402
@@ -98,6 +108,137 @@ def hand_made() -> None:
     check("no plane is an error", raises(lambda: tr.reduce_events([], (0, 1)), tr.TraceError))
 
 
+def idle_by_host() -> None:
+    # The window is [100, 1100). The device is busy in three pieces:
+    busy = [(100, 150), (200, 350), (1000, 1100)]  # so idle: [150, 200) and [350, 1000)
+    seg = [
+        ("admit", 120, 300, {}),                                   # [120, 420)
+        ("prefill", 160, 100, {"kind": "group", "tokens": 7}),     # nested in it: [160, 260)
+        ("fetch_wait", 500, 400, {"of": "chunk"}),                 # [500, 900)
+        ("emit", 950, 500, {"tokens": 5}),                         # crosses the window's edge
+        ("idle", 5000, 10, {}),                                    # outside
+    ]
+    flat = ia.flatten(seg, 100, 1100)
+    check("nested segments: the innermost wins, the outer one resumes", flat == [
+        (120, 160, "admit", {}), (160, 260, "prefill", {"kind": "group", "tokens": 7}), (260, 420, "admit", {}),
+        (500, 900, "fetch_wait", {"of": "chunk"}), (950, 1100, "emit", {"tokens": 5}),
+    ], flat)
+    gaps = ia.attribute([(150, 200), (350, 1000)], flat)
+    check("a gap under two segments", gaps[0]["by"] == {"admit": 10, "prefill": 40} and gaps[0]["most"][2] == "prefill", gaps[0])
+    check("a gap under segments and under none", gaps[1]["by"] == {"admit": 70, "fetch_wait": 400, "emit": 50, "other": 130}, gaps[1])
+    check("a gap under no segment at all is `other`", ia.attribute([(10, 30)], [])[0]["by"] == {"other": 20})
+    t = ia.table(busy, seg, 100, 1100)
+    check("idle seconds by cause add up to the idle time", abs(sum(t["idle_by_cause_s"].values()) - t["idle_s"]) < 1e-15
+          and abs(t["idle_s"] - 700e-9) < 1e-15, t)
+    one = tr.reduce_events([{"name": "d0", "ops": [("x", s, e - s) for s, e in busy], "modules": []}], (100, 1100))
+    shares = {c: 100.0 * v / t["window_s"] for c, v in t["idle_by_cause_s"].items()}
+    check("the shares add up to the idle share of the same trace",
+          abs(sum(shares.values()) - 100.0 * (1 - one["busy_s"] / one["window_s"])) < 1e-9, shares)
+    check("split by fetch_wait's `of` and prefill's `kind`",
+          t["idle_by_detail_s"] == {"fetch_wait.of=chunk": 400e-9, "prefill.kind=group": 40e-9}, t["idle_by_detail_s"])
+    check("gaps counted by the cause that covers most", t["gaps_by_leading_cause"] == {"fetch_wait": 1, "prefill": 1}, t)
+    check("a segment across the window's edge is clipped", t["idle_by_cause_s"]["emit"] == 50e-9, t["idle_by_cause_s"])
+    check("longest gap first, with its own causes", t["gaps"][0]["seconds"] == 650e-9
+          and list(t["gaps"][0]["by_cause_s"]) == ["fetch_wait", "other", "admit", "emit"], t["gaps"][0])
+    text = ia.label({"at_s": 1.15331, "seconds": 0.2, "by_cause_s": {"emit": 0.122, "other": 0.054, "dispatch": 0.024},
+                     "most": {"cause": "emit", "attrs": {"tokens": 512}}})
+    check("a gap's label names its own causes", text == "at +1.1533s, host: emit 61% [tokens=512], other 27%, dispatch 12%", text)
+    check("a label is at most 200 characters", len(ia.label({"at_s": 0.0, "seconds": 1.0, "by_cause_s": {f"cause_{i}": 1.0 for i in range(40)},
+                                                             "most": None})) <= 200)
+    check("a device that never idles has no gap", ia.table([(100, 1100)], seg, 100, 1100)["idle_s"] == 0.0)
+
+
+def rec(due, first=None, n=0, done=None, error=None):
+    r = loadgen.Record(traffic.Request("x", max(n, 1)), due)
+    r.sent = due
+    r.token_times = [] if first is None else [first + 0.01 * i for i in range(n)]
+    r.done, r.error, r.ok = done, error, done is not None
+    return r
+
+
+def in_run(tmp: str) -> None:
+    """--trace 2: what the harness decides without a chip."""
+    # The window is [10, 60). When may the tail's trace begin?
+    ramp = rec(5.0, first=6.0, n=3)                       # due in the ramp, still streaming: nobody reads it
+    streaming = rec(50.0, first=51.0, n=4)                # due in the window, first token there, not done
+    waiting = rec(59.5)                                   # due in the window, no token yet
+    done = rec(20.0, first=20.5, n=8, done=21.0)
+    failed = rec(30.0, error="status 500")
+    tail = rec(61.0)                                      # sent in the tail: nobody reads it
+    records = [ramp, streaming, waiting, done, failed, tail]
+    still = lambda metrics, recs=records: run.open_records(metrics, recs, 10.0, 60.0)  # noqa: E731
+    check("output_tok_s reads nothing after the close: the trace begins at once (chat-sat, reason-sat, longdoc-sat)",
+          still({"output_tok_s", "setup_s"}) == (0, 60.0), still({"output_tok_s", "setup_s"}))
+    check("ttft_p50_ms waits for the first token of every request due in the window (docqa)",
+          still({"output_tok_s", "ttft_p50_ms", "setup_s"})[0] == 1)
+    check("tpot_mean_ms waits for their whole lives (chat-rate)", still({"tpot_mean_ms", "setup_s"})[0] == 2)
+    waiting.token_times = [61.5]
+    check("a first token closes a record for ttft_p50_ms, and says when", still({"ttft_p50_ms"}) == (0, 61.5), still({"ttft_p50_ms"}))
+    check("but not for tpot_mean_ms", still({"tpot_mean_ms"})[0] == 2)
+    streaming.done, waiting.done = 62.0, 63.25
+    check("the last record closed", still({"tpot_mean_ms"}) == (0, 63.25), still({"tpot_mean_ms"}))
+    check("a failed request is closed", still({"tpot_mean_ms"}, [failed]) == (0, 60.0))
+    check("an end-to-end metric without a row in the table is an error", raises(lambda: still({"goodput"}), KeyError))
+    bench = resultline.load_benchmark()
+    check("every declared end-to-end metric has a row", all(m["name"] in run.READS_AFTER_CLOSE for m in bench["end_to_end"]))
+
+    # The same operator, started the same way, in modes 0 and 2.
+    import argparse
+    import subprocess as sp
+    from unittest import mock
+
+    started = {}
+    for mode in (0, 1, 2):
+        r = run.Run(argparse.Namespace(workload=bench["workloads"][0]["name"], seed=7, rehearse=True, trace=mode, seconds=1, keep=False))
+        r.workdir = os.path.join(tmp, "work")
+        os.makedirs(r.workdir, exist_ok=True)
+
+        class Started(Exception):
+            pass
+
+        def popen(argv, env=None, **kw):
+            started[mode] = (argv[:7] + argv[8:], env)  # the port is drawn anew each time
+            raise Started
+
+        with mock.patch.object(sp, "Popen", popen):
+            raises(lambda: r.start_operator(os.path.join(tmp, "ckpt")), Started)
+    check("the operator's command is the same in modes 0 and 2", started[0][0] == started[2][0], (started[0][0], started[2][0]))
+    check("and its environment", started[0][1] == started[2][1],
+          {k for k in set(started[0][1]) | set(started[2][1]) if started[0][1].get(k) != started[2][1].get(k)})
+    check("the gate is set in every mode (it is read when the endpoint is called)",
+          all(started[m][1].get("KUBEAI_DEBUG_PROFILE") == "1" for m in (0, 1, 2)))
+
+    # The tail's plan holds the window's as its prefix, whatever the loop.
+    key = lambda q: (q.prompt, q.max_tokens, q.tag, q.due_s)  # noqa: E731
+    for cell in bench["workloads"]:
+        spec = traffic.load(cell["traffic"])
+        short, longer = run.build_plan(spec, 2**31 + 3, 50), run.build_plan(spec, 2**31 + 3, 50, 57.0)
+        same = [key(q) for q in longer.shared[: len(short.shared)]] == [key(q) for q in short.shared] and all(
+            [key(q) for q in lc[: len(sc)]] == [key(q) for q in sc] for sc, lc in zip(short.per_client, longer.per_client))
+        more = len(longer.shared) > len(short.shared) or bool(short.per_client)
+        check(f"{cell['traffic']}: the tail's plan has the window's as its prefix, and requests beyond it", same and more)
+
+    # A context's tail view: the tail's polls where a reader brackets the
+    # trace with them, the run's one context for everything else.
+    ctx = run.Context()
+    ctx.before, ctx.after, ctx.polls, ctx.window_s, ctx.records = "b", "a", ["p"], 50.0, ["r"]
+    view = run.TailView(ctx, before="tb", after="ta", polls=["tp"], window_s=5.0)
+    view.kept_by_a_reader = 1
+    check("tail view", (view.before, view.after, view.polls, view.window_s, view.records) == ("tb", "ta", ["tp"], 5.0, ["r"])
+          and ctx.kept_by_a_reader == 1 and ctx.before == "b")
+
+    # The load goes on past the window only when told to, and stops when told.
+    plan = traffic.Plan(loop="closed", ramp_s=0.0, drain_s=1.0, clients=0)
+    load = loadgen.Load("127.0.0.1:9", "m", plan, 0.01)
+    load.start()
+    check("without hold the sends end with the window", load.t_end == load.t_close)
+    held = loadgen.Load("127.0.0.1:9", "m", plan, 0.01, hold=True)
+    held.start()
+    check("with hold they go on", held.t_end == float("inf"))
+    held.end_sending()
+    check("until told to stop", held.t_close <= held.t_end < float("inf") or held.t_end <= held.t_close)
+
+
 def recorded() -> None:
     path = os.path.join(HERE, "testdata", "cpu-small.xplane.pb")
     if not os.path.exists(path):
@@ -149,6 +290,24 @@ def last_line() -> None:
              "metrics": {n: {"value": 1.5, "unit": u} for n, u in e2e.items()}}
     good1 = {"correct": True, "attempted": 10, "failed": 0, "device": {**dev, "window_s": 4.0, "busy_s": 3.0},
              "metrics": {n: {"value": 1.5, "unit": u} for n, u in layer.items()}}
+    # --trace 2: both kinds side by side, and the traced line's device keys.
+    good2 = {**good1, "metrics": {**good0["metrics"], **good1["metrics"]},
+             "breakdown": {"device_ops": [["fusion in jit__unknown", 0.5]],
+                           "idle_gaps": [["at +1.1533s, host: emit 61% [tokens=512], other 27%, dispatch 12%", 0.004]]}}
+    bad2 = lambda obj, **kw: bool(resultline.problems(obj, bench, cell, 2, 1, **kw))  # noqa: E731
+    check("good line of a run that traced itself", not bad2(good2), resultline.problems(good2, bench, cell, 2, 1))
+    check("declared in mode 2 = the end-to-end and the per-layer metrics",
+          resultline.declared(bench, cell, 2) == {**e2e, **layer} and resultline.declared(bench, cell, True) == layer
+          and resultline.declared(bench, cell, False) == e2e)
+    check("mode 2 without the end-to-end metrics refused", bad2({**good2, "metrics": good1["metrics"]}))
+    check("mode 2 without the per-layer metrics refused", bad2({**good2, "metrics": good0["metrics"]}))
+    check("mode 2: a per-layer metric may be missed", not bad2(
+        {**good2, "metrics": {k: v for k, v in good2["metrics"].items() if k != next(iter(layer))}}, may_miss={next(iter(layer))}))
+    check("mode 2: an end-to-end metric may not", bad2(
+        {**good2, "metrics": {k: v for k, v in good2["metrics"].items() if k != "setup_s"}}, may_miss={"setup_s"}))
+    check("mode 2 without window_s refused", bad2({**good2, "device": dev}))
+    check("mode 2: a malformed breakdown refused", bad2({**good2, "breakdown": {"device_ops": [], "idle_gaps": [["x"]]}}))
+    check("a mode 2 line is no mode 0 line", bool(resultline.problems(good2, bench, cell, 0, 1)))
     bad = lambda obj, trace: bool(resultline.problems(obj, bench, cell, trace, 1))  # noqa: E731
     check("good untraced line", not bad(good0, False), resultline.problems(good0, bench, cell, False, 1))
     check("good traced line", not bad(good1, True), resultline.problems(good1, bench, cell, True, 1))
@@ -354,11 +513,13 @@ def by_files_alone(tmp: str) -> None:
 
 if __name__ == "__main__":
     hand_made()
+    idle_by_host()
     recorded()
     generator()
     last_line()
     benchmark_file()
     with tempfile.TemporaryDirectory() as tmp:
+        in_run(tmp)
         families(tmp)
         by_files_alone(tmp)
     print(f"{len(FAILED)} failed" if FAILED else "all passed")

@@ -223,6 +223,53 @@ class TestStallTracker:
         assert rep["accounted_ms"] == 0.0
         assert set(rep["causes"]) == set(perf_obs.STALL_CAUSES)
         assert all(c["fraction"] == 0.0 for c in rep["causes"].values())
+        assert rep["slowest_steps"] == []
+
+    def test_slowest_steps_keeps_the_slowest(self):
+        """Of 20 steps the 8 slowest stay, slowest first, each with its
+        kind, its age and its ms by cause; the 60 s window does not hold
+        them back."""
+        clock = FakeClock()
+        tr = PipelineStallTracker(window=60.0, clock=clock)
+        for i in range(20):
+            self._run(tr, clock, "fetch_wait", 10 + (i * 7) % 20)  # 10..29 ms, each once
+            self._run(tr, clock, "emit", 1)
+            tr.end_step("decode_chunk" if i % 2 else "prefill_group")
+        slow = tr.report()["slowest_steps"]
+        assert [s["ms"]["fetch_wait"] for s in slow] == [29, 28, 27, 26, 25, 24, 23, 22]
+        assert all(s["total_ms"] == pytest.approx(s["ms"]["fetch_wait"] + 1) for s in slow)
+        assert {s["kind"] for s in slow} == {"decode_chunk", "prefill_group"}
+        # A step slower than the least kept one takes its place ...
+        self._run(tr, clock, "dispatch", 26.5)
+        tr.end_step("decode_chunk")
+        slow = tr.slowest_steps()
+        assert [round(s["total_ms"], 1) for s in slow] == [30, 29, 28, 27, 26.5, 26, 25, 24]
+        assert slow[4]["ms"]["dispatch"] == 26.5 and slow[4]["age_s"] == 0.0
+        # ... and a quicker one does not get in (a comparison, no sort).
+        self._run(tr, clock, "emit", 5)
+        tr.end_step("decode_chunk")
+        assert [s["total_ms"] for s in tr.slowest_steps()] == [s["total_ms"] for s in slow]
+        # Past the report's 60 s window they are still there, with their age.
+        clock.advance(120.0)
+        rep = tr.report()
+        assert rep["accounted_ms"] == 0.0 and len(rep["slowest_steps"]) == 8
+        assert all(120.0 <= s["age_s"] <= 121.0 for s in rep["slowest_steps"])
+
+    def test_slowest_steps_forgets_after_its_horizon(self):
+        clock = FakeClock()
+        tr = PipelineStallTracker(clock=clock)
+        for _ in range(tr.SLOWEST_KEPT):
+            self._run(tr, clock, "fetch_wait", 500)  # a stall, eight times
+            tr.end_step("decode_chunk")
+        self._run(tr, clock, "idle", 1000.0 * tr.SLOWEST_HORIZON + 1.0)  # no request for ten minutes
+        # The first step after the horizon gets in though it is under the
+        # old floor: the stalls are forgotten, and nobody had to ask. The
+        # wait for a request is not part of how slow it was.
+        self._run(tr, clock, "emit", 2)
+        tr.end_step("decode_chunk")
+        assert [s["total_ms"] for s in tr.slowest_steps()] == [2.0]
+        clock.advance(tr.SLOWEST_HORIZON + 1.0)
+        assert tr.slowest_steps() == [] and tr.report()["slowest_steps"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -449,6 +496,49 @@ class TestProfilerCapture:
         code, _, _ = handle_perf_request("/debug/profile", "seconds=banana", engine=None)
         assert code == 400
 
+    def test_bad_python_tracer_400(self, monkeypatch):
+        monkeypatch.setenv("KUBEAI_DEBUG_PROFILE", "1")
+        code, _, body = handle_perf_request("/debug/profile", "seconds=0.05&python_tracer=off", engine=None)
+        assert code == 400 and "python_tracer" in json.loads(body)["error"]["message"]
+
+    @pytest.mark.parametrize("python_tracer", ["0", "1"])
+    def test_the_call_chooses_the_tracer_and_the_reply_names_the_file(self, monkeypatch, tmp_path, python_tracer):
+        """One .xplane.pb, named by the reply, that holds the capture's own
+        span and the scheduler's segments whichever tracer ran beside."""
+        from jax.profiler import ProfileData
+
+        monkeypatch.setenv("KUBEAI_DEBUG_PROFILE", "1")
+        monkeypatch.setattr(default_profiler, "root", str(tmp_path))
+        tr = PipelineStallTracker()
+        stop = threading.Event()
+
+        def loop():  # what the scheduler thread does, without an engine
+            while not stop.is_set():
+                with tr.segment("emit", tokens=3):
+                    time.sleep(0.005)
+
+        th = threading.Thread(target=loop, daemon=True)
+        th.start()
+        try:
+            code, _, body = handle_perf_request(
+                "/debug/profile", f"seconds=0.3&python_tracer={python_tracer}", engine=None
+            )
+        finally:
+            stop.set()
+            th.join()
+        assert code == 200
+        doc = json.loads(body)
+        assert doc["python_tracer"] is (python_tracer == "1") and doc["window_event"] == "profile.window"
+        assert doc["files"] == 1 and doc["xplane"].startswith(doc["trace_dir"]) and doc["xplane"].endswith(".xplane.pb")
+        events = [
+            (ev.name, dict(ev.stats)) for plane in ProfileData.from_file(doc["xplane"]).planes
+            if plane.name.startswith("/host:") for ln in plane.lines for ev in ln.events
+        ]
+        names = {n for n, _ in events}
+        assert "profile.window" in names and "sched.emit" in names
+        assert next(st for n, st in events if n == "sched.emit")["tokens"] == 3
+        assert ("$time sleep" in names) == (python_tracer == "1")
+
     def test_single_flight_409(self, monkeypatch, tmp_path):
         monkeypatch.setenv("KUBEAI_DEBUG_PROFILE", "1")
         monkeypatch.setattr(default_profiler, "root", str(tmp_path))
@@ -457,11 +547,11 @@ class TestProfilerCapture:
 
         orig_capture = default_profiler.capture
 
-        def slow_capture(seconds, engine=None, out_dir=None):
+        def slow_capture(seconds, engine=None, out_dir=None, **kw):
             # Signal once the lock is held, without burning a real trace
             # for the whole window.
             started.set()
-            return orig_capture(seconds, engine=engine, out_dir=out_dir)
+            return orig_capture(seconds, engine=engine, out_dir=out_dir, **kw)
 
         monkeypatch.setattr(default_profiler, "capture", slow_capture)
 

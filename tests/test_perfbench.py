@@ -57,7 +57,7 @@ MOE_METRICS = {
 
 def test_benchmark_declares_the_new_metrics():
     bench = resultline.load_benchmark()
-    assert "trace_in_run" not in bench  # the switch was left out (PERF.md section 7)
+    assert bench["trace_in_run"] is True  # since PR 38 a run measures and then traces itself (--trace 2)
     names = [m["name"] for m in bench["per_layer"]]
     at = [names.index(n) for n in NEW]  # each declared, wherever later PRs' entries stand
     assert at == sorted(at) and at == list(range(at[0], at[0] + len(NEW)))  # together, in the issue's order
@@ -68,6 +68,90 @@ def test_benchmark_declares_the_new_metrics():
         with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
             spec = json.load(f)
         assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py"))
+
+
+# PR 38's four metrics: one reader (idle_by_host), by the cell's own end-to-end metric.
+SAT_CELLS = {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa", "kanana2-bf16-reason-sat", "smallthinker-bf16-longdoc-sat"}
+IDLE = {
+    "idle_exposed_host_pct": SAT_CELLS, "idle_in_fetch_pct": SAT_CELLS,
+    "idle_exposed_host_pct.rate": {"qwen7b-int8-chat-rate"}, "idle_in_fetch_pct.rate": {"qwen7b-int8-chat-rate"},
+}
+HOST_WORK = {"sweep", "admit", "prefill", "kv_transfer", "dispatch", "host_overlap", "emit", "other"}
+
+
+@pytest.mark.parametrize("name", sorted(IDLE))
+def test_the_idle_gaps_metrics_are_declared_last_and_read_by_one_reader(name):
+    bench = resultline.load_benchmark()
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert [m["name"] for m in bench["per_layer"][-4:]] == list(IDLE)  # appended, in the issue's order
+    assert set(entry["workloads"]) == IDLE[name] and entry["layer"] == "scheduler" and entry["source"] == "device_trace"
+    assert entry["moves"] == ("tpot_mean_ms" if name.endswith(".rate") else "output_tok_s") and entry["better"] == "lower"
+    with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "idle_by_host"
+    # Host work that did not hide, or the wait in the fetch: `idle` (no
+    # request) is in neither, and the three add up to the idle share.
+    causes = set(spec["params"]["causes"])
+    assert causes == ({"fetch_wait"} if "in_fetch" in name else HOST_WORK)
+
+
+@pytest.mark.parametrize("cell", sorted(SAT_CELLS | {"qwen7b-int8-chat-rate"}))
+def test_a_line_of_a_run_that_traced_itself_carries_both_kinds(cell):
+    bench = resultline.load_benchmark()
+    both = resultline.declared(bench, cell, 2)
+    e2e, layer = resultline.declared(bench, cell, 0), resultline.declared(bench, cell, 1)
+    assert both == {**e2e, **layer} and len(both) == len(e2e) + len(layer)
+    assert {n for n, cells in IDLE.items() if cell in cells} <= set(layer)
+    line = _traced_line(both)
+    assert resultline.problems(line, bench, cell, 2, 1) == []
+    for kind in (e2e, layer):  # a line that lacks either kind is refused
+        cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k not in kind}}
+        assert resultline.problems(cut, bench, cell, 2, 1)
+    assert resultline.problems({**line, "device": {k: v for k, v in line["device"].items() if k != "busy_s"}}, bench, cell, 2, 1)
+
+
+def _tail_ctx(table, window_s=4.0):
+    ctx = types.SimpleNamespace(trace={"window_s": window_s, "busy_s": 3.0}, idle_by_host=table, rehearsal=True)
+    return ctx
+
+
+def test_the_idle_reader_splits_the_idle_share_of_the_same_trace():
+    from readers import device_idle, idle_by_host
+
+    table = {"window_s": 4.0, "n_segments": 12, "idle_by_cause_s": {"emit": 0.5, "other": 0.1, "fetch_wait": 0.3, "idle": 0.1}}
+    ctx = _tail_ctx(table)
+    exposed = idle_by_host.read(ctx, sorted(HOST_WORK))
+    fetch = idle_by_host.read(ctx, ["fetch_wait"])
+    under_idle = idle_by_host.read(ctx, ["idle"])
+    assert (exposed, fetch, under_idle) == (pytest.approx(15.0), pytest.approx(7.5), pytest.approx(2.5))
+    assert exposed + fetch + under_idle == pytest.approx(device_idle.read(ctx))
+    # Nothing to read: no trace, a program that wrote no segment, a table of another interval.
+    assert idle_by_host.read(_tail_ctx({}), ["fetch_wait"]) is None
+    assert idle_by_host.read(_tail_ctx({**table, "n_segments": 0}), ["fetch_wait"]) is None
+    assert idle_by_host.read(_tail_ctx(table, window_s=3.5), ["fetch_wait"]) is None
+    none = types.SimpleNamespace(trace=None, rehearsal=True)
+    assert idle_by_host.read(none, ["fetch_wait"]) is None and none.idle_by_host == {}
+
+
+def test_the_idle_reader_reads_the_recorded_trace(capsys):
+    """The child on the small recorded trace (a CPU engine from before the
+    segments were written: PR 23): the same interval as the trace child's,
+    no segment, so nothing to read, and it says so on its line."""
+    from readers import idle_by_host
+
+    path = os.path.join(ROOT, "perfbench", "testdata", "cpu-small.xplane.pb")
+    with open(os.path.join(ROOT, "perfbench", "testdata", "cpu-small.expected.json")) as f:
+        want = json.load(f)
+    ctx = types.SimpleNamespace(
+        trace={"window_s": want["window_s"], "busy_s": want["busy_s"], "bytes": os.path.getsize(path)},
+        trace_path=path, trace_t0=10.0, trace_t1=10.0 + want["profile_seconds"], rehearsal=True,
+    )
+    assert idle_by_host.read(ctx, ["fetch_wait"]) is None
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["phase"] == "idle_by_host" and line["error"] is None and line["n_segments"] == 0
+    assert line["window_s"] == pytest.approx(want["window_s"], abs=1e-9)
+    assert line["idle_s"] == pytest.approx(want["window_s"] - want["busy_s"], abs=1e-9)
+    assert line["idle_by_cause_s"] == {"other": pytest.approx(line["idle_s"])}
 
 
 def _traced_line(traced):
@@ -102,7 +186,8 @@ def test_every_cell_is_one_of_those_with_a_case_here():
 def test_the_expert_models_cell_declares_its_own_metrics_and_none_of_the_dense_cells():
     bench = resultline.load_benchmark()
     traced, untraced = resultline.declared(bench, MOE_CELL, True), resultline.declared(bench, MOE_CELL, False)
-    assert set(traced) == MOE_METRICS and not set(NEW) & set(traced)
+    # Its own, and since PR 38 the two idle-gap metrics every saturated cell reads.
+    assert set(traced) == MOE_METRICS | {"idle_exposed_host_pct", "idle_in_fetch_pct"} and not set(NEW) & set(traced)
     assert set(untraced) == {"output_tok_s", "setup_s"}
     for m in bench["per_layer"]:  # no metric without a list: a later cell takes none by default
         assert "workloads" in m, m["name"]
@@ -243,6 +328,55 @@ def test_the_scope_reader_reads_nothing_where_there_is_nothing(tmp_path, monkeyp
     assert scope_share.read(ctx, "^jit_prefill", "attn") is None
 
 
+def test_rehearsal_of_a_run_that_traces_itself(tmp_path):
+    """--rehearse --trace 2, every phase, on the CPU at a tiny size: one
+    last line with both kinds of metric; the end-to-end values are what
+    `Run.end_to_end` gave over the window's records; the traced interval is
+    the `profile.window` event with the Python tracer off; each idle gap
+    names its own causes, and the idle shares add up."""
+    cell = "qwen7b-int8-chat-rate"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", cell, "--rehearse",
+         "--trace", "2", "--seed", str(2**31 + 5)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600,
+    )
+    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
+    phases = [ln.get("phase") for ln in lines[:-1]]
+    for phase in ("checkpoint", "scale_from_zero", "window_open", "stop", "window", "trace", "logits", "end_to_end"):
+        assert phase in phases, phases
+    assert phases.index("stop") < phases.index("trace")  # read after the operator has gone, beside the logits child
+    last, trace, window = lines[-1], lines[phases.index("trace")], lines[phases.index("window")]
+    bench = resultline.load_benchmark()
+    layer = resultline.declared(bench, cell, 1)
+    assert resultline.problems(last, bench, cell, 2, 1, rehearsal=True, may_miss=set(layer)) == []
+    assert last["correct"] is True and last["device"]["platform"] == "cpu"
+    # Both kinds, the end-to-end ones from the one function that computes them.
+    e2e = lines[phases.index("end_to_end")]
+    for name in resultline.declared(bench, cell, 0):
+        assert last["metrics"][name]["value"] == e2e[name]
+    assert {"idle_exposed_host_pct.rate", "idle_in_fetch_pct.rate", "host_work_per_chunk_ms.rate"} <= set(last["metrics"])
+    # The interval is the capture's own event, with the Python tracer off,
+    # and the capture began only after the window's last record had closed.
+    assert trace["window_from"] == "host event 'profile.window'" and trace["python_tracer"] is False
+    assert 0 <= trace["last_record_closed_s"] <= trace["capture_began_s"]
+    assert last["device"]["window_s"] == pytest.approx(4.0, abs=0.1)
+    # Every idle piece under the segment beside it: the shares add up to the idle share.
+    table = trace["idle_by_host"]
+    assert table["n_segments"] > 0 and sum(table["idle_by_cause_s"].values()) == pytest.approx(table["idle_s"])
+    idle_pct = 100.0 * (1 - last["device"]["busy_s"] / last["device"]["window_s"])
+    under_idle = 100.0 * table["idle_by_cause_s"].get("idle", 0.0) / table["window_s"]
+    split = last["metrics"]["idle_exposed_host_pct.rate"]["value"] + last["metrics"]["idle_in_fetch_pct.rate"]["value"]
+    assert split + under_idle == pytest.approx(idle_pct, abs=0.1)
+    gaps = last["breakdown"]["idle_gaps"]
+    assert gaps and all("host: " in g[0] and "dominant stall cause" not in g[0] and len(g[0]) <= 200 for g in gaps)
+    # The window's line keeps where the longest silence lay and the engine's slowest steps inside it.
+    assert 0 <= window["longest_silence_at_s"] < 8 and all(-0.5 <= s["at_s"] < 9.5 for s in window["slowest_steps"])
+    assert window["slowest_steps"] and {"kind", "total_ms", "ms"} <= set(window["slowest_steps"][0])
+
+
 def test_rehearsal_of_a_traced_run(tmp_path):
     """--rehearse --trace 1, every phase, on the CPU at a tiny size: the
     accepted harness reads this PR's metrics from this PR's program."""
@@ -310,11 +444,13 @@ SWA_ROOFLINES = {
 def test_the_window_cells_metrics_are_its_own():
     bench = resultline.load_benchmark()
     mine = resultline.declared(bench, SWA_CELL, True)
-    assert len(mine) == 23 and SWA_ROOFLINES <= set(mine)
+    shared = {"idle_exposed_host_pct", "idle_in_fetch_pct"}  # PR 38: one reader in every saturated cell
+    assert len(mine) == 23 + len(shared) and SWA_ROOFLINES | shared <= set(mine)
     assert {"kv_pages_peak_pct.full", "kv_pages_peak_pct.window", "window_mfu.swa", "decode_step_roofline.swa"} <= set(mine)
     # No other cell carries them, and this cell none of theirs.
     for m in bench["per_layer"]:
-        assert (SWA_CELL in m["workloads"]) == (m["workloads"] == [SWA_CELL]), m["name"]
+        if m["name"] not in shared:
+            assert (SWA_CELL in m["workloads"]) == (m["workloads"] == [SWA_CELL]), m["name"]
     assert set(resultline.declared(bench, SWA_CELL, False)) == {"output_tok_s", "setup_s"}
     for name in mine:
         assert os.path.exists(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")), name

@@ -67,13 +67,11 @@ def test_causes_sum_to_the_loops_wall_time():
 
 
 @pytest.mark.parametrize("python_tracer", ["default", "0"])
-def test_profiler_capture_holds_the_segments_on_the_scheduler_line(tmp_path, monkeypatch, python_tracer):
+def test_profiler_capture_holds_the_segments_on_the_scheduler_line(tmp_path, python_tracer):
     from jax.profiler import ProfileData
 
-    if python_tracer == "0":
-        monkeypatch.setenv("KUBEAI_PROFILE_PYTHON_TRACER", "0")
-    else:
-        monkeypatch.delenv("KUBEAI_PROFILE_PYTHON_TRACER", raising=False)
+    # The call chooses the tracer (/debug/profile?python_tracer=0|1).
+    choice = {} if python_tracer == "default" else {"python_tracer": False}
 
     eng = build_test_engine()
     eng.start()
@@ -82,7 +80,7 @@ def test_profiler_capture_holds_the_segments_on_the_scheduler_line(tmp_path, mon
             t.join()
         box = {}
         cap = threading.Thread(
-            target=lambda: box.update(perf_obs.ProfilerCapture(str(tmp_path)).capture(1.0))
+            target=lambda: box.update(perf_obs.ProfilerCapture(str(tmp_path)).capture(1.0, **choice))
         )
         cap.start()
         time.sleep(0.2)
@@ -93,6 +91,9 @@ def test_profiler_capture_holds_the_segments_on_the_scheduler_line(tmp_path, mon
         eng.stop()
     files = glob.glob(os.path.join(box["trace_dir"], "**", "*.xplane.pb"), recursive=True)
     assert len(files) == 1, files
+    # The reply names what a reader cannot guess.
+    assert box["xplane"] == files[0] and box["window_event"] == "profile.window"
+    assert box["python_tracer"] is (python_tracer == "default")
     data = ProfileData.from_file(files[0])
     host = next(p for p in data.planes if p.name == "/host:CPU")
     lines = [ln for ln in host.lines if any(ev.name.startswith("sched.") for ev in ln.events)]
