@@ -57,7 +57,7 @@ from kubeai_tpu.engine import kvstate
 from kubeai_tpu.engine.tokenizer import IncrementalDetokenizer
 from kubeai_tpu.metrics import default_registry
 from kubeai_tpu.models import family
-from kubeai_tpu.models.base import ModelConfig
+from kubeai_tpu.models.base import LiveRows, ModelConfig
 from kubeai_tpu.obs import default_recorder
 from kubeai_tpu.obs import perf as perf_obs
 from kubeai_tpu.obs.tenants import default_accountant as tenant_accountant
@@ -579,6 +579,13 @@ class Engine:
             "fused decode slot-steps by state; batch utilization = "
             "active / (active + idle)",
         )
+        self.m_decode_rows = default_registry.counter(
+            "kubeai_engine_decode_rows_total",
+            "rows x steps of the dispatched decode chunks by state: live (rows "
+            "active in the dispatched mask) | idle (the rest of the batch)",
+        )
+        for state in ("live", "idle"):
+            self.m_decode_rows.inc(0, labels={"state": state})  # scraped as 0, not absent
         self.m_epilogue = default_registry.counter(
             "kubeai_engine_decode_epilogue_chunks_total",
             "dispatched decode chunks by optional part of the step's epilogue "
@@ -793,8 +800,20 @@ class Engine:
                 kind: int(self.m_prefill_rows.value(labels={"kind": kind}))
                 for kind in ("real", "duplicate")
             },
+            # Rows x steps of the decode chunks dispatched so far, by the
+            # `active` mask they were given, and the live share of them
+            # (kubeai_engine_decode_rows_total).
+            "decode_rows": self._decode_rows_report(),
             "stall": self._stall.report(),
         }
+
+    def _decode_rows_report(self) -> dict:
+        rows = {
+            state: int(self.m_decode_rows.value(labels={"state": state}))
+            for state in ("live", "idle")
+        }
+        total = rows["live"] + rows["idle"]
+        return {**rows, "live_share": round(rows["live"] / total, 4) if total else None}
 
     def pipeline_report(self) -> dict:
         """The GET /debug/pipeline payload: windowed stall attribution
@@ -2772,6 +2791,11 @@ class Engine:
                 **lora_args,
             )
         self._adm_mask[:] = False
+        n_live = int(self._h_active.sum())
+        self.m_decode_rows.inc(self.cfg.decode_chunk * n_live, labels={"state": "live"})
+        self.m_decode_rows.inc(
+            self.cfg.decode_chunk * (self.cfg.max_slots - n_live), labels={"state": "idle"}
+        )
         snapshot = [
             (i, s, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
@@ -3536,6 +3560,15 @@ def build_step_functions(
         )
         lengths = jnp.where(adm_mask, adm_len, lengths)
         last_tokens = jnp.where(adm_mask, adm_toks, last_tokens)
+        # The model's layers run on the live slots' rows first and the
+        # paged kernel walks only those. The order is made once a
+        # dispatch (`active` is the same for all K steps), and what
+        # does not change inside a chunk is taken in it out here; the
+        # step's tokens and lengths once a step, below. The model hands
+        # its hidden state back in slot order, so everything from the
+        # logits on is untouched.
+        live = LiveRows.first(active)
+        tables_live, lora_rows_live = live.take(tables, lora_rows)
 
         def body(carry, _):
             cache, hist, lengths, last, keys = carry
@@ -3550,9 +3583,10 @@ def build_step_functions(
             hist = hist.at[rows, lengths].set(
                 jnp.where(active, last, hist[rows, lengths])
             )
+            last_live, lengths_live = live.take(last, lengths)
             logits, cache = model.decode_step_paged(
-                params, mc, last[:, None], cache, tables, lengths,
-                lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
+                params, mc, last_live[:, None], cache, tables_live, lengths_live,
+                lora=lora, lora_rows=lora_rows_live, tp_mesh=mesh, live=live,
             )
             cache, counters = split_counters(cache)
             with jax.named_scope("sampling"):

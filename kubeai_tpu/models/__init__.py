@@ -4,7 +4,13 @@ A family module gives `init_params`, `params_from_hf`, `init_paged_cache`,
 `cached_attention_route`, `prefill_paged_cold`, `prefill_paged`,
 `decode_step_paged`, `refuse_unsupported`, `window_pool_tokens` and the
 rules `REUSE_WHOLE_PREFILL_CALLS` and `KV_PARK`; nothing but the
-published `model_type` chooses it."""
+published `model_type` chooses it. `decode_step_paged` takes the
+dispatch's `live` (`models/base.py::LiveRows`) with every per-row
+argument already in its order, live rows first (the engine's decode
+program gathers them: tokens, lengths, adapter rows, the block table):
+a family puts the hidden state back in slot order before its final norm
+(`live.restore`) and does with `live.count` what its kernel can (the
+ragged kernel stops there, `models/deepseek.py` says what its does)."""
 
 from kubeai_tpu.models.base import ModelConfig
 

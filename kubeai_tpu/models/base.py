@@ -6,6 +6,9 @@ import dataclasses
 import json
 import os
 from dataclasses import dataclass
+from typing import NamedTuple
+
+import jax.numpy as jnp
 
 from kubeai_tpu.ops.rope import RopeScaling
 
@@ -283,3 +286,35 @@ def layout_period(*layouts: tuple[int, ...]) -> int:
     return next(
         (p for p in range(1, n) if all(layout[i] == layout[i % p] for layout in layouts for i in range(n))), max(n, 1)
     )
+
+
+class LiveRows(NamedTuple):
+    """A decode step's rows with the live slots first, from the dispatch's
+    `active` mask. `engine/core.py::decode_fn` makes it once a chunk and
+    `take`s every per-row argument of the model step in that order; a
+    family's `decode_step_paged` given one hands `count` to a paged
+    kernel that can stop there and puts the hidden state back in slot
+    order (`restore`) before the final norm, so nothing `[B, V]` moves.
+    With every slot live the order is the identity and the count is B."""
+
+    order: jnp.ndarray  # [B] int32: row i of the step is slot order[i]'s
+    inverse: jnp.ndarray  # [B] int32: slot s is row inverse[s] of the step
+    count: jnp.ndarray  # [] int32: live slots, the first rows of the step
+
+    @classmethod
+    def first(cls, active: jnp.ndarray) -> "LiveRows":
+        """Live slots first, each group in slot order (no sort: a running
+        count places every slot)."""
+        live = active.astype(jnp.int32)
+        count = live.sum()
+        inverse = jnp.where(active, jnp.cumsum(live) - 1, count + jnp.cumsum(1 - live) - 1)
+        order = jnp.zeros_like(inverse).at[inverse].set(jnp.arange(live.shape[0], dtype=jnp.int32))
+        return cls(order, inverse, count)
+
+    def take(self, *arrays):
+        """Each of *arrays* ([B, ...] in slot order, or None) in the step's order."""
+        return tuple(None if a is None else a[self.order] for a in arrays)
+
+    def restore(self, x: jnp.ndarray) -> jnp.ndarray:
+        """*x* ([B, ...] in the step's order) back in slot order."""
+        return x[self.inverse]

@@ -57,6 +57,14 @@ shared prefix shorter than the largest bucket is recomputed.
 the repo's half-rotation `apply_rope` computes the same rotation, and q.k
 is invariant under a permutation applied to both.
 
+**Decode rows arrive live slots first** (`models/base.py::LiveRows`, as
+every family's do): the layers run on them in that order and the hidden
+state goes back to slot order before the final norm. What this family
+does NOT do with the count: `ops/mla_attention.py`'s kernel still walks
+every row (its slot loop carries a page-copy pipeline from slot to slot),
+and an idle row still reads the experts its garbage chooses; both wait
+for a cell below its knee on an expert configuration to judge them by.
+
 **Program counters.** The cache a call returns carries, beside `kv`, the
 scalar `moe_hits`: how many (layer, expert) pairs got at least one row in
 this call. The step programs (`engine/core.py`) take it off the cache and
@@ -318,6 +326,7 @@ def apply(
     left_aligned: bool = False,  # caller guarantees positions == arange(S)
     forced_choices: jnp.ndarray | None = None,  # [expert layers, B*S, k]: route by these (debug)
     return_choices: bool = False,  # also return the router's choices (debug; no timed program asks)
+    live=None,  # models/base.py::LiveRows of a decode step whose rows arrive live slots first
     **unsupported,  # what llama.apply takes and this family does not run (return_hidden, lora, ...)
 ):
     """Run the decoder over the paged latent pool. Returns (logits,
@@ -411,6 +420,8 @@ def apply(
             ),
         )
 
+    if live is not None:
+        x = live.restore(x)  # slot order again, before anything [B, V]
     x = rms_norm(x, params["final_norm"], eps)
     with jax.named_scope("lm_head"):
         if logits_idx is not None:
@@ -448,11 +459,14 @@ def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=N
     )
 
 
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
+def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None, **debug):
     """One decode step for [B, 1] tokens at positions *lengths* [B].
-    Returns (logits [B, 1, V], pool)."""
+    Returns (logits [B, 1, V], pool). With *live* (models/base.py::LiveRows)
+    every per-row argument arrives in its order, live rows first, and the
+    logits come back in slot order (the module docstring: the count goes
+    no further; *debug*'s choices stay in the step's order)."""
     _refuse_lora(lora)
-    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, **debug)
+    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, live=live, **debug)
 
 
 # Appended, so that no line above moves (a Pallas program's cache key holds

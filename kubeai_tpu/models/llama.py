@@ -360,6 +360,7 @@ def apply(
     # paged kernels run per shard under jax.shard_map (a Mosaic kernel
     # cannot be partitioned by GSPMD), q and the pool split on their
     # head axes as parallel/sharding.py's specs say.
+    live=None,  # models/base.py::LiveRows of a paged decode step whose rows arrive live slots first
 ):
     """Run the decoder. Returns (logits, new_cache).
 
@@ -568,17 +569,21 @@ def apply(
             if use_paged_kernel:
                 from kubeai_tpu.ops.paged_attention import paged_attention_ragged
 
+                # The live rows' count, where the caller gave one, is one
+                # more whole operand on every shard.
+                n_live = () if live is None else (live.count,)
                 with jax.named_scope("attn.kernel"):
                     attn_out = per_tp_shard(
-                        lambda q_, kv_, table_, lens_: paged_attention_ragged(
+                        lambda q_, kv_, table_, lens_, *n_: paged_attention_ragged(
                             q_, kv_, table_, lens_,
                             scale=config.query_scale,
                             softcap=config.attn_softcap,
                             k_scale=kq_scale if kv_quant else None,
                             v_scale=vq_scale if kv_quant else None,
+                            live_rows=n_[0] if n_ else None,
                         ),
-                        n_head_split=2, n_replicated=2,
-                    )(q, kv_full, table_l, positions[:, -1] + 1)  # keys 0..last pos inclusive
+                        n_head_split=2, n_replicated=2 + len(n_live),
+                    )(q, kv_full, table_l, positions[:, -1] + 1, *n_live)  # keys 0..last pos inclusive
             elif use_flash:
                 # Prefill positions are arange(S): the cache columns 0..S-1
                 # were just written with exactly k/v, so plain causal over
@@ -668,6 +673,8 @@ def apply(
         x, _ = jax.lax.scan(step_nocache, x, (params["layers"], lora_xs, sliding_flags))
         new_cache = None
 
+    if live is not None:
+        x = live.restore(x)  # slot order again, before anything [B, V]
     x = rms_norm(x, params["final_norm"] + norm_offset, config.rms_norm_eps)
     if return_hidden:
         return x.astype(jnp.float32), new_cache
@@ -786,12 +793,14 @@ def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=N
     )
 
 
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None):
+def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None):
     """One paged decode step for [B, 1] tokens at positions *lengths* [B].
-    Returns (logits [B, 1, V], pool)."""
+    Returns (logits [B, 1, V], pool). With *live* (models/base.py::LiveRows)
+    every per-row argument arrives in its order, live rows first: the
+    paged kernel walks those only and the logits come back in slot order."""
     return apply(
         params, config, tokens, lengths[:, None].astype(jnp.int32), pool,
-        lora=lora, lora_rows=lora_rows, page_table=page_table, tp_mesh=tp_mesh,
+        lora=lora, lora_rows=lora_rows, page_table=page_table, tp_mesh=tp_mesh, live=live,
     )
 
 

@@ -288,6 +288,7 @@ def apply(
     left_aligned: bool = False,  # caller guarantees positions == arange(S)
     forced_choices: jnp.ndarray | None = None,  # [L, B*S, k]: route by these (debug)
     return_choices: bool = False,  # also return the routers' choices (debug; no timed program asks)
+    live=None,  # models/base.py::LiveRows of a decode step whose rows arrive live slots first
     **unsupported,  # what llama.apply takes and this family does not run (return_hidden, lora, ...)
 ):
     """Run the decoder over the two paged pools. Returns (logits, cache)
@@ -344,6 +345,7 @@ def apply(
         if route == "paged_kernel":
             return paged_attention_ragged(
                 q, pool, table + row0, kv_len, sliding_window=window if kind else None,
+                live_rows=None if live is None else live.count,
             )
         gathered = pool[table + row0]  # [B, columns, page, 2Kv, h]
         n_keys = table.shape[1] * page
@@ -416,6 +418,8 @@ def apply(
         ),
     )
 
+    if live is not None:
+        x = live.restore(x)  # slot order again, before anything [B, V]
     x = rms_norm(x, params["final_norm"], eps)
     with jax.named_scope("lm_head"):
         if logits_idx is not None:
@@ -453,8 +457,12 @@ def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=N
     )
 
 
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
+def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None, **debug):
     """One decode step for [B, 1] tokens at positions *lengths* [B].
-    Returns (logits [B, 1, V], pools)."""
+    Returns (logits [B, 1, V], pools). With *live* (models/base.py::LiveRows)
+    every per-row argument arrives in its order, live rows first (both
+    halves of a table row together): both kinds of layer hand the kernel
+    the one count and the logits come back in slot order (*debug*'s
+    choices stay in the step's order)."""
     _refuse_lora(lora)
-    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, **debug)
+    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, live=live, **debug)

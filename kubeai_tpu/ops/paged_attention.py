@@ -9,9 +9,14 @@ causally with pages streamed HBM->VMEM — no gathered contiguous copy of
 the KV span (the portable XLA path in models/llama.py gathers;
 acceptable on CPU tests, wasteful on a bandwidth-bound TPU).
 
-On non-TPU backends this dispatches to the library's pure-JAX reference
-implementation (identical semantics), so the engine's kernel path is
-CPU-testable end-to-end.
+At decode (one query row a slot) a caller whose live rows come first
+hands in their count (`live_rows`, `models/base.py::LiveRows`): it is the
+kernel's `num_seqs`, so the rows past it are neither copied nor scored,
+and they come back as zeros.
+
+On non-TPU backends this dispatches to a jit-safe twin of the library's
+pure-JAX reference implementation (identical semantics, the count
+included), so the engine's kernel path is CPU-testable end-to-end.
 """
 
 from __future__ import annotations
@@ -63,11 +68,15 @@ def paged_attention_ragged(
     v_scale: float | None = None,  # (int8/fp8) pools; None = pool is bf16
     blocks: tuple[int, int] | None = None,  # a sweep's (kv pages, queries); serving leaves it None
     sliding_window: int | None = None,  # static: a query sees keys j > i - sliding_window only
+    live_rows: jnp.ndarray | None = None,  # [] int32, S == 1 only: rows [0, live_rows) hold requests
 ) -> jnp.ndarray:
     """Returns [B, S, H, h] attention output. With a quantized pool the
     kernel dequantizes pages in-VMEM (x.astype(f32) * scale -> q.dtype),
-    so HBM page traffic stays 8-bit."""
+    so HBM page traffic stays 8-bit. With *live_rows* the kernel walks
+    that many table rows and the rest of the output is zeros."""
     B, S, H, h = q.shape
+    if live_rows is not None and S != 1:
+        raise ValueError(f"live_rows is for calls of one query row a slot, not S={S}")
     max_pages = page_table.shape[1]
     page, Kv = kv_pages.shape[1], kv_pages.shape[2] // 2
     if scale is None:
@@ -79,7 +88,14 @@ def paged_attention_ragged(
     # span (writes went to the trash page); clamp so the kernel never
     # walks past the table width.
     kv_lens = jnp.minimum(kv_lengths, max_pages * page).astype(jnp.int32)
-    num_seqs = jnp.asarray([B], jnp.int32)
+    if live_rows is None:
+        num_seqs = jnp.asarray([B], jnp.int32)
+    else:
+        # Never 0: the kernel starts copying row 0's first block before
+        # it looks at the count, and only a walked row waits for its
+        # copy. With no live row (the warm-up's dispatch) row 0 is
+        # walked as every idle row was, and masked below like the rest.
+        num_seqs = jnp.clip(live_rows, 1, B).astype(jnp.int32).reshape(1)
 
     # The kernel's window only masks: it copies every page from the
     # table's first to the sequence's end, so a caller with a window
@@ -121,7 +137,13 @@ def paged_attention_ragged(
         v_scale=v_scale,
         **tuning,
     )
-    return out.reshape(B, S, H, h).astype(q.dtype)
+    out = out.reshape(B, S, H, h).astype(q.dtype)
+    if live_rows is not None:
+        # The kernel leaves the skipped rows' output blocks as they were
+        # (whatever the buffer held, a NaN perhaps); nothing reads an
+        # idle slot's token, but its row still runs through the layers.
+        out = jnp.where(jnp.arange(B)[:, None, None, None] < live_rows, out, 0)
+    return out
 
 
 def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, sm_scale, soft_cap=None, k_scale=None, v_scale=None, sliding_window=None):
@@ -129,10 +151,11 @@ def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, s
     signature (the library's pure-JAX reference uses Python loops over
     traced bounds, so it only runs eagerly; tests compare this twin
     against it with concrete values). Assumes the wrapper's uniform
-    query split (cu_q_lens = arange * S)."""
+    query split (cu_q_lens = arange * S). Table rows from num_seqs[0] on
+    are not attended: their output rows are zeros (the reference returns
+    none for them, the kernel leaves them unwritten)."""
     from kubeai_tpu.ops.attention import attention
 
-    del num_seqs  # every table row is a live slot in the engine's usage
     B = int(page_indices.shape[0])
     S = q_flat.shape[0] // B
     H, h = q_flat.shape[1], q_flat.shape[2]
@@ -154,6 +177,6 @@ def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, s
     mask = jnp.arange(skv)[None, None, :] <= pos_q[:, :, None]
     if sliding_window is not None:
         mask = jnp.logical_and(mask, jnp.arange(skv)[None, None, :] > pos_q[:, :, None] - sliding_window)
-    return attention(
-        q, k_att, v_att, mask, scale=sm_scale, softcap=soft_cap or 0.0
-    ).reshape(B * S, H, h)
+    out = attention(q, k_att, v_att, mask, scale=sm_scale, softcap=soft_cap or 0.0)
+    walked = jnp.arange(B)[:, None, None, None] < num_seqs[0]
+    return jnp.where(walked, out, 0).reshape(B * S, H, h)

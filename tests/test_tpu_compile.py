@@ -22,7 +22,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from kubeai_tpu.models import llama
-from kubeai_tpu.models.base import ModelConfig
+from kubeai_tpu.models.base import LiveRows, ModelConfig
 from kubeai_tpu.ops.flash_attention import flash_attention_tpu
 from kubeai_tpu.ops.paged_attention import paged_attention_ragged
 
@@ -147,6 +147,22 @@ def test_ragged_paged_kernel_lowers(v5e, heads, B, S, pool_dtype, max_len):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize(
+    "heads,B,max_len,window",
+    [(QWEN25_7B, SLOTS, 2048, None), (LLAMA3_8B, 8, 8192, None), (QWEN25_7B_TP4, SLOTS, 2048, None), (QWEN25_7B, 24, 16384, 4096)],
+    ids=["qwen2.5-7b/decode", "mistral-7b/decode-8x8192", "qwen2.5-7b/tp4/decode", "smallthinker/decode-window"],
+)
+def test_ragged_paged_kernel_lowers_with_the_live_rows_count_as_an_operand(v5e, heads, B, max_len, window):
+    """Decode as the engine calls it since it puts live rows first: the
+    count is a traced scalar (the kernel's `num_seqs`), the rows past it
+    are masked to zeros behind the kernel."""
+    text = _compile(
+        lambda q, kv, tbl, lens, n: paged_attention_ragged(q, kv, tbl, lens, sliding_window=window, live_rows=n),
+        *_paged_args(v5e[0], B, 1, heads, max_len=max_len), _sds(v5e, (), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 def test_ragged_paged_kernel_fits_vmem_at_8192(v5e):
     """deploy/models/llama-3.1-8b-instruct-tpu.yaml serves at
     --max-seq-len 8192 (128 pages a sequence). At the library's untuned
@@ -203,14 +219,16 @@ def test_tp4_decode_step_keeps_the_kernel(v5e):
     B, max_pages = 8, 2048 // PAGE
     pool_spec = paged_cache_specs()["kv"]
     pool = {"kv": on_mesh((B * max_pages + 1, PAGE, 2 * Kv, H_DIM), jnp.bfloat16, pool_spec)}
+    # As the decode program calls it: rows live slots first, their count
+    # one more replicated operand of the kernel's shard_map.
     compiled = jax.jit(
-        lambda p, t, c, tbl, lens: llama.decode_step_paged(
-            p, mc, t, c, tbl, lens, tp_mesh=mesh
+        lambda p, t, c, tbl, lens, active: llama.decode_step_paged(
+            p, mc, t, c, tbl, lens, tp_mesh=mesh, live=LiveRows.first(active)
         ),
         out_shardings=(NamedSharding(mesh, P()), {"kv": NamedSharding(mesh, pool_spec)}),
     ).lower(
         params, on_mesh((B, 1), jnp.int32), pool,
-        on_mesh((B, max_pages), jnp.int32), on_mesh((B,), jnp.int32),
+        on_mesh((B, max_pages), jnp.int32), on_mesh((B,), jnp.int32), on_mesh((B,), jnp.bool_),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # A quarter of the pool on each chip, not the whole of it.
@@ -349,9 +367,13 @@ def test_one_period_of_smallthinker_decodes_through_both_pools(v5e):
         jax.eval_shape(lambda: smallthinker.init_paged_cache(mc, 3841, PAGE, window_pages=B * 81 + 1)),
     )
     compiled = jax.jit(
-        lambda p, t, c, tbl, lens: smallthinker.decode_step_paged(p, mc, t, c, tbl, lens), donate_argnums=(2,),
+        lambda p, t, c, tbl, lens, active: smallthinker.decode_step_paged(
+            p, mc, t, c, tbl, lens, live=LiveRows.first(active)
+        ),
+        donate_argnums=(2,),
     ).lower(
         params, _sds(v5e, (B, 1), jnp.int32), pools, _sds(v5e, (B, 2 * max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.bool_),
     ).compile()
     # One period in the scan's body: 4 paged kernels and 4 x 3 grouped matmuls.
     assert compiled.as_text().count("tpu_custom_call") >= 16
