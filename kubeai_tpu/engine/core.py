@@ -209,13 +209,22 @@ def table_width(model_config: ModelConfig, cfg: EngineConfig) -> int:
     return engine_dims(cfg)[0] * (2 if window_pool_dims(model_config, cfg)[0] else 1)
 
 
+# What a family with state by slot keeps beside its pools, by the cache
+# dict's keys (models/nemotron_h.py::init_paged_cache).
+SLOT_STATE_KEYS = ("ssm", "conv")
+
+
 def init_pools(model_config: ModelConfig, cfg: EngineConfig):
     """The family's page pool(s) at the engine's dimensions (shared with
-    the AOT warm compiler, as engine_dims is)."""
+    the AOT warm compiler, as engine_dims is), and beside them, for a
+    family whose sequences keep state that is not pages, that state for
+    every slot (models/nemotron_h.py: the slot is its address)."""
+    model = family(model_config)
     window_pages = window_pool_dims(model_config, cfg)[1]
-    return family(model_config).init_paged_cache(
+    return model.init_paged_cache(
         model_config, engine_dims(cfg)[1], cfg.page_size,
         **({"window_pages": window_pages} if window_pages else {}),
+        **({"slots": cfg.max_slots} if getattr(model, "SLOT_STATE", False) else {}),
     )
 
 
@@ -342,6 +351,12 @@ class Engine:
                 model_config, kv_cache_dtype=self.cfg.kv_cache_dtype
             )
         family(model_config).refuse_unsupported(model_config)
+        if self.cfg.prefix_cache_min and not getattr(family(model_config), "PREFIX_REUSE", True):
+            # The family's rule (models/nemotron_h.py): nothing is looked
+            # up and nothing registered; the hit counters stay 0.
+            import dataclasses as _dc
+
+            self.cfg = _dc.replace(self.cfg, prefix_cache_min=0)
         self.model_config = model_config
         self.params = params
         self.tokenizer = tokenizer
@@ -548,7 +563,27 @@ class Engine:
             "only, from each call's start, tokens and the window; counted "
             "for a family with window layers",
         )
+        # State kept by slot beside the pages (a family with recurrent
+        # layers, models/nemotron_h.py; 0 / 0 for every other): there is no
+        # allocator, so a slot's state is in use while the slot is.
+        slot_state = getattr(family(self.model_config), "SLOT_STATE", False)
+        state_used_fn = lambda: float(sum(s is not None for s in self._slots)) if slot_state else 0.0  # noqa: E731
+        state_total_fn = lambda: float(self.cfg.max_slots) if slot_state else 0.0  # noqa: E731
+        self.m_state_used = default_registry.callback_gauge(
+            "kubeai_engine_state_slots_used",
+            "slots whose per-slot state (recurrent state and convolution "
+            "tail, beside the slot's pages) belongs to a live request",
+            state_used_fn,
+        )
+        self.m_state_total = default_registry.callback_gauge(
+            "kubeai_engine_state_slots_total",
+            "slots that own per-slot state beside their pages (0: the "
+            "model keeps nothing but pages)",
+            state_total_fn,
+        )
         self._gauge_callbacks = [
+            (self.m_state_used, state_used_fn),
+            (self.m_state_total, state_total_fn),
             (self.m_wpages_used, wpages_used_fn),
             (self.m_wpages_total, wpages_total_fn),
             (self.m_hbm_used, hbm_used_fn),
@@ -620,6 +655,13 @@ class Engine:
             "kubeai_engine_moe_assignments_total",
             "(token row, chosen expert) pairs the step programs computed, "
             "padding rows and idle slots included, by phase",
+        )
+        self.m_moe_absent = default_registry.counter(
+            "kubeai_engine_moe_assignments_absent_total",
+            "of kubeai_engine_moe_assignments_total, the pairs whose expert "
+            "this chip does not hold (a chip's share of the experts: dropped "
+            "before the rows are gathered), by phase; 0 for a chip that "
+            "holds every expert its router scores",
         )
         self.m_tok_rate = default_registry.gauge(
             "kubeai_engine_tokens_per_second",
@@ -774,6 +816,11 @@ class Engine:
             # the pool's own size over its tokens (a latent page is a
             # page of another width).
             "kv_bytes_per_token": kv_bytes_per_token,
+            # ... and what a slot owns outside its pages, whatever its
+            # length (models/nemotron_h.py; 0: nothing but pages).
+            "state_bytes_per_slot": int(
+                sum(v.nbytes for k, v in self._cache.items() if k in SLOT_STATE_KEYS) // self.cfg.max_slots
+            ),
             # ... and by kind of layer where the window layers keep a
             # pool of their own (the line above is then the full layers').
             **(
@@ -2257,12 +2304,17 @@ class Engine:
         if "moe_hits" not in counters:
             return
         mc = self.model_config
-        layers = mc.num_layers - min(mc.first_k_dense_replace, mc.num_layers)
+        # Expert layers: a pattern's `E` blocks (models/nemotron_h.py), else
+        # every layer behind the leading dense ones. `n_routed_experts` is
+        # what THIS chip holds: possible reads are of held experts.
+        layers = mc.layer_pattern.count("E") or mc.num_layers - min(mc.first_k_dense_replace, mc.num_layers)
         steps = self.cfg.decode_chunk if phase == "decode" else 1
         labels = {"phase": phase}
         self.m_moe_hit.inc(int(counters["moe_hits"]), labels=labels)
         self.m_moe_possible.inc(mc.n_routed_experts * layers * steps, labels=labels)
         self.m_moe_assign.inc(rows * mc.num_experts_per_tok * layers, labels=labels)
+        if "moe_absent" in counters:
+            self.m_moe_absent.inc(int(counters["moe_absent"]), labels=labels)
 
     def _count_attn_pairs(self, phase: str, starts: np.ndarray, n: int) -> None:
         """kubeai_engine_attn_pairs_total for calls of *n* real queries
@@ -3449,9 +3501,10 @@ def build_step_functions(
         scalar program counters in the cache dict (models/deepseek.py:
         `moe_hits`). They leave the program as its LAST output and never
         enter one: ({"kv": pool}, {name: scalar}); a second pool
-        (models/smallthinker.py: `kv_window`) stays with the first. A
-        family without counters gives {}: no output at all."""
-        pools = {k: v for k, v in cache.items() if k.startswith("kv")}
+        (models/smallthinker.py: `kv_window`) stays with the first, and
+        so does state kept by slot (models/nemotron_h.py: `ssm`, `conv`).
+        A family without counters gives {}: no output at all."""
+        pools = {k: v for k, v in cache.items() if k.startswith("kv") or k in SLOT_STATE_KEYS}
         return pools, {k: v for k, v in cache.items() if k not in pools}
 
     def mask_pad(logits):
@@ -3461,6 +3514,9 @@ def build_step_functions(
 
     mtk = cfg.max_top_k
     topn = max(1, cfg.top_logprobs_k)
+    # A family with state by slot is told the slot of every prefill row
+    # (a decode step's rows ARE the slots, in `live`'s order).
+    slot_state = getattr(model, "SLOT_STATE", False)
 
     def prefill_batch_fn(params, tokens, lengths, tables, slots, seeds, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_rows=None):
         """Cold prefill for N requests in ONE call (N is one of two
@@ -3476,6 +3532,7 @@ def build_step_functions(
         logits, cache = model.prefill_paged_cold(
             params, mc, tokens, cache, tables, lengths,
             lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
+            **({"slots": slots} if slot_state else {}),
         )
         cache, counters = split_counters(cache)
         with jax.named_scope("sampling"):
@@ -3501,6 +3558,7 @@ def build_step_functions(
             lora=lora,
             lora_rows=None if lora_row is None else lora_row[None],
             tp_mesh=mesh,
+            **({"slots": slot[None]} if slot_state else {}),
         )
         cache, counters = split_counters(cache)
         with jax.named_scope("sampling"):

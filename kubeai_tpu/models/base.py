@@ -105,6 +105,30 @@ class ModelConfig:
     sliding_window_size: int = 0
     sliding_window_layout: tuple[int, ...] = ()
     rope_layout: tuple[int, ...] = ()
+    # Nemotron-H family (model_type "nemotron_h", models/nemotron_h.py;
+    # read for that family only). `layer_pattern`: one character a block,
+    # `M` a Mamba-2 mixer, `*` attention, `E` experts; each block is one of
+    # the three alone. The mixer: `mamba_num_heads` heads of
+    # `mamba_head_dim`, B and C in `ssm_groups` groups of `ssm_state_size`,
+    # a depthwise convolution of `conv_kernel` taps, prefill in chunks of
+    # `ssm_chunk`. The experts work in a latent space of `moe_latent_size`
+    # (no gate matrix; n_routed_experts, moe_intermediate_size,
+    # num_experts_per_tok, norm_topk_prob, routed_scaling_factor above)
+    # beside one shared expert of `moe_shared_intermediate_size` on the
+    # hidden state. A chip that holds a SHARE of the experts holds
+    # `n_routed_experts` of them from `experts_first` on, of the
+    # `router_experts` the router scores (0: it holds them all).
+    layer_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 0
+    conv_kernel: int = 0
+    ssm_chunk: int = 0
+    moe_latent_size: int = 0
+    moe_shared_intermediate_size: int = 0
+    router_experts: int = 0
+    experts_first: int = 0
 
     @property
     def head_dim_(self) -> int:
@@ -172,6 +196,8 @@ class ModelConfig:
             gemma_kw = _deepseek_v3_keys(get)
         if model_type == "smallthinker":
             gemma_kw = _smallthinker_keys(get)
+        if model_type == "nemotron_h":
+            gemma_kw = _nemotron_h_keys(get)
         kw = dict(
             model_type=model_type,
             vocab_size=config.vocab_size,
@@ -275,6 +301,72 @@ def _smallthinker_keys(get) -> dict:
         norm_topk_prob=True,
         sliding_window_size=int(window),
         **layouts,
+    )
+
+
+def _nemotron_h_keys(get) -> dict:
+    """The Nemotron-H keys of a published config.json as ModelConfig
+    fields. What models/nemotron_h.py does not compute is refused here,
+    by name. The pattern may be longer than the depth (a checkpoint cut
+    in depth keeps the published 88 characters): the first
+    `num_hidden_layers` are the model's. `num_nextn_predict_layers` and
+    `mtp_hybrid_override_pattern` (the drafting head) are read by nothing:
+    it changes no served distribution and is not loaded. `rope_theta` and
+    `partial_rotary_factor` likewise: the family's attention layers apply
+    no rotary embedding. `time_step_*` initialise `dt_bias` in training."""
+    L = get("num_hidden_layers")
+    pattern = get("hybrid_override_pattern")
+    if not isinstance(pattern, str) or len(pattern) < L:
+        raise ValueError(f"nemotron_h: hybrid_override_pattern must name each of the {L} blocks")
+    pattern = pattern[:L]
+    if set(pattern) - set("M*E"):
+        raise ValueError(
+            f"nemotron_h: hybrid_override_pattern names blocks other than M, * and E ({sorted(set(pattern) - set('M*E'))}: "
+            "a dense feed-forward block is not supported)"
+        )
+    if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
+        raise ValueError("nemotron_h: group-limited routing (n_group/topk_group > 1) is not supported")
+    if not get("moe_latent_size"):
+        raise ValueError("nemotron_h: experts outside a latent space (no moe_latent_size) are not supported")
+    if (get("n_shared_experts") or 0) != 1:
+        raise ValueError("nemotron_h: n_shared_experts other than 1 is not supported")
+    for key, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu")):
+        if get(key, want) != want:
+            raise ValueError(f"nemotron_h: {key} {get(key)!r} is not supported ({want})")
+    for key in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias", "residual_in_fp32"):
+        if get(key):
+            raise ValueError(f"nemotron_h: {key} is not supported")
+    if not get("use_conv_bias", True):
+        raise ValueError("nemotron_h: use_conv_bias false is not supported")
+    if get("sliding_window"):
+        raise ValueError("nemotron_h: sliding_window is not supported")
+    heads, head_dim = get("mamba_num_heads") or 0, get("mamba_head_dim") or 0
+    groups = get("n_groups") or 0
+    if not heads or not groups or heads % groups or (heads * head_dim) % groups:
+        raise ValueError("nemotron_h: mamba_num_heads must be a whole number of heads for each of n_groups")
+    held, scored = get("n_routed_experts") or 0, get("router_experts") or 0
+    first = get("experts_first") or 0
+    if scored and first + held > scored:
+        raise ValueError(f"nemotron_h: experts {first}..{first + held - 1} are not among the router's {scored}")
+    return dict(
+        intermediate_size=0,  # no dense feed-forward block
+        rms_norm_eps=get("layer_norm_epsilon", 1e-5),
+        layer_pattern=pattern,
+        mamba_num_heads=heads,
+        mamba_head_dim=head_dim,
+        ssm_state_size=get("ssm_state_size"),
+        ssm_groups=groups,
+        conv_kernel=get("conv_kernel"),
+        ssm_chunk=get("chunk_size"),
+        n_routed_experts=held,
+        router_experts=scored,
+        experts_first=first,
+        n_shared_experts=1,
+        moe_intermediate_size=get("moe_intermediate_size") or 0,
+        moe_latent_size=get("moe_latent_size"),
+        moe_shared_intermediate_size=get("moe_shared_expert_intermediate_size") or 0,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
     )
 
 

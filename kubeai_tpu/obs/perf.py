@@ -128,6 +128,8 @@ def param_counts(mc) -> tuple[float, float]:
         return _deepseek_v3_param_counts(mc)
     if getattr(mc, "model_type", "") == "smallthinker":
         return _smallthinker_param_counts(mc)
+    if getattr(mc, "model_type", "") == "nemotron_h":
+        return _nemotron_h_param_counts(mc)
     H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
     attn = D * H * h + 2 * D * Kv * h + H * h * D
     if getattr(mc, "qkv_bias", False):
@@ -186,6 +188,32 @@ def _smallthinker_param_counts(mc) -> tuple[float, float]:
     total = 2 * V * D + D + L * (attn + router + mc.n_routed_experts * expert)
     # Active leaves the embedding table out (a row is looked up).
     active = V * D + D + L * (attn + router + mc.num_experts_per_tok * expert)
+    return float(total), float(active)
+
+
+def _nemotron_h_param_counts(mc) -> tuple[float, float]:
+    """Nemotron-H family (models/nemotron_h.py): blocks that are each a
+    Mamba-2 mixer, attention, or experts in a latent space beside a shared
+    expert. Held: what THIS chip holds (`n_routed_experts` experts a
+    block). Active: what a token is multiplied by on this chip, its
+    `num_experts_per_tok` choices landing here in the ratio of the held
+    experts to the router's width. Nemotron-3-Super at 11 blocks, 128 of
+    512 experts, a quarter of the vocabulary: 4.65G held, 1.04G a token.
+    Held to perfbench/families/nemotron_h_counts.py by
+    tests/test_nemotron_h.py."""
+    D, V = mc.hidden_size, mc.vocab_size
+    Hm, inner = mc.mamba_num_heads, mc.mamba_num_heads * mc.mamba_head_dim
+    C = inner + 2 * mc.ssm_groups * mc.ssm_state_size
+    mixer = D * (inner + C + Hm) + C * mc.conv_kernel + C + 3 * Hm + inner + inner * D + D
+    attn = D * (mc.num_heads + 2 * mc.num_kv_heads) * mc.head_dim_ + mc.num_heads * mc.head_dim_ * D + D
+    R = mc.router_experts or mc.n_routed_experts
+    expert = 2 * mc.moe_latent_size * mc.moe_intermediate_size
+    outside = D * R + R + 2 * D * mc.moe_latent_size + 2 * D * mc.moe_shared_intermediate_size + D
+    n = {k: mc.layer_pattern.count(k) for k in "M*E"}
+    dense = n["M"] * mixer + n["*"] * attn + n["E"] * outside
+    total = 2 * V * D + D + dense + n["E"] * mc.n_routed_experts * expert
+    # Active leaves the embedding table out (a row is looked up).
+    active = V * D + D + dense + n["E"] * mc.num_experts_per_tok * mc.n_routed_experts / R * expert
     return float(total), float(active)
 
 

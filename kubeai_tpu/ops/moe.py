@@ -102,11 +102,20 @@ def route_softmax_topk(r, k: int, forced=None):
     return idx.astype(jnp.int32), jax.nn.softmax(jnp.take_along_axis(r, idx, axis=1), axis=1)
 
 
-def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu):
+def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu, held=None):
     """sum_i weights[t, i] * GLU_{idx[t, i]}(x[t]) for every token, the
     gate's activation *act* (SiLU: SwiGLU; ReLU: SmallThinker's ReGLU):
     x [T, D]; idx, weights [T, k]; wg, wu [E, D, F]; wd [E, F, D].
     Returns (y [T, D] in x's dtype, hit: how many experts got a row).
+
+    Experts WITHOUT a gate matrix (`wg=None`, Nemotron-H) are
+    `act(x[t] Wu) Wd`, *act* on the up-projection.
+
+    With *held* = (first, count, of) this chip holds experts first ..
+    first+count-1 of the `of` the router chose among (wu, wd have `count`
+    groups; idx may name any of the `of`): the layer returns ITS experts'
+    part of the sum (`_held_part`). Nothing stands in for the absent
+    chips: their part of the sum is theirs.
 
     With *layer* (a traced int32) the weights are the WHOLE stack, wg, wu
     [L, E, D, F] and wd [L, E, F, D], and the layer's experts are groups
@@ -115,9 +124,13 @@ def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu):
     it the stack as it lies in memory. (Slicing a layer's experts out
     of the stack first is a copy of all of them, 1.2 GB a layer for
     kanana-2: 20 ms of a 49 ms decode step, PERF.md section 6, PR 33.)"""
+    if held is not None:
+        if layer is not None:
+            raise ValueError("a share of the experts is read from one layer's own arrays, not from a stack")
+        return _held_part(x, idx, weights, wg, wu, wd, act, held)
     T, D = x.shape
     k = idx.shape[1]
-    E = wg.shape[-3]
+    E = wu.shape[-3]
     with jax.named_scope("moe.dispatch"):
         flat = idx.reshape(T * k)
         order = jnp.argsort(flat, stable=True)
@@ -132,9 +145,9 @@ def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu):
     # `moe.experts` holds the grouped matmuls and nothing else: a device
     # trace files their time under it whatever implements them.
     with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(rows, wg, group_sizes)
+        gate = None if wg is None else grouped_matmul(rows, wg, group_sizes)
         up = grouped_matmul(rows, wu, group_sizes)
-    hidden = act(gate) * up
+    hidden = act(up) if gate is None else act(gate) * up
     with jax.named_scope("moe.experts"):
         out = grouped_matmul(hidden, wd, group_sizes)
     with jax.named_scope("moe.combine"):
@@ -145,3 +158,67 @@ def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu):
         rows_back = out[back].reshape(T, k, D).astype(jnp.float32)
         y = (rows_back * weights[:, :, None]).sum(axis=1)
     return y.astype(x.dtype), hit
+
+
+def held_capacity(n: int, count: int, of: int) -> int:
+    """Rows a pass of `_held_part` gathers for *n* assignments where
+    *count* of *of* experts are held: a third more than the held experts'
+    even share, in whole 64-row steps (every row tile `gmm_tiles` picks
+    divides those), and never more than there are assignments."""
+    return min(n, -(-(4 * n * count) // (3 * of * 64)) * 64)
+
+
+def _held_part(x, idx, weights, wg, wu, wd, act, held):
+    """`routed_experts` for a chip that holds experts first .. first+count-1
+    of the `of` the router scores: (this chip's part of y, held experts
+    that got a row). Assignments to experts that are not here are dropped
+    BEFORE any row is gathered: they sort behind every held group and no
+    pass reaches them, so no row, and no zero, of theirs goes through the
+    MXU or through memory.
+
+    Shapes stay static and nothing is ever left out: the held assignments,
+    sorted by expert, are taken `held_capacity` rows a pass, in a
+    `lax.while_loop` that runs while assignments are left (the pass is
+    traced and compiled once). One pass holds them all unless the routing
+    leans on this chip by a third more than its even share; the worst
+    case, every assignment here, is `ceil(rows / capacity)` passes."""
+    first, count, of = held
+    T, D = x.shape
+    k = idx.shape[1]
+    n = T * k
+    C = held_capacity(n, count, of)
+    passes = -(-n // C)
+    with jax.named_scope("moe.dispatch"):
+        flat = idx.reshape(n)
+        here = (flat >= first) & (flat < first + count)
+        key = jnp.where(here, flat - first, count)  # an absent expert's: behind every held group
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        ends = jnp.cumsum(sizes)
+        starts, n_here = ends - sizes, ends[-1]
+        hit = (sizes > 0).sum().astype(jnp.int32)
+        # Where each (token, choice) sits among the sorted assignments.
+        back = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
+        order = jnp.pad(order, (0, passes * C - n))
+
+    def one(carry):
+        lo, y = carry
+        with jax.named_scope("moe.dispatch"):
+            rows = x[jax.lax.dynamic_slice(order, (lo,), (C,)) // k]  # [C, D], sorted by expert
+            group_sizes = jnp.clip(ends - lo, 0, C) - jnp.clip(starts - lo, 0, C)
+        with jax.named_scope("moe.experts"):
+            gate = None if wg is None else grouped_matmul(rows, wg, group_sizes)
+            up = grouped_matmul(rows, wu, group_sizes)
+        hidden = act(up) if gate is None else act(gate) * up
+        with jax.named_scope("moe.experts"):
+            out = grouped_matmul(hidden, wd, group_sizes)
+        with jax.named_scope("moe.combine"):
+            # A (token, choice) whose row this pass computed takes it; rows
+            # past the held assignments were never written and are left out.
+            mine = here & (back >= lo) & (back < lo + C)
+            rows_back = jnp.where(mine[:, None], out[jnp.clip(back - lo, 0, C - 1)], 0).reshape(T, k, D)
+            return lo + C, y + jnp.einsum("tkd,tk->td", rows_back, weights, preferred_element_type=jnp.float32)
+
+    _, y = jax.lax.while_loop(lambda carry: carry[0] < n_here, one, (jnp.zeros((), jnp.int32), jnp.zeros((T, D), jnp.float32)))
+    return y.astype(x.dtype), hit
+

@@ -136,3 +136,45 @@ def test_every_scope_names_operations_in_the_window_family(scoped_swa_programs, 
     # Each kind's kernel scope sits inside its layer's scope: a reader tells them apart by the path.
     for kind in ("attn.full", "attn.window"):
         assert re.search(r'["/]' + re.escape(kind) + "/attn.kernel/", scoped_swa_programs[0][1]), kind
+
+
+# -- the state-space family's module (models/nemotron_h.py, ops/ssm.py) ----------
+
+SSM_SCOPES = (
+    "embed", "ssm", "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out_proj", "attn", "attn.kernel",
+    "moe", "moe.latent_down", "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.latent_up", "moe.shared",
+    "lm_head", "sampling", "logprobs",
+)
+NEMOTRON_H = ModelConfig(
+    model_type="nemotron_h", vocab_size=272, hidden_size=64, intermediate_size=0, num_layers=4, num_heads=4,
+    num_kv_heads=2, head_dim=16, dtype="float32", max_position=256, layer_pattern="ME*M", mamba_num_heads=8,
+    mamba_head_dim=8, ssm_state_size=16, ssm_groups=2, conv_kernel=4, ssm_chunk=8, num_experts_per_tok=3,
+    n_routed_experts=4, router_experts=16, experts_first=4, n_shared_experts=1, moe_intermediate_size=32,
+    moe_latent_size=32, moe_shared_intermediate_size=48, routed_scaling_factor=2.5,
+    use_paged_kernel=True, use_flash_prefill=True,
+)
+
+
+@pytest.fixture(scope="module")
+def scoped_ssm_programs():
+    return _lowered_programs(True, NEMOTRON_H)
+
+
+def test_scopes_change_metadata_only_in_the_state_space_family(scoped_ssm_programs):
+    plain = _lowered_programs(False, NEMOTRON_H)
+    assert len(scoped_ssm_programs) == len(plain) >= 4
+    for i, ((s_text, s_debug), (p_text, p_debug)) in enumerate(zip(scoped_ssm_programs, plain)):
+        assert s_text == p_text, f"program {i}: the computation changed with the scopes"
+        assert s_debug != p_debug, f"program {i}: the scopes left no trace in the metadata"
+
+
+@pytest.mark.parametrize("scope", SSM_SCOPES)
+def test_every_scope_names_operations_in_the_state_space_family(scoped_ssm_programs, scope):
+    rx = re.compile(r'["/]' + re.escape(scope) + "/")
+    for _, debug_text in scoped_ssm_programs[:2]:  # the decode chunk, a prefill
+        assert rx.search(debug_text), scope
+    # The mixer's parts sit inside `ssm`, the experts' inside `moe`: a reader tells them by the path.
+    for inner in ("ssm/ssm.scan", "ssm/ssm.conv", "moe/moe.latent_down", "attn/attn.kernel"):
+        assert re.search(r'["/]' + re.escape(inner) + "/", scoped_ssm_programs[0][1]), inner
+    # ... the share's grouped matmuls inside its loop over passes (ops/moe.py::_held_part).
+    assert re.search(r'["/]moe/while/body/moe\.experts/', scoped_ssm_programs[0][1])

@@ -83,7 +83,9 @@ HOST_WORK = {"sweep", "admit", "prefill", "kv_transfer", "dispatch", "host_overl
 def test_the_idle_gaps_metrics_are_declared_last_and_read_by_one_reader(name):
     bench = resultline.load_benchmark()
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert [m["name"] for m in bench["per_layer"][-4:]] == list(IDLE)  # appended, in the issue's order
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(next(iter(IDLE)))  # appended together, in the issue's order, wherever later PRs' entries stand
+    assert names[at : at + 4] == list(IDLE)
     assert set(entry["workloads"]) == IDLE[name] and entry["layer"] == "scheduler" and entry["source"] == "device_trace"
     assert entry["moves"] == ("tpot_mean_ms" if name.endswith(".rate") else "output_tok_s") and entry["better"] == "lower"
     with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
@@ -180,7 +182,7 @@ def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
 
 def test_every_cell_is_one_of_those_with_a_case_here():
     cells = [w["name"] for w in resultline.load_benchmark()["workloads"]]
-    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL, SWA_CELL])
+    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL, SWA_CELL, SSM_CELL])
 
 
 def test_the_expert_models_cell_declares_its_own_metrics_and_none_of_the_dense_cells():
@@ -569,3 +571,140 @@ def test_rehearsal_of_the_window_models_cell(tmp_path):
     assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
     assert logits["sample"]["window_pages_released"] > 0
     assert 0 < last["metrics"]["kv_pages_peak_pct.window"]["value"] <= 100
+
+
+# -- PR 40: the state-space family's cell and its readers ------------------------
+
+SSM_CELL = "nemotron3super-bf16-agent-sat"
+SSM_ROOFLINES = {"moe_experts_roofline.ssm", "ssm_decode_roofline", "ssm_prefill_roofline"}
+SSM_NEW = SSM_ROOFLINES | {"decode_ssm_share_pct", "prefill_ssm_share_pct", "decode_step_roofline.ssm", "window_mfu.ssm"}
+
+
+def test_the_state_space_cells_metrics_are_its_own():
+    bench = resultline.load_benchmark()
+    mine = resultline.declared(bench, SSM_CELL, True)
+    assert len(mine) == 23 and SSM_NEW <= set(mine)
+    assert all(n.endswith(".ssm") for n in set(mine) - SSM_NEW)  # twins of accepted readers, under its suffix
+    # No other cell carries them, this cell none of theirs, and they stand last, appended.
+    names = [m["name"] for m in bench["per_layer"]]
+    assert set(names[-23:]) == set(mine)
+    for m in bench["per_layer"]:
+        assert (SSM_CELL in m["workloads"]) == (m["workloads"] == [SSM_CELL]), m["name"]
+    assert set(resultline.declared(bench, SSM_CELL, False)) == {"output_tok_s", "setup_s"}
+    for name in mine:
+        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py")), name
+    cell = next(w for w in bench["workloads"] if w["name"] == SSM_CELL)
+    config = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    assert (cell["traffic"], cell["chips"]) == ("agent-sat", 1)
+    assert config["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    with open(os.path.join(ROOT, config["file"])) as f:
+        published = json.load(f)
+    assert published["source"] == config["source"] and set(config["reduced"]) < set(published["reduced"])
+    assert (published["num_hidden_layers"], published["n_routed_experts"], published["router_experts"]) == (11, 128, 512)
+    assert published["serving"]["engine_args"] == ["--warmup", "--max-slots", "96", "--max-seq-len", "8192"]
+    spec = traffic.load("agent-sat", False)
+    assert (spec["loop"], spec["clients"]) == ("closed", 120)
+    assert spec["prompt_tokens"] == {"dist": "lognormal", "median": 1500, "sigma": 0.8, "min": 256, "max": 6000}
+    assert spec["output_tokens"] == {"dist": "lognormal", "median": 512, "sigma": 0.5, "min": 128, "max": 1024}
+
+
+@pytest.mark.parametrize("missing", ["decode_step_ms.ssm", "ssm_decode_roofline", "prefill_ssm_share_pct", "window_mfu.ssm"])
+def test_a_traced_line_of_the_state_space_models_cell(missing):
+    bench = resultline.load_benchmark()
+    line = _traced_line(resultline.declared(bench, SSM_CELL, True))
+    assert resultline.problems(line, bench, SSM_CELL, True, 1) == []
+    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
+    assert resultline.problems(cut, bench, SSM_CELL, True, 1) == [f"metric {missing} of this workload and mode is missing"]
+    assert resultline.problems(cut, bench, SSM_CELL, True, 1, may_miss={missing}) == []
+    over = {**line, "metrics": {**line["metrics"], "ssm_decode_roofline": {"value": 106.0, "unit": "%"}}}
+    assert any("over 105%" in p for p in resultline.problems(over, bench, SSM_CELL, True, 1))
+
+
+def test_the_state_space_rooflines_from_counters_and_scopes():
+    """readers/ssm_rooflines.py on a made-up window: 100 decode chunks of 8
+    steps with 90 of 96 slots live, half the held experts hit, a million
+    prompt tokens; scope seconds as readers/ssm_scopes.py keeps them."""
+    from families import nemotron_h_counts as counts
+    from readers import ssm_rooflines, ssm_scopes
+
+    with open(os.path.join(ROOT, "perfbench", "configs", "nemotron3-super-120b-a12b-bf16.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")}
+    steps = 800
+    after = {
+        "kubeai_engine_state_slots_total": [({}, 96.0)],
+        "kubeai_engine_slot_steps_total": [({"state": "active"}, steps * 90.0), ({"state": "idle"}, steps * 6.0)],
+        "kubeai_engine_moe_experts_hit_total": [({"phase": "decode"}, steps * 5 * 64.0)],
+        "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 5 * 128.0)],
+        "kubeai_engine_prefill_tokens_total": [({}, 1.0e6)], "kubeai_engine_generated_tokens_total": [({}, 72000.0)],
+    }
+    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
+    zero["kubeai_engine_state_slots_total"] = after["kubeai_engine_state_slots_total"]  # a gauge
+    ctx = types.SimpleNamespace(
+        hf=hf, serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
+        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[], all_records=[],
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
+    )
+    ctx.ssm_scope_shares = {
+        "jit__unknown(7)": {"total_s": 1.6, "by_scope_s": {"ssm.conv": 0.1, "ssm.scan": 0.7, "ssm.in_proj": 0.1, "moe.experts": 0.4, "moe": 0.1}},
+        "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"ssm.scan": 0.5, "ssm.in_proj": 0.3, "moe.experts": 0.6}},
+    }
+    assert ssm_scopes.read(ctx, "^jit__unknown", "ssm|ssm.in_proj|ssm.conv|ssm.scan|ssm.gate_norm|ssm.out_proj") == pytest.approx(56.25)
+    assert ssm_scopes.read(ctx, "^jit_prefill", "ssm.scan") == pytest.approx(25.0)
+    n_steps = 10 * 8
+    read = lambda what, **kw: ssm_rooflines.read(ctx, what, **kw)  # noqa: E731
+    state = 90 * 2 * 5 * (4_194_304 + 61_440)
+    assert read("ssm_decode") == pytest.approx(100 * (state / 819e9) / (0.8 / n_steps))
+    experts = 5 * 64 * 5_505_024 * 2
+    assert read("experts") == pytest.approx(100 * (experts / 819e9) / (0.4 / n_steps))
+    outside = counts.weights_outside_experts_bytes(hf, 2)
+    assert outside == (5 * 109_640_064 + 35_655_680 + 5 * counts.expert_block_outside_params(hf) + 32768 * 4096 + 4096) * 2
+    assert read("decode_step") == pytest.approx(100 * ((outside + experts + state) / 819e9) / (1.6 / n_steps))  # no record: no keys read
+    flops = 5 * 6_553_600 * 1.0e6
+    assert read("ssm_prefill", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.5 * 50.0 / 4.0))
+    per_token = 2 * counts.active_params(hf) + 5 * 5 * 128 * 64 * 128
+    assert read("window_mfu") == pytest.approx(100 * per_token * 1_072_000 / (197e12 * 50))
+    assert all(0 < read(w, **kw) < 100 for w, kw in (("ssm_decode", {}), ("experts", {}), ("decode_step", {}), ("window_mfu", {})))
+    # A program of another family, or the parent's: no such gauge, nothing read, nothing raised.
+    ctx.after = _scrape(50.0)
+    assert all(read(w) is None for w in ("window_mfu", "experts", "ssm_decode", "decode_step", "ssm_prefill"))
+    ctx.after, ctx.hf = _scrape(50.0, **after), {**hf, "model_type": "smallthinker"}
+    assert read("window_mfu") is None
+    # ... and a trace that carries no `ssm` scope gives no share.
+    ctx.ssm_scope_shares = {"jit__unknown(7)": {"total_s": 1.6, "by_scope_s": {"attn": 0.4, "moe.experts": 0.4}}}
+    assert ssm_scopes.read(ctx, "^jit__unknown", "moe.experts") is None
+    ctx.trace, ctx.ssm_scope_shares = None, None
+    assert ssm_scopes.read(ctx, "^jit__unknown", "ssm.scan") is None
+
+
+@pytest.mark.slow  # two minutes alone: not tier-1, as the other families' rehearsals are not
+def test_rehearsal_of_the_state_space_models_cell(tmp_path):
+    """--rehearse --trace 2 of nemotron3super-bf16-agent-sat at the
+    configuration's `rehearsal` keys (11 blocks, 4 of 16 experts held):
+    every phase, the family's logits check through the slot's state, and
+    every per-layer metric the CPU can read."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", SSM_CELL, "--rehearse",
+         "--trace", "2", "--seed", str(2**31 + 13)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900,
+    )
+    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
+    last = lines[-1]
+    bench = resultline.load_benchmark()
+    # Time a step means nothing in a CPU trace (readers/ssm_rooflines.py).
+    may_miss = SSM_ROOFLINES | {"decode_step_roofline.ssm"}
+    assert resultline.problems(last, bench, SSM_CELL, 2, 1, rehearsal=True, may_miss=may_miss) == []
+    assert set(resultline.declared(bench, SSM_CELL, 2)) - may_miss <= set(last["metrics"])
+    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    assert set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices", "state"}
+    assert all(logits["compared"][part]["ok"] for part in ("prefill_cold", "prefill_chunked", "decode", "router_choices"))
+    assert logits["held_experts"] == [0, 4, 16] and logits["pattern"] == "MEMEMEM*"
+    assert 0 < last["metrics"]["moe_experts_hit_pct.ssm"]["value"] <= 100
+    assert 0 < last["metrics"]["decode_ssm_share_pct"]["value"] < 100
+

@@ -1,0 +1,51 @@
+"""The accepted families are untouched by a change to what they share
+(`engine/core.py`, `ops/moe.py`, `models/base.py`): the step programs of the
+dense, `deepseek_v3` and `smallthinker` toy configurations of
+tests/test_named_scopes.py lower to the text they lowered to before (text
+without locations, so an edit that only moves lines does not show). The
+digests in tests/data/step_program_digests.json were taken at the parent of
+PR 40 (commit c004975) by this file's own `digests()`:
+
+    JAX_PLATFORMS=cpu python tests/test_step_programs_unchanged.py > tests/data/step_program_digests.json
+
+A PR that MEANS to change one of these programs regenerates the file and
+says so; one that does not has changed a program it shares without knowing.
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))
+sys.path.insert(0, HERE)
+
+import test_named_scopes as scopes  # noqa: E402
+
+FAMILIES = {"dense": None, "deepseek_v3": scopes.DEEPSEEK, "smallthinker": scopes.SMALLTHINKER}
+DIGESTS = os.path.join(HERE, "data", "step_program_digests.json")
+
+
+def digests(family: str) -> list[str]:
+    """sha256 of every program the warm compile lowers for the family's toy
+    configuration: the decode chunk, 2 buckets x 2 sizes, the chunk."""
+    return [hashlib.sha256(text.encode()).hexdigest() for text, _ in scopes._lowered_programs(True, FAMILIES[family])]
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_accepted_families_lower_to_the_programs_they_lowered_to(family):
+    with open(DIGESTS) as f:
+        want = json.load(f)
+    got = digests(family)
+    assert len(got) == len(want[family]) >= 4
+    changed = [i for i, (a, b) in enumerate(zip(got, want[family])) if a != b]
+    assert not changed, f"{family}: step program(s) {changed} no longer lower to the text of {DIGESTS}"
+
+
+if __name__ == "__main__":
+    import conftest  # noqa: F401  (the tests' own devices and matmul precision)
+
+    print(json.dumps({family: digests(family) for family in sorted(FAMILIES)}, indent=1))

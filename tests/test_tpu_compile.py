@@ -379,3 +379,65 @@ def test_one_period_of_smallthinker_decodes_through_both_pools(v5e):
     assert compiled.as_text().count("tpu_custom_call") >= 16
     pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+# -- Nemotron-3-Super: the kernels of its step at the published widths -----------
+
+NEMOTRON_SLOTS, NEMOTRON_MAX_LEN = 96, 8192
+
+
+@pytest.mark.parametrize("k,n", [(1024, 2688), (2688, 1024)], ids=["up", "down"])
+@pytest.mark.parametrize("rows", [704, 256, 7552, 60096])
+def test_grouped_expert_matmul_lowers_at_nemotrons_latent_widths(v5e, rows, k, n):
+    """A pass of the chip's share (`ops/moe.py::held_capacity`: 96 slots x
+    22 choices, a quarter held, a third more; the smallest bucket's; a
+    chunk's 1024 x 22; a full prefill group's) over the 128 held experts of
+    one block: an expert's 1024 x 2688 matrix is cut once (5.25 MiB in
+    bf16) and every row tile the rule picks divides a pass."""
+    from kubeai_tpu.ops.moe import gmm_tiles, grouped_matmul, held_capacity
+
+    assert rows in {held_capacity(t * 22, 128, 512) for t in (96, 32, 1024, 8 * 1024)}
+    tm, tk, tn = gmm_tiles(rows, k, n)
+    assert rows % tm == 0 and tm % 8 == 0 and tk * tn <= 2 << 20
+    text = _compile(
+        grouped_matmul,
+        _sds(v5e, (rows, k), jnp.bfloat16), _sds(v5e, (128, k, n), jnp.bfloat16), _sds(v5e, (128,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_a_mixer_an_attention_and_an_expert_block_of_nemotron_decode_in_place(v5e):
+    """A decode step of `M*E` at the published widths with 96 slots of
+    8192: the step holds the paged kernel and the two grouped matmuls of
+    the chip's share, and the pool AND the slots' state come back in place
+    (the `M` block's 0.4 GB of float32 state is updated where it lies: no
+    copy of it is among the program's temporaries)."""
+    from kubeai_tpu.engine.coldstart import param_shapes
+    from kubeai_tpu.models import nemotron_h
+
+    mc = ModelConfig(
+        model_type="nemotron_h", vocab_size=1024, hidden_size=4096, intermediate_size=0, num_layers=3,
+        num_heads=32, num_kv_heads=2, head_dim=128, dtype="bfloat16", layer_pattern="M*E", mamba_num_heads=128,
+        mamba_head_dim=64, ssm_state_size=128, ssm_groups=8, conv_kernel=4, ssm_chunk=128, num_experts_per_tok=22,
+        n_routed_experts=128, router_experts=512, n_shared_experts=1, moe_intermediate_size=2688, moe_latent_size=1024,
+        moe_shared_intermediate_size=5376, routed_scaling_factor=5.0, use_flash_prefill=True, use_paged_kernel=True,
+    )
+    B, max_pages = NEMOTRON_SLOTS, NEMOTRON_MAX_LEN // PAGE
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), param_shapes(mc))
+    cache = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(lambda: nemotron_h.init_paged_cache(mc, B * max_pages + 1, PAGE, slots=B)),
+    )
+    compiled = jax.jit(
+        lambda p, t, c, tbl, lens, active: nemotron_h.decode_step_paged(p, mc, t, c, tbl, lens, live=LiveRows.first(active)),
+        donate_argnums=(2,),
+    ).lower(
+        params, _sds(v5e, (B, 1), jnp.int32), cache, _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.bool_),
+    ).compile()
+    # The paged kernel, and an up and a down grouped matmul in the share's one compiled pass.
+    assert compiled.as_text().count("tpu_custom_call") == 1 + 2
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in cache.values())
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    assert memory.temp_size_in_bytes < cache["ssm"].shape[1] * 128 * 64 * 128 * 4 // 2  # no copy of the state
