@@ -67,7 +67,7 @@ from kubeai_tpu.obs.recorder import (
 )
 from kubeai_tpu.obs.logs import get_logger, trace_extra
 from kubeai_tpu.obs.trace import RequestTrace, TraceContext
-from kubeai_tpu.ops import mla_attention, moe, paged_attention
+from kubeai_tpu.ops import mla_attention, moe, paged_attention, ssm
 from kubeai_tpu.qos import QoSQueue, record_admitted, record_preemption
 from kubeai_tpu.qos import install_queue as qos_install_queue
 from kubeai_tpu.qos import uninstall_queue as qos_uninstall_queue
@@ -808,10 +808,10 @@ class Engine:
             # (kv pages, queries) a block the ragged paged kernel was
             # given, per call shape this process has traced.
             "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
-            # The expert family's kernels, likewise: the MLA decode kernel's
-            # pages a block, ring and form; (tm, tk, tn) of the grouped matmul.
+            # Likewise: the MLA decode kernel's pages a block, ring and form; (tm, tk, tn) of the grouped
+            # matmul; the heads of a slot a program of the state-space step kernel holds (empty: portable).
             "mla_kernel_blocks": dict(mla_attention.chosen_blocks),
-            "grouped_matmul_tiles": dict(moe.chosen_tiles),
+            "grouped_matmul_tiles": dict(moe.chosen_tiles), "ssm_kernel_blocks": dict(ssm.chosen_blocks),
             # Bytes of the pool a token occupies, all layers, as stored:
             # the pool's own size over its tokens (a latent page is a
             # page of another width).

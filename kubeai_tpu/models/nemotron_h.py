@@ -41,7 +41,9 @@ grows with the context, so there is no allocator: the slot is the address.
     block's normed input is put in slot order (`live.restore`, a few KB a
     row) and its output back in the step's order (`live.take`), so the 4 MB
     a slot of state never moves; a slot that is not live has `d = 0`,
-    which leaves its state and tail exactly as they were (`ops/ssm.py`).
+    which leaves its state and tail exactly as they were. On the chip one
+    kernel passes over block j of the stacked state once, by index
+    (`ops/ssm.py::ssd_step_stacked`); nothing here slices the stack.
 
 **A chip's share of the experts.** With `router_experts` set the chip holds
 `n_routed_experts` experts from `experts_first` on: the router scores all
@@ -378,16 +380,19 @@ def apply(
                 zxd = jnp.dot(u, w["in_proj"])
             z, xBC, dt = zxd[..., :inner], zxd[..., inner : inner + C], zxd[..., inner + C :]
             if decode:
-                state, tail = states[j], tails[j]
+                tail = tails[j]
             elif carried is None:
                 state = jnp.zeros((B, *states.shape[2:]), states.dtype)
                 tail = jnp.zeros((B, *tails.shape[2:]), tails.dtype)
             else:
                 state = states[j, slots] * carried[:, None, None, None].astype(states.dtype)
                 tail = tails[j, slots] * carried[:, None, None].astype(tails.dtype)
-            # Each of the two writes its slot state back under its own scope:
-            # the compiler updates the arrays in place, so that write IS the
-            # operation that reads and writes the state.
+            # Whatever moves a slot array stands under the scope of the work it
+            # is part of: the tail's write back under `ssm.conv` (the compiler
+            # updates the array in place, so that write IS the operation that
+            # reads and writes it); under `ssm.scan` a decode step's pass over
+            # block j of the stacked state where it lies (`ssd_step_stacked`:
+            # never a slice of it here) and a prefill's write of its rows.
             with jax.named_scope("ssm.conv"):
                 yc, tail = ssm.causal_conv(xBC, tail, n_real, w["conv_w"], w["conv_b"])
                 xBC = jax.nn.silu(yc).astype(dtype)
@@ -398,14 +403,12 @@ def apply(
             with jax.named_scope("ssm.scan"):
                 d = jax.nn.softplus(dt.astype(jnp.float32) + w["dt_bias"]) * valid[:, :, None]
                 A = -jnp.exp(w["A_log"])
-                h32 = state.astype(jnp.float32)
                 if decode:
-                    y, h32 = ssm.ssd_step(h32, xs[:, 0], d[:, 0], A, Bm[:, 0], Cm[:, 0], w["D"])
+                    y, states = ssm.ssd_step_stacked(states, j, xs[:, 0], d[:, 0], A, Bm[:, 0], Cm[:, 0], w["D"])
                     y = y[:, None]
                 else:
-                    y, h32 = ssm.ssd_chunked(h32, xs, d, A, Bm, Cm, w["D"], config.ssm_chunk)
-                state = h32.astype(states.dtype)
-                states = states.at[j].set(state) if decode else states.at[j, slots].set(state)
+                    y, h32 = ssm.ssd_chunked(state.astype(jnp.float32), xs, d, A, Bm, Cm, w["D"], config.ssm_chunk)
+                    states = states.at[j, slots].set(h32.astype(states.dtype))
             with jax.named_scope("ssm.gate_norm"):
                 y = y.reshape(B, S, inner) * jax.nn.silu(z.astype(jnp.float32))
                 y = rms_norm(y.reshape(B, S, G, inner // G), jnp.ones((), jnp.float32), eps).reshape(B, S, inner)

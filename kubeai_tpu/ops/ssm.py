@@ -1,7 +1,7 @@
-"""Mamba-2's two sequence operations, in plain XLA: the causal depthwise
-convolution with a carried tail, and the selective state-space recurrence
-in its chunked form (prefill: many rows of one sequence a call) and its
-one-step form (decode: one row a sequence). Head `h` of `H` reads group
+"""Mamba-2's two sequence operations: the causal depthwise convolution
+with a carried tail, and the selective state-space recurrence in its
+chunked form (prefill: many rows of one sequence a call) and its one-step
+form (decode: one row a sequence). Head `h` of `H` reads group
 `h // (H // G)` of B and C; with `d_t` the step size after its softplus
 and `A < 0` a scalar a head:
 
@@ -19,14 +19,32 @@ That is a mask in the mathematics, not a copy around it: the caller zeroes
 `d` for such rows, so `a = exp(0) = 1` and the row adds `0 * x (x) B`; the
 new tail is taken at the last REAL row (`n_real`), which with no real row
 at all is the old tail.
+
+**Where each runs.** The convolution and the chunked form are plain XLA
+everywhere. The one-step form on the slots' stacked state
+(`ssd_step_stacked`) is, on the chip, a Pallas kernel that passes over a
+block's state ONCE: a slot's state comes into VMEM, is rewritten where it
+lay, and `y = S C` is formed from the tile while it is there. Elsewhere it
+is the portable `ssd_step`, which XLA compiles to an in-place update and
+a second fusion that reads the new state again for `y`;
+`tests/test_ssm_ops.py` runs the kernel in interpret mode against it.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# What the step kernel was given, per call shape this process has traced
+# (diagnosis: /debug/engine -> perf.ssm_kernel_blocks): the heads of a slot
+# a program holds and the bytes of that tile.
+chosen_blocks: dict[str, dict] = {}
 
 
 def causal_conv(x, tail, n_real, w, b):
@@ -54,8 +72,9 @@ def _grouped(x, G):
 def ssd_step(state, x, d, A, Bm, Cm, D):
     """One token a sequence. state [B, H, P, N] float32; x [B, H, P];
     d [B, H] (0 for a row that is not live); A, D [H]; Bm, Cm [B, G, N].
-    Returns (y [B, H, P] float32, the new state). Everything in float32:
-    the step reads and writes the whole state once and is bound by that."""
+    Returns (y [B, H, P] float32, the new state). Everything in float32.
+    The portable form: XLA makes it two passes over the state, the update
+    and then the reduction over N that reads the new state again."""
     G = Bm.shape[1]
     f32 = jnp.float32
     d = d.astype(f32)
@@ -65,6 +84,117 @@ def ssd_step(state, x, d, A, Bm, Cm, D):
     h = a[..., None, None] * h + (dg[..., None] * xg)[..., None] * Bm.astype(f32)[:, :, None, None, :]
     y = (h * Cm.astype(f32)[:, :, None, None, :]).sum(-1) + _grouped(D.astype(f32)[None], G)[..., None] * xg
     return y.reshape(x.shape), h.reshape(state.shape)
+
+
+# A tile of the step kernel holds at most this much state: one slot's at
+# the published widths (128 heads x 64 x 128 float32).
+KERNEL_TILE_BYTES = 4 << 20
+
+
+def kernel_heads_per_tile(H: int, P: int, N: int, G: int) -> int:
+    """Heads of one slot that a program of the step kernel holds, from the
+    state's own shape: whole groups (a group shares its B and C rows); the
+    whole slot or a multiple of 128 heads (a tile's heads are the lane axis
+    of its `d x` and `y` operands); the largest such tile within
+    `KERNEL_TILE_BYTES`, else the smallest there is. Read in the kernel's
+    own sweep (PR 41, PERF.md section 6: one block of 96 slots x 128 x 64 x
+    128 alone on the chip): 128 heads a program 1.29 ms, 64 1.31, 32 1.33,
+    16 1.47, against 1.28 for a kernel that only rewrites the state: a
+    program's copies in and out are the whole cost, and fewer, larger ones
+    cost least. Two buffers each way: 4 x the tile in VMEM."""
+    Hg = H // G
+    fits = [hb for hb in range(Hg, H + 1, Hg) if H % hb == 0 and (hb == H or hb % 128 == 0)]
+    small = [hb for hb in fits if hb * P * N * 4 <= KERNEL_TILE_BYTES]
+    return max(small) if small else min(fits)
+
+
+def _step_kernel(j_ref, a_ref, dx_ref, b_ref, c_ref, s_ref, y_ref, so_ref, *, group_heads):
+    """One tile: `hb` heads of one slot of block j. a_ref [1, 1, 1, hb]
+    (SMEM) the heads' decays; dx_ref [1, 1, P, hb] `d x`, a head a lane;
+    b_ref, c_ref [1, 1, groups, N]; s_ref / so_ref [1, 1, hb, P, N] the
+    SAME rows of the stacked state, in and out; y_ref [1, 1, P, hb]."""
+    del j_ref  # the index maps read it
+    hb, P, N = s_ref.shape[2:]
+    dx = dx_ref[0, 0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, hb), 1)
+    # The sum over N is a product with ones on the MXU (float32 in six
+    # bf16 passes, exact for ones): across lanes on the VPU it cost 8-18%
+    # of the kernel (PR 41's sweep); the MXU's hides behind the copies.
+    ones = jnp.ones((N, hb), jnp.float32)
+    y = jnp.zeros((P, hb), jnp.float32)
+    for h in range(hb):
+        g = h // group_heads
+        new = a_ref[0, 0, 0, h] * s_ref[0, 0, h] + dx[:, h : h + 1] * b_ref[0, 0, g : g + 1, :]
+        so_ref[0, 0, h] = new
+        summed = jnp.dot(new * c_ref[0, 0, g : g + 1, :], ones, precision=HIGHEST, preferred_element_type=jnp.float32)
+        y = jnp.where(lane == h, summed, y)  # every lane of `summed` holds the head's [P] sums
+    y_ref[0, 0] = y
+
+
+@functools.partial(jax.jit, static_argnames=("heads_per_tile", "interpret"))
+def ssd_step_kernel(states, j, x, d, A, Bm, Cm, D, *, heads_per_tile=None, interpret=False):
+    """The Pallas kernel behind `ssd_step_stacked` (same arguments; j may
+    be traced). The stacked state is the kernel's input AND output
+    (`input_output_aliases`), addressed by index maps a tile at a time:
+    block j's rows are read once and written once where they lie, no
+    other block's rows are touched, and nothing is sliced out."""
+    _, B, H, P, N = states.shape
+    G = Bm.shape[1]
+    f32 = jnp.float32
+    hb = heads_per_tile or kernel_heads_per_tile(H, P, N, G)
+    T, groups = H // hb, hb // (H // G)
+    d, x32 = d.astype(f32), x.astype(f32)
+    by_tile = lambda v: v.reshape(B, T, hb, *v.shape[2:])  # noqa: E731
+    a = by_tile(jnp.exp(d * A.astype(f32)[None]))[:, :, None, :]  # [B, T, 1, hb]
+    dx = jnp.swapaxes(by_tile(d[..., None] * x32), 2, 3)  # [B, T, P, hb]
+    small = lambda *shape, **space: pl.BlockSpec((1, 1, *shape), lambda b, t, j: (b, t, 0, 0), **space)  # noqa: E731
+    tile = pl.BlockSpec((1, 1, hb, P, N), lambda b, t, j: (j[0], b, t, 0, 0))
+    y, states = pl.pallas_call(
+        functools.partial(_step_kernel, group_heads=H // G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, T),
+            in_specs=[small(1, hb, memory_space=pltpu.SMEM), small(P, hb), small(groups, N), small(groups, N), tile],
+            out_specs=[small(P, hb), tile],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, T, P, hb), f32), jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=4 * hb * P * N * 4 + (8 << 20),
+        ),
+        interpret=interpret,
+        name="ssd_step_kernel",
+    )(
+        jnp.reshape(j, (1,)).astype(jnp.int32), a, dx,
+        Bm.astype(f32).reshape(B, T, groups, N), Cm.astype(f32).reshape(B, T, groups, N), states,
+    )
+    return jnp.swapaxes(y, 2, 3).reshape(B, H, P) + D.astype(f32)[None, :, None] * x32, states
+
+
+def kernel_takes(states) -> bool:
+    """Whether the step on *states* [n, B, H, P, N] is the kernel's: on
+    the chip, a float32 state whose tiles are whole vregs."""
+    P, N = states.shape[-2:]
+    return jax.default_backend() == "tpu" and states.dtype == jnp.float32 and N % 128 == 0 and P % 8 == 0
+
+
+def ssd_step_stacked(states, j: int, x, d, A, Bm, Cm, D):
+    """`ssd_step` on block *j* of the slots' stacked state [n, B, H, P, N]
+    (`models/nemotron_h.py`: `cache["ssm"]`), the other arguments as
+    `ssd_step`'s. Returns (y [B, H, P] float32, the stacked state with
+    block j's rows stepped). On the chip the kernel, in place; elsewhere
+    the portable step on `states[j]`, written back."""
+    if kernel_takes(states):
+        _, B, H, P, N = states.shape
+        G = Bm.shape[1]
+        hb = kernel_heads_per_tile(H, P, N, G)
+        chosen_blocks[f"B={B} H={H} P={P} N={N} G={G} {states.dtype.name}"] = {
+            "heads_per_tile": hb, "tile_bytes": hb * P * N * 4,
+            "pass": "one: a tile is rewritten where it lay and y = S C formed from it",
+        }
+        return ssd_step_kernel(states, j, x, d, A, Bm, Cm, D, heads_per_tile=hb)
+    y, h = ssd_step(states[j].astype(jnp.float32), x, d, A, Bm, Cm, D)
+    return y, states.at[j].set(h.astype(states.dtype))
 
 
 def ssd_chunked(state, x, d, A, Bm, Cm, D, chunk: int):

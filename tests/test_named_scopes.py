@@ -4,6 +4,7 @@ change nothing else: the step programs lower to the same text, metadata
 aside, with the scopes and without them."""
 
 import contextlib
+import functools
 import re
 
 import jax
@@ -178,3 +179,42 @@ def test_every_scope_names_operations_in_the_state_space_family(scoped_ssm_progr
         assert re.search(r'["/]' + re.escape(inner) + "/", scoped_ssm_programs[0][1]), inner
     # ... the share's grouped matmuls inside its loop over passes (ops/moe.py::_held_part).
     assert re.search(r'["/]moe/while/body/moe\.experts/', scoped_ssm_programs[0][1])
+
+
+@pytest.fixture(scope="module")
+def scoped_ssm_programs_through_the_kernel():
+    """The same programs with the chip's step kernel in the `M` blocks of
+    the decode program (interpret mode: the test steers the dispatcher)."""
+    from kubeai_tpu.ops import ssm
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(ssm, "kernel_takes", lambda states: True)
+        m.setattr(ssm, "ssd_step_kernel", functools.partial(ssm.ssd_step_kernel, interpret=True))
+        return _lowered_programs(True, NEMOTRON_H)
+
+
+@pytest.mark.parametrize("route", ["portable", "kernel"])
+def test_whatever_moves_the_stacked_state_in_decode_stands_under_ssm_scan(request, route):
+    """`ssm_decode_roofline` takes its time by scope: an operation of the
+    decode program that carries the slots' stacked state [n_M, slots, H, P,
+    N] and is not the program's own plumbing (the scan, its body's call,
+    their returns) is under `ssm/ssm.scan`; on the kernel's route that
+    operation is the call of the kernel, one a block (its own body is
+    attributed through the call)."""
+    fixture = "scoped_ssm_programs" if route == "portable" else "scoped_ssm_programs_through_the_kernel"
+    debug_text = request.getfixturevalue(fixture)[0][1]  # the decode chunk
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', debug_text, flags=re.M))
+    state = "tensor<2x2x8x8x16xf32>"  # two `M` blocks, two slots, 8 heads of 8 x 16
+    plumbing = re.compile(r"^(%\S+ = )?(func\.func|func\.call @closed_call|call @closed_call|stablehlo\.while|stablehlo\.return|return)[ (_]")
+    moved, calls = 0, 0
+    for function in debug_text.split("func.func")[1:]:
+        if function.lstrip().startswith("private @ssd_step_kernel"):
+            continue
+        for line in map(str.strip, function.split("\n")):
+            at = re.search(r"loc\((#loc\d+)\)$", line)
+            if state not in line or at is None or plumbing.match(line):
+                continue
+            assert re.search(r"(^|/)ssm/ssm\.scan(/|$)", locs[at.group(1)]), (line[:120], locs[at.group(1)])
+            moved += 1
+            calls += "call @ssd_step_kernel" in line
+    assert moved >= 2 and calls == (2 if route == "kernel" else 0)

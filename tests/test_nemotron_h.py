@@ -20,6 +20,7 @@ less its row's log-sum-exp on both sides.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -43,6 +44,7 @@ from kubeai_tpu.engine.weights import SafetensorsSource, load_engine_from_path  
 from kubeai_tpu.models import family, llama, nemotron_h  # noqa: E402
 from kubeai_tpu.models.base import LiveRows, ModelConfig  # noqa: E402
 from kubeai_tpu.obs.perf import param_counts  # noqa: E402
+from kubeai_tpu.ops import ssm  # noqa: E402
 
 LOGPROB_ABS = 5e-5
 PAGE, CHUNK, V = 8, 32, 384
@@ -295,6 +297,48 @@ def test_idle_rows_among_live_ones_move_no_state(eng, steps, tokens):
     alone.chunks(2, tokens[0, 10:], (32, 32, 3))
     lps_alone, toks_alone = generated(alone, 2, 67, tok2)
     assert np.array_equal(toks, toks_alone) and np.abs(lps - lps_alone).max() <= LOGPROB_ABS
+
+
+def test_a_decode_chunk_through_the_step_kernel_is_the_portable_routes(eng, steps, tokens, monkeypatch):
+    """The decode program with the chip's one-pass kernel in its `M` blocks
+    (interpret mode; the test steers the dispatcher, the program has no
+    option): two live slots beside a parked one and an empty one give the
+    portable route's log-probabilities and tokens, the five blocks' state
+    within the file's limit, the idle slots' bit for bit; and the process
+    records what it compiled for `/debug/engine`."""
+    def run(d):
+        _, tok0 = d.chunks(0, tokens[0], (32, 9))
+        d.chunks(1, tokens[0, 50:], (20,))
+        _, tok2 = d.chunks(2, tokens[0, 10:], (32, 32, 3))
+        before = d.state()
+        return before, *generated(d, 2, 67, tok2, others={0: (41, tok0)}), d.state()
+
+    _, want_lps, want_toks, want = run(Driver(eng, steps))
+    monkeypatch.setattr(ssm, "chosen_blocks", {})
+    monkeypatch.setattr(ssm, "kernel_takes", lambda states: True)
+    monkeypatch.setattr(ssm, "ssd_step_kernel", functools.partial(ssm.ssd_step_kernel, interpret=True))
+    before, lps, toks, got = run(Driver(eng, build_step_functions(eng.model_config, EC, n_valid_vocab=V)))
+    assert np.array_equal(toks, want_toks) and np.abs(lps - want_lps).max() <= LOGPROB_ABS
+    for k in ("ssm", "conv"):
+        assert np.abs(got[k] - want[k]).max() <= LOGPROB_ABS
+        assert np.array_equal(got[k][:, [1, 3]], before[k][:, [1, 3]])
+    assert not np.array_equal(got["ssm"][:, [0, 2]], before["ssm"][:, [0, 2]])
+    assert ssm.chosen_blocks == {"B=4 H=8 P=8 N=16 G=2 float32": {
+        "heads_per_tile": 8, "tile_bytes": 8 * 8 * 16 * 4,
+        "pass": "one: a tile is rewritten where it lay and y = S C formed from it",
+    }}
+    assert eng._perf_debug_section()["ssm_kernel_blocks"] == ssm.chosen_blocks
+
+
+def test_the_engine_reports_no_step_kernel_off_the_chip(eng, steps, tokens, monkeypatch):
+    """`/debug/engine` -> `perf.ssm_kernel_blocks` beside `mla_kernel_blocks`:
+    empty on the CPU backend, where the portable step is what compiles."""
+    monkeypatch.setattr(ssm, "chosen_blocks", {})
+    d = Driver(eng, build_step_functions(eng.model_config, EC, n_valid_vocab=V))
+    _, tok = d.chunks(0, tokens[0], (32, 9))
+    generated(d, 0, 41, tok, chunks=1)
+    perf = eng._perf_debug_section()
+    assert perf["ssm_kernel_blocks"] == {} and perf["mla_kernel_blocks"] == {}
 
 
 def test_a_slot_used_again_starts_from_zeros(eng, steps, tokens):

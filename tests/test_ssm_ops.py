@@ -3,7 +3,9 @@ Mamba-2 recurrence is the token-by-token recurrence (`ssd_step` a token),
 for calls that are and are not whole chunks, from zeros and from a state a
 call before left; the convolution with a carried tail is the convolution
 over the rows laid end to end; rows that are not real leave the state and
-the tail BIT FOR BIT as they were.
+the tail BIT FOR BIT as they were; and the one-step form's Pallas kernel, in
+interpret mode, is the portable step on block j of the slots' stacked state,
+every other block's rows bit for bit what they were.
 
 Bound: float32 on both sides (conftest: "highest" matmul precision), so
 what separates the two forms is summation order: read 4e-6 on outputs of
@@ -80,12 +82,80 @@ def test_rows_past_the_real_ones_leave_the_state_bit_for_bit(n_real):
     assert np.array_equal(np.asarray(again), np.asarray(state))
 
 
-def test_a_step_of_rows_that_are_not_live_moves_nothing():
+def _kernel_on_one_block(state, *args):
+    """The kernel (interpret mode) on a stack of the one block."""
+    y, states = ssm.ssd_step_kernel(state[None], 0, *args, interpret=True)
+    return y, states[0]
+
+
+@pytest.mark.parametrize("step", [ssm.ssd_step, _kernel_on_one_block], ids=["portable", "kernel"])
+def test_a_step_of_rows_that_are_not_live_moves_nothing(step):
     a = _inputs(1, seed=4, from_state=True)
     live = jnp.asarray([1.0, 0.0])[:, None]
-    y, state = ssm.ssd_step(a["state"], a["x"][:, 0], a["d"][:, 0] * live, a["A"], a["Bm"][:, 0], a["Cm"][:, 0], a["D"])
+    y, state = step(a["state"], a["x"][:, 0], a["d"][:, 0] * live, a["A"], a["Bm"][:, 0], a["Cm"][:, 0], a["D"])
     assert np.array_equal(np.asarray(state[1]), np.asarray(a["state"][1]))
     assert not np.array_equal(np.asarray(state[0]), np.asarray(a["state"][0]))
+
+
+# (B, H, P, N, G): the file's toy shape; three heads a group (not a power of
+# two); the published widths at 3 slots (a 4 MiB tile a slot, as on the chip).
+STEP_SHAPES = [(2, 8, 4, 16, 2), (2, 12, 8, 16, 4), (3, 128, 64, 128, 8)]
+
+
+@pytest.mark.parametrize("j", [0, 1, 2])
+@pytest.mark.parametrize("shape", STEP_SHAPES, ids=["toy", "three_heads_a_group", "published_widths"])
+def test_the_step_kernel_is_the_portable_step_on_block_j_alone(shape, j):
+    """Float32 on both sides: the update is the same three operations an
+    element (read: equal, or an FMA's last bit), `y` a sum of N products in
+    another order (read 8e-6 on outputs to 40 at the published widths)."""
+    Bs, Hs, Ps, Ns, Gs = shape
+    rng = np.random.default_rng(10 * Hs + j)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    states = f(3, Bs, Hs, Ps, Ns)
+    args = (
+        f(Bs, Hs, Ps), jnp.asarray(rng.uniform(0.01, 0.6, (Bs, Hs)), jnp.float32),
+        -jnp.asarray(rng.uniform(0.2, 3.0, (Hs,)), jnp.float32), f(Bs, Gs, Ns), f(Bs, Gs, Ns), f(Hs),
+    )
+    assert ssm.kernel_heads_per_tile(Hs, Ps, Ns, Gs) == Hs  # one program a slot at each of these
+    want_y, want_state = ssm.ssd_step(states[j], *args)
+    y, new = ssm.ssd_step_kernel(states, j, *args, interpret=True)
+    assert y.shape == want_y.shape and new.shape == states.shape
+    assert float(jnp.abs(y - want_y).max()) <= ABS
+    assert float(jnp.abs(new[j] - want_state).max()) <= 1e-6
+    for other in set(range(3)) - {j}:
+        assert np.array_equal(np.asarray(new[other]), np.asarray(states[other]))
+
+
+def test_the_step_kernel_in_tiles_of_a_slot():
+    """Two programs a slot (a tile holds whole groups): the same step."""
+    a = _inputs(1, seed=6, from_state=True)
+    args = (a["x"][:, 0], a["d"][:, 0], a["A"], a["Bm"][:, 0], a["Cm"][:, 0], a["D"])
+    want_y, want_state = ssm.ssd_step(a["state"], *args)
+    y, states = ssm.ssd_step_kernel(a["state"][None], 0, *args, heads_per_tile=H // 2, interpret=True)
+    assert float(jnp.abs(y - want_y).max()) <= ABS
+    assert float(jnp.abs(states[0] - want_state).max()) <= 1e-6
+
+
+@pytest.mark.parametrize(
+    "shape,want", [((128, 64, 128, 8), 128), ((256, 64, 128, 8), 128), ((256, 128, 128, 8), 128), ((12, 8, 16, 4), 12)],
+    ids=["published_whole_slot", "half_of_256_heads", "tile_over_the_budget", "toy_whole_slot"],
+)
+def test_the_tile_is_a_rule_on_the_shape(shape, want):
+    assert ssm.kernel_heads_per_tile(*shape) == want
+
+
+def test_the_stacked_step_off_the_chip_is_the_portable_step(monkeypatch):
+    """On the CPU backend `ssd_step_stacked` takes the portable step and
+    records nothing: `/debug/engine` -> `perf.ssm_kernel_blocks` stays
+    empty, which says the kernel is not what the process compiled."""
+    monkeypatch.setattr(ssm, "chosen_blocks", {})
+    a = _inputs(1, seed=8, from_state=True)
+    args = (a["x"][:, 0], a["d"][:, 0], a["A"], a["Bm"][:, 0], a["Cm"][:, 0], a["D"])
+    states = jnp.stack([a["state"], a["state"] + 1.0])
+    want_y, want_state = ssm.ssd_step(states[1], *args)
+    y, new = ssm.ssd_step_stacked(states, 1, *args)
+    assert np.array_equal(np.asarray(y), np.asarray(want_y)) and np.array_equal(np.asarray(new[1]), np.asarray(want_state))
+    assert np.array_equal(np.asarray(new[0]), np.asarray(states[0])) and ssm.chosen_blocks == {}
 
 
 def _conv_inputs(S, K=4, C=12, seed=0):

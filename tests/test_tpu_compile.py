@@ -406,12 +406,34 @@ def test_grouped_expert_matmul_lowers_at_nemotrons_latent_widths(v5e, rows, k, n
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("slots", [NEMOTRON_SLOTS, 1], ids=["the_cell", "the_logits_child"])
+def test_the_state_space_step_kernel_lowers_in_place_at_the_published_widths(v5e, slots):
+    """Block 3 of five over `slots` x 128 heads x 64 x 128 float32: a whole
+    slot a program (4 MiB in and out, two buffers each: 16 MiB of VMEM,
+    over the default scoped limit, so the call raises its own), and the
+    donated stack comes back aliased with nothing of its size beside it."""
+    from kubeai_tpu.ops import ssm
+
+    H, Pd, N, G = 128, 64, 128, 8
+    assert ssm.kernel_heads_per_tile(H, Pd, N, G) == H
+    compiled = jax.jit(ssm.ssd_step_kernel, donate_argnums=(0,)).lower(
+        _sds(v5e, (5, slots, H, Pd, N), jnp.float32), _sds(v5e, (), jnp.int32), _sds(v5e, (slots, H, Pd), jnp.bfloat16),
+        _sds(v5e, (slots, H), jnp.float32), _sds(v5e, (H,), jnp.float32), _sds(v5e, (slots, G, N), jnp.bfloat16),
+        _sds(v5e, (slots, G, N), jnp.bfloat16), _sds(v5e, (H,), jnp.float32),
+    ).compile()
+    assert "ssd_step_kernel" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 5 * slots * H * Pd * N * 4
+    assert memory.temp_size_in_bytes < slots * H * Pd * N * 4 // 2
+
+
 def test_a_mixer_an_attention_and_an_expert_block_of_nemotron_decode_in_place(v5e):
     """A decode step of `M*E` at the published widths with 96 slots of
-    8192: the step holds the paged kernel and the two grouped matmuls of
-    the chip's share, and the pool AND the slots' state come back in place
-    (the `M` block's 0.4 GB of float32 state is updated where it lies: no
-    copy of it is among the program's temporaries)."""
+    8192: the step holds the state-space step kernel, the paged kernel and
+    the two grouped matmuls of the chip's share, and the pool AND the
+    slots' state come back in place (the `M` block's 0.4 GB of float32
+    state is rewritten where it lies by the kernel's own tiles: no copy of
+    it is among the program's temporaries)."""
     from kubeai_tpu.engine.coldstart import param_shapes
     from kubeai_tpu.models import nemotron_h
 
@@ -435,8 +457,9 @@ def test_a_mixer_an_attention_and_an_expert_block_of_nemotron_decode_in_place(v5
         params, _sds(v5e, (B, 1), jnp.int32), cache, _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
         _sds(v5e, (B,), jnp.bool_),
     ).compile()
-    # The paged kernel, and an up and a down grouped matmul in the share's one compiled pass.
-    assert compiled.as_text().count("tpu_custom_call") == 1 + 2
+    # The step kernel, the paged kernel, and an up and a down grouped matmul in the share's one compiled pass.
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + 1 + 2 and "ssd_step_kernel" in text
     held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in cache.values())
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= held
