@@ -27,7 +27,8 @@ __all__ = ["ModelConfig", "family"]
 def family(config: ModelConfig):
     """The model module of *config*'s family: `models/deepseek.py` for
     `deepseek_v3`, `models/smallthinker.py` for `smallthinker`,
-    `models/nemotron_h.py` for `nemotron_h`, `models/llama.py` for every dense or Mixtral-style decoder it has
+    `models/nemotron_h.py` for `nemotron_h`, `models/afmoe.py` for `afmoe`,
+    `models/llama.py` for every dense or Mixtral-style decoder it has
     always run (Llama, Mistral, Qwen2, Gemma, Mixtral)."""
     if config.model_type == "deepseek_v3":
         from kubeai_tpu.models import deepseek
@@ -41,6 +42,10 @@ def family(config: ModelConfig):
         from kubeai_tpu.models import nemotron_h
 
         return nemotron_h
+    if config.model_type == "afmoe":
+        from kubeai_tpu.models import afmoe
+
+        return afmoe
     from kubeai_tpu.models import llama
 
     return llama

@@ -129,6 +129,11 @@ class ModelConfig:
     moe_shared_intermediate_size: int = 0
     router_experts: int = 0
     experts_first: int = 0
+    # AFMoE family (model_type "afmoe", models/afmoe.py): no field of its
+    # own. `_afmoe_keys` reads its published keys into embed_scale,
+    # first_k_dense_replace, the expert fields and the window layouts
+    # above; its gate, query/key norms and post-norms are the family's
+    # equations, not switches.
 
     @property
     def head_dim_(self) -> int:
@@ -198,6 +203,8 @@ class ModelConfig:
             gemma_kw = _smallthinker_keys(get)
         if model_type == "nemotron_h":
             gemma_kw = _nemotron_h_keys(get)
+        if model_type == "afmoe":
+            gemma_kw = _afmoe_keys(get)
         kw = dict(
             model_type=model_type,
             vocab_size=config.vocab_size,
@@ -367,6 +374,60 @@ def _nemotron_h_keys(get) -> dict:
         moe_shared_intermediate_size=get("moe_shared_expert_intermediate_size") or 0,
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
+    )
+
+
+def _afmoe_keys(get) -> dict:
+    """The AFMoE keys (Trinity) of a published config.json as ModelConfig
+    fields; models/afmoe.py says what each means. What it does not
+    compute is refused here, by name. `layer_types` may be longer than the
+    depth (a checkpoint cut in depth keeps the published list): the first
+    `num_hidden_layers` entries are the model's. The family has no fields
+    of its own: the window, the layouts (rope goes with the window), the
+    leading dense layers, the experts and the shared one, the router's
+    norm and scale and the embedding multiplier reuse the fields other
+    families brought. `load_balance_coeff` (it trains the selection bias)
+    and `use_grouped_mm` (a switch of the source's implementation) are
+    read by nothing."""
+    L = get("num_hidden_layers")
+    types = get("layer_types")
+    if not isinstance(types, (list, tuple)) or len(types) < L or set(types) - {"sliding_attention", "full_attention"}:
+        raise ValueError(f"afmoe: layer_types must give sliding_attention or full_attention for each of the {L} layers")
+    every = get("global_attn_every_n_layers")
+    if every and any((t == "full_attention") != ((i + 1) % every == 0) for i, t in enumerate(types)):
+        raise ValueError(f"afmoe: layer_types and global_attn_every_n_layers ({every}) disagree")
+    for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups"):
+        if (get(key) or 1) != 1:
+            raise ValueError(f"afmoe: grouped routing ({key} > 1) is not supported")
+    if get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"afmoe: score_func {get('score_func')!r} is not supported (sigmoid)")
+    if get("hidden_act", "silu") != "silu":
+        raise ValueError(f"afmoe: hidden_act {get('hidden_act')!r} is not supported (silu)")
+    if get("rope_scaling"):
+        raise ValueError("afmoe: rope_scaling is not supported")
+    if get("attention_bias"):
+        raise ValueError("afmoe: attention_bias is not supported")
+    layout = tuple(int(t == "sliding_attention") for t in types)
+    period = layout_period(layout)
+    if L % period:
+        raise ValueError(f"afmoe: {L} layers are not whole periods of layer_types' pattern of {period} layers")
+    dense = get("num_dense_layers") or 0
+    if dense > period:
+        raise ValueError(f"afmoe: num_dense_layers {dense} past the first period of {period} layers is not supported")
+    window = get("sliding_window") or 0
+    if any(layout[:L]) and window <= 0:
+        raise ValueError("afmoe: layer_types names sliding_attention layers and sliding_window gives no window")
+    return dict(
+        embed_scale=bool(get("mup_enabled", False)),
+        first_k_dense_replace=dense,
+        n_routed_experts=get("num_experts") or 0,
+        n_shared_experts=get("num_shared_experts") or 0,
+        moe_intermediate_size=get("moe_intermediate_size") or 0,
+        norm_topk_prob=bool(get("route_norm", True)),
+        routed_scaling_factor=float(get("route_scale") or 1.0),
+        sliding_window_size=int(window),
+        sliding_window_layout=layout[:L],
+        rope_layout=layout[:L],
     )
 
 

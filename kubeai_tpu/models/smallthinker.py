@@ -171,10 +171,11 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
     }
 
 
-def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> tuple[dict, dict]:
-    """Layer *i* of an HF checkpoint (get(name) -> array): linears
-    transposed to [in, out]; the experts stacked [E, out, in] on the host
-    (one contiguous copy; the device transposes them, `_put_row`)."""
+def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, dict]:
+    """Layer *i* of an HF checkpoint (get(name) -> array) by group of the
+    tree: linears transposed to [in, out]; the experts stacked [E, out,
+    in] on the host (one contiguous copy; the device transposes them,
+    `_put_row`)."""
     p = f"model.layers.{i}."
     conv = lambda a: np.asarray(a, dtype)  # noqa: E731
     lin = lambda name: conv(np.asarray(get(p + name + ".weight")).T)  # noqa: E731
@@ -188,40 +189,45 @@ def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> tuple[dict, dict]
         "wq": lin("self_attn.q_proj"), "wk": lin("self_attn.k_proj"),
         "wv": lin("self_attn.v_proj"), "wo": lin("self_attn.o_proj"),
     }
-    return layer, {"we_g": stack("gate"), "we_u": stack("up"), "we_d": stack("down")}
+    return {"layers": layer, "experts": {"we_g": stack("gate"), "we_u": stack("up"), "we_d": stack("down")}}
 
 
 def _put_row(buf, a, i, transpose: bool):
     return buf.at[i].set(jnp.swapaxes(a, -1, -2) if transpose else a)
 
 
-def stream_params_from_hf(source, config: ModelConfig, pad: int = 0) -> Params:
+def stream_params_from_hf(source, config: ModelConfig, pad: int = 0, layer_tensors=_layer_tensors, rows=None) -> Params:
     """The streamed load: while a layer is put on the device and written
     into its row of the stacked arrays ON the device (the buffer donated),
     a reader thread takes the next from the checkpoint and converts it (a
     layer's experts are 0.75 GB in bf16: the host holds two layers, the
     device never a stack twice). *source* serves tensors by HF name; *pad*
-    columns of zeros are added to the vocabulary."""
+    columns of zeros are added to the vocabulary. A family whose groups
+    do not all hold every layer (`models/afmoe.py`) brings its own
+    *layer_tensors* and, by group, *rows*: (rows of the group's stacks,
+    the layer that is their row 0)."""
     from concurrent.futures import ThreadPoolExecutor
 
     dtype = jnp.dtype(config.dtype)
     L = config.num_layers
+    rows = rows or {"layers": (L, 0), "experts": (L, 0)}
     donate = (0,) if jax.default_backend() != "cpu" else ()  # the CPU backend cannot reuse a donated buffer
     put_row = jax.jit(_put_row, static_argnums=(3,), donate_argnums=donate)
-    params: Params = {"layers": {}, "experts": {}}
+    params: Params = {group: {} for group in rows}
     with ThreadPoolExecutor(max_workers=1) as reader:
-        ahead = reader.submit(_layer_tensors, source.get, config, 0, dtype)
+        ahead = reader.submit(layer_tensors, source.get, config, 0, dtype)
         for i in range(L):
             groups = ahead.result()
             if i + 1 < L:
-                ahead = reader.submit(_layer_tensors, source.get, config, i + 1, dtype)
-            for group, tensors in zip(("layers", "experts"), groups):
+                ahead = reader.submit(layer_tensors, source.get, config, i + 1, dtype)
+            for group, tensors in groups.items():
                 experts = group == "experts"
+                n, first = rows[group]
                 for k, a in tensors.items():
                     shape = (a.shape[0], a.shape[2], a.shape[1]) if experts else a.shape
                     if k not in params[group]:
-                        params[group][k] = jnp.zeros((L, *shape), a.dtype)
-                    params[group][k] = put_row(params[group][k], a, i, experts)
+                        params[group][k] = jnp.zeros((n, *shape), a.dtype)
+                    params[group][k] = put_row(params[group][k], a, i - first, experts)
     embed = np.asarray(source.get("model.embed_tokens.weight"), dtype)
     head = np.asarray(source.get("lm_head.weight"), dtype).T
     if pad:
@@ -277,6 +283,84 @@ def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, page
 # Forward
 
 
+class TwoPools:
+    """One call's view of the two pools (module docstring), for every
+    family whose stack mixes full and window layers (`models/afmoe.py`
+    too): where each kind of layer writes this call's keys and values,
+    and what it reads. Full: the slot's whole table. Window: the columns
+    from the first page this call's first query can see, the lengths
+    shifted by as much. *positions* [B, S] are contiguous along S;
+    *page_table* is `[full | window]`; *route* is
+    `cached_attention_route`'s; *live* a decode step's LiveRows."""
+
+    def __init__(self, config: ModelConfig, cache: Params, page_table, positions, route: str, live=None):
+        self.config, self.positions, self.route, self.live = config, positions, route, live
+        S = positions.shape[1]
+        window = config.sliding_window_size
+        n_full, n_window = layer_kinds(config)
+        self.pools = {0: cache["kv"], 1: cache["kv_window"]}
+        self.page = page = self.pools[0].shape[1]
+        self.rows = {0: self.pools[0].shape[0] // n_full, 1: self.pools[1].shape[0] // n_window}  # logical pages a layer
+        max_pages = page_table.shape[1] // 2
+        tables = {0: page_table[:, :max_pages], 1: page_table[:, max_pages:]}
+        skv = max_pages * page
+        w_idx = jnp.clip(positions // page, 0, max_pages - 1)
+        self.w_offs = positions % page
+        self.w_pages = {
+            kind: jnp.where(positions < skv, jnp.take_along_axis(tables[kind], w_idx, axis=1), 0) for kind in (0, 1)
+        }
+        last = positions[:, -1]
+        first = jnp.maximum(positions[:, 0] - window + 1, 0) // page
+        Wp = window_pages(config, S, page, max_pages)
+        cols = jnp.clip(first[:, None] + jnp.arange(Wp, dtype=jnp.int32)[None, :], 0, max_pages - 1)
+        # By kind: a table, the keys' count and the position of the table's first key.
+        self.read = {
+            0: (tables[0], last + 1, jnp.zeros_like(first)),
+            1: (jnp.take_along_axis(tables[1], cols, axis=1), last + 1 - first * page, first * page),
+        }
+        # Which of its kind each layer of a period is: its pool rows follow.
+        kinds = config.sliding_window_layout[: period(config)]
+        self.nth = [sum(1 for j2 in range(j) if kinds[j2] == kinds[j]) for j in range(len(kinds))]
+        self.per_kind = {kind: sum(1 for v_ in kinds if v_ == kind) for kind in (0, 1)}
+        self.kinds = kinds
+
+    def row0(self, n, j: int):
+        """The first pool row of layer *j* of period *n* (in its kind's pool)."""
+        kind = self.kinds[j]
+        return (n * self.per_kind[kind] + self.nth[j]) * self.rows[kind]
+
+    def write(self, pool, row0, kind: int, k, v):
+        """*pool* with this call's keys and values [B, S, Kv, h] in the layer's pages."""
+        B, S, Kv, h = k.shape
+        interleaved = jnp.stack([k, v], axis=3).reshape(B, S, 2 * Kv, h)
+        return pool.at[self.w_pages[kind] + row0, self.w_offs].set(interleaved.astype(pool.dtype))
+
+    def attend(self, q, k, v, pool, row0, kind: int):
+        """The attention of one layer of *kind* over its pool (already
+        holding this call's keys and values)."""
+        if self.route == "flash":
+            from kubeai_tpu.ops.flash_attention import flash_attention_tpu
+
+            return flash_attention_tpu(q, k, v, causal=True)
+        window, positions = self.config.sliding_window_size, self.positions
+        table, kv_len, key0 = self.read[kind]
+        if self.route == "paged_kernel":
+            return paged_attention_ragged(
+                q, pool, table + row0, kv_len, sliding_window=window if kind else None,
+                live_rows=None if self.live is None else self.live.count,
+            )
+        B, _, Kv, h = k.shape
+        gathered = pool[table + row0]  # [B, columns, page, 2Kv, h]
+        n_keys = table.shape[1] * self.page
+        k_att = gathered[..., 0::2, :].reshape(B, n_keys, Kv, h)
+        v_att = gathered[..., 1::2, :].reshape(B, n_keys, Kv, h)
+        key_pos = key0[:, None, None] + jnp.arange(n_keys, dtype=jnp.int32)[None, None, :]
+        mask = key_pos <= positions[:, :, None]
+        if kind:
+            mask = jnp.logical_and(mask, key_pos > positions[:, :, None] - window)
+        return attention(q, k_att, v_att, mask)
+
+
 def apply(
     params: Params,
     config: ModelConfig,
@@ -300,62 +384,16 @@ def apply(
         raise ValueError("smallthinker: a call without the paged pool (embeddings, scoring) is not supported")
     B, S = tokens.shape
     H, Kv, h, L = config.num_heads, config.num_kv_heads, config.head_dim_, config.num_layers
-    window, eps, top_k = config.sliding_window_size, config.rms_norm_eps, config.num_experts_per_tok
+    eps, top_k = config.rms_norm_eps, config.num_experts_per_tok
     per = period(config)
     kinds = config.sliding_window_layout[:per]
     ropes = config.rope_layout[:per]
-    n_full, n_window = layer_kinds(config)
     inv_freq = jnp.asarray(rope_frequencies(h, config.rope_theta, None))
     route = cached_attention_route(config, S, left_aligned, True)
 
-    pools = {0: cache["kv"], 1: cache["kv_window"]}
-    page = pools[0].shape[1]
-    rows = {0: pools[0].shape[0] // n_full, 1: pools[1].shape[0] // n_window}  # logical pages a layer
-    max_pages = page_table.shape[1] // 2
-    tables = {0: page_table[:, :max_pages], 1: page_table[:, max_pages:]}
-    skv = max_pages * page
-    w_idx = jnp.clip(positions // page, 0, max_pages - 1)
-    w_offs = positions % page
-    w_pages = {
-        kind: jnp.where(positions < skv, jnp.take_along_axis(tables[kind], w_idx, axis=1), 0) for kind in (0, 1)
-    }
-    # What each kind of layer reads: a table, the keys' count and the
-    # position of the table's first key. Full: the slot's whole table.
-    # Window: the columns from the first page this call's first query
-    # can see (module docstring).
-    last = positions[:, -1]
-    first = jnp.maximum(positions[:, 0] - window + 1, 0) // page
-    Wp = window_pages(config, S, page, max_pages)
-    cols = jnp.clip(first[:, None] + jnp.arange(Wp, dtype=jnp.int32)[None, :], 0, max_pages - 1)
-    read = {
-        0: (tables[0], last + 1, jnp.zeros_like(first)),
-        1: (jnp.take_along_axis(tables[1], cols, axis=1), last + 1 - first * page, first * page),
-    }
+    two = TwoPools(config, cache, page_table, positions, route, live)
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(jnp.dtype(config.dtype))
-
-    def attend(q, k, v, pool, row0, kind):
-        """The attention of one layer of *kind* over its pool (already
-        holding this call's keys and values)."""
-        if route == "flash":
-            from kubeai_tpu.ops.flash_attention import flash_attention_tpu
-
-            return flash_attention_tpu(q, k, v, causal=True)
-        table, kv_len, key0 = read[kind]
-        if route == "paged_kernel":
-            return paged_attention_ragged(
-                q, pool, table + row0, kv_len, sliding_window=window if kind else None,
-                live_rows=None if live is None else live.count,
-            )
-        gathered = pool[table + row0]  # [B, columns, page, 2Kv, h]
-        n_keys = table.shape[1] * page
-        k_att = gathered[..., 0::2, :].reshape(B, n_keys, Kv, h)
-        v_att = gathered[..., 1::2, :].reshape(B, n_keys, Kv, h)
-        key_pos = key0[:, None, None] + jnp.arange(n_keys, dtype=jnp.int32)[None, None, :]
-        mask = key_pos <= positions[:, :, None]
-        if kind:
-            mask = jnp.logical_and(mask, key_pos > positions[:, :, None] - window)
-        return attention(q, k_att, v_att, mask)
 
     def layer(x, w, pool, row0, kind, rope, forced, l):
         scope = "attn.window" if kind else "attn.full"
@@ -371,10 +409,9 @@ def apply(
             v = jnp.dot(a, w["wv"]).reshape(B, S, Kv, h)
             if rope:
                 q, k = apply_rope(q, k, positions, inv_freq)
-            interleaved = jnp.stack([k, v], axis=3).reshape(B, S, 2 * Kv, h)
-            pool = pool.at[w_pages[kind] + row0, w_offs].set(interleaved.astype(pool.dtype))
+            pool = two.write(pool, row0, kind, k, v)
             with jax.named_scope("attn.kernel"):
-                o = attend(q, k, v, pool, row0, kind)
+                o = two.attend(q, k, v, pool, row0, kind)
             x = x + jnp.dot(o.reshape(B, S, H * h), w["wo"])
         with jax.named_scope("moe"):
             m = rms_norm(x, w["ln2"], eps).reshape(B * S, -1)
@@ -386,10 +423,6 @@ def apply(
             x = x + y.reshape(B, S, -1)
         return x, pool, hit, idx
 
-    # Which of its kind each layer of a period is: its pool rows follow.
-    nth = [sum(1 for j2 in range(j) if kinds[j2] == kinds[j]) for j in range(per)]
-    per_kind = {kind: sum(1 for v_ in kinds if v_ == kind) for kind in (0, 1)}
-
     def step(carry, xs):
         x, pool_f, pool_w, hits = carry
         n, forced = xs
@@ -397,7 +430,7 @@ def apply(
         chosen = []
         for j in range(per):  # unrolled: a period's layers differ in kind
             kind, l = kinds[j], n * per + j
-            row0 = (n * per_kind[kind] + nth[j]) * rows[kind]
+            row0 = two.row0(n, j)
             # Each layer's weights are read from the whole stack at its own
             # index: a period's block sliced out first and then indexed is
             # a copy of the block (73 MB of `wo` a period a step on the chip).
@@ -411,7 +444,7 @@ def apply(
 
     n_periods = L // per
     (x, pool_f, pool_w, hits), choices = jax.lax.scan(
-        step, (x, pools[0], pools[1], jnp.zeros((), jnp.int32)),
+        step, (x, two.pools[0], two.pools[1], jnp.zeros((), jnp.int32)),
         (
             jnp.arange(n_periods, dtype=jnp.int32),
             None if forced_choices is None else forced_choices.reshape(n_periods, per, *forced_choices.shape[1:]),

@@ -130,6 +130,8 @@ def param_counts(mc) -> tuple[float, float]:
         return _smallthinker_param_counts(mc)
     if getattr(mc, "model_type", "") == "nemotron_h":
         return _nemotron_h_param_counts(mc)
+    if getattr(mc, "model_type", "") == "afmoe":
+        return _afmoe_param_counts(mc)
     H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
     attn = D * H * h + 2 * D * Kv * h + H * h * D
     if getattr(mc, "qkv_bias", False):
@@ -217,6 +219,28 @@ def _nemotron_h_param_counts(mc) -> tuple[float, float]:
     return float(total), float(active)
 
 
+def _afmoe_param_counts(mc) -> tuple[float, float]:
+    """AFMoE family (models/afmoe.py): every layer holds gated
+    grouped-query attention with query/key norms and four norms;
+    `first_k_dense_replace` layers a dense feed-forward, the rest a
+    router with its selection bias, `n_shared_experts` shared experts and
+    `n_routed_experts` routed ones, of which a token passes through
+    `num_experts_per_tok`. Trinity-Mini at 8 of 32 layers: 5.98G held,
+    1.04G a token; at 32: 26.1G and 3.06G. Held to perfbench/families/
+    afmoe_counts.py by tests/test_afmoe.py."""
+    D, L, V = mc.hidden_size, mc.num_layers, mc.vocab_size
+    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
+    attn = 3 * D * H * h + 2 * D * Kv * h + 2 * h + 4 * D
+    n_dense = min(mc.first_k_dense_replace, L)
+    expert = 3 * D * mc.moe_intermediate_size
+    outside = D * mc.n_routed_experts + mc.n_routed_experts + mc.n_shared_experts * expert
+    always = L * attn + n_dense * 3 * D * mc.intermediate_size + (L - n_dense) * outside  # whatever the routing
+    total = 2 * V * D + D + always + (L - n_dense) * mc.n_routed_experts * expert
+    # Active leaves the embedding table out (a row is looked up).
+    active = V * D + D + always + (L - n_dense) * mc.num_experts_per_tok * expert
+    return float(total), float(active)
+
+
 @dataclass(frozen=True)
 class PerfModel:
     """Per-model roofline constants, computed once. ``flops_per_token``
@@ -251,7 +275,7 @@ class PerfModel:
             flops_per_token=2.0 * active,
             weight_bytes=float(weight_bytes),
             attn_flops_per_pair=(
-                4.0 * mc.num_heads * mc.head_dim_ if getattr(mc, "model_type", "") == "smallthinker" else 0.0
+                4.0 * mc.num_heads * mc.head_dim_ if getattr(mc, "model_type", "") in ("smallthinker", "afmoe") else 0.0
             ),
         )
 

@@ -218,3 +218,47 @@ def test_whatever_moves_the_stacked_state_in_decode_stands_under_ssm_scan(reques
             moved += 1
             calls += "call @ssd_step_kernel" in line
     assert moved >= 2 and calls == (2 if route == "kernel" else 0)
+
+
+# -- the gated window family's module (models/afmoe.py) ---------------------------
+
+AFM_SCOPES = (
+    "embed", "attn", "attn.qk_norm", "attn.full", "attn.window", "attn.kernel", "attn.gate", "norm.post", "ffn", "moe",
+    "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared", "lm_head", "sampling", "logprobs",
+)
+AFMOE = ModelConfig(
+    model_type="afmoe", vocab_size=272, hidden_size=64, intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=2,
+    head_dim=16, dtype="float32", max_position=256, num_experts_per_tok=2, n_routed_experts=8, n_shared_experts=1,
+    moe_intermediate_size=32, first_k_dense_replace=2, routed_scaling_factor=2.826, embed_scale=True, sliding_window_size=32,
+    sliding_window_layout=(1, 1, 1, 0) * 2, rope_layout=(1, 1, 1, 0) * 2, use_paged_kernel=True, use_flash_prefill=True,
+)
+
+
+@pytest.fixture(scope="module")
+def scoped_afm_programs():
+    return _lowered_programs(True, AFMOE)
+
+
+def test_scopes_change_metadata_only_in_the_gated_window_family(scoped_afm_programs):
+    plain = _lowered_programs(False, AFMOE)
+    assert len(scoped_afm_programs) == len(plain) >= 4
+    for i, ((s_text, s_debug), (p_text, p_debug)) in enumerate(zip(scoped_afm_programs, plain)):
+        assert s_text == p_text, f"program {i}: the computation changed with the scopes"
+        assert s_debug != p_debug, f"program {i}: the scopes left no trace in the metadata"
+
+
+@pytest.mark.parametrize("scope", AFM_SCOPES)
+def test_every_scope_names_operations_in_the_gated_window_family(scoped_afm_programs, scope):
+    rx = re.compile(r'["/]' + re.escape(scope) + "/")
+    for _, debug_text in scoped_afm_programs[:2]:  # the decode chunk, a prefill
+        assert rx.search(debug_text), scope
+    # What the family adds sits INSIDE its layer's kind, the kind inside `attn`, and the post-norms
+    # outside both: a reader tells them apart by the path (readers/afm_scopes.py, swa_scopes.py).
+    for inner in (
+        "attn/attn.full/attn.kernel", "attn/attn.window/attn.kernel", "attn/attn.window/attn.qk_norm", "attn/attn.full/attn.gate",
+        "moe/moe.shared",
+    ):
+        assert re.search(r'["/]' + re.escape(inner) + "/", scoped_afm_programs[0][1]), inner
+    assert not re.search(r'["/]attn[./][^"]*norm\.post/', scoped_afm_programs[0][1])
+    # The periods behind the first are one scanned body.
+    assert "stablehlo.while" in scoped_afm_programs[1][0]

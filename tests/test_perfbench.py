@@ -182,7 +182,7 @@ def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
 
 def test_every_cell_is_one_of_those_with_a_case_here():
     cells = [w["name"] for w in resultline.load_benchmark()["workloads"]]
-    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL, SWA_CELL, SSM_CELL])
+    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL, SWA_CELL, SSM_CELL, AFM_CELL, MCHAT_CELL])
 
 
 def test_the_expert_models_cell_declares_its_own_metrics_and_none_of_the_dense_cells():
@@ -585,9 +585,10 @@ def test_the_state_space_cells_metrics_are_its_own():
     mine = resultline.declared(bench, SSM_CELL, True)
     assert len(mine) == 23 and SSM_NEW <= set(mine)
     assert all(n.endswith(".ssm") for n in set(mine) - SSM_NEW)  # twins of accepted readers, under its suffix
-    # No other cell carries them, this cell none of theirs, and they stand last, appended.
+    # No other cell carries them, this cell none of theirs, and they were appended together, wherever later PRs' stand.
     names = [m["name"] for m in bench["per_layer"]]
-    assert set(names[-23:]) == set(mine)
+    at = names.index("decode_step_ms.ssm")
+    assert set(names[at : at + 23]) == set(mine)
     for m in bench["per_layer"]:
         assert (SSM_CELL in m["workloads"]) == (m["workloads"] == [SSM_CELL]), m["name"]
     assert set(resultline.declared(bench, SSM_CELL, False)) == {"output_tok_s", "setup_s"}
@@ -708,3 +709,191 @@ def test_rehearsal_of_the_state_space_models_cell(tmp_path):
     assert 0 < last["metrics"]["moe_experts_hit_pct.ssm"]["value"] <= 100
     assert 0 < last["metrics"]["decode_ssm_share_pct"]["value"] < 100
 
+
+
+# -- PR 42: the gated window family's cell, its readers, and docqa's bypass ------
+
+AFM_CELL = "trinitymini-bf16-mixedlen-sat"
+MCHAT_CELL = "mistral7b-int8-chat-sat"
+AFM_NEW = {"prefill_attn_full_share_pct", "attn_gate_norm_share_pct"}
+AFM_ROOFLINES = {
+    "moe_experts_roofline.afm", "full_attn_decode_roofline.afm", "window_attn_decode_roofline.afm", "prefill_attn_roofline.afm",
+}
+
+
+def test_the_two_new_cells_metrics_are_their_own():
+    bench = resultline.load_benchmark()
+    mine = resultline.declared(bench, AFM_CELL, True)
+    assert len(mine) == 23 and AFM_NEW | AFM_ROOFLINES | {"decode_step_roofline.afm", "window_mfu.afm"} <= set(mine)
+    assert all(n.endswith(".afm") for n in set(mine) - AFM_NEW)  # twins of accepted readers, under its suffix
+    bypass = resultline.declared(bench, MCHAT_CELL, True)
+    assert set(bypass) == {"decode_step_ms.mchat", "batch_occupancy_pct.mchat", "prefix_hit_pct.mchat"}
+    # No other cell carries them, these cells none of theirs; they stand last, appended; the list is at its cap.
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-26:] == list(mine) + list(bypass) and len(names) == 128
+    for m in bench["per_layer"]:
+        for cell in (AFM_CELL, MCHAT_CELL):
+            assert (cell in m["workloads"]) == (m["workloads"] == [cell]), m["name"]
+    for cell in (AFM_CELL, MCHAT_CELL):
+        assert set(resultline.declared(bench, cell, False)) == {"output_tok_s", "setup_s"}
+    # A data-only twin is its accepted metric's file, byte for byte.
+    for twin, of in (
+        ("decode_step_ms.afm", "decode_step_ms.swa"), ("kv_pages_peak_pct.window.afm", "kv_pages_peak_pct.window"),
+        ("idle_exposed_host_pct.afm", "idle_exposed_host_pct"), ("decode_attn_full_share_pct.afm", "decode_attn_full_share_pct"),
+        ("decode_step_ms.mchat", "decode_step_ms.tput"), ("prefix_hit_pct.mchat", "prefix_hit_pct"),
+        ("batch_occupancy_pct.mchat", "batch_occupancy_pct"),
+    ):
+        files = [open(os.path.join(ROOT, "perfbench", "layer_metrics", n + ".json")).read() for n in (twin, of)]
+        assert files[0] == files[1], twin
+    for name in list(mine) + list(bypass):
+        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
+            spec = json.load(f)
+        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py")), name
+        if name in AFM_ROOFLINES | {"decode_step_roofline.afm", "window_mfu.afm"}:
+            assert spec["reader"] == "afm_rooflines"  # this family's counts
+    cell = next(w for w in bench["workloads"] if w["name"] == AFM_CELL)
+    config = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    assert (cell["traffic"], cell["chips"], config["reduced"]) == ("mixedlen-sat", 1, ["num_hidden_layers"])
+    with open(os.path.join(ROOT, config["file"])) as f:
+        published = json.load(f)
+    assert published["source"] == config["source"] and set(config["reduced"]) == set(published["reduced"])
+    assert (published["num_hidden_layers"], published["num_experts"], published["sliding_window"], len(published["layer_types"])) == (8, 128, 2048, 32)
+    assert published["serving"]["engine_args"] == ["--warmup", "--max-slots", "24", "--max-seq-len", "32768", "--kv-pages", "6145"]
+    # The row's keys as published: every number of the catalog's config under the same key.
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f) if r["name"] == "Trinity-Mini")
+        assert row["source_url"] == published["source"]
+        assert {k for k, v in row["config"].items() if published.get(k) != v} == {"num_hidden_layers"}
+    spec = traffic.load("mixedlen-sat", False)
+    assert (spec["loop"], spec["clients"], spec["block"], spec["max_total_tokens"]) == ("closed", 28, 28, 32768)
+    assert spec["prompt_tokens"] == {"dist": "lognormal", "median": 4000, "sigma": 0.9, "min": 256, "max": 24576}
+    assert spec["output_tokens"] == {"dist": "lognormal", "median": 384, "sigma": 0.6, "min": 64, "max": 1280}
+    sizes = traffic.quantiles(spec["prompt_tokens"], 28)
+    assert (sizes[0], sizes[-1]) == (604, 24576) and 5500 < sum(sizes) / 28 < 6000  # short and long in ONE block
+    other = next(w for w in bench["workloads"] if w["name"] == MCHAT_CELL)
+    assert (other["config"], other["traffic"], other["chips"]) == ("mistral-7b-v0.3-int8", "chat-sat", 1)
+
+
+@pytest.mark.parametrize(
+    "cell,missing",
+    [(AFM_CELL, n) for n in ("decode_step_ms.afm", "kv_pages_peak_pct.window.afm", "attn_gate_norm_share_pct", "window_mfu.afm")]
+    + [(MCHAT_CELL, "prefix_hit_pct.mchat")],
+)
+def test_a_traced_line_of_the_two_new_cells(cell, missing):
+    bench = resultline.load_benchmark()
+    line = _traced_line(resultline.declared(bench, cell, 2))
+    assert resultline.problems(line, bench, cell, 2, 1) == []
+    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
+    assert resultline.problems(cut, bench, cell, 2, 1) == [f"metric {missing} of this workload and mode is missing"]
+    assert resultline.problems(cut, bench, cell, 2, 1, may_miss={missing}) == []
+    if cell == AFM_CELL:
+        over = {**line, "metrics": {**line["metrics"], "window_mfu.afm": {"value": 106.0, "unit": "%"}}}
+        assert any("over 105%" in p for p in resultline.problems(over, bench, cell, 2, 1))
+
+
+def test_the_gated_window_familys_rooflines_from_counters_and_scopes():
+    """readers/afm_rooflines.py on a made-up window: 100 decode chunks of 8
+    steps, pairs that are 24 slots x (2 full layers x 10000 + 6 window
+    layers x 2048) keys a step, half the experts hit; scope seconds by the
+    difference of swa_scopes' two reductions; afm_scopes' own list."""
+    from families import afmoe_counts as counts
+    from readers import afm_rooflines, afm_scopes
+
+    with open(os.path.join(ROOT, "perfbench", "configs", "trinity-mini-bf16.json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")}
+    steps = 800
+    pairs = lambda kind, phase, n: ({"kind": kind, "phase": phase}, float(n))  # noqa: E731
+    after = {
+        "kubeai_engine_attn_pairs_total": [
+            pairs("full", "decode", steps * 24 * 2 * 10000), pairs("window", "decode", steps * 24 * 6 * 2048),
+            pairs("full", "prefill", 2 * 4e9), pairs("window", "prefill", 6 * 1e9),
+        ],
+        "kubeai_engine_moe_experts_hit_total": [({"phase": "decode"}, steps * 6 * 64.0)],
+        "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 6 * 128.0)],
+        "kubeai_engine_step_seconds_count": [({"phase": "decode_chunk"}, 100.0)],
+        "kubeai_engine_prefill_tokens_total": [({}, 6.0e5)], "kubeai_engine_generated_tokens_total": [({}, 19200.0)],
+    }
+    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
+    ctx = types.SimpleNamespace(
+        hf=hf, serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
+        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[],
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
+    )
+    by = lambda **s: {"total_s": 1.6, "by_scope_s": {k.replace("_", "."): v for k, v in s.items()}}  # noqa: E731
+    ctx.swa_scope_shares = {
+        "layers": {
+            "jit__unknown(7)": by(attn_full=0.20, attn_window=0.40, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.5, "attn.window": 0.3}},
+        },
+        "kernels": {
+            "jit__unknown(7)": by(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.05, "attn.window": 0.1, "attn.kernel": 0.65}},
+        },
+    }
+    n_steps = 10 * 8
+    full_bytes, window_bytes = 24 * 2 * 10000 * 2048, 24 * 6 * 2048 * 2048
+    read = lambda what, **kw: afm_rooflines.read(ctx, what, **kw)  # noqa: E731
+    assert read("full_attn") == pytest.approx(100 * (full_bytes / 819e9) / (0.12 / n_steps))
+    assert read("window_attn") == pytest.approx(100 * (window_bytes / 819e9) / (0.24 / n_steps))
+    expert_bytes = 6 * 64 * 3 * 2048 * 1024 * 2
+    assert read("experts") == pytest.approx(100 * (expert_bytes / 819e9) / (0.64 / n_steps))
+    outside = counts.weights_outside_experts_bytes(hf, 2)
+    assert outside == (8 * 27_271_424 + 2 * 37_748_736 + 6 * (262_272 + 6_291_456) + 200192 * 2048 + 2048) * 2
+    assert read("decode_step") == pytest.approx(100 * ((outside + expert_bytes + full_bytes + window_bytes) / 819e9) / (1.6 / n_steps))
+    flops = 4 * 4096 * (2 * 4e9 + 6 * 1e9)
+    assert read("prefill_attn", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.8 * 50.0 / 4.0))
+    all_pairs = steps * 24 * (2 * 10000 + 6 * 2048) + 2 * 4e9 + 6 * 1e9
+    assert read("window_mfu") == pytest.approx(100 * (2 * counts.active_params(hf) * 619200 + 4 * 4096 * all_pairs) / (197e12 * 50))
+    assert all(0 < read(w, **kw) < 100 for w, kw in (("full_attn", {}), ("window_attn", {}), ("experts", {}), ("decode_step", {}), ("window_mfu", {})))
+    # The tail's own polls bracket the traced seconds where the run traced itself after its window.
+    ctx.tail_view = types.SimpleNamespace(before=_scrape(60.0, **zero), polls=[], after=_scrape(65.0, **after), trace_t0=61.0, trace_t1=65.0)
+    assert read("prefill_attn", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.8 * 5.0 / 4.0))
+    # afm_scopes: what the family adds to a plain block, told apart from the layer it sits in.
+    ctx.afm_scope_shares = {
+        "jit__unknown(7)": {"total_s": 1.6, "by_scope_s": {"attn.qk_norm": 0.02, "attn.gate": 0.03, "norm.post": 0.05, "attn.window": 0.4}},
+    }
+    assert afm_scopes.read(ctx, "^jit__unknown", "attn.qk_norm|attn.gate|norm.post") == pytest.approx(100 * 0.10 / 1.6)
+    # A program of another family, or the parent's: no such counter or scope, nothing read, nothing raised.
+    ctx.afm_scope_shares = {"jit__unknown(7)": by(attn_window=0.4, moe_experts=0.6)}
+    assert afm_scopes.read(ctx, "^jit__unknown", "attn.qk_norm|attn.gate|norm.post") is None
+    ctx.hf = {**hf, "model_type": "smallthinker"}
+    assert all(read(w) is None for w in ("window_mfu", "experts", "full_attn", "decode_step"))
+    ctx.hf, ctx.after = hf, _scrape(50.0)
+    assert all(read(w) is None for w in ("window_mfu", "experts", "full_attn", "window_attn", "decode_step", "prefill_attn"))
+    ctx.trace, ctx.afm_scope_shares = None, None
+    assert afm_scopes.read(ctx, "^jit__unknown", "norm.post") is None
+
+
+@pytest.mark.slow  # two minutes alone: not tier-1, as the other families' rehearsals are not
+def test_rehearsal_of_the_gated_window_models_cell(tmp_path):
+    """--rehearse --trace 2 of trinitymini-bf16-mixedlen-sat at the
+    configuration's `rehearsal` keys (8 layers, window 256, 8 experts
+    top-2): every phase, the family's logits check through both pools past
+    the window, and every per-layer metric the CPU can read."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", AFM_CELL, "--rehearse",
+         "--trace", "2", "--seed", str(2**31 + 13)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900,
+    )
+    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
+    last = lines[-1]
+    bench = resultline.load_benchmark()
+    # Time a step of one scope means nothing in a CPU trace (readers/afm_rooflines.py), and the tail's 4 s
+    # of a rehearsal's five clients need not hold a whole run of a prefill program.
+    may_miss = AFM_ROOFLINES | {"prefill_attn_full_share_pct", "prefill_moe_share_pct.afm", "prefill_share_pct.afm"}
+    assert resultline.problems(last, bench, AFM_CELL, 2, 1, rehearsal=True, may_miss=may_miss) == []
+    assert set(resultline.declared(bench, AFM_CELL, 2)) - may_miss <= set(last["metrics"])
+    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    assert set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
+    assert all(part["ok"] for part in logits["compared"].values())
+    assert logits["sample"]["long_prompt"] == 800 and logits["sample"]["window_pages_released"] > 0
+    assert 0 < last["metrics"]["moe_experts_hit_pct.afm"]["value"] <= 100
+    assert 0 < last["metrics"]["attn_gate_norm_share_pct"]["value"] < 100
+    assert last["metrics"]["kv_pages_peak_pct.window.afm"]["value"] > 0

@@ -1,10 +1,12 @@
 """The accepted families are untouched by a change to what they share
-(`engine/core.py`, `ops/moe.py`, `models/base.py`): the step programs of the
-dense, `deepseek_v3` and `smallthinker` toy configurations of
-tests/test_named_scopes.py lower to the text they lowered to before (text
-without locations, so an edit that only moves lines does not show). The
-digests in tests/data/step_program_digests.json were taken at the parent of
-PR 40 (commit c004975) by this file's own `digests()`:
+(`engine/core.py`, `ops/moe.py`, `models/base.py`, and since PR 42
+`models/smallthinker.py::TwoPools`): the step programs of the toy
+configurations of tests/test_named_scopes.py lower to the text they lowered
+to before (text without locations, so an edit that only moves lines does
+not show). The digests in tests/data/step_program_digests.json were taken by
+this file's own `digests()`: the dense, `deepseek_v3` and `smallthinker`
+ones at the parent of PR 40 (commit c004975), `nemotron_h`'s at the parent
+of PR 42 (commit 1b02cb5), `afmoe`'s as PR 42 left its module:
 
     JAX_PLATFORMS=cpu python tests/test_step_programs_unchanged.py > tests/data/step_program_digests.json
 
@@ -25,7 +27,10 @@ sys.path.insert(0, HERE)
 
 import test_named_scopes as scopes  # noqa: E402
 
-FAMILIES = {"dense": None, "deepseek_v3": scopes.DEEPSEEK, "smallthinker": scopes.SMALLTHINKER}
+FAMILIES = {
+    "dense": None, "deepseek_v3": scopes.DEEPSEEK, "smallthinker": scopes.SMALLTHINKER, "nemotron_h": scopes.NEMOTRON_H,
+    "afmoe": scopes.AFMOE,
+}
 DIGESTS = os.path.join(HERE, "data", "step_program_digests.json")
 
 

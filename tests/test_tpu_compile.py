@@ -464,3 +464,84 @@ def test_a_mixer_an_attention_and_an_expert_block_of_nemotron_decode_in_place(v5
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= held
     assert memory.temp_size_in_bytes < cache["ssm"].shape[1] * 128 * 64 * 128 * 4 // 2  # no copy of the state
+
+
+# -- Trinity-Mini (afmoe): the kernels of its step at the published widths ---------
+
+TRINITY = (32, 4)  # 32 query / 4 KV heads of 128 (G = 8: no other configuration groups so)
+AFM_WINDOW, AFM_SLOTS, AFM_MAX_LEN = 2048, 24, 32768
+
+
+@pytest.mark.parametrize(
+    "B,S,columns,window",
+    [
+        (AFM_SLOTS, 1, AFM_MAX_LEN // PAGE, None),
+        (AFM_SLOTS, 1, (AFM_WINDOW - 1) // PAGE + 2, AFM_WINDOW),
+        (1, 1024, AFM_MAX_LEN // PAGE, None),
+        (1, 1024, (1024 + AFM_WINDOW - 2) // PAGE + 2, AFM_WINDOW),
+        (8, 32, (32 + AFM_WINDOW - 2) // PAGE + 2, AFM_WINDOW),
+    ],
+    ids=["decode/full-512-pages", "decode/window-33-pages", "chunk-1024/full-512-pages", "chunk-1024/window-49-pages", "cold-8x32/window"],
+)
+def test_ragged_paged_kernel_lowers_at_g8_behind_512_page_tables(v5e, B, S, columns, window):
+    """The cell trinitymini-bf16-mixedlen-sat: 24 slots of 512 pages (twice
+    the widest table that had run); a full layer walks the slot's whole
+    table, up to 1024 query rows behind 25k cached keys; a window layer
+    the columns from its first visible page."""
+    q, pool, _, lens = _paged_args(v5e[0], B, S, TRINITY, max_len=AFM_MAX_LEN)
+    table = _sds(v5e, (B, columns), jnp.int32)
+    text = _compile(
+        lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, sliding_window=window), q, pool, table, lens,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)], ids=["gate_up", "down"])
+@pytest.mark.parametrize("rows", [24 * 8, 8 * 1024, 8 * 1024 * 8, 8 * 32])
+def test_grouped_expert_matmul_lowers_at_trinitys_widths(v5e, rows, k, n):
+    """24 slots x 8 choices at decode (192 rows: one tile), a chunk's 8192,
+    a full group's 65536 and the smallest bucket's 256, over the 6 x 128
+    experts of the whole stack; an expert's 2048 x 1024 matrix is one tile
+    (4 MiB in bf16: the most `gmm_tiles` leaves whole)."""
+    from kubeai_tpu.ops.moe import gmm_tiles, grouped_matmul
+
+    assert gmm_tiles(rows, k, n)[1:] == (k, n)
+    text = _compile(
+        grouped_matmul,
+        _sds(v5e, (rows, k), jnp.bfloat16), _sds(v5e, (6 * 128, k, n), jnp.bfloat16), _sds(v5e, (6 * 128,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_the_first_period_of_trinity_decodes_through_both_pools(v5e):
+    """A decode step of the first period (dense + window twice, experts +
+    window, experts + full) at the published widths and the cell's 512-page
+    tables: the step holds both kinds' paged kernels and the grouped
+    matmuls, and both pools come back in place."""
+    from kubeai_tpu.engine.coldstart import param_shapes
+    from kubeai_tpu.models import afmoe
+
+    mc = ModelConfig(
+        model_type="afmoe", vocab_size=1024, hidden_size=2048, intermediate_size=6144, num_layers=4, num_heads=32,
+        num_kv_heads=4, head_dim=128, dtype="bfloat16", num_experts_per_tok=8, n_routed_experts=128, n_shared_experts=1,
+        moe_intermediate_size=1024, first_k_dense_replace=2, routed_scaling_factor=2.826, embed_scale=True,
+        sliding_window_size=AFM_WINDOW, sliding_window_layout=(1, 1, 1, 0), rope_layout=(1, 1, 1, 0), rope_theta=10000.0,
+        use_flash_prefill=True, use_paged_kernel=True,
+    )
+    B, max_pages = AFM_SLOTS, AFM_MAX_LEN // PAGE
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), param_shapes(mc))
+    pools = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(lambda: afmoe.init_paged_cache(mc, 6145, PAGE, window_pages=B * 49 + 1)),
+    )
+    compiled = jax.jit(
+        lambda p, t, c, tbl, lens, active: afmoe.decode_step_paged(p, mc, t, c, tbl, lens, live=LiveRows.first(active)),
+        donate_argnums=(2,),
+    ).lower(
+        params, _sds(v5e, (B, 1), jnp.int32), pools, _sds(v5e, (B, 2 * max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.bool_),
+    ).compile()
+    # One period, unrolled: 4 paged kernels and 2 x 3 grouped matmuls.
+    assert compiled.as_text().count("tpu_custom_call") >= 10
+    pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
