@@ -308,15 +308,17 @@ def warm_compile(
                 sds((n_pad,), i32), sds((n_pad, Kb), i32),
                 sds((n_pad, Kb), f32), sds((B,), i32), cache,
             )
-    max_bucket = max(cfg.prefill_buckets)
-    compile_one(
-        f"prefill_chunk[{max_bucket}]",
-        sf.prefill_chunk_jit,
-        params, sds((1, max_bucket), i32), sds((), i32), sds((), i32),
-        sds((1, table_cols), i32), sds((), i32), sds((), u32), sds((), f32),
-        sds((), f32), sds((), i32), sds((Kb,), i32), sds((Kb,), f32),
-        sds((B,), i32), cache,
-    )
+    # The chunk calls that carry a long prompt: the largest bucket, and the
+    # wide chunk where a prompt of this engine reaches it (prefill_plan).
+    for rows in sorted({max(cfg.prefill_buckets), core.wide_chunk(cfg)}):
+        compile_one(
+            f"prefill_chunk[{rows}]",
+            sf.prefill_chunk_jit,
+            params, sds((1, rows), i32), sds((), i32), sds((), i32),
+            sds((1, table_cols), i32), sds((), i32), sds((), u32), sds((), f32),
+            sds((), f32), sds((), i32), sds((Kb,), i32), sds((Kb,), f32),
+            sds((B,), i32), cache,
+        )
     out = {"shapes": shapes, "seconds": round(time.monotonic() - t0, 3)}
     if errors:
         out["errors"] = errors

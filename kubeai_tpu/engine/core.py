@@ -187,6 +187,39 @@ def engine_dims(cfg: EngineConfig) -> tuple[int, int, int]:
     return max_pages, P, hist_width
 
 
+def wide_chunk(cfg: EngineConfig) -> int:
+    """Rows of the widest chunk call a prompt of this engine is cut into
+    (prefill_plan): twice the largest prefill bucket, 2048 as configured,
+    where a prompt can be that long, and the largest bucket where none
+    can (`max_seq_len` does not exceed it: no such program is compiled).
+    `max(prefill_buckets)` stays the longest prompt ONE cold call takes."""
+    top = max(cfg.prefill_buckets)
+    return 2 * top if cfg.max_seq_len > 2 * top else top
+
+
+def prefill_plan(cfg: EngineConfig, left: int) -> list[tuple[int, int]]:
+    """The chunk calls that prefill the *left* tokens of a prompt behind
+    whatever is cached, as (rows of the call, real tokens in it): wide
+    calls while that many tokens are left (a chunk reads every weight
+    once whatever its rows, and an expert family's chunk is bound by
+    reading them: PERF.md section 6, PR 43), then one call of the
+    largest bucket if more than that is left, then the tail in the
+    smallest bucket that holds it. Only the last call is padded. Reads
+    nothing but the tokens left: the plan of a prompt's last `left`
+    tokens is the end of the plan of the whole prompt wherever the cut
+    falls on a call's edge (_plan_admission cuts a hit there for the
+    families with REUSE_WHOLE_PREFILL_CALLS)."""
+    top, wide = max(cfg.prefill_buckets), wide_chunk(cfg)
+    plan = [(wide, wide)] * (left // wide)
+    left %= wide
+    if left > top:
+        plan.append((top, top))
+        left -= top
+    if left:
+        plan.append((next(b for b in cfg.prefill_buckets if left <= b), left))
+    return plan
+
+
 def window_pool_dims(model_config: ModelConfig, cfg: EngineConfig) -> tuple[int, int]:
     """(window in tokens, pages of the window layers' pool) for a family
     whose window layers keep a page pool of their own beside the full
@@ -199,7 +232,7 @@ def window_pool_dims(model_config: ModelConfig, cfg: EngineConfig) -> tuple[int,
     window = family(model_config).window_pool_tokens(model_config)
     if not window:
         return 0, 0
-    cap = WindowPages.slot_cap(engine_dims(cfg)[0], window, max(cfg.prefill_buckets), cfg.page_size)
+    cap = WindowPages.slot_cap(engine_dims(cfg)[0], window, wide_chunk(cfg), cfg.page_size)
     return window, cfg.max_slots * cap + 1
 
 
@@ -632,6 +665,13 @@ class Engine:
             "prompt positions computed as bucket/batch padding (prefill waste; "
             "compare against kubeai_engine_prefill_tokens_total)",
         )
+        self.m_chunk_tokens = default_registry.counter(
+            "kubeai_engine_prefill_chunk_tokens_total",
+            "real prompt tokens prefilled by chunk calls (a prompt longer "
+            "than the largest bucket, or one behind cached tokens), by the "
+            "call's rows: the wide chunk, the largest bucket or the tail's "
+            "bucket (how often the wide chunk engages)",
+        )
         self.m_prefill_rows = default_registry.counter(
             "kubeai_engine_prefill_rows_total",
             "rows the cold group prefill calls computed, by kind: real (a "
@@ -847,6 +887,12 @@ class Engine:
                 kind: int(self.m_prefill_rows.value(labels={"kind": kind}))
                 for kind in ("real", "duplicate")
             },
+            # Real prompt tokens the chunk calls prefilled, by the call's
+            # rows (kubeai_engine_prefill_chunk_tokens_total).
+            "prefill_chunk_tokens": {
+                str(rows): int(self.m_chunk_tokens.value(labels={"rows": str(rows)}))
+                for rows in sorted({*self.cfg.prefill_buckets, wide_chunk(self.cfg)})
+            },
             # Rows x steps of the decode chunks dispatched so far, by the
             # `active` mask they were given, and the live share of them
             # (kubeai_engine_decode_rows_total).
@@ -949,7 +995,7 @@ class Engine:
         self._page_table = np.zeros((B, table_width(self.model_config, self.cfg)), np.int32)
         window = window_pool_dims(self.model_config, self.cfg)[0]
         self._wpages = (
-            WindowPages(self._page_table[:, self._max_pages :], window, max(self.cfg.prefill_buckets), ps)
+            WindowPages(self._page_table[:, self._max_pages :], window, wide_chunk(self.cfg), ps)
             if window else None
         )
         # Where each slot's next decode chunk starts (the device's own
@@ -1127,11 +1173,13 @@ class Engine:
                 )
                 shapes += 1
         # Chunked prefill pads its FINAL chunk to the smallest fitting
-        # bucket (non-final chunks are always max_bucket wide), so the
-        # serving path hits one chunk shape per bucket — warming only
-        # max_bucket leaves a mid-serving compile on the first
-        # prefix-reuse prompt whose tail lands in a smaller bucket.
-        for bucket in self.cfg.prefill_buckets:
+        # bucket (the calls before it are the largest bucket or the wide
+        # chunk: prefill_plan), so the serving path hits one chunk shape
+        # per bucket — warming only max_bucket leaves a mid-serving
+        # compile on the first prefix-reuse prompt whose tail lands in a
+        # smaller bucket — and ONE more, the wide chunk, where a prompt
+        # of this engine can be that long.
+        for bucket in sorted({*self.cfg.prefill_buckets, wide_chunk(self.cfg)}):
             *_, self._cache, self._adm_toks, _counters = self._prefill_chunk_jit(
                 self.params,
                 np.zeros((1, bucket), np.int32),
@@ -2412,18 +2460,21 @@ class Engine:
 
         if self.cfg.prefix_cache_min:
             claimed = self._pool.match_prefix(ids, sig)
-            step = 1  # pages a hit is cut down to a multiple of
+            # Pages a hit may be cut down to, longest first: any number up
+            # to what was found, or ...
+            cuts = range(len(claimed), 0, -1)
             if claimed and family(self.model_config).REUSE_WHOLE_PREFILL_CALLS:
-                # A hit takes away whole leading calls of the prompt's cold
-                # prefill and nothing else (models/deepseek.py).
-                call = max(self.cfg.prefill_buckets)
-                step = call // ps if call % ps == 0 else len(claimed) + 1
-            keep = len(claimed) // step * step
+                # ... whole leading calls of the prompt's cold prefill and
+                # nothing else (models/deepseek.py): the edges between the
+                # cold plan's calls that fall on a page's edge.
+                edges = np.cumsum([rows for rows, _ in prefill_plan(self.cfg, len(ids))[:-1]])
+                cuts = [int(e) // ps for e in edges[::-1] if e % ps == 0 and e <= len(claimed) * ps]
+            keep = next(iter(cuts), 0)
             if wp is not None:
                 # ... and only where the window pool still holds the pages
                 # the first new query can see (models/smallthinker.py).
                 w_digests = wp.pool.chain_digests(ids, sig)
-                keep, w_claimed = wp.match(w_digests, keep, step)
+                keep, w_claimed = wp.match(w_digests, cuts)
             self._pool.release(claimed[keep:])
             claimed = claimed[:keep]
             if claimed and len(claimed) * ps < self.cfg.prefix_cache_min:
@@ -2512,29 +2563,29 @@ class Engine:
         if req.trace is not None:
             req.trace.mark("prefill")
             req.trace.attrs["reuse_tokens"] = reuse
-        max_bucket = max(self.cfg.prefill_buckets)
-        # Only the last chunk is shorter than the largest bucket.
-        tail = (len(ids) - reuse) % max_bucket
-        pad_tokens = self._bucket(tail) - tail if tail else 0
+        plan = prefill_plan(self.cfg, len(ids) - reuse)
+        widest = plan[0][0]  # the calls come widest first
+        # Only the last call is shorter than its rows.
+        pad_tokens = plan[-1][0] - plan[-1][1]
         with self._stall.segment(
-            "prefill", kind="chunk", bucket=max_bucket, batch=1,
+            "prefill", kind="chunk", bucket=widest, batch=1,
             tokens=len(ids) - reuse, cached=reuse, pad=pad_tokens,
         ) as seg:
-            out = self._prefill_chunks(slot_idx, req, reuse, seed, max_bucket)
+            out = self._prefill_chunks(slot_idx, req, reuse, seed, plan)
         self.m_step.observe(seg.seconds, labels={"phase": "prefill_chunked"})
         self._stall.end_step("prefill_chunked")
         if pad_tokens:
             self.m_pad_prefill.inc(pad_tokens)
         default_recorder.record_step(
             kind="prefill_chunked", slot=slot_idx,
-            kernel=self._attn_kernel("prefill_chunked", max_bucket),
+            kernel=self._attn_kernel("prefill_chunked", widest),
             prompt_tokens=len(ids), reuse_tokens=reuse,
             pad_tokens=pad_tokens,
             dur_ms=round(seg.seconds * 1000, 3),
         )
         return out
 
-    def _prefill_chunks(self, slot_idx: int, req: Request, reuse: int, seed, max_bucket: int):
+    def _prefill_chunks(self, slot_idx: int, req: Request, reuse: int, seed, plan: list[tuple[int, int]]):
         ids = req.prompt_ids
         sp = req.params
         lora_args = {}
@@ -2546,10 +2597,10 @@ class Engine:
         table = self._page_table[slot_idx : slot_idx + 1].copy()
         bias_ids, bias_vals = self._bias_rows(sp)
         tok = lp = None
-        for start in range(reuse, len(ids), max_bucket):
-            chunk = ids[start : start + max_bucket]
-            is_last = start + max_bucket >= len(ids)
-            bucket = max_bucket if not is_last else self._bucket(len(chunk))
+        start = reuse
+        for bucket, real in plan:
+            chunk = ids[start : start + real]
+            self.m_chunk_tokens.inc(real, labels={"rows": str(bucket)})
             if self._wpages is not None:
                 # The window table moves with the chunk: pages behind its
                 # first query's window go back, the chunk's own come.
@@ -2590,6 +2641,7 @@ class Engine:
                     **lora_args,
                 )
                 self._program_counters.append(("prefill", bucket, counters))
+            start += real
 
         self._register(slot_idx, req, seed, lora_row, reuse)
         return (slot_idx, self._slot_epoch[slot_idx], tok, None, lp, t_ids, t_lp)

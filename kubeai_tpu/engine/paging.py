@@ -305,7 +305,8 @@ class WindowPages:
     (content-registered ones stay findable until the pool needs them),
     pages up to the call's last position are allocated. A slot therefore
     holds at most `cap = (window + chunk) / page + 1` pages whatever its
-    length, and the pool is sized so that every slot can hold its cap at
+    length (*chunk*: the widest call the engine makes for one slot,
+    `engine/core.py::wide_chunk`), and the pool is sized so that every slot can hold its cap at
     once: a window page is always there when a slot needs it, so
     admission never waits on this pool and nothing is reserved ahead."""
 
@@ -338,16 +339,14 @@ class WindowPages:
     def held(self, slot: int) -> int:
         return self._hi[slot] - self._lo[slot]
 
-    def match(self, digests: list[bytes], n: int, step: int) -> tuple[int, list[int]]:
-        """The longest prefix of at most *n* pages, a multiple of *step*,
+    def match(self, digests: list[bytes], cuts) -> tuple[int, list[int]]:
+        """The first of *cuts* (a prefix's length in pages, longest first)
         whose visible window pages are all resident: (pages of prefix,
         the claimed window pages), (0, []) where there is none."""
-        n = n // step * step
-        while n > 0:
+        for n in cuts:
             pages = self.pool.claim_pages(digests[self.first_page(n * self.page_size) : n])
             if pages is not None:
                 return n, pages
-            n -= step
         return 0, []
 
     def admit(self, slot: int, digests: list[bytes], reuse_pages: int, claimed: list[int], limit: int) -> None:

@@ -37,10 +37,16 @@ the pages ("xla").
 
 **A prefix found in the cache is used in whole prefill calls**
 (`REUSE_WHOLE_PREFILL_CALLS`, read by `engine/core.py::_plan_admission`):
-a hit is cut down to a multiple of the largest prefill bucket, so what
-is left of the prompt is prefilled by the very calls a cold prefill of it
-ends with (same program, same shapes, same offsets, same bits in the
-pages before them) and gives the same bits. A dense decoder takes a hit
+a hit is cut down to an edge between two calls of the prompt's COLD plan
+(`engine/core.py::prefill_plan`: calls of the wide chunk, 2048 rows,
+while that many tokens are left, then one of the largest bucket, 1024,
+if more than that is left, then the tail; so a hit keeps `j x 2048`
+tokens, `j` at most the cold plan's wide calls, or all of them and the
+1024-row call behind them; a prompt under 2048 tokens keeps its first
+1024 as it always did), so what is left of the prompt is prefilled by
+the very calls a cold prefill of it ends with (same program, same shapes,
+same offsets, same bits in the pages before them) and gives the same
+bits. A dense decoder takes a hit
 to the page, and the tail then runs in another bucket than the cold
 prompt did: the compiler fuses and tiles by the call's shape, a token's
 hidden state comes out a bf16 step apart, and there it stays a rounding.
@@ -49,7 +55,11 @@ sixth of a token's routed output, and moves a first-position log-prob by
 up to 0.5 (chip, PR 33: 3-15 of 20 prompts gave other tokens cold than
 behind their cached pages, and still 3 of 20 with excess precision off
 and every sum written out in order; PERF.md section 6). The price: a
-shared prefix shorter than the largest bucket is recomputed.
+shared prefix shorter than the largest bucket is recomputed, and of a
+longer one what lies past the last such edge. (The wide call is itself
+another shape than two calls of 1024 rows: a prompt's tokens may differ
+by such a rounding from what the narrower plan gave, and cold against
+cached they still agree to the bit, which is the promise.)
 
 **Rope on interleaved pairs** (`rope_interleave`): HF rotates the pairs
 `(x[2j], x[2j+1])`; the loader permutes the rope columns of `q_proj` and

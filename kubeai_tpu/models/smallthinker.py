@@ -33,7 +33,9 @@ table row is two tables side by side, `[full | window]`, each
 (`engine/paging.py::WindowPages`) keeps in the window table only the
 pages some query of the next call can still see and hands the others
 back, so a slot holds at most `(window + chunk) / page + 1` window pages
-whatever its length, while its full pages grow with it.
+whatever its length (`chunk`: the widest chunk call, `engine/core.py::
+wide_chunk`, 2048 rows where a prompt can be that long: 97 pages at the
+published window), while its full pages grow with it.
 
 **A window layer reads its window.** The kernel's `sliding_window` only
 masks: it would still copy every page up to the sequence's end. So the
@@ -47,7 +49,8 @@ nothing else moves. The portable route gathers the same columns.
 **Routes** (`cached_attention_route`): cold prefill of 256 rows or more
 takes the flash kernel for both kinds where the call is no longer than
 the window (the window mask is then all true; the engine's largest
-bucket, 1024, is a quarter of the published window); decode, chunks
+bucket, 1024, the longest cold call, is a quarter of the published
+window); decode, chunks
 behind cached tokens and every other cold call take the ragged paged
 kernel on the chip ("paged_kernel"); everything on the CPU the portable
 gather ("xla") unless a test asks for the kernel's twin.

@@ -47,13 +47,23 @@ def kernel_blocks(S: int, G: int, pages_per_seq: int, page: int) -> tuple[int, i
     KV: 512 tokens a block, which a short context does not copy far
     past its end, and at least twice the query rows a slot, since a
     chunk sits behind that many keys or more and every block turn costs
-    what scoring 300 (G=4) to 1300 (G=7) keys does. Swept on a v5e at
+    what scoring 300 (G=4) to 1300 (G=7) keys does; never more than
+    2048 tokens, which is what a chunk of 1024 rows takes and what the
+    wide chunk of 2048 rows (engine/core.py::prefill_plan) keeps: every
+    query block walks every KV block up to the call's LAST row, whole
+    blocks, so 4096-token blocks cost a call that ends 5k tokens in a
+    block's worth of masked keys a query block. Swept on a v5e at
     page 64 (PERF.md section 6, PR 30): at decode 8 pages x 1 query is
     the fastest or within 4% of it from 32 slots x 350 tokens (59 us a
     call against 377) to 8 slots x 8000; an fp8 pool liked 4 pages 7%
-    better."""
+    better. At 2048 rows (PERF.md section 6, PR 43; G = 4, 7, 8, 16
+    behind 2-22k cached tokens): 32 pages a block read from 24% under
+    64 pages (behind 3-4k) to 5% over them (behind 12-22k), and 2-9%
+    under two calls of 1024 rows at every shape but one (G = 4 starting
+    3072 tokens in, a start no plan gives a cold prompt: +12%); twice
+    the query rows a block gained under 2%."""
     queries = min(S, 1 << (max(1, 256 // G).bit_length() - 1))
-    kv_pages = max(512, 2 * S) // page
+    kv_pages = max(512, min(2 * S, 2048)) // page
     return max(1, min(kv_pages, pages_per_seq)), queries
 
 

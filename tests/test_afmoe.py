@@ -47,7 +47,8 @@ from kubeai_tpu.obs.perf import param_counts  # noqa: E402
 LOGITS_ABS = 2e-5
 CHOICE_EPS = 1e-5
 PAGE, WINDOW, CHUNK = 8, 32, 32
-CAP = (WINDOW + CHUNK) // PAGE + 1
+WIDE = 2 * CHUNK  # the engine's widest chunk call (core.wide_chunk): max_seq_len is past it
+CAP = (WINDOW + WIDE) // PAGE + 1
 TYPES = ["sliding_attention"] * 3 + ["full_attention"]
 
 HF = {

@@ -269,10 +269,12 @@ def test_warmup_covers_all_shapes_and_engine_serves(ckpt_dir):
     stats = eng.cold_start_timeline.snapshot()["attrs"]["warmup"]
     # decode + (1, cap) x 2 buckets + chunk x 2 buckets (the final
     # chunk of a chunked prefill pads to the smallest fitting bucket,
-    # so every bucket is a live chunk shape) = 7 shapes for TINY_EC,
+    # so every bucket is a live chunk shape) + the wide chunk of 32
+    # rows (a prompt of TINY_EC's 64 positions reaches it:
+    # core.prefill_plan) = 8 shapes for TINY_EC,
     # + 4 restore-path shapes (KV evolve, import pow2 1 and 2,
     # slotset) on a single-host engine with KV restore enabled.
-    assert stats["shapes"] == 11
+    assert stats["shapes"] == 12
     eng.start()
     try:
         ids, _, fin = eng.generate(

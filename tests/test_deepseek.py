@@ -354,13 +354,19 @@ def generate(eng, prompt, n=6):
             raise RuntimeError(ev[1])
 
 
-@pytest.mark.parametrize("n,reused", [(150, 128), (51, 0)], ids=["two_whole_calls", "shorter_than_a_call"])
+@pytest.mark.parametrize(
+    "n,reused", [(150, 128), (51, 0), (220, 192), (100, 64)],
+    ids=["one_wide_call", "shorter_than_a_call", "a_wide_call_and_one_of_the_largest_bucket", "under_the_wide_width"],
+)
 def test_a_prefix_hit_on_latent_pages_gives_the_cold_runs_bits(eng, n, reused):
-    """A hit is used in whole prefill calls (the largest bucket, 64 here):
-    150 tokens run cold as chunks at 0, 64 and 128, and behind their 9
-    cached pages as the chunk at 128 alone, the same call on the same
-    pages; 51 tokens' 3 cached pages are less than a call and the prompt
-    runs as it did cold. Either way the same tokens and the same
+    """A hit is used in whole prefill calls (the wide chunk is 128 rows
+    here, the largest bucket 64): 150 tokens run cold as a wide call and
+    the chunk at 128, and behind their 9 cached pages as the chunk at 128
+    alone, the same call on the same pages; 220 tokens run cold as calls
+    at 0 (128 rows), 128 (64) and 192, and behind 13 cached pages as the
+    last alone; 100 tokens never see the wide call and reuse their first
+    64 as they always did; 51 tokens' 3 cached pages are less than a call
+    and the prompt runs as it did cold. Either way the same tokens and the same
     log-probs to the bit, which is what a router needs
     (models/deepseek.py)."""
     assert deepseek.REUSE_WHOLE_PREFILL_CALLS and not llama.REUSE_WHOLE_PREFILL_CALLS

@@ -133,6 +133,11 @@ _BLOCK_CASES = {
     "mistral-7b/chunk-1024": (32, 8, 1, 1024, 8192, jnp.bfloat16),
     "qwen2.5-7b/decode-fp8-pool": (28, 4, 32, 1, 2048, jnp.float8_e4m3fn),
     "qwen2.5-7b/tp4/decode": (7, 1, 32, 1, 2048, jnp.bfloat16),
+    # The wide chunk (engine/core.py::prefill_plan): 2048 rows of one slot.
+    "mistral-7b/chunk-2048": (32, 8, 1, 2048, 8192, jnp.bfloat16),
+    "smallthinker/chunk-2048": (28, 4, 1, 2048, 16384, jnp.bfloat16),
+    "trinity/chunk-2048": (32, 4, 1, 2048, 32768, jnp.bfloat16),
+    "nemotron/chunk-2048": (32, 2, 1, 2048, 8192, jnp.bfloat16),
 }
 
 
@@ -182,6 +187,11 @@ def test_kernel_blocks_are_chosen_from_the_calls_shapes(monkeypatch, case):
         # another slot's keys.
         assert queries == S
         assert kv_pages < default[0] and queries < default[1]
+    if S == 2048:
+        # The wide chunk keeps the KV block of a 1024-row chunk, 2048
+        # tokens, and a query block of 256 score rows (PERF.md section 6, PR 43).
+        assert kv_pages == 32 == pa.kernel_blocks(1024, H // Kv, mp, ps)[0]
+        assert queries == {4: 64, 7: 32, 8: 32, 16: 16}[H // Kv]
     # A sweep's pair goes through as given and leaves no record.
     jax.eval_shape(
         lambda q, kv, tbl, lens: pa.paged_attention_ragged(q, kv, tbl, lens, blocks=(2, 8), **quant),

@@ -48,7 +48,8 @@ from kubeai_tpu.ops import moe  # noqa: E402
 LOGITS_ABS = 2e-5
 CHOICE_EPS = 1e-5
 PAGE, WINDOW, CHUNK = 8, 32, 32
-CAP = (WINDOW + CHUNK) // PAGE + 1
+WIDE = 2 * CHUNK  # the engine's widest chunk call (core.wide_chunk): max_seq_len is past it
+CAP = (WINDOW + WIDE) // PAGE + 1
 
 HF = {
     "model_type": "smallthinker", "vocab_size": 384, "hidden_size": 64, "num_hidden_layers": 8,
@@ -172,7 +173,7 @@ def test_chunked_prefill_and_decode_through_both_pools_agree_with_the_reference(
     assert d["compared"] == 8 * tokens.size and d["worst_gap"] <= CHOICE_EPS
     # The window budget: never more than the cap, pages handed back as
     # the row advanced, and at the end no more than a window's worth.
-    assert held <= CAP == 9
+    assert held <= wp.cap == 9
     assert wp.released == (139 - WINDOW + 1) // PAGE and wp.held(0) == WINDOW // PAGE + 1
     assert wp.pool.used() == wp.held(0)
 
@@ -280,13 +281,18 @@ def test_a_slots_window_pages_stay_under_the_cap_while_its_full_pages_grow(eng):
 # -- (b) a prefix hit across the two pools ------------------------------------
 
 
-@pytest.mark.parametrize("n,reused", [(120, 96), (40, 32), (30, 0)], ids=["three_whole_calls", "one_call", "shorter_than_a_call"])
+@pytest.mark.parametrize(
+    "n,reused", [(120, 96), (40, 32), (30, 0), (200, 192)],
+    ids=["a_wide_call_and_one_of_the_largest_bucket", "one_call", "shorter_than_a_call", "three_wide_calls"],
+)
 def test_a_prefix_hit_across_both_pools_gives_the_cold_runs_bits(eng, source, n, reused):
-    """A hit is used in whole prefill calls (the largest bucket, 32) and
-    only where the window pool still holds the pages the first new query
-    sees: 120 tokens run cold as chunks at 0, 32, 64 and 96, and behind
-    their cached pages as the chunk at 96 alone, on 12 claimed full pages
-    and the 4 window pages of positions 64-95. The same tokens and
+    """A hit is used in whole prefill calls (the wide chunk, 64 rows, the
+    largest bucket, 32) and only where the window pool still holds the
+    pages the first new query sees: 120 tokens run cold as chunks at 0 (64
+    rows), 64 and 96, and behind their cached pages as the chunk at 96
+    alone, on 12 claimed full pages and the 4 window pages of positions
+    64-95; 200 tokens as three wide calls and a tail, and behind 24 cached
+    pages as the tail alone. The same tokens and
     log-probs to the bit, and the reference's."""
     assert smallthinker.REUSE_WHOLE_PREFILL_CALLS and not smallthinker.KV_PARK and llama.KV_PARK
     prompt = [1] + np.random.default_rng(100 + n).integers(32, 127, n - 1).tolist()
@@ -341,7 +347,7 @@ def test_window_pages_moves_one_contiguous_run_and_registers_what_it_hands_back(
     assert wp.released == 2 and (table[0] > 0).tolist() == [False] * 2 + [True] * 5 + [False] * 9
     wp.settle(0)
     # What was handed back is still findable; a second slot claims it.
-    n, pages = wp.match(digests, 6, 2)
+    n, pages = wp.match(digests, range(6, 0, -2))
     assert n == 6 and len(pages) == 6 - wp.first_page(48)
     wp.admit(1, digests, n, pages, limit=10)
     assert wp.held(1) == len(pages) and table[1, wp.first_page(48) : 6].tolist() == pages

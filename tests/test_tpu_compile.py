@@ -30,6 +30,7 @@ from kubeai_tpu.ops.paged_attention import paged_attention_ragged
 QWEN25_7B = (28, 4)
 LLAMA3_8B = (32, 8)  # Mistral-7B has the same grouping
 GEMMA_2B = (8, 1)
+NEMOTRON_ATTN = (32, 2)  # the one attention block of nemotron3-super's cut: G = 16
 # What one of four tp shards sees of the two 7B/8B models.
 QWEN25_7B_TP4 = (7, 1)
 LLAMA3_8B_TP4 = (8, 2)
@@ -129,6 +130,10 @@ def test_flash_prefill_lowers(v5e, heads):
         # 128 pages; a 1024-row chunk of a document behind its prefix.
         (LLAMA3_8B, 8, 1, jnp.bfloat16, 8192),
         (LLAMA3_8B, 1, 1024, jnp.bfloat16, 8192),
+        # The wide chunk (engine/core.py::prefill_plan): 2048 rows of one
+        # slot, at G = 4 and at agent-sat's G = 16 behind 128 pages.
+        (LLAMA3_8B, 1, 2048, jnp.bfloat16, 8192),
+        (NEMOTRON_ATTN, 1, 2048, jnp.bfloat16, 8192),
     ],
     ids=[
         "qwen2.5-7b/decode", "qwen2.5-7b/prefill-8x512", "qwen2.5-7b/decode-fp8-pool",
@@ -136,6 +141,7 @@ def test_flash_prefill_lowers(v5e, heads):
         "qwen2.5-7b/tp4/decode", "qwen2.5-7b/tp4/prefill-8x512",
         "llama3-8b/tp4/decode", "gemma-2b/decode",
         "mistral-7b/decode-8x8192", "mistral-7b/chunk-1024-of-8192",
+        "mistral-7b/chunk-2048-of-8192", "nemotron/chunk-2048-of-8192",
     ],
 )
 def test_ragged_paged_kernel_lowers(v5e, heads, B, S, pool_dtype, max_len):
@@ -265,10 +271,11 @@ def test_mla_paged_decode_kernel_lowers(v5e, B, max_len):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("B,S", [(1, 256), (8, 1024)])
+@pytest.mark.parametrize("B,S", [(1, 256), (8, 1024), (1, 2048)])
 def test_latent_prefill_attention_lowers(v5e, B, S):
     """Every prefill's attention: 32 query heads of 640 over latent pages,
-    in blocks of keys (XLA; no kernel)."""
+    in blocks of keys (XLA; no kernel). `[1, 2048]` is the wide chunk,
+    which reason-sat's engine warms and its prompts never reach."""
     from kubeai_tpu.ops.mla_attention import latent_attention_paged
 
     max_pages = 4096 // PAGE
@@ -314,8 +321,13 @@ SWA_WINDOW, SWA_SLOTS, SWA_MAX_LEN = 4096, 24, 16384
         (1, 1024, SWA_MAX_LEN // PAGE, None),
         (1, 1024, (1024 + SWA_WINDOW - 2) // PAGE + 2, SWA_WINDOW),
         (8, 32, (32 + SWA_WINDOW - 2) // PAGE + 2, SWA_WINDOW),
+        (1, 2048, SWA_MAX_LEN // PAGE, None),
+        (1, 2048, (2048 + SWA_WINDOW - 2) // PAGE + 2, SWA_WINDOW),
     ],
-    ids=["decode/full-256-pages", "decode/window-65-pages", "chunk-1024/full", "chunk-1024/window-81-pages", "cold-8x32/window"],
+    ids=[
+        "decode/full-256-pages", "decode/window-65-pages", "chunk-1024/full", "chunk-1024/window-81-pages", "cold-8x32/window",
+        "chunk-2048/full", "chunk-2048/window-97-pages",
+    ],
 )
 def test_ragged_paged_kernel_lowers_with_a_window_over_a_shifted_table(v5e, B, S, columns, window):
     """The cell smallthinker-bf16-longdoc-sat: 24 slots of 256 pages; a
@@ -331,7 +343,7 @@ def test_ragged_paged_kernel_lowers_with_a_window_over_a_shifted_table(v5e, B, S
 
 
 @pytest.mark.parametrize("k,n", [(2560, 768), (768, 2560)], ids=["gate_up", "down"])
-@pytest.mark.parametrize("rows", [24 * 6, 6 * 1024, 8 * 1024 * 6, 6 * 32])
+@pytest.mark.parametrize("rows", [24 * 6, 6 * 1024, 8 * 1024 * 6, 6 * 32, 6 * 2048])
 def test_grouped_expert_matmul_lowers_at_smallthinkers_widths(v5e, rows, k, n):
     """24 slots x 6 choices at decode (144 rows: one tile), a chunk's 6144,
     a full group's 49152 and the smallest bucket's 192, over the 12 x 64
@@ -387,16 +399,17 @@ NEMOTRON_SLOTS, NEMOTRON_MAX_LEN = 96, 8192
 
 
 @pytest.mark.parametrize("k,n", [(1024, 2688), (2688, 1024)], ids=["up", "down"])
-@pytest.mark.parametrize("rows", [704, 256, 7552, 60096])
+@pytest.mark.parametrize("rows", [704, 256, 7552, 60096, 15040])
 def test_grouped_expert_matmul_lowers_at_nemotrons_latent_widths(v5e, rows, k, n):
     """A pass of the chip's share (`ops/moe.py::held_capacity`: 96 slots x
     22 choices, a quarter held, a third more; the smallest bucket's; a
-    chunk's 1024 x 22; a full prefill group's) over the 128 held experts of
+    chunk's 1024 x 22; a full prefill group's; the wide chunk's 2048 x 22)
+    over the 128 held experts of
     one block: an expert's 1024 x 2688 matrix is cut once (5.25 MiB in
     bf16) and every row tile the rule picks divides a pass."""
     from kubeai_tpu.ops.moe import gmm_tiles, grouped_matmul, held_capacity
 
-    assert rows in {held_capacity(t * 22, 128, 512) for t in (96, 32, 1024, 8 * 1024)}
+    assert rows in {held_capacity(t * 22, 128, 512) for t in (96, 32, 1024, 8 * 1024, 2048)}
     tm, tk, tn = gmm_tiles(rows, k, n)
     assert rows % tm == 0 and tm % 8 == 0 and tk * tn <= 2 << 20
     text = _compile(
@@ -480,8 +493,13 @@ AFM_WINDOW, AFM_SLOTS, AFM_MAX_LEN = 2048, 24, 32768
         (1, 1024, AFM_MAX_LEN // PAGE, None),
         (1, 1024, (1024 + AFM_WINDOW - 2) // PAGE + 2, AFM_WINDOW),
         (8, 32, (32 + AFM_WINDOW - 2) // PAGE + 2, AFM_WINDOW),
+        (1, 2048, AFM_MAX_LEN // PAGE, None),
+        (1, 2048, (2048 + AFM_WINDOW - 2) // PAGE + 2, AFM_WINDOW),
     ],
-    ids=["decode/full-512-pages", "decode/window-33-pages", "chunk-1024/full-512-pages", "chunk-1024/window-49-pages", "cold-8x32/window"],
+    ids=[
+        "decode/full-512-pages", "decode/window-33-pages", "chunk-1024/full-512-pages", "chunk-1024/window-49-pages", "cold-8x32/window",
+        "chunk-2048/full-512-pages", "chunk-2048/window-65-pages",
+    ],
 )
 def test_ragged_paged_kernel_lowers_at_g8_behind_512_page_tables(v5e, B, S, columns, window):
     """The cell trinitymini-bf16-mixedlen-sat: 24 slots of 512 pages (twice
@@ -497,7 +515,7 @@ def test_ragged_paged_kernel_lowers_at_g8_behind_512_page_tables(v5e, B, S, colu
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)], ids=["gate_up", "down"])
-@pytest.mark.parametrize("rows", [24 * 8, 8 * 1024, 8 * 1024 * 8, 8 * 32])
+@pytest.mark.parametrize("rows", [24 * 8, 8 * 1024, 8 * 1024 * 8, 8 * 32, 8 * 2048])
 def test_grouped_expert_matmul_lowers_at_trinitys_widths(v5e, rows, k, n):
     """24 slots x 8 choices at decode (192 rows: one tile), a chunk's 8192,
     a full group's 65536 and the smallest bucket's 256, over the 6 x 128
