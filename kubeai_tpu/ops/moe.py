@@ -4,7 +4,10 @@ none is dropped and there is no capacity.
 The `T*k` assignments are sorted by expert, the rows gathered in that
 order, and each of the three expert projections is ONE grouped matmul
 over the groups that have rows (rows of expert e times expert e's
-matrix); the weighted rows are scatter-added back to their tokens.
+matrix). The way back is a gather, choice by choice: the rows of the
+tokens' i-th choice are gathered as [tokens, D] and added, times their
+float32 weights, into ONE float32 [tokens, D], i = 0 .. k-1
+(`_back_to_tokens`).
 Shapes are static (`T*k` rows whatever the loads are), so a batch whose
 routing changes never recompiles. `models/llama.py::moe_mlp` (Mixtral:
 softmax over the top-k, a static capacity, tokens past it dropped) is a
@@ -19,6 +22,16 @@ import jax.numpy as jnp
 # (tm, tk, tn) the grouped matmul was given, per call shape this process
 # has traced (diagnosis: /debug/engine -> perf.grouped_matmul_tiles).
 chosen_tiles: dict[str, tuple[int, int, int]] = {}
+# The way back (`_back_to_tokens`) gathers all of a call's T*k rows at once
+# while they are at most this many bytes: on core they stay from the gather
+# to the sum (46 MB, Nemotron's 1024-token call, and 50 MB, kanana-2's 2048,
+# read under the loop; 63 MB, SmallThinker's 2048, read over it). Past it the
+# loop's float32 sum holds this many tokens at a time: 21 MB at D = 2560, on
+# core through all k turns, where a whole 8192-token group's 67 MB would be
+# read and written in HBM every turn. Read on the chip at every shape the
+# cells run, alone and inside longdoc-sat's chunks (PERF.md section 6, PR 44).
+ON_CORE_ROWS = 48 << 20
+WAY_BACK_BLOCK = 2048
 
 
 def gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
@@ -102,6 +115,67 @@ def route_softmax_topk(r, k: int, forced=None):
     return idx.astype(jnp.int32), jax.nn.softmax(jnp.take_along_axis(r, idx, axis=1), axis=1)
 
 
+def _where_sorted(order):
+    """[n] int32: where assignment j sits among the sorted ones, the inverse
+    of the permutation *order* (row r of the sorted rows is assignment
+    order[r])."""
+    n = order.shape[0]
+    return jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
+
+
+def _back_to_tokens(out, back, weights, mine=None):
+    """sum_i weights[t, i] * out[back[t, i]] in float32: out [R, D], the
+    experts' rows sorted by expert; back [T, k] int32, the row of each
+    (token, choice); weights [T, k] float32. Returns [T, D] float32. With
+    *mine* [T, k] bool only the choices it marks are summed (the others'
+    rows may never have been written: they are left out, not multiplied
+    by zero).
+
+    Choice by choice: the rows of choice i are [tokens, D], tokens on the
+    sublanes whatever k is, and are added in the order i = 0 .. k-1 into
+    ONE float32 [tokens, D]. The order of a token's k additions is fixed,
+    so the result is the same function of its rows at every call size, and
+    no array has k between the tokens and D. (`out[back].reshape(T, k, D)`
+    summed over k puts k on the sublanes: at k = 6 a relayout in float32
+    padded to 8 rows a token, which past 1024 tokens leaves the core for
+    HBM. PERF.md section 6, PR 44.)
+
+    One algorithm, two ways to hold the rows, by the call's static size.
+    While all T*k gathered rows fit on core (`ON_CORE_ROWS`: every decode
+    step, every call up to 1024 tokens) they are ONE gather in choice-major
+    order, viewed as [k, T, D], and one fused sum: two operations a layer.
+    Past that the gathered rows would go through HBM, so a loop over the
+    choices gathers [B, D] a turn and adds it to the float32 sum of
+    `WAY_BACK_BLOCK` tokens, which stays on core through its k turns."""
+    T, k = weights.shape
+    D = out.shape[1]
+    back, weights, mine = (a if a is None else a.T for a in (back, weights, mine))  # [k, T]: choice i is a row
+
+    def add(y, rows, weight, mine):
+        term = rows.astype(jnp.float32) * weight[:, None]
+        return y + (term if mine is None else jnp.where(mine[:, None], term, 0.0))
+
+    if T * k * D * out.dtype.itemsize <= ON_CORE_ROWS:
+        rows = out[back.reshape(T * k)].reshape(k, T, D)
+        y = jnp.zeros((T, D), jnp.float32)
+        for i in range(k):
+            y = add(y, rows[i], weights[i], None if mine is None else mine[i])
+        return y
+
+    B = WAY_BACK_BLOCK if T % WAY_BACK_BLOCK == 0 else T
+
+    def block(operands):
+        back, weights, mine = operands  # [k, B]
+        turn = lambda i, y: add(y, out[back[i]], weights[i], None if mine is None else mine[i])
+        return jax.lax.fori_loop(0, k, turn, jnp.zeros((B, D), jnp.float32))
+
+    if T == B:
+        return block((back, weights, mine))
+    # [k, T] -> [T / B, k, B]: a block's choice i is one row of B tokens.
+    blocks = jax.tree.map(lambda a: a.reshape(k, T // B, B).swapaxes(0, 1), (back, weights, mine))
+    return jax.lax.map(block, blocks).reshape(T, D)
+
+
 def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu, held=None):
     """sum_i weights[t, i] * GLU_{idx[t, i]}(x[t]) for every token, the
     gate's activation *act* (SiLU: SwiGLU; ReLU: SmallThinker's ReGLU):
@@ -151,12 +225,7 @@ def routed_experts(x, idx, weights, wg, wu, wd, layer=None, act=jax.nn.silu, hel
     with jax.named_scope("moe.experts"):
         out = grouped_matmul(hidden, wd, group_sizes)
     with jax.named_scope("moe.combine"):
-        # Back to (token, choice) order by a gather (row j of the sorted
-        # rows is assignment order[j]), then the weighted sum over a
-        # token's k rows in float32.
-        back = jnp.zeros((T * k,), jnp.int32).at[order].set(jnp.arange(T * k, dtype=jnp.int32))
-        rows_back = out[back].reshape(T, k, D).astype(jnp.float32)
-        y = (rows_back * weights[:, :, None]).sum(axis=1)
+        y = _back_to_tokens(out, _where_sorted(order).reshape(T, k), weights)
     return y.astype(x.dtype), hit
 
 
@@ -197,8 +266,7 @@ def _held_part(x, idx, weights, wg, wu, wd, act, held):
         ends = jnp.cumsum(sizes)
         starts, n_here = ends - sizes, ends[-1]
         hit = (sizes > 0).sum().astype(jnp.int32)
-        # Where each (token, choice) sits among the sorted assignments.
-        back = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
+        here, back = here.reshape(T, k), _where_sorted(order).reshape(T, k)
         order = jnp.pad(order, (0, passes * C - n))
 
     def one(carry):
@@ -216,8 +284,7 @@ def _held_part(x, idx, weights, wg, wu, wd, act, held):
             # A (token, choice) whose row this pass computed takes it; rows
             # past the held assignments were never written and are left out.
             mine = here & (back >= lo) & (back < lo + C)
-            rows_back = jnp.where(mine[:, None], out[jnp.clip(back - lo, 0, C - 1)], 0).reshape(T, k, D)
-            return lo + C, y + jnp.einsum("tkd,tk->td", rows_back, weights, preferred_element_type=jnp.float32)
+            return lo + C, y + _back_to_tokens(out, jnp.clip(back - lo, 0, C - 1), weights, mine)
 
     _, y = jax.lax.while_loop(lambda carry: carry[0] < n_here, one, (jnp.zeros((), jnp.int32), jnp.zeros((T, D), jnp.float32)))
     return y.astype(x.dtype), hit

@@ -6,7 +6,9 @@ to before (text without locations, so an edit that only moves lines does
 not show). The digests in tests/data/step_program_digests.json were taken by
 this file's own `digests()`: the dense, `deepseek_v3` and `smallthinker`
 ones at the parent of PR 40 (commit c004975), `nemotron_h`'s at the parent
-of PR 42 (commit 1b02cb5), `afmoe`'s as PR 42 left its module:
+of PR 42 (commit 1b02cb5), `afmoe`'s as PR 42 left its module; PR 44 took
+the four expert families' again (it MEANT to change them: the way back of
+`ops/moe.py`, `_back_to_tokens`; the dense family's stayed as they were):
 
     JAX_PLATFORMS=cpu python tests/test_step_programs_unchanged.py > tests/data/step_program_digests.json
 

@@ -11,6 +11,7 @@ them (never an option of the program).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -563,3 +564,59 @@ def test_the_first_period_of_trinity_decodes_through_both_pools(v5e):
     assert compiled.as_text().count("tpu_custom_call") >= 10
     pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in pools.values())
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+# -- the expert layer's way back to tokens (ops/moe.py::_back_to_tokens) --------
+
+WAY_BACK = {  # k, D, experts here, F, the share held
+    "smallthinker": (6, 2560, 64, 768, None),
+    "kanana-2": (6, 2048, 128, 768, None),
+    "trinity": (8, 2048, 128, 1024, None),
+    "nemotron-latent-share": (22, 1024, 128, 2688, (0, 128, 512)),
+}
+
+
+@pytest.fixture(scope="module")
+def way_back_program(v5e):
+    """(family, T) -> the family's expert layer at T tokens, compiled once:
+    a 2048-row case reads the 1024-row program's temporaries too."""
+    from kubeai_tpu.ops import moe
+
+    programs = {}
+
+    def compiled(family, T):
+        if (family, T) not in programs:
+            k, D, E, F, held = WAY_BACK[family]
+            layer = lambda x, idx, w, wg, wu, wd: moe.routed_experts(x, idx, w, None if held else wg, wu, wd, held=held)
+            programs[family, T] = jax.jit(layer).lower(
+                _sds(v5e, (T, D), jnp.bfloat16), _sds(v5e, (T, k), jnp.int32), _sds(v5e, (T, k), jnp.float32),
+                _sds(v5e, (E, D, F), jnp.bfloat16), _sds(v5e, (E, D, F), jnp.bfloat16), _sds(v5e, (E, F, D), jnp.bfloat16),
+            ).compile()
+        return programs[family, T]
+
+    return compiled
+
+
+@pytest.mark.parametrize("T", [1024, 2048])
+@pytest.mark.parametrize("family", sorted(WAY_BACK))
+def test_the_expert_layers_way_back_holds_no_token_choice_width_array(way_back_program, family, T):
+    """A chunk's expert layer at the published widths, through
+    `routed_experts` (a share's: through the pass of `_held_part`): the
+    compiled program has no array shaped [T, k, D] (k on the sublanes: at
+    k = 6 a relayout in float32 padded to 8 rows a token, which at 2048
+    tokens left the core for HBM, 231 MB of temporaries a layer, and cost
+    nine times its bytes: PERF.md section 6, PR 44) and no float32 array of
+    T*k*D elements; and the 2048-row call's temporaries are at most the
+    bf16 rows' own bytes over twice the 1024-row call's: no step between
+    the two."""
+    k, D = WAY_BACK[family][:2]
+    program = way_back_program(family, T)
+    text = program.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.findall(rf"\w+\[{T},{k},{D}\]", text)
+    widest = max(int(np.prod([int(d) for d in dims.split(",")])) for dims in re.findall(r"f32\[([\d,]+)\]", text))
+    assert widest < T * k * D
+    temporaries = program.memory_analysis().temp_size_in_bytes
+    print(f"{family} T={T}: temp_size_in_bytes {temporaries:,}, widest float32 array {widest:,} elements")
+    if T == 2048:
+        assert temporaries <= T * k * D * 2 + 2 * way_back_program(family, 1024).memory_analysis().temp_size_in_bytes
