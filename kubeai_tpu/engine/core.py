@@ -242,11 +242,6 @@ def table_width(model_config: ModelConfig, cfg: EngineConfig) -> int:
     return engine_dims(cfg)[0] * (2 if window_pool_dims(model_config, cfg)[0] else 1)
 
 
-# What a family with state by slot keeps beside its pools, by the cache
-# dict's keys (models/nemotron_h.py::init_paged_cache).
-SLOT_STATE_KEYS = ("ssm", "conv")
-
-
 def init_pools(model_config: ModelConfig, cfg: EngineConfig):
     """The family's page pool(s) at the engine's dimensions (shared with
     the AOT warm compiler, as engine_dims is), and beside them, for a
@@ -257,7 +252,7 @@ def init_pools(model_config: ModelConfig, cfg: EngineConfig):
     return model.init_paged_cache(
         model_config, engine_dims(cfg)[1], cfg.page_size,
         **({"window_pages": window_pages} if window_pages else {}),
-        **({"slots": cfg.max_slots} if getattr(model, "SLOT_STATE", False) else {}),
+        **({"slots": cfg.max_slots} if model.SLOT_STATE else {}),
     )
 
 
@@ -384,7 +379,7 @@ class Engine:
                 model_config, kv_cache_dtype=self.cfg.kv_cache_dtype
             )
         family(model_config).refuse_unsupported(model_config)
-        if self.cfg.prefix_cache_min and not getattr(family(model_config), "PREFIX_REUSE", True):
+        if self.cfg.prefix_cache_min and not family(model_config).PREFIX_REUSE:
             # The family's rule (models/nemotron_h.py): nothing is looked
             # up and nothing registered; the hit counters stay 0.
             import dataclasses as _dc
@@ -599,7 +594,7 @@ class Engine:
         # State kept by slot beside the pages (a family with recurrent
         # layers, models/nemotron_h.py; 0 / 0 for every other): there is no
         # allocator, so a slot's state is in use while the slot is.
-        slot_state = getattr(family(self.model_config), "SLOT_STATE", False)
+        slot_state = bool(family(self.model_config).SLOT_STATE)
         state_used_fn = lambda: float(sum(s is not None for s in self._slots)) if slot_state else 0.0  # noqa: E731
         state_total_fn = lambda: float(self.cfg.max_slots) if slot_state else 0.0  # noqa: E731
         self.m_state_used = default_registry.callback_gauge(
@@ -859,7 +854,7 @@ class Engine:
             # ... and what a slot owns outside its pages, whatever its
             # length (models/nemotron_h.py; 0: nothing but pages).
             "state_bytes_per_slot": int(
-                sum(v.nbytes for k, v in self._cache.items() if k in SLOT_STATE_KEYS) // self.cfg.max_slots
+                sum(self._cache[k].nbytes for k in family(self.model_config).SLOT_STATE) // self.cfg.max_slots
             ),
             # ... and by kind of layer where the window layers keep a
             # pool of their own (the line above is then the full layers').
@@ -1615,7 +1610,7 @@ class Engine:
         # the scheduler thunk would freeze every client's token stream
         # for its duration. Only the bank install/broadcast needs
         # dispatch-stream ordering.
-        if not hasattr(family(self.model_config), "init_lora_bank"):
+        if family(self.model_config).init_lora_bank is None:
             raise ValueError(f"{self.model_config.model_type}: LoRA adapters are not supported")
         staged = self._stage_adapter(name, path)
 
@@ -3556,7 +3551,7 @@ def build_step_functions(
         (models/smallthinker.py: `kv_window`) stays with the first, and
         so does state kept by slot (models/nemotron_h.py: `ssm`, `conv`).
         A family without counters gives {}: no output at all."""
-        pools = {k: v for k, v in cache.items() if k.startswith("kv") or k in SLOT_STATE_KEYS}
+        pools = {k: v for k, v in cache.items() if k.startswith("kv") or k in model.SLOT_STATE}
         return pools, {k: v for k, v in cache.items() if k not in pools}
 
     def mask_pad(logits):
@@ -3568,7 +3563,7 @@ def build_step_functions(
     topn = max(1, cfg.top_logprobs_k)
     # A family with state by slot is told the slot of every prefill row
     # (a decode step's rows ARE the slots, in `live`'s order).
-    slot_state = getattr(model, "SLOT_STATE", False)
+    slot_state = bool(model.SLOT_STATE)
 
     def prefill_batch_fn(params, tokens, lengths, tables, slots, seeds, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_rows=None):
         """Cold prefill for N requests in ONE call (N is one of two

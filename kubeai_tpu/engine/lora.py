@@ -4,7 +4,7 @@ The reference delegates adapter serving entirely to vLLM's
 /v1/load_lora_adapter (ref: internal/vllmclient/client.go); here the
 engine owns it: PEFT-format checkpoints (adapter_config.json +
 adapter_model.safetensors) are parsed into the batched multi-LoRA bank
-(models.llama.init_lora_bank) and installed with device scatters —
+(the family's `init_lora_bank`) and installed with device scatters —
 loading or unloading an adapter never recompiles the serving functions.
 """
 
@@ -18,7 +18,7 @@ import threading
 import jax.numpy as jnp
 import numpy as np
 
-from kubeai_tpu.models import llama
+from kubeai_tpu.models import family
 from kubeai_tpu.models.base import ModelConfig
 
 # PEFT target_modules name -> our param name.
@@ -101,14 +101,14 @@ class AdapterRuntime:
         # Row 0 is the reserved no-adapter identity.
         if self._multiproc:
             shapes = jax.eval_shape(
-                lambda: llama.init_lora_bank(config, max_adapters + 1, max_rank, dtype)
+                lambda: family(config).init_lora_bank(config, max_adapters + 1, max_rank, dtype)
             )
             self._host_bank = {
                 k: np.zeros(s.shape, s.dtype) for k, s in shapes.items()
             }
             self.bank = self._publish_global()
         else:
-            self.bank = llama.init_lora_bank(config, max_adapters + 1, max_rank, dtype)
+            self.bank = family(config).init_lora_bank(config, max_adapters + 1, max_rank, dtype)
         self._rows: dict[str, int] = {}
         # Per-row generation, bumped whenever a row's weights change
         # (load/reload/unload): rows are recycled, so consumers caching

@@ -424,7 +424,7 @@ def load_engine_from_path(
         )
 
     with timeline.phase("load"):
-        if use_stream and hasattr(model, "stream_params_from_hf"):
+        if use_stream and model.stream_params_from_hf is not None:
             # A family that streams its own tree (models/deepseek.py).
             from kubeai_tpu.engine.coldstart import padded_vocab_size
 

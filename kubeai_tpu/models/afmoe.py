@@ -18,9 +18,10 @@ shared one. With `x` a layer's input, `h` the hidden size:
     f  = sum_{e in S} s_e / (sum_S s + 1e-20) * scale * swiglu_e(m) + swiglu_shared(m)
     x' = u + rms(f; g4)
 
-The engine reaches a model through `kubeai_tpu.models.family(config)`.
-What the family does not run is refused at load (`refuse_unsupported`,
-and `models/base.py::_afmoe_keys` for what the config itself asks).
+The engine reaches a model through `kubeai_tpu.models.family(config)`;
+`models/__init__.py` declares what this module gives it. What the family
+does not run is refused at load (`refuse_unsupported`, and `config_keys`
+for what the config itself asks).
 
 **Two axes.** The KIND OF ATTENTION follows `layer_types` with a period
 (4 as published) and the KIND OF FEED-FORWARD the depth (dense before
@@ -48,18 +49,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeai_tpu.models import smallthinker
-from kubeai_tpu.models.base import ModelConfig
-from kubeai_tpu.models.deepseek import _swiglu
-from kubeai_tpu.models.smallthinker import (  # noqa: F401  (the family's interface: models/__init__.py)
-    KV_PARK,
-    PAGED_KERNEL_LABEL,
-    REUSE_WHOLE_PREFILL_CALLS,
+from kubeai_tpu.models import shared
+from kubeai_tpu.models.base import ModelConfig, layout_period
+from kubeai_tpu.models.shared import layer_counts, swiglu as _swiglu  # the names `apply` calls them by
+from kubeai_tpu.models.smallthinker import (  # noqa: F401  (what IS SmallThinker's: the two pools and their routes)
     TwoPools,
-    _DictSource,
     cached_attention_route,
     init_paged_cache,
-    kv_pool_dtype,
     layer_kinds,
     period,
     window_pool_tokens,
@@ -70,23 +66,14 @@ from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
 
 Params = dict[str, Any]
 
-
-def layer_counts(config: ModelConfig) -> tuple[int, int]:
-    """(leading dense layers, expert layers)."""
-    dense = min(config.first_k_dense_replace, config.num_layers)
-    return dense, config.num_layers - dense
+PAGED_KERNEL_LABEL = "ragged"
+REUSE_WHOLE_PREFILL_CALLS = True  # the module docstring says why
+KV_PARK = False  # likewise
 
 
 def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1) -> None:
     """What this family does not run, refused at load by name."""
-    if quantization:
-        raise ValueError("afmoe: --quantization is not supported (no int8 for stacked expert weights)")
-    if tp > 1:
-        raise ValueError("afmoe: --tensor-parallel-size > 1 is not supported (experts and both pools are unsharded)")
-    if config.kv_cache_dtype not in ("", "auto", config.dtype):
-        raise ValueError("afmoe: a kv_cache_dtype other than the compute dtype is not supported")
-    if config.tie_word_embeddings:
-        raise ValueError("afmoe: tied embeddings are not supported (the checkpoint must hold lm_head.weight)")
+    shared.refuse_common("afmoe", config, quantization, tp, "experts and both pools are unsharded")
     if config.rope_scaling is not None:
         raise ValueError("afmoe: rope_scaling is not supported")
     full, window = layer_kinds(config)
@@ -97,11 +84,6 @@ def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1)
         raise ValueError("afmoe: num_dense_layers past the first period is not supported")
     if not n_moe or not config.n_routed_experts:
         raise ValueError("afmoe: a stack without an expert layer is not supported")
-
-
-def _refuse_lora(lora) -> None:
-    if lora is not None:
-        raise ValueError("afmoe: LoRA adapters are not supported")
 
 
 # ---------------------------------------------------------------------------
@@ -189,20 +171,16 @@ def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, dict]:
 
 
 def stream_params_from_hf(source, config: ModelConfig, pad: int = 0) -> Params:
-    """`models/smallthinker.py`'s streamed load over this family's groups
-    (an expert layer is 1.6 GB in bf16: the host holds two, the device
-    never a stack twice): `dense` holds the leading layers, `moe` and
-    `experts` the layers behind them."""
+    """`shared.stream_stacks` over this family's groups (an expert layer
+    is 1.6 GB in bf16: the host holds two, the device never a stack
+    twice): `dense` holds the leading layers, `moe` and `experts` the
+    layers behind them."""
     n_dense = layer_counts(config)[0]
     rows = {g: (n, n_dense if g in ("moe", "experts") else 0) for g, n in _group_rows(config).items()}
-    return smallthinker.stream_params_from_hf(source, config, pad, layer_tensors=_layer_tensors, rows=rows)
+    return shared.stream_stacks(source, config, pad, _layer_tensors, rows)
 
 
-def params_from_hf(state_dict: dict[str, np.ndarray], config: ModelConfig, dtype=None, to_device: bool = True) -> Params:
-    """An HF state dict (name -> array) as this module's tree."""
-    del to_device  # one path: the tree is assembled on the device
-    cfg = config if dtype is None else config.replace(dtype=str(jnp.dtype(dtype)))
-    return stream_params_from_hf(_DictSource(state_dict), cfg)
+params_from_hf = shared.params_from_hf_by(stream_params_from_hf)
 
 
 # ---------------------------------------------------------------------------
@@ -358,36 +336,85 @@ def apply(
     return logits, new_cache
 
 
-def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None, tp_mesh=None, **debug):
-    """A chunk [B, S] at absolute offset *start* [B] behind whatever the
-    tables' pages already hold. Returns (logits [B, 1, V] at *last_idx*
-    within the chunk, pools)."""
-    _refuse_lora(lora)
-    S = tokens.shape[1]
-    start = jnp.reshape(start, (-1,)).astype(jnp.int32)
-    pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    return apply(
-        params, config, tokens, pos, pool, page_table,
-        logits_idx=jnp.reshape(last_idx, (-1,)).astype(jnp.int32), **debug,
+prefill_paged, prefill_paged_cold, decode_step_paged = shared.paged_entry_points(apply, "afmoe")
+
+# The seam's other names (models/__init__.py says what each rule means).
+PREFIX_REUSE = True
+SLOT_STATE = ()
+init_lora_bank = None
+
+
+def config_keys(get) -> dict:
+    """The AFMoE keys (Trinity) of a published config.json as ModelConfig
+    fields; the module docstring says what each means. What this module
+    does not compute is refused here, by name. `layer_types` may be longer
+    than the depth (a checkpoint cut in depth keeps the published list):
+    the first `num_hidden_layers` entries are the model's. The family has
+    no fields of its own: the window, the layouts (rope goes with the
+    window), the leading dense layers, the experts and the shared one, the
+    router's norm and scale and the embedding multiplier reuse the fields
+    other families brought. `load_balance_coeff` (it trains the selection
+    bias) and `use_grouped_mm` (a switch of the source's implementation)
+    are read by nothing."""
+    L = get("num_hidden_layers")
+    types = get("layer_types")
+    if not isinstance(types, (list, tuple)) or len(types) < L or set(types) - {"sliding_attention", "full_attention"}:
+        raise ValueError(f"afmoe: layer_types must give sliding_attention or full_attention for each of the {L} layers")
+    every = get("global_attn_every_n_layers")
+    if every and any((t == "full_attention") != ((i + 1) % every == 0) for i, t in enumerate(types)):
+        raise ValueError(f"afmoe: layer_types and global_attn_every_n_layers ({every}) disagree")
+    for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups"):
+        if (get(key) or 1) != 1:
+            raise ValueError(f"afmoe: grouped routing ({key} > 1) is not supported")
+    if get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"afmoe: score_func {get('score_func')!r} is not supported (sigmoid)")
+    if get("hidden_act", "silu") != "silu":
+        raise ValueError(f"afmoe: hidden_act {get('hidden_act')!r} is not supported (silu)")
+    if get("rope_scaling"):
+        raise ValueError("afmoe: rope_scaling is not supported")
+    if get("attention_bias"):
+        raise ValueError("afmoe: attention_bias is not supported")
+    layout = tuple(int(t == "sliding_attention") for t in types)
+    period = layout_period(layout)
+    if L % period:
+        raise ValueError(f"afmoe: {L} layers are not whole periods of layer_types' pattern of {period} layers")
+    dense = get("num_dense_layers") or 0
+    if dense > period:
+        raise ValueError(f"afmoe: num_dense_layers {dense} past the first period of {period} layers is not supported")
+    window = get("sliding_window") or 0
+    if any(layout[:L]) and window <= 0:
+        raise ValueError("afmoe: layer_types names sliding_attention layers and sliding_window gives no window")
+    return dict(
+        embed_scale=bool(get("mup_enabled", False)),
+        first_k_dense_replace=dense,
+        n_routed_experts=get("num_experts") or 0,
+        n_shared_experts=get("num_shared_experts") or 0,
+        moe_intermediate_size=get("moe_intermediate_size") or 0,
+        norm_topk_prob=bool(get("route_norm", True)),
+        routed_scaling_factor=float(get("route_scale") or 1.0),
+        sliding_window_size=int(window),
+        sliding_window_layout=layout[:L],
+        rope_layout=layout[:L],
     )
 
 
-def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
-    """Whole-prompt prefill (positions arange(S)). Returns (logits
-    [B, 1, V] at lengths-1, pools)."""
-    _refuse_lora(lora)
-    B, S = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    return apply(
-        params, config, tokens, pos, pool, page_table,
-        logits_idx=jnp.reshape(lengths, (-1,)).astype(jnp.int32) - 1, left_aligned=True, **debug,
-    )
-
-
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None, **debug):
-    """One decode step for [B, 1] tokens at positions *lengths* [B].
-    Returns (logits [B, 1, V], pools). *live* as in
-    `smallthinker.decode_step_paged`: both kinds of layer hand the kernel
-    the one count and the logits come back in slot order."""
-    _refuse_lora(lora)
-    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, live=live, **debug)
+def param_counts(mc: ModelConfig) -> tuple[float, float]:
+    """(held, active a token): every layer holds gated grouped-query
+    attention with query/key norms and four norms;
+    `first_k_dense_replace` layers a dense feed-forward, the rest a
+    router with its selection bias, `n_shared_experts` shared experts and
+    `n_routed_experts` routed ones, of which a token passes through
+    `num_experts_per_tok`. Trinity-Mini at 8 of 32 layers: 5.98G held,
+    1.04G a token; at 32: 26.1G and 3.06G. Held to perfbench/families/
+    afmoe_counts.py by tests/test_afmoe.py."""
+    D, L, V = mc.hidden_size, mc.num_layers, mc.vocab_size
+    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
+    attn = 3 * D * H * h + 2 * D * Kv * h + 2 * h + 4 * D
+    n_dense = min(mc.first_k_dense_replace, L)
+    expert = 3 * D * mc.moe_intermediate_size
+    outside = D * mc.n_routed_experts + mc.n_routed_experts + mc.n_shared_experts * expert
+    always = L * attn + n_dense * 3 * D * mc.intermediate_size + (L - n_dense) * outside  # whatever the routing
+    total = 2 * V * D + D + always + (L - n_dense) * mc.n_routed_experts * expert
+    # Active leaves the embedding table out (a row is looked up).
+    active = V * D + D + always + (L - n_dense) * mc.num_experts_per_tok * expert
+    return float(total), float(active)

@@ -130,7 +130,7 @@ class ModelConfig:
     router_experts: int = 0
     experts_first: int = 0
     # AFMoE family (model_type "afmoe", models/afmoe.py): no field of its
-    # own. `_afmoe_keys` reads its published keys into embed_scale,
+    # own. Its `config_keys` reads its published keys into embed_scale,
     # first_k_dense_replace, the expert fields and the window layouts
     # above; its gate, query/key norms and post-norms are the family's
     # equations, not switches.
@@ -141,8 +141,10 @@ class ModelConfig:
 
     @classmethod
     def from_hf(cls, config) -> "ModelConfig":
-        """Build from a transformers PretrainedConfig (Llama/Mistral/Mixtral/
-        Gemma/Qwen2-style field names)."""
+        """Build from a transformers PretrainedConfig: the keys every
+        family shares under their Llama-style names, then the keys of the
+        `model_type`'s module (`config_keys`: its own fields, and the
+        refusal by name of what it does not compute)."""
         get = lambda k, d=None: getattr(config, k, d)
         scaling = None
         rs = get("rope_scaling")
@@ -174,37 +176,9 @@ class ModelConfig:
                     f"unsupported rope_scaling type {rope_type!r}; "
                     "supported: llama3, linear"
                 )
+        from kubeai_tpu.models import family_of  # the modules import this file
+
         model_type = get("model_type", "llama")
-        gemma_kw = {}
-        if model_type == "qwen2":
-            # Qwen2 hardcodes q/k/v projection biases (modeling_qwen2).
-            gemma_kw["qkv_bias"] = True
-        if model_type in ("gemma", "gemma2"):
-            gemma_kw = dict(
-                hidden_act="gelu_tanh",
-                embed_scale=True,
-                rms_one_offset=True,
-            )
-            if model_type == "gemma2":
-                gemma_kw.update(
-                    post_norms=True,
-                    attn_softcap=get("attn_logit_softcapping", 50.0) or 0.0,
-                    logit_softcap=get("final_logit_softcapping", 30.0) or 0.0,
-                    query_scale=(get("query_pre_attn_scalar") or 0) ** -0.5
-                    if get("query_pre_attn_scalar")
-                    else None,
-                    # HF Gemma2 applies the window on even layer indices.
-                    sliding_window=get("sliding_window") or 0,
-                    sliding_layers="even",
-                )
-        if model_type == "deepseek_v3":
-            gemma_kw = _deepseek_v3_keys(get)
-        if model_type == "smallthinker":
-            gemma_kw = _smallthinker_keys(get)
-        if model_type == "nemotron_h":
-            gemma_kw = _nemotron_h_keys(get)
-        if model_type == "afmoe":
-            gemma_kw = _afmoe_keys(get)
         kw = dict(
             model_type=model_type,
             vocab_size=config.vocab_size,
@@ -222,7 +196,8 @@ class ModelConfig:
             num_experts=get("num_local_experts", 0) or 0,
             num_experts_per_tok=get("num_experts_per_tok", 2) or 2,
         )
-        kw.update(gemma_kw)  # a family's own keys win (smallthinker names its experts per token otherwise)
+        # The family's own keys win (smallthinker names its experts per token otherwise).
+        kw.update(family_of(model_type).config_keys(get))
         return cls(**kw)
 
     @classmethod
@@ -239,196 +214,6 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
-
-
-def _deepseek_v3_keys(get) -> dict:
-    """The DeepSeek-V3 keys of a published config.json as ModelConfig
-    fields. What models/deepseek.py does not compute is refused here,
-    by name, and not served as something else."""
-    if get("q_lora_rank"):
-        raise ValueError("deepseek_v3: a query low-rank (q_lora_rank) is not supported")
-    if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
-        raise ValueError("deepseek_v3: group-limited routing (n_group/topk_group > 1) is not supported")
-    if get("scoring_func", "sigmoid") != "sigmoid":
-        raise ValueError(f"deepseek_v3: scoring_func {get('scoring_func')!r} is not supported (sigmoid)")
-    if (get("moe_layer_freq") or 1) != 1:
-        raise ValueError("deepseek_v3: moe_layer_freq other than 1 is not supported")
-    if get("attention_bias"):
-        raise ValueError("deepseek_v3: attention_bias is not supported")
-    return dict(
-        n_routed_experts=get("n_routed_experts") or 0,
-        n_shared_experts=get("n_shared_experts") or 0,
-        moe_intermediate_size=get("moe_intermediate_size") or 0,
-        first_k_dense_replace=get("first_k_dense_replace") or 0,
-        norm_topk_prob=bool(get("norm_topk_prob", True)),
-        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
-        kv_lora_rank=get("kv_lora_rank"),
-        qk_nope_head_dim=get("qk_nope_head_dim"),
-        qk_rope_head_dim=get("qk_rope_head_dim"),
-        v_head_dim=get("v_head_dim"),
-        rope_interleave=bool(get("rope_interleave", False)),
-    )
-
-
-def _smallthinker_keys(get) -> dict:
-    """The SmallThinker keys of a published config.json as ModelConfig
-    fields. What models/smallthinker.py does not compute is refused
-    here, by name. The layouts may be longer than the depth (a
-    checkpoint cut in depth keeps the published lists): the first
-    `num_hidden_layers` entries are the model's."""
-    L = get("num_hidden_layers")
-    if not get("moe_primary_router_apply_softmax", False):
-        raise ValueError("smallthinker: moe_primary_router_apply_softmax false (a sigmoid router) is not supported")
-    if get("moe_enable_secondary_experts") or get("moe_num_secondary_experts"):
-        raise ValueError("smallthinker: secondary experts are not supported")
-    if get("rope_scaling"):
-        raise ValueError("smallthinker: rope_scaling is not supported")
-    if not get("norm_topk_prob", True):
-        raise ValueError("smallthinker: norm_topk_prob false is not supported")
-    layouts = {}
-    for key in ("sliding_window_layout", "rope_layout"):
-        layout = get(key)
-        if not isinstance(layout, (list, tuple)) or len(layout) < L or any(v not in (0, 1) for v in layout):
-            raise ValueError(f"smallthinker: {key} must give 0 or 1 for each of the {L} layers")
-        layouts[key] = tuple(int(v) for v in layout)
-    period = layout_period(*layouts.values())
-    if L % period:
-        raise ValueError(
-            f"smallthinker: {L} layers are not whole periods of the layouts' pattern of {period} layers"
-        )
-    layouts = {key: layout[:L] for key, layout in layouts.items()}
-    window = get("sliding_window_size") or 0
-    if any(layouts["sliding_window_layout"]) and window <= 0:
-        raise ValueError("smallthinker: sliding_window_layout names window layers and sliding_window_size gives no window")
-    return dict(
-        intermediate_size=0,  # no dense feed-forward anywhere in the stack
-        n_routed_experts=get("moe_num_primary_experts") or 0,
-        num_experts_per_tok=get("moe_num_active_primary_experts") or 0,
-        moe_intermediate_size=get("moe_ffn_hidden_size") or 0,
-        norm_topk_prob=True,
-        sliding_window_size=int(window),
-        **layouts,
-    )
-
-
-def _nemotron_h_keys(get) -> dict:
-    """The Nemotron-H keys of a published config.json as ModelConfig
-    fields. What models/nemotron_h.py does not compute is refused here,
-    by name. The pattern may be longer than the depth (a checkpoint cut
-    in depth keeps the published 88 characters): the first
-    `num_hidden_layers` are the model's. `num_nextn_predict_layers` and
-    `mtp_hybrid_override_pattern` (the drafting head) are read by nothing:
-    it changes no served distribution and is not loaded. `rope_theta` and
-    `partial_rotary_factor` likewise: the family's attention layers apply
-    no rotary embedding. `time_step_*` initialise `dt_bias` in training."""
-    L = get("num_hidden_layers")
-    pattern = get("hybrid_override_pattern")
-    if not isinstance(pattern, str) or len(pattern) < L:
-        raise ValueError(f"nemotron_h: hybrid_override_pattern must name each of the {L} blocks")
-    pattern = pattern[:L]
-    if set(pattern) - set("M*E"):
-        raise ValueError(
-            f"nemotron_h: hybrid_override_pattern names blocks other than M, * and E ({sorted(set(pattern) - set('M*E'))}: "
-            "a dense feed-forward block is not supported)"
-        )
-    if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
-        raise ValueError("nemotron_h: group-limited routing (n_group/topk_group > 1) is not supported")
-    if not get("moe_latent_size"):
-        raise ValueError("nemotron_h: experts outside a latent space (no moe_latent_size) are not supported")
-    if (get("n_shared_experts") or 0) != 1:
-        raise ValueError("nemotron_h: n_shared_experts other than 1 is not supported")
-    for key, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu")):
-        if get(key, want) != want:
-            raise ValueError(f"nemotron_h: {key} {get(key)!r} is not supported ({want})")
-    for key in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias", "residual_in_fp32"):
-        if get(key):
-            raise ValueError(f"nemotron_h: {key} is not supported")
-    if not get("use_conv_bias", True):
-        raise ValueError("nemotron_h: use_conv_bias false is not supported")
-    if get("sliding_window"):
-        raise ValueError("nemotron_h: sliding_window is not supported")
-    heads, head_dim = get("mamba_num_heads") or 0, get("mamba_head_dim") or 0
-    groups = get("n_groups") or 0
-    if not heads or not groups or heads % groups or (heads * head_dim) % groups:
-        raise ValueError("nemotron_h: mamba_num_heads must be a whole number of heads for each of n_groups")
-    held, scored = get("n_routed_experts") or 0, get("router_experts") or 0
-    first = get("experts_first") or 0
-    if scored and first + held > scored:
-        raise ValueError(f"nemotron_h: experts {first}..{first + held - 1} are not among the router's {scored}")
-    return dict(
-        intermediate_size=0,  # no dense feed-forward block
-        rms_norm_eps=get("layer_norm_epsilon", 1e-5),
-        layer_pattern=pattern,
-        mamba_num_heads=heads,
-        mamba_head_dim=head_dim,
-        ssm_state_size=get("ssm_state_size"),
-        ssm_groups=groups,
-        conv_kernel=get("conv_kernel"),
-        ssm_chunk=get("chunk_size"),
-        n_routed_experts=held,
-        router_experts=scored,
-        experts_first=first,
-        n_shared_experts=1,
-        moe_intermediate_size=get("moe_intermediate_size") or 0,
-        moe_latent_size=get("moe_latent_size"),
-        moe_shared_intermediate_size=get("moe_shared_expert_intermediate_size") or 0,
-        norm_topk_prob=bool(get("norm_topk_prob", True)),
-        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
-    )
-
-
-def _afmoe_keys(get) -> dict:
-    """The AFMoE keys (Trinity) of a published config.json as ModelConfig
-    fields; models/afmoe.py says what each means. What it does not
-    compute is refused here, by name. `layer_types` may be longer than the
-    depth (a checkpoint cut in depth keeps the published list): the first
-    `num_hidden_layers` entries are the model's. The family has no fields
-    of its own: the window, the layouts (rope goes with the window), the
-    leading dense layers, the experts and the shared one, the router's
-    norm and scale and the embedding multiplier reuse the fields other
-    families brought. `load_balance_coeff` (it trains the selection bias)
-    and `use_grouped_mm` (a switch of the source's implementation) are
-    read by nothing."""
-    L = get("num_hidden_layers")
-    types = get("layer_types")
-    if not isinstance(types, (list, tuple)) or len(types) < L or set(types) - {"sliding_attention", "full_attention"}:
-        raise ValueError(f"afmoe: layer_types must give sliding_attention or full_attention for each of the {L} layers")
-    every = get("global_attn_every_n_layers")
-    if every and any((t == "full_attention") != ((i + 1) % every == 0) for i, t in enumerate(types)):
-        raise ValueError(f"afmoe: layer_types and global_attn_every_n_layers ({every}) disagree")
-    for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups"):
-        if (get(key) or 1) != 1:
-            raise ValueError(f"afmoe: grouped routing ({key} > 1) is not supported")
-    if get("score_func", "sigmoid") != "sigmoid":
-        raise ValueError(f"afmoe: score_func {get('score_func')!r} is not supported (sigmoid)")
-    if get("hidden_act", "silu") != "silu":
-        raise ValueError(f"afmoe: hidden_act {get('hidden_act')!r} is not supported (silu)")
-    if get("rope_scaling"):
-        raise ValueError("afmoe: rope_scaling is not supported")
-    if get("attention_bias"):
-        raise ValueError("afmoe: attention_bias is not supported")
-    layout = tuple(int(t == "sliding_attention") for t in types)
-    period = layout_period(layout)
-    if L % period:
-        raise ValueError(f"afmoe: {L} layers are not whole periods of layer_types' pattern of {period} layers")
-    dense = get("num_dense_layers") or 0
-    if dense > period:
-        raise ValueError(f"afmoe: num_dense_layers {dense} past the first period of {period} layers is not supported")
-    window = get("sliding_window") or 0
-    if any(layout[:L]) and window <= 0:
-        raise ValueError("afmoe: layer_types names sliding_attention layers and sliding_window gives no window")
-    return dict(
-        embed_scale=bool(get("mup_enabled", False)),
-        first_k_dense_replace=dense,
-        n_routed_experts=get("num_experts") or 0,
-        n_shared_experts=get("num_shared_experts") or 0,
-        moe_intermediate_size=get("moe_intermediate_size") or 0,
-        norm_topk_prob=bool(get("route_norm", True)),
-        routed_scaling_factor=float(get("route_scale") or 1.0),
-        sliding_window_size=int(window),
-        sliding_window_layout=layout[:L],
-        rope_layout=layout[:L],
-    )
 
 
 def layout_period(*layouts: tuple[int, ...]) -> int:

@@ -3,11 +3,9 @@ latent attention (MLA, no query low-rank) over latent pages, a leading run
 of dense layers, then layers of sigmoid-routed experts beside shared ones.
 
 The engine reaches a model through `kubeai_tpu.models.family(config)`;
-this module gives it the entry points it uses of `models/llama.py`
-(`init_params`, `params_from_hf`, `stream_params_from_hf`,
-`init_paged_cache`, `cached_attention_route`, `prefill_paged_cold`,
-`prefill_paged`, `decode_step_paged`). What the family does not run is
-refused at load, one line each (`refuse_unsupported`).
+`models/__init__.py` declares what this module gives it. What the family
+does not run is refused at load, one line each (`refuse_unsupported`,
+and `config_keys` for what the config itself asks).
 
 **What a token caches** is ONE vector a layer, shared by all heads:
 `[c | k_rope]`, the normed latent (kv_lora_rank) and the rotated rope key
@@ -89,7 +87,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeai_tpu.models import shared
 from kubeai_tpu.models.base import ModelConfig
+from kubeai_tpu.models.shared import layer_counts, swiglu as _swiglu  # the names `apply` calls them by
 from kubeai_tpu.ops import moe
 from kubeai_tpu.ops.mla_attention import latent_attention_paged, mla_paged_decode
 from kubeai_tpu.ops.norms import rms_norm
@@ -111,31 +111,9 @@ def page_width(config: ModelConfig) -> int:
     return -(-latent_width(config) // 128) * 128
 
 
-def kv_pool_dtype(config: ModelConfig):
-    return jnp.dtype(config.dtype)
-
-
-def layer_counts(config: ModelConfig) -> tuple[int, int]:
-    """(leading dense layers, expert layers)."""
-    dense = min(config.first_k_dense_replace, config.num_layers)
-    return dense, config.num_layers - dense
-
-
 def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1) -> None:
     """What this family does not run, refused at load by name."""
-    if quantization:
-        raise ValueError("deepseek_v3: --quantization is not supported (no int8 for stacked expert weights)")
-    if tp > 1:
-        raise ValueError("deepseek_v3: --tensor-parallel-size > 1 is not supported (latent pages are not sharded)")
-    if config.kv_cache_dtype not in ("", "auto", config.dtype):
-        raise ValueError("deepseek_v3: a kv_cache_dtype other than the compute dtype is not supported")
-    if config.tie_word_embeddings:
-        raise ValueError("deepseek_v3: tied embeddings are not supported (the checkpoint must hold lm_head.weight)")
-
-
-def _refuse_lora(lora) -> None:
-    if lora is not None:
-        raise ValueError("deepseek_v3: LoRA adapters are not supported")
+    shared.refuse_common("deepseek_v3", config, quantization, tp, "latent pages are not sharded")
 
 
 # ---------------------------------------------------------------------------
@@ -199,11 +177,12 @@ def _deinterleave(config: ModelConfig, n: int) -> np.ndarray:
     return np.concatenate([np.arange(0, n, 2), np.arange(1, n, 2)])
 
 
-def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, np.ndarray]:
+def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, dict]:
     """Layer *i* of an HF checkpoint (get(name) -> array) as this module's
-    per-layer arrays: linears transposed to [in, out], `kv_b_proj` split
-    per head into W_uk and W_uv, the rope columns de-interleaved, one
-    layer's experts stacked on a leading axis."""
+    per-layer arrays, under its group of the tree (`dense` or `moe`):
+    linears transposed to [in, out], `kv_b_proj` split per head into W_uk
+    and W_uv, the rope columns de-interleaved, one layer's experts
+    stacked on a leading axis."""
     H = config.num_heads
     dn, dr, dv, r = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim, config.kv_lora_rank
     p = f"model.layers.{i}."
@@ -227,10 +206,10 @@ def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, np.ndar
     }
     if i < config.first_k_dense_replace:
         out.update(wg=conv(lin("mlp.gate_proj")), wu=conv(lin("mlp.up_proj")), wd=conv(lin("mlp.down_proj")))
-        return out
+        return {"dense": out}
     E = config.n_routed_experts
     # Experts stay [E, out, in] on the host (one contiguous copy); the
-    # device transposes them (`_put_row`).
+    # device transposes them (`shared.stream_stacks`).
     stack = lambda which: np.stack([np.asarray(get(f"{p}mlp.experts.{j}.{which}.weight")) for j in range(E)])  # noqa: E731
     out.update(
         wr=conv(lin("mlp.gate")),
@@ -239,55 +218,19 @@ def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, np.ndar
         ws_g=conv(lin("mlp.shared_experts.gate_proj")), ws_u=conv(lin("mlp.shared_experts.up_proj")),
         ws_d=conv(lin("mlp.shared_experts.down_proj")),
     )
-    return out
-
-
-def _put_row(buf, a, i, transpose: bool):
-    return buf.at[i].set(jnp.swapaxes(a, -1, -2) if transpose else a)
+    return {"moe": out}
 
 
 def stream_params_from_hf(source, config: ModelConfig, pad: int = 0) -> Params:
-    """The streamed load: a layer is read, converted and put on the
-    device before the next is touched (an expert layer of kanana-2 is
-    1.28 GB in bf16; the host never holds two), and written into its row
-    of the group's stacked array ON the device, the buffer donated, so
-    the device never holds a group twice either. *source* serves tensors
-    by HF name (`weights.SafetensorsSource`); *pad* columns of zeros are
-    added to the vocabulary."""
-    dtype = jnp.dtype(config.dtype)
+    """`shared.stream_stacks` over this family's two groups (an expert
+    layer of kanana-2 is 1.28 GB in bf16: the host holds two, the device
+    never a group twice): `dense` holds the leading layers, `moe` the
+    layers behind them."""
     n_dense, n_moe = layer_counts(config)
-    # The CPU backend cannot reuse a donated buffer and says so each call.
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    put_row = jax.jit(_put_row, static_argnums=(3,), donate_argnums=donate)
-    params: Params = {"dense": {}, "moe": {}}
-    for i in range(config.num_layers):
-        group, n, row = ("dense", n_dense, i) if i < n_dense else ("moe", n_moe, i - n_dense)
-        for k, a in _layer_tensors(source.get, config, i, dtype).items():
-            experts = k.startswith("we_")
-            shape = (a.shape[0], a.shape[2], a.shape[1]) if experts else a.shape
-            if k not in params[group]:
-                params[group][k] = jnp.zeros((n, *shape), a.dtype)
-            params[group][k] = put_row(params[group][k], a, row, experts)
-    embed = np.asarray(source.get("model.embed_tokens.weight"), dtype)
-    head = np.asarray(source.get("lm_head.weight"), dtype).T
-    if pad:
-        embed, head = np.pad(embed, ((0, pad), (0, 0))), np.pad(head, ((0, 0), (0, pad)))
-    params["embed"] = jax.device_put(embed)
-    params["final_norm"] = jax.device_put(np.asarray(source.get("model.norm.weight"), dtype))
-    params["lm_head"] = jax.device_put(head)
-    return params
+    return shared.stream_stacks(source, config, pad, _layer_tensors, {"dense": (n_dense, 0), "moe": (n_moe, n_dense)})
 
 
-class _DictSource:
-    def __init__(self, state_dict):
-        self.get = state_dict.__getitem__
-
-
-def params_from_hf(state_dict: dict[str, np.ndarray], config: ModelConfig, dtype=None, to_device: bool = True) -> Params:
-    """An HF state dict (name -> array) as this module's tree."""
-    del to_device  # one path: the tree is assembled on the device
-    cfg = config if dtype is None else config.replace(dtype=str(jnp.dtype(dtype)))
-    return stream_params_from_hf(_DictSource(state_dict), cfg)
+params_from_hf = shared.params_from_hf_by(stream_params_from_hf)
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +243,7 @@ def init_paged_cache(config: ModelConfig, num_pages: int, page_size: int, dtype=
     every layer is its trash page. (No singleton "head" axis: the chip
     tiles an array's last two axes, and a [1, W] tile would pad one row
     to sixteen.)"""
-    dtype = dtype or kv_pool_dtype(config)
+    dtype = dtype or jnp.dtype(config.dtype)
     return {"kv": jnp.zeros((config.num_layers * num_pages, page_size, page_width(config)), dtype)}
 
 
@@ -319,10 +262,6 @@ def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, page
 
 # ---------------------------------------------------------------------------
 # Forward
-
-
-def _swiglu(x, wg, wu, wd):
-    return jnp.dot(jax.nn.silu(jnp.dot(x, wg)) * jnp.dot(x, wu), wd)
 
 
 def apply(
@@ -443,48 +382,68 @@ def apply(
     return logits, new_cache
 
 
-def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None, tp_mesh=None, **debug):
-    """A chunk [B, S] at absolute offset *start* [B] behind whatever the
-    table's pages already hold. Returns (logits [B, 1, V] at *last_idx*
-    within the chunk, pool)."""
-    _refuse_lora(lora)
-    S = tokens.shape[1]
-    start = jnp.reshape(start, (-1,)).astype(jnp.int32)
-    pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    return apply(
-        params, config, tokens, pos, pool, page_table,
-        logits_idx=jnp.reshape(last_idx, (-1,)).astype(jnp.int32), **debug,
-    )
+prefill_paged, prefill_paged_cold, decode_step_paged = shared.paged_entry_points(apply, "deepseek_v3")
 
-
-def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
-    """Whole-prompt prefill (positions arange(S)). Returns (logits
-    [B, 1, V] at lengths-1, pool)."""
-    _refuse_lora(lora)
-    B, S = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    return apply(
-        params, config, tokens, pos, pool, page_table,
-        logits_idx=jnp.reshape(lengths, (-1,)).astype(jnp.int32) - 1, left_aligned=True, **debug,
-    )
-
-
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None, **debug):
-    """One decode step for [B, 1] tokens at positions *lengths* [B].
-    Returns (logits [B, 1, V], pool). With *live* (models/base.py::LiveRows)
-    every per-row argument arrives in its order, live rows first, and the
-    logits come back in slot order (the module docstring: the count goes
-    no further; *debug*'s choices stay in the step's order)."""
-    _refuse_lora(lora)
-    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, live=live, **debug)
-
-
-# Appended, so that no line above moves (a Pallas program's cache key holds
-# its call site's line): the seam's two newest names.
-KV_PARK = True  # a slot's pages can be parked, restored and handed off (engine/kvstate.py)
+# The seam's other names (models/__init__.py says what each rule means).
+KV_PARK = True
+PREFIX_REUSE = True
+SLOT_STATE = ()
+init_lora_bank = None
+layer_kinds = None
 
 
 def window_pool_tokens(config: ModelConfig) -> int:
     """No layer of this family keeps a page pool of its own
     (models/smallthinker.py has the family whose window layers do)."""
     return 0
+
+
+def config_keys(get) -> dict:
+    """The DeepSeek-V3 keys of a published config.json as ModelConfig
+    fields. What this module does not compute is refused here, by name,
+    and not served as something else."""
+    if get("q_lora_rank"):
+        raise ValueError("deepseek_v3: a query low-rank (q_lora_rank) is not supported")
+    if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
+        raise ValueError("deepseek_v3: group-limited routing (n_group/topk_group > 1) is not supported")
+    if get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"deepseek_v3: scoring_func {get('scoring_func')!r} is not supported (sigmoid)")
+    if (get("moe_layer_freq") or 1) != 1:
+        raise ValueError("deepseek_v3: moe_layer_freq other than 1 is not supported")
+    if get("attention_bias"):
+        raise ValueError("deepseek_v3: attention_bias is not supported")
+    return dict(
+        n_routed_experts=get("n_routed_experts") or 0,
+        n_shared_experts=get("n_shared_experts") or 0,
+        moe_intermediate_size=get("moe_intermediate_size") or 0,
+        first_k_dense_replace=get("first_k_dense_replace") or 0,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
+        kv_lora_rank=get("kv_lora_rank"),
+        qk_nope_head_dim=get("qk_nope_head_dim"),
+        qk_rope_head_dim=get("qk_rope_head_dim"),
+        v_head_dim=get("v_head_dim"),
+        rope_interleave=bool(get("rope_interleave", False)),
+    )
+
+
+def param_counts(mc: ModelConfig) -> tuple[float, float]:
+    """(held, active a token): latent attention without a query low-rank;
+    `first_k_dense_replace` dense layers, then layers whose every routed
+    expert is resident and of which a token passes through
+    `num_experts_per_tok` and the shared ones. kanana-2 at 8 layers:
+    5.07G held, 0.78G a token (64.1M + 7 x 64.4M + the head)."""
+    D, L, V, H = mc.hidden_size, mc.num_layers, mc.vocab_size, mc.num_heads
+    dn, dr, dv, r = mc.qk_nope_head_dim, mc.qk_rope_head_dim, mc.v_head_dim, mc.kv_lora_rank
+    attn = D * H * (dn + dr) + D * (r + dr) + r + r * H * (dn + dv) + H * dv * D + 2 * D
+    n_dense = min(mc.first_k_dense_replace, L)
+    expert = 3 * D * mc.moe_intermediate_size
+    router = D * mc.n_routed_experts + mc.n_routed_experts
+    shared_ = mc.n_shared_experts * expert
+    dense = attn + 3 * D * mc.intermediate_size
+    fixed = 2 * V * D + D
+    total = fixed + n_dense * dense + (L - n_dense) * (attn + router + shared_ + mc.n_routed_experts * expert)
+    # Active leaves the embedding table out (a row is looked up, not
+    # multiplied; at 128k rows it would be a third of the count).
+    active = V * D + D + n_dense * dense + (L - n_dense) * (attn + router + shared_ + mc.num_experts_per_tok * expert)
+    return float(total), float(active)

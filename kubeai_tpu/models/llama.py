@@ -813,3 +813,58 @@ def window_pool_tokens(config: ModelConfig) -> int:
     """No layer of this family keeps a page pool of its own
     (models/smallthinker.py has the family whose window layers do)."""
     return 0
+
+
+# The seam's other names (models/__init__.py says what each rule means).
+PREFIX_REUSE = True
+SLOT_STATE = ()
+stream_params_from_hf = None  # engine/weights.py's streamed load shards and quantizes this family's tree
+layer_kinds = None
+
+
+def config_keys(get) -> dict:
+    """The dense family's dialects of a published config.json as
+    ModelConfig fields: one module's variants by `model_type` (every
+    other type is plain Llama naming)."""
+    model_type = get("model_type", "llama")
+    if model_type == "qwen2":
+        return {"qkv_bias": True}  # Qwen2 hardcodes q/k/v projection biases (modeling_qwen2)
+    if model_type not in ("gemma", "gemma2"):
+        return {}
+    keys = dict(hidden_act="gelu_tanh", embed_scale=True, rms_one_offset=True)
+    if model_type == "gemma2":
+        keys.update(
+            post_norms=True,
+            attn_softcap=get("attn_logit_softcapping", 50.0) or 0.0,
+            logit_softcap=get("final_logit_softcapping", 30.0) or 0.0,
+            query_scale=(get("query_pre_attn_scalar") or 0) ** -0.5
+            if get("query_pre_attn_scalar")
+            else None,
+            # HF Gemma2 applies the window on even layer indices.
+            sliding_window=get("sliding_window") or 0,
+            sliding_layers="even",
+        )
+    return keys
+
+
+def param_counts(mc: ModelConfig) -> tuple[float, float]:
+    """(held, active a token), analytically. Dense: total == active;
+    Mixtral-style experts are all resident (weight-read roofline: a
+    batched decode step touches every expert) and only the routed top-k
+    active (FLOPs/token)."""
+    D, F, L, V = mc.hidden_size, mc.intermediate_size, mc.num_layers, mc.vocab_size
+    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
+    attn = D * H * h + 2 * D * Kv * h + H * h * D
+    if mc.qkv_bias:
+        attn += (H + 2 * Kv) * h
+    mlp = 3 * D * F
+    norms = 2 * D + (2 * D if mc.post_norms else 0)
+    E = mc.num_experts
+    if E:
+        router = D * E
+        layer_total = attn + norms + E * mlp + router
+        layer_active = attn + norms + mc.num_experts_per_tok * mlp + router
+    else:
+        layer_total = layer_active = attn + norms + mlp
+    fixed = V * D + (0 if mc.tie_word_embeddings else V * D) + D
+    return float(fixed + L * layer_total), float(fixed + L * layer_active)

@@ -17,8 +17,8 @@ attention, `E` experts). Block `i`, with `u = rmsnorm(x; g_i)`:
 then a final rmsnorm and the untied head. The drafting head
 (`num_nextn_predict_layers`) is not loaded: it changes no served
 distribution. The engine reaches a model through
-`kubeai_tpu.models.family(config)`; this module gives it the entry points
-it uses of `models/llama.py`.
+`kubeai_tpu.models.family(config)`; `models/__init__.py` declares what
+this module gives it.
 
 **The pattern is walked statically**: eleven blocks of a cut, 88 of the
 whole, unrolled; `params["blocks"][i]` holds block i's tensors and nothing
@@ -63,13 +63,13 @@ quantization, no LoRA.
 
 from __future__ import annotations
 
-from types import SimpleNamespace
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeai_tpu.models import shared
 from kubeai_tpu.models.base import ModelConfig
 from kubeai_tpu.ops import moe, ssm
 from kubeai_tpu.ops.attention import attention
@@ -81,7 +81,7 @@ Params = dict[str, Any]
 PAGED_KERNEL_LABEL = "ragged"
 KV_PARK = False  # the module docstring says why
 PREFIX_REUSE = False  # likewise
-SLOT_STATE = True  # init_paged_cache takes `slots`; the prefill entry points take each row's slot
+SLOT_STATE = ("ssm", "conv")  # init_paged_cache takes `slots`; the prefill entry points take each row's slot
 
 
 def kinds(config: ModelConfig) -> dict[str, int]:
@@ -116,22 +116,12 @@ def held_share(config: ModelConfig):
 
 def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1) -> None:
     """What this family does not run, refused at load by name."""
-    if quantization:
-        raise ValueError("nemotron_h: --quantization is not supported (no int8 for expert or mixer weights)")
-    if tp > 1:
-        raise ValueError("nemotron_h: --tensor-parallel-size > 1 is not supported (state, experts and the pool are unsharded)")
-    if config.kv_cache_dtype not in ("", "auto", config.dtype):
-        raise ValueError("nemotron_h: a kv_cache_dtype other than the compute dtype is not supported")
-    if config.tie_word_embeddings:
-        raise ValueError("nemotron_h: tied embeddings are not supported (the checkpoint must hold lm_head.weight)")
+    shared.refuse_common(
+        "nemotron_h", config, quantization, tp, "state, experts and the pool are unsharded", int8_for="expert or mixer weights",
+    )
     missing = [k for k, n in kinds(config).items() if not n]
     if missing:
         raise ValueError(f"nemotron_h: a stack without a block of each kind is not supported (none of {missing})")
-
-
-def _refuse_lora(lora) -> None:
-    if lora is not None:
-        raise ValueError("nemotron_h: LoRA adapters are not supported")
 
 
 # ---------------------------------------------------------------------------
@@ -219,42 +209,21 @@ def _block_tensors(get, config: ModelConfig, i: int, dtype) -> dict:
 
 
 def stream_params_from_hf(source, config: ModelConfig, pad: int = 0) -> Params:
-    """The streamed load: while a block is put on the device, a reader
-    thread takes the next from the checkpoint and converts it (an `E`
-    block is 1.5 GB in bf16: the host holds two). *source* serves tensors
-    by HF name; *pad* columns of zeros are added to the vocabulary."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    dtype = jnp.dtype(config.dtype)
-    L = len(config.layer_pattern)
+    """The streamed load of a tree that is a LIST of blocks, not stacks:
+    each block (`shared.read_ahead`: an `E` block is 1.5 GB in bf16, the
+    host holds two) is put on the device as it comes. *source* serves
+    tensors by HF name; *pad* columns of zeros are added to the
+    vocabulary."""
     transposed = jax.jit(lambda a: jnp.swapaxes(a, -1, -2))
-    blocks = []
-    with ThreadPoolExecutor(max_workers=1) as reader:
-        ahead = reader.submit(_block_tensors, source.get, config, 0, dtype)
-        for i in range(L):
-            tensors = ahead.result()
-            if i + 1 < L:
-                ahead = reader.submit(_block_tensors, source.get, config, i + 1, dtype)
-            blocks.append({
-                k: transposed(jax.device_put(a)) if k in ("we_1", "we_2") else jax.device_put(a) for k, a in tensors.items()
-            })
-    embed = np.asarray(source.get("backbone.embeddings.weight"), dtype)
-    head = np.asarray(source.get("lm_head.weight"), dtype).T
-    if pad:
-        embed, head = np.pad(embed, ((0, pad), (0, 0))), np.pad(head, ((0, 0), (0, pad)))
-    return {
-        "embed": jax.device_put(embed),
-        "final_norm": jax.device_put(np.asarray(source.get("backbone.norm_f.weight"), dtype)),
-        "lm_head": jax.device_put(head),
-        "blocks": blocks,
-    }
+    blocks = [
+        {k: transposed(jax.device_put(a)) if k in ("we_1", "we_2") else jax.device_put(a) for k, a in tensors.items()}
+        for tensors in shared.read_ahead(_block_tensors, source, config, len(config.layer_pattern), jnp.dtype(config.dtype))
+    ]
+    outside = shared.embed_norm_head(source, config, pad, embed="backbone.embeddings.weight", norm="backbone.norm_f.weight")
+    return {**outside, "blocks": blocks}
 
 
-def params_from_hf(state_dict: dict[str, np.ndarray], config: ModelConfig, dtype=None, to_device: bool = True) -> Params:
-    """An HF state dict (name -> array) as this module's tree."""
-    del to_device  # one path: the tree is assembled on the device
-    cfg = config if dtype is None else config.replace(dtype=str(jnp.dtype(dtype)))
-    return stream_params_from_hf(SimpleNamespace(get=state_dict.__getitem__), cfg)
+params_from_hf = shared.params_from_hf_by(stream_params_from_hf)
 
 
 # ---------------------------------------------------------------------------
@@ -478,44 +447,100 @@ def apply(
     return logits, new_cache
 
 
-def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None, tp_mesh=None, slots=None, **debug):
-    """A chunk [B, S] at absolute offset *start* [B] of the sequences in
-    *slots* [B]: behind whatever the tables' pages hold and from the state
-    the chunks before it left there (zeros where *start* is 0). Returns
-    (logits [B, 1, V] at *last_idx* within the chunk, cache)."""
-    _refuse_lora(lora)
-    S = tokens.shape[1]
-    start = jnp.reshape(start, (-1,)).astype(jnp.int32)
-    last_idx = jnp.reshape(last_idx, (-1,)).astype(jnp.int32)
-    pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    return apply(
-        params, config, tokens, pos, pool, page_table, n_real=last_idx + 1,
-        slots=jnp.reshape(slots, (-1,)).astype(jnp.int32), carried=start > 0, logits_idx=last_idx, **debug,
+prefill_paged, prefill_paged_cold, decode_step_paged = shared.paged_entry_points(apply, "nemotron_h", by_slot=bool(SLOT_STATE))
+
+# The seam's other names (models/__init__.py says what each rule means).
+REUSE_WHOLE_PREFILL_CALLS = False  # moot: PREFIX_REUSE is False
+init_lora_bank = None
+layer_kinds = None
+
+
+def config_keys(get) -> dict:
+    """The Nemotron-H keys of a published config.json as ModelConfig
+    fields. What this module does not compute is refused here, by name.
+    The pattern may be longer than the depth (a checkpoint cut in depth
+    keeps the published 88 characters): the first `num_hidden_layers` are
+    the model's. `num_nextn_predict_layers` and
+    `mtp_hybrid_override_pattern` (the drafting head) are read by nothing:
+    it changes no served distribution and is not loaded. `rope_theta` and
+    `partial_rotary_factor` likewise: the family's attention layers apply
+    no rotary embedding. `time_step_*` initialise `dt_bias` in training."""
+    L = get("num_hidden_layers")
+    pattern = get("hybrid_override_pattern")
+    if not isinstance(pattern, str) or len(pattern) < L:
+        raise ValueError(f"nemotron_h: hybrid_override_pattern must name each of the {L} blocks")
+    pattern = pattern[:L]
+    if set(pattern) - set("M*E"):
+        raise ValueError(
+            f"nemotron_h: hybrid_override_pattern names blocks other than M, * and E ({sorted(set(pattern) - set('M*E'))}: "
+            "a dense feed-forward block is not supported)"
+        )
+    if (get("n_group") or 1) != 1 or (get("topk_group") or 1) != 1:
+        raise ValueError("nemotron_h: group-limited routing (n_group/topk_group > 1) is not supported")
+    if not get("moe_latent_size"):
+        raise ValueError("nemotron_h: experts outside a latent space (no moe_latent_size) are not supported")
+    if (get("n_shared_experts") or 0) != 1:
+        raise ValueError("nemotron_h: n_shared_experts other than 1 is not supported")
+    for key, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu")):
+        if get(key, want) != want:
+            raise ValueError(f"nemotron_h: {key} {get(key)!r} is not supported ({want})")
+    for key in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias", "residual_in_fp32"):
+        if get(key):
+            raise ValueError(f"nemotron_h: {key} is not supported")
+    if not get("use_conv_bias", True):
+        raise ValueError("nemotron_h: use_conv_bias false is not supported")
+    if get("sliding_window"):
+        raise ValueError("nemotron_h: sliding_window is not supported")
+    heads, head_dim = get("mamba_num_heads") or 0, get("mamba_head_dim") or 0
+    groups = get("n_groups") or 0
+    if not heads or not groups or heads % groups or (heads * head_dim) % groups:
+        raise ValueError("nemotron_h: mamba_num_heads must be a whole number of heads for each of n_groups")
+    held, scored = get("n_routed_experts") or 0, get("router_experts") or 0
+    first = get("experts_first") or 0
+    if scored and first + held > scored:
+        raise ValueError(f"nemotron_h: experts {first}..{first + held - 1} are not among the router's {scored}")
+    return dict(
+        intermediate_size=0,  # no dense feed-forward block
+        rms_norm_eps=get("layer_norm_epsilon", 1e-5),
+        layer_pattern=pattern,
+        mamba_num_heads=heads,
+        mamba_head_dim=head_dim,
+        ssm_state_size=get("ssm_state_size"),
+        ssm_groups=groups,
+        conv_kernel=get("conv_kernel"),
+        ssm_chunk=get("chunk_size"),
+        n_routed_experts=held,
+        router_experts=scored,
+        experts_first=first,
+        n_shared_experts=1,
+        moe_intermediate_size=get("moe_intermediate_size") or 0,
+        moe_latent_size=get("moe_latent_size"),
+        moe_shared_intermediate_size=get("moe_shared_expert_intermediate_size") or 0,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor") or 1.0),
     )
 
 
-def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, slots=None, **debug):
-    """Whole-prompt prefill (positions arange(S)) of the sequences in
-    *slots* [B], each from zeros. Returns (logits [B, 1, V] at
-    lengths-1, cache)."""
-    _refuse_lora(lora)
-    B, S = tokens.shape
-    lengths = jnp.reshape(lengths, (-1,)).astype(jnp.int32)
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    return apply(
-        params, config, tokens, pos, pool, page_table, n_real=lengths,
-        slots=jnp.reshape(slots, (-1,)).astype(jnp.int32), logits_idx=lengths - 1, left_aligned=True, **debug,
-    )
-
-
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None, **debug):
-    """One decode step for [B, 1] tokens at positions *lengths* [B]; row i
-    is slot i's, or with *live* (models/base.py::LiveRows) slot
-    `live.order[i]`'s, live rows first: the attention kernel stops at the
-    count, the `M` blocks move live slots' state only, and the logits come
-    back in slot order. Without *live* every row is taken as live.
-    Returns (logits [B, 1, V], cache)."""
-    _refuse_lora(lora)
-    B = tokens.shape[0]
-    n_real = jnp.ones((B,), jnp.int32) if live is None else (jnp.arange(B, dtype=jnp.int32) < live.count).astype(jnp.int32)
-    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, n_real=n_real, live=live, **debug)
+def param_counts(mc: ModelConfig) -> tuple[float, float]:
+    """(held, active a token): blocks that are each a Mamba-2 mixer,
+    attention, or experts in a latent space beside a shared expert. Held:
+    what THIS chip holds (`n_routed_experts` experts a block). Active:
+    what a token is multiplied by on this chip, its `num_experts_per_tok`
+    choices landing here in the ratio of the held experts to the router's
+    width. Nemotron-3-Super at 11 blocks, 128 of 512 experts, a quarter
+    of the vocabulary: 4.65G held, 1.04G a token. Held to
+    perfbench/families/nemotron_h_counts.py by tests/test_nemotron_h.py."""
+    D, V = mc.hidden_size, mc.vocab_size
+    Hm, inner = mc.mamba_num_heads, mc.mamba_num_heads * mc.mamba_head_dim
+    C = inner + 2 * mc.ssm_groups * mc.ssm_state_size
+    mixer = D * (inner + C + Hm) + C * mc.conv_kernel + C + 3 * Hm + inner + inner * D + D
+    attn = D * (mc.num_heads + 2 * mc.num_kv_heads) * mc.head_dim_ + mc.num_heads * mc.head_dim_ * D + D
+    R = mc.router_experts or mc.n_routed_experts
+    expert = 2 * mc.moe_latent_size * mc.moe_intermediate_size
+    outside = D * R + R + 2 * D * mc.moe_latent_size + 2 * D * mc.moe_shared_intermediate_size + D
+    n = kinds(mc)
+    dense = n["M"] * mixer + n["*"] * attn + n["E"] * outside
+    total = 2 * V * D + D + dense + n["E"] * mc.n_routed_experts * expert
+    # Active leaves the embedding table out (a row is looked up).
+    active = V * D + D + dense + n["E"] * mc.num_experts_per_tok * mc.n_routed_experts / R * expert
+    return float(total), float(active)

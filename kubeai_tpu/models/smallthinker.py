@@ -13,9 +13,9 @@ experts chosen from the layer's INPUT. With `x` a layer's input:
     y = sum_{e in S} w_e (relu(m Wg_e) * (m Wu_e)) Wd_e;   x' = h + y
 
 The engine reaches a model through `kubeai_tpu.models.family(config)`;
-this module gives it the entry points it uses of `models/llama.py`. What
-the family does not run is refused at load (`refuse_unsupported`, and
-`models/base.py::_smallthinker_keys` for what the config itself asks).
+`models/__init__.py` declares what this module gives it. What the family
+does not run is refused at load (`refuse_unsupported`, and `config_keys`
+for what the config itself asks).
 
 **The scan runs over periods.** Inside its body the period's layers are
 unrolled, so each kind of layer is a call of its own: the window is a
@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeai_tpu.models import shared
 from kubeai_tpu.models.base import ModelConfig, layout_period
 from kubeai_tpu.ops import moe
 from kubeai_tpu.ops.attention import attention
@@ -112,28 +113,12 @@ def window_pages(config: ModelConfig, S: int, page: int, max_pages: int) -> int:
     return min(max_pages, (S + config.sliding_window_size - 2) // page + 2)
 
 
-def kv_pool_dtype(config: ModelConfig):
-    return jnp.dtype(config.dtype)
-
-
 def refuse_unsupported(config: ModelConfig, quantization: str = "", tp: int = 1) -> None:
     """What this family does not run, refused at load by name."""
-    if quantization:
-        raise ValueError("smallthinker: --quantization is not supported (no int8 for stacked expert weights)")
-    if tp > 1:
-        raise ValueError("smallthinker: --tensor-parallel-size > 1 is not supported (experts and both pools are unsharded)")
-    if config.kv_cache_dtype not in ("", "auto", config.dtype):
-        raise ValueError("smallthinker: a kv_cache_dtype other than the compute dtype is not supported")
-    if config.tie_word_embeddings:
-        raise ValueError("smallthinker: tied embeddings are not supported (the checkpoint must hold lm_head.weight)")
+    shared.refuse_common("smallthinker", config, quantization, tp, "experts and both pools are unsharded")
     full, window = layer_kinds(config)
     if not full or not window:
         raise ValueError("smallthinker: a stack without both full and window layers is not supported")
-
-
-def _refuse_lora(lora) -> None:
-    if lora is not None:
-        raise ValueError("smallthinker: LoRA adapters are not supported")
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +163,7 @@ def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, dict]:
     """Layer *i* of an HF checkpoint (get(name) -> array) by group of the
     tree: linears transposed to [in, out]; the experts stacked [E, out,
     in] on the host (one contiguous copy; the device transposes them,
-    `_put_row`)."""
+    `shared.stream_stacks`)."""
     p = f"model.layers.{i}."
     conv = lambda a: np.asarray(a, dtype)  # noqa: E731
     lin = lambda name: conv(np.asarray(get(p + name + ".weight")).T)  # noqa: E731
@@ -195,62 +180,15 @@ def _layer_tensors(get, config: ModelConfig, i: int, dtype) -> dict[str, dict]:
     return {"layers": layer, "experts": {"we_g": stack("gate"), "we_u": stack("up"), "we_d": stack("down")}}
 
 
-def _put_row(buf, a, i, transpose: bool):
-    return buf.at[i].set(jnp.swapaxes(a, -1, -2) if transpose else a)
-
-
-def stream_params_from_hf(source, config: ModelConfig, pad: int = 0, layer_tensors=_layer_tensors, rows=None) -> Params:
-    """The streamed load: while a layer is put on the device and written
-    into its row of the stacked arrays ON the device (the buffer donated),
-    a reader thread takes the next from the checkpoint and converts it (a
-    layer's experts are 0.75 GB in bf16: the host holds two layers, the
-    device never a stack twice). *source* serves tensors by HF name; *pad*
-    columns of zeros are added to the vocabulary. A family whose groups
-    do not all hold every layer (`models/afmoe.py`) brings its own
-    *layer_tensors* and, by group, *rows*: (rows of the group's stacks,
-    the layer that is their row 0)."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    dtype = jnp.dtype(config.dtype)
+def stream_params_from_hf(source, config: ModelConfig, pad: int = 0) -> Params:
+    """`shared.stream_stacks` over this family's two groups, each of every
+    layer (a layer's experts are 0.75 GB in bf16: the host holds two
+    layers, the device never a stack twice)."""
     L = config.num_layers
-    rows = rows or {"layers": (L, 0), "experts": (L, 0)}
-    donate = (0,) if jax.default_backend() != "cpu" else ()  # the CPU backend cannot reuse a donated buffer
-    put_row = jax.jit(_put_row, static_argnums=(3,), donate_argnums=donate)
-    params: Params = {group: {} for group in rows}
-    with ThreadPoolExecutor(max_workers=1) as reader:
-        ahead = reader.submit(layer_tensors, source.get, config, 0, dtype)
-        for i in range(L):
-            groups = ahead.result()
-            if i + 1 < L:
-                ahead = reader.submit(layer_tensors, source.get, config, i + 1, dtype)
-            for group, tensors in groups.items():
-                experts = group == "experts"
-                n, first = rows[group]
-                for k, a in tensors.items():
-                    shape = (a.shape[0], a.shape[2], a.shape[1]) if experts else a.shape
-                    if k not in params[group]:
-                        params[group][k] = jnp.zeros((n, *shape), a.dtype)
-                    params[group][k] = put_row(params[group][k], a, i - first, experts)
-    embed = np.asarray(source.get("model.embed_tokens.weight"), dtype)
-    head = np.asarray(source.get("lm_head.weight"), dtype).T
-    if pad:
-        embed, head = np.pad(embed, ((0, pad), (0, 0))), np.pad(head, ((0, 0), (0, pad)))
-    params["embed"] = jax.device_put(embed)
-    params["final_norm"] = jax.device_put(np.asarray(source.get("model.norm.weight"), dtype))
-    params["lm_head"] = jax.device_put(head)
-    return params
+    return shared.stream_stacks(source, config, pad, _layer_tensors, {"layers": (L, 0), "experts": (L, 0)})
 
 
-class _DictSource:
-    def __init__(self, state_dict):
-        self.get = state_dict.__getitem__
-
-
-def params_from_hf(state_dict: dict[str, np.ndarray], config: ModelConfig, dtype=None, to_device: bool = True) -> Params:
-    """An HF state dict (name -> array) as this module's tree."""
-    del to_device  # one path: the tree is assembled on the device
-    cfg = config if dtype is None else config.replace(dtype=str(jnp.dtype(dtype)))
-    return stream_params_from_hf(_DictSource(state_dict), cfg)
+params_from_hf = shared.params_from_hf_by(stream_params_from_hf)
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +198,7 @@ def params_from_hf(state_dict: dict[str, np.ndarray], config: ModelConfig, dtype
 def init_paged_cache(config: ModelConfig, num_pages: int, page_size: int, dtype=None, window_pages: int = 0) -> Params:
     """The two pools (module docstring): *num_pages* logical pages a full
     layer, *window_pages* a window layer."""
-    dtype = dtype or kv_pool_dtype(config)
+    dtype = dtype or jnp.dtype(config.dtype)
     full, window = layer_kinds(config)
     page = (page_size, 2 * config.num_kv_heads, config.head_dim_)
     return {
@@ -467,38 +405,69 @@ def apply(
     return logits, new_cache
 
 
-def prefill_paged(params, config, tokens, pool, page_table, start, last_idx, lora=None, lora_rows=None, tp_mesh=None, **debug):
-    """A chunk [B, S] at absolute offset *start* [B] behind whatever the
-    tables' pages already hold. Returns (logits [B, 1, V] at *last_idx*
-    within the chunk, pools)."""
-    _refuse_lora(lora)
-    S = tokens.shape[1]
-    start = jnp.reshape(start, (-1,)).astype(jnp.int32)
-    pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    return apply(
-        params, config, tokens, pos, pool, page_table,
-        logits_idx=jnp.reshape(last_idx, (-1,)).astype(jnp.int32), **debug,
+prefill_paged, prefill_paged_cold, decode_step_paged = shared.paged_entry_points(apply, "smallthinker")
+
+# The seam's other names (models/__init__.py says what each rule means).
+PREFIX_REUSE = True
+SLOT_STATE = ()
+init_lora_bank = None
+
+
+def config_keys(get) -> dict:
+    """The SmallThinker keys of a published config.json as ModelConfig
+    fields. What this module does not compute is refused here, by name.
+    The layouts may be longer than the depth (a checkpoint cut in depth
+    keeps the published lists): the first `num_hidden_layers` entries are
+    the model's."""
+    L = get("num_hidden_layers")
+    if not get("moe_primary_router_apply_softmax", False):
+        raise ValueError("smallthinker: moe_primary_router_apply_softmax false (a sigmoid router) is not supported")
+    if get("moe_enable_secondary_experts") or get("moe_num_secondary_experts"):
+        raise ValueError("smallthinker: secondary experts are not supported")
+    if get("rope_scaling"):
+        raise ValueError("smallthinker: rope_scaling is not supported")
+    if not get("norm_topk_prob", True):
+        raise ValueError("smallthinker: norm_topk_prob false is not supported")
+    layouts = {}
+    for key in ("sliding_window_layout", "rope_layout"):
+        layout = get(key)
+        if not isinstance(layout, (list, tuple)) or len(layout) < L or any(v not in (0, 1) for v in layout):
+            raise ValueError(f"smallthinker: {key} must give 0 or 1 for each of the {L} layers")
+        layouts[key] = tuple(int(v) for v in layout)
+    period = layout_period(*layouts.values())
+    if L % period:
+        raise ValueError(
+            f"smallthinker: {L} layers are not whole periods of the layouts' pattern of {period} layers"
+        )
+    layouts = {key: layout[:L] for key, layout in layouts.items()}
+    window = get("sliding_window_size") or 0
+    if any(layouts["sliding_window_layout"]) and window <= 0:
+        raise ValueError("smallthinker: sliding_window_layout names window layers and sliding_window_size gives no window")
+    return dict(
+        intermediate_size=0,  # no dense feed-forward anywhere in the stack
+        n_routed_experts=get("moe_num_primary_experts") or 0,
+        num_experts_per_tok=get("moe_num_active_primary_experts") or 0,
+        moe_intermediate_size=get("moe_ffn_hidden_size") or 0,
+        norm_topk_prob=True,
+        sliding_window_size=int(window),
+        **layouts,
     )
 
 
-def prefill_paged_cold(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, **debug):
-    """Whole-prompt prefill (positions arange(S)). Returns (logits
-    [B, 1, V] at lengths-1, pools)."""
-    _refuse_lora(lora)
-    B, S = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    return apply(
-        params, config, tokens, pos, pool, page_table,
-        logits_idx=jnp.reshape(lengths, (-1,)).astype(jnp.int32) - 1, left_aligned=True, **debug,
-    )
-
-
-def decode_step_paged(params, config, tokens, pool, page_table, lengths, lora=None, lora_rows=None, tp_mesh=None, live=None, **debug):
-    """One decode step for [B, 1] tokens at positions *lengths* [B].
-    Returns (logits [B, 1, V], pools). With *live* (models/base.py::LiveRows)
-    every per-row argument arrives in its order, live rows first (both
-    halves of a table row together): both kinds of layer hand the kernel
-    the one count and the logits come back in slot order (*debug*'s
-    choices stay in the step's order)."""
-    _refuse_lora(lora)
-    return apply(params, config, tokens, lengths[:, None].astype(jnp.int32), pool, page_table, live=live, **debug)
+def param_counts(mc: ModelConfig) -> tuple[float, float]:
+    """(held, active a token): every layer holds grouped-query attention,
+    a router and `n_routed_experts` experts, of which a token passes
+    through `num_experts_per_tok`; no dense layer, no shared expert. The
+    published 21B-A3B at 12 layers: 4.78G in layers + 0.78G outside held;
+    0.68G + 0.39G a token (56.5M a layer with 6 experts, and the head).
+    Held to perfbench/families/smallthinker_counts.py by
+    tests/test_smallthinker.py."""
+    D, L, V = mc.hidden_size, mc.num_layers, mc.vocab_size
+    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
+    attn = D * (H + 2 * Kv) * h + H * h * D + 2 * D
+    expert = 3 * D * mc.moe_intermediate_size
+    router = D * mc.n_routed_experts
+    total = 2 * V * D + D + L * (attn + router + mc.n_routed_experts * expert)
+    # Active leaves the embedding table out (a row is looked up).
+    active = V * D + D + L * (attn + router + mc.num_experts_per_tok * expert)
+    return float(total), float(active)

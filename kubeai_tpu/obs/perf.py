@@ -119,126 +119,11 @@ _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
 
 
 def param_counts(mc) -> tuple[float, float]:
-    """(total, active) parameter counts from a ModelConfig, analytically.
-    Dense families have total == active; MoE counts every expert as
-    resident (weight-read roofline: a batched decode step touches all
-    experts) but only the routed top-k as active (FLOPs/token)."""
-    D, F, L, V = mc.hidden_size, mc.intermediate_size, mc.num_layers, mc.vocab_size
-    if getattr(mc, "model_type", "") == "deepseek_v3":
-        return _deepseek_v3_param_counts(mc)
-    if getattr(mc, "model_type", "") == "smallthinker":
-        return _smallthinker_param_counts(mc)
-    if getattr(mc, "model_type", "") == "nemotron_h":
-        return _nemotron_h_param_counts(mc)
-    if getattr(mc, "model_type", "") == "afmoe":
-        return _afmoe_param_counts(mc)
-    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
-    attn = D * H * h + 2 * D * Kv * h + H * h * D
-    if getattr(mc, "qkv_bias", False):
-        attn += (H + 2 * Kv) * h
-    mlp = 3 * D * F
-    norms = 2 * D + (2 * D if getattr(mc, "post_norms", False) else 0)
-    E = getattr(mc, "num_experts", 0)
-    if E:
-        k = mc.num_experts_per_tok
-        router = D * E
-        layer_total = attn + norms + E * mlp + router
-        layer_active = attn + norms + k * mlp + router
-    else:
-        layer_total = layer_active = attn + norms + mlp
-    embed = V * D
-    head = 0 if getattr(mc, "tie_word_embeddings", False) else V * D
-    fixed = embed + head + D
-    return float(fixed + L * layer_total), float(fixed + L * layer_active)
+    """(total, active) parameter counts of a ModelConfig, analytically:
+    its family's (kubeai_tpu/models/__init__.py::SEAM, `param_counts`)."""
+    from kubeai_tpu.models import family  # lazily: the families import jax
 
-
-def _deepseek_v3_param_counts(mc) -> tuple[float, float]:
-    """DeepSeek-V3 family (models/deepseek.py): latent attention without
-    a query low-rank; `first_k_dense_replace` dense layers, then layers
-    whose every routed expert is resident and of which a token passes
-    through `num_experts_per_tok` and the shared ones. kanana-2 at 8
-    layers: 5.07G held, 0.78G a token (64.1M + 7 x 64.4M + the head)."""
-    D, L, V, H = mc.hidden_size, mc.num_layers, mc.vocab_size, mc.num_heads
-    dn, dr, dv, r = mc.qk_nope_head_dim, mc.qk_rope_head_dim, mc.v_head_dim, mc.kv_lora_rank
-    attn = D * H * (dn + dr) + D * (r + dr) + r + r * H * (dn + dv) + H * dv * D + 2 * D
-    n_dense = min(mc.first_k_dense_replace, L)
-    expert = 3 * D * mc.moe_intermediate_size
-    router = D * mc.n_routed_experts + mc.n_routed_experts
-    shared = mc.n_shared_experts * expert
-    dense = attn + 3 * D * mc.intermediate_size
-    fixed = 2 * V * D + D
-    total = fixed + n_dense * dense + (L - n_dense) * (attn + router + shared + mc.n_routed_experts * expert)
-    # Active leaves the embedding table out (a row is looked up, not
-    # multiplied; at 128k rows it would be a third of the count).
-    active = V * D + D + n_dense * dense + (L - n_dense) * (attn + router + shared + mc.num_experts_per_tok * expert)
-    return float(total), float(active)
-
-
-def _smallthinker_param_counts(mc) -> tuple[float, float]:
-    """SmallThinker family (models/smallthinker.py): every layer holds
-    grouped-query attention, a router and `n_routed_experts` experts, of
-    which a token passes through `num_experts_per_tok`; no dense layer,
-    no shared expert. The published 21B-A3B at 12 layers: 4.78G in
-    layers + 0.78G outside held; 0.68G + 0.39G a token (56.5M a layer
-    with 6 experts, and the head). Held to perfbench/families/
-    smallthinker_counts.py by tests/test_smallthinker.py."""
-    D, L, V = mc.hidden_size, mc.num_layers, mc.vocab_size
-    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
-    attn = D * (H + 2 * Kv) * h + H * h * D + 2 * D
-    expert = 3 * D * mc.moe_intermediate_size
-    router = D * mc.n_routed_experts
-    total = 2 * V * D + D + L * (attn + router + mc.n_routed_experts * expert)
-    # Active leaves the embedding table out (a row is looked up).
-    active = V * D + D + L * (attn + router + mc.num_experts_per_tok * expert)
-    return float(total), float(active)
-
-
-def _nemotron_h_param_counts(mc) -> tuple[float, float]:
-    """Nemotron-H family (models/nemotron_h.py): blocks that are each a
-    Mamba-2 mixer, attention, or experts in a latent space beside a shared
-    expert. Held: what THIS chip holds (`n_routed_experts` experts a
-    block). Active: what a token is multiplied by on this chip, its
-    `num_experts_per_tok` choices landing here in the ratio of the held
-    experts to the router's width. Nemotron-3-Super at 11 blocks, 128 of
-    512 experts, a quarter of the vocabulary: 4.65G held, 1.04G a token.
-    Held to perfbench/families/nemotron_h_counts.py by
-    tests/test_nemotron_h.py."""
-    D, V = mc.hidden_size, mc.vocab_size
-    Hm, inner = mc.mamba_num_heads, mc.mamba_num_heads * mc.mamba_head_dim
-    C = inner + 2 * mc.ssm_groups * mc.ssm_state_size
-    mixer = D * (inner + C + Hm) + C * mc.conv_kernel + C + 3 * Hm + inner + inner * D + D
-    attn = D * (mc.num_heads + 2 * mc.num_kv_heads) * mc.head_dim_ + mc.num_heads * mc.head_dim_ * D + D
-    R = mc.router_experts or mc.n_routed_experts
-    expert = 2 * mc.moe_latent_size * mc.moe_intermediate_size
-    outside = D * R + R + 2 * D * mc.moe_latent_size + 2 * D * mc.moe_shared_intermediate_size + D
-    n = {k: mc.layer_pattern.count(k) for k in "M*E"}
-    dense = n["M"] * mixer + n["*"] * attn + n["E"] * outside
-    total = 2 * V * D + D + dense + n["E"] * mc.n_routed_experts * expert
-    # Active leaves the embedding table out (a row is looked up).
-    active = V * D + D + dense + n["E"] * mc.num_experts_per_tok * mc.n_routed_experts / R * expert
-    return float(total), float(active)
-
-
-def _afmoe_param_counts(mc) -> tuple[float, float]:
-    """AFMoE family (models/afmoe.py): every layer holds gated
-    grouped-query attention with query/key norms and four norms;
-    `first_k_dense_replace` layers a dense feed-forward, the rest a
-    router with its selection bias, `n_shared_experts` shared experts and
-    `n_routed_experts` routed ones, of which a token passes through
-    `num_experts_per_tok`. Trinity-Mini at 8 of 32 layers: 5.98G held,
-    1.04G a token; at 32: 26.1G and 3.06G. Held to perfbench/families/
-    afmoe_counts.py by tests/test_afmoe.py."""
-    D, L, V = mc.hidden_size, mc.num_layers, mc.vocab_size
-    H, Kv, h = mc.num_heads, mc.num_kv_heads, mc.head_dim_
-    attn = 3 * D * H * h + 2 * D * Kv * h + 2 * h + 4 * D
-    n_dense = min(mc.first_k_dense_replace, L)
-    expert = 3 * D * mc.moe_intermediate_size
-    outside = D * mc.n_routed_experts + mc.n_routed_experts + mc.n_shared_experts * expert
-    always = L * attn + n_dense * 3 * D * mc.intermediate_size + (L - n_dense) * outside  # whatever the routing
-    total = 2 * V * D + D + always + (L - n_dense) * mc.n_routed_experts * expert
-    # Active leaves the embedding table out (a row is looked up).
-    active = V * D + D + always + (L - n_dense) * mc.num_experts_per_tok * expert
-    return float(total), float(active)
+    return family(mc).param_counts(mc)
 
 
 @dataclass(frozen=True)
@@ -265,6 +150,8 @@ class PerfModel:
         tree), overrides the analytic estimate; otherwise params are
         costed at 1 byte for int8 weight-only quantization, else the
         model dtype's width."""
+        from kubeai_tpu.models import family  # lazily: the families import jax
+
         total, active = param_counts(mc)
         if weight_bytes is None:
             per_param = 1 if quantization == "int8" else _DTYPE_BYTES.get(mc.dtype, 2)
@@ -274,9 +161,8 @@ class PerfModel:
             active_params=active,
             flops_per_token=2.0 * active,
             weight_bytes=float(weight_bytes),
-            attn_flops_per_pair=(
-                4.0 * mc.num_heads * mc.head_dim_ if getattr(mc, "model_type", "") in ("smallthinker", "afmoe") else 0.0
-            ),
+            # The engine counts pairs for a family with a window pool (SEAM, `window_pool_tokens`).
+            attn_flops_per_pair=4.0 * mc.num_heads * mc.head_dim_ if family(mc).window_pool_tokens(mc) else 0.0,
         )
 
     def step_floor_seconds(self, hbm_gbps: float) -> float:

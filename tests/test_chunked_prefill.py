@@ -208,7 +208,7 @@ def served(mc, params, prompt, n, monkeypatch=None):
     finally:
         eng.stop()
     cap = eng._wpages.cap if eng._wpages is not None else None
-    state = {k: np.asarray(v) for k, v in eng._cache.items() if k in core.SLOT_STATE_KEYS}
+    state = {k: np.asarray(eng._cache[k]) for k in family(mc).SLOT_STATE}
     return out, calls, (held[0], cap), state
 
 
@@ -234,7 +234,7 @@ def test_a_wide_call_gives_what_the_calls_of_the_largest_bucket_gave(name, monke
         window = family(mc).window_pool_tokens(mc)
         assert cap == (window + 64) // PAGE + 1 and cap_n == (window + 32) // PAGE + 1
         assert window // PAGE < held <= cap and held_n <= cap_n
-    assert sorted(state) == sorted(state_n) == (sorted(core.SLOT_STATE_KEYS) if name == "state_space" else [])
+    assert sorted(state) == sorted(state_n) == (["conv", "ssm"] if name == "state_space" else [])
     for key in state:  # slot 0's recurrent state and convolution tail after 150 + 6 tokens
         np.testing.assert_allclose(state[key][:, 0], state_n[key][:, 0], atol=2e-5)
 

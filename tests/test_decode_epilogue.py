@@ -163,7 +163,11 @@ def test_a_guest_joins_and_leaves_and_every_stream_is_its_own(engines, monkeypat
     log = _GateLog(eng.m_epilogue)
     monkeypatch.setattr(eng, "m_epilogue", log)
 
-    host_reqs = [eng.submit(list(p), sp) for p, sp in hosts]
+    # Both hosts are queued within ONE turn of the scheduler (the thunk runs on
+    # its thread), so one round admits them and they end in one chunk: admitted
+    # a round apart, the host that holds a steady gate open would end a chunk
+    # before the other and the gate's log would end in a `0`.
+    host_reqs = eng._await_aux(eng._submit_aux(lambda: [eng.submit(list(p), sp) for p, sp in hosts]), what="the hosts")
     heads = [_drain(r, first=4) for r in host_reqs]  # the hosts are decoding
     in_company = []
     for p, sp in guests:
