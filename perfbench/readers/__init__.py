@@ -15,6 +15,23 @@ In a run that traced itself (`--trace 2`) `before` / `after` / `polls` /
 `records` / `window_s` are the MEASURED window's (untraced), `trace` and
 `trace_t0` / `trace_t1` the tail's, whose requests are in `all_records`
 only; `trace_path` and `window_event_rx` are what the engine's reply named;
-a reader listed under `tail_view` in perfbench/trace_in_run.json gets the
-tail's polls in `before` / `polls` / `after` (run.py's TailView).
+a METRIC listed under `tail_view` in perfbench/trace_in_run.json gets the
+tail's polls in `before` / `polls` / `after` (run.py's TailView): list there
+every metric that divides counters by times of the trace, so that both are
+of the same seconds (PR 46), and none that is counters over the window alone
+(`window_mfu.*`).
+
+How a cell gets its metrics. An entry of `per_layer` in BENCHMARK.json is
+ONE measurement: a file under layer_metrics/ (reader + parameters), what it
+moves, and the cells that report it. A PR that adds a cell may not edit an
+accepted entry, so it adds an entry of its own (a twin: the accepted file
+under a new suffix) ONLY for a measurement whose accepted entry it cannot
+edit, and says in CHANGES.md which accepted entry each twin copies; the next
+`benchmark` PR folds the twins into their entry's `workloads` list.
+`per_layer` may hold 128 entries and held 128 when this was written, 56 of
+them twins (PERF.md sections 3 and 7: the groups, and what stops the fold).
+A new reading that needs a family's own counts should take the counts
+module from the configuration's `model_type` inside ONE reader, not come as
+a reader a family (`moe_` / `swa_` / `ssm_` / `afm_rooflines.py` are four
+copies of one reading).
 """

@@ -6,8 +6,12 @@ work whatever implements it. The keys and values a decode step reads
 INSIDE each layer's mask come from the program's own counter,
 kubeai_engine_attn_pairs_total{kind, phase="decode"} (a pair is one key
 of one layer seen by one query: 2 x kv heads x head_dim values), over the
-decode steps of the same window: a ratio of two counters, so it does not
-hang on where the traced seconds fall.
+decode steps between the same two scrapes. In a run that traced itself the
+three decode shares below are listed under `tail_view` in
+perfbench/trace_in_run.json (PR 46), so the scrapes are the TAIL's and the
+bytes a step are of the seconds whose time a step divides them: the
+measured window's mean over the tail's time read 104.9% once on a mix of
+caches from 256 to 24k tokens (ledger, PR 43).
 
     experts       bytes of the experts HIT a decode step (counters
                   kubeai_engine_moe_experts_hit_total / ..._possible_total)
@@ -41,6 +45,7 @@ PAIRS = "kubeai_engine_attn_pairs_total"
 HIT = "kubeai_engine_moe_experts_hit_total"
 POSSIBLE = "kubeai_engine_moe_expert_reads_possible_total"
 CHUNKS = "kubeai_engine_step_seconds_count"
+ROWS = "kubeai_engine_decode_rows_total"
 
 
 def _delta(ctx, series, **labels):
@@ -57,12 +62,28 @@ def _around_trace(ctx):
     return lo[-1], hi[0]
 
 
+def _decode_steps(ctx):
+    """Decode steps DISPATCHED between the two scrapes. The pairs are
+    counted where a chunk is dispatched, and so are the rows of its batch
+    (kubeai_engine_decode_rows_total, live and idle: slots x steps a
+    chunk). The chunks' own count (kubeai_engine_step_seconds_count) is
+    taken where a chunk ENDS, one chunk later: over a tail of 20-40 chunks
+    it is off by one against the pairs (window layers read 102% of what
+    24 slots can hold: my chip run, PR 46), so it only stands in for a
+    program from before the rows were counted (PR 39)."""
+    rows = _delta(ctx, ROWS)
+    if rows:
+        args = ctx.serving["engine_args"]
+        return rows / int(args[args.index("--max-slots") + 1])
+    chunks = _delta(ctx, CHUNKS, phase="decode_chunk")
+    return chunks * ctx.serving["decode_chunk"] if chunks else None
+
+
 def _kv_bytes_per_step(ctx, counts):
     """{kind: bytes of keys and values inside the masks a decode step}."""
-    chunks = _delta(ctx, CHUNKS, phase="decode_chunk")
-    if not chunks or not ctx.after.has(PAIRS):
+    steps = _decode_steps(ctx)
+    if not steps or not ctx.after.has(PAIRS):
         return None
-    steps = chunks * ctx.serving["decode_chunk"]
     one = counts.kv_bytes_per_token_layer(ctx.hf, ctx.serving["kv_dtype_bytes"])
     return {kind: _delta(ctx, PAIRS, kind=kind, phase="decode") * one / steps for kind in ("full", "window")}
 

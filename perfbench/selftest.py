@@ -20,7 +20,14 @@
    kind; the operator's command and environment are those of `--trace 0`;
    a tail's plan has the window's plan as its prefix;
  * BENCHMARK.json: every name resolves to a file, every metric's `moves`
-   is reported where the metric is;
+   is reported where the metric is; every file under layer_metrics/ is
+   named by exactly one entry; every entry lists its cells; no cell
+   declares fewer per-layer metrics than it was accepted with; every name
+   under `tail_view` (trace_in_run.json) is an entry's;
+ * the window families' decode rooflines take bytes and time from the same
+   seconds: through run.py's own `read_layer_metric`, a context whose
+   measured window held caches half as long as its tail's reads the
+   tail's, `window_mfu.*` the window's;
  * families/: same seed, same bytes (the shards of both dense
    configurations against the digests the harness wrote before the plan
    moved into families/, testdata/checkpoint-digests.json); every
@@ -47,6 +54,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import children  # noqa: E402
+import engine_io  # noqa: E402
 import idle_attribution as ia  # noqa: E402
 import loadgen  # noqa: E402
 import resultline  # noqa: E402
@@ -239,6 +247,68 @@ def in_run(tmp: str) -> None:
     check("until told to stop", held.t_close <= held.t_end < float("inf") or held.t_end <= held.t_close)
 
 
+def _counters(at: float, chunks: int, keys: int, tokens: int, ended: int | None = None) -> engine_io.Scrape:
+    """The engine's /metrics after *chunks* decode chunks of 8 steps were
+    dispatched (*ended* of them have ended) whose 24 queries each saw
+    *keys* keys in a full layer and 2048 in a window layer (one layer of
+    each kind counted), half of the experts hit."""
+    steps = 8 * chunks
+    return engine_io.Scrape("\n".join([
+        f'kubeai_engine_decode_rows_total{{state="live"}} {steps * 20}', f'kubeai_engine_decode_rows_total{{state="idle"}} {steps * 4}',
+        f'kubeai_engine_attn_pairs_total{{kind="full",phase="decode"}} {steps * 24 * keys}',
+        f'kubeai_engine_attn_pairs_total{{kind="window",phase="decode"}} {steps * 24 * 2048}',
+        'kubeai_engine_attn_pairs_total{kind="full",phase="prefill"} 0',
+        'kubeai_engine_attn_pairs_total{kind="window",phase="prefill"} 0',
+        f'kubeai_engine_moe_experts_hit_total{{phase="decode"}} {steps * 32}',
+        f'kubeai_engine_moe_expert_reads_possible_total{{phase="decode"}} {steps * 64}',
+        f'kubeai_engine_step_seconds_count{{phase="decode_chunk"}} {chunks if ended is None else ended}',
+        f"kubeai_engine_prefill_tokens_total {tokens}", f"kubeai_engine_generated_tokens_total {steps * 24}",
+    ]), at)
+
+
+def same_seconds() -> None:
+    """A share of a roofline divides bytes by time: both of the traced
+    seconds. The measured window [0, 50) decoded over caches of 4000 keys,
+    the tail [60, 65) over 8000, and the trace is the tail's: through
+    run.py's own `read_layer_metric`, every decode roofline of the two
+    window families reads the tail's keys (104.9% was the window's mean
+    over the tail's time: ledger, PR 43), `window_mfu.*` the window's."""
+    import argparse
+
+    bench = resultline.load_benchmark()
+    by = lambda **sec: {"total_s": 1.6, "by_scope_s": {k.replace("_", "."): v for k, v in sec.items()}}  # noqa: E731
+    for cell, suffix in (("smallthinker-bf16-longdoc-sat", ""), ("trinitymini-bf16-mixedlen-sat", ".afm")):
+        if cell not in [w["name"] for w in bench["workloads"]]:
+            continue
+        r = run.Run(argparse.Namespace(workload=cell, seed=7, rehearse=False, trace=2, seconds=50, keep=False))
+        ctx = run.Context()
+        ctx.rehearsal, ctx.hf, ctx.serving, ctx.peaks = False, r.hf, r.serving, {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+        ctx.before, ctx.polls, ctx.after, ctx.window_s = _counters(0.0, 0, 0, 0), [], _counters(50.0, 400, 4000, 10**6), 50.0
+        ctx.trace_t0, ctx.trace_t1 = 60.5, 64.5
+        ctx.trace = {"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0)}}
+        ctx.swa_scope_shares = {
+            "layers": {"jit__unknown(7)": by(attn_full=0.20, attn_window=0.40, moe_experts=0.64)},
+            "kernels": {"jit__unknown(7)": by(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64)},
+        }
+        ctx.tail_view = run.TailView(
+            ctx, before=_counters(60.0, 400, 4000, 10**6), polls=[], after=_counters(65.0, 440, 4364, 10**6 + 10**5, ended=439), window_s=5.0,
+        )  # 440 x 4364 - 400 x 4000 = 40 x 8004: the tail's 40 chunks saw twice the keys; the last has not ENDED
+
+        # A run traced inside its window (`--trace 1`) has no tail: it reads the context it is given.
+        direct = run.Run(argparse.Namespace(workload=cell, seed=7, rehearse=False, trace=1, seconds=50, keep=False)).read_layer_metric
+        full, window = "full_attn_decode_roofline" + suffix, "window_attn_decode_roofline" + suffix
+        step = "decode_step_roofline" + (suffix or ".swa")
+        got = {n: r.read_layer_metric(n, ctx) for n in (full, window, step)}
+        check(f"{cell}: the decode rooflines read something", all(v is not None and v > 0 for v in got.values()), got)
+        check(f"{cell}: {full} reads the keys of the traced seconds, twice the measured window's",
+              abs(got[full] / direct(full, ctx) - 8004 / 4000) < 1e-9, (got[full], direct(full, ctx)))
+        # Pairs are counted where a chunk is dispatched: so are the steps they are divided by (40, not the 39 that ended).
+        check(f"{cell}: {window} reads the same 2048 either way", abs(got[window] - direct(window, ctx)) < 1e-9 * got[window])
+        check(f"{cell}: {step} counts the tail's keys too", got[step] == direct(step, ctx.tail_view) > direct(step, ctx))
+        mfu = next(n for n in resultline.declared(bench, cell, 1) if n.startswith("window_mfu"))
+        check(f"{cell}: {mfu} keeps the measured window", r.read_layer_metric(mfu, ctx) == direct(mfu, ctx) != direct(mfu, ctx.tail_view))
+
+
 def recorded() -> None:
     path = os.path.join(HERE, "testdata", "cpu-small.xplane.pb")
     if not os.path.exists(path):
@@ -325,6 +395,13 @@ def last_line() -> None:
     check("render refuses NaN", raises(lambda: resultline.render({"x": float("nan")}), ValueError))
 
 
+ACCEPTED_PER_LAYER = {
+    "qwen7b-int8-chat-sat": 19, "mistral7b-int8-docqa": 23, "qwen7b-int8-chat-rate": 17, "kanana2-bf16-reason-sat": 20,
+    "smallthinker-bf16-longdoc-sat": 25, "nemotron3super-bf16-agent-sat": 23, "trinitymini-bf16-mixedlen-sat": 23,
+    "mistral7b-int8-chat-sat": 3,
+}
+
+
 def benchmark_file() -> None:
     bench = resultline.load_benchmark()
     root = resultline.ROOT
@@ -342,6 +419,23 @@ def benchmark_file() -> None:
         where = m.get("workloads", cells)
         check(f"{m['name']} moves {m['moves']} where it is reported",
               all(c in moved.get("workloads", cells) for c in where))
+        # No metric without a list: a later cell takes none by default.
+        check(f"{m['name']} lists its cells", bool(m.get("workloads")) and set(m["workloads"]) <= set(cells))
+    names = [m["name"] for m in bench["per_layer"]]
+    files = sorted(n[: -len(".json")] for n in os.listdir(os.path.join(HERE, "layer_metrics")) if n.endswith(".json"))
+    check("one file an entry and one entry a file", files == sorted(names) and len(set(names)) == len(names),
+          sorted(set(files) ^ set(names)))
+    # What each accepted cell printed when this list was written (ledger, PR 45): merging
+    # entries that are one measurement (PERF.md section 7) may rename a metric, never drop one.
+    for cell, floor in ACCEPTED_PER_LAYER.items():
+        if cell in cells:
+            got = len(resultline.declared(bench, cell, 1))
+            check(f"{cell}: at least the {floor} per-layer metrics it was accepted with", got >= floor, got)
+    with open(os.path.join(HERE, "trace_in_run.json")) as f:
+        tail_view = json.load(f)["tail_view"]
+    # A name that no entry has would be read over the measured window's polls again, in silence.
+    check("every metric under tail_view is declared", set(tail_view) <= set(names), sorted(set(tail_view) - set(names)))
+    check("the whole step's share of the peak keeps the measured window", not [n for n in tail_view if "mfu" in n])
 
 
 def sha256(path: str) -> str:
@@ -518,6 +612,7 @@ if __name__ == "__main__":
     generator()
     last_line()
     benchmark_file()
+    same_seconds()
     with tempfile.TemporaryDirectory() as tmp:
         in_run(tmp)
         families(tmp)
