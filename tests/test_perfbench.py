@@ -1,35 +1,45 @@
-"""The benchmark's own machinery (perfbench/), on the CPU: the metrics this
-PR declares and where the result line carries them, the plans the one
-traffic generator builds, operations filed under their named scope, the
-reader that finds a run's trace, and a whole `--rehearse --trace 1` run.
-perfbench/selftest.py holds the yardstick's own checks; it runs here too."""
+"""The benchmark's own machinery (perfbench/), on the CPU. What
+BENCHMARK.json and the files beside it must keep is held as properties, over
+every cell, configuration and traffic file the benchmark has when the tests
+are collected: a cell is covered by being in `workloads`, and a measurement
+is found by what its file says (its reader and parameters), never by its
+name, its place in `per_layer` or the length of that list, so a `benchmark`
+PR may fold, rename, retire or add entries, and a `model_config` PR may
+bring a cell, without an edit here (its family's toy configuration is
+tests/test_named_scopes.py's to keep). Besides: the plans the one traffic
+generator builds, operations filed under their named scope, the reader that
+finds a run's trace, each family's roofline arithmetic, and whole
+`--rehearse` runs. perfbench/selftest.py holds the list's own invariants
+(one file an entry, every entry lists its cells, `moves` is reported where
+the metric is, no cell under the count it was accepted with); it runs here."""
 
+import functools
 import json
 import os
+import re
 import subprocess
 import sys
 import types
 
 import pytest
 
+from kubeai_tpu.models import MODULES, ModelConfig
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "perfbench"))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import resultline  # noqa: E402
 import scope_reduce  # noqa: E402
+import test_named_scopes as toys  # noqa: E402  (one toy configuration a family, lowered with its scopes)
 import traffic  # noqa: E402
 from readers import scope_share  # noqa: E402
 
-NEW = {
-    "prefill_padding_pct": {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa"},
-    "host_work_per_chunk_ms": {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa"},
-    "host_work_per_chunk_ms.rate": {"qwen7b-int8-chat-rate"},
-    "decode_sampling_share_pct": {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa"},
-    "decode_attn_share_pct": {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa"},
-    "decode_ffn_share_pct": {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa"},
-    "decode_epilogue_ran_pct": {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa"},
-    "decode_epilogue_ran_pct.rate": {"qwen7b-int8-chat-rate"},
-}
+BENCH = resultline.load_benchmark()
+CELLS = {w["name"]: w for w in BENCH["workloads"]}
+DECODE = "^jit__unknown"  # the decode program, as a metric file names it (ROADMAP B-III: its name)
+HOST_WORK = {"sweep", "admit", "prefill", "kv_transfer", "dispatch", "host_overlap", "emit", "other"}
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
 def test_selftest_passes():
@@ -38,83 +48,242 @@ def test_selftest_passes():
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, timeout=300,
     )
     out = proc.stdout.decode()
-    assert proc.returncode == 0 and out.strip().endswith("all passed"), out[-3000:]
+    failed = [ln for ln in out.splitlines() if ln.startswith("FAIL ")]  # the checks that failed, by name
+    assert proc.returncode == 0 and out.strip().endswith("all passed"), failed or out[-3000:]
 
 
-# The cells PR 24/25's eight metrics name; a cell of a later PR (another
-# family's) carries none of them and has its own case below.
-NEW_CELLS = sorted(set().union(*NEW.values()))
-# PR 33's cell and what it declares: every per-layer metric that lists it.
-MOE_CELL = "kanana2-bf16-reason-sat"
-MOE_METRICS = {
-    "decode_step_ms.moe", "device_idle_pct.moe", "prefill_share_pct.moe", "batch_occupancy_pct.moe",
-    "kv_pages_peak_pct.moe", "host_work_per_chunk_ms.moe", "prefill_padding_pct.moe", "decode_mla_share_pct",
-    "decode_moe_share_pct", "decode_sampling_share_pct.moe", "moe_experts_hit_pct", "scale_from_zero_s.moe",
-    "engine_load_s.moe", "engine_warmup_s.moe", "moe_experts_roofline", "mla_decode_roofline",
-    "decode_step_roofline.moe", "window_mfu.moe",
+# What a measurement IS: its file's reader, and what the file tells the reader to read.
+IS = {
+    "idle_gaps": lambda m: m["reader"] == "idle_by_host",
+    "decode_by_scope": lambda m: m["reader"] == "scope_share" and m["params"]["module"] == DECODE,
+    "of_prefill_programs": lambda m: m["params"].get("module", "").startswith("^jit_prefill"),
+    "experts_hit": lambda m: m["reader"] == "delta_ratio" and m["params"]["num"][0]["series"] == "kubeai_engine_moe_experts_hit_total",
+    "window_pool_peak": lambda m: m["reader"] == "gauge_peak" and m["params"]["gauge"] == "kubeai_engine_kv_window_pages_used",
+    "timed_by_a_familys_reader": lambda m: m["reader"].endswith("_rooflines") and "module" in m["params"],
+    "share_of_a_peak": lambda m: "roofline" in m["reader"],
 }
 
 
-def test_benchmark_declares_the_new_metrics():
-    bench = resultline.load_benchmark()
-    assert bench["trace_in_run"] is True  # since PR 38 a run measures and then traces itself (--trace 2)
-    names = [m["name"] for m in bench["per_layer"]]
-    at = [names.index(n) for n in NEW]  # each declared, wherever later PRs' entries stand
-    assert at == sorted(at) and at == list(range(at[0], at[0] + len(NEW)))  # together, in the issue's order
-    for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert set(m["workloads"]) == NEW[m["name"]]
-    for name in NEW:
-        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
-        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py"))
+def _per_layer(cell, what=None):
+    """name -> entry of the per-layer metrics *cell* declares, in the names'
+    order, each entry with its FILE's `reader` and `params` beside
+    BENCHMARK.json's keys; only those that are *what* (a key of IS), if given."""
+    names, found = resultline.declared(BENCH, cell, 1), {}
+    for m in BENCH["per_layer"]:
+        if m["name"] in names:
+            with open(os.path.join(ROOT, "perfbench", "layer_metrics", m["name"] + ".json")) as f:
+                found[m["name"]] = {**m, **json.load(f)}
+    return {n: found[n] for n in sorted(found) if what is None or IS[what](found[n])}
 
 
-# PR 38's four metrics: one reader (idle_by_host), by the cell's own end-to-end metric.
-SAT_CELLS = {"qwen7b-int8-chat-sat", "mistral7b-int8-docqa", "kanana2-bf16-reason-sat", "smallthinker-bf16-longdoc-sat"}
-IDLE = {
-    "idle_exposed_host_pct": SAT_CELLS, "idle_in_fetch_pct": SAT_CELLS,
-    "idle_exposed_host_pct.rate": {"qwen7b-int8-chat-rate"}, "idle_in_fetch_pct.rate": {"qwen7b-int8-chat-rate"},
-}
-HOST_WORK = {"sweep", "admit", "prefill", "kv_transfer", "dispatch", "host_overlap", "emit", "other"}
+def _cells_that_declare(what):
+    """For a parametrize list. A cell whose files cannot be read is kept:
+    its own case then fails and says which, not the module's collection."""
+    def declares(cell):
+        try:
+            return bool(_per_layer(cell, what))
+        except (OSError, KeyError, ValueError):
+            return True
+    return [cell for cell in CELLS if declares(cell)]
 
 
-@pytest.mark.parametrize("name", sorted(IDLE))
-def test_the_idle_gaps_metrics_are_declared_last_and_read_by_one_reader(name):
-    bench = resultline.load_benchmark()
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    names = [m["name"] for m in bench["per_layer"]]
-    at = names.index(next(iter(IDLE)))  # appended together, in the issue's order, wherever later PRs' entries stand
-    assert names[at : at + 4] == list(IDLE)
-    assert set(entry["workloads"]) == IDLE[name] and entry["layer"] == "scheduler" and entry["source"] == "device_trace"
-    assert entry["moves"] == ("tpot_mean_ms" if name.endswith(".rate") else "output_tok_s") and entry["better"] == "lower"
-    with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
-        spec = json.load(f)
-    assert spec["reader"] == "idle_by_host"
-    # Host work that did not hide, or the wait in the fetch: `idle` (no
-    # request) is in neither, and the three add up to the idle share.
-    causes = set(spec["params"]["causes"])
-    assert causes == ({"fetch_wait"} if "in_fetch" in name else HOST_WORK)
+def _timed_rooflines(cell, but=()):
+    """The cell's shares of a peak for which a family's own reader divides by
+    a time taken from the trace (of the program or of one scope in it: the
+    file names a `module`), but the readings named *but*. Time a step means
+    nothing in a CPU trace, so a rehearsal leaves them out
+    (readers/moe_, swa_, ssm_, afm_rooflines.py)."""
+    return {n for n, m in _per_layer(cell, "timed_by_a_familys_reader").items() if m["params"]["what"] not in but}
 
 
-@pytest.mark.parametrize("cell", sorted(SAT_CELLS | {"qwen7b-int8-chat-rate"}))
-def test_a_line_of_a_run_that_traced_itself_carries_both_kinds(cell):
-    bench = resultline.load_benchmark()
-    both = resultline.declared(bench, cell, 2)
-    e2e, layer = resultline.declared(bench, cell, 0), resultline.declared(bench, cell, 1)
-    assert both == {**e2e, **layer} and len(both) == len(e2e) + len(layer)
-    assert {n for n, cells in IDLE.items() if cell in cells} <= set(layer)
-    line = _traced_line(both)
-    assert resultline.problems(line, bench, cell, 2, 1) == []
-    for kind in (e2e, layer):  # a line that lacks either kind is refused
-        cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k not in kind}}
-        assert resultline.problems(cut, bench, cell, 2, 1)
-    assert resultline.problems({**line, "device": {k: v for k, v in line["device"].items() if k != "busy_s"}}, bench, cell, 2, 1)
+def _config(name):
+    """BENCHMARK.json's entry of a configuration, and its file."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return entry, json.load(f)
+
+
+def _engine_arg(published, flag):
+    args = published["serving"]["engine_args"]
+    return int(args[args.index(flag) + 1])
+
+
+# -- what a cell declares, and what defines it -----------------------------------
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_what_a_cell_declares(cell):
+    e2e, layer, both = (resultline.declared(BENCH, cell, mode) for mode in (0, 1, 2))
+    assert "setup_s" in e2e and len(e2e) >= 2  # and the metric the cell is judged by
+    assert layer and both == {**e2e, **layer} and len(both) == len(e2e) + len(layer)
+    for name, m in _per_layer(cell).items():  # which opens every name's file
+        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", m["reader"] + ".py")), (name, m["reader"])
+
+
+@pytest.mark.parametrize("cell", _cells_that_declare("idle_gaps"))
+def test_a_cells_idle_gaps_are_read_by_one_reader_in_two_parts(cell):
+    """Host work that did not hide, or the wait in the fetch: `idle` (no
+    request) is in neither, and the three add up to the idle share. A cell
+    that reports one of the pair reports the other."""
+    e2e = resultline.declared(BENCH, cell, 0)
+    mine = _per_layer(cell, "idle_gaps").values()
+    for m in mine:
+        assert (m["layer"], m["source"], m["better"]) == ("scheduler", "device_trace", "lower"), m["name"]
+        assert m["moves"] in e2e and m["moves"] != "setup_s", m["name"]
+    assert sorted((set(m["params"]["causes"]) for m in mine), key=len) == [{"fetch_wait"}, HOST_WORK]
+
+
+def _counts_taken(reader):
+    """The families (`model_type`s: the files of perfbench/families) whose own counts a reader's source takes."""
+    with open(os.path.join(ROOT, "perfbench", "readers", reader + ".py")) as f:
+        return set(re.findall(r"""["']families\.(\w+)_counts["']""", f.read()))
+
+
+def _scopes_asked(m):
+    """The scopes an entry's file has its reader look under, where it reads by scope (`kind`: swa_scopes' `attn.<kind>`)."""
+    params = m["params"]
+    return params["scope"].split("|") if "scope" in params else ["attn." + params["kind"]] if "kind" in params else []
+
+
+@functools.cache
+def _toy_programs(model_type):
+    """[(program, its text with the operations' names)] of the toy
+    configuration tests/test_named_scopes.py keeps for the family that runs
+    *model_type* (kubeai_tpu/models: `MODULES`; every other type is the
+    dense family's, whose toy is that file's default), lowered, not compiled."""
+    mine = [v for v in vars(toys).values() if isinstance(v, ModelConfig) and v.model_type == model_type]
+    assert mine or model_type not in MODULES, f"tests/test_named_scopes.py keeps no toy configuration of {model_type}"
+    texts = [debug_text for _, debug_text in toys._lowered_programs(True, *mine[:1])]
+    return [(re.search(r"^module @(\S+)", text, flags=re.M).group(1), text) for text in texts]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_declares_only_what_its_familys_program_can_be_read_for(cell):
+    """As far as tier-1 can see it: a reader that takes one family's counts
+    is declared by that family's cells alone, and an entry read by scope
+    asks for a scope that names operations in a program of the cell's
+    family that its `module` matches. (That every declared metric IS read is
+    the rehearsals' to hold, and on the chip `output_malformed`.)"""
+    _, published = _config(CELLS[cell]["config"])
+    family = published["model_type"]
+    assert os.path.exists(os.path.join(ROOT, "perfbench", "families", family + ".py")), family
+    for name, m in _per_layer(cell).items():
+        taken = _counts_taken(m["reader"])
+        assert taken <= {family}, f"{name}: {m['reader']} takes the counts of {sorted(taken)}, {cell} serves a {family}"
+        scopes = _scopes_asked(m)
+        if scopes:
+            matched = [text for program, text in _toy_programs(family) if re.search(m["params"]["module"], program)]
+            named = [s for s in scopes if any(re.search(r'["/]' + re.escape(s) + "/", text) for text in matched)]
+            assert named, f"{name}: no program of a {family} that {m['params']['module']} matches names an operation under {scopes}"
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_a_configuration_is_its_source_cut_only_where_it_says(name):
+    entry, published = _config(name)
+    assert published["source"] == entry["source"]
+    assert set(entry["reduced"]) <= set(published["reduced"])  # each cut has its reason in the file
+    # The row's keys as published: every key of the catalog's config under the same key with the same value.
+    rows = []
+    if os.path.exists(CATALOG):
+        with open(CATALOG) as f:
+            rows = [r for r in map(json.loads, f) if r["source_url"] == published["source"]]
+    for row in rows:
+        departs = {k for k, v in row["config"].items() if k not in published or published[k] != v}
+        assert departs <= set(entry["reduced"]), (row["name"], sorted(departs))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cells_traffic_fits_its_configuration_and_saturates_it_where_it_says_so(cell):
+    """The numbers themselves (slots, clients, medians) are PERF.md section
+    4's and the driver's to hold (`benchmark_edited`, `weakened`): a
+    `benchmark` PR that steadies a cell changes them."""
+    w = CELLS[cell]
+    _, published = _config(w["config"])
+    assert w["chips"] == published["serving"]["chips"]
+    spec = traffic.load(w["traffic"])
+    longest = spec.get("max_total_tokens") or spec["prompt_tokens"]["max"] + spec["output_tokens"]["max"]
+    seq_len, slots = _engine_arg(published, "--max-seq-len"), _engine_arg(published, "--max-slots")
+    assert longest <= seq_len, f"{w['traffic']} sends {longest} tokens, {w['config']} serves --max-seq-len {seq_len}"
+    if spec["loop"] == "closed" and w["traffic"].endswith("-sat"):  # above the knee: a freed slot finds a request waiting
+        assert spec["clients"] >= slots, f"{w['traffic']} has {spec['clients']} clients for {w['config']}'s {slots} slots"
+
+
+# -- the last line of a traced run ---------------------------------------------
+
+
+def _traced_line(cell, mode=2):
+    """A well-formed last line of a run of *cell* in *mode*: every metric it declares there."""
+    return {
+        "correct": True, "attempted": 10, "failed": 0,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": CELLS[cell]["chips"], "memory_peak_bytes": 1.2e10,
+                   "window_s": 4.0, "busy_s": 3.9},
+        "metrics": {n: {"value": 1.5, "unit": u} for n, u in resultline.declared(BENCH, cell, mode).items()},
+    }
+
+
+def _problems(line, cell, mode=2, **kw):
+    return resultline.problems(line, BENCH, cell, mode, CELLS[cell]["chips"], **kw)
+
+
+def _without(line, names):
+    return {**line, "metrics": {k: v for k, v in line["metrics"].items() if k not in names}}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_traced_line_with_all_the_cell_declares_has_no_problems(cell):
+    assert _problems(_traced_line(cell, 1), cell, 1) == [] and _problems(_traced_line(cell), cell) == []
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_line_of_a_run_that_traced_itself_is_refused_without_either_kind(cell):
+    line = _traced_line(cell)
+    for mode in (0, 1):
+        assert _problems(_without(line, resultline.declared(BENCH, cell, mode)), cell)
+    assert _problems({**line, "device": {k: v for k, v in line["device"].items() if k != "busy_s"}}, cell)
+
+
+@pytest.mark.parametrize("at", ["first", "median", "last"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_traced_line_that_lacks_one_metric(cell, at):
+    names = list(_per_layer(cell))
+    missing = names[{"first": 0, "median": len(names) // 2, "last": -1}[at]]
+    cut = _without(_traced_line(cell), {missing})
+    assert _problems(cut, cell) == [f"metric {missing} of this workload and mode is missing"]
+    # A reader that finds nothing (the parent's program) leaves its metric out.
+    assert _problems(cut, cell, may_miss={missing}) == []
+
+
+@pytest.mark.parametrize("cell", _cells_that_declare("share_of_a_peak"))
+def test_a_share_of_a_peak_over_105_is_refused(cell):
+    """Which of a cell's shares of a peak the harness holds to it is the
+    harness's own rule (PERF.md section 7: by the name's ending, so a
+    suffixed one escapes): each in turn at 106, and at least one is refused."""
+    line = _traced_line(cell)
+    over = lambda name: {**line, "metrics": {**line["metrics"], name: {**line["metrics"][name], "value": 106.0}}}  # noqa: E731
+    refused = [n for n in _per_layer(cell, "share_of_a_peak") if any("over 105%" in p for p in _problems(over(n), cell))]
+    assert refused
+
+
+@pytest.mark.parametrize("name", sorted({w["traffic"] for w in BENCH["workloads"]}))
+@pytest.mark.parametrize("seed", [3, 2**31 + 17])
+def test_a_longer_plan_has_the_shorter_one_as_its_prefix(name, seed):
+    spec = traffic.load(name)
+    key = lambda r: (r.prompt, r.max_tokens, r.tag, r.due_s)  # noqa: E731
+    short = traffic.build(spec, seed, 50)
+    longer = traffic.build(spec, seed, 50 + spec["ramp_s"] + 5)
+    assert [key(r) for r in longer.shared[: len(short.shared)]] == [key(r) for r in short.shared]
+    assert len(longer.per_client) == len(short.per_client)
+    for sc, lc in zip(short.per_client, longer.per_client):
+        assert [key(r) for r in lc[: len(sc)]] == [key(r) for r in sc]
+    if short.loop == "open":  # and the open loop has requests left for the tail
+        assert longer.shared[-1].due_s >= short.shared[-1].due_s + spec["ramp_s"] + 4
+
+
+# -- the readers of a trace -----------------------------------------------------
 
 
 def _tail_ctx(table, window_s=4.0):
-    ctx = types.SimpleNamespace(trace={"window_s": window_s, "busy_s": 3.0}, idle_by_host=table, rehearsal=True)
-    return ctx
+    return types.SimpleNamespace(trace={"window_s": window_s, "busy_s": 3.0}, idle_by_host=table, rehearsal=True)
 
 
 def test_the_idle_reader_splits_the_idle_share_of_the_same_trace():
@@ -154,79 +323,6 @@ def test_the_idle_reader_reads_the_recorded_trace(capsys):
     assert line["window_s"] == pytest.approx(want["window_s"], abs=1e-9)
     assert line["idle_s"] == pytest.approx(want["window_s"] - want["busy_s"], abs=1e-9)
     assert line["idle_by_cause_s"] == {"other": pytest.approx(line["idle_s"])}
-
-
-def _traced_line(traced):
-    return {
-        "correct": True, "attempted": 10, "failed": 0,
-        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1.2e10, "window_s": 4.0, "busy_s": 3.9},
-        "metrics": {n: {"value": 1.5, "unit": u} for n, u in traced.items()},
-    }
-
-
-@pytest.mark.parametrize("cell", NEW_CELLS)
-def test_a_traced_line_carries_the_new_metrics_in_their_cells(cell):
-    bench = resultline.load_benchmark()
-    assert cell in [w["name"] for w in bench["workloads"]]
-    traced, untraced = resultline.declared(bench, cell, True), resultline.declared(bench, cell, False)
-    mine = {n for n, cells in NEW.items() if cell in cells}
-    assert mine and mine <= set(traced) and not set(NEW) & set(untraced)
-    assert not (set(NEW) - mine) & set(traced)
-    line = _traced_line(traced)
-    assert resultline.problems(line, bench, cell, True, 1) == []
-    # A reader that finds nothing (the parent's program) leaves its metric out.
-    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k not in mine}}
-    assert resultline.problems(cut, bench, cell, True, 1, may_miss=mine) == []
-    assert resultline.problems(cut, bench, cell, True, 1)
-
-
-def test_every_cell_is_one_of_those_with_a_case_here():
-    cells = [w["name"] for w in resultline.load_benchmark()["workloads"]]
-    assert sorted(cells) == sorted(NEW_CELLS + [MOE_CELL, SWA_CELL, SSM_CELL, AFM_CELL, MCHAT_CELL])
-
-
-def test_the_expert_models_cell_declares_its_own_metrics_and_none_of_the_dense_cells():
-    bench = resultline.load_benchmark()
-    traced, untraced = resultline.declared(bench, MOE_CELL, True), resultline.declared(bench, MOE_CELL, False)
-    # Its own, and since PR 38 the two idle-gap metrics every saturated cell reads.
-    assert set(traced) == MOE_METRICS | {"idle_exposed_host_pct", "idle_in_fetch_pct"} and not set(NEW) & set(traced)
-    assert set(untraced) == {"output_tok_s", "setup_s"}
-    for m in bench["per_layer"]:  # no metric without a list: a later cell takes none by default
-        assert "workloads" in m, m["name"]
-        if m["name"] in MOE_METRICS:
-            assert m["workloads"] == [MOE_CELL]
-            assert m["moves"] == ("setup_s" if m["name"].split(".")[0] in ("scale_from_zero_s", "engine_load_s", "engine_warmup_s") else "output_tok_s")
-    for name in MOE_METRICS:
-        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
-        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py"))
-
-
-@pytest.mark.parametrize("missing", sorted(n for n in MOE_METRICS if n.endswith(".moe")))
-def test_a_traced_line_of_the_expert_models_cell(missing):
-    bench = resultline.load_benchmark()
-    line = _traced_line(resultline.declared(bench, MOE_CELL, True))
-    assert resultline.problems(line, bench, MOE_CELL, True, 1) == []
-    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
-    assert resultline.problems(cut, bench, MOE_CELL, True, 1) == [f"metric {missing} of this workload and mode is missing"]
-    assert resultline.problems(cut, bench, MOE_CELL, True, 1, may_miss={missing}) == []
-    over = {**line, "metrics": {**line["metrics"], "window_mfu.moe": {"value": 106.0, "unit": "%"}}}
-    assert any("over 105%" in p for p in resultline.problems(over, bench, MOE_CELL, True, 1))
-
-
-@pytest.mark.parametrize("name", ["chat-sat", "chat-rate", "docqa", "reason-sat"])
-@pytest.mark.parametrize("seed", [3, 2**31 + 17])
-def test_a_longer_plan_has_the_shorter_one_as_its_prefix(name, seed):
-    spec = traffic.load(name)
-    key = lambda r: (r.prompt, r.max_tokens, r.tag, r.due_s)  # noqa: E731
-    short = traffic.build(spec, seed, 50)
-    longer = traffic.build(spec, seed, 50 + spec["ramp_s"] + 5)
-    assert [key(r) for r in longer.shared[: len(short.shared)]] == [key(r) for r in short.shared]
-    assert len(longer.per_client) == len(short.per_client)
-    for sc, lc in zip(short.per_client, longer.per_client):
-        assert [key(r) for r in lc[: len(sc)]] == [key(r) for r in sc]
-    if short.loop == "open":  # and the open loop has requests left for the tail
-        assert longer.shared[-1].due_s >= short.shared[-1].due_s + spec["ramp_s"] + 4
 
 
 HLO = """HloModule jit__unknown, is_scheduled=true
@@ -330,144 +426,7 @@ def test_the_scope_reader_reads_nothing_where_there_is_nothing(tmp_path, monkeyp
     assert scope_share.read(ctx, "^jit_prefill", "attn") is None
 
 
-def test_rehearsal_of_a_run_that_traces_itself(tmp_path):
-    """--rehearse --trace 2, every phase, on the CPU at a tiny size: one
-    last line with both kinds of metric; the end-to-end values are what
-    `Run.end_to_end` gave over the window's records; the traced interval is
-    the `profile.window` event with the Python tracer off; each idle gap
-    names its own causes, and the idle shares add up."""
-    cell = "qwen7b-int8-chat-rate"
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", cell, "--rehearse",
-         "--trace", "2", "--seed", str(2**31 + 5)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600,
-    )
-    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
-    phases = [ln.get("phase") for ln in lines[:-1]]
-    for phase in ("checkpoint", "scale_from_zero", "window_open", "stop", "window", "trace", "logits", "end_to_end"):
-        assert phase in phases, phases
-    assert phases.index("stop") < phases.index("trace")  # read after the operator has gone, beside the logits child
-    last, trace, window = lines[-1], lines[phases.index("trace")], lines[phases.index("window")]
-    bench = resultline.load_benchmark()
-    layer = resultline.declared(bench, cell, 1)
-    assert resultline.problems(last, bench, cell, 2, 1, rehearsal=True, may_miss=set(layer)) == []
-    assert last["correct"] is True and last["device"]["platform"] == "cpu"
-    # Both kinds, the end-to-end ones from the one function that computes them.
-    e2e = lines[phases.index("end_to_end")]
-    for name in resultline.declared(bench, cell, 0):
-        assert last["metrics"][name]["value"] == e2e[name]
-    assert {"idle_exposed_host_pct.rate", "idle_in_fetch_pct.rate", "host_work_per_chunk_ms.rate"} <= set(last["metrics"])
-    # The interval is the capture's own event, with the Python tracer off,
-    # and the capture began only after the window's last record had closed.
-    assert trace["window_from"] == "host event 'profile.window'" and trace["python_tracer"] is False
-    assert 0 <= trace["last_record_closed_s"] <= trace["capture_began_s"]
-    assert last["device"]["window_s"] == pytest.approx(4.0, abs=0.1)
-    # Every idle piece under the segment beside it: the shares add up to the idle share.
-    table = trace["idle_by_host"]
-    assert table["n_segments"] > 0 and sum(table["idle_by_cause_s"].values()) == pytest.approx(table["idle_s"])
-    idle_pct = 100.0 * (1 - last["device"]["busy_s"] / last["device"]["window_s"])
-    under_idle = 100.0 * table["idle_by_cause_s"].get("idle", 0.0) / table["window_s"]
-    split = last["metrics"]["idle_exposed_host_pct.rate"]["value"] + last["metrics"]["idle_in_fetch_pct.rate"]["value"]
-    assert split + under_idle == pytest.approx(idle_pct, abs=0.1)
-    gaps = last["breakdown"]["idle_gaps"]
-    assert gaps and all("host: " in g[0] and "dominant stall cause" not in g[0] and len(g[0]) <= 200 for g in gaps)
-    # The window's line keeps where the longest silence lay and the engine's slowest steps inside it.
-    assert 0 <= window["longest_silence_at_s"] < 8 and all(-0.5 <= s["at_s"] < 9.5 for s in window["slowest_steps"])
-    assert window["slowest_steps"] and {"kind", "total_ms", "ms"} <= set(window["slowest_steps"][0])
-
-
-def test_rehearsal_of_a_traced_run(tmp_path):
-    """--rehearse --trace 1, every phase, on the CPU at a tiny size: the
-    accepted harness reads this PR's metrics from this PR's program."""
-    cell = "qwen7b-int8-chat-sat"
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
-    env.pop("XLA_FLAGS", None)  # conftest's eight virtual devices: the cell asks for one
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", cell, "--rehearse",
-         "--trace", "1", "--seed", str(2**31 + 5)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600,
-    )
-    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
-    phases = [ln.get("phase") for ln in lines[:-1]]
-    for phase in ("checkpoint", "scale_from_zero", "window_open", "stop", "window", "logits", "trace", "scopes"):
-        assert phase in phases, phases
-    scopes = lines[phases.index("scopes")]
-    assert scopes["error"] is None and any(p.startswith("jit__unknown") for p in scopes["programs"])
-    last = lines[-1]
-    bench = resultline.load_benchmark()
-    assert resultline.problems(
-        last, bench, cell, True, 1, rehearsal=True, may_miss=set(resultline.declared(bench, cell, True)),
-    ) == []
-    assert last["correct"] is True and last["device"]["platform"] == "cpu"
-    mine = {n for n, cells in NEW.items() if cell in cells}
-    assert mine <= set(last["metrics"]), sorted(last["metrics"])
-    assert 0 <= last["metrics"]["prefill_padding_pct"]["value"] < 100
-    shares = [last["metrics"][n]["value"] for n in mine if n.endswith("_share_pct")]
-    assert all(0 <= v <= 100 for v in shares) and sum(shares) <= 100.0
-
-
-@pytest.mark.slow  # about a minute alone, more beside five other workers: not tier-1 (CHANGES.md, PR 33)
-def test_rehearsal_of_the_expert_models_cell(tmp_path):
-    """--rehearse --trace 1 of kanana2-bf16-reason-sat at the tests' small
-    size: every phase, the family's two-part logits check, and every
-    per-layer metric the CPU can read."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", MOE_CELL, "--rehearse",
-         "--trace", "1", "--seed", str(2**31 + 7)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600,
-    )
-    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
-    last = lines[-1]
-    bench = resultline.load_benchmark()
-    # Time a step of one scope means nothing in a CPU trace (readers/moe_rooflines.py).
-    may_miss = {"moe_experts_roofline", "mla_decode_roofline"}
-    assert resultline.problems(last, bench, MOE_CELL, True, 1, rehearsal=True, may_miss=may_miss) == []
-    assert last["correct"] is True and MOE_METRICS - may_miss <= set(last["metrics"])
-    logits = next(ln for ln in lines if ln.get("phase") == "logits")
-    assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
-    assert 0 < last["metrics"]["moe_experts_hit_pct"]["value"] <= 100
-
-
-# -- PR 36: the window family's cell and its readers ---------------------------
-
-SWA_CELL = "smallthinker-bf16-longdoc-sat"
-SWA_ROOFLINES = {
-    "moe_experts_roofline.swa", "full_attn_decode_roofline", "window_attn_decode_roofline", "prefill_attn_roofline.swa",
-}
-
-
-def test_the_window_cells_metrics_are_its_own():
-    bench = resultline.load_benchmark()
-    mine = resultline.declared(bench, SWA_CELL, True)
-    shared = {"idle_exposed_host_pct", "idle_in_fetch_pct"}  # PR 38: one reader in every saturated cell
-    assert len(mine) == 23 + len(shared) and SWA_ROOFLINES | shared <= set(mine)
-    assert {"kv_pages_peak_pct.full", "kv_pages_peak_pct.window", "window_mfu.swa", "decode_step_roofline.swa"} <= set(mine)
-    # No other cell carries them, and this cell none of theirs.
-    for m in bench["per_layer"]:
-        if m["name"] not in shared:
-            assert (SWA_CELL in m["workloads"]) == (m["workloads"] == [SWA_CELL]), m["name"]
-    assert set(resultline.declared(bench, SWA_CELL, False)) == {"output_tok_s", "setup_s"}
-    for name in mine:
-        assert os.path.exists(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")), name
-
-
-@pytest.mark.parametrize("missing", ["decode_step_ms.swa", "kv_pages_peak_pct.window", "window_attn_decode_roofline", "window_mfu.swa"])
-def test_a_traced_line_of_the_window_models_cell(missing):
-    bench = resultline.load_benchmark()
-    line = _traced_line(resultline.declared(bench, SWA_CELL, True))
-    assert resultline.problems(line, bench, SWA_CELL, True, 1) == []
-    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
-    assert resultline.problems(cut, bench, SWA_CELL, True, 1) == [f"metric {missing} of this workload and mode is missing"]
-    assert resultline.problems(cut, bench, SWA_CELL, True, 1, may_miss={missing}) == []
-    over = {**line, "metrics": {**line["metrics"], "window_attn_decode_roofline": {"value": 106.0, "unit": "%"}}}
-    assert any("over 105%" in p for p in resultline.problems(over, bench, SWA_CELL, True, 1))
+# -- each family's shares of a peak, from counters and scopes ----------------------
 
 
 def _scrape(at, **series):
@@ -479,6 +438,32 @@ def _scrape(at, **series):
     )
 
 
+def _made_up_window(config, after, gauges=()):
+    """A context as run.py gathers it, of a made-up window of 50 s in which
+    the counters went from 0 to *after* (the *gauges* stood still), and
+    whose traced 4 s held ten whole runs of the decode program in 1.6 s and
+    eighty of a prefill program in 2.0 s; `hf` the configuration's published keys."""
+    with open(os.path.join(ROOT, "perfbench", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    hf = {k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")}
+    zero = {k: v if k in gauges else [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
+    return types.SimpleNamespace(
+        hf=hf, serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
+        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[], all_records=[],
+        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
+    )
+
+
+def _pairs(kind, phase, n):
+    return {"kind": kind, "phase": phase}, float(n)
+
+
+def _by_scope(total_s=1.6, **seconds):
+    """A program's seconds by scope as scope_reduce keeps them (`ssm_in_proj` stands for the scope `ssm.in_proj`)."""
+    return {"total_s": total_s, "by_scope_s": {k.replace("_", ".", 1): v for k, v in seconds.items()}}
+
+
 def test_the_window_rooflines_from_counters_and_scopes():
     """readers/swa_rooflines.py on a made-up window: 100 decode chunks of 8
     steps, pairs that are 24 slots x (3 full layers x 8000 + 9 window
@@ -486,37 +471,26 @@ def test_the_window_rooflines_from_counters_and_scopes():
     difference of the two reductions (readers/swa_scopes.py)."""
     from readers import swa_rooflines, swa_scopes
 
-    with open(os.path.join(ROOT, "perfbench", "configs", "smallthinker-21b-a3b-bf16.json")) as f:
-        cfg = json.load(f)
     steps = 800
-    pairs = lambda kind, phase, n: ({"kind": kind, "phase": phase}, float(n))  # noqa: E731
     after = {
         "kubeai_engine_attn_pairs_total": [
-            pairs("full", "decode", steps * 24 * 3 * 8000), pairs("window", "decode", steps * 24 * 9 * 4096),
-            pairs("full", "prefill", 3 * 4e9), pairs("window", "prefill", 9 * 2e9),
+            _pairs("full", "decode", steps * 24 * 3 * 8000), _pairs("window", "decode", steps * 24 * 9 * 4096),
+            _pairs("full", "prefill", 3 * 4e9), _pairs("window", "prefill", 9 * 2e9),
         ],
         "kubeai_engine_moe_experts_hit_total": [({"phase": "decode"}, steps * 12 * 32.0)],
         "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 12 * 64.0)],
         "kubeai_engine_step_seconds_count": [({"phase": "decode_chunk"}, 100.0)],
         "kubeai_engine_prefill_tokens_total": [({}, 1.0e6)], "kubeai_engine_generated_tokens_total": [({}, 19200.0)],
     }
-    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
-    ctx = types.SimpleNamespace(
-        hf={k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")},
-        serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
-        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[],
-        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
-    )
-    by = lambda **s: {"total_s": 1.6, "by_scope_s": {k.replace("_", "."): v for k, v in s.items()}}  # noqa: E731
+    ctx = _made_up_window("smallthinker-21b-a3b-bf16", after)
     ctx.swa_scope_shares = {
         "layers": {
-            "jit__unknown(7)": by(attn_full=0.20, attn_window=0.40, moe_experts=0.64),
-            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.2, "attn.window": 0.4}},
+            "jit__unknown(7)": _by_scope(attn_full=0.20, attn_window=0.40, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": _by_scope(2.0, attn_full=0.2, attn_window=0.4),
         },
         "kernels": {
-            "jit__unknown(7)": by(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64),
-            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.05, "attn.window": 0.1, "attn.kernel": 0.45}},
+            "jit__unknown(7)": _by_scope(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": _by_scope(2.0, attn_full=0.05, attn_window=0.1, attn_kernel=0.45),
         },
     }
     assert swa_scopes.read(ctx, "^jit__unknown", "full") == pytest.approx(12.5)
@@ -546,83 +520,6 @@ def test_the_window_rooflines_from_counters_and_scopes():
     assert swa_scopes.read(ctx, "^jit__unknown", "full") is None
 
 
-@pytest.mark.slow  # a minute and a half alone: not tier-1, as the expert model's rehearsal is not
-def test_rehearsal_of_the_window_models_cell(tmp_path):
-    """--rehearse --trace 1 of smallthinker-bf16-longdoc-sat at the
-    configuration's `rehearsal` keys (window 256, 8 layers): every phase,
-    the family's logits check through both pools, and every per-layer
-    metric the CPU can read."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", SWA_CELL, "--rehearse",
-         "--trace", "1", "--seed", str(2**31 + 11)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900,
-    )
-    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
-    last = lines[-1]
-    bench = resultline.load_benchmark()
-    # Time a step of one scope means nothing in a CPU trace (readers/swa_rooflines.py).
-    assert resultline.problems(last, bench, SWA_CELL, True, 1, rehearsal=True, may_miss=SWA_ROOFLINES) == []
-    assert last["correct"] is True
-    assert set(resultline.declared(bench, SWA_CELL, True)) - SWA_ROOFLINES <= set(last["metrics"])
-    logits = next(ln for ln in lines if ln.get("phase") == "logits")
-    assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
-    assert logits["sample"]["window_pages_released"] > 0
-    assert 0 < last["metrics"]["kv_pages_peak_pct.window"]["value"] <= 100
-
-
-# -- PR 40: the state-space family's cell and its readers ------------------------
-
-SSM_CELL = "nemotron3super-bf16-agent-sat"
-SSM_ROOFLINES = {"moe_experts_roofline.ssm", "ssm_decode_roofline", "ssm_prefill_roofline"}
-SSM_NEW = SSM_ROOFLINES | {"decode_ssm_share_pct", "prefill_ssm_share_pct", "decode_step_roofline.ssm", "window_mfu.ssm"}
-
-
-def test_the_state_space_cells_metrics_are_its_own():
-    bench = resultline.load_benchmark()
-    mine = resultline.declared(bench, SSM_CELL, True)
-    assert len(mine) == 23 and SSM_NEW <= set(mine)
-    assert all(n.endswith(".ssm") for n in set(mine) - SSM_NEW)  # twins of accepted readers, under its suffix
-    # No other cell carries them, this cell none of theirs, and they were appended together, wherever later PRs' stand.
-    names = [m["name"] for m in bench["per_layer"]]
-    at = names.index("decode_step_ms.ssm")
-    assert set(names[at : at + 23]) == set(mine)
-    for m in bench["per_layer"]:
-        assert (SSM_CELL in m["workloads"]) == (m["workloads"] == [SSM_CELL]), m["name"]
-    assert set(resultline.declared(bench, SSM_CELL, False)) == {"output_tok_s", "setup_s"}
-    for name in mine:
-        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
-        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py")), name
-    cell = next(w for w in bench["workloads"] if w["name"] == SSM_CELL)
-    config = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert (cell["traffic"], cell["chips"]) == ("agent-sat", 1)
-    assert config["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    with open(os.path.join(ROOT, config["file"])) as f:
-        published = json.load(f)
-    assert published["source"] == config["source"] and set(config["reduced"]) < set(published["reduced"])
-    assert (published["num_hidden_layers"], published["n_routed_experts"], published["router_experts"]) == (11, 128, 512)
-    assert published["serving"]["engine_args"] == ["--warmup", "--max-slots", "96", "--max-seq-len", "8192"]
-    spec = traffic.load("agent-sat", False)
-    assert (spec["loop"], spec["clients"]) == ("closed", 120)
-    assert spec["prompt_tokens"] == {"dist": "lognormal", "median": 1500, "sigma": 0.8, "min": 256, "max": 6000}
-    assert spec["output_tokens"] == {"dist": "lognormal", "median": 512, "sigma": 0.5, "min": 128, "max": 1024}
-
-
-@pytest.mark.parametrize("missing", ["decode_step_ms.ssm", "ssm_decode_roofline", "prefill_ssm_share_pct", "window_mfu.ssm"])
-def test_a_traced_line_of_the_state_space_models_cell(missing):
-    bench = resultline.load_benchmark()
-    line = _traced_line(resultline.declared(bench, SSM_CELL, True))
-    assert resultline.problems(line, bench, SSM_CELL, True, 1) == []
-    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
-    assert resultline.problems(cut, bench, SSM_CELL, True, 1) == [f"metric {missing} of this workload and mode is missing"]
-    assert resultline.problems(cut, bench, SSM_CELL, True, 1, may_miss={missing}) == []
-    over = {**line, "metrics": {**line["metrics"], "ssm_decode_roofline": {"value": 106.0, "unit": "%"}}}
-    assert any("over 105%" in p for p in resultline.problems(over, bench, SSM_CELL, True, 1))
-
-
 def test_the_state_space_rooflines_from_counters_and_scopes():
     """readers/ssm_rooflines.py on a made-up window: 100 decode chunks of 8
     steps with 90 of 96 slots live, half the held experts hit, a million
@@ -630,9 +527,6 @@ def test_the_state_space_rooflines_from_counters_and_scopes():
     from families import nemotron_h_counts as counts
     from readers import ssm_rooflines, ssm_scopes
 
-    with open(os.path.join(ROOT, "perfbench", "configs", "nemotron3-super-120b-a12b-bf16.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")}
     steps = 800
     after = {
         "kubeai_engine_state_slots_total": [({}, 96.0)],
@@ -641,17 +535,11 @@ def test_the_state_space_rooflines_from_counters_and_scopes():
         "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 5 * 128.0)],
         "kubeai_engine_prefill_tokens_total": [({}, 1.0e6)], "kubeai_engine_generated_tokens_total": [({}, 72000.0)],
     }
-    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
-    zero["kubeai_engine_state_slots_total"] = after["kubeai_engine_state_slots_total"]  # a gauge
-    ctx = types.SimpleNamespace(
-        hf=hf, serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
-        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[], all_records=[],
-        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
-    )
+    ctx = _made_up_window("nemotron3-super-120b-a12b-bf16", after, gauges=("kubeai_engine_state_slots_total",))
+    hf = ctx.hf
     ctx.ssm_scope_shares = {
-        "jit__unknown(7)": {"total_s": 1.6, "by_scope_s": {"ssm.conv": 0.1, "ssm.scan": 0.7, "ssm.in_proj": 0.1, "moe.experts": 0.4, "moe": 0.1}},
-        "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"ssm.scan": 0.5, "ssm.in_proj": 0.3, "moe.experts": 0.6}},
+        "jit__unknown(7)": _by_scope(ssm_conv=0.1, ssm_scan=0.7, ssm_in_proj=0.1, moe_experts=0.4, moe=0.1),
+        "jit_prefill_chunk_fn(9)": _by_scope(2.0, ssm_scan=0.5, ssm_in_proj=0.3, moe_experts=0.6),
     }
     assert ssm_scopes.read(ctx, "^jit__unknown", "ssm|ssm.in_proj|ssm.conv|ssm.scan|ssm.gate_norm|ssm.out_proj") == pytest.approx(56.25)
     assert ssm_scopes.read(ctx, "^jit_prefill", "ssm.scan") == pytest.approx(25.0)
@@ -675,122 +563,10 @@ def test_the_state_space_rooflines_from_counters_and_scopes():
     ctx.after, ctx.hf = _scrape(50.0, **after), {**hf, "model_type": "smallthinker"}
     assert read("window_mfu") is None
     # ... and a trace that carries no `ssm` scope gives no share.
-    ctx.ssm_scope_shares = {"jit__unknown(7)": {"total_s": 1.6, "by_scope_s": {"attn": 0.4, "moe.experts": 0.4}}}
+    ctx.ssm_scope_shares = {"jit__unknown(7)": _by_scope(attn=0.4, moe_experts=0.4)}
     assert ssm_scopes.read(ctx, "^jit__unknown", "moe.experts") is None
     ctx.trace, ctx.ssm_scope_shares = None, None
     assert ssm_scopes.read(ctx, "^jit__unknown", "ssm.scan") is None
-
-
-@pytest.mark.slow  # two minutes alone: not tier-1, as the other families' rehearsals are not
-def test_rehearsal_of_the_state_space_models_cell(tmp_path):
-    """--rehearse --trace 2 of nemotron3super-bf16-agent-sat at the
-    configuration's `rehearsal` keys (11 blocks, 4 of 16 experts held):
-    every phase, the family's logits check through the slot's state, and
-    every per-layer metric the CPU can read."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", SSM_CELL, "--rehearse",
-         "--trace", "2", "--seed", str(2**31 + 13)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900,
-    )
-    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
-    last = lines[-1]
-    bench = resultline.load_benchmark()
-    # Time a step means nothing in a CPU trace (readers/ssm_rooflines.py).
-    may_miss = SSM_ROOFLINES | {"decode_step_roofline.ssm"}
-    assert resultline.problems(last, bench, SSM_CELL, 2, 1, rehearsal=True, may_miss=may_miss) == []
-    assert set(resultline.declared(bench, SSM_CELL, 2)) - may_miss <= set(last["metrics"])
-    logits = next(ln for ln in lines if ln.get("phase") == "logits")
-    assert set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices", "state"}
-    assert all(logits["compared"][part]["ok"] for part in ("prefill_cold", "prefill_chunked", "decode", "router_choices"))
-    assert logits["held_experts"] == [0, 4, 16] and logits["pattern"] == "MEMEMEM*"
-    assert 0 < last["metrics"]["moe_experts_hit_pct.ssm"]["value"] <= 100
-    assert 0 < last["metrics"]["decode_ssm_share_pct"]["value"] < 100
-
-
-
-# -- PR 42: the gated window family's cell, its readers, and docqa's bypass ------
-
-AFM_CELL = "trinitymini-bf16-mixedlen-sat"
-MCHAT_CELL = "mistral7b-int8-chat-sat"
-AFM_NEW = {"prefill_attn_full_share_pct", "attn_gate_norm_share_pct"}
-AFM_ROOFLINES = {
-    "moe_experts_roofline.afm", "full_attn_decode_roofline.afm", "window_attn_decode_roofline.afm", "prefill_attn_roofline.afm",
-}
-
-
-def test_the_two_new_cells_metrics_are_their_own():
-    bench = resultline.load_benchmark()
-    mine = resultline.declared(bench, AFM_CELL, True)
-    assert len(mine) == 23 and AFM_NEW | AFM_ROOFLINES | {"decode_step_roofline.afm", "window_mfu.afm"} <= set(mine)
-    assert all(n.endswith(".afm") for n in set(mine) - AFM_NEW)  # twins of accepted readers, under its suffix
-    bypass = resultline.declared(bench, MCHAT_CELL, True)
-    assert set(bypass) == {"decode_step_ms.mchat", "batch_occupancy_pct.mchat", "prefix_hit_pct.mchat"}
-    # No other cell carries them, these cells none of theirs; they stand last, appended; the list is at its cap.
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-26:] == list(mine) + list(bypass) and len(names) == 128
-    for m in bench["per_layer"]:
-        for cell in (AFM_CELL, MCHAT_CELL):
-            assert (cell in m["workloads"]) == (m["workloads"] == [cell]), m["name"]
-    for cell in (AFM_CELL, MCHAT_CELL):
-        assert set(resultline.declared(bench, cell, False)) == {"output_tok_s", "setup_s"}
-    # A data-only twin is its accepted metric's file, byte for byte.
-    for twin, of in (
-        ("decode_step_ms.afm", "decode_step_ms.swa"), ("kv_pages_peak_pct.window.afm", "kv_pages_peak_pct.window"),
-        ("idle_exposed_host_pct.afm", "idle_exposed_host_pct"), ("decode_attn_full_share_pct.afm", "decode_attn_full_share_pct"),
-        ("decode_step_ms.mchat", "decode_step_ms.tput"), ("prefix_hit_pct.mchat", "prefix_hit_pct"),
-        ("batch_occupancy_pct.mchat", "batch_occupancy_pct"),
-    ):
-        files = [open(os.path.join(ROOT, "perfbench", "layer_metrics", n + ".json")).read() for n in (twin, of)]
-        assert files[0] == files[1], twin
-    for name in list(mine) + list(bypass):
-        with open(os.path.join(ROOT, "perfbench", "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
-        assert os.path.exists(os.path.join(ROOT, "perfbench", "readers", spec["reader"] + ".py")), name
-        if name in AFM_ROOFLINES | {"decode_step_roofline.afm", "window_mfu.afm"}:
-            assert spec["reader"] == "afm_rooflines"  # this family's counts
-    cell = next(w for w in bench["workloads"] if w["name"] == AFM_CELL)
-    config = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert (cell["traffic"], cell["chips"], config["reduced"]) == ("mixedlen-sat", 1, ["num_hidden_layers"])
-    with open(os.path.join(ROOT, config["file"])) as f:
-        published = json.load(f)
-    assert published["source"] == config["source"] and set(config["reduced"]) == set(published["reduced"])
-    assert (published["num_hidden_layers"], published["num_experts"], published["sliding_window"], len(published["layer_types"])) == (8, 128, 2048, 32)
-    assert published["serving"]["engine_args"] == ["--warmup", "--max-slots", "24", "--max-seq-len", "32768", "--kv-pages", "6145"]
-    # The row's keys as published: every number of the catalog's config under the same key.
-    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if os.path.exists(catalog):
-        with open(catalog) as f:
-            row = next(r for r in map(json.loads, f) if r["name"] == "Trinity-Mini")
-        assert row["source_url"] == published["source"]
-        assert {k for k, v in row["config"].items() if published.get(k) != v} == {"num_hidden_layers"}
-    spec = traffic.load("mixedlen-sat", False)
-    assert (spec["loop"], spec["clients"], spec["block"], spec["max_total_tokens"]) == ("closed", 28, 28, 32768)
-    assert spec["prompt_tokens"] == {"dist": "lognormal", "median": 4000, "sigma": 0.9, "min": 256, "max": 24576}
-    assert spec["output_tokens"] == {"dist": "lognormal", "median": 384, "sigma": 0.6, "min": 64, "max": 1280}
-    sizes = traffic.quantiles(spec["prompt_tokens"], 28)
-    assert (sizes[0], sizes[-1]) == (604, 24576) and 5500 < sum(sizes) / 28 < 6000  # short and long in ONE block
-    other = next(w for w in bench["workloads"] if w["name"] == MCHAT_CELL)
-    assert (other["config"], other["traffic"], other["chips"]) == ("mistral-7b-v0.3-int8", "chat-sat", 1)
-
-
-@pytest.mark.parametrize(
-    "cell,missing",
-    [(AFM_CELL, n) for n in ("decode_step_ms.afm", "kv_pages_peak_pct.window.afm", "attn_gate_norm_share_pct", "window_mfu.afm")]
-    + [(MCHAT_CELL, "prefix_hit_pct.mchat")],
-)
-def test_a_traced_line_of_the_two_new_cells(cell, missing):
-    bench = resultline.load_benchmark()
-    line = _traced_line(resultline.declared(bench, cell, 2))
-    assert resultline.problems(line, bench, cell, 2, 1) == []
-    cut = {**line, "metrics": {k: v for k, v in line["metrics"].items() if k != missing}}
-    assert resultline.problems(cut, bench, cell, 2, 1) == [f"metric {missing} of this workload and mode is missing"]
-    assert resultline.problems(cut, bench, cell, 2, 1, may_miss={missing}) == []
-    if cell == AFM_CELL:
-        over = {**line, "metrics": {**line["metrics"], "window_mfu.afm": {"value": 106.0, "unit": "%"}}}
-        assert any("over 105%" in p for p in resultline.problems(over, bench, cell, 2, 1))
 
 
 def test_the_gated_window_familys_rooflines_from_counters_and_scopes():
@@ -801,37 +577,27 @@ def test_the_gated_window_familys_rooflines_from_counters_and_scopes():
     from families import afmoe_counts as counts
     from readers import afm_rooflines, afm_scopes
 
-    with open(os.path.join(ROOT, "perfbench", "configs", "trinity-mini-bf16.json")) as f:
-        cfg = json.load(f)
-    hf = {k: v for k, v in cfg.items() if k not in ("serving", "rehearsal", "reduced", "assumed", "source")}
     steps = 800
-    pairs = lambda kind, phase, n: ({"kind": kind, "phase": phase}, float(n))  # noqa: E731
     after = {
         "kubeai_engine_attn_pairs_total": [
-            pairs("full", "decode", steps * 24 * 2 * 10000), pairs("window", "decode", steps * 24 * 6 * 2048),
-            pairs("full", "prefill", 2 * 4e9), pairs("window", "prefill", 6 * 1e9),
+            _pairs("full", "decode", steps * 24 * 2 * 10000), _pairs("window", "decode", steps * 24 * 6 * 2048),
+            _pairs("full", "prefill", 2 * 4e9), _pairs("window", "prefill", 6 * 1e9),
         ],
         "kubeai_engine_moe_experts_hit_total": [({"phase": "decode"}, steps * 6 * 64.0)],
         "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 6 * 128.0)],
         "kubeai_engine_step_seconds_count": [({"phase": "decode_chunk"}, 100.0)],
         "kubeai_engine_prefill_tokens_total": [({}, 6.0e5)], "kubeai_engine_generated_tokens_total": [({}, 19200.0)],
     }
-    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
-    ctx = types.SimpleNamespace(
-        hf=hf, serving=cfg["serving"], rehearsal=False, window_s=50.0, trace_t0=20.0, trace_t1=24.0,
-        before=_scrape(0.0, **zero), after=_scrape(50.0, **after), polls=[],
-        peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-        trace={"window_s": 4.0, "modules_s": {"jit__unknown(7)": (1.6, 1.6, 1.6, 10.0), "jit_prefill_chunk_fn(9)": (2.0, 2.0, 2.0, 80.0)}},
-    )
-    by = lambda **s: {"total_s": 1.6, "by_scope_s": {k.replace("_", "."): v for k, v in s.items()}}  # noqa: E731
+    ctx = _made_up_window("trinity-mini-bf16", after)
+    hf = ctx.hf
     ctx.swa_scope_shares = {
         "layers": {
-            "jit__unknown(7)": by(attn_full=0.20, attn_window=0.40, moe_experts=0.64),
-            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.5, "attn.window": 0.3}},
+            "jit__unknown(7)": _by_scope(attn_full=0.20, attn_window=0.40, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": _by_scope(2.0, attn_full=0.5, attn_window=0.3),
         },
         "kernels": {
-            "jit__unknown(7)": by(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64),
-            "jit_prefill_chunk_fn(9)": {"total_s": 2.0, "by_scope_s": {"attn.full": 0.05, "attn.window": 0.1, "attn.kernel": 0.65}},
+            "jit__unknown(7)": _by_scope(attn_full=0.08, attn_window=0.16, attn_kernel=0.36, moe_experts=0.64),
+            "jit_prefill_chunk_fn(9)": _by_scope(2.0, attn_full=0.05, attn_window=0.1, attn_kernel=0.65),
         },
     }
     n_steps = 10 * 8
@@ -850,15 +616,16 @@ def test_the_gated_window_familys_rooflines_from_counters_and_scopes():
     assert read("window_mfu") == pytest.approx(100 * (2 * counts.active_params(hf) * 619200 + 4 * 4096 * all_pairs) / (197e12 * 50))
     assert all(0 < read(w, **kw) < 100 for w, kw in (("full_attn", {}), ("window_attn", {}), ("experts", {}), ("decode_step", {}), ("window_mfu", {})))
     # The tail's own polls bracket the traced seconds where the run traced itself after its window.
+    zero = {k: [(labels, 0.0) for labels, _ in v] for k, v in after.items()}
     ctx.tail_view = types.SimpleNamespace(before=_scrape(60.0, **zero), polls=[], after=_scrape(65.0, **after), trace_t0=61.0, trace_t1=65.0)
     assert read("prefill_attn", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.8 * 5.0 / 4.0))
     # afm_scopes: what the family adds to a plain block, told apart from the layer it sits in.
     ctx.afm_scope_shares = {
-        "jit__unknown(7)": {"total_s": 1.6, "by_scope_s": {"attn.qk_norm": 0.02, "attn.gate": 0.03, "norm.post": 0.05, "attn.window": 0.4}},
+        "jit__unknown(7)": _by_scope(attn_qk_norm=0.02, attn_gate=0.03, norm_post=0.05, attn_window=0.4),
     }
     assert afm_scopes.read(ctx, "^jit__unknown", "attn.qk_norm|attn.gate|norm.post") == pytest.approx(100 * 0.10 / 1.6)
     # A program of another family, or the parent's: no such counter or scope, nothing read, nothing raised.
-    ctx.afm_scope_shares = {"jit__unknown(7)": by(attn_window=0.4, moe_experts=0.6)}
+    ctx.afm_scope_shares = {"jit__unknown(7)": _by_scope(attn_window=0.4, moe_experts=0.6)}
     assert afm_scopes.read(ctx, "^jit__unknown", "attn.qk_norm|attn.gate|norm.post") is None
     ctx.hf = {**hf, "model_type": "smallthinker"}
     assert all(read(w) is None for w in ("window_mfu", "experts", "full_attn", "decode_step"))
@@ -868,32 +635,164 @@ def test_the_gated_window_familys_rooflines_from_counters_and_scopes():
     assert afm_scopes.read(ctx, "^jit__unknown", "norm.post") is None
 
 
+# -- whole runs, every phase, on the CPU at a tiny size ----------------------------
+
+
+def _run_rehearsal(tmp_path, cell, trace, seed, timeout=600):
+    """`run.py --workload <cell> --rehearse --trace <trace>`: the JSON lines it printed, the result last."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    env.pop("XLA_FLAGS", None)  # conftest's eight virtual devices: the cell asks for one
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", cell, "--rehearse",
+         "--trace", str(trace), "--seed", str(seed)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=timeout,
+    )
+    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
+    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
+    return lines
+
+
+def test_rehearsal_of_a_run_that_traces_itself(tmp_path):
+    """--rehearse --trace 2, every phase: one last line with both kinds of
+    metric; the end-to-end values are what `Run.end_to_end` gave over the
+    window's records; the traced interval is the `profile.window` event
+    with the Python tracer off; each idle gap names its own causes, and the
+    idle shares add up."""
+    cell = "qwen7b-int8-chat-rate"
+    assert BENCH["trace_in_run"] is True  # since PR 38 this is the traced run the driver asks for
+    lines = _run_rehearsal(tmp_path, cell, 2, 2**31 + 5)
+    phases = [ln.get("phase") for ln in lines[:-1]]
+    for phase in ("checkpoint", "scale_from_zero", "window_open", "stop", "window", "trace", "logits", "end_to_end"):
+        assert phase in phases, phases
+    assert phases.index("stop") < phases.index("trace")  # read after the operator has gone, beside the logits child
+    last, trace, window = lines[-1], lines[phases.index("trace")], lines[phases.index("window")]
+    assert _problems(last, cell, rehearsal=True, may_miss=set(_per_layer(cell))) == []
+    assert last["correct"] is True and last["device"]["platform"] == "cpu"
+    # Both kinds, the end-to-end ones from the one function that computes them.
+    e2e = lines[phases.index("end_to_end")]
+    for name in resultline.declared(BENCH, cell, 0):
+        assert last["metrics"][name]["value"] == e2e[name]
+    idle_parts = _per_layer(cell, "idle_gaps")
+    assert len(idle_parts) == 2 and set(idle_parts) <= set(last["metrics"])
+    assert last["metrics"]["host_work_per_chunk_ms.rate"]["value"] >= 0
+    # The interval is the capture's own event, with the Python tracer off,
+    # and the capture began only after the window's last record had closed.
+    assert trace["window_from"] == "host event 'profile.window'" and trace["python_tracer"] is False
+    assert 0 <= trace["last_record_closed_s"] <= trace["capture_began_s"]
+    assert last["device"]["window_s"] == pytest.approx(4.0, abs=0.1)
+    # Every idle piece under the segment beside it: the shares add up to the idle share.
+    table = trace["idle_by_host"]
+    assert table["n_segments"] > 0 and sum(table["idle_by_cause_s"].values()) == pytest.approx(table["idle_s"])
+    idle_pct = 100.0 * (1 - last["device"]["busy_s"] / last["device"]["window_s"])
+    under_idle = 100.0 * table["idle_by_cause_s"].get("idle", 0.0) / table["window_s"]
+    split = sum(last["metrics"][n]["value"] for n in idle_parts)
+    assert split + under_idle == pytest.approx(idle_pct, abs=0.1)
+    gaps = last["breakdown"]["idle_gaps"]
+    assert gaps and all("host: " in g[0] and "dominant stall cause" not in g[0] and len(g[0]) <= 200 for g in gaps)
+    # The window's line keeps where the longest silence lay and the engine's slowest steps inside it.
+    assert 0 <= window["longest_silence_at_s"] < 8 and all(-0.5 <= s["at_s"] < 9.5 for s in window["slowest_steps"])
+    assert window["slowest_steps"] and {"kind", "total_ms", "ms"} <= set(window["slowest_steps"][0])
+
+
+def test_rehearsal_of_a_traced_run(tmp_path):
+    """--rehearse --trace 1, every phase: the accepted harness reads the
+    scopes, the padding and the host's work from the program as it is."""
+    cell = "qwen7b-int8-chat-sat"
+    lines = _run_rehearsal(tmp_path, cell, 1, 2**31 + 5)
+    phases = [ln.get("phase") for ln in lines[:-1]]
+    for phase in ("checkpoint", "scale_from_zero", "window_open", "stop", "window", "logits", "trace", "scopes"):
+        assert phase in phases, phases
+    scopes = lines[phases.index("scopes")]
+    assert scopes["error"] is None and any(p.startswith("jit__unknown") for p in scopes["programs"])
+    last = lines[-1]
+    assert _problems(last, cell, 1, rehearsal=True, may_miss=set(_per_layer(cell))) == []
+    assert last["correct"] is True and last["device"]["platform"] == "cpu"
+    assert 0 <= last["metrics"]["prefill_padding_pct"]["value"] < 100
+    assert 0 <= last["metrics"]["decode_epilogue_ran_pct"]["value"] <= 100
+    assert last["metrics"]["host_work_per_chunk_ms"]["value"] >= 0
+    # The decode step by scope: each share read, and together no more than the step.
+    by_scope = _per_layer(cell, "decode_by_scope")
+    shares = [last["metrics"][n]["value"] for n in by_scope]
+    assert len(shares) >= 3 and all(0 <= v <= 100 for v in shares) and sum(shares) <= 100.0
+
+
+def _family_rehearsal(tmp_path, cell, trace, seed, may_miss, timeout=900):
+    """A rehearsal of an expert family's cell at its configuration's
+    `rehearsal` keys: the last line carries every metric the cell declares
+    but *may_miss* (so a cell declares only what its own program can be read
+    for). -> the last line, the logits child's line."""
+    lines = _run_rehearsal(tmp_path, cell, trace, seed, timeout)
+    last = lines[-1]
+    assert _problems(last, cell, trace, rehearsal=True, may_miss=may_miss) == []
+    assert set(resultline.declared(BENCH, cell, trace)) - may_miss <= set(last["metrics"])
+    return last, next(ln for ln in lines if ln.get("phase") == "logits")
+
+
+def _value(last, cell, what):
+    """What the line carries for the cell's ONE metric that is *what* (a key of IS)."""
+    (name,) = _per_layer(cell, what)
+    return last["metrics"][name]["value"]
+
+
+@pytest.mark.slow  # about a minute alone, more beside five other workers: not tier-1 (CHANGES.md, PR 33)
+def test_rehearsal_of_the_expert_models_cell(tmp_path):
+    """--rehearse --trace 1 of kanana2-bf16-reason-sat at the tests' small
+    size: every phase, the family's two-part logits check, and every
+    per-layer metric the CPU can read."""
+    cell = "kanana2-bf16-reason-sat"
+    last, logits = _family_rehearsal(tmp_path, cell, 1, 2**31 + 7, _timed_rooflines(cell, but=("decode_step",)), timeout=600)
+    assert last["correct"] is True
+    assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
+    assert 0 < _value(last, cell, "experts_hit") <= 100
+
+
+@pytest.mark.slow  # a minute and a half alone: not tier-1, as the expert model's rehearsal is not
+def test_rehearsal_of_the_window_models_cell(tmp_path):
+    """--rehearse --trace 1 of smallthinker-bf16-longdoc-sat at the
+    configuration's `rehearsal` keys (window 256, 8 layers): every phase,
+    the family's logits check through both pools, and every per-layer
+    metric the CPU can read."""
+    cell = "smallthinker-bf16-longdoc-sat"
+    last, logits = _family_rehearsal(tmp_path, cell, 1, 2**31 + 11, _timed_rooflines(cell, but=("decode_step",)))
+    assert last["correct"] is True
+    assert logits["ok"] and set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
+    assert logits["sample"]["window_pages_released"] > 0
+    assert 0 < _value(last, cell, "window_pool_peak") <= 100
+
+
+@pytest.mark.slow  # two minutes alone: not tier-1, as the other families' rehearsals are not
+def test_rehearsal_of_the_state_space_models_cell(tmp_path):
+    """--rehearse --trace 2 of nemotron3super-bf16-agent-sat at the
+    configuration's `rehearsal` keys (11 blocks, 4 of 16 experts held):
+    every phase, the family's logits check through the slot's state, and
+    every per-layer metric the CPU can read (this family's reader times the
+    whole step in the trace too). Fails in the sandbox on every commit since
+    the one that wrote it, for want of the prefill programs' share by scope:
+    a CPU trace names every warmed prefill shape alike and scope_reduce.py
+    then reduces none (PERF.md section 7, "Open from PR 47": a `benchmark`
+    PR's to repair, in perfbench/; what may miss here stays as it was)."""
+    cell = "nemotron3super-bf16-agent-sat"
+    last, logits = _family_rehearsal(tmp_path, cell, 2, 2**31 + 13, _timed_rooflines(cell))
+    assert set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices", "state"}
+    assert all(logits["compared"][part]["ok"] for part in ("prefill_cold", "prefill_chunked", "decode", "router_choices"))
+    assert logits["held_experts"] == [0, 4, 16] and logits["pattern"] == "MEMEMEM*"
+    assert 0 < _value(last, cell, "experts_hit") <= 100
+    assert 0 < last["metrics"]["decode_ssm_share_pct"]["value"] < 100
+
+
 @pytest.mark.slow  # two minutes alone: not tier-1, as the other families' rehearsals are not
 def test_rehearsal_of_the_gated_window_models_cell(tmp_path):
     """--rehearse --trace 2 of trinitymini-bf16-mixedlen-sat at the
     configuration's `rehearsal` keys (8 layers, window 256, 8 experts
     top-2): every phase, the family's logits check through both pools past
     the window, and every per-layer metric the CPU can read."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"), "--workload", AFM_CELL, "--rehearse",
-         "--trace", "2", "--seed", str(2**31 + 13)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900,
-    )
-    lines = [json.loads(ln) for ln in proc.stdout.decode().splitlines() if ln.startswith("{")]
-    assert proc.returncode == 0, (lines[-1], proc.stderr.decode()[-2000:])
-    last = lines[-1]
-    bench = resultline.load_benchmark()
-    # Time a step of one scope means nothing in a CPU trace (readers/afm_rooflines.py), and the tail's 4 s
-    # of a rehearsal's five clients need not hold a whole run of a prefill program.
-    may_miss = AFM_ROOFLINES | {"prefill_attn_full_share_pct", "prefill_moe_share_pct.afm", "prefill_share_pct.afm"}
-    assert resultline.problems(last, bench, AFM_CELL, 2, 1, rehearsal=True, may_miss=may_miss) == []
-    assert set(resultline.declared(bench, AFM_CELL, 2)) - may_miss <= set(last["metrics"])
-    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    cell = "trinitymini-bf16-mixedlen-sat"
+    # The tail's 4 s of a rehearsal's five clients need not hold a whole run of a prefill program.
+    may_miss = _timed_rooflines(cell, but=("decode_step",)) | set(_per_layer(cell, "of_prefill_programs"))
+    last, logits = _family_rehearsal(tmp_path, cell, 2, 2**31 + 13, may_miss)
     assert set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices"}
     assert all(part["ok"] for part in logits["compared"].values())
     assert logits["sample"]["long_prompt"] == 800 and logits["sample"]["window_pages_released"] > 0
-    assert 0 < last["metrics"]["moe_experts_hit_pct.afm"]["value"] <= 100
+    assert 0 < _value(last, cell, "experts_hit") <= 100
     assert 0 < last["metrics"]["attn_gate_norm_share_pct"]["value"] < 100
-    assert last["metrics"]["kv_pages_peak_pct.window.afm"]["value"] > 0
+    assert _value(last, cell, "window_pool_peak") > 0
