@@ -17,16 +17,19 @@ machinery that collapses it:
   ``kubeai_engine_cold_start_seconds{phase}`` histogram. Sum-of-phases
   exceeding wall-clock is the direct evidence that load and compile
   overlapped.
-- ``warm_compile()`` / ``warm_from_checkpoint()`` — build the engine's
-  EXACT jitted step functions (core.build_step_functions) and compile
-  them against abstract ``ShapeDtypeStruct`` trees derived from
-  config.json alone — no weights needed. With the persistent cache
-  enabled the compiled binaries land on disk, so the loader Job
-  (``--warm-compile-cache``), a parked replica, or a background thread
-  overlapped with the weight stream can all pre-pay compilation.
-- ``start_background_warm()`` — kick the AOT compile off on a thread
-  while weights stream, so engine start costs ~max(load, compile)
-  instead of their sum.
+- ``warm_compile()`` / ``warm_from_checkpoint()`` — bring up the
+  engine's EXACT step programs (engine/step_programs.py: one list of
+  call shapes, one table of held executables) against abstract
+  ``ShapeDtypeStruct`` trees derived from config.json alone — no weights
+  needed. With the persistent cache placed the executables land on disk
+  twice: in jax's cache, keyed by the lowered text, and in the
+  deployment's bundle beside it, from which the next start LOADS them
+  without tracing or lowering. The loader Job (``--warm-compile-cache``)
+  and a parked replica pre-pay both.
+- ``start_background_warm()`` — fill the table on a thread while weights
+  stream, so engine start costs ~max(load, programs) instead of their
+  sum; the Engine then RUNS the table's executables (they are not
+  compiled a second time through its jit calls).
 """
 
 from __future__ import annotations
@@ -234,99 +237,28 @@ def warm_compile(
     n_valid_vocab: int | None = None,
     include_group: bool = True,
 ) -> dict:
-    """AOT-compile the engine's step functions for *model_config* ×
-    *engine_config* against abstract arguments: the decode chunk,
-    batch-1 cold prefill per bucket, the group-cap batch, and the
-    chunked-prefill shape — the same coverage Engine.warmup() dispatches.
+    """Bring up the engine's step programs for *model_config* ×
+    *engine_config* ahead of time and return the stats alone: every call
+    of the one list (step_programs.StepPrograms.calls: the decode chunk,
+    batch-1 and group-cap cold prefill per bucket, a chunk call for every
+    bucket and the wide chunk, the coverage Engine.warmup() executes).
 
-    With the persistent compile cache enabled (setup_compile_cache) the
-    compiled executables land on disk keyed by the identical HLO the
-    real engine later lowers, so its first dispatches become cache
-    reads. Without a cache dir this still validates compilability but
-    benefits nobody else — callers should set the cache up first.
+    With the persistent compile cache placed (setup_compile_cache) the
+    executables land there AND in the deployment's bundle beside it
+    (engine/step_programs.py), from which the next start of this tree and
+    deployment loads them without tracing or lowering: the loader Job and
+    a parked replica call this for that file. The table itself is dropped
+    here (an engine start keeps it: start_background_warm). Without a
+    cache dir this still validates compilability but benefits nobody else.
 
     The *model_config* must be the engine's post-padding config; pass
     the tokenizer's vocab as *n_valid_vocab* so the pad-masking branch
     matches the serving process. Per-shape failures are collected, not
     raised — a warm miss must never fail a load."""
-    import jax
-    import jax.numpy as jnp
+    from kubeai_tpu.engine.step_programs import StepPrograms, fill_step_table
 
-    from kubeai_tpu.engine import core
-
-    cfg = engine_config or core.EngineConfig()
-    t0 = time.monotonic()
-    sf = core.build_step_functions(model_config, cfg, n_valid_vocab)
-    hist_width = core.engine_dims(cfg)[2]
-    # Columns of a block-table row: two tables side by side for a family
-    # with two page budgets a slot.
-    table_cols = core.table_width(model_config, cfg)
-    B = cfg.max_slots
-    Kb = cfg.max_logit_bias
-    params = param_shapes(model_config, quantization)
-    cache = jax.eval_shape(lambda: core.init_pools(model_config, cfg))
-    keys = jax.eval_shape(
-        lambda: jax.random.key_data(jax.random.split(jax.random.key(0), B))
-    )
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
-    shapes = 0
-    errors: list[str] = []
-
-    def compile_one(label, fn, *args, **kw):
-        nonlocal shapes
-        try:
-            fn.lower(*args, **kw).compile()
-            shapes += 1
-        except Exception as e:  # pragma: no cover - depends on backend
-            log.warning("warm compile of %s failed: %s", label, e)
-            errors.append(f"{label}: {e}")
-
-    compile_one(
-        "decode",
-        sf.decode_jit,
-        params, cache, sds((B, table_cols), i32), sds((B, hist_width), i32),
-        sds((B,), i32), sds((B,), i32), keys,
-        sds((B,), jnp.bool_), sds((B,), f32), sds((B,), f32), sds((B,), i32),
-        sds((B,), f32), sds((B,), f32), sds((B,), jnp.bool_), sds((B,), i32),
-        sds((B, Kb), i32), sds((B, Kb), f32),
-        sds((B,), jnp.bool_), sds((B,), i32), sds((B,), u32), sds((B,), i32),
-    )
-    cap = max(1, min(cfg.prefill_group_cap, cfg.max_slots))
-    sizes = (1, cap) if include_group and cap > 1 else (1,)
-    for bucket in cfg.prefill_buckets:
-        for n_pad in sizes:
-            compile_one(
-                f"prefill_batch[{n_pad}x{bucket}]",
-                sf.prefill_batch_jit,
-                params, sds((n_pad, bucket), i32), sds((n_pad,), i32),
-                sds((n_pad, table_cols), i32), sds((n_pad,), i32),
-                sds((n_pad,), u32), sds((n_pad,), f32), sds((n_pad,), f32),
-                sds((n_pad,), i32), sds((n_pad, Kb), i32),
-                sds((n_pad, Kb), f32), sds((B,), i32), cache,
-            )
-    # The chunk calls that carry a long prompt: the largest bucket, and the
-    # wide chunk where a prompt of this engine reaches it (prefill_plan).
-    for rows in sorted({max(cfg.prefill_buckets), core.wide_chunk(cfg)}):
-        compile_one(
-            f"prefill_chunk[{rows}]",
-            sf.prefill_chunk_jit,
-            params, sds((1, rows), i32), sds((), i32), sds((), i32),
-            sds((1, table_cols), i32), sds((), i32), sds((), u32), sds((), f32),
-            sds((), f32), sds((), i32), sds((Kb,), i32), sds((Kb,), f32),
-            sds((B,), i32), cache,
-        )
-    out = {"shapes": shapes, "seconds": round(time.monotonic() - t0, 3)}
-    if errors:
-        out["errors"] = errors
-    log.info(
-        "AOT warm compile: %d shapes in %.1fs (%d failed)",
-        shapes, out["seconds"], len(errors),
-    )
-    return out
+    programs = StepPrograms(model_config, engine_config, n_valid_vocab, quantization)
+    return fill_step_table(programs, include_group).stats
 
 
 def warm_from_checkpoint(
@@ -384,26 +316,25 @@ def warm_from_checkpoint(
 
 
 class BackgroundWarm:
-    """Handle to an AOT warm compile running on a daemon thread. The
-    launcher stamps the timeline's compile phase around the thread's
-    actual lifetime; join() returns the warm stats (or the error as a
-    stats dict — a warm failure must never fail the load)."""
+    """Handle to a warm of the step programs running on a daemon thread.
+    The launcher stamps the timeline's compile phase around the thread's
+    actual lifetime; join() returns what the warm returned (None where it
+    raised: a warm failure must never fail the load)."""
 
     def __init__(self, fn, timeline: ColdStartTimeline | None = None):
-        self.result: dict | None = None
+        self.result = None
         self._timeline = timeline
         if timeline is not None:
             # The compile phase begins the moment the thread is
-            # launched: lowering starts concurrently with the caller's
-            # weight stream, which is exactly the claim the stamps make.
+            # launched: it runs concurrently with the caller's weight
+            # stream, which is exactly the claim the stamps make.
             timeline.begin("compile")
 
         def run():
             try:
                 self.result = fn()
             except Exception as e:  # pragma: no cover - backend-dependent
-                log.warning("background warm compile failed: %s", e)
-                self.result = {"shapes": 0, "error": str(e)}
+                log.warning("background warm of the step programs failed: %s", e)
             finally:
                 if timeline is not None:
                     timeline.end("compile")
@@ -413,7 +344,7 @@ class BackgroundWarm:
         )
         self._thread.start()
 
-    def join(self, timeout: float | None = None) -> dict | None:
+    def join(self, timeout: float | None = None):
         self._thread.join(timeout)
         return self.result
 
@@ -425,10 +356,14 @@ def start_background_warm(
     n_valid_vocab: int | None = None,
     timeline: ColdStartTimeline | None = None,
 ) -> BackgroundWarm:
+    """Fill the step table (engine/step_programs.py) on a thread while
+    the weights stream; join() hands the table to the Engine, which runs
+    ITS executables: nothing the warm brought up is compiled again."""
+    from kubeai_tpu.engine.step_programs import StepPrograms, fill_step_table
+
     return BackgroundWarm(
-        lambda: warm_compile(
-            model_config, engine_config,
-            quantization=quantization, n_valid_vocab=n_valid_vocab,
+        lambda: fill_step_table(
+            StepPrograms(model_config, engine_config, n_valid_vocab, quantization)
         ),
         timeline=timeline,
     )

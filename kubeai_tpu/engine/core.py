@@ -242,6 +242,26 @@ def table_width(model_config: ModelConfig, cfg: EngineConfig) -> int:
     return engine_dims(cfg)[0] * (2 if window_pool_dims(model_config, cfg)[0] else 1)
 
 
+def serving_configs(
+    model_config: ModelConfig, engine_config: EngineConfig | None
+) -> tuple[ModelConfig, EngineConfig]:
+    """(ModelConfig, EngineConfig) as an Engine serves them: the pool's
+    dtype is the engine's where it sets one, and a family that reuses no
+    prefix looks none up. The Engine and the list of its step programs
+    (engine/step_programs.py) both start from here, so a warm thread's
+    programs are the Engine's."""
+    import dataclasses as _dc
+
+    cfg = engine_config or EngineConfig()
+    if cfg.kv_cache_dtype:
+        model_config = _dc.replace(model_config, kv_cache_dtype=cfg.kv_cache_dtype)
+    if cfg.prefix_cache_min and not family(model_config).PREFIX_REUSE:
+        # The family's rule (models/nemotron_h.py): nothing is looked
+        # up and nothing registered; the hit counters stay 0.
+        cfg = _dc.replace(cfg, prefix_cache_min=0)
+    return model_config, cfg
+
+
 def init_pools(model_config: ModelConfig, cfg: EngineConfig):
     """The family's page pool(s) at the engine's dimensions (shared with
     the AOT warm compiler, as engine_dims is), and beside them, for a
@@ -370,21 +390,10 @@ class Engine:
         engine_config: EngineConfig | None = None,
         mesh=None,
         publisher=None,
+        step_table=None,
     ):
-        self.cfg = engine_config or EngineConfig()
-        if self.cfg.kv_cache_dtype:
-            import dataclasses as _dc
-
-            model_config = _dc.replace(
-                model_config, kv_cache_dtype=self.cfg.kv_cache_dtype
-            )
+        model_config, self.cfg = serving_configs(model_config, engine_config)
         family(model_config).refuse_unsupported(model_config)
-        if self.cfg.prefix_cache_min and not family(model_config).PREFIX_REUSE:
-            # The family's rule (models/nemotron_h.py): nothing is looked
-            # up and nothing registered; the hit counters stay 0.
-            import dataclasses as _dc
-
-            self.cfg = _dc.replace(self.cfg, prefix_cache_min=0)
         self.model_config = model_config
         self.params = params
         self.tokenizer = tokenizer
@@ -704,8 +713,10 @@ class Engine:
         )
         self.m_recompiles = default_registry.counter(
             "kubeai_engine_jit_recompiles_total",
-            "jitted step-function compilations observed (warmup compiles "
-            "included; growth after warmup means shape churn)",
+            "step programs this process brought up: the step table's "
+            "(loaded from the bundle or compiled ahead of time) and lazy "
+            "compiles through the jitted functions (warmup's included; "
+            "growth after warmup means shape churn)",
         )
         self._jit_entries_seen = 0
         # Shared sliding-window rate (obs/perf.py): the same
@@ -784,7 +795,7 @@ class Engine:
         register_engine_debug_section("perf", self._perf_section_fn)
 
         self._init_device_state()
-        self._build_step_fns()
+        self._build_step_fns(step_table)
 
     # -- perf X-ray --------------------------------------------------------
 
@@ -840,8 +851,12 @@ class Engine:
                 }
                 for dev in jax.local_devices()
             ],
+            # How this process brought up its step programs
+            # (kubeai_engine_step_programs_total; engine/step_programs.py).
+            "step_programs": {how: self._table.stats[how] for how in ("loaded", "compiled", "lazy")},
             # (kv pages, queries) a block the ragged paged kernel was
-            # given, per call shape this process has traced.
+            # given, per call shape this process has traced (none where
+            # every program was loaded from the bundle: nothing is traced).
             "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
             # Likewise: the MLA decode kernel's pages a block, ring and form; (tm, tk, tn) of the grouped
             # matmul; the heads of a slot a program of the state-space step kernel holds (empty: portable).
@@ -1067,17 +1082,50 @@ class Engine:
         if not hasattr(self, "_adapters"):
             self._adapters = None  # AdapterRuntime; survives _recover()
 
-    def _build_step_fns(self):
+    def _build_step_fns(self, step_table=None):
+        """Take the step programs from *step_table* (the warm thread's:
+        engine/step_programs.py) where it was filled for exactly this
+        engine's configs, else start an empty table of this engine's own
+        list. Either way the list states (model_config, cfg, the valid
+        vocab) once and the jitted functions are built from it once;
+        the table outlives _recover(): executables do not depend on
+        device state."""
+        from kubeai_tpu.engine.step_programs import StepPrograms, StepTable
+
         mc = self.model_config
         # The model vocab may be padded past the tokenizer's (tp
         # divisibility, MXU tiling); padded columns carry zero weights and
         # logit 0.0, which is very much sampleable — mask them out.
         n_valid = min(getattr(self.tokenizer, "vocab_size", mc.vocab_size), mc.vocab_size)
-        sf = build_step_functions(mc, self.cfg, n_valid, mesh=self._mesh)
+        if step_table is not None and not (
+            self._mesh is None and step_table.programs.serves(mc, self.cfg, n_valid)
+        ):
+            log.warning("the warmed step programs are another deployment's; compiling this engine's own")
+            step_table = None
+        self._table = step_table or StepTable(StepPrograms(mc, self.cfg, n_valid, mesh=self._mesh))
+        sf = self._table.programs.step_functions
         self._step_fns = sf
+        # What _step falls to on a miss, and what the gang follower's
+        # replay calls directly.
         self._prefill_chunk_jit = sf.prefill_chunk_jit
         self._prefill_batch_jit = sf.prefill_batch_jit
         self._decode_jit = sf.decode_jit
+        self._lazy_seen = 0
+
+    def _step(self, member: str, shape: tuple, *args, **kw):
+        """The one dispatcher of the step programs (the scheduler's three
+        dispatch sites and warmup): *member* of StepFunctions at the call
+        shape *shape* (() for the decode chunk, the shape of a prefill's
+        tokens). A program the table holds runs as the held executable
+        (donation and output layout are its own, as compiled from the
+        same jax.jit); a miss falls to the engine's jitted function and
+        compiles lazily: a shape the list did not foresee, or a call that
+        carries an adapter (`lora=`: another signature, another
+        program)."""
+        held = None if kw else self._table.held.get((member, shape))
+        if held is not None:
+            return held(*args)
+        return getattr(self, "_" + member)(*args, **kw)
 
     def _attn_kernel(self, kind: str, queries: int) -> str:
         """The attention implementation the step *kind* compiles to at
@@ -1115,47 +1163,50 @@ class Engine:
         self._thread.start()
 
     def warmup(self, include_group: bool = True) -> dict:
-        """Pre-compile (or pre-load from the persistent compile cache)
-        every step-function shape the serving path hits: the decode
-        chunk, batch-1 cold prefill for every bucket, the group-cap
-        batch (cold bursts), and one chunked-prefill shape (long/reuse
-        prompts). Called BEFORE start()/serving so the first real
-        request never pays a compile; dispatches write only the KV
-        pool's trash page (tables all zero — the designed garbage sink)
-        and touch no slot bookkeeping. Single-host only: on a gang every
-        dispatch must be broadcast, and followers compile at replay."""
+        """Execute every step program of the one list once
+        (engine/step_programs.py::StepPrograms.calls: the decode chunk,
+        batch-1 and group-cap cold prefill for every bucket, a chunk call
+        for every bucket and the wide chunk), through the dispatcher the
+        serving path uses: a program the table holds (loaded from the
+        bundle or compiled by the warm thread) only runs, one it does not
+        hold compiles through its jitted function. Called BEFORE
+        start()/serving so the first real request never pays a compile;
+        dispatches write only the KV pool's trash page (tables all zero —
+        the designed garbage sink) and touch no slot bookkeeping.
+        Single-host only: on a gang every dispatch must be broadcast, and
+        followers compile at replay."""
         if self._multiproc or self._publisher is not None:
             log.info("warmup skipped on a multi-host gang")
             return {"shapes": 0, "skipped": "gang"}
-        B = self.cfg.max_slots
         Kb = self.cfg.max_logit_bias
+        cols = self._page_table.shape[1]
         t0 = time.monotonic()
         shapes = 0
-        # Decode chunk (the hot loop).
-        (
-            *_,
-            self._cache, self._tok_hist, self._lengths,
-            self._last_tokens, self._keys, _counters,
-        ) = self._decode_jit(
-            self.params, self._cache, self._page_table.copy(), self._tok_hist,
-            self._lengths, self._last_tokens, self._keys,
-            self._h_active.copy(), self._h_temp.copy(), self._h_top_p.copy(),
-            self._h_top_k.copy(), self._h_presence.copy(), self._h_freq.copy(),
-            self._h_want_top.copy(),
-            self._h_gen_start.copy(), self._h_bias_ids.copy(),
-            self._h_bias_vals.copy(), self._adm_mask.copy(),
-            self._adm_len.copy(), self._adm_seed.copy(), self._adm_toks,
-        )
-        shapes += 1
-        cap = max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
-        sizes = (1, cap) if include_group and cap > 1 else (1,)
-        for bucket in self.cfg.prefill_buckets:
-            for n_pad in sizes:
-                *_, self._cache, self._adm_toks, _counters = self._prefill_batch_jit(
+        for call in self._table.programs.calls(include_group):
+            if call.member == "decode_jit":  # the hot loop
+                (
+                    *_,
+                    self._cache, self._tok_hist, self._lengths,
+                    self._last_tokens, self._keys, _counters,
+                ) = self._step(
+                    call.member, call.shape,
+                    self.params, self._cache, self._page_table.copy(), self._tok_hist,
+                    self._lengths, self._last_tokens, self._keys,
+                    self._h_active.copy(), self._h_temp.copy(), self._h_top_p.copy(),
+                    self._h_top_k.copy(), self._h_presence.copy(), self._h_freq.copy(),
+                    self._h_want_top.copy(),
+                    self._h_gen_start.copy(), self._h_bias_ids.copy(),
+                    self._h_bias_vals.copy(), self._adm_mask.copy(),
+                    self._adm_len.copy(), self._adm_seed.copy(), self._adm_toks,
+                )
+            elif call.member == "prefill_batch_jit":
+                n_pad, bucket = call.shape
+                *_, self._cache, self._adm_toks, _counters = self._step(
+                    call.member, call.shape,
                     self.params,
                     np.zeros((n_pad, bucket), np.int32),
                     np.full((n_pad,), bucket, np.int32),
-                    np.zeros((n_pad, self._page_table.shape[1]), np.int32),
+                    np.zeros((n_pad, cols), np.int32),
                     np.zeros((n_pad,), np.int32),
                     np.zeros((n_pad,), np.uint32),
                     np.ones((n_pad,), np.float32),
@@ -1166,31 +1217,29 @@ class Engine:
                     self._adm_toks,
                     self._cache,
                 )
-                shapes += 1
-        # Chunked prefill pads its FINAL chunk to the smallest fitting
-        # bucket (the calls before it are the largest bucket or the wide
-        # chunk: prefill_plan), so the serving path hits one chunk shape
-        # per bucket — warming only max_bucket leaves a mid-serving
-        # compile on the first prefix-reuse prompt whose tail lands in a
-        # smaller bucket — and ONE more, the wide chunk, where a prompt
-        # of this engine can be that long.
-        for bucket in sorted({*self.cfg.prefill_buckets, wide_chunk(self.cfg)}):
-            *_, self._cache, self._adm_toks, _counters = self._prefill_chunk_jit(
-                self.params,
-                np.zeros((1, bucket), np.int32),
-                np.int32(0),
-                np.int32(bucket - 1),
-                np.zeros((1, self._page_table.shape[1]), np.int32),
-                np.int32(0),
-                np.uint32(0),
-                np.float32(1.0),
-                np.float32(1.0),
-                np.int32(0),
-                np.zeros((Kb,), np.int32),
-                np.zeros((Kb,), np.float32),
-                self._adm_toks,
-                self._cache,
-            )
+            else:
+                rows = call.shape[1]
+                *_, self._cache, self._adm_toks, _counters = self._step(
+                    call.member, call.shape,
+                    self.params,
+                    np.zeros((1, rows), np.int32),
+                    np.int32(0),
+                    np.int32(rows - 1),
+                    np.zeros((1, cols), np.int32),
+                    np.int32(0),
+                    np.uint32(0),
+                    np.float32(1.0),
+                    np.float32(1.0),
+                    np.int32(0),
+                    np.zeros((Kb,), np.int32),
+                    np.zeros((Kb,), np.float32),
+                    self._adm_toks,
+                    self._cache,
+                )
+            # One program in flight at a time: queued behind each other,
+            # held executables would have their outputs and workspaces
+            # allocated together, a peak no serving step reaches.
+            jax.block_until_ready((self._adm_toks, self._lengths))
             shapes += 1
         if self._kv_enabled():
             # Restore-path jits (park/import/slotset): left lazy, the
@@ -1694,8 +1743,9 @@ class Engine:
         return used, limit
 
     def _jit_cache_entries(self) -> int:
-        """Total compiled executables across the step functions (jax's
-        per-function lowering cache). Growth = a compilation happened."""
+        """Executables that came up through the jitted functions (jax's
+        per-function cache): shapes the table does not hold, calls that
+        carry an adapter, embeddings. Growth = a compilation happened."""
         sf = self._step_fns
         fns = [sf.decode_jit, sf.prefill_batch_jit, sf.prefill_chunk_jit]
         if hasattr(self, "_embed_jit"):  # built on first embeddings call
@@ -1703,12 +1753,21 @@ class Engine:
         return sum(fn._cache_size() for fn in fns)
 
     def _update_recompile_counter(self) -> None:
-        """Scheduler-loop poll: surface compilations (warmup AND shape-
-        churn recompiles) as a counter — steady growth after warmup is
-        the classic silent TPU latency killer. The KV cached-page
-        eviction counter rides the same poll (paging.py stays
-        dependency-free; both sources are scheduler-thread-owned)."""
-        n = self._jit_cache_entries()
+        """Scheduler-loop poll: surface the programs this process brought
+        up (the table's, loaded or compiled ahead of time, AND lazy
+        compiles through the jitted functions: warmup's on a start with
+        an empty table, shape churn afterwards) as a counter — steady
+        growth after warmup is the classic silent TPU latency killer. The
+        KV cached-page eviction counter rides the same poll (paging.py
+        stays dependency-free; both sources are scheduler-thread-owned)."""
+        from kubeai_tpu.engine.step_programs import M_STEP_PROGRAMS
+
+        lazy = self._jit_cache_entries()
+        if lazy > self._lazy_seen:
+            M_STEP_PROGRAMS.inc(lazy - self._lazy_seen, labels={"how": "lazy"})
+        self._lazy_seen = lazy
+        self._table.stats["lazy"] = lazy  # /debug/engine: cold_start.warm_compile
+        n = len(self._table.held) + lazy
         if n > self._jit_entries_seen:
             self.m_recompiles.inc(n - self._jit_entries_seen)
             self._jit_entries_seen = n
@@ -2618,7 +2677,8 @@ class Engine:
                     "bias_ids": bias_ids, "bias_vals": bias_vals,
                 },
             ):
-                tok, lp, t_ids, t_lp, self._cache, self._adm_toks, counters = self._prefill_chunk_jit(
+                tok, lp, t_ids, t_lp, self._cache, self._adm_toks, counters = self._step(
+                    "prefill_chunk_jit", chunk_padded.shape,
                     self.params,
                     chunk_padded,
                     np.int32(start),
@@ -2795,7 +2855,8 @@ class Engine:
                 **({"lora_rows": lora_rows_arr} if self._adapters is not None else {}),
             },
         ):
-            toks, lps, t_ids, t_lp, self._cache, self._adm_toks, counters = self._prefill_batch_jit(
+            toks, lps, t_ids, t_lp, self._cache, self._adm_toks, counters = self._step(
+                "prefill_batch_jit", tokens.shape,
                 self.params,
                 tokens,
                 lengths,
@@ -2865,7 +2926,8 @@ class Engine:
                 c_seq, lpc_seq, tid_seq, tlp_seq,
                 self._cache, self._tok_hist, self._lengths, self._last_tokens, self._keys,
                 counters,
-            ) = self._decode_jit(
+            ) = self._step(
+                "decode_jit", (),
                 self.params,
                 self._cache,
                 self._page_table.copy(),
@@ -3514,10 +3576,11 @@ class Engine:
 @dataclass
 class StepFunctions:
     """The engine's jitted step functions, built OUTSIDE the Engine so
-    the cold-start warm compiler (engine/coldstart.py) can construct
-    byte-identical programs from config alone — AOT-compiling these with
-    abstract args populates the persistent compile cache the engine's
-    own first dispatches then hit."""
+    the one list of its step programs (engine/step_programs.py) can
+    build them ONCE from config alone: the warm thread compiles them
+    against abstract args (or loads their executables from the bundle)
+    and the Engine runs those executables; a call the table does not
+    hold goes through these functions and compiles lazily."""
 
     prefill_batch_jit: Any
     prefill_chunk_jit: Any
@@ -3532,10 +3595,10 @@ def build_step_functions(
 ) -> StepFunctions:
     """Build the jitted prefill/decode step functions for a config pair.
 
-    Extracted from Engine so the SAME traced programs can be compiled
-    ahead of time (loader warm, parked replicas, compile/load overlap):
-    identical closures + identical argument shapes ⇒ identical HLO ⇒
-    persistent-compile-cache hits when the real engine first dispatches.
+    Extracted from Engine so the SAME programs can be brought up ahead
+    of time (loader warm, parked replicas, the start's warm thread):
+    engine/step_programs.py::StepPrograms calls this once a deployment
+    and both the warm thread and the Engine use what it returns.
     *n_valid_vocab* is the tokenizer's vocab (logits beyond it are
     masked); defaults to the model vocab (no padding mask)."""
     mc = model_config

@@ -302,14 +302,19 @@ def load_engine_from_path(
 
     Cold-start fast path (single-process): safetensors tensors are
     converted and device_put per-parameter as they are read
-    (stream_params_from_hf) while the step functions AOT-compile on a
-    background thread (engine/coldstart.py), so start costs
-    ~max(load, compile) instead of their sum. Phase stamps land on
-    *timeline* (a fresh one is created and installed at /debug/engine
-    when omitted). Knobs: KUBEAI_STREAM_WEIGHTS=0 restores the
-    whole-checkpoint load; KUBEAI_COLDSTART_OVERLAP is auto (overlap
-    when a persistent compile cache is enabled — the only regime where
-    the background compile pays), 1 forces, 0 disables;
+    (stream_params_from_hf) while the step programs are brought up on a
+    background thread (engine/coldstart.py, engine/step_programs.py):
+    LOADED from the deployment's bundle beside the compile cache where
+    this tree and deployment have started before, lowered and compiled
+    otherwise, so start costs ~max(load, programs) instead of their sum.
+    The Engine is handed that table and runs ITS executables; a start
+    without the thread (tp > 1, a gang, a .bin checkpoint, overlap off)
+    has an empty table and compiles through its jit calls. Phase stamps
+    land on *timeline* (a fresh one is created and installed at
+    /debug/engine when omitted). Knobs: KUBEAI_STREAM_WEIGHTS=0 restores
+    the whole-checkpoint load; KUBEAI_COLDSTART_OVERLAP is auto (overlap
+    when a persistent compile cache is placed: the regime where the
+    thread's work outlives the process), 1 forces, 0 disables;
     KUBEAI_ENGINE_WARMUP=1 pre-dispatches every step shape before
     returning (and the *warmup* arg overrides the env).
 
@@ -347,11 +352,11 @@ def load_engine_from_path(
     if stream is None:
         stream = os.environ.get("KUBEAI_STREAM_WEIGHTS", "1") != "0"
     if overlap is None:
-        # "auto": overlap only pays off through the persistent compile
-        # cache (the AOT executables themselves are not reused by the
-        # engine's jit calls) — without one, a background compile would
-        # burn CPU and delay readiness for nothing. "1" forces it on
-        # (e.g. to validate compilability), "0" off.
+        # "auto": with a persistent compile cache the thread loads
+        # the step programs from the bundle beside it (or compiles and
+        # writes both); without one every start would lower and compile
+        # beside the loader's threads, so the programs are left to the
+        # engine's first calls. "1" forces it on, "0" off.
         knob = os.environ.get("KUBEAI_COLDSTART_OVERLAP", "auto")
         overlap = knob == "1" or (knob != "0" and bool(cache_dir))
     if warmup is None:
@@ -401,7 +406,7 @@ def load_engine_from_path(
         else:
             mesh = make_mesh(tp=tp)
 
-    warmer = None
+    warmer = step_table = None
     if overlap and not multiproc and tp == 1 and source is not None:
         # The padded config the engine will serve with is fully known
         # before any tensor data is read — kick off AOT compilation of
@@ -455,20 +460,22 @@ def load_engine_from_path(
                 params = shard_tree(params, llama_param_specs(config), mesh)
 
     if warmer is not None:
-        # Engine construction and warmup must not race the background
-        # compiles (duplicate compilation of the same programs); by now
-        # the warm has had the whole load to run, so on real checkpoints
-        # this wait is ~max(load, compile) - load.
-        stats = warmer.join()
-        if stats:
-            timeline.attrs["warm_compile"] = stats
+        # The Engine runs the table's executables, so it waits for
+        # them; by now the warm has had the whole load to run, so on real
+        # checkpoints this wait is ~max(load, programs) - load.
+        step_table = warmer.join()
+        if step_table is not None:
+            timeline.attrs["warm_compile"] = step_table.stats
 
     def build(m=None):
         # Engine construction (device-state allocation + jit wrapper
         # setup) gets its own stamp so the phase timeline has no
         # unattributed gap between compile and warmup.
         timeline.begin("build")
-        eng = Engine(config, params, tokenizer, ec, mesh=m, publisher=publisher)
+        eng = Engine(
+            config, params, tokenizer, ec, mesh=m, publisher=publisher,
+            step_table=step_table,
+        )
         timeline.end("build")
         if warmup and not multiproc:
             with timeline.phase("warmup"):

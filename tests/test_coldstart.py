@@ -221,8 +221,17 @@ def test_warm_compile_populates_persistent_cache(ckpt_dir, tmp_path, compile_cac
     assert stats["shapes"] > 0
     assert not stats.get("errors")
     entries = [f for f in os.listdir(cache) if f.endswith("-cache")]
-    # Every warmed shape must have landed on disk (min-compile-secs=0).
+    # Every warmed shape must have landed on disk (min-compile-secs=0),
     assert len(entries) >= stats["shapes"]
+    # and in the deployment's bundle beside jax's entries, from which the
+    # loader Job's next run (and the serving pod's start) loads them all.
+    assert [os.path.join(cache, f) for f in os.listdir(cache) if f.endswith(".bundle")] == [stats["bundle"]["path"]]
+    again = warm_from_checkpoint(
+        ckpt_dir,
+        ["--max-slots", "2", "--max-seq-len", "64"],
+        include_group=False,
+    )
+    assert (again["loaded"], again["compiled"]) == (stats["shapes"], 0)
 
 
 def test_warm_compile_reports_failures_not_raises():
@@ -258,23 +267,38 @@ def test_compile_overlaps_load_smoke(ckpt_dir):
     assert snap["attrs"]["warm_compile"]["shapes"] > 0
 
 
-def test_warmup_covers_all_shapes_and_engine_serves(ckpt_dir):
+@pytest.mark.parametrize("overlap", [False, True], ids=["an empty table", "the warm thread's table"])
+def test_warmup_covers_all_shapes_and_engine_serves(ckpt_dir, overlap):
     from kubeai_tpu.engine.sampling import SamplingParams
+    from kubeai_tpu.engine.step_programs import StepPrograms
     from kubeai_tpu.engine.weights import load_engine_from_path
 
     eng = load_engine_from_path(
         ckpt_dir, TINY_EC, dtype="float32",
-        stream=True, overlap=False, warmup=True,
+        stream=True, overlap=overlap, warmup=True,
     )
-    stats = eng.cold_start_timeline.snapshot()["attrs"]["warmup"]
-    # decode + (1, cap) x 2 buckets + chunk x 2 buckets (the final
-    # chunk of a chunked prefill pads to the smallest fitting bucket,
-    # so every bucket is a live chunk shape) + the wide chunk of 32
-    # rows (a prompt of TINY_EC's 64 positions reaches it:
-    # core.prefill_plan) = 8 shapes for TINY_EC,
+    attrs = eng.cold_start_timeline.snapshot()["attrs"]
+    # The one list (engine/step_programs.py), which warm_compile fills
+    # the table from and warmup() walks: decode + (1, cap) x 2 buckets +
+    # chunk x 2 buckets (the final chunk of a chunked prefill pads to the
+    # smallest fitting bucket, so every bucket is a live chunk shape) +
+    # the wide chunk of 32 rows (a prompt of TINY_EC's 64 positions
+    # reaches it: core.prefill_plan) = 8 shapes for TINY_EC,
+    programs = len(StepPrograms(eng.model_config, TINY_EC).calls())
+    assert programs == 8
     # + 4 restore-path shapes (KV evolve, import pow2 1 and 2,
     # slotset) on a single-host engine with KV restore enabled.
-    assert stats["shapes"] == 12
+    assert attrs["warmup"]["shapes"] == programs + 4 == 12
+    if overlap:
+        # Every program came up ONCE, on the warm thread, and warmup()
+        # ran it: none went through the engine's jitted functions too.
+        warm = attrs["warm_compile"]
+        assert (warm["shapes"], warm["compiled"], warm["loaded"]) == (programs, programs, 0)
+        assert len(eng._table.held) == programs and eng._jit_cache_entries() == 0
+    else:
+        assert "warm_compile" not in attrs
+        assert not eng._table.held and eng._jit_cache_entries() == programs
+    assert eng._jit_entries_seen == programs  # jit_recompiles_total, either way
     eng.start()
     try:
         ids, _, fin = eng.generate(

@@ -101,8 +101,10 @@ def test_the_programs_carry_the_names_the_trace_readers_select_by():
     # (it is a jitted partial) and the prefill ones by their functions'.
     names = [name for _, name in _warm_programs(2)]
     assert names[0] == "jit__unknown"
-    assert set(names[1:-1]) == {"jit_prefill_batch_fn"} and len(names) > 2
-    assert names[-1] == "jit_prefill_chunk_fn"
+    # ... in the one list's order (engine/step_programs.py): two buckets x
+    # (one row, the group cap), then a chunk call a bucket.
+    assert names[1:5] == ["jit_prefill_batch_fn"] * 4
+    assert names[5:] == ["jit_prefill_chunk_fn"] * 2
 
 
 def test_the_decode_chunk_returns_four_fetched_arrays_then_five_carries():

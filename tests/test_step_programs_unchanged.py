@@ -8,7 +8,11 @@ this file's own `digests()`: the dense, `deepseek_v3` and `smallthinker`
 ones at the parent of PR 40 (commit c004975), `nemotron_h`'s at the parent
 of PR 42 (commit 1b02cb5), `afmoe`'s as PR 42 left its module; PR 44 took
 the four expert families' again (it MEANT to change them: the way back of
-`ops/moe.py`, `_back_to_tokens`; the dense family's stayed as they were):
+`ops/moe.py`, `_back_to_tokens`; the dense family's stayed as they were);
+PR 48 added one digest a family, the chunk call of the SMALLER bucket, which
+`warmup()` always ran and the warm compile's own list had left out (the one
+list of engine/step_programs.py has both's coverage); the six that were
+there kept theirs:
 
     JAX_PLATFORMS=cpu python tests/test_step_programs_unchanged.py > tests/data/step_program_digests.json
 
@@ -38,7 +42,7 @@ DIGESTS = os.path.join(HERE, "data", "step_program_digests.json")
 
 def digests(family: str) -> list[str]:
     """sha256 of every program the warm compile lowers for the family's toy
-    configuration: the decode chunk, 2 buckets x 2 sizes, the chunk."""
+    configuration: the decode chunk, 2 buckets x 2 sizes, a chunk call a bucket."""
     return [hashlib.sha256(text.encode()).hexdigest() for text, _ in scopes._lowered_programs(True, FAMILIES[family])]
 
 
