@@ -67,7 +67,7 @@ from kubeai_tpu.obs.recorder import (
 )
 from kubeai_tpu.obs.logs import get_logger, trace_extra
 from kubeai_tpu.obs.trace import RequestTrace, TraceContext
-from kubeai_tpu.ops import mla_attention, moe, paged_attention, ssm
+from kubeai_tpu.ops import chunk_attention, mla_attention, moe, paged_attention, ssm
 from kubeai_tpu.qos import QoSQueue, record_admitted, record_preemption
 from kubeai_tpu.qos import install_queue as qos_install_queue
 from kubeai_tpu.qos import uninstall_queue as qos_uninstall_queue
@@ -600,6 +600,15 @@ class Engine:
             "only, from each call's start, tokens and the window; counted "
             "for a family with window layers",
         )
+        self.m_attn_pairs_walked = default_registry.counter(
+            "kubeai_engine_attn_pairs_walked_total",
+            "(query, key) pairs the chunk kernel SCORES (ops/chunk_attention.py: "
+            "whole KV blocks for whole query tiles, padded rows too) for the "
+            "prefill calls it takes, summed over the layers of a kind (full | "
+            "window), phase prefill: from each call's rows, start and the "
+            "window; attn_pairs_total of the same calls over it is the share "
+            "of the kernel's work inside the mask",
+        )
         # State kept by slot beside the pages (a family with recurrent
         # layers, models/nemotron_h.py; 0 / 0 for every other): there is no
         # allocator, so a slot's state is in use while the slot is.
@@ -858,6 +867,15 @@ class Engine:
             # given, per call shape this process has traced (none where
             # every program was loaded from the bundle: nothing is traced).
             "paged_kernel_blocks": dict(paged_attention.chosen_blocks),
+            # (query rows, keys) a tile and a block of the chunk kernel, per
+            # call shape likewise, and by kind of layer the share of the
+            # pairs it scored that lay inside the mask (attn_pairs_total over
+            # attn_pairs_walked_total of the prefill calls it took; a family
+            # with window layers counts them).
+            "chunk_kernel_tiles": dict(chunk_attention.chosen_tiles),
+            "chunk_kernel_hit_share": {
+                kind: round(inside / walked, 4) for kind, (inside, walked) in self._chunk_pairs.items() if walked
+            },
             # Likewise: the MLA decode kernel's pages a block, ring and form; (tm, tk, tn) of the grouped
             # matmul; the heads of a slot a program of the state-space step kernel holds (empty: portable).
             "mla_kernel_blocks": dict(mla_attention.chosen_blocks),
@@ -1014,6 +1032,9 @@ class Engine:
         self._w_pos = np.zeros((B,), np.int64)
         # (full layers, window layers): what _count_attn_pairs multiplies by.
         self._kind_layers = family(self.model_config).layer_kinds(self.model_config) if window else (0, 0)
+        # By kind, [inside the mask, scored] over the prefill calls the chunk
+        # kernel takes: the hit share of /debug/engine's perf section.
+        self._chunk_pairs = {"full": [0, 0], "window": [0, 0]}
         self._window_released_seen = 0  # of WindowPages.released, already in the counter
         # Per-slot request state is HOST-authoritative numpy, uploaded
         # with every decode dispatch (the arrays ride the execute RPC —
@@ -2418,11 +2439,14 @@ class Engine:
         if "moe_absent" in counters:
             self.m_moe_absent.inc(int(counters["moe_absent"]), labels=labels)
 
-    def _count_attn_pairs(self, phase: str, starts: np.ndarray, n: int) -> None:
+    def _count_attn_pairs(self, phase: str, starts: np.ndarray, n: int, rows: int = 0) -> None:
         """kubeai_engine_attn_pairs_total for calls of *n* real queries
         a row behind *starts* [rows] cached tokens: a query at position
         p sees p + 1 keys in a full layer and min(p + 1, window) in a
-        window layer. Exact, on the host, no sync."""
+        window layer. Exact, on the host, no sync. With *rows*, the
+        rows a slot of a prefill call that the chunk kernel takes (every
+        one but a cold call on the flash route), also what that kernel
+        scores for it: kubeai_engine_attn_pairs_walked_total."""
         W = self._wpages.window
         n_full, n_window = self._kind_layers
         a, b = starts.astype(np.int64) + 1, starts.astype(np.int64) + n  # p + 1 runs a..b
@@ -2433,6 +2457,14 @@ class Engine:
         self.m_attn_pairs.inc(int(n_window * window), labels={"kind": "window", "phase": phase})
         if phase == "decode":
             self._pairs_window.add(float(n_full * full + n_window * window))
+        if rows:
+            mc, page = self.model_config, self.cfg.page_size
+            tiles = chunk_attention.kernel_tiles(rows, mc.num_heads // mc.num_kv_heads, page, self._max_pages)
+            for kind, layers, inside, reach in (("full", n_full, full, None), ("window", n_window, window, W)):
+                walked = layers * sum(chunk_attention.pairs_walked(rows, int(p), reach, *tiles, page) for p in starts)
+                self.m_attn_pairs_walked.inc(walked, labels={"kind": kind, "phase": phase})
+                self._chunk_pairs[kind][0] += int(layers * inside)
+                self._chunk_pairs[kind][1] += walked
 
     def _emit_admitted(self, admitted: list) -> None:
         """One host sync for all first tokens of an admission round —
@@ -2660,7 +2692,7 @@ class Engine:
                 # first query's window go back, the chunk's own come.
                 self._wpages.advance(slot_idx, start, start + bucket)
                 table = self._page_table[slot_idx : slot_idx + 1].copy()
-                self._count_attn_pairs("prefill", np.asarray([start]), len(chunk))
+                self._count_attn_pairs("prefill", np.asarray([start]), len(chunk), rows=bucket)
             chunk_padded = np.zeros((1, bucket), np.int32)
             chunk_padded[0, : len(chunk)] = chunk
             with self._lockstep(
@@ -2821,6 +2853,7 @@ class Engine:
         bias_ids = np.zeros((n, self.cfg.max_logit_bias), np.int32)
         bias_vals = np.zeros((n, self.cfg.max_logit_bias), np.float32)
         lora_rows_arr = np.zeros((n,), np.int32)
+        chunk_kernel_rows = 0 if self._attn_kernel("prefill_group", bucket) == "flash" else bucket
         for j, (slot_idx, req) in enumerate(items):
             ids = req.prompt_ids
             sp = req.params
@@ -2828,7 +2861,7 @@ class Engine:
             lengths[j] = len(ids)
             if self._wpages is not None:
                 self._wpages.advance(slot_idx, 0, bucket)
-                self._count_attn_pairs("prefill", np.zeros((1,), np.int64), len(ids))
+                self._count_attn_pairs("prefill", np.zeros((1,), np.int64), len(ids), rows=chunk_kernel_rows)
             tables[j] = self._page_table[slot_idx]
             slots_arr[j] = slot_idx
             seeds[j] = self._seed32(sp, j)

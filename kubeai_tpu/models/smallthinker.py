@@ -37,22 +37,29 @@ whatever its length (`chunk`: the widest chunk call, `engine/core.py::
 wide_chunk`, 2048 rows where a prompt can be that long: 97 pages at the
 published window), while its full pages grow with it.
 
-**A window layer reads its window.** The kernel's `sliding_window` only
-masks: it would still copy every page up to the sequence's end. So the
+**A window layer reads its window.** At decode the kernel's
+`sliding_window` only masks (the library's ragged kernel, one query row a
+slot): it would still copy every page up to the sequence's end. So the
 window layers hand it the table FROM the first page a query of this call
 can see (`first = max(0, pos0 - window + 1) // page`, a gather of
 `(S + window - 2) // page + 2` columns) with the lengths shifted by
 `first * page`: every mask of the kernel is relative (a query sits at
 `kv_len - S + i`), keys carry their rope from when they were written, so
-nothing else moves. The portable route gathers the same columns.
+nothing else moves. The portable route gathers the same columns, and a
+chunk's kernel (`ops/chunk_attention.py`, more than one row a slot) is
+handed them too, one way for every route: it starts each query tile at
+the page of the first key the tile can see and stops at its last row's
+block on its own, wherever the table starts, so behind the shift it
+walks what it would walk over the whole table.
 
 **Routes** (`cached_attention_route`): cold prefill of 256 rows or more
 takes the flash kernel for both kinds where the call is no longer than
 the window (the window mask is then all true; the engine's largest
 bucket, 1024, the longest cold call, is a quarter of the published
 window); decode, chunks
-behind cached tokens and every other cold call take the ragged paged
-kernel on the chip ("paged_kernel"); everything on the CPU the portable
+behind cached tokens and every other cold call take the paged kernels on
+the chip ("paged_kernel": `ops/paged_attention.py`, the library's ragged
+kernel for one row a slot and the repo's chunk kernel for more); everything on the CPU the portable
 gather ("xla") unless a test asks for the kernel's twin.
 
 **What is limited for this family, stated here once.**
@@ -211,7 +218,7 @@ def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, page
     """The attention implementation a cached call of *S* queries a row
     takes, for both kinds of layer: "flash" (cold prefill of whole
     256-row tiles, no longer than the window: the window mask is all
-    true), "paged_kernel" (the ragged kernel over pages in place) or
+    true), "paged_kernel" (`ops/paged_attention.py` over pages in place) or
     "xla" (the portable gather of the same pages)."""
     if config.use_flash_prefill and left_aligned and S >= 256 and S % 256 == 0 and S <= config.sliding_window_size:
         return "flash"

@@ -1,13 +1,23 @@
 """Paged attention for TPU: in-place page reads for prefill and decode.
 
-Wraps JAX's ragged-paged-attention Pallas kernel (the vLLM-TPU
-workhorse): KV lives as [P, page, 2*Kv, h] pages with K/V interleaved
-on the head axis, a block table maps each slot's positions onto pages,
-and queries of ANY length per slot (1 for decode, a few for a caller
-that scores several tokens a step, a whole bucket for prefill) attend
-causally with pages streamed HBM->VMEM — no gathered contiguous copy of
-the KV span (the portable XLA path in models/llama.py gathers;
-acceptable on CPU tests, wasteful on a bandwidth-bound TPU).
+KV lives as [P, page, 2*Kv, h] pages with K/V interleaved on the head
+axis, a block table maps each slot's positions onto pages, and queries
+of ANY length per slot (1 for decode, a few for a caller that scores
+several tokens a step, a whole bucket for prefill) attend causally with
+pages streamed HBM->VMEM — no gathered contiguous copy of the KV span
+(the portable XLA path in models/llama.py gathers; acceptable on CPU
+tests, wasteful on a bandwidth-bound TPU).
+
+Two kernels on the chip, chosen by what the call is. One query row a
+slot (every decode call) runs JAX's ragged-paged-attention Pallas kernel
+(the vLLM-TPU workhorse), which walks each query block from the table's
+first key to the sequence's end and only masks: right for a row that
+sees all of it. More than one row a slot (a prefill chunk behind cached
+tokens, a cold call the flash route does not take) runs the repo's own
+`ops/chunk_attention.py`, in which a query tile visits only the KV
+blocks its own mask intersects; a pool it does not read (anything but
+bf16 pages of 128-wide heads: a quantized pool above all, which needs
+its pages dequantized in VMEM) stays on the library kernel.
 
 At decode (one query row a slot) a caller whose live rows come first
 hands in their count (`live_rows`, `models/base.py::LiveRows`): it is the
@@ -23,6 +33,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from kubeai_tpu.ops import chunk_attention
 
 
 # What kernel_blocks chose, per compiled call shape: filled at trace
@@ -40,31 +52,20 @@ def kernel_blocks(S: int, G: int, pages_per_seq: int, page: int) -> tuple[int, i
     and (b) scores the whole query block against every sequence that
     touches it.
 
+    This is the decode rule: since PR 49 a call of more rows a slot is
+    the library kernel's only over a pool `ops/chunk_attention.py` does
+    not read, which no cell runs, and takes the same blocks.
     Queries: one slot's rows a block, so at decode no slot's row meets
-    another slot's keys; a long chunk is cut where the score tile
+    another slot's keys; more rows are cut where the score tile
     (queries x G rows) reaches 256 rows: more rows gained under 3% and
     the compiler's time grows faster than the tile (27 s at 896 rows).
     KV: 512 tokens a block, which a short context does not copy far
-    past its end, and at least twice the query rows a slot, since a
-    chunk sits behind that many keys or more and every block turn costs
-    what scoring 300 (G=4) to 1300 (G=7) keys does; never more than
-    2048 tokens, which is what a chunk of 1024 rows takes and what the
-    wide chunk of 2048 rows (engine/core.py::prefill_plan) keeps: every
-    query block walks every KV block up to the call's LAST row, whole
-    blocks, so 4096-token blocks cost a call that ends 5k tokens in a
-    block's worth of masked keys a query block. Swept on a v5e at
-    page 64 (PERF.md section 6, PR 30): at decode 8 pages x 1 query is
-    the fastest or within 4% of it from 32 slots x 350 tokens (59 us a
-    call against 377) to 8 slots x 8000; an fp8 pool liked 4 pages 7%
-    better. At 2048 rows (PERF.md section 6, PR 43; G = 4, 7, 8, 16
-    behind 2-22k cached tokens): 32 pages a block read from 24% under
-    64 pages (behind 3-4k) to 5% over them (behind 12-22k), and 2-9%
-    under two calls of 1024 rows at every shape but one (G = 4 starting
-    3072 tokens in, a start no plan gives a cold prompt: +12%); twice
-    the query rows a block gained under 2%."""
+    past its end. Swept on a v5e at page 64 (PERF.md section 6, PR 30):
+    at decode 8 pages x 1 query is the fastest or within 4% of it from
+    32 slots x 350 tokens (59 us a call against 377) to 8 slots x 8000;
+    an fp8 pool liked 4 pages 7% better."""
     queries = min(S, 1 << (max(1, 256 // G).bit_length() - 1))
-    kv_pages = max(512, min(2 * S, 2048)) // page
-    return max(1, min(kv_pages, pages_per_seq)), queries
+    return max(1, min(512 // page, pages_per_seq)), queries
 
 
 def paged_attention_ragged(
@@ -76,14 +77,15 @@ def paged_attention_ragged(
     softcap: float = 0.0,
     k_scale: float | None = None,  # static dequant scales for quantized
     v_scale: float | None = None,  # (int8/fp8) pools; None = pool is bf16
-    blocks: tuple[int, int] | None = None,  # a sweep's (kv pages, queries); serving leaves it None
+    blocks: tuple[int, int] | None = None,  # a sweep's (kv pages, queries) for the LIBRARY kernel, whatever S; serving leaves it None
     sliding_window: int | None = None,  # static: a query sees keys j > i - sliding_window only
     live_rows: jnp.ndarray | None = None,  # [] int32, S == 1 only: rows [0, live_rows) hold requests
 ) -> jnp.ndarray:
     """Returns [B, S, H, h] attention output. With a quantized pool the
-    kernel dequantizes pages in-VMEM (x.astype(f32) * scale -> q.dtype),
-    so HBM page traffic stays 8-bit. With *live_rows* the kernel walks
-    that many table rows and the rest of the output is zeros."""
+    library kernel (at every S: the chunk kernel reads bf16 pages only)
+    dequantizes pages in-VMEM (x.astype(f32) * scale -> q.dtype), so HBM
+    page traffic stays 8-bit. With *live_rows* the kernel walks that
+    many table rows and the rest of the output is zeros."""
     B, S, H, h = q.shape
     if live_rows is not None and S != 1:
         raise ValueError(f"live_rows is for calls of one query row a slot, not S={S}")
@@ -91,6 +93,17 @@ def paged_attention_ragged(
     page, Kv = kv_pages.shape[1], kv_pages.shape[2] // 2
     if scale is None:
         scale = h**-0.5
+    if S > 1 and blocks is None and jax.default_backend() != "cpu" and chunk_attention.reads(q, kv_pages, page_table, k_scale, v_scale):
+        tile, kv_block = chunk_attention.kernel_tiles(S, H // Kv, page, max_pages)
+        chunk_attention.chosen_tiles[
+            f"B={B} S={S} H={H} Kv={Kv} pages={max_pages}x{page}" + (f" window={sliding_window}" if sliding_window else "")
+        ] = {"query_tile": tile, "kv_block": kv_block}
+        # The overrun guard below, and never fewer keys than rows (a
+        # row's position is its distance from the last key).
+        return chunk_attention.chunk_attention_kernel(
+            q, kv_pages, page_table, jnp.clip(kv_lengths, S, max_pages * page),
+            scale=float(scale), softcap=float(softcap), sliding_window=sliding_window or None,
+        )
 
     q_flat = q.reshape(B * S, H, h)
     cu_q_lens = (jnp.arange(B + 1, dtype=jnp.int32) * S)
@@ -107,8 +120,8 @@ def paged_attention_ragged(
         # walked as every idle row was, and masked below like the rest.
         num_seqs = jnp.clip(live_rows, 1, B).astype(jnp.int32).reshape(1)
 
-    # The kernel's window only masks: it copies every page from the
-    # table's first to the sequence's end, so a caller with a window
+    # The library kernel's window only masks: it copies every page from
+    # the table's first to the sequence's end, so a caller with a window
     # hands in the table from the first page inside it, lengths shifted
     # (models/smallthinker.py).
     tuning = {"sliding_window": sliding_window} if sliding_window else {}

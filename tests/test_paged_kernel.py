@@ -143,13 +143,18 @@ _BLOCK_CASES = {
 
 @pytest.mark.parametrize("case", list(_BLOCK_CASES), ids=list(_BLOCK_CASES))
 def test_kernel_blocks_are_chosen_from_the_calls_shapes(monkeypatch, case):
-    """The (kv pages, queries) pair the library kernel is given is
-    kernel_blocks' choice from the call's own shapes and dtypes: inside
-    what the kernel accepts, recorded for /debug/engine, and at decode
+    """One query row a slot (and any call over a pool the chunk kernel
+    does not read): the (kv pages, queries) pair the library kernel is
+    given is kernel_blocks' choice from the call's own shapes and dtypes,
+    inside what the kernel accepts, recorded for /debug/engine, and
     smaller than the library default it replaces (128 pages x 32
     queries, clipped to the table's width and the call's rows: a block
-    of 32 slots scored against every slot's whole table)."""
+    of 32 slots scored against every slot's whole table). More rows a
+    slot over a bf16 pool: the repo's own chunk kernel, its tiles from
+    the call's shapes and on record likewise, and the library kernel is
+    not called."""
     import kubeai_tpu.ops.paged_attention as pa
+    from kubeai_tpu.ops import chunk_attention
 
     lib = pytest.importorskip("jax.experimental.pallas.ops.tpu.ragged_paged_attention")
     H, Kv, B, S, max_len, pool_dtype = _BLOCK_CASES[case]
@@ -161,9 +166,15 @@ def test_kernel_blocks_are_chosen_from_the_calls_shapes(monkeypatch, case):
         seen["blk"] = (num_kv_pages_per_block, num_queries_per_block)
         return q_flat
 
+    def fake_chunk_kernel(q, kv, table, lens, **kw):
+        seen["chunk"] = kw
+        return q
+
     monkeypatch.setattr(lib, "ragged_paged_attention", fake_kernel)
+    monkeypatch.setattr(chunk_attention, "chunk_attention_kernel", fake_chunk_kernel)
     monkeypatch.setattr(pa.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(pa, "chosen_blocks", {})
+    monkeypatch.setattr(chunk_attention, "chosen_tiles", {})
     quant = {} if pool_dtype == jnp.bfloat16 else {"k_scale": 1.0, "v_scale": 1.0}
     args = (
         jax.ShapeDtypeStruct((B, S, H, h), jnp.bfloat16),
@@ -175,30 +186,32 @@ def test_kernel_blocks_are_chosen_from_the_calls_shapes(monkeypatch, case):
         lambda q, kv, tbl, lens: pa.paged_attention_ragged(q, kv, tbl, lens, **quant), *args
     )
     assert out.shape == (B, S, H, h)
-    kv_pages, queries = seen["blk"]
-    assert (kv_pages, queries) == pa.kernel_blocks(S, H // Kv, mp, ps)
-    assert 0 < kv_pages <= mp
-    assert 0 < queries <= B * S
-    # One entry a compiled call shape, the pair as the kernel got it.
-    assert list(pa.chosen_blocks.values()) == [(kv_pages, queries)]
-    default = (min(mp, 128), min(B * S, 32))
-    if S <= 4:
+    if S > 1:
+        # The chunk kernel, with the scale and no window; nothing of the library's.
+        assert "blk" not in seen and pa.chosen_blocks == {}
+        assert seen["chunk"] == {"scale": h**-0.5, "softcap": 0.0, "sliding_window": None}
+        tile, kv_block = chunk_attention.kernel_tiles(S, H // Kv, ps, mp)
+        assert list(chunk_attention.chosen_tiles.values()) == [{"query_tile": tile, "kv_block": kv_block}]
+        assert S % tile == 0 and kv_block == 256
+        # 256 query rows a tile, 512 where four heads share a KV head (PERF.md section 6, PR 49).
+        assert tile == min(S, {4: 512, 7: 256, 16: 256, 8: 256}[H // Kv])
+    else:
+        kv_pages, queries = seen["blk"]
+        assert (kv_pages, queries) == pa.kernel_blocks(S, H // Kv, mp, ps) == (8, 1)
+        # One entry a compiled call shape, the pair as the kernel got it.
+        assert list(pa.chosen_blocks.values()) == [(kv_pages, queries)]
         # One slot a query block: no slot's rows are scored against
         # another slot's keys.
-        assert queries == S
-        assert kv_pages < default[0] and queries < default[1]
-    if S == 2048:
-        # The wide chunk keeps the KV block of a 1024-row chunk, 2048
-        # tokens, and a query block of 256 score rows (PERF.md section 6, PR 43).
-        assert kv_pages == 32 == pa.kernel_blocks(1024, H // Kv, mp, ps)[0]
-        assert queries == {4: 64, 7: 32, 8: 32, 16: 16}[H // Kv]
-    # A sweep's pair goes through as given and leaves no record.
+        assert kv_pages < min(mp, 128) and queries < min(B * S, 32)
+        assert "chunk" not in seen and chunk_attention.chosen_tiles == {}
+    # A sweep's pair goes to the library kernel as given, whatever S, and leaves no record.
+    before = len(pa.chosen_blocks)
     jax.eval_shape(
         lambda q, kv, tbl, lens: pa.paged_attention_ragged(q, kv, tbl, lens, blocks=(2, 8), **quant),
         *args,
     )
     assert seen["blk"] == (2, 8)
-    assert len(pa.chosen_blocks) == 1
+    assert len(pa.chosen_blocks) == before
 
 
 def test_wrapper_clamps_overrun_lengths():

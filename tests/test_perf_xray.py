@@ -447,6 +447,26 @@ class TestEngineWiring:
         finally:
             eng.stop()
 
+    def test_debug_engine_serves_the_chunk_kernel_tiles(self, monkeypatch):
+        """Likewise the (query rows, keys) ops/chunk_attention.py was given
+        for each call shape of more than one row a slot; the hit share
+        beside it is empty for a family that counts no attention pairs."""
+        from kubeai_tpu.engine.core import build_test_engine
+        from kubeai_tpu.obs.recorder import handle_debug_request
+        from kubeai_tpu.ops import chunk_attention
+
+        shape = "B=1 S=2048 H=28 Kv=4 pages=256x64 window=4096"
+        monkeypatch.setattr(chunk_attention, "chosen_tiles", {shape: {"query_tile": 256, "kv_block": 256}})
+        eng = build_test_engine()
+        try:
+            code, _, body = handle_debug_request("/debug/engine", "limit=1")
+            assert code == 200
+            perf = json.loads(body)["perf"]
+            assert perf["chunk_kernel_tiles"] == {shape: {"query_tile": 256, "kv_block": 256}}
+            assert perf["chunk_kernel_hit_share"] == {}
+        finally:
+            eng.stop()
+
     def test_stop_unregisters_perf_section(self):
         """stop() must unpin the engine from the process-global debug
         registry (it holds the KV pool + jit caches via the bound

@@ -489,6 +489,36 @@ def test_param_counts_are_the_benchmarks_counts(ckpt):
     assert counts.kv_bytes_per_token(published, 2) == {"full": 3 * 2048, "window": 9 * 2048}
 
 
+@pytest.mark.parametrize("start,real,rows", [(8192, 32, 32), (8192, 20, 32), (0, 8, 8), (100, 16, 16)])
+def test_walked_pairs_are_counted_beside_the_pairs_of_a_prefill_call(eng, start, real, rows):
+    """kubeai_engine_attn_pairs_walked_total: what the chunk kernel scores
+    for a prefill call it takes, from the call's rows, start and the
+    window (`ops/chunk_attention.py::pairs_walked`): whole blocks for
+    every row, padded ones too, so never under the pairs inside the mask;
+    and behind 8192 keys a window layer's call walks far fewer than
+    rows x keys, where the library's kernel walked every one."""
+    from kubeai_tpu.ops import chunk_attention
+
+    pairs = lambda kind: eng.m_attn_pairs.value(labels={"kind": kind, "phase": "prefill"})  # noqa: E731
+    walked = lambda kind: eng.m_attn_pairs_walked.value(labels={"kind": kind, "phase": "prefill"})  # noqa: E731
+    before = {kind: (pairs(kind), walked(kind), *eng._chunk_pairs[kind]) for kind in ("full", "window")}
+    eng._count_attn_pairs("prefill", np.asarray([start]), real, rows=rows)
+    tiles = chunk_attention.kernel_tiles(rows, 2, PAGE, MAX_PAGES)
+    for kind, layers, reach in (("full", 2, None), ("window", 6, WINDOW)):
+        inside, scored = pairs(kind) - before[kind][0], walked(kind) - before[kind][1]
+        assert scored == layers * chunk_attention.pairs_walked(rows, start, reach, *tiles, PAGE) >= inside > 0
+        assert (eng._chunk_pairs[kind][0] - before[kind][2], eng._chunk_pairs[kind][1] - before[kind][3]) == (inside, scored)
+    if start == 8192:
+        assert walked("window") - before["window"][1] < 6 * rows * (start + rows) // 16
+        assert walked("full") - before["full"][1] <= 2 * rows * (start + rows + tiles[1])
+    share = eng._perf_debug_section()["chunk_kernel_hit_share"]
+    assert set(share) == {"full", "window"} and all(0 < v <= 1 for v in share.values())
+    # A call the flash kernel takes (rows=0) moves the pairs alone.
+    scored = walked("full")
+    eng._count_attn_pairs("prefill", np.asarray([0]), 8)
+    assert walked("full") == scored
+
+
 def test_attention_pairs_are_counted_from_start_tokens_and_window(eng):
     """kubeai_engine_attn_pairs_total against a loop over queries."""
     def brute(starts, n):

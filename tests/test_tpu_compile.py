@@ -620,3 +620,42 @@ def test_the_expert_layers_way_back_holds_no_token_choice_width_array(way_back_p
     print(f"{family} T={T}: temp_size_in_bytes {temporaries:,}, widest float32 array {widest:,} elements")
     if T == 2048:
         assert temporaries <= T * k * D * 2 + 2 * way_back_program(family, 1024).memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize(
+    "heads,B,S,columns,window,softcap",
+    [
+        (QWEN25_7B, 1, 2048, (2048 + 4096 - 2) // PAGE + 2, 4096, 0.0),
+        (QWEN25_7B, 1, 1024, 16384 // PAGE, None, 0.0),
+        ((32, 4), 1, 2048, 32768 // PAGE, None, 0.0),
+        (LLAMA3_8B, 1, 2048, 8192 // PAGE, None, 0.0),
+        (NEMOTRON_ATTN, 1, 2048, 8192 // PAGE, None, 0.0),
+        (QWEN25_7B, 8, 32, (32 + 4096 - 2) // PAGE + 2, 4096, 0.0),
+        (QWEN25_7B_TP4, 8, 512, 2048 // PAGE, None, 30.0),
+        (QWEN25_7B, SLOTS, 3, 2048 // PAGE, None, 0.0),
+    ],
+    ids=[
+        "smallthinker/chunk-2048/window-97-pages", "smallthinker/chunk-1024/full", "trinity/chunk-2048/full-512-pages",
+        "mistral-7b/chunk-2048", "nemotron/chunk-2048", "smallthinker/cold-8x32/window", "qwen2.5-7b/tp4/8x512-softcap",
+        "qwen2.5-7b/verify-S3",
+    ],
+)
+def test_chunk_kernel_lowers(v5e, monkeypatch, heads, B, S, columns, window, softcap):
+    """More than one query row a slot over a bf16 pool is the repo's own
+    kernel (ops/chunk_attention.py), at every grouping the cells run (G =
+    4 at 512 query rows a tile; 7, 8 and 16 at 256), with and without a
+    window, at the smallest bucket, with a softcap, and at a caller's few
+    rows a slot: the tiles it was given are on record, and the program
+    holds its custom call and not the library's."""
+    from kubeai_tpu.ops import chunk_attention
+
+    monkeypatch.setattr(chunk_attention, "chosen_tiles", {})
+    q, pool, _, lens = _paged_args(v5e[0], B, S, heads, max_len=columns * PAGE)
+    table = _sds(v5e, (B, columns), jnp.int32)
+    text = _compile(
+        lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, sliding_window=window, softcap=softcap),
+        q, pool, table, lens,
+    )
+    assert "tpu_custom_call" in text and "chunk_attention_kernel" in text and "ragged_paged_attention_kernel" not in text
+    tile = min(S, 512 if heads[0] // heads[1] <= 4 else 256)
+    assert list(chunk_attention.chosen_tiles.values()) == [{"query_tile": tile, "kv_block": 256}]
