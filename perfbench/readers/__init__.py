@@ -21,17 +21,33 @@ every metric that divides counters by times of the trace, so that both are
 of the same seconds (PR 46), and none that is counters over the window alone
 (`window_mfu.*`).
 
-How a cell gets its metrics. An entry of `per_layer` in BENCHMARK.json is
-ONE measurement: a file under layer_metrics/ (reader + parameters), what it
-moves, and the cells that report it. A PR that adds a cell may not edit an
-accepted entry, so it adds an entry of its own (a twin: the accepted file
-under a new suffix) ONLY for a measurement whose accepted entry it cannot
-edit, and says in CHANGES.md which accepted entry each twin copies; the next
-`benchmark` PR folds the twins into their entry's `workloads` list.
-`per_layer` may hold 128 entries and held 128 when this was written, 56 of
-them twins (PERF.md sections 3 and 7: the groups, and what stops the fold).
-A new reading that needs a family's own counts should take the counts
-module from the configuration's `model_type` inside ONE reader, not come as
-a reader a family (`moe_` / `swa_` / `ssm_` / `afm_rooflines.py` are four
-copies of one reading).
+How a cell gets its metrics (since the fold, PR 50). An entry of
+`per_layer` in BENCHMARK.json is ONE measurement: a file under
+layer_metrics/ (reader + parameters), what it moves, and the cells that
+report it (`workloads`; every entry has the list, so a new cell takes none
+by default). No two entries are the same file with the same `moves`, `unit`,
+`better`, `source` and `layer`: selftest.py refuses a second name. So a cell
+comes to its metrics in two ways:
+ * a measurement the benchmark does not have yet comes as a NEW entry with a
+   new file (appended; 72 of the 128 entries were taken when this was
+   written, selftest.py prints how many are free);
+ * a measurement it has is JOINED: the cell's name goes into the accepted
+   entry's `workloads`, under the entry's name, and only where one traced
+   run on the chip shows that the reader reads a number on that cell's
+   program. That is an edit of an accepted entry: a `benchmark` PR may make
+   it; a PR of another kind makes it where the driver lets it (the ledger's
+   `benchmark_edited`), and where it may not it leaves the measurement out,
+   says so in CHANGES.md and lists it in PERF.md section 7 for the next
+   `benchmark` PR. It does NOT copy the file under a suffix (56 such second
+   names were folded away: CHANGES.md, PR 50, has old name -> new name for
+   a reader of older ledger lines).
+A suffix that is left tells apart entries that differ in `moves` (`.rate`,
+`.lat`: chat-rate's move `tpot_mean_ms`; `.tput`: `output_tok_s`) or in
+their reader (`.moe` / `.swa` / `.ssm` / `.afm` on the four families'
+`*_rooflines` / `*_scopes` readings and `window_mfu.*`). Those four readers
+are four copies of one reading: a new reading that needs a family's own
+counts should take the counts module from the configuration's `model_type`
+inside ONE reader, not come as a reader a family. What a metric IS is read
+from its entry and its file, never from its name (resultline.py's 105% rule:
+unit `%` and a reader whose module name holds `roofline`).
 """

@@ -11,7 +11,9 @@
  * the traffic generator: every seed gives the same multiset of sizes and
    the same arrival span, in another order, and takes seeds past 2**31;
  * resultline.py refuses each way a last line went wrong before, and a
-   `--trace 2` line that lacks either kind of metric;
+   `--trace 2` line that lacks either kind of metric; every entry that is a
+   share of a peak (unit `%`, a reader that divides by one) is refused at
+   106, whatever its name ends in, and a `%` that is no such share is not;
  * every idle piece of the device put down to the scheduler segment beside
    it (idle_attribution.py), on hand-made events: a gap under two
    segments, under none, across the window's edge, nested segments, shares
@@ -21,9 +23,10 @@
    a tail's plan has the window's plan as its prefix;
  * BENCHMARK.json: every name resolves to a file, every metric's `moves`
    is reported where the metric is; every file under layer_metrics/ is
-   named by exactly one entry; every entry lists its cells; no cell
-   declares fewer per-layer metrics than it was accepted with; every name
-   under `tail_view` (trace_in_run.json) is an entry's;
+   named by exactly one entry; every entry lists its cells; no two entries
+   are one measurement (the fold of PR 50 holds), and how many of the 128
+   are free; no cell declares fewer per-layer metrics than it was accepted
+   with; every name under `tail_view` (trace_in_run.json) is an entry's;
  * the window families' decode rooflines take bytes and time from the same
    seconds: through run.py's own `read_layer_metric`, a context whose
    measured window held caches half as long as its tail's reads the
@@ -395,10 +398,42 @@ def last_line() -> None:
     check("render refuses NaN", raises(lambda: resultline.render({"x": float("nan")}), ValueError))
 
 
+def _metric_file(name: str) -> dict:
+    with open(os.path.join(HERE, "layer_metrics", name + ".json")) as f:
+        return json.load(f)
+
+
+def over_a_peak() -> None:
+    """The 105% rule goes by what the entry is: each share of a peak at 106
+    is refused in a cell that declares it, suffix or none (`.moe`, `.swa`,
+    `.ssm`, `.afm` behind `_roofline` hid eleven from the name's ending: one
+    of them read 104.892% in PR 43's check), and a `%` that divides by no
+    peak is not this rule's."""
+    bench = resultline.load_benchmark()
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1.2e10, "window_s": 4.0, "busy_s": 3.0}
+
+    def over(cell: str, name: str) -> bool:
+        layer = resultline.declared(bench, cell, 1)
+        line = {"correct": True, "attempted": 10, "failed": 0, "device": dev,
+                "metrics": {n: {"value": 106.0 if n == name else 1.5, "unit": u} for n, u in layer.items()}}
+        return any("over 105%" in p for p in resultline.problems(line, bench, cell, 1, 1))
+
+    shares = [m for m in bench["per_layer"] if m["unit"] == "%" and "roofline" in _metric_file(m["name"])["reader"]]
+    for m in shares:
+        check(f"{m['name']} at 106 is refused in every cell that declares it", all(over(c, m["name"]) for c in m["workloads"]))
+    check("the shares of a peak: the 24 there were, or more", len(shares) >= 24, len(shares))
+    check("no share of a peak is called otherwise than `_roofline` or `mfu`",
+          all("_roofline" in m["name"] or "mfu" in m["name"] for m in shares))
+    others = [m for m in bench["per_layer"] if m["unit"] == "%" and m not in shares]
+    refused = [m["name"] for m in others if over(m["workloads"][0], m["name"])]
+    check("device_idle_pct at 106, and every other `%` that divides by no peak, is not this rule's",
+          "device_idle_pct" in [m["name"] for m in others] and not refused, refused)
+
+
 ACCEPTED_PER_LAYER = {
     "qwen7b-int8-chat-sat": 19, "mistral7b-int8-docqa": 23, "qwen7b-int8-chat-rate": 17, "kanana2-bf16-reason-sat": 20,
-    "smallthinker-bf16-longdoc-sat": 25, "nemotron3super-bf16-agent-sat": 23, "trinitymini-bf16-mixedlen-sat": 23,
-    "mistral7b-int8-chat-sat": 3,
+    "smallthinker-bf16-longdoc-sat": 25, "nemotron3super-bf16-agent-sat": 23, "trinitymini-bf16-mixedlen-sat": 28,
+    "mistral7b-int8-chat-sat": 11,
 }
 
 
@@ -425,8 +460,20 @@ def benchmark_file() -> None:
     files = sorted(n[: -len(".json")] for n in os.listdir(os.path.join(HERE, "layer_metrics")) if n.endswith(".json"))
     check("one file an entry and one entry a file", files == sorted(names) and len(set(names)) == len(names),
           sorted(set(files) ^ set(names)))
-    # What each accepted cell printed when this list was written (ledger, PR 45): merging
-    # entries that are one measurement (PERF.md section 7) may rename a metric, never drop one.
+    # One entry a measurement (the fold, PR 50): a cell that an accepted entry can be read in joins
+    # its `workloads`; the same file under another name would only spend one of the 128 entries.
+    seen: dict[tuple, str] = {}
+    for m in bench["per_layer"]:
+        if m["name"] not in files:
+            continue  # reported above
+        key = (json.dumps(_metric_file(m["name"]), sort_keys=True), *(m[k] for k in ("moves", "unit", "better", "source", "layer")))
+        check(f"{m['name']} is a measurement of its own", key not in seen, f"a second name of {seen.get(key)}")
+        seen.setdefault(key, m["name"])
+    print(f"     per_layer: {len(names)} entries of 128, {128 - len(names)} free")
+    check("per_layer within its cap of 128 entries", len(names) <= 128, len(names))
+    # What each accepted cell printed when this list was written (ledger, PR 49; mixedlen-sat's 28 and
+    # mistral chat-sat's 11 since they joined the entries PR 42 left out: my chip runs, PR 50):
+    # merging entries that are one measurement may rename a metric, never drop one.
     for cell, floor in ACCEPTED_PER_LAYER.items():
         if cell in cells:
             got = len(resultline.declared(bench, cell, 1))
@@ -612,6 +659,7 @@ if __name__ == "__main__":
     generator()
     last_line()
     benchmark_file()
+    over_a_peak()
     same_seconds()
     with tempfile.TemporaryDirectory() as tmp:
         in_run(tmp)
