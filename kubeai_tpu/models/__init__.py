@@ -20,6 +20,7 @@ MODULES = {
     "smallthinker": "smallthinker",
     "nemotron_h": "nemotron_h",
     "afmoe": "afmoe",
+    "lfm2_moe": "lfm2_moe",
 }
 DENSE = "llama"
 

@@ -71,6 +71,7 @@ import numpy as np
 
 from kubeai_tpu.models import shared
 from kubeai_tpu.models.base import ModelConfig
+from kubeai_tpu.models.shared import cached_attention_route  # noqa: F401  (the seam's name; one pool)
 from kubeai_tpu.ops import moe, ssm
 from kubeai_tpu.ops.attention import attention
 from kubeai_tpu.ops.norms import rms_norm
@@ -243,18 +244,6 @@ def init_paged_cache(config: ModelConfig, num_pages: int, page_size: int, dtype=
         "ssm": jnp.zeros((n["M"], slots, Hm, P, N), state_dtype),
         "conv": jnp.zeros((n["M"], slots, config.conv_kernel - 1, conv_channels(config)), dtype),
     }
-
-
-def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, paged: bool) -> str:
-    """The attention implementation a cached call of *S* queries a row
-    takes in the `*` blocks: "flash" (cold prefill of whole 256-row
-    tiles), "paged_kernel" (the ragged kernel over pages in place) or
-    "xla" (the portable gather of the same pages)."""
-    if config.use_flash_prefill and left_aligned and S >= 256 and S % 256 == 0:
-        return "flash"
-    if config.use_paged_kernel and paged:
-        return "paged_kernel"
-    return "xla"
 
 
 # ---------------------------------------------------------------------------

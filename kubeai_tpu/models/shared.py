@@ -1,7 +1,8 @@
 """What the families whose every call goes through the paged pool hold in
-common (`deepseek.py`, `smallthinker.py`, `nemotron_h.py`, `afmoe.py`): the
+common (`deepseek.py`, `smallthinker.py`, `nemotron_h.py`, `afmoe.py`,
+`lfm2_moe.py`): the
 refusals they share, the three paged entry points over a module's `apply`,
-and the streamed load. `llama.py` keeps its own: its entry points pass LoRA
+the streamed load, and the routes of a stack with one page pool. `llama.py` keeps its own: its entry points pass LoRA
 banks and a mesh on, its loader shards and quantizes."""
 
 from __future__ import annotations
@@ -22,17 +23,23 @@ Params = dict[str, Any]
 # Refusals
 
 
-def refuse_common(name: str, config: ModelConfig, quantization: str, tp: int, tp_reason: str, int8_for: str = "stacked expert weights") -> None:
+def refuse_common(
+    name: str, config: ModelConfig, quantization: str, tp: int, tp_reason: str, int8_for: str = "stacked expert weights",
+    tied: bool = False,
+) -> None:
     """The four refusals every one of these families opens its
     `refuse_unsupported` with; *tp_reason* says what of the family is not
-    sharded, *int8_for* which weights have no int8 form."""
+    sharded, *int8_for* which weights have no int8 form; *tied*: the
+    family's head IS its embedding, and it is an untied one it refuses."""
     if quantization:
         raise ValueError(f"{name}: --quantization is not supported (no int8 for {int8_for})")
     if tp > 1:
         raise ValueError(f"{name}: --tensor-parallel-size > 1 is not supported ({tp_reason})")
     if config.kv_cache_dtype not in ("", "auto", config.dtype):
         raise ValueError(f"{name}: a kv_cache_dtype other than the compute dtype is not supported")
-    if config.tie_word_embeddings:
+    if tied and not config.tie_word_embeddings:
+        raise ValueError(f"{name}: an untied head is not supported (the head is the embedding: the checkpoint holds no lm_head.weight)")
+    if config.tie_word_embeddings and not tied:
         raise ValueError(f"{name}: tied embeddings are not supported (the checkpoint must hold lm_head.weight)")
 
 
@@ -119,14 +126,16 @@ def _put_row(buf, a, i, transpose: bool):
     return buf.at[i].set(jnp.swapaxes(a, -1, -2) if transpose else a)
 
 
-def stream_stacks(source, config: ModelConfig, pad: int, layer_tensors, rows: dict[str, tuple[int, int]]) -> Params:
+def stream_stacks(source, config: ModelConfig, pad: int, layer_tensors, rows: dict[str, tuple[int, int | dict]], **outside) -> Params:
     """The streamed load of a tree whose groups stack their layers on a
     leading axis: each layer (`read_ahead`) is written into its row of
     the stacked arrays ON the device, the buffer donated, so the device
     never holds a stack twice. *layer_tensors* gives a layer by group,
     `{group: {name: array}}`; *rows* by group (rows of the group's
-    stacks, the layer that is their row 0): a group with no layer stays
-    an empty dict. A tensor named `we_*` is a layer's experts, kept
+    stacks, the layer that is their row 0; or, where the group's layers
+    do not follow each other, the row of EVERY layer that has it, a
+    mapping layer -> row): a group
+    with no layer stays an empty dict. A tensor named `we_*` is a layer's experts, kept
     [E, out, in] on the host (one contiguous copy) and transposed on the
     device. *source* serves tensors by HF name
     (`weights.SafetensorsSource`); *pad* columns of zeros are added to
@@ -143,26 +152,25 @@ def stream_stacks(source, config: ModelConfig, pad: int, layer_tensors, rows: di
                 shape = (a.shape[0], a.shape[2], a.shape[1]) if experts else a.shape
                 if k not in params[group]:
                     params[group][k] = jnp.zeros((n, *shape), a.dtype)
-                params[group][k] = put_row(params[group][k], a, i - first, experts)
-    return {**params, **embed_norm_head(source, config, pad)}
+                params[group][k] = put_row(params[group][k], a, i - first if isinstance(first, int) else first[i], experts)
+    return {**params, **embed_norm_head(source, config, pad, **outside)}
 
 
 def embed_norm_head(
     source, config: ModelConfig, pad: int,
-    embed: str = "model.embed_tokens.weight", norm: str = "model.norm.weight", head: str = "lm_head.weight",
+    embed: str = "model.embed_tokens.weight", norm: str = "model.norm.weight", head: str | None = "lm_head.weight",
 ) -> Params:
     """What a tree holds outside its layers, from the three HF names:
-    `embed` [V, D], `final_norm`, `lm_head` [D, V] (untied), the
+    `embed` [V, D], `final_norm`, `lm_head` [D, V] (untied; *head* None:
+    the head is the embedding and the tree holds no `lm_head`), the
     vocabulary padded by *pad*."""
     dtype = jnp.dtype(config.dtype)
-    embed_, head_ = np.asarray(source.get(embed), dtype), np.asarray(source.get(head), dtype).T
-    if pad:
-        embed_, head_ = np.pad(embed_, ((0, pad), (0, 0))), np.pad(head_, ((0, 0), (0, pad)))
-    return {
-        "embed": jax.device_put(embed_),
-        "final_norm": jax.device_put(np.asarray(source.get(norm), dtype)),
-        "lm_head": jax.device_put(head_),
-    }
+    embed_ = np.asarray(source.get(embed), dtype)
+    out = {"embed": np.pad(embed_, ((0, pad), (0, 0))) if pad else embed_, "final_norm": np.asarray(source.get(norm), dtype)}
+    if head is not None:
+        head_ = np.asarray(source.get(head), dtype).T
+        out["lm_head"] = np.pad(head_, ((0, 0), (0, pad))) if pad else head_
+    return {k: jax.device_put(a) for k, a in out.items()}
 
 
 class DictSource:
@@ -198,3 +206,23 @@ def layer_counts(config: ModelConfig) -> tuple[int, int]:
 
 def swiglu(x, wg, wu, wd):
     return jnp.dot(jax.nn.silu(jnp.dot(x, wg)) * jnp.dot(x, wu), wd)
+
+
+def cached_attention_route(config: ModelConfig, S: int, left_aligned: bool, paged: bool) -> str:
+    """The attention implementation a cached call of *S* queries a row
+    takes in a stack with ONE page pool (`nemotron_h.py`'s `*` blocks,
+    `lfm2_moe.py`'s attention layers): "flash" (cold prefill of whole
+    256-row tiles), "paged_kernel" (the ragged kernel over pages in place)
+    or "xla" (the portable gather of the same pages)."""
+    if config.use_flash_prefill and left_aligned and S >= 256 and S % 256 == 0:
+        return "flash"
+    if config.use_paged_kernel and paged:
+        return "paged_kernel"
+    return "xla"
+
+
+def take_row(tree: dict, i) -> dict:
+    """Row *i* of every stacked array of *tree*, read from the whole stack
+    at its own index (a block sliced out first and then indexed is a copy
+    of the block); *i* an int in an unrolled layer, traced in a scan."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)

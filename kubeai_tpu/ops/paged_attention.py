@@ -27,6 +27,20 @@ and they come back as zeros.
 On non-TPU backends this dispatches to a jit-safe twin of the library's
 pure-JAX reference implementation (identical semantics, the count
 included), so the engine's kernel path is CPU-testable end-to-end.
+
+Heads narrower than a lane tile (`h` = 64: `models/lfm2_moe.py`). Neither
+kernel takes them: the library's asserts at trace (its running sum is 128
+lanes wide and is tiled over the head: `64 % 128`), the repo's reads whole
+lane tiles. Such a family keeps its pool as `[P, page, 2 * Kv / n, n * h]`
+with n = `heads_a_tile(h)` KV heads SIDE BY SIDE in a 128-lane row (`pack_kv`:
+their K on the even row, their V on the odd one), the same bytes a token
+as `[P, page, 2 * Kv, h]`, and hands both kernels what they take: a pool
+of Kv / n heads of 128, and queries with each head's values in ITS KV
+head's lanes and zeros in the others (`widen_queries`). A score is then
+`q . k` of the head's own 64 values plus exact zeros, the softmax is over
+the same keys, and the output's own lanes (`narrow_outputs`) are `p . v`
+of the head's own values: the same arithmetic to the bit, for n times the
+matrix unit's work and no byte more from HBM.
 """
 
 from __future__ import annotations
@@ -203,3 +217,45 @@ def _cpu_twin(q_flat, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, s
     out = attention(q, k_att, v_att, mask, scale=sm_scale, softcap=soft_cap or 0.0)
     walked = jnp.arange(B)[:, None, None, None] < num_seqs[0]
     return jnp.where(walked, out, 0).reshape(B * S, H, h)
+
+
+# ---------------------------------------------------------------------------
+# Heads narrower than a lane tile (module docstring)
+
+
+def heads_a_tile(h: int) -> int:
+    """KV heads that share a 128-lane row of the pool: 1 for heads of whole
+    lane tiles, 128 / h for narrower ones."""
+    return 128 // h if h < 128 and 128 % h == 0 else 1
+
+
+def pack_kv(k: jnp.ndarray, v: jnp.ndarray, n: int) -> jnp.ndarray:
+    """k, v [B, S, Kv, h] as the pool's rows [B, S, 2 * Kv / n, n * h]: KV
+    heads j*n .. j*n+n-1 side by side, their keys on row 2j and their
+    values on row 2j+1 (n = 1: K even, V odd, every family's layout)."""
+    B, S, Kv, h = k.shape
+    wide = lambda a: a.reshape(B, S, Kv // n, n * h)  # noqa: E731
+    return jnp.stack([wide(k), wide(v)], axis=3).reshape(B, S, 2 * Kv // n, n * h)
+
+
+def widen_queries(q: jnp.ndarray, Kv: int, n: int) -> jnp.ndarray:
+    """q [B, S, H, h] -> [B, S, H, n * h]: a head's values in the lanes of
+    its own KV head within the packed row, exact zeros in the others."""
+    if n == 1:
+        return q
+    B, S, H, h = q.shape
+    G = H // Kv
+    by_lane = q.reshape(B, S, Kv // n, n, G, 1, h)
+    wide = [jnp.pad(by_lane[:, :, :, l], ((0, 0),) * 4 + ((l, n - 1 - l), (0, 0))) for l in range(n)]
+    return jnp.stack(wide, axis=3).reshape(B, S, H, n * h)
+
+
+def narrow_outputs(o: jnp.ndarray, Kv: int, n: int) -> jnp.ndarray:
+    """o [B, S, H, n * h] of a call on widened queries -> [B, S, H, h]: each
+    head's own lanes (the others hold its weights over a neighbour's values)."""
+    if n == 1:
+        return o
+    B, S, H, wide = o.shape
+    G, h = H // Kv, wide // n
+    by_lane = o.reshape(B, S, Kv // n, n, G, n, h)
+    return jnp.stack([by_lane[:, :, :, l, :, l] for l in range(n)], axis=3).reshape(B, S, H, h)

@@ -40,11 +40,17 @@ PUBLISHED = {
         COMMON, model_type="afmoe", layer_types=["sliding_attention"] * 3 + ["full_attention"], sliding_window=32,
         num_dense_layers=1, num_experts=8, num_shared_experts=1, num_experts_per_tok=2, moe_intermediate_size=32,
     ),
+    "lfm2_moe": dict(
+        {k: v for k, v in COMMON.items() if k != "head_dim"}, model_type="lfm2_moe", hidden_size=256,
+        layer_types=["conv", "full_attention", "conv", "conv"], conv_L_cache=3, num_dense_layers=1, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=32, rope_parameters={"rope_theta": 1000000.0, "rope_type": "default"},
+    ),
 }
 # ... and a field of the module's own `config_keys` that it must have set.
 OWN_KEY = {
     "llama": ("post_norms", True), "deepseek": ("kv_lora_rank", 32), "smallthinker": ("sliding_window_layout", (0, 1, 1, 1)),
     "nemotron_h": ("layer_pattern", "ME*M"), "afmoe": ("rope_layout", (1, 1, 1, 0)),
+    "lfm2_moe": ("layer_pattern", "cacc"),
 }
 NAMES = sorted(PUBLISHED)
 

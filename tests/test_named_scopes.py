@@ -262,3 +262,43 @@ def test_every_scope_names_operations_in_the_gated_window_family(scoped_afm_prog
     assert not re.search(r'["/]attn[./][^"]*norm\.post/', scoped_afm_programs[0][1])
     # The periods behind the first are one scanned body.
     assert "stablehlo.while" in scoped_afm_programs[1][0]
+
+
+# -- the short-convolution family's module (models/lfm2_moe.py, ops/shortconv.py) --
+
+LFM_SCOPES = (
+    "embed", "conv", "conv.in_proj", "conv.gate", "conv.taps", "conv.out_proj", "attn", "attn.qk_norm", "attn.kernel", "ffn",
+    "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "lm_head", "sampling", "logprobs",
+)
+LFM2_MOE = ModelConfig(
+    model_type="lfm2_moe", vocab_size=272, hidden_size=128, intermediate_size=96, num_layers=10, num_heads=2, num_kv_heads=2,
+    dtype="float32", max_position=256, num_experts_per_tok=2, n_routed_experts=8, moe_intermediate_size=32,
+    first_k_dense_replace=2, layer_pattern="ccacccaccc", conv_kernel=3, rope_theta=1e6, tie_word_embeddings=True,
+    use_paged_kernel=True, use_flash_prefill=True,
+)
+
+
+@pytest.fixture(scope="module")
+def scoped_lfm_programs():
+    return _lowered_programs(True, LFM2_MOE)
+
+
+def test_scopes_change_metadata_only_in_the_short_convolution_family(scoped_lfm_programs):
+    plain = _lowered_programs(False, LFM2_MOE)
+    assert len(scoped_lfm_programs) == len(plain) >= 4
+    for i, ((s_text, s_debug), (p_text, p_debug)) in enumerate(zip(scoped_lfm_programs, plain)):
+        assert s_text == p_text, f"program {i}: the computation changed with the scopes"
+        assert s_debug != p_debug, f"program {i}: the scopes left no trace in the metadata"
+
+
+@pytest.mark.parametrize("scope", LFM_SCOPES)
+def test_every_scope_names_operations_in_the_short_convolution_family(scoped_lfm_programs, scope):
+    rx = re.compile(r'["/]' + re.escape(scope) + "/")
+    for _, debug_text in scoped_lfm_programs[:2]:  # the decode chunk, a prefill
+        assert rx.search(debug_text), scope
+    # The operator's parts sit INSIDE `conv`, the heads' norms and the kernel inside `attn`: a reader
+    # tells them apart by the path (perfbench/readers/family_scopes.py, families/lfm2_moe_counts.py).
+    for inner in ("conv/conv.in_proj", "conv/conv.gate", "conv/conv.taps", "conv/conv.out_proj", "attn/attn.qk_norm", "attn/attn.kernel"):
+        assert re.search(r'["/]' + re.escape(inner) + "/", scoped_lfm_programs[0][1]), inner
+    # The periods behind the dense layers are one scanned body.
+    assert "stablehlo.while" in scoped_lfm_programs[1][0]

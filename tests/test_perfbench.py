@@ -652,6 +652,121 @@ def _run_rehearsal(tmp_path, cell, trace, seed, timeout=600):
     return lines
 
 
+def test_the_one_family_reader_takes_its_counts_and_scopes_from_the_model_type():
+    """readers/family_rooflines.py + family_scopes.py on a made-up window of
+    the short-convolution family: the counts and the scope list are
+    families/<model_type>_counts.py's, found by the configuration's
+    `model_type`. 100 decode chunks of 8 steps with 180 of 192 slots live,
+    every expert hit, a million prompt tokens, two requests whose prefill
+    ended between the polls, one of them decoding through the traced seconds."""
+    from families import lfm2_moe_counts as counts
+    from readers import family_rooflines, family_scopes
+
+    steps = 800
+    after = {
+        "kubeai_engine_state_slots_total": [({}, 192.0)],
+        "kubeai_engine_slot_steps_total": [({"state": "active"}, steps * 180.0), ({"state": "idle"}, steps * 12.0)],
+        "kubeai_engine_moe_experts_hit_total": [({"phase": "decode"}, steps * 8 * 64.0)],
+        "kubeai_engine_moe_expert_reads_possible_total": [({"phase": "decode"}, steps * 8 * 64.0)],
+        "kubeai_engine_prefill_tokens_total": [({}, 1.0e6)], "kubeai_engine_generated_tokens_total": [({}, 300000.0)],
+    }
+    ctx = _made_up_window("lfm2-24b-a2b-bf16", after, gauges=("kubeai_engine_state_slots_total",))
+    hf = ctx.hf
+    assert family_scopes.counts_of(ctx) is counts and counts.SCOPES.index("conv.taps") < counts.SCOPES.index("conv")
+    record = lambda n, first, k: types.SimpleNamespace(prompt_tokens=n, token_times=[first + 0.02 * i for i in range(k)], done=None)  # noqa: E731
+    ctx.all_records = [record(3000, 19.0, 400), record(1000, 30.0, 10)]  # the first decodes all through [20, 24)
+    ctx.family_scope_shares = {
+        "jit__unknown(7)": _by_scope(conv_in_proj=0.06, conv_taps=0.02, conv_gate=0.01, conv_out_proj=0.03, conv=0.0, attn_kernel=0.2, attn=0.05, moe_experts=1.0, moe=0.1),
+        "jit_prefill_chunk_fn(9)": _by_scope(2.0, conv_in_proj=0.2, conv_out_proj=0.1, conv_taps=0.1, attn_kernel=0.25, moe_experts=0.9),
+    }
+    conv = "conv|conv.in_proj|conv.gate|conv.taps|conv.out_proj"
+    assert family_scopes.read(ctx, "^jit__unknown", conv) == pytest.approx(7.5)
+    assert family_scopes.read(ctx, "^jit_prefill", conv) == pytest.approx(20.0)
+    assert family_scopes.seconds(ctx, "^jit__unknown", "attn.kernel") == pytest.approx((0.2, 1.6, 10.0))
+    n_steps = 10 * 8
+    read = lambda what, **kw: family_rooflines.read(ctx, what, **kw)  # noqa: E731
+    tails = 180 * 2 * 8 * 2 * 2048 * 2
+    conv_weights = 8 * 16_785_408 * 2
+    assert read("conv_decode") == pytest.approx(100 * ((conv_weights + tails) / 819e9) / (0.12 / n_steps))
+    live_tokens = sum(3000 + int((20.0 + (i + 0.5) * 0.1 - 19.0) / 0.02) + 1 for i in range(40)) / 40  # the one live request, as trace_common samples it
+    kv = live_tokens * 4096
+    assert read("attn_decode") == pytest.approx(100 * (kv / 819e9) / (0.2 / n_steps), rel=1e-3)
+    experts = 8 * 64 * 9_437_184 * 2
+    assert read("experts") == pytest.approx(100 * (experts / 819e9) / (1.0 / n_steps))
+    outside = counts.weights_outside_experts_bytes(hf, 2)
+    assert outside == (8 * 16_785_408 + 2 * 10_487_936 + 2 * 72_353_792 + 8 * (2048 * 64 + 64 + 2048) + 65536 * 2048 + 2048) * 2
+    assert read("decode_step") == pytest.approx(100 * ((outside + experts + tails + kv) / 819e9) / (1.6 / n_steps), rel=1e-3)
+    # Prefill: the counters and the records between the polls around the traced seconds (here the window's edges).
+    flops = 8 * 33_554_432 * 1.0e6
+    assert read("conv_prefill", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.4 * 50.0 / 4.0))
+    pairs = 3000 * 3001 / 2 + 1000 * 1001 / 2
+    assert read("prefill_attn", module="^jit_prefill") == pytest.approx(100 * (2 * 8192 * pairs / 197e12) / (0.25 * 50.0 / 4.0))
+    decode_pairs = (3000 + (1 + 399) / 2) * 399 + (1000 + (1 + 9) / 2) * 9  # token i of a request sees prompt + i keys
+    want_mfu = 100 * (2 * counts.active_params(hf) * 1_300_000 + 2 * 8192 * (pairs + decode_pairs)) / (197e12 * 50)
+    assert read("window_mfu") == pytest.approx(want_mfu, rel=1e-6)
+    assert all(0 < read(w, **kw) < 100 for w, kw in (("conv_decode", {}), ("attn_decode", {}), ("experts", {}), ("decode_step", {}), ("window_mfu", {})))
+    # `view="tail"`: the tail's own polls bracket the traced seconds where the run traced itself after its window.
+    half = {k: v if k == "kubeai_engine_state_slots_total" else [(labels, x / 2) for labels, x in v] for k, v in after.items()}
+    ctx.tail_view = types.SimpleNamespace(**{**vars(ctx), "before": _scrape(60.0, **half), "polls": [], "after": _scrape(70.0, **after), "trace_t0": 62.0, "trace_t1": 66.0})
+    assert read("conv_prefill", module="^jit_prefill", view="tail") == pytest.approx(100 * (flops / 2 / 197e12) / (0.4 * 10.0 / 4.0))
+    assert read("conv_prefill", module="^jit_prefill") == pytest.approx(100 * (flops / 197e12) / (0.4 * 50.0 / 4.0))
+    assert read("window_mfu", view="tail") == pytest.approx(want_mfu, rel=1e-6)  # the measured window's, whatever the view
+    del ctx.tail_view
+    # A trace that carries none of the family's OWN scopes (another family's program, the parent's) gives no share ...
+    ctx.family_scope_shares = {"jit__unknown(7)": _by_scope(attn=0.4, moe_experts=0.4)}
+    assert family_scopes.read(ctx, "^jit__unknown", "moe.experts") is None and read("experts") is None and read("conv_decode") is None
+    # ... a program without the counters, or a family without a counts module, nothing at all; nothing raised.
+    ctx.after = _scrape(50.0)
+    assert all(read(w) is None for w in ("window_mfu", "experts", "conv_decode", "conv_prefill", "attn_decode", "prefill_attn", "decode_step"))
+    ctx.after, ctx.hf = _scrape(50.0, **after), {**hf, "model_type": "qwen2"}
+    assert read("window_mfu") is None and family_scopes.read(ctx, "^jit__unknown", conv) is None
+    ctx.hf = {**hf, "model_type": "../x"}
+    assert family_scopes.counts_of(ctx) is None
+    ctx.hf, ctx.trace, ctx.family_scope_shares = hf, None, None
+    assert family_scopes.read(ctx, "^jit__unknown", conv) is None and read("decode_step") is None
+
+
+def test_the_next_familys_operator_comes_to_the_reader_as_a_row_of_its_counts_table(monkeypatch):
+    """readers/family_rooflines.py holds no operator's name: a made-up
+    family whose counts file states a `scan` operator (its scopes, and its
+    shares as units of work times a count) reads through the same reader and
+    the same `what=` with no edit of it; a `what` its table lacks is refused
+    by name."""
+    from readers import family_rooflines
+
+    made_up = types.ModuleType("families.madeup_counts")
+    made_up.SCOPES, made_up.OWN_SCOPES = ("scan.state", "scan", "attn.kernel"), ("scan.state", "scan")
+    made_up.ROOFLINES = {
+        "scan_decode": {
+            "phase": "decode", "peak": "hbm_bytes_per_s", "scopes": ("scan", "scan.state"),
+            "work": {"step": lambda hf, sv: 1.0e9 * sv["weight_dtype_bytes"], "live_row": lambda hf, sv: 4.0e6},
+        },
+        "scan_prefill": {
+            "phase": "prefill", "peak": "bf16_flops", "scopes": ("scan",),
+            "work": {"prompt_token": lambda hf, sv: 2.0 * hf["hidden_size"] ** 2},
+        },
+    }
+    monkeypatch.setitem(sys.modules, "families.madeup_counts", made_up)
+    steps = 800
+    after = {
+        "kubeai_engine_slot_steps_total": [({"state": "active"}, steps * 96.0), ({"state": "idle"}, steps * 96.0)],
+        "kubeai_engine_prefill_tokens_total": [({}, 1.0e6)],
+    }
+    ctx = _made_up_window("lfm2-24b-a2b-bf16", after)
+    ctx.hf = {**ctx.hf, "model_type": "madeup"}
+    ctx.family_scope_shares = {
+        "jit__unknown(7)": _by_scope(scan_state=0.1, scan=0.3, attn_kernel=0.2),
+        "jit_prefill_chunk_fn(9)": _by_scope(2.0, scan=0.5),
+    }
+    n_steps = 10 * 8
+    assert family_rooflines.read(ctx, "scan_decode") == pytest.approx(100 * ((2.0e9 + 96 * 4.0e6) / 819e9) / (0.4 / n_steps))
+    assert family_rooflines.read(ctx, "scan_prefill", module="^jit_prefill") == pytest.approx(
+        100 * (2.0 * 2048**2 * 1.0e6 / 197e12) / (0.5 * 50.0 / 4.0)
+    )
+    with pytest.raises(ValueError, match="conv_decode.*scan_decode"):
+        family_rooflines.read(ctx, "conv_decode")
+
+
 def test_rehearsal_of_a_run_that_traces_itself(tmp_path):
     """--rehearse --trace 2, every phase: one last line with both kinds of
     metric; the end-to-end values are what `Run.end_to_end` gave over the
@@ -796,3 +911,23 @@ def test_rehearsal_of_the_gated_window_models_cell(tmp_path):
     assert 0 < _value(last, cell, "experts_hit") <= 100
     assert 0 < last["metrics"]["attn_gate_norm_share_pct"]["value"] < 100
     assert _value(last, cell, "window_pool_peak") > 0
+
+
+@pytest.mark.slow  # two minutes alone: not tier-1, as the other families' rehearsals are not
+def test_rehearsal_of_the_short_convolution_models_cell(tmp_path):
+    """--rehearse --trace 2 of lfm2-bf16-fleet-sat at the configuration's
+    `rehearsal` keys (hidden 128, 2 heads of 64, 10 layers, 8 experts top-2):
+    every phase, the family's three-part logits check in chunks of 64 behind
+    a carried tail, and every per-layer metric the CPU can read through the
+    one family reader."""
+    cell = "lfm2-bf16-fleet-sat"
+    # The tail's 4 s of a rehearsal's eight clients need not hold a whole run of a prefill program.
+    may_miss = _timed_rooflines(cell) | set(_per_layer(cell, "of_prefill_programs"))
+    last, logits = _family_rehearsal(tmp_path, cell, 2, 2**31 + 17, may_miss)
+    assert last["correct"] is True
+    assert set(logits["compared"]) == {"prefill_cold", "prefill_chunked", "decode", "router_choices", "tails"}
+    assert all(part["ok"] for part in logits["compared"].values())
+    assert logits["sample"]["chunks"] == [64, 64, 15] and logits["pattern"] == "ccaccc"
+    assert 0 < _value(last, cell, "experts_hit") <= 100
+    assert 0 < last["metrics"]["decode_conv_share_pct"]["value"] < 100 and last["metrics"]["state_slots_peak_pct"]["value"] == 100
+    assert 0 < last["metrics"]["window_mfu.lfm"]["value"] < 100

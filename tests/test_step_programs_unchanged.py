@@ -12,7 +12,8 @@ the four expert families' again (it MEANT to change them: the way back of
 PR 48 added one digest a family, the chunk call of the SMALLER bucket, which
 `warmup()` always ran and the warm compile's own list had left out (the one
 list of engine/step_programs.py has both's coverage); the six that were
-there kept theirs:
+there kept theirs; PR 51 added `lfm2_moe`'s as it left its module (the
+other five kept theirs):
 
     JAX_PLATFORMS=cpu python tests/test_step_programs_unchanged.py > tests/data/step_program_digests.json
 
@@ -35,7 +36,7 @@ import test_named_scopes as scopes  # noqa: E402
 
 FAMILIES = {
     "dense": None, "deepseek_v3": scopes.DEEPSEEK, "smallthinker": scopes.SMALLTHINKER, "nemotron_h": scopes.NEMOTRON_H,
-    "afmoe": scopes.AFMOE,
+    "afmoe": scopes.AFMOE, "lfm2_moe": scopes.LFM2_MOE,
 }
 DIGESTS = os.path.join(HERE, "data", "step_program_digests.json")
 

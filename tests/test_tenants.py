@@ -494,7 +494,9 @@ def engine_server():
 
     eng = build_test_engine(
         engine_config=EngineConfig(
-            max_slots=2, max_seq_len=512, prefill_buckets=(16, 32),
+            # Room for a stream that no machine finishes inside the deadline
+            # test's 0.4 s (400 tokens took 0.36-0.40 s on an idle sandbox).
+            max_slots=2, max_seq_len=2048, prefill_buckets=(16, 32),
             max_queue=8, decode_chunk=2,
         )
     )
@@ -575,7 +577,7 @@ def test_stream_deadline_abort_still_delivers_usage(engine_server):
         engine_server,
         {
             "model": "tenants-m1", "prompt": "count forever", "stream": True,
-            "max_tokens": 400, "temperature": 0,
+            "max_tokens": 1900, "temperature": 0,
             "stream_options": {"include_usage": True},
         },
         headers={"X-Request-Deadline": "0.4"},

@@ -659,3 +659,100 @@ def test_chunk_kernel_lowers(v5e, monkeypatch, heads, B, S, columns, window, sof
     assert "tpu_custom_call" in text and "chunk_attention_kernel" in text and "ragged_paged_attention_kernel" not in text
     tile = min(S, 512 if heads[0] // heads[1] <= 4 else 256)
     assert list(chunk_attention.chosen_tiles.values()) == [{"query_tile": tile, "kv_block": 256}]
+
+
+# -- LFM2-24B-A2B (lfm2_moe): heads of 64, two to a lane tile, at the published widths ----
+
+LFM_SLOTS, LFM_MAX_LEN = 192, 8192
+
+
+def _lfm(v5e):
+    """(config, parameters, cache) of one dense conv layer and one period
+    (`c | a c c c`) at the published widths, 192 slots of 8192, 8193 pages."""
+    from kubeai_tpu.engine.coldstart import param_shapes
+    from kubeai_tpu.models import lfm2_moe
+
+    mc = ModelConfig(
+        model_type="lfm2_moe", vocab_size=1024, hidden_size=2048, intermediate_size=11776, num_layers=5, num_heads=32,
+        num_kv_heads=8, dtype="bfloat16", num_experts_per_tok=4, n_routed_experts=64, moe_intermediate_size=1536,
+        first_k_dense_replace=1, layer_pattern="caccc", conv_kernel=3, rope_theta=1e6, tie_word_embeddings=True,
+        use_flash_prefill=True, use_paged_kernel=True,
+    )
+    lfm2_moe.refuse_unsupported(mc)
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), param_shapes(mc))
+    cache = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype), jax.eval_shape(lambda: lfm2_moe.init_paged_cache(mc, 8193, PAGE, slots=LFM_SLOTS))
+    )
+    return mc, params, cache
+
+
+def test_the_library_kernel_refuses_heads_of_64_and_the_lane_tile_reading_lowers(v5e):
+    """Why the family packs two KV heads to a 128-lane row: at `h = 64` the
+    library's ragged kernel asserts while it is traced (its running sum is
+    128 lanes wide and is tiled over the head), for one row a slot and for
+    many; the same bytes read as 4 heads of 128 lower through the library's
+    kernel at decode and through the repo's chunk kernel for a chunk."""
+    B, pages = LFM_SLOTS, LFM_MAX_LEN // PAGE
+    for S, rows in ((1, B), (2048, 1)):
+        narrow = (
+            _sds(v5e, (rows, S, 32, 64), jnp.bfloat16), _sds(v5e, (8193, PAGE, 16, 64), jnp.bfloat16),
+            _sds(v5e, (rows, pages), jnp.int32), _sds(v5e, (rows,), jnp.int32),
+        )
+        with pytest.raises(AssertionError):
+            _compile(lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, blocks=(8, 1)), *narrow)
+        wide = (_sds(v5e, (rows, S, 32, 128), jnp.bfloat16), _sds(v5e, (8193, PAGE, 8, 128), jnp.bfloat16), *narrow[2:])
+        text = _compile(lambda q, kv, tbl, lens: paged_attention_ragged(q, kv, tbl, lens, scale=0.125), *wide)
+        assert ("chunk_attention_kernel" in text) == (S > 1) and "tpu_custom_call" in text
+
+
+def test_a_dense_layer_and_a_period_of_lfm2_decode_in_place_through_the_paged_kernel(v5e):
+    """A decode step of `c | a c c c` at the published widths with 192
+    slots of 8192: the step holds the library's paged kernel on 4 heads of
+    128 lanes (two of the published 64 side by side) and the three grouped
+    matmuls of each expert layer, and the pool AND the slots' tails come
+    back in place."""
+    from kubeai_tpu.models import lfm2_moe
+
+    mc, params, cache = _lfm(v5e)
+    B, max_pages = LFM_SLOTS, LFM_MAX_LEN // PAGE
+    assert cache["kv"].shape == (8193, PAGE, 8, 128) and cache["conv"].shape == (4, B, 2, 2048)
+    compiled = jax.jit(
+        lambda p, t, c, tbl, lens, active: lfm2_moe.decode_step_paged(p, mc, t, c, tbl, lens, live=LiveRows.first(active)),
+        donate_argnums=(2,),
+    ).lower(
+        params, _sds(v5e, (B, 1), jnp.int32), cache, _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    # One scanned period: one paged kernel, 4 expert layers x 3 grouped matmuls.
+    assert text.count("tpu_custom_call") == 1 + 4 * 3 and "ragged_paged_attention_kernel" in text
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in cache.values())
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    assert memory.temp_size_in_bytes < cache["kv"].shape[0] * PAGE * 8 * 128 * 2 // 2  # no copy of the pool
+
+
+def test_a_2048_row_chunk_of_lfm2_runs_the_chunk_kernel_on_heads_side_by_side(v5e, monkeypatch):
+    """The cell's widest prefill call behind cached tokens: the attention
+    layer is the repo's own chunk kernel (G = 8 on the widened heads: 256
+    query rows a tile), never the portable gather, and pool and tails come
+    back in place."""
+    from kubeai_tpu.models import lfm2_moe
+    from kubeai_tpu.ops import chunk_attention
+
+    monkeypatch.setattr(chunk_attention, "chosen_tiles", {})
+    mc, params, cache = _lfm(v5e)
+    assert lfm2_moe.cached_attention_route(mc, 2048, False, True) == "paged_kernel"
+    max_pages = LFM_MAX_LEN // PAGE
+    compiled = jax.jit(
+        lambda p, t, c, tbl, start, last, slot: lfm2_moe.prefill_paged(p, mc, t, c, tbl, start, last, slots=slot),
+        donate_argnums=(2,),
+    ).lower(
+        params, _sds(v5e, (1, 2048), jnp.int32), cache, _sds(v5e, (1, max_pages), jnp.int32), _sds(v5e, (1,), jnp.int32),
+        _sds(v5e, (1,), jnp.int32), _sds(v5e, (1,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "chunk_attention_kernel" in text and "ragged_paged_attention_kernel" not in text
+    assert list(chunk_attention.chosen_tiles.values()) == [{"query_tile": 256, "kv_block": 256}]
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in cache.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
