@@ -289,12 +289,35 @@ class FinishInfo:
     kv: dict | None = None
 
 
+class EventQueue(queue.Queue):
+    """A request's events: `queue.Queue`'s surface (`put(ev)`, `get(timeout=)`
+    of ONE event) and the two calls of a hand-over. The tokens of a decode
+    chunk reach the host together, so the scheduler hands a request its
+    share of them at once and the reader is woken once for all of them."""
+
+    def put_many(self, events) -> None:
+        """`put` for each of *events*, under one lock and with one notify."""
+        with self.not_empty:
+            self.queue.extend(events)
+            self.unfinished_tasks += len(events)
+            self.not_empty.notify(len(events))
+
+    def get_many(self, timeout: float | None = None) -> list:
+        """Everything that is there, in order, waiting as `get` does for
+        the first (`queue.Empty` after *timeout*)."""
+        first = self.get(timeout=timeout)
+        with self.mutex:
+            rest = list(self.queue)
+            self.queue.clear()
+        return [first, *rest]
+
+
 @dataclass
 class Request:
     prompt_ids: list[int]
     params: SamplingParams
     adapter: str | None = None
-    out: "queue.Queue[Any]" = field(default_factory=queue.Queue)
+    out: EventQueue = field(default_factory=EventQueue)
     # events on `out`: ("token", id, text_delta, logprob, top) |
     # ("done", FinishInfo) | ("error", message). id -1 = text-only flush
     # (held-back chars; logprob None).
@@ -370,6 +393,11 @@ class _Slot:
     kv_seed: int = 0
     kv_steps: int = 0
     kv_key0: Any = None  # np.uint32 raw key data, set on restore
+    # Events made for the request and not yet handed to it, and how many
+    # generated tokens among them are not yet on the counter: what ONE
+    # fetched array held for the slot goes over in one `_hand_over`.
+    outbox: list = field(default_factory=list)
+    uncounted: int = 0
 
     @property
     def holdback(self) -> int:
@@ -467,6 +495,16 @@ class Engine:
         self.m_gen = default_registry.counter(
             "kubeai_engine_generated_tokens_total", "tokens generated"
         )
+        self.m_handovers = default_registry.counter(
+            "kubeai_engine_emit_handovers_total",
+            "hand-overs of events to a request (one lock, one wake of its "
+            "reader): what a fetched decode chunk holds for a slot goes over "
+            "at once, so generated_tokens_total over this reads about "
+            "decode_chunk under load; 1 would mean a wake a token",
+        )
+        # [tokens, hand-overs] of THIS engine (the registry's counters are
+        # the process's): /debug/engine -> perf.tokens_per_handover.
+        self._handed = [0, 0]
         self.m_prefill = default_registry.counter(
             "kubeai_engine_prefill_tokens_total", "prompt tokens prefilled"
         )
@@ -876,6 +914,10 @@ class Engine:
             "chunk_kernel_hit_share": {
                 kind: round(inside / walked, 4) for kind, (inside, walked) in self._chunk_pairs.items() if walked
             },
+            # Generated tokens a hand-over to a request (_hand_over): about
+            # decode_chunk under load, less by first tokens and by chunks a
+            # request ends in; 1.0 would mean a wake a token.
+            "tokens_per_handover": round(self._handed[0] / self._handed[1], 3) if self._handed[1] else None,
             # Likewise: the MLA decode kernel's pages a block, ring and form; (tm, tk, tn) of the grouped
             # matmul; the heads of a slot a program of the state-space step kernel holds (empty: portable).
             "mla_kernel_blocks": dict(mla_attention.chosen_blocks),
@@ -2499,7 +2541,8 @@ class Engine:
                     row = tid if j is None else tid[j]
                     lrow = tlp if j is None else tlp[j]
                     top = list(zip(row.tolist(), lrow.tolist()))
-                self._emit_token(slot_idx, tok, lp, top)
+                if self._emit_token(slot_idx, tok, lp, top):
+                    self._hand_over(slot)
 
     def _lora_sig(self, adapter: str | None) -> tuple[int, int]:
         if self._adapters is None:
@@ -3036,9 +3079,9 @@ class Engine:
 
     def _emit_chunk(self, snapshot, dur, corr, lp_c, t_ids, t_lp) -> dict:
         """Deliver a fetched chunk's tokens ([K, B] device-chosen tokens
-        and their log-probs, [K, B, N] top-N alternatives or None): one
-        token a live slot a step; returns its step record, less the
-        segment times."""
+        and their log-probs, [K, B, N] top-N alternatives or None): K
+        tokens a live slot, in one hand-over to its request; returns its
+        step record, less the segment times."""
         # Saturation accounting BEFORE emission: this chunk ran K fused
         # steps over the full [B] batch with only the snapshot's slots
         # doing useful work, and the step's wall time (dispatch ->
@@ -3052,34 +3095,49 @@ class Engine:
         if idle:
             self.m_slot_steps.inc(idle, labels={"state": "idle"})
         n_emitted = 0
-        for k in range(K_steps):
-            for i, slot_obj, epoch in snapshot:
-                # The device-chosen next token (the model's continuation
-                # input — greedy argmax OR sampled) with its logprob
-                # under the model.
-                tok = int(corr[k, i])
+        # Slot by slot, each slot's K tokens in order: what the chunk holds
+        # for a request is handed to it ONCE (one lock, one wake of its
+        # reader, one socket write), by `_hand_over` here or by `_free` if
+        # the request ended in the chunk. Slots share nothing in this walk
+        # but the page pool, so only the order in which two requests that
+        # end in one chunk return their pages differs from a walk by step.
+        toks_by_slot, lps_by_slot = corr.T.tolist(), lp_c.T.tolist()
+        for i, slot_obj, epoch in snapshot:
+            # A new occupant since the dispatch reset the slot's history:
+            # these tokens are not its.
+            own = self._slot_epoch[i] == epoch
+            # Emit only while the slot still belongs to the request it
+            # held at dispatch time (it may finish mid-chunk, or have
+            # been freed and re-admitted since dispatch).
+            live = self._slots[i] is slot_obj
+            want_top = t_ids is not None and slot_obj.req.params.logprobs
+            lps = lps_by_slot[i]
+            # The device-chosen next tokens (the model's continuation
+            # input — greedy argmax OR sampled), each with its logprob
+            # under the model.
+            for k, tok in enumerate(toks_by_slot[i]):
                 # Record KV residency for prefix reuse: each step WROTE
                 # its pending (input) token; the emitted token becomes
-                # the next write. Skip if a new occupant reset the slot.
-                if self._slot_epoch[i] == epoch:
+                # the next write. All K of them, whoever holds the slot
+                # now: `_free` reads the history as of the ending token.
+                if own:
                     if self._kv_pending[i] is not None:
                         self._kv_history[i].append(self._kv_pending[i])
                     self._kv_pending[i] = tok
-                # Emit only while the slot still belongs to the request
-                # it held at dispatch time (it may finish mid-chunk, or
-                # have been freed and re-admitted since dispatch).
-                if self._slots[i] is not slot_obj:
+                if not live:
                     continue
                 # One PRNG key evolution per fused step whose token
                 # reaches emission — a park snapshot reconstructs the
                 # slot key from this count (see _Slot.kv_steps).
                 slot_obj.kv_steps += 1
                 top = None
-                if t_ids is not None and slot_obj.req.params.logprobs:
+                if want_top:
                     # The model's distribution at this choice point.
                     top = list(zip(t_ids[k, i].tolist(), t_lp[k, i].tolist()))
-                self._emit_token(i, tok, float(lp_c[k, i]), top)
+                live = self._emit_token(i, tok, lps[k], top)
                 n_emitted += 1
+            if live:
+                self._hand_over(slot_obj)
         # Goodput gauge: emitted tokens over a sliding ~10s window
         # (shared TokenRateWindow — counter-delta semantics, so it
         # agrees with the fleet collector's derivation by construction).
@@ -3107,21 +3165,23 @@ class Engine:
         }
         return step
 
-    def _emit_token(self, slot_idx: int, token_id: int, logprob: float | None = None, top=None):
-        """Deliver one generated token to the request; apply stop logic.
+    def _emit_token(self, slot_idx: int, token_id: int, logprob: float | None = None, top=None) -> bool:
+        """One generated token of the slot's request: apply stop logic and
+        put its event in the slot's outbox, for the caller's `_hand_over`.
         Events are ("token", id, text_delta, logprob, top) — the logprob
         is the model's log p(token | prefix) (None for text-only
         flushes); *top* is the model's top-N alternatives at that choice
         point as [(token_id, logprob), ...] when the request asked for
-        logprobs, else None."""
+        logprobs, else None. False: the request ended at this token, and
+        `_free` has handed over what it was owed."""
         slot = self._slots[slot_idx]
         req = slot.req
         if req.cancelled.is_set():
             self._free(slot_idx, "stop", deliver=False)
-            return
+            return False
 
         slot.generated += 1
-        self.m_gen.inc()
+        slot.uncounted += 1
         if slot.generated == 1:
             # True TTFT: the request's first token reached emission.
             # (Observed here, not at slot admission — admission can be
@@ -3137,7 +3197,7 @@ class Engine:
         eos = self.tokenizer.eos_id
         if eos is not None and token_id == eos:
             self._free(slot_idx, "stop")
-            return
+            return False
 
         # push() returns only newly-completed text (incomplete trailing
         # UTF-8 held back), keeping per-token work O(delta).
@@ -3156,9 +3216,9 @@ class Engine:
                 ev = ("token", token_id, tail, logprob, top)
                 if slot.event_log is not None:
                     slot.event_log.append(ev)
-                req.out.put(ev)
+                slot.outbox.append(ev)
                 self._free(slot_idx, "stop", flush=False)
-                return
+                return False
 
         emit_upto = max(len(text) - slot.holdback, slot.delivered_chars)
         delta = text[slot.delivered_chars : emit_upto]
@@ -3166,10 +3226,27 @@ class Engine:
         ev = ("token", token_id, delta, logprob, top)
         if slot.event_log is not None:
             slot.event_log.append(ev)
-        req.out.put(ev)
+        slot.outbox.append(ev)
 
         if slot.generated >= slot.budget:
             self._free(slot_idx, "length")
+            return False
+        return True
+
+    def _hand_over(self, slot: "_Slot") -> None:
+        """Give the slot's request, in ONE operation on its queue, the
+        events made for it since the last hand-over."""
+        if slot.uncounted:
+            # Before the reader wakes: a client that has its tokens finds
+            # them on the counter.
+            self.m_gen.inc(slot.uncounted)
+            self._handed[0] += slot.uncounted
+            slot.uncounted = 0
+        if slot.outbox:
+            events, slot.outbox = slot.outbox, []
+            self.m_handovers.inc()
+            self._handed[1] += 1
+            slot.req.out.put_many(events)
 
     def _free(self, slot_idx: int, reason: str, deliver: bool = True, flush: bool = True,
               outcome: str | None = None):
@@ -3208,10 +3285,12 @@ class Engine:
                         reason = "stop"
                 tail = text[slot.delivered_chars : end]
                 if tail:
-                    slot.req.out.put(("token", -1, tail, None, None))
-            slot.req.out.put(
+                    slot.outbox.append(("token", -1, tail, None, None))
+            slot.outbox.append(
                 ("done", FinishInfo(reason, slot.prompt_len, slot.generated, kv=offer))
             )
+        # With whatever this chunk made for the request before it ended.
+        self._hand_over(slot)
         self._finish_request(
             slot.req, outcome or ("ok" if deliver else "cancelled"),
             finish_reason=reason, completion_tokens=slot.generated,

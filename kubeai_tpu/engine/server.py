@@ -31,7 +31,7 @@ import time
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-from kubeai_tpu.engine.core import Engine
+from kubeai_tpu.engine.core import Engine, EventQueue
 from kubeai_tpu.engine import kvstate
 from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.faults import FaultError, fault, handle_faults_request, set_thread_scope
@@ -1144,15 +1144,27 @@ def _make_handler(srv: EngineServer):
                 # in a multi-replica single-process drill fleet).
                 fault("engine.stream")
                 data = f"data: {payload}\n\n".encode()
-                self.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
-                self.wfile.flush()
+                frames.append(f"{len(data):x}\r\n".encode() + data + b"\r\n")
+
+            # The frames of the events taken at one wake leave in ONE
+            # write: a decode chunk hands a request its tokens at once
+            # (Engine._hand_over), and a system call a frame would undo
+            # that. The bytes and their order are those of a write a frame.
+            frames: list[bytes] = []
+
+            def flush():
+                if frames:
+                    data = b"".join(frames)
+                    frames.clear()
+                    self.wfile.write(data)
+                    self.wfile.flush()
 
             obj = "chat.completion.chunk" if chat else "text_completion"
 
             # n > 1 choices decode concurrently; their events interleave
             # into one SSE stream tagged by choice index (OpenAI's n>1
             # stream shape) via a merge queue fed by one pump per choice.
-            merged: "queue.Queue[tuple[int, tuple]]" = queue.Queue()
+            merged: "EventQueue[tuple[int, tuple]]" = EventQueue()
 
             def pump(idx, r):
                 # Short poll + cancellation check: a cancelled request's
@@ -1162,7 +1174,7 @@ def _make_handler(srv: EngineServer):
                 waited = 0.0
                 while True:
                     try:
-                        ev = r.out.get(timeout=1.0)
+                        evs = r.out.get_many(timeout=1.0)
                     except queue.Empty:
                         if r.cancelled.is_set():
                             return
@@ -1172,8 +1184,8 @@ def _make_handler(srv: EngineServer):
                             return
                         continue
                     waited = 0.0
-                    merged.put((idx, ev))
-                    if ev[0] in ("done", "error"):
+                    merged.put_many([(idx, ev) for ev in evs])
+                    if evs[-1][0] in ("done", "error"):
                         return
 
             if len(reqs) == 1:
@@ -1185,6 +1197,22 @@ def _make_handler(srv: EngineServer):
                 ]
                 for t in pumps:
                     t.start()
+
+            def events():
+                """The stream's (choice, event) pairs, one at a time:
+                everything that is there at one wake (a chunk's tokens)
+                before it blocks again, and the frames made so far are
+                written before it does."""
+                while True:
+                    flush()
+                    if pumps is None:
+                        try:
+                            evs = reqs[0].out.get_many(timeout=600)
+                        except queue.Empty:
+                            evs = [("error", "generation timed out")]
+                        yield from ((0, ev) for ev in evs)
+                    else:
+                        yield from merged.get_many()
 
             remaining = len(reqs)
             prompt_tokens = 0
@@ -1235,14 +1263,7 @@ def _make_handler(srv: EngineServer):
                             "choices": [{"index": idx, "text": echo_text,
                                          "finish_reason": None}],
                         }))
-                while remaining:
-                    if pumps is None:
-                        try:
-                            idx, ev = 0, reqs[0].out.get(timeout=600)
-                        except queue.Empty:
-                            idx, ev = 0, ("error", "generation timed out")
-                    else:
-                        idx, ev = merged.get()
+                for idx, ev in events():
                     if ev[0] == "token":
                         if ev[1] >= 0:
                             # Counted BEFORE the empty-delta skip: a
@@ -1335,8 +1356,8 @@ def _make_handler(srv: EngineServer):
                             send_chunk(usage_chunk())
                         if remaining == 0:
                             send_chunk("[DONE]")
-                            self.wfile.write(b"0\r\n\r\n")
-                            self.wfile.flush()
+                            frames.append(b"0\r\n\r\n")
+                            flush()
                             return
                     else:
                         _cancel_all(reqs)
@@ -1349,7 +1370,8 @@ def _make_handler(srv: EngineServer):
                             # tokens were unbillable.
                             send_chunk(usage_chunk())
                         send_chunk(json.dumps({"error": {"message": ev[1]}}))
-                        self.wfile.write(b"0\r\n\r\n")
+                        frames.append(b"0\r\n\r\n")
+                        flush()
                         return
             except FaultError:
                 # Injected mid-stream death: die like a crashed replica —
@@ -1360,6 +1382,9 @@ def _make_handler(srv: EngineServer):
 
                 _cancel_all(reqs)
                 try:
+                    # The frames of the events BEFORE the one it fired at
+                    # had left, a write each: they still leave.
+                    flush()
                     self.connection.shutdown(_socket.SHUT_RDWR)
                 except OSError:
                     pass

@@ -413,6 +413,48 @@ class TestEngineWiring:
         finally:
             eng.stop()
 
+    def test_tokens_per_handover_beside_the_emit_cause(self):
+        """/debug/engine -> perf.tokens_per_handover: generated tokens a
+        hand-over to a request. An admission's first token is one of one;
+        a decode chunk's tokens go over together, so the ratio says how
+        often that engaged (1.0: never), and the `emit` cause of a chunk
+        is still one segment and one `decode_chunk` observation."""
+        from kubeai_tpu.engine.core import build_test_engine
+        from kubeai_tpu.engine.sampling import SamplingParams
+
+        eng = build_test_engine()
+        assert eng._perf_debug_section()["tokens_per_handover"] is None  # nothing handed over yet
+        emit_segments = []
+        segment = eng._stall.segment
+
+        def counting(cause, **attrs):
+            if cause == "emit":
+                emit_segments.append(attrs)
+            return segment(cause, **attrs)
+
+        eng._stall.segment = counting
+        chunks = lambda: sum(  # noqa: E731
+            n for key, (_, _, n) in eng.m_step.snapshot().items() if ("phase", "decode_chunk") in key
+        )
+        c0 = chunks()
+        eng.start()
+        try:
+            K = eng.cfg.decode_chunk
+            _, _, fin = eng.generate(
+                list(b"hello there"), SamplingParams(temperature=0.7, seed=3, max_tokens=1 + 2 * K), timeout=120,
+            )
+            time.sleep(0.2)
+        finally:
+            eng.stop()
+        n = fin.completion_tokens
+        tokens, handovers = eng._handed
+        assert tokens == n and n > 1
+        # The first token alone, then at most a hand-over a chunk.
+        assert 2 <= handovers <= 1 + -(-(n - 1) // K)
+        assert eng._perf_debug_section()["tokens_per_handover"] == round(n / handovers, 3) > 1.0
+        assert chunks() - c0 == len(emit_segments) >= handovers - 1
+        assert "kubeai_engine_emit_handovers_total" in default_registry.render()
+
     def test_mfu_roofline_gauges_on_metrics_page(self):
         from kubeai_tpu.engine.core import build_test_engine
 

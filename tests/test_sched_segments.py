@@ -66,6 +66,60 @@ def test_causes_sum_to_the_loops_wall_time():
         eng.stop()
 
 
+def test_one_emit_segment_and_one_observation_a_chunk():
+    """`host_work_per_chunk_ms` divides the seconds under `sweep` + `admit`
+    + `dispatch` + `host_overlap` + `emit` by the count of `decode_chunk`
+    observations: a chunk's delivery, however many requests it hands
+    their tokens to, is still ONE `emit` segment that brackets all of it
+    and ONE observation."""
+    from kubeai_tpu.metrics import default_registry
+
+    eng = build_test_engine()
+    emits: list = []
+    segment = eng._stall.segment
+
+    def counting(cause, **attrs):
+        seg = segment(cause, **attrs)
+        if cause == "emit":
+            emits.append((seg, attrs))
+        return seg
+
+    eng._stall.segment = counting
+    observed = lambda: sum(  # noqa: E731
+        n for key, (_, _, n) in eng.m_step.snapshot().items() if ("phase", "decode_chunk") in key
+    )
+    handovers = default_registry.counter("kubeai_engine_emit_handovers_total")
+    generated = default_registry.counter("kubeai_engine_generated_tokens_total")
+    emit_s = lambda: eng._stall._counter.value(labels={"cause": "emit"})  # noqa: E731
+    eng.start()
+    try:
+        for t in _generate(eng):  # compile first
+            t.join()
+        time.sleep(0.2)
+        default_recorder.clear()
+        n0, o0, h0, g0, e0 = len(emits), observed(), handovers.value(), generated.value(), emit_s()
+        for t in _generate(eng, n_requests=4, max_tokens=40):
+            t.join()
+        time.sleep(0.2)
+    finally:
+        eng.stop()
+    chunks = len(emits) - n0
+    assert chunks >= 5
+    assert observed() - o0 == chunks
+    # ... and ONE step record, which carries that segment's time.
+    steps = [s for s in default_recorder.engine_steps() if s["kind"] == "decode_chunk"]
+    assert len(steps) == chunks and all(s["emit_ms"] > 0 for s in steps)
+    # The segment covers the whole delivery: its seconds are the counter's.
+    assert sum(seg.seconds for seg, _ in emits[n0:]) == pytest.approx(emit_s() - e0, rel=1e-6, abs=1e-6)
+    # What it announces on the trace is still the chunk's tokens, K a live slot.
+    assert all(a["tokens"] % eng.cfg.decode_chunk == 0 and a["tokens"] > 0 for _, a in emits[n0:])
+    # ... and a request was handed its tokens once a chunk, not once a token:
+    # its first token alone, then a hand-over for each chunk it decoded in.
+    tokens, handed = generated.value() - g0, handovers.value() - h0
+    assert tokens >= 4 * eng.cfg.decode_chunk
+    assert 4 <= handed <= 4 + (tokens - 4) / eng.cfg.decode_chunk + 4
+
+
 @pytest.mark.parametrize("python_tracer", ["default", "0"])
 def test_profiler_capture_holds_the_segments_on_the_scheduler_line(tmp_path, python_tracer):
     from jax.profiler import ProfileData
