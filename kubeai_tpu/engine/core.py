@@ -30,6 +30,8 @@ engine image matrix; SURVEY.md §2.9). Architecture:
 
 from __future__ import annotations
 
+import bisect
+import math
 import queue
 import threading
 import time
@@ -80,6 +82,10 @@ log = get_logger("kubeai_tpu.engine")
 # The scheduler segments a decode chunk's step record carries, as
 # dispatch_ms / host_overlap_ms / fetch_wait_ms / emit_ms.
 _CHUNK_SEGMENTS = ("dispatch", "host_overlap", "fetch_wait", "emit")
+
+# What a queued request waits for (Engine._queue_parts), as the labels of
+# kubeai_engine_queue_wait_by_cause_seconds_total.
+_QUEUE_CAUSES = ({"cause": "turn"}, {"cause": "slots"}, {"cause": "pages"})
 
 
 def _name_os_thread(name: str) -> None:
@@ -293,11 +299,20 @@ class EventQueue(queue.Queue):
     """A request's events: `queue.Queue`'s surface (`put(ev)`, `get(timeout=)`
     of ONE event) and the two calls of a hand-over. The tokens of a decode
     chunk reach the host together, so the scheduler hands a request its
-    share of them at once and the reader is woken once for all of them."""
+    share of them at once and the reader is woken once for all of them.
+    `handed_at` is the stamp (`put_many`'s *stamp*) of the oldest hand-over
+    whose events are still here, `taken_at` that stamp for what the last
+    `get_many` took (None: its events came with no stamp), for the queue's
+    one reader: from hand-over to bytes written is the reader's to measure."""
 
-    def put_many(self, events) -> None:
+    handed_at: float | None = None
+    taken_at: float | None = None
+
+    def put_many(self, events, stamp: float | None = None) -> None:
         """`put` for each of *events*, under one lock and with one notify."""
         with self.not_empty:
+            if self.handed_at is None:
+                self.handed_at = stamp
             self.queue.extend(events)
             self.unfinished_tasks += len(events)
             self.not_empty.notify(len(events))
@@ -309,6 +324,7 @@ class EventQueue(queue.Queue):
         with self.mutex:
             rest = list(self.queue)
             self.queue.clear()
+            self.taken_at, self.handed_at = self.handed_at, None
         return [first, *rest]
 
 
@@ -523,8 +539,22 @@ class Engine:
         )
         self.m_queue_wait = default_registry.histogram(
             "kubeai_engine_queue_wait_seconds",
-            "submit to prefill dispatch (slot + KV page wait)",
+            "submit to prefill dispatch: the admission turn, then slots, then pages "
+            "(kubeai_engine_queue_wait_by_cause_seconds_total divides its sum)",
         )
+        self.m_queue_by_cause = default_registry.counter(
+            "kubeai_engine_queue_wait_by_cause_seconds_total",
+            "kubeai_engine_queue_wait_seconds_sum by what the request waited for: "
+            "turn (the scheduler's next admission round, one chunk turnaround apart "
+            "under load, and the round's own work before the request's prefill "
+            "dispatch) | slots (rounds that ended with every slot busy) | pages "
+            "(rounds that left the head of the line deferred on the KV pool)",
+        )
+        # Admission rounds (_plan_admissions), oldest first: (stamp, seconds
+        # since the first of them that a waiting request waited for slots,
+        # for pages), and why the newest stopped taking requests.
+        self._rounds: list[tuple[float, float, float]] = []
+        self._round_stopped = "empty"
         self.m_prefill_s = default_registry.histogram(
             "kubeai_engine_prefill_seconds",
             "per request: its prefill dispatch to its first emitted token (a "
@@ -1437,10 +1467,13 @@ class Engine:
         end = tr.end_mono
         t_prefill = tr.first_mark("prefill")
         # Queue wait ends at prefill dispatch; a request that never made
-        # it to a slot waited its whole life.
-        self.m_queue_wait.observe(
-            (t_prefill if t_prefill is not None else end) - tr.t0_mono
-        )
+        # it to a slot waited its whole life, divided by the same rule.
+        admitted = t_prefill if t_prefill is not None else end
+        self.m_queue_wait.observe(admitted - tr.t0_mono)
+        if tr.queue_parts is None:
+            tr.queue_parts = self._queue_parts(tr.t0_mono, admitted)
+        for labels, seconds in zip(_QUEUE_CAUSES, tr.queue_parts):
+            self.m_queue_by_cause.inc(seconds, labels=labels)
         if t_prefill is not None:
             first_tok = tr.tokens[0] if tr.tokens else end
             self.m_prefill_s.observe(first_tok - t_prefill)
@@ -1472,6 +1505,7 @@ class Engine:
         park_kv: str = "",
         restore: Any = None,
         restore_key: str = "",
+        received: float | None = None,
     ) -> Request:
         """Enqueue a request; raises queue.Full when saturated (the proxy
         retries another replica, and the server maps it to 429 +
@@ -1480,7 +1514,9 @@ class Engine:
         the request to an inbound trace (proxy hop); omitted, a fresh
         trace is generated — every request gets a timeline. *deadline*
         (time.monotonic()-based) lets the scheduler abort the request —
-        queued or mid-decode — once the caller's budget is spent."""
+        queued or mid-decode — once the caller's budget is spent.
+        *received* (time.monotonic()) is when the server took the request
+        in, before it read the body: the timeline's `receive` phase."""
         # Failpoint: chaos tests inject admission errors/delays/hangs.
         fault("engine.submit")
         # The prompt plus at least one generated token must fit both the
@@ -1508,7 +1544,7 @@ class Engine:
             park_kv=park_kv, restore=restore, restore_key=restore_key,
         )
         req.trace = RequestTrace(
-            ctx=trace_ctx, component="engine", t0_mono=req.arrival
+            ctx=trace_ctx, component="engine", t0_mono=req.arrival, received=received
         )
         req.trace.attrs["prompt_tokens"] = len(prompt_ids)
         req.trace.attrs["priority"] = priority
@@ -2015,6 +2051,13 @@ class Engine:
         # fault ONE replica of a multi-replica in-process fleet.
         faults.set_thread_scope(getattr(self, "fault_scope", None))
         _name_os_thread("engine-loop")  # the line a profiler trace gives this thread
+        perf_obs.gc_watch.install()  # collections on the record while a loop runs
+        try:
+            self._loop_iterations()
+        finally:
+            perf_obs.gc_watch.remove()
+
+    def _loop_iterations(self):
         # Every statement of an iteration runs under exactly one segment
         # (obs/perf.py STALL_CAUSES): stamped once, the stamps feed the
         # stall counter, /debug/pipeline, the step records and, while a
@@ -2044,6 +2087,7 @@ class Engine:
                 pending = dispatched
                 with segment("sweep"):
                     self._update_recompile_counter()
+                    perf_obs.gc_watch.flush()
                 if (
                     pending is None and not admitted and self._n_active == 0
                     and self._aux.empty()
@@ -2306,7 +2350,11 @@ class Engine:
 
     def _plan_admissions(self, admitted: list, taken: set[int], max_bucket: int) -> list:
         """Drain the queue into free slots and reserved pages; returns the
-        prefill calls to make, in dispatch order, as (items, thunk)."""
+        prefill calls to make, in dispatch order, as (items, thunk). One
+        admission ROUND: stamped once, with why it stopped taking requests
+        (what those it left behind wait for until the next: _queue_parts)."""
+        self._stamp_round()
+        stopped = "empty"
         singles: list[tuple[int, int, "Request", int]] = []  # (seq, slot, req, reuse)
         groups: dict[int, list[tuple[int, "Request"]]] = {}  # bucket -> items
         seq = 0
@@ -2317,6 +2365,7 @@ class Engine:
                 # of waiting behind bulk work (docs/qos.md); otherwise
                 # this admission round is done.
                 if self._peek_priority() != "interactive" or not self._preempt_one(taken):
+                    stopped = "slots"
                     break
             # Pool-blocked requests wait at the head of the line, but a
             # strictly higher class arriving behind them may overtake:
@@ -2359,6 +2408,7 @@ class Engine:
                 if res == "defer":
                     self._deferred.insert(0, req)
                     self.m_queue.set(self.queue_depth())
+                    stopped = "pages"
                     break
                 # res is None: restore failed — req.restore was cleared,
                 # fall through to the replay (prefill) admission below.
@@ -2371,6 +2421,7 @@ class Engine:
                 # KV pool can't back prompt+budget yet; wait for a free.
                 self._deferred.insert(0, req)
                 self.m_queue.set(self.queue_depth())
+                stopped = "pages"
                 break
             slot_idx, reuse = plan
             taken.add(slot_idx)
@@ -2384,6 +2435,7 @@ class Engine:
             else:
                 singles.append((seq, slot_idx, req, reuse))
             seq += 1
+        self._round_stopped = stopped
 
         work: list[tuple[list, Any]] = []  # (items, thunk)
         # Groups first: shared pages registered by a cold group member
@@ -2414,6 +2466,59 @@ class Engine:
 
             work.append(([(slot_idx, req)], one))
         return work
+
+    ROUNDS_KEPT = 4096  # admission rounds looked back over: minutes of chunk turnarounds
+
+    def _stamp_round(self) -> None:
+        """An admission round begins: one stamp, and the seconds since the
+        last round's onto what that round left its requests waiting for."""
+        now = time.monotonic()
+        rounds = self._rounds
+        slots = pages = 0.0
+        if rounds:
+            last, slots, pages = rounds[-1]
+            if self._round_stopped == "slots":
+                slots += now - last
+            elif self._round_stopped == "pages":
+                pages += now - last
+        rounds.append((now, slots, pages))
+        if len(rounds) > 2 * self.ROUNDS_KEPT:
+            # A new list: a finisher on another thread keeps the one it has.
+            self._rounds = rounds[-self.ROUNDS_KEPT :]
+
+    def _queue_parts(self, arrival: float, until: float) -> tuple[float, float, float]:
+        """The wait from *arrival* to *until* by what it was for, in
+        seconds that add up to it: (turn, slots, pages). Over the rounds
+        R1 < ... < Rk stamped inside it, each R(i+1) - Ri is for what round
+        i stopped at, every slot busy or the head of the line deferred on
+        the pool; `turn` is the rest: R1 - arrival, the wait for the
+        scheduler to come round to admission (one chunk turnaround apart
+        under load), and until - Rk, the admitting round's own work before
+        this request's prefill dispatch. Two bisections of the kept rounds;
+        what lies before the oldest kept one counts as `turn`."""
+        rounds = self._rounds
+        first = bisect.bisect_right(rounds, (arrival, math.inf))
+        last = bisect.bisect_right(rounds, (until, math.inf)) - 1
+        slots = pages = 0.0
+        if last > first:
+            slots = rounds[last][1] - rounds[first][1]
+            pages = rounds[last][2] - rounds[first][2]
+        return max(until - arrival - slots - pages, 0.0), slots, pages
+
+    def _admitted(self, req: Request) -> None:
+        """*req*'s prefill is the next thing dispatched: its queue wait
+        ends with this stamp and is divided here, and a profiler trace
+        gets the division as a `req.admit` event on this thread's line."""
+        tr = req.trace
+        if tr is None:
+            return
+        tr.mark("prefill")
+        now = tr.marks[-1][1]
+        turn, slots, pages = tr.queue_parts = self._queue_parts(tr.t0_mono, now)
+        perf_obs.trace_mark(
+            "req.admit", rid=tr.rid, waited_ms=(now - tr.t0_mono) * 1e3,
+            turn_ms=turn * 1e3, slots_ms=slots * 1e3, pages_ms=pages * 1e3,
+        )
 
     def _run_prefills(self, work: list) -> None:
         for w, (items, thunk) in enumerate(work):
@@ -2689,8 +2794,8 @@ class Engine:
         ids = req.prompt_ids
         sp = req.params
         seed = self._seed32(sp)
+        self._admitted(req)
         if req.trace is not None:
-            req.trace.mark("prefill")
             req.trace.attrs["reuse_tokens"] = reuse
         plan = prefill_plan(self.cfg, len(ids) - reuse)
         widest = plan[0][0]  # the calls come widest first
@@ -2699,6 +2804,7 @@ class Engine:
         with self._stall.segment(
             "prefill", kind="chunk", bucket=widest, batch=1,
             tokens=len(ids) - reuse, cached=reuse, pad=pad_tokens,
+            calls=len(plan), rid=req.trace.rid if req.trace is not None else "",
         ) as seg:
             out = self._prefill_chunks(slot_idx, req, reuse, seed, plan)
         self.m_step.observe(seg.seconds, labels={"phase": "prefill_chunked"})
@@ -2859,13 +2965,14 @@ class Engine:
         that was sent, so the call's padding is its rows' bucket tails."""
         n = len(items)
         for _, req in items:
-            if req.trace is not None:
-                req.trace.mark("prefill")
+            self._admitted(req)
+        first = items[0][1].trace
         real_tokens = int(sum(len(r.prompt_ids) for _, r in items))
         pad_tokens = n * bucket - real_tokens
         with self._stall.segment(
             "prefill", kind="group", bucket=bucket, batch=n,
             tokens=real_tokens, cached=0, pad=pad_tokens,
+            calls=1, rid=first.rid if first is not None else "",
         ) as seg:
             out = self._prefill_group_call(items, bucket)
         self.m_step.observe(seg.seconds, labels={"phase": "prefill_group"})
@@ -3073,6 +3180,8 @@ class Engine:
         # iteration's segments (its dispatch and host overlap, and the
         # fetch and emission of the chunk dispatched one iteration ago).
         ms = self._stall.end_step("decode_chunk")
+        if "gc_ms" in ms:  # the cyclic collector ran inside this iteration
+            step["gc_ms"] = round(ms["gc_ms"], 3)
         default_recorder.record_step(
             **step, **{f"{c}_ms": round(ms.get(c, 0.0), 3) for c in _CHUNK_SEGMENTS}
         )
@@ -3246,7 +3355,9 @@ class Engine:
             events, slot.outbox = slot.outbox, []
             self.m_handovers.inc()
             self._handed[1] += 1
-            slot.req.out.put_many(events)
+            # Stamped for the reader: from here to its bytes written is
+            # the delivery's lag (engine/server.py, deliver_lag).
+            slot.req.out.put_many(events, time.monotonic())
 
     def _free(self, slot_idx: int, reason: str, deliver: bool = True, flush: bool = True,
               outcome: str | None = None):

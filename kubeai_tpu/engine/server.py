@@ -31,7 +31,7 @@ import time
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-from kubeai_tpu.engine.core import Engine, EventQueue
+from kubeai_tpu.engine.core import Engine, EventQueue, _name_os_thread
 from kubeai_tpu.engine import kvstate
 from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.faults import FaultError, fault, handle_faults_request, set_thread_scope
@@ -64,7 +64,9 @@ from kubeai_tpu.obs.history import (
     installed_history,
     uninstall_history,
 )
+from kubeai_tpu.obs import perf as perf_obs
 from kubeai_tpu.obs.perf import handle_perf_request
+from kubeai_tpu.obs.recorder import amend_decode
 from kubeai_tpu.obs.tenants import TENANT_HEADER, sanitize_tenant
 from kubeai_tpu.qos import (
     DEFAULT_CLASS,
@@ -95,6 +97,26 @@ M_RESUMED = default_registry.counter(
     "or a mid-stream crash replay): the deterministic prefix is "
     "regenerated here and the proxy suppresses it",
 )
+# A first token's two stages on this side of the scheduler (PERF.md
+# section 3, "engine server"), each stamped by the serving thread.
+M_RECEIVE = default_registry.histogram(
+    "kubeai_engine_receive_seconds",
+    "entry of a completions POST (before the body is read) to submit() "
+    "returned: body read, JSON parse, tokenization; once per submitted "
+    "request, never for one refused before it",
+)
+M_DELIVER_LAG = default_registry.counter(
+    "kubeai_engine_deliver_lag_seconds_total",
+    "streamed responses: seconds from the scheduler's hand-over of events "
+    "to the serving thread's write of their frames done (the wake, the "
+    "framing, the socket), summed over writes; which=first is a request's "
+    "first such write, later the rest",
+)
+M_DELIVER_WRITES = default_registry.counter(
+    "kubeai_engine_deliver_writes_total",
+    "the writes kubeai_engine_deliver_lag_seconds_total sums over, by which",
+)
+_FIRST, _LATER = {"which": "first"}, {"which": "later"}
 
 
 class EngineServer:
@@ -357,6 +379,12 @@ def _make_handler(srv: EngineServer):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
 
+        def setup(self):
+            # Once a connection: the line a profiler trace gives this
+            # thread (its serve.* events; who runs beside engine-loop).
+            _name_os_thread("engine-serve")
+            super().setup()
+
         def log_message(self, fmt, *args):
             log.debug("%s " + fmt, self.address_string(), *args)
 
@@ -507,6 +535,7 @@ def _make_handler(srv: EngineServer):
                 self._error(404, f"no route {path}")
 
         def do_POST(self):
+            received = time.monotonic()  # the `receive` stage begins: before the body is read
             set_thread_scope(srv.port)  # per-replica failpoint twins
             path = self.path.split("?")[0]
             # Correlation id propagated by the proxy (X-Request-ID): one
@@ -582,7 +611,8 @@ def _make_handler(srv: EngineServer):
                         "already delivered upstream", rid, resume_tokens,
                     )
             try:
-                body = json.loads(self._read_body() or b"{}")
+                raw = self._read_body()
+                body = json.loads(raw or b"{}")
             except json.JSONDecodeError as e:
                 return self._error(400, f"invalid JSON: {e}")
             if srv.draining.is_set() and path.startswith("/v1/") and path != "/v1/models":
@@ -610,12 +640,14 @@ def _make_handler(srv: EngineServer):
                         body, chat=False, trace_ctx=trace_ctx, deadline=deadline,
                         resume_tokens=resume_tokens, tenant=tenant,
                         priority=priority, preemptible=preemptible,
+                        received=received, body_bytes=len(raw),
                     )
                 elif path == "/v1/chat/completions":
                     self._completions(
                         body, chat=True, trace_ctx=trace_ctx, deadline=deadline,
                         resume_tokens=resume_tokens, tenant=tenant,
                         priority=priority, preemptible=preemptible,
+                        received=received, body_bytes=len(raw),
                     )
                 elif path == "/v1/embeddings":
                     self._embeddings(body)
@@ -709,7 +741,8 @@ def _make_handler(srv: EngineServer):
             return prompt, None
 
         def _completions(self, body: dict, chat: bool, trace_ctx=None, deadline=None, resume_tokens=0, tenant="",
-                         priority: str = DEFAULT_CLASS, preemptible: bool = False):
+                         priority: str = DEFAULT_CLASS, preemptible: bool = False,
+                         received: float | None = None, body_bytes: int = 0):
             tok = srv.engine.tokenizer
             # Belt over the proxy stamp: preemption resumes via the
             # stream-replay cursor, so a non-streaming body can never
@@ -903,10 +936,20 @@ def _make_handler(srv: EngineServer):
                         deadline=deadline, tenant=tenant,
                         priority=priority, preemptible=preemptible,
                         park_kv=park_kv, restore=restore_state,
-                        restore_key=restore_key,
+                        restore_key=restore_key, received=received,
                     )
+                    if received is not None:
+                        # The `receive` stage ends: body read, JSON parse
+                        # and tokenization lie behind this thread.
+                        took = time.monotonic() - received
+                        M_RECEIVE.observe(took)
+                        perf_obs.trace_mark(
+                            "serve.receive", rid=r.trace.rid if r.trace is not None else "",
+                            prompt_tokens=len(prompt_ids), ms=took * 1e3,
+                        )
                     if r.trace is not None:
                         r.trace.model = srv.model_name
+                        r.trace.attrs["body_bytes"] = body_bytes
                         if n_choices > 1:
                             r.trace.attrs["choice"] = i
                         if resume_tokens:
@@ -1151,13 +1194,50 @@ def _make_handler(srv: EngineServer):
             # (Engine._hand_over), and a system call a frame would undo
             # that. The bytes and their order are those of a write a frame.
             frames: list[bytes] = []
+            # Delivery, measured where it ends: `taken` is (the stamp of
+            # the hand-over this thread woke for, its events) until their
+            # frames are written; `lag` the seconds from stamp to written
+            # as [first write's, all writes', writes, longest].
+            taken: tuple[float, int] | None = None
+            lag = [0.0, 0.0, 0, 0.0]
+            trace_rid = reqs[0].trace.rid if reqs[0].trace is not None else ""
 
             def flush():
+                nonlocal taken
                 if frames:
                     data = b"".join(frames)
                     frames.clear()
                     self.wfile.write(data)
                     self.wfile.flush()
+                    if taken is not None:
+                        seconds = time.monotonic() - taken[0]
+                        if not lag[2]:
+                            lag[0] = seconds
+                        lag[1] += seconds
+                        lag[2] += 1
+                        lag[3] = max(lag[3], seconds)
+                        perf_obs.trace_mark("serve.write", rid=trace_rid, events=taken[1], lag_ms=seconds * 1e3)
+                taken = None
+
+            def delivered():
+                """The stream ended: its writes onto the two counters, by
+                which, and onto the `decode` phase of its timelines."""
+                first, total, writes, longest = lag
+                if not writes:
+                    return
+                M_DELIVER_LAG.inc(first, labels=_FIRST)
+                M_DELIVER_WRITES.inc(1, labels=_FIRST)
+                if writes > 1:
+                    M_DELIVER_LAG.inc(total - first, labels=_LATER)
+                    M_DELIVER_WRITES.inc(writes - 1, labels=_LATER)
+                attrs = {
+                    "deliver_first_ms": round(first * 1e3, 3),
+                    "deliver_mean_ms": round(total / writes * 1e3, 3),
+                    "deliver_max_ms": round(longest * 1e3, 3),
+                }
+                for r in reqs:
+                    if r.trace is not None:
+                        amend_decode(r.trace, attrs)
 
             obj = "chat.completion.chunk" if chat else "text_completion"
 
@@ -1184,7 +1264,7 @@ def _make_handler(srv: EngineServer):
                             return
                         continue
                     waited = 0.0
-                    merged.put_many([(idx, ev) for ev in evs])
+                    merged.put_many([(idx, ev) for ev in evs], r.out.taken_at)
                     if evs[-1][0] in ("done", "error"):
                         return
 
@@ -1203,16 +1283,21 @@ def _make_handler(srv: EngineServer):
                 everything that is there at one wake (a chunk's tokens)
                 before it blocks again, and the frames made so far are
                 written before it does."""
+                nonlocal taken
                 while True:
                     flush()
                     if pumps is None:
+                        source = reqs[0].out
                         try:
-                            evs = reqs[0].out.get_many(timeout=600)
+                            evs = [(0, ev) for ev in source.get_many(timeout=600)]
                         except queue.Empty:
-                            evs = [("error", "generation timed out")]
-                        yield from ((0, ev) for ev in evs)
+                            evs, source = [(0, ("error", "generation timed out"))], None
                     else:
-                        yield from merged.get_many()
+                        source = merged
+                        evs = merged.get_many()
+                    if source is not None and source.taken_at is not None:
+                        taken = (source.taken_at, len(evs))
+                    yield from evs
 
             remaining = len(reqs)
             prompt_tokens = 0
@@ -1391,6 +1476,8 @@ def _make_handler(srv: EngineServer):
                 self.close_connection = True
             except (BrokenPipeError, ConnectionResetError):
                 _cancel_all(reqs)
+            finally:
+                delivered()
 
     return Handler
 

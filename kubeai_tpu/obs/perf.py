@@ -1,7 +1,7 @@
 """Perf X-ray: roofline/MFU accounting, step-pipeline stall attribution,
 and on-demand device profiler capture.
 
-Four pieces, all dependency-free (jax is imported lazily: by the profiler
+Five pieces, all dependency-free (jax is imported lazily: by the profiler
 capture, and for the TraceAnnotation of a scheduler segment):
 
 - **PerfModel** — model FLOPs/token and weight-bytes/token computed ONCE
@@ -20,6 +20,10 @@ capture, and for the TraceAnnotation of a scheduler segment):
   the scheduler loop once and feeds the ``GET /debug/pipeline`` report,
   the ``kubeai_engine_stall_seconds_total{cause}`` counter, the engine's
   step records and the ``sched.<cause>`` events of a profiler trace.
+- **GcWatch** — the interpreter's cyclic collector on the record: one
+  ``gc.callbacks`` function while an engine loop runs, feeding
+  ``kubeai_engine_gc_seconds_total{generation}``, the ``gc_ms`` of the
+  step a collection fell in, and a ``host.gc`` event of a profiler trace.
 - **ProfilerCapture** + ``handle_perf_request`` — ``GET
   /debug/profile?seconds=N`` starts a ``jax.profiler`` trace (single-
   flight; opt-in via ``KUBEAI_DEBUG_PROFILE=1``, mirroring the
@@ -30,6 +34,7 @@ capture, and for the TraceAnnotation of a scheduler segment):
 
 from __future__ import annotations
 
+import gc
 import logging
 import os
 import threading
@@ -339,6 +344,87 @@ def _trace_annotation():
     return _annotation
 
 
+def trace_mark(name: str, **attrs) -> None:
+    """A mark on the calling thread's line of a profiler trace: an event
+    with *attrs* and no length, where something ENDED whose length is one
+    of the attrs (a flag test while no profiler runs, as every event
+    through ``_trace_annotation``)."""
+    with _trace_annotation()(name, **attrs):
+        pass
+
+
+class GcWatch:
+    """The cyclic collector's runs, stamped by one ``gc.callbacks``
+    function that is installed while some engine loop runs (``install`` /
+    ``remove``, counted). A collection stops every Python thread of the
+    process, whichever thread set it off, so its seconds are the
+    process's: ``seconds`` by generation and ``total`` are cumulative and
+    written by the callback alone (collections never overlap). The
+    callback takes NO lock and feeds no metric: a collection can begin
+    between any two bytecodes of its thread, also inside a metric's own
+    locked region. ``flush`` (the scheduler loop, once an iteration: one
+    comparison where nothing was collected) moves what is new to
+    ``kubeai_engine_gc_seconds_total{generation}``; a tracker reads
+    ``total`` at ``end_step`` for the step's ``gc_ms``; while a profiler
+    runs, a ``host.gc`` event lies on the collecting thread's line."""
+
+    def __init__(self, registry=None):
+        self.seconds = [0.0, 0.0, 0.0]
+        self.total = 0.0
+        self._fed = [0.0, 0.0, 0.0]
+        self._fed_total = 0.0
+        self._open = None  # (start stamp, annotation) of the collection under way
+        self._users = 0
+        self._lock = threading.Lock()  # install / remove / flush; never the callback
+        self._counter = (registry or default_registry).counter(
+            "kubeai_engine_gc_seconds_total",
+            "seconds the interpreter's cyclic garbage collector ran, by generation "
+            "(every Python thread of the process stands still meanwhile); diagnosis: "
+            "a stall with no collection beside it was not the collector's",
+        )
+
+    def install(self) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self._on_gc)
+
+    def remove(self) -> None:
+        with self._lock:
+            self._users -= 1
+            if self._users == 0 and self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = _trace_annotation()("host.gc", generation=info["generation"])
+            ann.__enter__()
+            self._open = (time.monotonic(), ann)
+        elif self._open is not None:
+            (t0, ann), self._open = self._open, None
+            seconds = time.monotonic() - t0
+            self.seconds[info["generation"]] += seconds
+            self.total += seconds
+            if getattr(ann, "is_enabled", bool)():  # a profiler runs: what it freed, on the event
+                ann.set_metadata(collected=info["collected"])
+            ann.__exit__(None, None, None)
+
+    def flush(self) -> None:
+        if self.total == self._fed_total or not self._lock.acquire(blocking=False):
+            return
+        try:
+            self._fed_total = self.total
+            for gen, seconds in enumerate(self.seconds):
+                if seconds > self._fed[gen]:
+                    self._counter.inc(seconds - self._fed[gen], labels={"generation": str(gen)})
+                    self._fed[gen] = seconds
+        finally:
+            self._lock.release()
+
+
+gc_watch = GcWatch()
+
+
 class Segment:
     """One stamped slice of the scheduler thread's time (see
     ``PipelineStallTracker.segment``). After exit: ``t0``/``t1`` are the
@@ -399,6 +485,7 @@ class PipelineStallTracker:
         self._stack: list[Segment] = []  # open segments (scheduler thread only)
         self._last_end: float | None = None  # end stamp of the last outermost segment
         self._step: dict[str, float] = {}  # ms by cause since the last end_step
+        self._gc_seen = gc_watch.total  # the collector's seconds at the last end_step
         # The few slowest steps of the last ten minutes, (total ms, end
         # stamp, kind, ms by cause): what an operator asks after a stall.
         # ``_slow_floor`` is what a step must outlast to get in, until the
@@ -424,10 +511,16 @@ class PipelineStallTracker:
         """Close the current step's record: ms by cause of the segments
         that ended since the last call, counted in the window as one step
         of *kind* (decode_chunk | prefill_group | prefill_chunked |
-        kv_restore)."""
+        kv_restore). ``gc_ms`` beside the causes, where the cyclic
+        collector ran since the last call (GcWatch): inside the causes'
+        time, not beside it."""
         step, self._step = self._step, {}
         now = self._clock()
         total = sum(step.values()) - step.get("idle", 0.0)  # a wait for requests is no slow step
+        gc_total = gc_watch.total
+        if gc_total != self._gc_seen:
+            step["gc_ms"] = (gc_total - self._gc_seen) * 1000.0
+            self._gc_seen = gc_total
         with self._lock:
             self._records.append((now, kind, None))
             self._prune_locked(now)
@@ -452,8 +545,8 @@ class PipelineStallTracker:
         """The slowest steps of the last ten minutes, slowest first:
         kind, seconds since the step ended (``age_s``; ``end_monotonic`` is
         the stamp itself, this host's CLOCK_MONOTONIC), total ms (``idle``
-        left out) and ms by cause of the segments that ended inside the
-        step."""
+        left out), ms by cause of the segments that ended inside the
+        step and, where the cyclic collector ran inside it, ``gc_ms``."""
         now = self._clock() if now is None else now
         with self._lock:
             kept = [e for e in self._slowest if e[1] >= now - self.SLOWEST_HORIZON]
@@ -462,7 +555,9 @@ class PipelineStallTracker:
         return [
             {
                 "kind": kind, "age_s": round(now - end, 3), "end_monotonic": round(end, 6),
-                "total_ms": round(total, 3), "ms": {c: round(ms, 3) for c, ms in step.items()},
+                "total_ms": round(total, 3),
+                "ms": {c: round(ms, 3) for c, ms in step.items() if c != "gc_ms"},
+                **({"gc_ms": round(step["gc_ms"], 3)} if "gc_ms" in step else {}),
             }
             for total, end, kind, step in kept
         ]
@@ -642,7 +737,9 @@ class ProfilerCapture:
                 # reader takes its window from here, not from the first
                 # and last device operation.
                 with _trace_annotation()(PROFILE_WINDOW_EVENT, seconds=seconds):
+                    window = [time.monotonic()]
                     time.sleep(seconds)
+                    window.append(time.monotonic())
             finally:
                 t_stop = time.monotonic()
                 xplane = session.stop()  # collects and writes: the long part
@@ -667,6 +764,11 @@ class ProfilerCapture:
                 # whether the Python tracer ran beside it.
                 "xplane": xplane,
                 "window_event": PROFILE_WINDOW_EVENT,
+                # This host's CLOCK_MONOTONIC just inside that event's two
+                # ends: any monotonic stamp of this host (a timeline's
+                # phases, slowest_steps[].end_monotonic, a client's own
+                # records) goes onto the trace's clock by these alone.
+                "window_monotonic": window,
                 "python_tracer": bool(python_tracer),
                 # What the capture cost beyond the traced seconds.
                 "start_seconds": round(t_traced - t_start, 3),

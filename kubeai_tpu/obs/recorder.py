@@ -94,7 +94,14 @@ class FlightRecorder:
             try:
                 if observe is not None:
                     observe(tr)
-                self.record_timeline(assemble_request_trace(tr))
+                timeline = assemble_request_trace(tr)
+                self.record_timeline(timeline)
+                # For amend_decode: what the serving thread learns after
+                # this goes onto the dict in the ring. Either it has set
+                # `late` by now or it will find `timeline` set.
+                tr.timeline = timeline
+                if tr.late is not None:
+                    _amend_decode(timeline, tr.late)
             except Exception:
                 pass  # a malformed trace must never kill the worker
             finally:
@@ -200,15 +207,42 @@ class FlightRecorder:
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+def _amend_decode(timeline: dict, attrs: dict) -> None:
+    """*attrs* into *timeline*'s ``decode`` phase, as a new dict put in
+    the old one's place: a reader serializing the timeline meanwhile
+    keeps the dict it began with."""
+    for phase in timeline["phases"]:
+        if phase["name"] == "decode":
+            phase["attrs"] = {**phase["attrs"], **attrs}
+
+
+def amend_decode(tr: RequestTrace, attrs: dict) -> None:
+    """Attrs of the ``decode`` phase that are known only after the
+    scheduler let the trace go (the serving thread's delivery record: its
+    last write comes after the request's end). They go onto the trace, for
+    an assembly still to come, and onto the assembled timeline where there
+    is one: of the two threads at least one sees what the other wrote."""
+    tr.late = attrs
+    timeline = tr.timeline
+    if timeline is not None:
+        _amend_decode(timeline, attrs)
+
+
 def assemble_request_trace(tr: RequestTrace) -> dict:
     """RequestTrace (raw marks + token stamps) -> timeline dict with the
     canonical engine phases:
 
-    - ``queue``   submit -> prefill dispatch (slot + KV page wait)
+    - ``receive`` entry of the POST -> submit (body read, JSON parse,
+      tokenization), only where the serving thread stamped its entry;
+      it lies BEFORE the timeline's ``start_ms``
+    - ``queue``   submit -> prefill dispatch: the admission turn, then
+      slots, then pages (attrs ``turn_ms`` / ``slots_ms`` / ``pages_ms``
+      add up to the phase)
     - ``prefill`` prefill dispatch -> first emitted token
     - ``decode``  first token -> terminal (attrs carry per-token
       offsets, so TTFT/TPOT percentiles are recomputable from the
-      recorded timeline alone — bench.py does exactly that)
+      recorded timeline alone — bench.py does exactly that; and, of a
+      streamed request, ``deliver_*_ms``: hand-over to bytes written)
     """
     base = tr.t0_wall - tr.t0_mono
 
@@ -219,11 +253,20 @@ def assemble_request_trace(tr: RequestTrace) -> dict:
     phases: list[dict] = []
     t_prefill = tr.first_mark("prefill")
     t_first_tok = tr.tokens[0] if tr.tokens else None
+    if tr.received is not None:
+        phases.append({
+            "name": "receive",
+            "start_ms": ms(tr.received),
+            "duration_ms": round((tr.t0_mono - tr.received) * 1000, 3),
+            "attrs": {k: tr.attrs[k] for k in ("body_bytes", "prompt_tokens") if k in tr.attrs},
+        })
     phases.append({
         "name": "queue",
         "start_ms": ms(tr.t0_mono),
         "duration_ms": round(((t_prefill if t_prefill is not None else end) - tr.t0_mono) * 1000, 3),
-        "attrs": {},
+        "attrs": {} if tr.queue_parts is None else {
+            k: round(s * 1000, 3) for k, s in zip(("turn_ms", "slots_ms", "pages_ms"), tr.queue_parts)
+        },
     })
     if t_prefill is not None:
         phases.append({
@@ -248,6 +291,8 @@ def assemble_request_trace(tr: RequestTrace) -> dict:
         }
         if gaps:
             decode_attrs["tpot_ms_mean"] = round(sum(gaps) / len(gaps), 3)
+        if tr.late is not None:
+            decode_attrs.update(tr.late)
         phases.append({
             "name": "decode",
             "start_ms": ms(t_first_tok),

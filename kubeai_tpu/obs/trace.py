@@ -134,11 +134,21 @@ def extract_context(headers, fallback_request_id: str = "") -> TraceContext:
 class RequestTrace:
     """Timestamp collector for one engine request. The scheduler loop
     only ever calls ``mark``/``tok`` (a monotonic read + list append);
-    span assembly happens in the flight recorder's worker thread."""
+    span assembly happens in the flight recorder's worker thread.
+
+    Beside the marks, the stamps of a first token's other stages, each
+    written once by the thread that does the work: ``received`` (the
+    serving thread's read at the entry of the POST, before ``t0_mono``),
+    ``queue_parts`` (seconds of the queue wait by what it waited for:
+    turn, slots, pages; the scheduler, at the prefill dispatch) and
+    ``late`` (attrs of the ``decode`` phase the serving thread knows only
+    after its last write; obs/recorder.py ``amend_decode``, which needs
+    ``timeline``, the assembled dict, once there is one)."""
 
     __slots__ = (
         "ctx", "component", "model", "t0_wall", "t0_mono",
         "marks", "tokens", "end_mono", "outcome", "attrs",
+        "rid", "received", "queue_parts", "late", "timeline",
     )
 
     def __init__(
@@ -147,11 +157,20 @@ class RequestTrace:
         component: str = "engine",
         model: str = "",
         t0_mono: float | None = None,
+        received: float | None = None,
     ):
         self.ctx = ctx.child() if ctx is not None else extract_context({})
+        # What a profiler trace's req.* / serve.* events call the request:
+        # the trace id's first 8 hex digits behind a letter (a trace reader
+        # takes a value of digits alone, or with one `e`, for a number).
+        self.rid = "r" + self.ctx.trace_id[:8]
         self.component = component
         self.model = model
         self.t0_mono = time.monotonic() if t0_mono is None else t0_mono
+        self.received = received
+        self.queue_parts: tuple[float, float, float] | None = None
+        self.late: dict | None = None
+        self.timeline: dict | None = None
         # Wall anchor taken once; offsets are all monotonic.
         self.t0_wall = time.time() - (time.monotonic() - self.t0_mono)
         self.marks: list[tuple[str, float]] = []

@@ -631,9 +631,9 @@ def recorded(monkeypatch):
         log.setdefault(id(self), []).append(ev)
         return put(self, ev, *a, **kw)
 
-    def spy_put_many(self, evs):
+    def spy_put_many(self, evs, *a):
         log.setdefault(id(self), []).extend(evs)
-        return put_many(self, evs)
+        return put_many(self, evs, *a)
 
     monkeypatch.setattr(EventQueue, "put", spy_put)
     monkeypatch.setattr(EventQueue, "put_many", spy_put_many)

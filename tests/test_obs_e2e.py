@@ -92,13 +92,17 @@ def test_debug_requests_timeline_covers_e2e_latency(served):
 
     tl = _await_timeline(rid, "engine")
     names = [p["name"] for p in tl["phases"]]
-    assert names == ["queue", "prefill", "decode"], names
+    assert names == ["receive", "queue", "prefill", "decode"], names
     assert tl["outcome"] == "ok"
     assert tl["model"] == "m1"
-    # The phases partition the engine timeline...
-    phase_sum = sum(p["duration_ms"] for p in tl["phases"])
+    # The phases from submit on partition the engine timeline (`receive`,
+    # the server's own stage, lies before its start)...
+    receive = tl["phases"][0]
+    assert receive["start_ms"] + receive["duration_ms"] == pytest.approx(tl["start_ms"], abs=0.01)
+    phase_sum = sum(p["duration_ms"] for p in tl["phases"][1:])
     assert abs(phase_sum - tl["duration_ms"]) < 2.0
-    decode = tl["phases"][2]
+    phase_sum += receive["duration_ms"]  # what the proxy waited for holds it too
+    decode = tl["phases"][3]
     assert decode["attrs"]["tokens"] == body["usage"]["completion_tokens"]
 
     # The proxy recorded its own timeline joined on the SAME trace id.
