@@ -80,12 +80,12 @@ def child(ckpt: str, engine_args: list[str]) -> None:
     os._exit(0)  # the engine's gauges keep threads; nothing to drain
 
 
-def main() -> int:
-    if sys.argv[1] == "--child":
-        child(sys.argv[2], json.loads(sys.argv[3]))
-        return 0
-    cfg_path, n, out_path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
-    rehearse = "--rehearse" in sys.argv
+def deployment(cfg_path: str, rehearse: bool, kind: str, out_path: str, seed: str) -> tuple:
+    """A configuration's deployment made ready for child processes (also
+    benchmarks/prefill_call_cost.py's): (name, work directory under
+    `.perfbench_work/<kind>/`, the seeded checkpoint written there, the
+    serving's engine arguments, the children's environment: the compile
+    cache the engine server would use, the CPU for a rehearsal)."""
     with open(cfg_path) as f:
         config = json.load(f)
     hf = {k: v for k, v in config.items() if k not in NOT_HF}
@@ -94,7 +94,7 @@ def main() -> int:
         hf.update(config["rehearsal"]["hf_overrides"])
         serving.update({k: v for k, v in config["rehearsal"].items() if k != "hf_overrides"})
     name = os.path.basename(cfg_path).removesuffix(".json")
-    work = os.path.join(ROOT, ".perfbench_work", "starts", name)
+    work = os.path.join(ROOT, ".perfbench_work", kind, name)
     os.makedirs(work, exist_ok=True)
     os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
     hf_path = os.path.join(work, "hf_config.json")
@@ -105,19 +105,29 @@ def main() -> int:
     env["PYTHONUNBUFFERED"] = "1"
     env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(ROOT, ".jax_compile_cache"))
     ckpt = os.path.join(work, "ckpt")
-    t = time.monotonic()
     subprocess.run(
-        [sys.executable, os.path.join(ROOT, "perfbench", "children.py"), "checkpoint", ckpt, hf_path, "4800000001"],
+        [sys.executable, os.path.join(ROOT, "perfbench", "children.py"), "checkpoint", ckpt, hf_path, seed],
         env={**env, "JAX_PLATFORMS": "cpu"}, check=True, stdout=subprocess.DEVNULL,
     )
-    print(f"{name}: checkpoint {time.monotonic() - t:.1f}s", flush=True)
     if rehearse:
         env["JAX_PLATFORMS"] = "cpu"
+    return name, work, ckpt, serving["engine_args"], env
+
+
+def main() -> int:
+    if sys.argv[1] == "--child":
+        child(sys.argv[2], json.loads(sys.argv[3]))
+        return 0
+    cfg_path, n, out_path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
+    rehearse = "--rehearse" in sys.argv
+    t = time.monotonic()
+    name, work, ckpt, engine_args, env = deployment(cfg_path, rehearse, "starts", out_path, "4800000001")
+    print(f"{name}: checkpoint {time.monotonic() - t:.1f}s", flush=True)
     for i in range(n):
         t = time.monotonic()
         with open(os.path.join(work, f"start-{i}.log"), "wb") as err:
             proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--child", ckpt, json.dumps(serving["engine_args"])],
+                [sys.executable, os.path.abspath(__file__), "--child", ckpt, json.dumps(engine_args)],
                 env=env, stdout=subprocess.PIPE, stderr=err,
             )
         wall = time.monotonic() - t

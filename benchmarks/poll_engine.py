@@ -44,8 +44,11 @@ def main() -> None:
                 perf = {key: perf.get(key) for key in perf_keys}
             except Exception as e:
                 perf = {"error": str(e)}
+            metrics = [l for l in body.splitlines() if l.startswith(prefixes)]
+            if not metrics and "error" in perf:
+                continue  # the operator's own port: it names the engine's series and has no perf section
             with open(out, "w") as f:
-                json.dump({"at": time.time(), "port": port, "metrics": [l for l in body.splitlines() if l.startswith(prefixes)], "perf": perf}, f)
+                json.dump({"at": time.time(), "port": port, "metrics": metrics, "perf": perf}, f)
         time.sleep(5)
 
 

@@ -143,7 +143,10 @@ class EngineConfig:
     # count and let warmup cover every shape. This names the full-group
     # shape only: a round's same-bucket cold prompts run as calls of
     # this many rows while that many are left, and the rest as one-row
-    # calls, so no call computes a row nobody sent (_plan_admissions).
+    # calls, so no call computes a row nobody sent; where warm-up has
+    # measured what a call costs, one of the rest may instead ride a
+    # chunk call of two slots as a row that starts at 0, where that saves
+    # its own read of the weights (_plan_admissions, round_calls).
     prefill_group_cap: int = 8
     # Paged KV: tokens per page. 64 keeps TPU tiling happy (page x head
     # dims land on (16,128)+ bf16 tiles) while giving fine-grained HBM
@@ -203,27 +206,101 @@ def wide_chunk(cfg: EngineConfig) -> int:
     return 2 * top if cfg.max_seq_len > 2 * top else top
 
 
-def prefill_plan(cfg: EngineConfig, left: int) -> list[tuple[int, int]]:
+# What a prefill call costs, as (seconds to read the held weights once,
+# seconds a row): Engine.call_cost, measured at warm-up where the device is
+# one obs/perf.py knows. Until then, and on any other device, a read costs
+# nothing, so every choice below falls to the fewest rows.
+UNKNOWN_DEVICE = (0.0, 1.0)
+
+
+def call_seconds(cost: tuple[float, float], n: int, rows: int) -> float:
+    """A call of *n* slots x *rows*: every weight is read once, whatever
+    the rows, and every row pays its own work."""
+    return cost[0] + n * rows * cost[1]
+
+
+def pair_rows(cfg: EngineConfig, model_config: ModelConfig) -> tuple[int, ...]:
+    """The row counts compiled for TWO slots a chunk call: the three
+    widest (512, 1024 and the wide chunk as configured). None with one
+    slot, and none for a family with REUSE_WHOLE_PREFILL_CALLS: it promises
+    a prompt the bits of its own cold prefill whatever was cached
+    (models/deepseek.py), and a piece padded up to a partner's rows in a
+    [2, rows] program is another program and shape than the [1, own rows]
+    call the prompt runs alone."""
+    if cfg.max_slots < 2 or family(model_config).REUSE_WHOLE_PREFILL_CALLS:
+        return ()
+    return tuple(sorted({*cfg.prefill_buckets, wide_chunk(cfg)})[-3:])
+
+
+def prefill_plan(cfg: EngineConfig, left: int, cost: tuple[float, float] = UNKNOWN_DEVICE) -> list[tuple[int, int]]:
     """The chunk calls that prefill the *left* tokens of a prompt behind
-    whatever is cached, as (rows of the call, real tokens in it): wide
-    calls while that many tokens are left (a chunk reads every weight
-    once whatever its rows, and an expert family's chunk is bound by
-    reading them: PERF.md section 6, PR 43), then one call of the
-    largest bucket if more than that is left, then the tail in the
-    smallest bucket that holds it. Only the last call is padded. Reads
-    nothing but the tokens left: the plan of a prompt's last `left`
-    tokens is the end of the plan of the whole prompt wherever the cut
-    falls on a call's edge (_plan_admission cuts a hit there for the
-    families with REUSE_WHOLE_PREFILL_CALLS)."""
+    whatever is cached, as (rows of the call, real tokens in it), widest
+    first; only the last call is padded. Wide calls while that many
+    tokens are left, then the tail in the smallest bucket that holds it,
+    behind one call of the largest bucket if more than that is left; or,
+    in that last case, ONE padded wide call where that costs less under
+    *cost* (call_seconds): the one comparison a prompt. So a read is only
+    ever saved for rows, never rows for a read: the cost's line is
+    measured between the two widest calls and under-prices a narrow one,
+    whose rows do not feed the matrix unit. Where reading the weights
+    costs more than the rows of the padding, an expert family on the
+    chip, a prompt of 1025-2047 tokens is ONE padded wide call and not
+    [1024, tail]; a FLOP-bound deployment keeps the cut of fewest rows
+    but for two full calls of the largest bucket, which become one wide
+    call; a device of unknown peaks keeps it altogether. Reads nothing but
+    the tokens left and the deployment's two costs: the plan of a prompt's
+    last `left` tokens is the end of the plan of the whole prompt wherever
+    the cut falls on a call's edge (_plan_admission cuts a hit there for
+    the families with REUSE_WHOLE_PREFILL_CALLS), and never depends on who
+    else is admitted in the round (round_calls)."""
     top, wide = max(cfg.prefill_buckets), wide_chunk(cfg)
-    plan = [(wide, wide)] * (left // wide)
-    left %= wide
-    if left > top:
-        plan.append((top, top))
-        left -= top
-    if left:
-        plan.append((next(b for b in cfg.prefill_buckets if left <= b), left))
-    return plan
+    bucket = lambda n: next(b for b in cfg.prefill_buckets if n <= b)  # noqa: E731
+    n_wide, rest = divmod(left, wide)
+    rows = [wide] * n_wide
+    if rest > top:
+        cut = [top, bucket(rest - top)]
+        padded = call_seconds(cost, 1, wide) < sum(call_seconds(cost, 1, r) for r in cut)
+        rows += [wide] if padded else cut
+    elif rest:
+        rows.append(bucket(rest))
+    return [(r, r) for r in rows[:-1]] + [(r, left - sum(rows[:-1])) for r in rows[-1:]]
+
+
+def round_calls(
+    plans: list[list[tuple[int, int]]], cost: tuple[float, float], pairs: tuple[int, ...],
+    first: list[int] | None = None, spare: frozenset[int] = frozenset(),
+) -> list[tuple[int, list[tuple[int, int]]]]:
+    """The chunk calls of one admission round, in dispatch order, as (rows
+    of the call, [(prompt i, its piece k)]): *plans[i]* is prompt i's
+    prefill_plan, and wave w holds piece `w - first[i]` of every prompt
+    (*first*: the wave a prompt starts in, 0 unless it reads pages that a
+    prompt of this round writes), so a prompt's pieces keep their order
+    and no call holds two pieces of one prompt. Inside a wave, widest
+    first, a piece shares ONE call of two slots with the next, the
+    narrower padded up to the wider's rows (the next of *pairs*, the row
+    counts compiled for two slots), wherever that costs less than the two
+    apart: one read of the weights saved for the rows of the padding. (The
+    matching of neighbours that saves MOST costs 0.7% less prefill time on
+    fleet-sat's lengths than this first fit: PERF.md section 6, PR 54.) A
+    prompt in *spare* (a cold prompt with a one-row call of its own
+    elsewhere) is laid out only where it shares a call, and left out
+    otherwise."""
+    first = first or [0] * len(plans)
+    waves: dict[int, list[tuple[int, int, int]]] = {}
+    for i, plan in enumerate(plans):
+        for k, (rows, _) in enumerate(plan):
+            waves.setdefault(first[i] + k, []).append((rows, i, k))
+    calls = []
+    for w in sorted(waves):
+        pieces = sorted(waves[w], key=lambda p: -p[0])  # stable: the planned order among equals
+        while pieces:
+            rows, i, k = pieces.pop(0)
+            shared = next((r for r in pairs if r >= rows), None)
+            if pieces and shared and call_seconds(cost, 2, shared) < call_seconds(cost, 1, rows) + call_seconds(cost, 1, pieces[0][0]):
+                calls.append((shared, [(i, k), pieces.pop(0)[1:]]))
+            elif i not in spare:
+                calls.append((rows, [(i, k)]))
+    return calls
 
 
 def window_pool_dims(model_config: ModelConfig, cfg: EngineConfig) -> tuple[int, int]:
@@ -380,6 +457,22 @@ class Request:
     # the scheduler's restore admission reclaims the matching pinned
     # pages (skipping the payload upload) and drops the blob once used.
     restore_key: str = ""
+
+
+@dataclass
+class _Chunked:
+    """A prompt on the chunk route in its admission round: where its next
+    piece starts, and what its calls have cost so far (its step record is
+    written with its last piece)."""
+
+    slot: int
+    req: Request
+    reuse: int  # tokens found in the prefix cache: the first piece starts behind them
+    plan: list[tuple[int, int]]  # prefill_plan of what is left: (rows, real tokens) a piece
+    done: int = 0  # pieces dispatched
+    seed: Any = None  # drawn with the first piece
+    pad: int = 0  # rows of its calls' rows that held no token of its own
+    seconds: float = 0.0  # host seconds of the calls it rode
 
 
 @dataclass
@@ -753,6 +846,13 @@ class Engine:
             "call's rows: the wide chunk, the largest bucket or the tail's "
             "bucket (how often the wide chunk engages)",
         )
+        self.m_prefill_calls = default_registry.counter(
+            "kubeai_engine_prefill_calls_total",
+            "prefill device calls, by kind (group: a cold call of one row or "
+            "the group cap | chunk) and the slots a call carried: a chunk call "
+            "of slots=2 is two prompts' pieces behind one read of the weights "
+            "(kubeai_engine_prefill_tokens_total over this: tokens a call)",
+        )
         self.m_prefill_rows = default_registry.counter(
             "kubeai_engine_prefill_rows_total",
             "rows the cold group prefill calls computed, by kind: real (a "
@@ -846,6 +946,17 @@ class Engine:
         self._perf_devices = (
             max(1, self._mesh.devices.size) if self._mesh is not None else 1
         )
+        # What a prefill call costs on this deployment (call_seconds):
+        # (seconds to read the held weights once, seconds a row). ONE
+        # source: warmup() measures it (_measure_call_cost) where the
+        # device is one obs/perf.py knows. Until then, on any other device
+        # (the CPU) and on an engine that is never warmed up (a gang), a
+        # read costs nothing: a prompt's cut is the one of fewest rows and
+        # no two prompts share a call (prefill_plan, round_calls). The two
+        # peaks do not stand in for the measurement: a family's rows can
+        # cost twice what they say and its reads hide behind them
+        # (PERF.md section 6, PR 54).
+        self.call_cost: tuple[float, float] = UNKNOWN_DEVICE
         self._stall = perf_obs.PipelineStallTracker()
         mfu_fn = lambda: self._mfu()  # noqa: E731
         roofline_fn = lambda: self._roofline_fraction()  # noqa: E731
@@ -992,6 +1103,20 @@ class Engine:
             "prefill_chunk_tokens": {
                 str(rows): int(self.m_chunk_tokens.value(labels={"rows": str(rows)}))
                 for rows in sorted({*self.cfg.prefill_buckets, wide_chunk(self.cfg)})
+            },
+            # What a prefill call costs here (call_cost, [ms, us a row]:
+            # measured at warm-up, 0 and 1 s a row before it and where the
+            # device is not known), the cut of a 1500-token prompt under
+            # it, and the device calls so far by kind and slots
+            # (kubeai_engine_prefill_calls_total).
+            "prefill_call_cost": {
+                "read_weights_ms": round(self.call_cost[0] * 1e3, 3), "row_us": round(self.call_cost[1] * 1e6, 3),
+                "plan_1500": prefill_plan(self.cfg, 1500, self.call_cost),
+                "two_slot_rows": list(pair_rows(self.cfg, self.model_config)),
+            },
+            "prefill_calls": {
+                f"{kind}x{slots}": int(self.m_prefill_calls.value(labels={"kind": kind, "slots": slots}))
+                for kind, slots in (("group", "1"), ("group", str(max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots)))), ("chunk", "1"), ("chunk", "2"))
             },
             # Rows x steps of the decode chunks dispatched so far, by the
             # `active` mask they were given, and the live share of them
@@ -1255,11 +1380,83 @@ class Engine:
         self._thread = threading.Thread(target=self._loop, name="engine-loop", daemon=True)
         self._thread.start()
 
+    def _warm_prefill(self, member: str, shape: tuple, tokens=None) -> None:
+        """One prefill program run on the trash page (tables all zero),
+        as warmup() runs every one: *tokens* [n, rows] or zeros."""
+        n, rows = shape
+        Kb, cols = self.cfg.max_logit_bias, self._page_table.shape[1]
+        # A cold call's `lengths`; a chunk call's `starts` and `last_idx`.
+        per_row = (
+            (np.full((n,), rows, np.int32),) if member == "prefill_batch_jit"
+            else (np.zeros((n,), np.int32), np.full((n,), rows - 1, np.int32))
+        )
+        *_, self._cache, self._adm_toks, _counters = self._step(
+            member, shape,
+            self.params,
+            np.zeros((n, rows), np.int32) if tokens is None else tokens,
+            *per_row,
+            np.zeros((n, cols), np.int32),
+            np.zeros((n,), np.int32),
+            np.zeros((n,), np.uint32),
+            np.ones((n,), np.float32),
+            np.ones((n,), np.float32),
+            np.zeros((n,), np.int32),
+            np.zeros((n, Kb), np.int32),
+            np.zeros((n, Kb), np.float32),
+            self._adm_toks,
+            self._cache,
+        )
+
+    def _measure_call_cost(self) -> tuple[float, float]:
+        """What a chunk call costs HERE (call_seconds), measured once
+        every program has run: the two widest one-slot chunk programs run
+        four times more each, turn about and alone, on drawn tokens (rows
+        of one token would ask an expert family for one set of experts);
+        the first run of each is thrown away. A run's time is taken from
+        the dispatch's RETURN to `block_until_ready`: the device's own,
+        without the host's dispatch, which serving hides behind the call
+        before and which would otherwise sit in the read (2-3 ms of 16 on
+        lfm2: PERF.md section 5). The line goes through the narrower
+        program's FASTEST run and the wider's MEDIAN one, so what the clock
+        adds to a run can only make the read smaller: a plan leaves the cut
+        of fewest rows only for more than the runs differ by. A read that
+        hides behind the rows' work comes out as 0: the plans of fewest
+        rows, no shared call. UNKNOWN_DEVICE where the times give no line."""
+        widest = sorted({*self.cfg.prefill_buckets, wide_chunk(self.cfg)})[-2:]
+        if len(widest) < 2:
+            return UNKNOWN_DEVICE
+        rng = np.random.default_rng(0)
+        tokens = {rows: rng.integers(0, self.model_config.vocab_size, (1, rows)).astype(np.int32) for rows in widest}
+        device: dict[int, list[float]] = {rows: [] for rows in widest}
+        whole: dict[int, list[float]] = {rows: [] for rows in widest}  # with the dispatch: logged beside, plans nothing
+        for _ in range(4):
+            for rows in widest:
+                called = time.monotonic()
+                self._warm_prefill("prefill_chunk_jit", (1, rows), tokens[rows])
+                returned = time.monotonic()
+                jax.block_until_ready(self._adm_toks)
+                ready = time.monotonic()
+                device[rows].append(ready - returned)
+                whole[rows].append(ready - called)
+        narrow, wide = min(device[widest[0]][1:]), sorted(device[widest[1]][1:])[1]
+        row = (wide - narrow) / (widest[1] - widest[0])
+        cost = (max(narrow - widest[0] * row, 0.0), row) if row > 0 else UNKNOWN_DEVICE
+        log.info(
+            "prefill call cost: read %.2f ms + %.2f ms a 1024 rows (%s rows, the first run of each not used: "
+            "%s ms on the device, %s with the dispatch), so 1500 tokens are %s",
+            cost[0] * 1e3, cost[1] * 1024e3, widest,
+            [[round(t * 1e3, 2) for t in device[rows]] for rows in widest],
+            [[round(t * 1e3, 2) for t in whole[rows]] for rows in widest],
+            prefill_plan(self.cfg, 1500, cost),
+        )
+        return cost
+
     def warmup(self, include_group: bool = True) -> dict:
         """Execute every step program of the one list once
         (engine/step_programs.py::StepPrograms.calls: the decode chunk,
         batch-1 and group-cap cold prefill for every bucket, a chunk call
-        for every bucket and the wide chunk), through the dispatcher the
+        for every bucket and the wide chunk, a chunk call of two slots for
+        the three widest), through the dispatcher the
         serving path uses: a program the table holds (loaded from the
         bundle or compiled by the warm thread) only runs, one it does not
         hold compiles through its jitted function. Called BEFORE
@@ -1271,8 +1468,6 @@ class Engine:
         if self._multiproc or self._publisher is not None:
             log.info("warmup skipped on a multi-host gang")
             return {"shapes": 0, "skipped": "gang"}
-        Kb = self.cfg.max_logit_bias
-        cols = self._page_table.shape[1]
         t0 = time.monotonic()
         shapes = 0
         for call in self._table.programs.calls(include_group):
@@ -1292,43 +1487,8 @@ class Engine:
                     self._h_bias_vals.copy(), self._adm_mask.copy(),
                     self._adm_len.copy(), self._adm_seed.copy(), self._adm_toks,
                 )
-            elif call.member == "prefill_batch_jit":
-                n_pad, bucket = call.shape
-                *_, self._cache, self._adm_toks, _counters = self._step(
-                    call.member, call.shape,
-                    self.params,
-                    np.zeros((n_pad, bucket), np.int32),
-                    np.full((n_pad,), bucket, np.int32),
-                    np.zeros((n_pad, cols), np.int32),
-                    np.zeros((n_pad,), np.int32),
-                    np.zeros((n_pad,), np.uint32),
-                    np.ones((n_pad,), np.float32),
-                    np.ones((n_pad,), np.float32),
-                    np.zeros((n_pad,), np.int32),
-                    np.zeros((n_pad, Kb), np.int32),
-                    np.zeros((n_pad, Kb), np.float32),
-                    self._adm_toks,
-                    self._cache,
-                )
             else:
-                rows = call.shape[1]
-                *_, self._cache, self._adm_toks, _counters = self._step(
-                    call.member, call.shape,
-                    self.params,
-                    np.zeros((1, rows), np.int32),
-                    np.int32(0),
-                    np.int32(rows - 1),
-                    np.zeros((1, cols), np.int32),
-                    np.int32(0),
-                    np.uint32(0),
-                    np.float32(1.0),
-                    np.float32(1.0),
-                    np.int32(0),
-                    np.zeros((Kb,), np.int32),
-                    np.zeros((Kb,), np.float32),
-                    self._adm_toks,
-                    self._cache,
-                )
+                self._warm_prefill(call.member, call.shape)
             # One program in flight at a time: queued behind each other,
             # held executables would have their outputs and workspaces
             # allocated together, a peak no serving step reaches.
@@ -1373,10 +1533,18 @@ class Engine:
             )
             shapes += 1
         jax.block_until_ready(self._adm_toks)
+        measured = {}
+        if all(self._perf_constants()):  # a device of the tables: its clock is worth planning on
+            t = time.monotonic()
+            self.call_cost = self._measure_call_cost()
+            measured = {
+                "call_cost_ms": [round(self.call_cost[0] * 1e3, 3), round(self.call_cost[1] * 1024e3, 3)],  # a read, 1024 rows
+                "call_cost_seconds": round(time.monotonic() - t, 3),
+            }
         dur = time.monotonic() - t0
         self._update_recompile_counter()
         log.info("engine warmup: %d shapes in %.1fs", shapes, dur)
-        return {"shapes": shapes, "seconds": round(dur, 3)}
+        return {"shapes": shapes, "seconds": round(dur, 3), **measured}
 
     def stop(self):
         self._running = False
@@ -2012,22 +2180,11 @@ class Engine:
                     self._adm_toks, self._cache, **lora_args,
                 )
             elif op == "prefill_chunk":
-                lora_args = {}
-                if "lora_row" in sc:
-                    if self._adapters is None:
-                        raise RuntimeError(
-                            "rank 0 dispatched LoRA state this follower lacks"
-                        )
-                    lora_args = {
-                        "lora": self._adapters.bank,
-                        "lora_row": np.int32(sc["lora_row"]),
-                    }
+                lora_args = self._follower_lora(ar)
                 _, _, _, _, self._cache, self._adm_toks, _ = self._prefill_chunk_jit(
-                    self.params, ar["tokens"], np.int32(sc["start"]),
-                    np.int32(sc["last_idx"]), ar["table"], np.int32(sc["slot"]),
-                    np.uint32(sc["seed"]), np.float32(sc["temperature"]),
-                    np.float32(sc["top_p"]), np.int32(sc["top_k"]),
-                    ar["bias_ids"], ar["bias_vals"],
+                    self.params, ar["tokens"], ar["starts"], ar["last_idx"], ar["tables"],
+                    ar["slots"], ar["seeds"], ar["temps"], ar["top_ps"],
+                    ar["top_ks"], ar["bias_ids"], ar["bias_vals"],
                     self._adm_toks, self._cache, **lora_args,
                 )
             elif op == "embed":
@@ -2355,9 +2512,8 @@ class Engine:
         (what those it left behind wait for until the next: _queue_parts)."""
         self._stamp_round()
         stopped = "empty"
-        singles: list[tuple[int, int, "Request", int]] = []  # (seq, slot, req, reuse)
+        singles: list[tuple[int, "Request", int]] = []  # (slot, req, reuse), in the order planned
         groups: dict[int, list[tuple[int, "Request"]]] = {}  # bucket -> items
-        seq = 0
         while True:
             if not (self._n_active + len(taken) < self.cfg.max_slots):
                 # Every slot is busy. An interactive request at the head
@@ -2433,8 +2589,7 @@ class Engine:
             if reuse == 0 and len(req.prompt_ids) <= max_bucket:
                 groups.setdefault(self._bucket(len(req.prompt_ids)), []).append((slot_idx, req))
             else:
-                singles.append((seq, slot_idx, req, reuse))
-            seq += 1
+                singles.append((slot_idx, req, reuse))
         self._round_stopped = stopped
 
         work: list[tuple[list, Any]] = []  # (items, thunk)
@@ -2449,23 +2604,68 @@ class Engine:
         # of 128 or more, and below that wins only for three or more
         # prompts of at most 32 tokens or five of at most 64 in ONE round
         # (the table is in PERF.md section 6, PR 32).
-        cap = max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
+        cost, cap = self.call_cost, max(1, min(self.cfg.prefill_group_cap, self.cfg.max_slots))
+        chunked = [
+            _Chunked(slot_idx, req, reuse, prefill_plan(self.cfg, len(req.prompt_ids) - reuse, cost))
+            for slot_idx, req, reuse in singles
+        ]
+        parts: list[tuple[list, int]] = []  # the cold calls: (items, bucket)
         for bucket, items in groups.items():
             full = len(items) // cap * cap
-            parts = [items[off : off + cap] for off in range(0, full, cap)]
-            parts += [[item] for item in items[full:]]
-            for part in parts:
+            parts += [(items[off : off + cap], bucket) for off in range(0, full, cap)]
+            parts += [([item], bucket) for item in items[full:]]
+        # A one-row cold prompt may ride a chunk call of two slots as a
+        # row that starts at 0, where that saves its own read of the
+        # weights (round_calls), and keeps its cold call otherwise.
+        spare: dict[int, int] = {}  # its place in `chunked` -> its place in `parts`
+        for j, (part, bucket) in enumerate(parts):
+            if len(part) == 1:
+                (slot_idx, req), = part
+                spare[len(chunked)] = j
+                chunked.append(_Chunked(slot_idx, req, 0, [(bucket, len(req.prompt_ids))]))
+        calls = round_calls(
+            [c.plan for c in chunked], cost, pair_rows(self.cfg, self.model_config),
+            first=self._first_waves(chunked, set(spare)), spare=frozenset(spare),
+        )
+        riding = {spare[i] for _, members in calls for i, _ in members if i in spare}
+        for j, (part, bucket) in enumerate(parts):
+            if j in riding:
+                continue
 
-                def batch(items=part, bucket=bucket):
-                    admitted.extend(self._prefill_group(items, bucket))
+            def batch(items=part, bucket=bucket):
+                admitted.extend(self._prefill_group(items, bucket))
 
-                work.append((part, batch))
-        for _, slot_idx, req, reuse in sorted(singles, key=lambda t: t[0]):
-            def one(slot_idx=slot_idx, req=req, reuse=reuse):
-                admitted.append(self._prefill_chunked(slot_idx, req, reuse))
+            work.append((part, batch))
+        for rows, members in calls:
+            part = [chunked[i] for i, _ in members]
 
-            work.append(([(slot_idx, req)], one))
+            def chunk(rows=rows, part=part):
+                admitted.extend(self._prefill_chunk_call(rows, part))
+
+            work.append(([(c.slot, c.req) for c in part], chunk))
         return work
+
+    def _first_waves(self, chunked: list["_Chunked"], spare: set[int]) -> list[int]:
+        """The wave (round_calls) each of the round's chunk-route prompts
+        starts in: 0, or, for a prompt planned on top of pages that a
+        prompt of this same round writes (_plan_admission registers a
+        prompt's pages as it plans it), the wave behind its writers' last
+        pieces; cold calls come before every wave, and a cold prompt that
+        may ride a chunk call (*spare*) does so in wave 0."""
+        first = [0] * len(chunked)
+        if not self.cfg.prefix_cache_min or not any(c.reuse for c in chunked):
+            return first
+        ends = {c.slot: 1 if i in spare else None for i, c in enumerate(chunked)}  # slot -> the wave behind its last piece
+        ps = self.cfg.page_size
+        for i, c in enumerate(chunked):
+            if c.reuse:
+                read = set(self._slot_pages[c.slot][: c.reuse // ps])
+                first[i] = max(
+                    (end for slot, end in ends.items() if end and not read.isdisjoint(self._slot_fresh[slot])), default=0
+                )
+            if i not in spare:
+                ends[c.slot] = first[i] + len(c.plan)
+        return first
 
     ROUNDS_KEPT = 4096  # admission rounds looked back over: minutes of chunk turnarounds
 
@@ -2528,7 +2728,7 @@ class Engine:
                 log.exception("prefill failed")
                 poisoned = False
                 for slot_idx, req in items:
-                    if self._slots[slot_idx] is None:
+                    if self._slots[slot_idx] is None and not req.finished:  # (finished: an earlier chunk call of the round failed it)
                         req.out.put(("error", f"prefill failed: {e}"))
                         self._finish_request(req, "error", error=f"prefill failed: {e}")
                         # The prefill never wrote this slot's pages: any
@@ -2560,7 +2760,7 @@ class Engine:
                 ):
                     for later_items, _ in work[w + 1 :]:
                         for slot_idx, req in later_items:
-                            if self._slots[slot_idx] is None:
+                            if self._slots[slot_idx] is None and not req.finished:
                                 req.out.put(("error", f"prefill failed: {e}"))
                                 self._finish_request(
                                     req, "error", error=f"prefill failed: {e}"
@@ -2701,7 +2901,7 @@ class Engine:
                 # ... whole leading calls of the prompt's cold prefill and
                 # nothing else (models/deepseek.py): the edges between the
                 # cold plan's calls that fall on a page's edge.
-                edges = np.cumsum([rows for rows, _ in prefill_plan(self.cfg, len(ids))[:-1]])
+                edges = np.cumsum([rows for rows, _ in prefill_plan(self.cfg, len(ids), self.call_cost)[:-1]])
                 cuts = [int(e) // ps for e in edges[::-1] if e % ps == 0 and e <= len(claimed) * ps]
             keep = next(iter(cuts), 0)
             if wp is not None:
@@ -2785,102 +2985,112 @@ class Engine:
                 return b
         return self.cfg.prefill_buckets[-1]
 
-    def _prefill_chunked(self, slot_idx: int, req: Request, reuse: int = 0):
-        """Chunk-prefill *req* (pages already reserved by
-        _plan_admission) into its slot's block-table pages, skipping the
-        first *reuse* tokens (their KV lives in claimed shared pages):
-        full-bucket chunks at increasing offsets; only the final chunk's
-        sample is kept. Every argument is numpy (rides the dispatch)."""
-        ids = req.prompt_ids
-        sp = req.params
-        seed = self._seed32(sp)
-        self._admitted(req)
-        if req.trace is not None:
-            req.trace.attrs["reuse_tokens"] = reuse
-        plan = prefill_plan(self.cfg, len(ids) - reuse)
-        widest = plan[0][0]  # the calls come widest first
-        # Only the last call is shorter than its rows.
-        pad_tokens = plan[-1][0] - plan[-1][1]
+    def _prefill_chunk_call(self, rows: int, part: list["_Chunked"]) -> list:
+        """ONE chunk call of *rows* for the next piece of each prompt of
+        *part* (one, or two that share the read of the weights:
+        round_calls), into each slot's block-table pages (reserved by
+        _plan_admission) behind what its earlier pieces and the prefix
+        cache left; a narrower piece is padded up to *rows*. A prompt
+        whose last piece this was is registered, and only that piece's
+        sample is kept: returns the round's `admitted` entries of those.
+        Every argument is numpy (rides the dispatch)."""
+        part = [c for c in part if not c.req.finished]  # an earlier call of the round failed it
+        if not part:
+            return []
+        for c in part:
+            if c.seed is None:  # its first piece
+                c.seed = self._seed32(c.req.params)
+                self._admitted(c.req)
+                if c.req.trace is not None:
+                    c.req.trace.attrs["reuse_tokens"] = c.reuse
+        n = len(part)
+        real_tokens = sum(c.plan[c.done][1] for c in part)
+        # One segment a DEVICE call (until PR 54: one a prompt, with its
+        # plan's length as `calls`); `rid` names every prompt in it.
         with self._stall.segment(
-            "prefill", kind="chunk", bucket=widest, batch=1,
-            tokens=len(ids) - reuse, cached=reuse, pad=pad_tokens,
-            calls=len(plan), rid=req.trace.rid if req.trace is not None else "",
+            "prefill", kind="chunk", bucket=rows, batch=n,
+            tokens=real_tokens, cached=sum(c.reuse for c in part if not c.done), pad=n * rows - real_tokens,
+            calls=1, rid=",".join(c.req.trace.rid for c in part if c.req.trace is not None),
         ) as seg:
-            out = self._prefill_chunks(slot_idx, req, reuse, seed, plan)
+            out = self._prefill_chunk_rows(rows, part)
         self.m_step.observe(seg.seconds, labels={"phase": "prefill_chunked"})
         self._stall.end_step("prefill_chunked")
-        if pad_tokens:
-            self.m_pad_prefill.inc(pad_tokens)
-        default_recorder.record_step(
-            kind="prefill_chunked", slot=slot_idx,
-            kernel=self._attn_kernel("prefill_chunked", widest),
-            prompt_tokens=len(ids), reuse_tokens=reuse,
-            pad_tokens=pad_tokens,
-            dur_ms=round(seg.seconds * 1000, 3),
-        )
+        self.m_prefill_calls.inc(labels={"kind": "chunk", "slots": str(n)})
+        if n * rows > real_tokens:
+            self.m_pad_prefill.inc(n * rows - real_tokens)
+        for c in part:
+            c.seconds += seg.seconds
+            if c.done == len(c.plan):
+                default_recorder.record_step(
+                    kind="prefill_chunked", slot=c.slot,
+                    kernel=self._attn_kernel("prefill_chunked", c.plan[0][0]),
+                    prompt_tokens=len(c.req.prompt_ids), reuse_tokens=c.reuse,
+                    pad_tokens=c.pad,
+                    dur_ms=round(c.seconds * 1000, 3),
+                )
         return out
 
-    def _prefill_chunks(self, slot_idx: int, req: Request, reuse: int, seed, plan: list[tuple[int, int]]):
-        ids = req.prompt_ids
-        sp = req.params
-        lora_args = {}
-        lora_row = 0
-        if self._adapters is not None:
-            lora_row = self._adapters.row_for(req.adapter)
-            lora_args = {"lora": self._adapters.bank, "lora_row": np.int32(lora_row)}
-
-        table = self._page_table[slot_idx : slot_idx + 1].copy()
-        bias_ids, bias_vals = self._bias_rows(sp)
-        tok = lp = None
-        start = reuse
-        for bucket, real in plan:
-            chunk = ids[start : start + real]
-            self.m_chunk_tokens.inc(real, labels={"rows": str(bucket)})
+    def _prefill_chunk_rows(self, rows: int, part: list["_Chunked"]) -> list:
+        n = len(part)
+        tokens = np.zeros((n, rows), np.int32)
+        starts = np.zeros((n,), np.int32)
+        last_idx = np.zeros((n,), np.int32)
+        slots_arr = np.asarray([c.slot for c in part], np.int32)
+        seeds = np.zeros((n,), np.uint32)
+        temps = np.ones((n,), np.float32)
+        top_ps = np.ones((n,), np.float32)
+        top_ks = np.zeros((n,), np.int32)
+        bias_ids = np.zeros((n, self.cfg.max_logit_bias), np.int32)
+        bias_vals = np.zeros((n, self.cfg.max_logit_bias), np.float32)
+        lora_rows_arr = np.zeros((n,), np.int32)
+        for j, c in enumerate(part):
+            sp = c.req.params
+            own_rows, real = c.plan[c.done]
+            start = c.reuse + sum(r for _, r in c.plan[: c.done])
+            tokens[j, :real] = c.req.prompt_ids[start : start + real]
+            starts[j], last_idx[j], seeds[j] = start, real - 1, c.seed
+            temps[j], top_ps[j], top_ks[j] = sp.temperature, sp.top_p, sp.top_k
+            bias_ids[j], bias_vals[j] = self._bias_rows(sp)
+            if self._adapters is not None:
+                lora_rows_arr[j] = self._adapters.row_for(c.req.adapter)
+            self.m_chunk_tokens.inc(real, labels={"rows": str(rows)})
             if self._wpages is not None:
-                # The window table moves with the chunk: pages behind its
-                # first query's window go back, the chunk's own come.
-                self._wpages.advance(slot_idx, start, start + bucket)
-                table = self._page_table[slot_idx : slot_idx + 1].copy()
-                self._count_attn_pairs("prefill", np.asarray([start]), len(chunk), rows=bucket)
-            chunk_padded = np.zeros((1, bucket), np.int32)
-            chunk_padded[0, : len(chunk)] = chunk
-            with self._lockstep(
-                "prefill_chunk",
-                scalars={
-                    "start": start, "last_idx": len(chunk) - 1,
-                    "slot": slot_idx, "seed": int(seed),
-                    "temperature": float(sp.temperature), "top_p": float(sp.top_p),
-                    "top_k": int(sp.top_k),
-                    **({"lora_row": lora_row} if self._adapters is not None else {}),
-                },
-                arrays={
-                    "tokens": chunk_padded, "table": table,
-                    "bias_ids": bias_ids, "bias_vals": bias_vals,
-                },
-            ):
-                tok, lp, t_ids, t_lp, self._cache, self._adm_toks, counters = self._step(
-                    "prefill_chunk_jit", chunk_padded.shape,
-                    self.params,
-                    chunk_padded,
-                    np.int32(start),
-                    np.int32(len(chunk) - 1),
-                    table,
-                    np.int32(slot_idx),
-                    seed,
-                    np.float32(sp.temperature),
-                    np.float32(sp.top_p),
-                    np.int32(sp.top_k),
-                    bias_ids,
-                    bias_vals,
-                    self._adm_toks,
-                    self._cache,
-                    **lora_args,
-                )
-                self._program_counters.append(("prefill", bucket, counters))
-            start += real
-
-        self._register(slot_idx, req, seed, lora_row, reuse)
-        return (slot_idx, self._slot_epoch[slot_idx], tok, None, lp, t_ids, t_lp)
+                # The window table moves with the piece: pages behind its
+                # first query's window go back, its own come. (The two
+                # families with window pages never share a call: pair_rows.)
+                self._wpages.advance(c.slot, start, start + own_rows)
+                self._count_attn_pairs("prefill", np.asarray([start]), real, rows=rows)
+            c.pad += rows - real
+            c.done += 1
+        tables = self._page_table[slots_arr]
+        lora_args = {}
+        if self._adapters is not None:
+            lora_args = {"lora": self._adapters.bank, "lora_rows": lora_rows_arr}
+        with self._lockstep(
+            "prefill_chunk",
+            arrays={
+                "tokens": tokens, "starts": starts, "last_idx": last_idx, "tables": tables,
+                "slots": slots_arr, "seeds": seeds, "temps": temps,
+                "top_ps": top_ps, "top_ks": top_ks,
+                "bias_ids": bias_ids, "bias_vals": bias_vals,
+                # As a cold call's: followers branch on the key's presence.
+                **({"lora_rows": lora_rows_arr} if self._adapters is not None else {}),
+            },
+        ):
+            toks, lps, t_ids, t_lp, self._cache, self._adm_toks, counters = self._step(
+                "prefill_chunk_jit", tokens.shape,
+                self.params, tokens, starts, last_idx, tables, slots_arr, seeds,
+                temps, top_ps, top_ks, bias_ids, bias_vals,
+                self._adm_toks, self._cache,
+                **lora_args,
+            )
+        self._program_counters.append(("prefill", tokens.size, counters))
+        out = []
+        for j, c in enumerate(part):
+            if c.done == len(c.plan):
+                self._register(c.slot, c.req, c.seed, int(lora_rows_arr[j]), c.reuse)
+                out.append((c.slot, self._slot_epoch[c.slot], toks, j, lps, t_ids, t_lp))
+        return out
 
     def _bias_rows(self, sp: SamplingParams) -> tuple[np.ndarray, np.ndarray]:
         """A request's logit_bias as fixed-width (ids, vals) rows
@@ -2980,6 +3190,7 @@ class Engine:
         if pad_tokens > 0:
             self.m_pad_prefill.inc(pad_tokens)
         self.m_prefill_rows.inc(n, labels={"kind": "real"})
+        self.m_prefill_calls.inc(labels={"kind": "group", "slots": str(n)})
         default_recorder.record_step(
             kind="prefill_group", bucket=bucket, batch=n,
             kernel=self._attn_kernel("prefill_group", bucket),
@@ -3851,23 +4062,12 @@ def build_step_functions(
     # (a decode step's rows ARE the slots, in `live`'s order).
     slot_state = bool(model.SLOT_STATE)
 
-    def prefill_batch_fn(params, tokens, lengths, tables, slots, seeds, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_rows=None):
-        """Cold prefill for N requests in ONE call (N is one of two
-        compiled row counts — 1, and the group cap for a full group):
-        tokens [N, S] land in the pages of *tables* [N, max_pages].
-        Sampled first tokens are scattered into the device staging
-        vector adm_toks[slots] so the NEXT decode dispatch can merge
-        them in-graph without a host round-trip (every row is its own
-        request's: the slots are distinct). PRNG keys derive from
-        uint32 *seeds* in-graph, so every argument arrives as plain
-        numpy riding the dispatch."""
-        keys = jax.vmap(jax.random.key)(seeds)
-        logits, cache = model.prefill_paged_cold(
-            params, mc, tokens, cache, tables, lengths,
-            lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
-            **({"slots": slots} if slot_state else {}),
-        )
-        cache, counters = split_counters(cache)
+    def first_tokens(logits, slots, keys, temp, top_p, top_k, bias_ids, bias_vals, adm_toks):
+        """A prefill call's epilogue: every row's sampled first token,
+        scattered into the device staging vector adm_toks[slots] so the
+        NEXT decode dispatch can merge it in-graph without a host
+        round-trip (every row is its own request's: the slots are
+        distinct)."""
         with jax.named_scope("sampling"):
             masked = mask_pad(logits[:, -1])
             # Bias steers choice; the reported logprob stays the model's
@@ -3880,32 +4080,40 @@ def build_step_functions(
             logp = jax.nn.log_softmax(masked, axis=-1)
             lps = jnp.take_along_axis(logp, toks[:, None], axis=1)[:, 0]
             t_lp, t_ids = jax.lax.top_k(logp, topn)
-        adm_toks = adm_toks.at[slots].set(toks)
-        return toks, lps, t_ids.astype(jnp.int32), t_lp, cache, adm_toks, counters
+        return toks, lps, t_ids.astype(jnp.int32), t_lp, adm_toks.at[slots].set(toks)
 
-    def prefill_chunk_fn(params, tokens, start, last_idx, table, slot, seed, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_row=None):
-        """One chunk of a long or prefix-resuming prompt."""
-        key = jax.random.key(seed)
-        logits, cache = model.prefill_paged(
-            params, mc, tokens, cache, table, start[None], last_idx[None],
-            lora=lora,
-            lora_rows=None if lora_row is None else lora_row[None],
-            tp_mesh=mesh,
-            **({"slots": slot[None]} if slot_state else {}),
+    def prefill_batch_fn(params, tokens, lengths, tables, slots, seeds, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_rows=None):
+        """Cold prefill for N requests in ONE call (N is one of two
+        compiled row counts — 1, and the group cap for a full group):
+        tokens [N, S] land in the pages of *tables* [N, max_pages]. PRNG
+        keys derive from uint32 *seeds* in-graph, so every argument
+        arrives as plain numpy riding the dispatch."""
+        keys = jax.vmap(jax.random.key)(seeds)
+        logits, cache = model.prefill_paged_cold(
+            params, mc, tokens, cache, tables, lengths,
+            lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
+            **({"slots": slots} if slot_state else {}),
         )
         cache, counters = split_counters(cache)
-        with jax.named_scope("sampling"):
-            masked = mask_pad(logits[:, -1])
-            tok = sample(
-                apply_logit_bias(masked, bias_ids[None], bias_vals[None]),
-                key[None], temp[None], top_p[None], top_k[None], max_top_k=mtk,
-            )[0]
-        with jax.named_scope("logprobs"):
-            logp = jax.nn.log_softmax(masked, axis=-1)
-            lp = logp[0, tok]
-            t_lp, t_ids = jax.lax.top_k(logp[0], topn)
-        adm_toks = adm_toks.at[slot].set(tok)
-        return tok, lp, t_ids.astype(jnp.int32), t_lp, cache, adm_toks, counters
+        *out, adm_toks = first_tokens(logits, slots, keys, temp, top_p, top_k, bias_ids, bias_vals, adm_toks)
+        return *out, cache, adm_toks, counters
+
+    def prefill_chunk_fn(params, tokens, starts, last_idx, tables, slots, seeds, temp, top_p, top_k, bias_ids, bias_vals, adm_toks, cache, lora=None, lora_rows=None):
+        """One chunk call: a piece [rows] of a long or prefix-resuming
+        prompt for each of its N slots (one, or two that share the read
+        of the weights: round_calls), row j behind *starts[j]* tokens of
+        its own slot (0: a cold prompt as a row). prefill_batch_fn's
+        arguments plus *starts*; every row samples, and only the sample
+        of a prompt's last piece is kept."""
+        keys = jax.vmap(jax.random.key)(seeds)
+        logits, cache = model.prefill_paged(
+            params, mc, tokens, cache, tables, starts, last_idx,
+            lora=lora, lora_rows=lora_rows, tp_mesh=mesh,
+            **({"slots": slots} if slot_state else {}),
+        )
+        cache, counters = split_counters(cache)
+        *out, adm_toks = first_tokens(logits, slots, keys, temp, top_p, top_k, bias_ids, bias_vals, adm_toks)
+        return *out, cache, adm_toks, counters
 
     K = cfg.decode_chunk
 

@@ -8,8 +8,9 @@ this deployment have made before LOADS them without tracing or lowering.
   vocab, the quantization), builds the ONE set of jitted step functions
   (core.build_step_functions) that the warm compiler, ``Engine.warmup()``
   and the scheduler's dispatch sites all use, and enumerates every call:
-  the decode chunk, batch-1 and group-cap cold prefill per bucket, and a
-  chunk call for every bucket and the wide chunk.
+  the decode chunk, batch-1 and group-cap cold prefill per bucket, a
+  chunk call for every bucket and the wide chunk, and a chunk call of two
+  slots for the three widest of those.
 - ``StepTable`` holds ``jax.stages.Compiled`` executables under (step
   function, shape of the argument that varies). The engine's one
   dispatcher (core.Engine._step) looks a call up here: a hit runs the
@@ -134,7 +135,11 @@ class StepPrograms:
         Chunked prefill pads its FINAL chunk to the smallest fitting
         bucket (the calls before it are the largest bucket or the wide
         chunk: core.prefill_plan), so there is a chunk shape per bucket
-        and ONE more, the wide chunk, where a prompt can be that long."""
+        and ONE more, the wide chunk, where a prompt can be that long; and
+        the three widest of them once more for TWO slots a call (the
+        pieces of two prompts behind one read of the weights:
+        core.round_calls; none for a family that reuses whole prefill
+        calls: core.pair_rows)."""
         cfg = self.cfg
         calls = [StepCall("decode", "decode_jit", ())]
         cap = max(1, min(cfg.prefill_group_cap, cfg.max_slots))
@@ -144,6 +149,8 @@ class StepPrograms:
                 calls.append(StepCall(f"prefill_batch[{n_pad}x{bucket}]", "prefill_batch_jit", (n_pad, bucket)))
         for rows in sorted({*cfg.prefill_buckets, core.wide_chunk(cfg)}):
             calls.append(StepCall(f"prefill_chunk[{rows}]", "prefill_chunk_jit", (1, rows)))
+        for rows in core.pair_rows(cfg, self.model_config):
+            calls.append(StepCall(f"prefill_chunk[2x{rows}]", "prefill_chunk_jit", (2, rows)))
         return calls
 
     @functools.cached_property
@@ -180,16 +187,12 @@ class StepPrograms:
                 sds((B,), b8), sds((B,), i32), sds((B,), u32), sds((B,), i32),
             )
         n, rows = call.shape
-        if call.member == "prefill_batch_jit":
-            return (
-                params, sds((n, rows), i32), sds((n,), i32), sds((n, cols), i32), sds((n,), i32),
-                sds((n,), u32), sds((n,), f32), sds((n,), f32), sds((n,), i32),
-                sds((n, Kb), i32), sds((n, Kb), f32), sds((B,), i32), cache,
-            )
+        # A cold call's `lengths`; a chunk call's `starts` and `last_idx`.
+        per_row = (sds((n,), i32),) * (1 if call.member == "prefill_batch_jit" else 2)
         return (
-            params, sds((1, rows), i32), sds((), i32), sds((), i32), sds((1, cols), i32),
-            sds((), i32), sds((), u32), sds((), f32), sds((), f32), sds((), i32),
-            sds((Kb,), i32), sds((Kb,), f32), sds((B,), i32), cache,
+            params, sds((n, rows), i32), *per_row, sds((n, cols), i32), sds((n,), i32),
+            sds((n,), u32), sds((n,), f32), sds((n,), f32), sds((n,), i32),
+            sds((n, Kb), i32), sds((n, Kb), f32), sds((B,), i32), cache,
         )
 
 

@@ -37,14 +37,19 @@ the pages ("xla").
 (`REUSE_WHOLE_PREFILL_CALLS`, read by `engine/core.py::_plan_admission`):
 a hit is cut down to an edge between two calls of the prompt's COLD plan
 (`engine/core.py::prefill_plan`: calls of the wide chunk, 2048 rows,
-while that many tokens are left, then one of the largest bucket, 1024,
-if more than that is left, then the tail; so a hit keeps `j x 2048`
-tokens, `j` at most the cold plan's wide calls, or all of them and the
-1024-row call behind them; a prompt under 2048 tokens keeps its first
-1024 as it always did), so what is left of the prompt is prefilled by
+while that many tokens are left, then what they leave cut by the
+deployment's measured cost: [1024, tail] where rows cost more than a read
+of the weights, else ONE padded wide call; so a hit keeps `j x 2048` tokens, `j` at most the
+cold plan's wide calls, or those and the 1024-row call behind them where
+the plan has one), so what is left of the prompt is prefilled by
 the very calls a cold prefill of it ends with (same program, same shapes,
 same offsets, same bits in the pages before them) and gives the same
-bits. A dense decoder takes a hit
+bits. For the same reason a family with this seam shares NO chunk call
+between two prompts (`engine/core.py::pair_rows`: no `[2, rows]` program
+is compiled for it): a piece padded up to a partner's rows in a two-slot
+program is another program and shape than the `[1, own rows]` call the
+prompt runs alone, and which prompts meet in a round is not the prompt's
+to know. A dense decoder takes a hit
 to the page, and the tail then runs in another bucket than the cold
 prompt did: the compiler fuses and tiles by the call's shape, a token's
 hidden state comes out a bf16 step apart, and there it stays a rounding.

@@ -249,7 +249,8 @@ def test_warm_up_compiles_the_wide_chunk_exactly_where_a_prompt_reaches_it(max_s
     assert wide_chunk(cfg) == wide
     eng = Engine(mc, llama.init_params(mc, jax.random.key(1)), ByteTokenizer(), cfg)
     eng.warmup()
-    assert eng._prefill_chunk_jit._cache_size() == 3 + (wide == 64)  # a chunk program a bucket, and the wide one
+    # A chunk program a bucket, and the wide one; the three widest once more for two slots a call.
+    assert eng._prefill_chunk_jit._cache_size() == 3 + (wide == 64) + 3
     assert eng._prefill_batch_jit._cache_size() == 3 * 2  # no cold shape is added: buckets x (1, group) rows
     entries = eng._jit_cache_entries()
     eng.start()

@@ -283,12 +283,13 @@ def test_warmup_covers_all_shapes_and_engine_serves(ckpt_dir, overlap):
     # chunk x 2 buckets (the final chunk of a chunked prefill pads to the
     # smallest fitting bucket, so every bucket is a live chunk shape) +
     # the wide chunk of 32 rows (a prompt of TINY_EC's 64 positions
-    # reaches it: core.prefill_plan) = 8 shapes for TINY_EC,
+    # reaches it: core.prefill_plan) + those three chunk shapes once
+    # more at two slots a call (core.round_calls) = 11 shapes for TINY_EC,
     programs = len(StepPrograms(eng.model_config, TINY_EC).calls())
-    assert programs == 8
+    assert programs == 11
     # + 4 restore-path shapes (KV evolve, import pow2 1 and 2,
     # slotset) on a single-host engine with KV restore enabled.
-    assert attrs["warmup"]["shapes"] == programs + 4 == 12
+    assert attrs["warmup"]["shapes"] == programs + 4 == 15
     if overlap:
         # Every program came up ONCE, on the warm thread, and warmup()
         # ran it: none went through the engine's jitted functions too.

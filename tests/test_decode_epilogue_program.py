@@ -102,9 +102,10 @@ def test_the_programs_carry_the_names_the_trace_readers_select_by():
     names = [name for _, name in _warm_programs(2)]
     assert names[0] == "jit__unknown"
     # ... in the one list's order (engine/step_programs.py): two buckets x
-    # (one row, the group cap), then a chunk call a bucket.
+    # (one row, the group cap), then a chunk call a bucket and the same
+    # for two slots.
     assert names[1:5] == ["jit_prefill_batch_fn"] * 4
-    assert names[5:] == ["jit_prefill_chunk_fn"] * 2
+    assert names[5:] == ["jit_prefill_chunk_fn"] * 4
 
 
 def test_the_decode_chunk_returns_four_fetched_arrays_then_five_carries():

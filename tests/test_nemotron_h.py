@@ -135,14 +135,24 @@ class Driver:
     def chunk(self, slot, toks, start, n):
         """One chunk call: *n* real tokens padded to their bucket. Returns
         ([1, V] log-probabilities at the last real token, the greedy token)."""
-        bucket = next(b for b in EC.prefill_buckets if b >= n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = toks[:n]
-        tok, _, t_ids, t_lp, self.cache, self.adm_toks, _ = self.steps.prefill_chunk_jit(
-            self.eng.params, padded, np.int32(start), np.int32(n - 1), self.table[slot : slot + 1].copy(),
-            np.int32(slot), np.uint32(0), *self.one, self.adm_toks, self.cache,
+        lps, toks = self.chunk_rows([slot], [toks[:n]], [start], next(b for b in EC.prefill_buckets if b >= n))
+        return lps, int(toks[0])
+
+    def chunk_rows(self, slots, pieces, starts, rows):
+        """One chunk call of len(slots) slots x *rows*, slot j's piece behind
+        *starts[j]* tokens of its own (engine/core.py::round_calls shares a
+        call so): ([n, V] log-probabilities at each piece's last token, the
+        greedy tokens)."""
+        n = len(slots)
+        padded = np.zeros((n, rows), np.int32)
+        for j, piece in enumerate(pieces):
+            padded[j, : len(piece)] = piece
+        toks, _, t_ids, t_lp, self.cache, self.adm_toks, _ = self.steps.prefill_chunk_jit(
+            self.eng.params, padded, np.asarray(starts, np.int32), np.asarray([len(p) - 1 for p in pieces], np.int32),
+            self.table[list(slots)].copy(), np.asarray(slots, np.int32), np.zeros((n,), np.uint32),
+            *(np.repeat(a[None], n, axis=0) for a in self.one), self.adm_toks, self.cache,
         )
-        return logprobs_by_id(t_ids, t_lp)[None], int(tok)
+        return logprobs_by_id(t_ids, t_lp), np.asarray(toks)
 
     def chunks(self, slot, toks, sizes):
         start = 0
@@ -267,6 +277,26 @@ def test_cold_group_prefill_and_chunked_prefill_agree(eng, steps, source, tokens
     assert np.abs(lp[0] - want[0, -1]).max() <= LOGPROB_ABS
     # The program's counters: 5 expert blocks x 8 held experts at most, nothing absent.
     assert 0 < int(counters["moe_hits"]) <= 5 * 8 and int(counters["moe_absent"]) == 0
+
+
+def test_a_call_of_two_slots_gives_each_what_its_own_call_gives(eng, steps, tokens):
+    """Two prompts' pieces behind one read of the weights
+    (engine/core.py::round_calls): slot 0's second piece, 20 tokens carried
+    behind its first 32 and padded up to the call's 32 rows, beside slot 2's
+    cold 25 at 0. Each row's log-probabilities, and what each slot keeps
+    outside its pages, are what the slots' own calls leave; slot 1's, between
+    them, stays zeros."""
+    shared, apart = Driver(eng, steps), Driver(eng, steps)
+    for d in (shared, apart):
+        d.chunk(0, tokens[0], 0, 32)
+    got, toks = shared.chunk_rows([0, 2], [tokens[0, 32:52], tokens[0, 60:85]], [32, 0], 32)
+    want0, tok0 = apart.chunk(0, tokens[0, 32:52], 32, 20)
+    want2, tok2 = apart.chunk(2, tokens[0, 60:85], 0, 25)
+    assert toks.tolist() == [tok0, tok2]
+    assert np.abs(got[0] - want0[0]).max() <= LOGPROB_ABS and np.abs(got[1] - want2[0]).max() <= LOGPROB_ABS
+    for key in ("ssm", "conv"):
+        a, b = np.asarray(shared.cache[key]), np.asarray(apart.cache[key])
+        assert np.abs(a[:, [0, 2]] - b[:, [0, 2]]).max() <= LOGPROB_ABS and a[:, [0, 2]].any() and not a[:, 1].any()
 
 
 def test_the_state_the_program_leaves_is_the_references(eng, steps, source, tokens):

@@ -13,7 +13,12 @@ PR 48 added one digest a family, the chunk call of the SMALLER bucket, which
 `warmup()` always ran and the warm compile's own list had left out (the one
 list of engine/step_programs.py has both's coverage); the six that were
 there kept theirs; PR 51 added `lfm2_moe`'s as it left its module (the
-other five kept theirs):
+other five kept theirs); PR 54 MEANT to change every family's two chunk
+programs (digests 5 and 6: `prefill_chunk_fn` takes its per-request
+arguments by row, so that a call can hold two prompts' pieces) and added
+two, the same calls at two slots, in the families that share chunk calls
+(core.pair_rows: dense, nemotron_h, lfm2_moe); the decode chunk's and the
+four cold calls' stayed as they were:
 
     JAX_PLATFORMS=cpu python tests/test_step_programs_unchanged.py > tests/data/step_program_digests.json
 
@@ -43,7 +48,7 @@ DIGESTS = os.path.join(HERE, "data", "step_program_digests.json")
 
 def digests(family: str) -> list[str]:
     """sha256 of every program the warm compile lowers for the family's toy
-    configuration: the decode chunk, 2 buckets x 2 sizes, a chunk call a bucket."""
+    configuration: the decode chunk, 2 buckets x 2 sizes, a chunk call a bucket, the same at two slots where the family shares calls."""
     return [hashlib.sha256(text.encode()).hexdigest() for text, _ in scopes._lowered_programs(True, FAMILIES[family])]
 
 

@@ -30,7 +30,8 @@ DENSE = ModelConfig(
     dtype="float32", max_position=256, use_paged_kernel=True, use_flash_prefill=True,
 )
 FAMILIES = {"dense": DENSE, "deepseek_v3": DEEPSEEK, "smallthinker": SMALLTHINKER, "nemotron_h": NEMOTRON_H, "afmoe": AFMOE}
-# decode + 2 buckets x (1, the cap of 2) + a chunk call a bucket = 7 programs.
+# decode + 2 buckets x (1, the cap of 2) + a chunk call a bucket = 7 programs, + the same for two slots (the three
+# widest row counts, of which this deployment has two) = 9 where the family shares chunk calls (core.pair_rows).
 CFG = EngineConfig(max_slots=2, max_seq_len=64, page_size=16, prefill_buckets=(16, 32), decode_chunk=2)
 N_VALID = 259  # the byte tokenizer's vocab under a model vocab of 272
 # A cold one-row call, and a prompt past the largest bucket: chunk calls of 32 and 16 rows.
@@ -108,7 +109,7 @@ def two_starts(request, tmp_path_factory):
 
 def test_a_second_start_loads_every_program_of_the_list(two_starts):
     n = len(_programs(two_starts.mc).calls())
-    assert n == 7
+    assert n == (7 if family(two_starts.mc).REUSE_WHOLE_PREFILL_CALLS else 9)
     first, second = two_starts.first.table.stats, two_starts.second.table.stats
     assert (first["loaded"], first["compiled"], first["shapes"]) == (0, n, n), first
     assert (second["loaded"], second["compiled"], second["shapes"]) == (n, 0, n), second
@@ -123,7 +124,7 @@ def test_a_second_start_loads_every_program_of_the_list(two_starts):
 
 
 def test_a_second_start_runs_no_step_functions_python_body(two_starts):
-    assert two_starts.first.bodies >= 7  # every program traced its step function
+    assert two_starts.first.bodies >= len(_programs(two_starts.mc).calls())  # every program traced its step function
     assert two_starts.second.bodies == 0
 
 
@@ -149,7 +150,7 @@ def test_a_shape_outside_the_table_compiles_lazily_and_is_counted(two_starts):
     del table.held[("prefill_batch_jit", (1, 16))]  # as if the list had not foreseen it
     eng = Engine(mc, family(mc).init_params(mc, jax.random.key(0)), ByteTokenizer(), CFG, step_table=table)
     eng._update_recompile_counter()
-    assert eng._jit_entries_seen == 6 and table.stats["lazy"] == 0
+    assert eng._jit_entries_seen == len(table.held) and table.stats["lazy"] == 0
     recompiles = eng.m_recompiles.value()
     lazy = step_programs.M_STEP_PROGRAMS.value(labels={"how": "lazy"})
     eng.start()
@@ -272,16 +273,16 @@ def test_a_bad_bundle_is_a_miss_that_compiles_and_serves(damage, cache_dir, lazy
     damage(path, key)
     table = fill_step_table(_programs())
     stats = table.stats
-    assert stats["shapes"] == 7 and stats["compiled"] >= 1 and "errors" not in stats, stats
+    assert stats["shapes"] == 9 and stats["compiled"] >= 1 and "errors" not in stats, stats
     if damage is _truncate:
-        assert 1 <= stats["loaded"] < 7  # the records before the cut are good
+        assert 1 <= stats["loaded"] < 9  # the records before the cut are good
     else:
         assert stats["loaded"] == 0
     assert _greedy(table, DENSE) == lazy_tokens
     if damage is not _a_directory_in_its_place:
         # ... and the bundle was written again: the next start loads all of it.
         again = fill_step_table(_programs()).stats
-        assert (again["loaded"], again["compiled"]) == (7, 0)
+        assert (again["loaded"], again["compiled"]) == (9, 0)
 
 
 def test_a_loaded_program_of_another_signature_is_a_miss(cache_dir):
@@ -292,14 +293,14 @@ def test_a_loaded_program_of_another_signature_is_a_miss(cache_dir):
     with pytest.MonkeyPatch.context() as m:
         m.setattr(step_programs, "bundle_key", lambda programs, source_root=None: bundle_key(_programs()))
         stats = fill_step_table(_programs(cfg=wider)).stats
-    assert (stats["loaded"], stats["compiled"]) == (0, 7)
+    assert (stats["loaded"], stats["compiled"]) == (0, 9)
 
 
 def test_without_a_cache_directory_nothing_is_written_or_read(tmp_path, monkeypatch):
     assert jax.config.jax_compilation_cache_dir is None
     monkeypatch.setattr(step_programs, "write_bundle", lambda *a: pytest.fail("wrote a bundle"))
     stats = fill_step_table(_programs(), include_group=False).stats
-    assert (stats["loaded"], stats["compiled"]) == (0, 5) and "bundle" not in stats
+    assert (stats["loaded"], stats["compiled"]) == (0, 7) and "bundle" not in stats
 
 
 def test_a_deployment_keeps_its_two_newest_bundles(tmp_path):
@@ -333,6 +334,15 @@ def _warmup_calls_before_the_list(cfg, include_group=True) -> list[tuple]:
     return calls
 
 
+# ... and the three programs a deployment has had since a chunk call takes
+# two prompts' pieces (core.round_calls): its three widest row counts at two
+# slots a call; none where there is one slot.
+TWO_SLOT_ROWS = {
+    "tiny": [16, 32], "tiny, no group": [16, 32], "defaults": [256, 512, 1024], "a wide chunk": [512, 1024, 2048],
+    "no prompt reaches the wide chunk": [256, 512, 1024], "one slot": [],
+}
+
+
 LIST_CASES = {
     "tiny": (CFG, True),
     "tiny, no group": (CFG, False),
@@ -347,7 +357,11 @@ LIST_CASES = {
 def test_the_list_covers_every_call_warmup_made(case):
     cfg, include_group = LIST_CASES[case]
     calls = StepPrograms(DENSE, cfg, N_VALID).calls(include_group)
-    assert [c.key for c in calls] == _warmup_calls_before_the_list(cfg, include_group)
+    before = _warmup_calls_before_the_list(cfg, include_group)
+    assert [c.key for c in calls[: len(before)]] == before
+    assert [c.key for c in calls[len(before) :]] == [("prefill_chunk_jit", (2, rows)) for rows in TWO_SLOT_ROWS[case]]
+    # A family that reuses whole prefill calls shares none (core.pair_rows): the list as it was.
+    assert [c.key for c in StepPrograms(SMALLTHINKER, cfg, N_VALID).calls(include_group)] == before
     assert len({c.label for c in calls}) == len(calls)  # a label names one program in the bundle
     assert calls[0].member == "decode_jit"  # tests/test_named_scopes.py reads the programs in this order
 

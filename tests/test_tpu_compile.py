@@ -276,7 +276,10 @@ def test_mla_paged_decode_kernel_lowers(v5e, B, max_len):
 def test_latent_prefill_attention_lowers(v5e, B, S):
     """Every prefill's attention: 32 query heads of 640 over latent pages,
     in blocks of keys (XLA; no kernel). `[1, 2048]` is the wide chunk,
-    which reason-sat's engine warms and its prompts never reach."""
+    which a prompt of 1025 tokens and more runs as where warm-up measures
+    a read of the weights dearer than the padding's rows
+    (engine/core.py::prefill_plan); the family shares no chunk call
+    (core.pair_rows), so there is no `[2, 2048]`."""
     from kubeai_tpu.ops.mla_attention import latent_attention_paged
 
     max_pages = 4096 // PAGE
@@ -633,11 +636,15 @@ def test_the_expert_layers_way_back_holds_no_token_choice_width_array(way_back_p
         (QWEN25_7B, 8, 32, (32 + 4096 - 2) // PAGE + 2, 4096, 0.0),
         (QWEN25_7B_TP4, 8, 512, 2048 // PAGE, None, 30.0),
         (QWEN25_7B, SLOTS, 3, 2048 // PAGE, None, 0.0),
+        # Two prompts' pieces in one call (engine/core.py::round_calls): the widest, at two slots, in the
+        # families that share calls (core.pair_rows: not smallthinker, not trinity).
+        (NEMOTRON_ATTN, 2, 2048, 8192 // PAGE, None, 0.0),
+        (LLAMA3_8B, 2, 2048, 8192 // PAGE, None, 0.0),
     ],
     ids=[
         "smallthinker/chunk-2048/window-97-pages", "smallthinker/chunk-1024/full", "trinity/chunk-2048/full-512-pages",
         "mistral-7b/chunk-2048", "nemotron/chunk-2048", "smallthinker/cold-8x32/window", "qwen2.5-7b/tp4/8x512-softcap",
-        "qwen2.5-7b/verify-S3",
+        "qwen2.5-7b/verify-S3", "nemotron/chunk-2x2048", "mistral-7b/chunk-2x2048",
     ],
 )
 def test_chunk_kernel_lowers(v5e, monkeypatch, heads, B, S, columns, window, softcap):
@@ -732,8 +739,10 @@ def test_a_dense_layer_and_a_period_of_lfm2_decode_in_place_through_the_paged_ke
     assert memory.temp_size_in_bytes < cache["kv"].shape[0] * PAGE * 8 * 128 * 2 // 2  # no copy of the pool
 
 
-def test_a_2048_row_chunk_of_lfm2_runs_the_chunk_kernel_on_heads_side_by_side(v5e, monkeypatch):
-    """The cell's widest prefill call behind cached tokens: the attention
+@pytest.mark.parametrize("n", [1, 2], ids=["one_slot", "two_slots"])
+def test_a_2048_row_chunk_of_lfm2_runs_the_chunk_kernel_on_heads_side_by_side(v5e, monkeypatch, n):
+    """The cell's widest prefill call behind cached tokens, for one slot
+    and for two that share it (engine/core.py::round_calls): the attention
     layer is the repo's own chunk kernel (G = 8 on the widened heads: 256
     query rows a tile), never the portable gather, and pool and tails come
     back in place."""
@@ -748,8 +757,8 @@ def test_a_2048_row_chunk_of_lfm2_runs_the_chunk_kernel_on_heads_side_by_side(v5
         lambda p, t, c, tbl, start, last, slot: lfm2_moe.prefill_paged(p, mc, t, c, tbl, start, last, slots=slot),
         donate_argnums=(2,),
     ).lower(
-        params, _sds(v5e, (1, 2048), jnp.int32), cache, _sds(v5e, (1, max_pages), jnp.int32), _sds(v5e, (1,), jnp.int32),
-        _sds(v5e, (1,), jnp.int32), _sds(v5e, (1,), jnp.int32),
+        params, _sds(v5e, (n, 2048), jnp.int32), cache, _sds(v5e, (n, max_pages), jnp.int32), _sds(v5e, (n,), jnp.int32),
+        _sds(v5e, (n,), jnp.int32), _sds(v5e, (n,), jnp.int32),
     ).compile()
     text = compiled.as_text()
     assert "chunk_attention_kernel" in text and "ragged_paged_attention_kernel" not in text
